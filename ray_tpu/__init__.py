@@ -195,12 +195,12 @@ def remote(*args, **kwargs):
     """The `@ray_tpu.remote` decorator for functions and classes (parity:
     `ray.remote`, `worker.py:1697`).
 
-    Supported options: num_returns, num_cpus, num_tpus, resources,
-    max_retries (functions); num_cpus, num_tpus, resources, max_restarts,
-    max_concurrency (classes).
+    Supported options: num_returns, num_cpus, resources, max_retries
+    (functions); num_cpus, num_tpus, resources, max_restarts,
+    max_concurrency (classes). Only an actor can claim a TPU: a chip
+    belongs to one process, and tasks share CPU pool workers.
     """
-    _FN_OPTS = {"num_returns", "num_cpus", "num_tpus", "resources",
-                "max_retries"}
+    _FN_OPTS = {"num_returns", "num_cpus", "resources", "max_retries"}
     _CLS_OPTS = {"num_cpus", "num_tpus", "resources", "max_restarts",
                  "max_concurrency"}
 
@@ -224,7 +224,6 @@ def remote(*args, **kwargs):
             target,
             num_returns=kwargs.get("num_returns", 1),
             num_cpus=kwargs.get("num_cpus"),
-            num_tpus=kwargs.get("num_tpus"),
             resources=kwargs.get("resources"),
             max_retries=kwargs.get("max_retries", 3))
 

@@ -1413,8 +1413,9 @@ class HeadServer:
                 if info is None:
                     continue
                 try:
-                    w = self._spawn_worker_locked(node, dedicated=True,
-                                           extra_env=spec.env_vars)
+                    w = self._spawn_worker_locked(
+                        node, dedicated=True, resources=spec.resources,
+                        extra_env=spec.env_vars)
                 except Exception as e:
                     # A bad spawn (e.g. unpicklable env) must not abort the
                     # drain loop and strand other queued tasks.
@@ -1485,16 +1486,24 @@ class HeadServer:
         return f"w{self._token_counter}-{os.urandom(3).hex()}"
 
     def _spawn_worker_locked(self, node: NodeInfo, dedicated: bool,
-                      extra_env: Optional[dict] = None) -> WorkerInfo:
+                             resources: Optional[Dict[str, float]] = None,
+                             extra_env: Optional[dict] = None) -> WorkerInfo:
+        # One process owns a host's chips, and the scheduler says which:
+        # only a worker spawned for a "TPU" claim (an actor's — pool
+        # workers hold none) may open the device. Everyone else is put on
+        # the CPU out loud, so a stray `import jax` in a task can neither
+        # take the chip from its owner nor fall back without saying so.
+        env = {} if (resources or {}).get("TPU", 0) > 0 \
+            else {"JAX_PLATFORMS": "cpu"}
+        env.update(extra_env or {})
         token = self._next_token()
         if node.conn is None:
-            w = self._spawn_local_worker(token, dedicated, extra_env)
+            w = self._spawn_local_worker(token, env)
         else:
             # Remote node: the agent forks the worker (reference: raylet
             # WorkerPool on the task's node).
             node.conn.send({"kind": "spawn_worker", "token": token,
-                            "dedicated": dedicated,
-                            "env": dict(extra_env or {})})
+                            "dedicated": dedicated, "env": env})
             w = WorkerInfo(node.node_id, token, proc=None)
         w.dedicated = dedicated
         self._spawned[token] = w
@@ -1502,12 +1511,11 @@ class HeadServer:
             node.spawning_pool += 1
         return w
 
-    def _spawn_local_worker(self, token: str, dedicated: bool,
-                            extra_env: Optional[dict]) -> WorkerInfo:
+    def _spawn_local_worker(self, token: str,
+                            extra_env: Dict[str, str]) -> WorkerInfo:
         env = dict(os.environ)
         env.update(self.worker_env)
-        if extra_env:
-            env.update(extra_env)
+        env.update(extra_env)
         env["RAY_TPU_SESSION_DIR"] = self.session_dir
         env["RAY_TPU_SESSION_NAME"] = self.session_name
         env["RAY_TPU_NODE_ID"] = "node0"

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import atexit
 import datetime
+import glob
 import os
 import shutil
 import tempfile
@@ -33,17 +34,16 @@ def default_resources() -> Dict[str, float]:
 
 
 def detect_tpus() -> float:
-    """Count local TPU devices if jax is already imported (cheap); otherwise
-    report 0 and let the user pass resources={"TPU": n} explicitly."""
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return 0.0
-    try:
-        devs = jax.devices()
-    except Exception:
-        return 0.0
-    return float(len([d for d in devs if d.platform != "cpu"]))
+    """Count this host's TPU chips without opening them.
+
+    A chip belongs to the first process that initialises a jax backend on
+    it, and the driver is rarely the process that should (under `rllib
+    train` the trial actor is), so the count comes from the accelerator
+    device files — `/dev/accel<N>` or one `/dev/vfio/<N>` group per chip,
+    depending on the TPU VM image — never from `jax.devices()`.
+    """
+    return float(len(glob.glob("/dev/accel[0-9]*"))
+                 + len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 class Node:

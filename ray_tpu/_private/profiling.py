@@ -344,18 +344,29 @@ def sample_once() -> Dict[str, str]:
     return out
 
 
+def _live_devices() -> list:
+    """This process's local XLA devices, or [] when its own code has not
+    brought a jax backend up yet. Telemetry runs on background threads in
+    every runtime process; `jax.local_devices()` from one of them would
+    *initialise* the backend — and on a TPU host the first process to do
+    that takes the chip, whoever was meant to own it. Errors from a live
+    backend propagate: a device that stops answering is not "no device".
+
+    Looks only at modules already loaded: an `import` here can run while
+    the main thread is halfway through its own `import jax` and hand it a
+    partially initialised module.
+    """
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    initialized = getattr(bridge, "backends_are_initialized", None)
+    if initialized is None or not initialized():
+        return []
+    return sys.modules["jax"].local_devices()
+
+
 def owns_device() -> bool:
     """True when this process has a non-CPU XLA device attached (so a
-    `jax.profiler` trace would capture real device activity). Never
-    imports jax itself: a process that did not pay the import does not
-    own a device."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return any(d.platform != "cpu" for d in jax.local_devices())
-    except Exception:
-        return False
+    `jax.profiler` trace would capture real device activity)."""
+    return any(d.platform != "cpu" for d in _live_devices())
 
 
 def run_capture(duration_s: float, hz: Optional[float] = None,
@@ -451,29 +462,18 @@ def top_frames(folded: Dict[str, int], n: int = 10) -> List[tuple]:
 # ---------------------------------------------------------------------
 
 def device_memory_stats() -> List[dict]:
-    """Per-device HBM stats via `device.memory_stats()`. Returns [] when
-    jax was never imported here, and skips devices whose backend
-    returns None/empty (the CPU backend) — telemetry degrades to
-    nothing rather than erroring on hosts without accelerators."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return []
-    try:
-        devices = jax.local_devices()
-    except Exception:
-        return []
+    """Per-device HBM stats via `device.memory_stats()` for the devices
+    this process already holds (`_live_devices`). Backends that report
+    none (the CPU backend returns None) contribute no rows."""
     out = []
-    for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
+    for d in _live_devices():
+        stats = d.memory_stats()
         if not stats:
             continue
         out.append({
             "device": "d%d" % d.id,
-            "platform": getattr(d, "platform", "?"),
-            "kind": getattr(d, "device_kind", ""),
+            "platform": d.platform,
+            "kind": d.device_kind,
             "used": stats.get("bytes_in_use"),
             "peak": stats.get("peak_bytes_in_use"),
             "limit": stats.get("bytes_limit"),

@@ -2113,6 +2113,14 @@ class Runtime:
     def submit_task(self, function_key: str, args, kwargs, num_returns=1,
                     resources=None, max_retries=3, name="") -> List[ObjectRef]:
         t_submit = time.time()
+        if (resources or {}).get("TPU", 0) > 0:
+            # Tasks run on shared pool workers, which are CPU processes
+            # (head._spawn_worker_locked): honouring this claim there
+            # would train on the host without saying so.
+            raise ValueError(
+                f"task {name!r} claims a TPU, but a chip belongs to one "
+                "process at a time and pool workers never own one; claim "
+                "it from an actor (`num_tpus=` on a remote class)")
         a, kw = self._prepare_args(args, kwargs)
         parent = task_events.current_task_id()
         spec = TaskSpec(

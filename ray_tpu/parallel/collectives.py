@@ -216,8 +216,6 @@ def payload_bytes(tree, codec: str) -> int:
 def allreduce_probe_s(tree, mesh: Mesh, codec: str, axis: str = "dp",
                       iters: int = 3) -> float:
     """Median wall seconds of one standalone all-reduce of `tree`."""
-    from jax.experimental.shard_map import shard_map
-
     zeros = jax.device_put(
         jax.tree.map(
             lambda p: np.zeros(np.shape(p), np.float32), tree),
@@ -231,13 +229,13 @@ def allreduce_probe_s(tree, mesh: Mesh, codec: str, axis: str = "dp",
                 ef = jax.tree.map(lambda e: e[0], ef)
                 out, ef = psum_quantized(t, ef, axis)
                 return out, jax.tree.map(lambda e: e[None], ef)
-            # check_rep=False: replication of the summed output can't be
+            # check_vma=False: replication of the summed output can't be
             # statically inferred through all_gather + sum (it IS
             # replicated — every replica sums the same gathered payload).
-            return shard_map(
+            return jax.shard_map(
                 per_replica, mesh=mesh,
                 in_specs=(P(), P(axis)), out_specs=(P(), P(axis)),
-                check_rep=False)(t, ef)
+                check_vma=False)(t, ef)
 
         fn = jax.jit(step)
         args = (zeros, ef0)
@@ -245,7 +243,7 @@ def allreduce_probe_s(tree, mesh: Mesh, codec: str, axis: str = "dp",
         def step(t):
             def per_replica(t):
                 return jax.lax.psum(t, axis)
-            return shard_map(per_replica, mesh=mesh,
+            return jax.shard_map(per_replica, mesh=mesh,
                              in_specs=(P(),), out_specs=P())(t)
 
         fn = jax.jit(step)

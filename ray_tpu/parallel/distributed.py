@@ -54,10 +54,7 @@ def initialize(coordinator_address: str, num_processes: int,
     keeps a 1-process view).
     """
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # config knob absent on some builds: best effort
-        logger.debug("jax_cpu_collectives_implementation not settable")
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if local_device_ids is not None:
         kwargs["local_device_ids"] = list(local_device_ids)
@@ -80,10 +77,8 @@ def shutdown() -> None:
 def global_mesh(axis_name: str = "dp"):
     """A 1-D mesh over every device in the distributed world (all
     processes). Call after `initialize`."""
-    import jax
-    from jax.sharding import Mesh
-    import numpy as np
-    return Mesh(np.asarray(jax.devices()), (axis_name,))
+    from . import mesh as mesh_lib
+    return mesh_lib.make_mesh(axis_names=(axis_name,))
 
 
 def process_local_batch(sharding, local_array):

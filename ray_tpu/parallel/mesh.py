@@ -7,18 +7,39 @@ is ONE jitted program over a `jax.sharding.Mesh`: parameters replicated,
 batches sharded along the `dp` axis, and XLA inserts the gradient psum over
 ICI. The same program runs on 1 chip (trivial mesh) or a pod slice.
 
-Axis vocabulary (used by parallel/learner.py and the policies):
+Axis vocabulary (used by the policies and `sgd.JaxTrainer`):
 - "dp": data parallel (batch dim)
 - "mp": model/tensor parallel (large dense layers, optional)
 """
 
 from __future__ import annotations
 
+import os
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+# The directory is part of every cache key, so it must be the same in
+# every process and on every run: a fixed, git-ignored path in the
+# checkout — never a temp dir, a pid or the session directory.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Point XLA's persistent compile cache somewhere that stays put.
+
+    Every device owner builds its mesh through `make_mesh`, which calls
+    this first. Where JAX_COMPILATION_CACHE_DIR is set jax has already
+    taken it (spawned workers inherit the variable) and nothing is set
+    here; otherwise the cache goes to `COMPILE_CACHE_DIR`. Returns the
+    directory in use."""
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
 def get_devices(platform: Optional[str] = None):
@@ -37,6 +58,7 @@ def make_mesh(num_devices: Optional[int] = None,
     With only `num_devices`, makes a 1-D "dp" mesh. With `shape`,
     reshapes devices to that topology (e.g. (4, 2) for ("dp", "mp")).
     """
+    place_compile_cache()
     devs = list(devices if devices is not None else jax.devices())
     if num_devices is not None:
         devs = devs[:num_devices]

@@ -14,22 +14,20 @@ import cloudpickle
 from ._private import worker_state
 
 
-def _resource_spec(num_cpus, num_tpus, resources) -> dict:
+def _resource_spec(num_cpus, resources) -> dict:
     spec = {}
     spec["CPU"] = float(num_cpus) if num_cpus is not None else 1.0
-    if num_tpus:
-        spec["TPU"] = float(num_tpus)
     if resources:
         spec.update({k: float(v) for k, v in resources.items()})
     return spec
 
 
 class RemoteFunction:
-    def __init__(self, fn, num_returns=1, num_cpus=None, num_tpus=None,
+    def __init__(self, fn, num_returns=1, num_cpus=None,
                  resources=None, max_retries=3, name=None):
         self._function = fn
         self._num_returns = num_returns
-        self._resources = _resource_spec(num_cpus, num_tpus, resources)
+        self._resources = _resource_spec(num_cpus, resources)
         self._max_retries = max_retries
         self._name = name or getattr(fn, "__name__", "fn")
         self._key: Optional[str] = None
@@ -54,7 +52,7 @@ class RemoteFunction:
             return None
         return refs[0] if self._num_returns == 1 else refs
 
-    def options(self, num_returns=None, num_cpus=None, num_tpus=None,
+    def options(self, num_returns=None, num_cpus=None,
                 resources=None, max_retries=None, name=None):
         """Return a copy with overridden submit options (reference:
         `remote_function.py` `.options`)."""
@@ -66,8 +64,6 @@ class RemoteFunction:
         clone._resources = dict(self._resources)
         if num_cpus is not None:
             clone._resources["CPU"] = float(num_cpus)
-        if num_tpus is not None:
-            clone._resources["TPU"] = float(num_tpus)
         if resources:
             clone._resources.update({k: float(v) for k, v in resources.items()})
         # Share the exported key/bytes with the original.

@@ -156,16 +156,32 @@ class Trainer(Trainable):
 
     def _make_mesh(self):
         """Build the learner mesh. Requesting more devices than exist is
-        an error, not a silent single-device fallback."""
+        an error, not a silent single-device fallback — and so is getting
+        CPU devices for `num_tpus_for_learner`: jax registers its TPU
+        backend to fail quietly, so a chip that is missing or held by
+        another process would otherwise train on the host and exit 0.
+        Only a process put on the CPU out loud (JAX_PLATFORMS=cpu: the
+        test meshes, rollout workers) may stand in CPU devices."""
         import jax
         from ...parallel import mesh as mesh_lib
         n = self.config.get("num_tpus_for_learner") or 0
-        available = len(jax.devices())
-        if n > available:
+        devices = jax.devices()
+        if n > len(devices):
             raise ValueError(
-                f"num_tpus_for_learner={n} but only {available} device(s) "
-                f"visible to this process")
+                f"num_tpus_for_learner={n} but only {len(devices)} "
+                f"device(s) visible to this process")
+        if n and devices[0].platform == "cpu" \
+                and jax.config.jax_platforms != "cpu":
+            raise RuntimeError(
+                f"num_tpus_for_learner={n} but jax gave this process "
+                f"{devices[0].device_kind!r} devices: the TPU is missing "
+                "or another process holds it (one process drives all "
+                "chips of a host). Set JAX_PLATFORMS=cpu to train on the "
+                "host on purpose.")
         self.learner_mesh = mesh_lib.make_mesh(num_devices=n or 1)
+        d = self.learner_mesh.devices.flat[0]
+        self._device = {"platform": d.platform, "kind": d.device_kind,
+                        "count": int(self.learner_mesh.devices.size)}
 
     def _init(self, config, env_creator):
         """Subclasses/templates build workers + optimizer here."""
@@ -194,6 +210,17 @@ class Trainer(Trainable):
                 result = self._train_inner()
                 self._maybe_evaluate(result)
                 self._push_train_metrics(result, time.monotonic() - t0)
+                # Which device trained, read where it trained: a CLI
+                # caller can tell a TPU run from a CPU one from the
+                # result alone, without touching jax itself.
+                from ray_tpu._private.profiling import device_memory_stats
+                result["device"] = dict(
+                    self._device, peak_bytes_in_use=max(
+                        (s["peak"] for s in device_memory_stats()),
+                        default=None))
+                policy = getattr(self.workers.local_worker, "policy", None)
+                if hasattr(policy, "devices_in_use"):
+                    result["device"].update(policy.devices_in_use())
                 return result
             except RayError as e:
                 if not self.config.get("ignore_worker_failures"):

@@ -1,8 +1,8 @@
 """WorkerSet: one local RolloutWorker + N remote RolloutWorker actors.
 
 Parity: `rllib/evaluation/worker_set.py`. The local worker holds the
-learner-side policy (TPU); remote workers are actors pinned to CPU JAX via
-per-actor env vars (Podracer-style actor/learner split).
+learner-side policy (TPU); remote workers are actors that claim no TPU, so
+the head starts them on CPU JAX (Podracer-style actor/learner split).
 """
 
 from __future__ import annotations
@@ -11,7 +11,7 @@ from typing import Callable, List, Optional
 
 import ray_tpu
 
-from .rollout_worker import RolloutWorker, make_remote_worker_env
+from .rollout_worker import RolloutWorker
 
 
 class WorkerSet:
@@ -75,12 +75,12 @@ class WorkerSet:
     def _make_remote_worker(self, index: int):
         cfg = self._config
         # Rollout policies never touch the TPU: the chip stays with the
-        # learner process (SURVEY.md §5.8 TPU-native equivalent).
+        # learner process (SURVEY.md §5.8 TPU-native equivalent) because
+        # these actors claim CPUs only (head._spawn_worker_locked).
         policy_config = dict(cfg.get("policy_config") or cfg)
         policy_config.pop("_mesh", None)
         return self._remote_cls.options(
-            num_cpus=cfg.get("num_cpus_per_worker", 1),
-            env_vars=make_remote_worker_env()).remote(
+            num_cpus=cfg.get("num_cpus_per_worker", 1)).remote(
                 self._env_creator, self._policy_cls, policy_config,
                 num_envs=cfg.get("num_envs_per_worker", 1),
                 rollout_fragment_length=cfg.get(

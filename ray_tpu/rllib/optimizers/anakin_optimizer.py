@@ -182,6 +182,7 @@ class AnakinOptimizer(PolicyOptimizer):
                     policy.params, policy.opt_state, self._env_state,
                     self._obs, self._rng, self._ep_rew, self._ep_len)
             stats = {k: float(v) for k, v in stats.items()}
+        policy._batch_on = len(self._obs.sharding.device_set)
         self._grad_time_total += time.perf_counter() - t0
         self._grad_calls += 1
         n = self.updates_per_call * self.num_envs * self.T

@@ -192,6 +192,7 @@ class JaxPolicy(Policy):
         self._build_jitted_fns()
         self._sgd_fns: Dict = {}
         self.global_timestep = 0
+        self._batch_on = 0  # devices under the newest train batch's obs
         # Updates donate self.params; serialize them against weight
         # reads/writes from other threads (async optimizers run learning
         # on a LearnerThread while the driver broadcasts weights).
@@ -309,8 +310,6 @@ class JaxPolicy(Policy):
         # q8 makes the exchange explicit via shard_map so each sender
         # quantizes (grad + carried residual) before it travels.
         if codec == "q8":
-            from jax.experimental.shard_map import shard_map
-
             def loss_grad(params, batch, rng, loss_state, ef):
                 def per_replica(params, batch, rng, loss_state, ef):
                     ef = jax.tree.map(lambda e: e[0], ef)
@@ -322,14 +321,14 @@ class JaxPolicy(Policy):
                         (loss, dict(stats)), axis)
                     return loss, stats, grads, jax.tree.map(
                         lambda e: e[None], ef)
-                # check_rep=False: the summed output IS replicated
+                # check_vma=False: the summed output IS replicated
                 # (every replica sums the same gathered payload) but
                 # shard_map cannot infer that through all_gather + sum.
-                return shard_map(
+                return jax.shard_map(
                     per_replica, mesh=self.mesh,
                     in_specs=(P(), P(axis), P(), P(), P(axis)),
                     out_specs=(P(), P(), P(), P(axis)),
-                    check_rep=False)(params, batch, rng, loss_state, ef)
+                    check_vma=False)(params, batch, rng, loss_state, ef)
         else:
             def loss_grad(params, batch, rng, loss_state, ef):
                 loss, stats, grads = local_loss_grad(
@@ -478,7 +477,19 @@ class JaxPolicy(Policy):
                 if v.dtype == np.bool_:
                     v = v.astype(np.float32)
                 out[k] = jax.device_put(v, self._bsharded)
+        if sb.OBS in out:
+            self._batch_on = len(out[sb.OBS].sharding.device_set)
         return out
+
+    def devices_in_use(self) -> Dict:
+        """How many devices the parameters and the newest train batch's
+        observations occupy, read from the arrays' own shardings — what a
+        mesh was built for and where `device_put` really left the data
+        are different questions."""
+        with self._update_lock:
+            params_on = set().union(*(
+                x.sharding.device_set for x in jax.tree.leaves(self.params)))
+        return {"params_on": len(params_on), "batch_on": self._batch_on}
 
     def postprocess_trajectory(self, batch, other_agent_batches=None,
                                episode=None):

@@ -30,8 +30,12 @@ class TaskPool:
         if not ready and blocking_wait:
             ready, _ = ray_tpu.wait(pending, num_returns=1, timeout=10.0)
         for ref in ready:
-            worker = self._tasks.pop(ref)
-            yield worker, ref
+            # The consumer may retire a worker between two yields (fleet
+            # eviction / preemption -> remove_worker), which takes that
+            # worker's other ready refs out from under this loop.
+            worker = self._tasks.pop(ref, None)
+            if worker is not None:
+                yield worker, ref
 
     def remove_worker(self, worker) -> list:
         """Drop every in-flight task of one worker (fleet removal /

@@ -178,7 +178,6 @@ class JaxRunner:
             return jax.value_and_grad(batch_loss)(params)
 
         if codec == "q8":
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             ndev = int(self.mesh.shape[axis])
 
@@ -191,13 +190,13 @@ class JaxRunner:
                     loss = jax.lax.pmean(loss, axis)
                     return loss, grads, jax.tree.map(
                         lambda e: e[None], ef)
-                # check_rep=False: the summed output IS replicated but
+                # check_vma=False: the summed output IS replicated but
                 # that can't be inferred through all_gather + sum.
-                return shard_map(
+                return jax.shard_map(
                     per_replica, mesh=self.mesh,
                     in_specs=(P(), P(axis), P(axis), P(axis)),
                     out_specs=(P(), P(), P(axis)),
-                    check_rep=False)(params, x, y, ef)
+                    check_vma=False)(params, x, y, ef)
         else:
             def loss_grad(params, x, y, ef):
                 loss, grads = local_loss_grad(params, x, y)
@@ -434,7 +433,9 @@ class JaxTrainer:
 
     num_replicas=0: in-process training over the full device mesh (the
     TPU path). num_replicas>=1: runner actors, one shard each, synchronous
-    weight-averaged epochs, elastic recovery on actor death.
+    weight-averaged epochs, elastic recovery on actor death. Runners claim
+    no TPU, so the head starts them on CPU JAX (one process drives all of
+    a host's chips); `runner_env` overrides their environment.
     """
 
     def __init__(self,

@@ -42,7 +42,10 @@ class RayTrialExecutor:
         remote_cls = ray_tpu.remote(cls)
         # The trial actor itself takes 1 CPU; its own rollout-worker
         # actors claim theirs separately (the full footprint is what
-        # `has_resources` gates on).
+        # `has_resources` gates on). The TPU claim is the trial actor's
+        # alone: it is what makes the head spawn this worker — and no
+        # other — with the device (head._spawn_worker_locked).
+        request = cls.default_resource_request(trial.config) or {}
         logdir = trial.logdir
 
         def logger_creator(config, _logdir=logdir):
@@ -50,7 +53,8 @@ class RayTrialExecutor:
             return UnifiedLogger(config, _logdir)
 
         try:
-            runner = remote_cls.options(num_cpus=1).remote(
+            runner = remote_cls.options(
+                num_cpus=1, num_tpus=request.get("TPU") or None).remote(
                 config=trial.config, logger_creator=logger_creator)
             trial.runner = runner
             self._trial_actor[trial] = runner

@@ -100,16 +100,15 @@ class TestQuantizedAllReduce:
         """One jitted q8 all-reduce over stacked[ndev, n] per-device
         values (built ONCE per test — jax.jit caches on fn identity)."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         def per_replica(v, e):
             out, ne = collectives.psum_quantized(v[0], e[0], "dp")
             return out[None], ne[None]
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             per_replica, mesh=mesh, in_specs=(P("dp"), P("dp")),
-            out_specs=(P("dp"), P("dp")), check_rep=False))
+            out_specs=(P("dp"), P("dp")), check_vma=False))
         sh = NamedSharding(mesh, P("dp"))
 
         def run(stacked, ef_stacked):

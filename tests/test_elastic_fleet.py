@@ -310,6 +310,24 @@ class TestWeightPlaneChurn:
         assert sorted(p.remove_worker(w1)) == ["a", "b"]
         assert p.count == 1
 
+    def test_taskpool_survives_removal_between_yields(self, monkeypatch):
+        """Preempting a worker while draining `completed()` drops its
+        other ready refs; the drain must skip them, not KeyError."""
+        from ray_tpu.rllib.utils import actors
+        monkeypatch.setattr(actors.ray_tpu, "wait",
+                            lambda refs, **kw: (list(refs), []))
+        p = actors.TaskPool()
+        w1, w2 = object(), object()
+        p.add(w1, "a")
+        p.add(w1, "b")
+        p.add(w2, "c")
+        seen = []
+        for worker, ref in p.completed():
+            seen.append(ref)
+            if worker is w1:
+                p.remove_worker(w1)
+        assert seen == ["a", "c"] and p.count == 0
+
     def test_bootstrap_routes_delta_for_warm_rejoin(self, monkeypatch):
         b, weights = self._broadcaster(monkeypatch)
         b.broadcast()                       # v1: full (no base yet)

@@ -151,6 +151,13 @@ class _FakeDevice:
 
 
 class TestDeviceTelemetry:
+    @pytest.fixture(autouse=True)
+    def _backend_up(self):
+        # Telemetry reads devices only once this process's own code has
+        # brought the backend up (profiling._live_devices).
+        import jax
+        jax.devices()
+
     def test_graceful_when_memory_stats_returns_none(self, monkeypatch):
         import jax
         monkeypatch.setattr(
