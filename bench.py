@@ -457,7 +457,8 @@ def bench_sebulba(n_dev: int, env: str, obs_delta, n_actors: int,
         out = {}
         for a in opt._inline_actors:
             for k, v in a.sampler.transfer_stats().items():
-                out[k] = out.get(k, 0) + v
+                if k != "phases":  # a per-thread table, not a counter
+                    out[k] = out.get(k, 0) + v
         return out
 
     last_result = [None]
@@ -500,17 +501,6 @@ def bench_sebulba(n_dev: int, env: str, obs_delta, n_actors: int,
                 (s1.get("policy_lag_sum", 0)
                  - s0.get("policy_lag_sum", 0)) / max(1, sampled), 3),
         }
-        # Wire-codec view of the obs stream (sampled probe through the
-        # runtime's StreamEncoder): what the striped data plane would
-        # ship per step on a host-to-host wire vs the raw bytes.
-        pw_raw = s1.get("wire_probe_raw", 0) - s0.get("wire_probe_raw", 0)
-        pw_wire = (s1.get("wire_probe_wire", 0)
-                   - s0.get("wire_probe_wire", 0))
-        if pw_raw > 0:
-            ratio = pw_wire / pw_raw
-            acct["wire_codec_ratio"] = round(ratio, 3)
-            acct["wire_bytes_per_step"] = round(
-                acct["bytes_per_step"] * ratio, 1)
         return trained / dt / n_dev, acct
 
     med, stddev_pct, acct, rates = median_windows(window, windows)

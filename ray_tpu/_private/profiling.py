@@ -32,6 +32,12 @@ On-demand captures (the active profiling plane) also live here:
   - `device_memory_stats()` / `publish_device_gauges()` — per-device
     HBM used/peak/limit via `device.memory_stats()`, degrading to
     nothing on backends (CPU) that return None.
+
+The hot loops name their own time with `phase()` (below): one `with`
+that is a `jax.profiler.TraceAnnotation` ("ray_tpu.<name>", on the
+device trace's clock while a profiler session runs, a no-op outside
+one) and an entry in the calling thread's `PhaseClock` (always on;
+cumulative seconds and counts that partition the thread's wall time).
 """
 
 from __future__ import annotations
@@ -342,6 +348,128 @@ def sample_once() -> Dict[str, str]:
         name = names.get(tid) or ("tid-%d" % tid)
         out[name] = _fold_frame(frame, name)
     return out
+
+
+class PhaseClock:
+    """One loop thread's wall time by phase: `{name: [seconds, count]}`
+    since the thread bound the clock. A single writer (the bound thread,
+    through `phase()`) and no lock; any thread may `snapshot()`. Phases
+    of one thread do not nest, so that they partition its wall time and
+    what no phase covers is a number of its own (`other_s`)."""
+
+    __slots__ = ("_phases", "_open", "_t_start")
+
+    def __init__(self):
+        self._phases: Dict[str, list] = {}
+        self._open = None  # (name, t0) of the phase the thread is inside
+        self._t_start = time.perf_counter()
+
+    def bind(self) -> "PhaseClock":
+        """Make this the calling thread's clock (the thread's owner calls
+        it from that thread; a no-op when it already is). Wall time
+        counts from the first bind."""
+        if getattr(_thread, "clock", None) is not self:
+            if not self._phases:
+                self._t_start = time.perf_counter()
+            _thread.clock = self
+        return self
+
+    def seconds(self, name: str) -> float:
+        cell = self._phases.get(name)
+        return cell[0] if cell else 0.0
+
+    def snapshot(self) -> dict:
+        """Cumulative and monotone: `seconds` and `counts` per phase,
+        `wall_s` since the first bind, `other_s` = wall - sum(seconds).
+        A phase that is open now counts up to now, so a thread blocked
+        in one (a full learner queue) does not read as `other`."""
+        now = time.perf_counter()
+        open_ = self._open
+        cells = dict(self._phases)  # one C-level copy: safe against the writer
+        seconds = {k: v[0] for k, v in cells.items()}
+        counts = {k: v[1] for k, v in cells.items()}
+        if open_ is not None:
+            seconds[open_[0]] = seconds.get(open_[0], 0.0) + now - open_[1]
+            counts.setdefault(open_[0], 0)
+        wall = now - self._t_start
+        return {"wall_s": wall, "other_s": wall - sum(seconds.values()),
+                "seconds": seconds, "counts": counts}
+
+
+def sum_snapshots(snapshots: List[dict]) -> dict:
+    """Snapshots of several threads added up key by key (shares of the
+    summed `wall_s` are then means over the threads)."""
+    out = {"wall_s": 0.0, "other_s": 0.0, "seconds": {}, "counts": {}}
+    for snap in snapshots:
+        out["wall_s"] += snap["wall_s"]
+        out["other_s"] += snap["other_s"]
+        for key in ("seconds", "counts"):
+            for name, v in snap[key].items():
+                out[key][name] = out[key].get(name, 0) + v
+    return out
+
+
+_thread = threading.local()  # .clock: the PhaseClock bound to this thread
+
+
+class phase:
+    """`with phase("sebulba.upload"): ...` — a named step of a loop
+    thread. Opens `jax.profiler.TraceAnnotation("ray_tpu.<name>")` (taken
+    from `sys.modules`, as `_live_devices()` takes jax: this module never
+    imports it) and adds the `perf_counter` delta and a count to the
+    calling thread's `PhaseClock`. A thread with no clock gets the
+    annotation only. There is no switch: the accumulators are always on,
+    and "tracing on" means a `jax.profiler` session is running."""
+
+    __slots__ = ("_name", "_span", "_clock", "_t0")
+
+    def __init__(self, name: str):
+        # The phase's time starts here, not in __enter__: `with phase(..)`
+        # does both at once, and with several loop threads under one GIL
+        # every call is a point where the thread may have to hand the GIL
+        # over. Reading the clock first keeps that wait inside the phase
+        # instead of in no phase at all.
+        self._t0 = time.perf_counter()
+        self._name = name
+
+    def __enter__(self):
+        clock = self._clock = getattr(_thread, "clock", None)
+        if clock is not None:
+            if __debug__ and clock._open is not None:
+                raise RuntimeError(
+                    f"phase {self._name!r} opened inside "
+                    f"{clock._open[0]!r}: phases of a thread do not nest")
+            clock._open = (self._name, self._t0)
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            self._span = None
+        else:
+            self._span = profiler.TraceAnnotation("ray_tpu." + self._name)
+            self._span.__enter__()
+        return self
+
+    def then(self, name: str) -> None:
+        """End this phase and begin `name`, inside the one `with` — for a
+        step that changes its name half way, as when a lock is taken:
+        the wait for it, then the work under it."""
+        self.__exit__(None, None, None)
+        self.__init__(name)
+        self.__enter__()
+
+    def __exit__(self, *exc):
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        clock = self._clock
+        if clock is not None:
+            clock._open = None
+            dt = time.perf_counter() - self._t0
+            cell = clock._phases.get(self._name)
+            if cell is None:
+                clock._phases[self._name] = [dt, 1]
+            else:
+                cell[0] += dt
+                cell[1] += 1
+        return False
 
 
 def _live_devices() -> list:

@@ -55,24 +55,42 @@ device round-trip per env step while the link sat at 45%):
   the lag — exactly the off-policyness IMPALA's correction exists for
   (PAPERS: "Podracer architectures for scalable RL").
 
-Byte/time accounting is kept on the instance (`bytes_h2d`, `bytes_d2h`,
-`t_fetch`, `t_env`, `policy_lag_sum`, `fetch_waits`) so `bench.py` can
-print a per-stage bandwidth account instead of asserting
-"transfer-bound" untested.
+Byte accounting is kept on the instance (`bytes_h2d`, `bytes_d2h`,
+`policy_lag_sum`, `fetch_waits`); time accounting on `self.clock`, the
+`PhaseClock` of whichever thread samples: every step of the loop below
+is a `phase("sebulba.<step>")`, so the thread's wall time is partitioned
+by name (`transfer_stats()["phases"]`; `t_fetch` / `t_env` are views of
+it) and the same names are spans on a `jax.profiler` trace.
 """
 
 from __future__ import annotations
 
-import time
+import functools
 from typing import List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..._private.profiling import PhaseClock, phase
 from .. import sample_batch as sb
 from ..sample_batch import SampleBatch
 from .sampler import RolloutMetrics
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("sebulba/apply")
+def apply_full(frames, rows, fulls):
+    """Bucketed whole-row replacement: rows [b] int32 (pad == n,
+    dropped), fulls [b, HW] uint8. One jitted function for every bucket
+    and every sampler: jit keeps a program per shape."""
+    return frames.at[rows].set(fulls, mode="drop")
+
+
+def _full_bucket(count: int, n: int) -> int:
+    """Rows of the full-row scatter that takes `count` of `n` rows: the
+    next power of two, at most n."""
+    return min(1 << (count - 1).bit_length(), n)
 
 
 class _EnvGroup:
@@ -168,21 +186,11 @@ class DeviceSebulbaSampler:
         # ---- transfer accounting (read by bench.py) ------------------
         self.bytes_h2d = 0       # delta entries / frames + flags shipped
         self.bytes_d2h = 0       # action arrays fetched down
-        self.t_fetch = 0.0       # host blocked waiting for actions
-        self.t_env = 0.0         # host inside env.vector_step
         self.steps_total = 0
         self.policy_lag_sum = 0  # sum over transitions of selection lag
         self.fetch_waits = 0     # blocking D2H action fetches (windows)
-        # Wire-codec probe: every Nth upload, a sample of the staged
-        # obs buffer runs through the runtime's wire codec
-        # (_private/serialization.StreamEncoder) to measure what the
-        # striped data plane would put on a host-to-host wire for this
-        # stream. Sampled, because compressing every upload inline
-        # would gate the sampler; the ratio is what bench.py needs.
-        self.wire_probe_raw = 0
-        self.wire_probe_wire = 0
-        self._wire_probe_every = 64
-        self._wire_uploads = 0
+        # The sampling thread's time by phase; bound in sample().
+        self.clock = PhaseClock()
 
         if self.delta:
             frame_space = getattr(envs[0], "inner", envs[0])\
@@ -190,7 +198,6 @@ class DeviceSebulbaSampler:
             fs = frame_space.shape
             self._frame_shape = fs
             self._hw = int(np.prod(fs))
-            self._full_fns = {}
 
         self.groups: List[_EnvGroup] = []
         for env in envs:
@@ -198,6 +205,8 @@ class DeviceSebulbaSampler:
                 _EnvGroup(self, env, self._eps_counter))
             self._eps_counter += env.num_envs
         self._build_fns()
+        if self.delta:
+            self._warm_full_buckets()
         # Prime every group's pipeline: obs_0 onto the device, first
         # selection window dispatched.
         for g in self.groups:
@@ -225,6 +234,7 @@ class DeviceSebulbaSampler:
             shape = self._frame_shape
             K = int(self.groups[0].env.delta_budget)
 
+            @jax.named_scope("sebulba/apply")
             def apply_delta(stack, frames, packed):
                 # frames: [N, HW] uint8 retained on device. packed:
                 # [N, 3K+1] uint8 — ONE upload per step carrying the
@@ -249,11 +259,13 @@ class DeviceSebulbaSampler:
             # which the train batch retains.
             self._apply_fn = jax.jit(apply_delta, donate_argnums=(1,))
         else:
+            @jax.named_scope("sebulba/apply")
             def apply_frame(stack, frame, done):
                 return update_stack(stack, frame, done) if S else frame
 
             self._apply_fn = jax.jit(apply_frame)
 
+        @jax.named_scope("sebulba/select")
         def select_fn(params, obs, rng, explore):
             """Model forward at the newest obs, then k sampled action
             arrays. All k actions of a window are selected from THIS
@@ -292,15 +304,18 @@ class DeviceSebulbaSampler:
             [np.ascontiguousarray(idx).view(np.uint8),
              val, done.astype(np.uint8)[:, None]], axis=1)
 
-    def _full_fn(self, b: int):
-        """Bucketed whole-row replacement: rows [b] int32 (pad == n,
-        dropped), fulls [b, HW] uint8."""
-        if b not in self._full_fns:
-            def apply_full(frames, rows, fulls):
-                return frames.at[rows].set(fulls, mode="drop")
-            self._full_fns[b] = jax.jit(
-                apply_full, donate_argnums=(0,))
-        return self._full_fns[b]
+    def _warm_full_buckets(self):
+        """Run every bucket of `apply_full` once, as an all-pad scatter
+        that leaves the frames as they are: which bucket a step needs
+        depends on how many envs reset in it, and the first burst of a
+        new size would otherwise compile in the middle of a run."""
+        g, policy = self.groups[0], self.policy
+        for b in sorted({_full_bucket(c, g.n) for c in range(1, g.n + 1)}):
+            g.frames_d = apply_full(
+                g.frames_d,
+                jax.device_put(np.full(b, g.n, np.int32), policy._repl),
+                jax.device_put(np.zeros((b, self._hw), np.uint8),
+                               policy._repl))
 
     # ------------------------------------------------------------------
     def _dispatch_apply(self, g: _EnvGroup):
@@ -317,40 +332,41 @@ class DeviceSebulbaSampler:
                 # Resets / over-budget rows: bucketed full-row scatter
                 # ahead of the sparse delta (delta entries for these
                 # rows are pad, per the DeltaStep contract).
-                b = 1 << (int(len(ds.full_rows)) - 1).bit_length() \
-                    if len(ds.full_rows) > 1 else 1
-                b = min(b, g.n)
-                rows = np.full(b, g.n, np.int32)
-                rows[:len(ds.full_rows)] = ds.full_rows
-                fulls = np.zeros((b, self._hw), np.uint8)
-                fulls[:len(ds.full_rows)] = ds.full_frames
-                g.frames_d = self._full_fn(b)(
-                    g.frames_d,
-                    jax.device_put(rows, policy._repl),
-                    jax.device_put(fulls, policy._repl))
-                self.bytes_h2d += rows.nbytes + fulls.nbytes
-            if ds is None:
-                # First step after reset: frames already uploaded whole;
-                # an all-pad delta leaves them untouched.
-                from ..env.delta_obs import all_pad_delta
-                pad = all_pad_delta(
-                    g.n, int(g.env.delta_budget), self._hw)
-                idx, val = pad.idx, pad.val
-            else:
-                idx, val = ds.idx, ds.val
-            packed = self._pack_step(idx, val, done)
-            packed_d = jax.device_put(packed, policy._bsharded)
-            self.bytes_h2d += packed.nbytes
-            self._wire_probe(packed)
-            g.obs_next, g.frames_d = self._apply_fn(
-                g.stack, g.frames_d, packed_d)
+                with phase("sebulba.upload"):
+                    b = _full_bucket(len(ds.full_rows), g.n)
+                    rows = np.full(b, g.n, np.int32)
+                    rows[:len(ds.full_rows)] = ds.full_rows
+                    fulls = np.zeros((b, self._hw), np.uint8)
+                    fulls[:len(ds.full_rows)] = ds.full_frames
+                    rows_d = jax.device_put(rows, policy._repl)
+                    fulls_d = jax.device_put(fulls, policy._repl)
+                    self.bytes_h2d += rows.nbytes + fulls.nbytes
+                with phase("sebulba.apply"):
+                    g.frames_d = apply_full(g.frames_d, rows_d, fulls_d)
+            with phase("sebulba.upload"):
+                if ds is None:
+                    # First step after reset: frames already uploaded
+                    # whole; an all-pad delta leaves them untouched.
+                    from ..env.delta_obs import all_pad_delta
+                    pad = all_pad_delta(
+                        g.n, int(g.env.delta_budget), self._hw)
+                    idx, val = pad.idx, pad.val
+                else:
+                    idx, val = ds.idx, ds.val
+                packed = self._pack_step(idx, val, done)
+                packed_d = jax.device_put(packed, policy._bsharded)
+                self.bytes_h2d += packed.nbytes
+            with phase("sebulba.apply"):
+                g.obs_next, g.frames_d = self._apply_fn(
+                    g.stack, g.frames_d, packed_d)
         else:
             frame = g.host_obs
-            frame_d = jax.device_put(frame, policy._bsharded)
-            done_d = jax.device_put(done, policy._bsharded)
-            self.bytes_h2d += frame.nbytes + done.nbytes
-            self._wire_probe(frame)
-            g.obs_next = self._apply_fn(g.stack, frame_d, done_d)
+            with phase("sebulba.upload"):
+                frame_d = jax.device_put(frame, policy._bsharded)
+                done_d = jax.device_put(done, policy._bsharded)
+                self.bytes_h2d += frame.nbytes + done.nbytes
+            with phase("sebulba.apply"):
+                g.obs_next = self._apply_fn(g.stack, frame_d, done_d)
         if self.frame_stack:
             g.stack = g.obs_next
 
@@ -359,27 +375,32 @@ class DeviceSebulbaSampler:
         start the D2H action copy so the eventual fetch is a cache hit.
         Reads live params — serialized against learner updates."""
         policy = self.policy
-        with policy._update_lock:
-            out = self._select_fn(
-                policy.params, g.obs_next, policy._next_rng(),
-                self.explore)
-        out[0].copy_to_host_async()
-        g.pending = out
+        with phase("sebulba.lock_wait") as step:
+            policy._update_lock.acquire()
+            try:
+                step.then("sebulba.select")
+                out = self._select_fn(
+                    policy.params, g.obs_next, policy._next_rng(),
+                    self.explore)
+            finally:
+                policy._update_lock.release()
+            out[0].copy_to_host_async()
+            g.pending = out
 
     def _consume_window(self, g: _EnvGroup):
         """Block on the group's dispatched selection window — the ONLY
         device fetch on the hot path, one [k, n] array per k steps."""
         acts_d, logp_d, di_d, val_d = g.pending
         g.pending = None
-        t0 = time.perf_counter()
-        g.win_actions = np.asarray(acts_d)
-        self.t_fetch += time.perf_counter() - t0
+        with phase("sebulba.fetch"):
+            g.win_actions = np.asarray(acts_d)
         self.fetch_waits += 1
         self.bytes_d2h += g.win_actions.nbytes
         g.win_logp, g.win_di, g.win_val = logp_d, di_d, val_d
 
     # ------------------------------------------------------------------
     def sample(self) -> SampleBatch:
+        self.clock.bind()
         T, k = self.T, self.k
         G = len(self.groups)
         obs_buf = [[] for _ in range(G)]
@@ -400,44 +421,46 @@ class DeviceSebulbaSampler:
                     # apply/select programs keep running on device —
                     # the double-buffering that hides the round-trip.
                     self._consume_window(g)
-                obs_buf[gi].append(g.obs_next)
-                logp_buf[gi].append(g.win_logp[jw])
-                di_buf[gi].append(g.win_di)
-                vf_buf[gi].append(g.win_val)
-                actions = g.win_actions[jw]
-                t0 = time.perf_counter()
-                if self.delta:
-                    g.host_delta, rewards, dones = \
-                        g.env.vector_step_delta(actions)
-                else:
-                    next_obs, rewards, dones = g.env.vector_step(actions)
-                    g.host_obs = np.asarray(next_obs)
-                self.t_env += time.perf_counter() - t0
-                eps_ids[gi][t] = g.cur_eps
-                ts[gi][t] = g.ep_len
-                act_host[gi].append(actions)
-                rew_buf[gi].append(np.asarray(rewards, np.float32))
-                done_buf[gi].append(np.asarray(dones))
-                g.ep_rew += rewards
-                g.ep_len += 1
-                if dones.any():
-                    done_idx = np.nonzero(dones)[0]
-                    for i in done_idx:
-                        self.metrics.append(RolloutMetrics(
-                            int(g.ep_len[i]), float(g.ep_rew[i])))
-                    g.ep_rew[dones] = 0.0
-                    g.ep_len[dones] = 0
-                    g.cur_eps[dones] = self._eps_counter + np.arange(
-                        len(done_idx), dtype=np.int64)
-                    self._eps_counter += len(done_idx)
-                g.host_done = np.asarray(dones)
-                # Per-turn accounting (not per-fragment): the bench's
-                # windowed bytes-per-step ratio needs finer ticks than
-                # fragment completions on LOW-rate configs — the
-                # full-frame continuity line completes only ~2-3
-                # fragments per 10s window, quantizing the ratio by
-                # 2-3x. Total per fragment is unchanged.
-                self.steps_total += g.n
+                with phase("sebulba.record"):
+                    obs_buf[gi].append(g.obs_next)
+                    logp_buf[gi].append(g.win_logp[jw])
+                    di_buf[gi].append(g.win_di)
+                    vf_buf[gi].append(g.win_val)
+                    actions = g.win_actions[jw]
+                with phase("sebulba.env_step"):
+                    if self.delta:
+                        g.host_delta, rewards, dones = \
+                            g.env.vector_step_delta(actions)
+                    else:
+                        next_obs, rewards, dones = \
+                            g.env.vector_step(actions)
+                        g.host_obs = np.asarray(next_obs)
+                with phase("sebulba.record"):
+                    eps_ids[gi][t] = g.cur_eps
+                    ts[gi][t] = g.ep_len
+                    act_host[gi].append(actions)
+                    rew_buf[gi].append(np.asarray(rewards, np.float32))
+                    done_buf[gi].append(np.asarray(dones))
+                    g.ep_rew += rewards
+                    g.ep_len += 1
+                    if dones.any():
+                        done_idx = np.nonzero(dones)[0]
+                        for i in done_idx:
+                            self.metrics.append(RolloutMetrics(
+                                int(g.ep_len[i]), float(g.ep_rew[i])))
+                        g.ep_rew[dones] = 0.0
+                        g.ep_len[dones] = 0
+                        g.cur_eps[dones] = self._eps_counter + np.arange(
+                            len(done_idx), dtype=np.int64)
+                        self._eps_counter += len(done_idx)
+                    g.host_done = np.asarray(dones)
+                    # Per-turn accounting (not per-fragment): the bench's
+                    # windowed bytes-per-step ratio needs finer ticks than
+                    # fragment completions on LOW-rate configs — the
+                    # full-frame continuity line completes only ~2-3
+                    # fragments per 10s window, quantizing the ratio by
+                    # 2-3x. Total per fragment is unchanged.
+                    self.steps_total += g.n
                 # Prefetch: the obs apply for the NEXT step runs while
                 # this turn finishes bookkeeping (and while the learner
                 # trains); at window end the next selection dispatches.
@@ -445,66 +468,66 @@ class DeviceSebulbaSampler:
                 if jw == k - 1:
                     self._dispatch_select(g)
 
-        # Selection lag per transition: sub-step j of a window executed
-        # an action chosen from the window-head obs, j steps stale.
-        lags = (np.arange(T, dtype=np.int64) % k).astype(np.int32)
-        self.policy_lag_sum += int(lags.sum()) * self._n
+        with phase("sebulba.pack"):
+            # Selection lag per transition: sub-step j of a window executed
+            # an action chosen from the window-head obs, j steps stale.
+            lags = (np.arange(T, dtype=np.int64) % k).astype(np.int32)
+            self.policy_lag_sum += int(lags.sum()) * self._n
 
-        # Each group's obs_next is the post-fragment bootstrap
-        # observation AND step 0 of the next fragment — computed once.
-        boot_obs = (self.groups[0].obs_next if G == 1 else
-                    jnp.concatenate(
-                        [g.obs_next for g in self.groups], axis=0))
+            # Each group's obs_next is the post-fragment bootstrap
+            # observation AND step 0 of the next fragment — computed once.
+            boot_obs = (self.groups[0].obs_next if G == 1 else
+                        jnp.concatenate(
+                            [g.obs_next for g in self.groups], axis=0))
 
-        def dpack(gbufs):
-            parts = []
-            for g, bufs in zip(self.groups, gbufs):
-                a = jnp.stack(bufs)  # [T, n, ...]
-                parts.append(jnp.swapaxes(a, 0, 1).reshape(
-                    (g.n * T,) + a.shape[2:]))
-            return parts[0] if G == 1 else jnp.concatenate(parts, axis=0)
+            def dpack(gbufs):
+                parts = []
+                for g, bufs in zip(self.groups, gbufs):
+                    a = jnp.stack(bufs)  # [T, n, ...]
+                    parts.append(jnp.swapaxes(a, 0, 1).reshape(
+                        (g.n * T,) + a.shape[2:]))
+                return parts[0] if G == 1 else jnp.concatenate(parts, axis=0)
 
-        def hpack(gbufs):
-            parts = []
-            for g, bufs in zip(self.groups, gbufs):
-                a = np.stack(bufs)
-                parts.append(np.swapaxes(a, 0, 1).reshape(
-                    (g.n * T,) + a.shape[2:]))
-            return parts[0] if G == 1 else np.concatenate(parts, axis=0)
+            def hpack(gbufs):
+                parts = []
+                for g, bufs in zip(self.groups, gbufs):
+                    a = np.stack(bufs)
+                    parts.append(np.swapaxes(a, 0, 1).reshape(
+                        (g.n * T,) + a.shape[2:]))
+                return parts[0] if G == 1 else np.concatenate(parts, axis=0)
 
-        def hpack_tn(arrs):
-            return np.concatenate(
-                [np.swapaxes(a, 0, 1).reshape(-1) for a in arrs])
+            def hpack_tn(arrs):
+                return np.concatenate(
+                    [np.swapaxes(a, 0, 1).reshape(-1) for a in arrs])
 
-        return SampleBatch({
-            sb.OBS: dpack(obs_buf),
-            sb.ACTION_LOGP: dpack(logp_buf),
-            sb.ACTION_DIST_INPUTS: dpack(di_buf),
-            sb.VF_PREDS: dpack(vf_buf),
-            sb.BOOTSTRAP_OBS: boot_obs,
-            sb.ACTIONS: hpack(act_host),
-            sb.REWARDS: hpack(rew_buf),
-            sb.DONES: hpack(done_buf),
-            sb.EPS_ID: hpack_tn(eps_ids),
-            sb.T: hpack_tn(ts),
-            sb.POLICY_LAG: np.tile(lags, self._n),
-        })
+            return SampleBatch({
+                sb.OBS: dpack(obs_buf),
+                sb.ACTION_LOGP: dpack(logp_buf),
+                sb.ACTION_DIST_INPUTS: dpack(di_buf),
+                sb.VF_PREDS: dpack(vf_buf),
+                sb.BOOTSTRAP_OBS: boot_obs,
+                sb.ACTIONS: hpack(act_host),
+                sb.REWARDS: hpack(rew_buf),
+                sb.DONES: hpack(done_buf),
+                sb.EPS_ID: hpack_tn(eps_ids),
+                sb.T: hpack_tn(ts),
+                sb.POLICY_LAG: np.tile(lags, self._n),
+            })
 
     def get_metrics(self) -> List[RolloutMetrics]:
         out = self.metrics
         self.metrics = []
         return out
 
-    def _wire_probe(self, arr) -> None:
-        self._wire_uploads += 1
-        if self._wire_uploads % self._wire_probe_every:
-            return
-        from ray_tpu._private import serialization as _ser
-        mv = memoryview(np.ascontiguousarray(arr)).cast("B")
-        sample = bytes(mv[:262144])
-        _, payload = _ser.StreamEncoder(mode="on").encode(sample)
-        self.wire_probe_raw += len(sample)
-        self.wire_probe_wire += len(payload)
+    @property
+    def t_fetch(self) -> float:
+        """Seconds the host was blocked waiting for actions."""
+        return self.clock.seconds("sebulba.fetch")
+
+    @property
+    def t_env(self) -> float:
+        """Seconds the host spent inside the envs' vector step."""
+        return self.clock.seconds("sebulba.env_step")
 
     def transfer_stats(self) -> dict:
         return {
@@ -515,6 +538,5 @@ class DeviceSebulbaSampler:
             "steps": self.steps_total,
             "policy_lag_sum": self.policy_lag_sum,
             "fetch_waits": self.fetch_waits,
-            "wire_probe_raw": self.wire_probe_raw,
-            "wire_probe_wire": self.wire_probe_wire,
+            "phases": self.clock.snapshot(),
         }

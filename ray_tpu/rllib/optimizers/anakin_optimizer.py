@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ..._private.profiling import PhaseClock, phase
 from .. import sample_batch as sb
 from .policy_optimizer import PolicyOptimizer
 
@@ -56,6 +57,8 @@ class AnakinOptimizer(PolicyOptimizer):
         self._episodes_total = 0
         self._grad_time_total = 0.0
         self._grad_calls = 0
+        # The calling thread's time by phase: anakin.call, anakin.readback.
+        self.clock = PhaseClock()
 
         policy = self.policy
         mesh_size = int(policy.mesh.devices.size) \
@@ -92,56 +95,71 @@ class AnakinOptimizer(PolicyOptimizer):
             """[T, N, ...] -> env-major flat [N*T, ...]."""
             return jnp.swapaxes(x, 0, 1).reshape((N * T,) + x.shape[2:])
 
+        # Every op of the program sits under one of four scopes
+        # (`jax.named_scope`: op metadata, nothing at run time), so a
+        # trace attributes device time by name. Scopes nest where a scan
+        # is called inside one; an op belongs to the innermost (last)
+        # `anakin/<scope>` of its name.
         def one_update(carry, _):
             (params, opt_state, env_state, obs, rng,
              ep_rew, ep_len, ep_acc) = carry
 
             def step_fn(scarry, _):
                 env_state, obs, rng, ep_rew, ep_len, ep_acc = scarry
-                rng, akey, ekey = jax.random.split(rng, 3)
-                dist_inputs, _ = policy.apply(params, obs)
-                action = policy.dist_class(dist_inputs).sample(akey)
-                env_state, next_obs, reward, done = vstep(
-                    env_state, action, jax.random.split(ekey, N))
-                # Episode bookkeeping (completed-episode sums + counts).
-                ep_rew = ep_rew + reward
-                ep_len = ep_len + 1
-                donef = done.astype(jnp.float32)
-                ep_acc = (ep_acc[0] + jnp.sum(donef * ep_rew),
-                          ep_acc[1] + jnp.sum(donef * ep_len),
-                          ep_acc[2] + jnp.sum(donef))
-                ep_rew = jnp.where(done, 0.0, ep_rew)
-                ep_len = jnp.where(done, 0, ep_len)
+                with jax.named_scope("anakin/inference"):
+                    rng, akey, ekey = jax.random.split(rng, 3)
+                    dist_inputs, _ = policy.apply(params, obs)
+                    action = policy.dist_class(dist_inputs).sample(akey)
+                with jax.named_scope("anakin/env_step"):
+                    env_state, next_obs, reward, done = vstep(
+                        env_state, action, jax.random.split(ekey, N))
+                    # Episode bookkeeping (completed-episode sums +
+                    # counts).
+                    ep_rew = ep_rew + reward
+                    ep_len = ep_len + 1
+                    donef = done.astype(jnp.float32)
+                    ep_acc = (ep_acc[0] + jnp.sum(donef * ep_rew),
+                              ep_acc[1] + jnp.sum(donef * ep_len),
+                              ep_acc[2] + jnp.sum(donef))
+                    ep_rew = jnp.where(done, 0.0, ep_rew)
+                    ep_len = jnp.where(done, 0, ep_len)
                 out = (obs, action, reward, done, dist_inputs)
                 return (env_state, next_obs, rng, ep_rew, ep_len,
                         ep_acc), out
 
-            (env_state, obs, rng, ep_rew, ep_len, ep_acc), traj = \
-                jax.lax.scan(
-                    step_fn,
-                    (env_state, obs, rng, ep_rew, ep_len, ep_acc),
-                    None, length=T)
-            obs_t, act_t, rew_t, done_t, logits_t = traj
-            batch = {
-                sb.OBS: em(obs_t),
-                sb.ACTIONS: em(act_t),
-                sb.REWARDS: em(rew_t),
-                sb.DONES: em(done_t).astype(jnp.float32),
-                sb.ACTION_DIST_INPUTS: em(logits_t),
-                # Behaviour log-probs equal target log-probs on-policy;
-                # losses that want them recompute from the logits.
-                sb.BOOTSTRAP_OBS: obs,
-            }
-            rng, lkey = jax.random.split(rng)
-            (loss, stats), grads = jax.value_and_grad(
-                policy._loss_fn, argnums=1, has_aux=True)(
-                    policy, params, batch, lkey, policy.loss_state)
-            updates, opt_state = policy.optimizer.update(
-                grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            # The rollout loop's own ops (stacking the trajectory) count
+            # as env_step.
+            with jax.named_scope("anakin/env_step"):
+                (env_state, obs, rng, ep_rew, ep_len, ep_acc), traj = \
+                    jax.lax.scan(
+                        step_fn,
+                        (env_state, obs, rng, ep_rew, ep_len, ep_acc),
+                        None, length=T)
+            with jax.named_scope("anakin/loss"):
+                obs_t, act_t, rew_t, done_t, logits_t = traj
+                batch = {
+                    sb.OBS: em(obs_t),
+                    sb.ACTIONS: em(act_t),
+                    sb.REWARDS: em(rew_t),
+                    sb.DONES: em(done_t).astype(jnp.float32),
+                    sb.ACTION_DIST_INPUTS: em(logits_t),
+                    # Behaviour log-probs equal target log-probs
+                    # on-policy; losses that want them recompute from
+                    # the logits.
+                    sb.BOOTSTRAP_OBS: obs,
+                }
+                rng, lkey = jax.random.split(rng)
+                (loss, stats), grads = jax.value_and_grad(
+                    policy._loss_fn, argnums=1, has_aux=True)(
+                        policy, params, batch, lkey, policy.loss_state)
+            with jax.named_scope("anakin/update"):
+                updates, opt_state = policy.optimizer.update(
+                    grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return (params, opt_state, env_state, obs, rng,
                     ep_rew, ep_len, ep_acc), stats
 
+        @jax.named_scope("anakin/update")
         def anakin_fn(params, opt_state, env_state, obs, rng,
                       ep_rew, ep_len):
             ep_acc = (jnp.zeros((), jnp.float32),
@@ -175,13 +193,16 @@ class AnakinOptimizer(PolicyOptimizer):
     def step(self) -> dict:
         policy = self.policy
         t0 = time.perf_counter()
+        self.clock.bind()
         with policy._update_lock:
-            (policy.params, policy.opt_state, self._env_state, self._obs,
-             self._rng, self._ep_rew, self._ep_len, stats) = \
-                self._anakin_fn(
+            with phase("anakin.call"):
+                (policy.params, policy.opt_state, self._env_state,
+                 self._obs, self._rng, self._ep_rew, self._ep_len,
+                 stats) = self._anakin_fn(
                     policy.params, policy.opt_state, self._env_state,
                     self._obs, self._rng, self._ep_rew, self._ep_len)
-            stats = {k: float(v) for k, v in stats.items()}
+            with phase("anakin.readback"):
+                stats = {k: float(v) for k, v in stats.items()}
         policy._batch_on = len(self._obs.sharding.device_set)
         self._grad_time_total += time.perf_counter() - t0
         self._grad_calls += 1

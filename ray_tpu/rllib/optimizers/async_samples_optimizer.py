@@ -25,6 +25,7 @@ from typing import List
 
 import ray_tpu
 
+from ..._private.profiling import PhaseClock, phase, sum_snapshots
 from ..sample_batch import SampleBatch
 from ..utils.actors import TaskPool
 from ..utils.compression import decompress_batch
@@ -61,10 +62,15 @@ class LearnerThread(threading.Thread):
         self.learner_queue_size = WindowStat("learner_queue_size", 50)
         self.queue_timer = _Timer()
         self.grad_timer = _Timer()
+        # This thread's time by phase: learner.dequeue here, and
+        # learner.h2d / lock_wait / train / readback inside the policy's
+        # learn calls (the span of grad_timer).
+        self.clock = PhaseClock()
         self.daemon = True
         self._hbm_last = 0.0
 
     def run(self):
+        self.clock.bind()
         while not self.stopped:
             try:
                 self.step()
@@ -76,7 +82,7 @@ class LearnerThread(threading.Thread):
     def step(self):
         from ..._private import metrics as metrics_mod
         t0 = time.perf_counter()
-        with self.queue_timer:
+        with self.queue_timer, phase("learner.dequeue"):
             try:
                 batch = self.inqueue.get(timeout=0.5)
             except queue.Empty:
@@ -139,7 +145,6 @@ class InlineActorThread(threading.Thread):
         self.error = None  # first exception that killed the thread
         self.steps_sampled = 0  # monotonic; read without lock (int swap)
         self._gauge_last = None
-        self._gauge_t0 = time.perf_counter()
         # Pinned at construction: an actor orphaned by a failed stop()
         # must not fire occurrences into a controller some LATER
         # ray_tpu.init(chaos=...) installs — that would perturb the
@@ -165,12 +170,14 @@ class InlineActorThread(threading.Thread):
                     # sample must not ghost-write the aK gauges of a
                     # successor trainer's same-tag actor.
                     self._publish_pipeline_gauges()
-                while not self.stopped:
-                    try:
-                        self.learner.inqueue.put(batch, timeout=1.0)
-                        break
-                    except queue.Full:
-                        continue
+                # On the clock that sample() bound to this thread.
+                with phase("sebulba.enqueue"):
+                    while not self.stopped:
+                        try:
+                            self.learner.inqueue.put(batch, timeout=1.0)
+                            break
+                        except queue.Full:
+                            continue
         except Exception as e:  # noqa: BLE001 — surfaced to driver
             logger.exception("inline actor died")
             self.error = e
@@ -181,28 +188,28 @@ class InlineActorThread(threading.Thread):
         `scripts stat --metrics` / Prometheus), so a pipeline regression
         shows up live instead of only inside a 10 s bench window:
         `sebulba_action_fetch_pct.aK` (host blocked on the device
-        round-trip), `sebulba_env_step_pct.aK`, and
-        `sebulba_policy_lag_steps.aK` (mean selection lag)."""
+        round-trip), `sebulba_env_step_pct.aK` (both shares of the
+        actor thread's clock: phases `sebulba.fetch`, `sebulba.env_step`
+        over `wall_s`), and `sebulba_policy_lag_steps.aK` (mean
+        selection lag)."""
         if not hasattr(self.sampler, "transfer_stats"):
             return  # host-side VectorSampler: no device pipeline
-        now = time.perf_counter()
-        dt = now - self._gauge_t0
         stats = self.sampler.transfer_stats()
-        if self._gauge_last is not None and dt >= 0.5:
-            last = self._gauge_last
+        now, last = stats["phases"], self._gauge_last
+        dt = now["wall_s"] - last["phases"]["wall_s"] if last else 0.0
+        if last is not None and dt >= 0.5:
             from ..._private import metrics as metrics_mod
             tag = f"a{self.idx}"
             # Mean roll-up: the cluster series must stay a percentage
             # (4 actors at ~97% read ~97%, not the 387% a sum renders);
             # per-actor values stay attributable under per_node.
-            metrics_mod.set_gauge(
-                f"sebulba_action_fetch_pct.{tag}",
-                100.0 * (stats["t_fetch_s"] - last["t_fetch_s"]) / dt,
-                rollup="mean")
-            metrics_mod.set_gauge(
-                f"sebulba_env_step_pct.{tag}",
-                100.0 * (stats["t_env_s"] - last["t_env_s"]) / dt,
-                rollup="mean")
+            for gauge, name in (
+                    ("sebulba_action_fetch_pct", "sebulba.fetch"),
+                    ("sebulba_env_step_pct", "sebulba.env_step")):
+                spent = (now["seconds"].get(name, 0.0)
+                         - last["phases"]["seconds"].get(name, 0.0))
+                metrics_mod.set_gauge(
+                    f"{gauge}.{tag}", 100.0 * spent / dt, rollup="mean")
             dsteps = stats["steps"] - last["steps"]
             if dsteps > 0:
                 metrics_mod.set_gauge(
@@ -210,9 +217,8 @@ class InlineActorThread(threading.Thread):
                     (stats.get("policy_lag_sum", 0)
                      - last.get("policy_lag_sum", 0)) / dsteps,
                     rollup="mean")
-        if self._gauge_last is None or dt >= 0.5:
+        if last is None or dt >= 0.5:
             self._gauge_last = stats
-            self._gauge_t0 = now
 
     def stop(self):
         self.stopped = True
@@ -740,7 +746,10 @@ class AsyncSamplesOptimizer(PolicyOptimizer):
                     if hasattr(a.sampler, "transfer_stats")]
         if transfer:
             out["transfer"] = {
-                k: sum(t[k] for t in transfer) for k in transfer[0]}
+                k: sum(t[k] for t in transfer) for k in transfer[0]
+                if k != "phases"}
+            out["transfer"]["phases"] = sum_snapshots(
+                [t["phases"] for t in transfer])
         stragglers = self._update_stragglers()
         if stragglers:
             out["stragglers"] = stragglers

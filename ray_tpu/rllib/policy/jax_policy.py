@@ -36,6 +36,7 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
+from ..._private.profiling import phase
 from ...models import catalog
 from ...models.distributions import get_action_dist
 from ...parallel import collectives
@@ -248,6 +249,7 @@ class JaxPolicy(Policy):
 
     def _build_jitted_fns(self):
         if self.recurrent:
+            @jax.named_scope("policy/action")
             def action_fn(params, obs, state, rng, explore):
                 # One time step: [B] -> [B, 1].
                 obs_bt = obs[:, None]
@@ -273,6 +275,7 @@ class JaxPolicy(Policy):
 
             self._value_fn = jax.jit(value_fn)
         else:
+            @jax.named_scope("policy/action")
             def action_fn(params, obs, rng, explore):
                 dist_inputs, value = self.apply(params, obs)
                 dist = self.dist_class(dist_inputs)
@@ -295,6 +298,7 @@ class JaxPolicy(Policy):
         axis = self.layout.batch_axis
         ndev = int(self.mesh.shape[axis])
 
+        @jax.named_scope("train/loss")
         def local_loss_grad(params, batch, rng, loss_state):
             def lf(p):
                 if cdt != jnp.float32:
@@ -315,10 +319,11 @@ class JaxPolicy(Policy):
                     ef = jax.tree.map(lambda e: e[0], ef)
                     loss, stats, grads = local_loss_grad(
                         params, batch, rng, loss_state)
-                    grads, ef = collectives.pmean_quantized(
-                        grads, ef, axis, ndev)
-                    loss, stats = jax.lax.pmean(
-                        (loss, dict(stats)), axis)
+                    with jax.named_scope("train/allreduce"):
+                        grads, ef = collectives.pmean_quantized(
+                            grads, ef, axis, ndev)
+                        loss, stats = jax.lax.pmean(
+                            (loss, dict(stats)), axis)
                     return loss, stats, grads, jax.tree.map(
                         lambda e: e[None], ef)
                 # check_vma=False: the summed output IS replicated
@@ -337,14 +342,22 @@ class JaxPolicy(Policy):
 
         self._loss_grad = loss_grad
 
-        def train_fn(params, opt_state, ef, batch, rng, loss_state):
-            loss, stats, grads, ef = loss_grad(
-                params, batch, rng, loss_state, ef)
+        @jax.named_scope("train/update")
+        def apply_update(params, opt_state, grads, stats):
             updates, opt_state = self.optimizer.update(
                 grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             stats = dict(stats)
             stats["grad_gnorm"] = optax.global_norm(grads)
+            return params, opt_state, stats
+
+        self._apply_update = apply_update
+
+        def train_fn(params, opt_state, ef, batch, rng, loss_state):
+            loss, stats, grads, ef = loss_grad(
+                params, batch, rng, loss_state, ef)
+            params, opt_state, stats = apply_update(
+                params, opt_state, grads, stats)
             return params, opt_state, ef, stats
 
         self._train_fn = jax.jit(
@@ -498,17 +511,30 @@ class JaxPolicy(Policy):
                                         episode)
         return batch
 
-    def learn_on_batch(self, batch) -> Dict:
-        dev_batch = self._device_batch(batch)
-        with self._update_lock:
-            self.params, self.opt_state, self._ef_state, stats = \
-                self._train_fn(
+    def _locked_update(self, fn, dev_batch):
+        """One donated-buffer update program under `_update_lock`, as
+        phases of the calling (learner) thread: the wait for the lock,
+        then the dispatch."""
+        with phase("learner.lock_wait") as step:
+            self._update_lock.acquire()
+            try:
+                step.then("learner.train")
+                self.params, self.opt_state, self._ef_state, stats = fn(
                     self.params, self.opt_state, self._ef_state, dev_batch,
                     self._next_rng(), self.loss_state)
+            finally:
+                self._update_lock.release()
+        return stats
+
+    def learn_on_batch(self, batch) -> Dict:
+        with phase("learner.h2d"):
+            dev_batch = self._device_batch(batch)
+        stats = self._locked_update(self._train_fn, dev_batch)
         self._account_allreduce(1)
         self.global_timestep += batch.count if hasattr(batch, "count") \
             else len(next(iter(batch.values())))
-        return {k: float(v) for k, v in stats.items()}
+        with phase("learner.readback"):
+            return {k: float(v) for k, v in stats.items()}
 
     def sgd_learn(self, batch, num_sgd_iter: int, minibatch_size: int,
                   seq_len: int = 1) -> Dict:
@@ -545,19 +571,17 @@ class JaxPolicy(Policy):
                 batch = sliced
         elif usable != n:
             batch = batch.slice(0, usable)
-        dev_batch = self._device_batch(batch)
+        with phase("learner.h2d"):
+            dev_batch = self._device_batch(batch)
         key = (num_sgd_iter, num_mb, minibatch_size, seq_len)
         if key not in self._sgd_fns:
             self._sgd_fns[key] = self._make_sgd_fn(*key)
-        with self._update_lock:
-            self.params, self.opt_state, self._ef_state, stats = \
-                self._sgd_fns[key](
-                    self.params, self.opt_state, self._ef_state, dev_batch,
-                    self._next_rng(), self.loss_state)
+        stats = self._locked_update(self._sgd_fns[key], dev_batch)
         self._account_allreduce(num_sgd_iter * num_mb)
         from ..sample_batch import real_count
         self.global_timestep += real_count(batch)
-        return {k: float(v) for k, v in stats.items()}
+        with phase("learner.readback"):
+            return {k: float(v) for k, v in stats.items()}
 
     def _account_allreduce(self, n_updates: int) -> None:
         """Collective-plane accounting for `n_updates` gradient
@@ -606,11 +630,8 @@ class JaxPolicy(Policy):
                     params, opt_state, ef = carry
                     loss, stats, grads, ef = self._loss_grad(
                         params, mb, erng, loss_state, ef)
-                    updates, opt_state = self.optimizer.update(
-                        grads, opt_state, params)
-                    params = optax.apply_updates(params, updates)
-                    stats = dict(stats)
-                    stats["grad_gnorm"] = optax.global_norm(grads)
+                    params, opt_state, stats = self._apply_update(
+                        params, opt_state, grads, stats)
                     return (params, opt_state, ef), stats
 
                 (params, opt_state, ef), stats = jax.lax.scan(

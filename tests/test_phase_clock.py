@@ -1,0 +1,359 @@
+"""The program's own clock (`_private/profiling.phase`, `PhaseClock`) and
+the names it puts on its time (ISSUE 25):
+
+- phases of a bound thread partition its wall time, counts are exact, an
+  unbound thread gets the annotation only, nesting raises, `then` hands
+  the time from one name to the next inside one `with`;
+- every step of an inline actor's loop is a phase: after two fragments
+  each has run, phases + `other_s` is the thread's `wall_s`, and
+  `t_fetch_s` / `t_env_s` are views of the same clock;
+- every program carries its `jax.named_scope`s in its lowered op
+  metadata, so that no refactor drops one unnoticed.
+
+Every wait has its own timeout (there is no pytest-timeout here).
+"""
+
+import queue
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from ray_tpu._private import profiling
+from ray_tpu._private.profiling import PhaseClock, phase, sum_snapshots
+
+WAIT_S = 120.0
+
+
+def _in_thread(target):
+    """Run `target` on a fresh thread; its result, or its exception."""
+    box = {}
+
+    def body():
+        try:
+            box["out"] = target()
+        except BaseException as e:  # noqa: BLE001 - re-raised below
+            box["err"] = e
+
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    t.join(WAIT_S)
+    assert not t.is_alive(), "thread did not finish in time"
+    if "err" in box:
+        raise box["err"]
+    return box["out"]
+
+
+# ---------------------------------------------------------------------
+# the primitive
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("bound", [True, False])
+def test_phases_partition_a_threads_wall_time(bound):
+    rounds = 40
+
+    def loop():
+        clock = PhaseClock()
+        if bound:
+            clock.bind()
+        for _ in range(rounds):
+            with phase("test.a"):
+                time.sleep(0.005)
+            with phase("test.b"):
+                time.sleep(0.002)
+        return clock.snapshot()
+
+    snap = _in_thread(loop)
+    if not bound:
+        # Annotation only: nothing raised, nothing counted.
+        assert snap["seconds"] == {} and snap["counts"] == {}
+        return
+    assert snap["counts"] == {"test.a": rounds, "test.b": rounds}
+    covered = sum(snap["seconds"].values())
+    assert snap["seconds"]["test.a"] > snap["seconds"]["test.b"] > 0
+    assert abs(covered + snap["other_s"] - snap["wall_s"]) < 1e-9
+    assert 0 <= snap["other_s"] <= 0.02 * snap["wall_s"], snap
+
+
+def test_nested_phases_raise_and_leave_the_clock_usable():
+    def body():
+        clock = PhaseClock().bind()
+        with pytest.raises(RuntimeError, match="do not nest"):
+            with phase("test.outer"):
+                with phase("test.inner"):
+                    pass
+        with phase("test.outer"):
+            pass
+        return clock.snapshot()
+
+    snap = _in_thread(body)
+    assert snap["counts"] == {"test.outer": 2}
+
+
+def test_then_ends_one_phase_and_begins_the_next():
+    def body():
+        clock = PhaseClock().bind()
+        with phase("test.wait") as step:
+            time.sleep(0.004)
+            step.then("test.work")
+            during = clock.snapshot()
+            time.sleep(0.002)
+        with phase("test.unbound-free"):
+            pass
+        return during, clock.snapshot()
+
+    during, snap = _in_thread(body)
+    assert during["counts"] == {"test.wait": 1, "test.work": 0}
+    assert snap["counts"] == {"test.wait": 1, "test.work": 1,
+                              "test.unbound-free": 1}
+    assert snap["seconds"]["test.wait"] >= 0.004
+    assert snap["seconds"]["test.work"] >= 0.002
+    assert abs(sum(snap["seconds"].values()) + snap["other_s"]
+               - snap["wall_s"]) < 1e-9
+    # No clock bound: then() is the annotation's business only.
+    with phase("test.wait") as step:
+        step.then("test.work")
+
+
+def test_snapshot_counts_the_open_phase_and_sums_over_threads():
+    entered, release = threading.Event(), threading.Event()
+    clock = PhaseClock()
+
+    def blocked():
+        clock.bind()
+        with phase("test.blocked"):
+            entered.set()
+            assert release.wait(WAIT_S)
+
+    t = threading.Thread(target=blocked, daemon=True)
+    t.start()
+    try:
+        assert entered.wait(WAIT_S)
+        time.sleep(0.02)
+        during = clock.snapshot()
+    finally:
+        release.set()
+        t.join(WAIT_S)
+    assert not t.is_alive()
+    after = clock.snapshot()
+    # Blocked inside a phase is that phase's time, not `other`.
+    assert during["counts"] == {"test.blocked": 0}
+    assert during["seconds"]["test.blocked"] >= 0.02
+    assert after["counts"] == {"test.blocked": 1}
+    assert after["seconds"]["test.blocked"] >= during["seconds"][
+        "test.blocked"]
+    both = sum_snapshots([during, after])
+    assert both["counts"] == {"test.blocked": 1}
+    assert both["wall_s"] == during["wall_s"] + after["wall_s"]
+
+
+def test_importing_the_primitive_does_not_import_jax():
+    import subprocess
+    import sys
+    code = ("import sys; from ray_tpu._private.profiling import phase, "
+            "PhaseClock\nc = PhaseClock().bind()\n"
+            "with phase('x'): pass\n"
+            "assert c.snapshot()['counts'] == {'x': 1}\n"
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=WAIT_S)
+
+
+# ---------------------------------------------------------------------
+# the sites: an inline actor's loop
+# ---------------------------------------------------------------------
+ACTOR_PHASES = ("sebulba.fetch", "sebulba.record", "sebulba.env_step",
+                "sebulba.upload", "sebulba.apply", "sebulba.lock_wait",
+                "sebulba.select", "sebulba.pack", "sebulba.enqueue")
+
+
+def _sprite_sampler(delta: bool):
+    from ray_tpu.rllib.agents.pg.pg import DEFAULT_CONFIG, PGJaxPolicy
+    from ray_tpu.rllib.env.delta_obs import BatchedSpriteAtari
+    from ray_tpu.rllib.evaluation.device_sampler import DeviceSebulbaSampler
+    # Episodes shorter than a fragment: the delta path's full-row upload
+    # and scatter run too.
+    envs = [BatchedSpriteAtari(2, episode_len=3, seed=s) for s in (1, 2)]
+    cfg = dict(DEFAULT_CONFIG)
+    cfg.update({"model": {"fcnet_hiddens": [8],
+                          "conv_filters": ((4, 8, 4), (8, 4, 2))},
+                "seed": 0})
+    policy = PGJaxPolicy(envs[0].observation_space, envs[0].action_space,
+                         cfg)
+    sampler = DeviceSebulbaSampler(envs, policy, rollout_fragment_length=4,
+                                   use_delta=delta)
+    assert sampler.delta == delta
+    return sampler
+
+
+@pytest.mark.parametrize("delta", [True, False], ids=["delta", "frames"])
+def test_every_step_of_the_actor_loop_is_a_phase(delta):
+    from ray_tpu.rllib.optimizers.async_samples_optimizer import (
+        InlineActorThread)
+
+    class Learner:
+        inqueue = queue.Queue(maxsize=1)
+
+    sampler = _sprite_sampler(delta)
+    actor = InlineActorThread(sampler, Learner(), idx=0)
+    actor.start()
+    try:
+        for _ in range(2):
+            Learner.inqueue.get(timeout=WAIT_S)
+    finally:
+        actor.stop()
+        deadline = time.monotonic() + WAIT_S
+        while actor.is_alive() and time.monotonic() < deadline:
+            try:  # a fragment in flight may be waiting for room
+                Learner.inqueue.get(timeout=0.05)
+            except queue.Empty:
+                pass
+        actor.join(WAIT_S)
+    assert not actor.is_alive() and actor.error is None
+
+    stats = sampler.transfer_stats()
+    snap = stats["phases"]
+    for name in ACTOR_PHASES:
+        assert snap["counts"].get(name, 0) > 0, (name, snap["counts"])
+        assert snap["seconds"][name] > 0
+    assert set(snap["counts"]) == set(ACTOR_PHASES)
+    assert snap["other_s"] >= 0
+    assert abs(sum(snap["seconds"].values()) + snap["other_s"]
+               - snap["wall_s"]) < 1e-9
+    # The benchmark's two older readers see the same clock.
+    assert stats["t_fetch_s"] == round(snap["seconds"]["sebulba.fetch"], 3)
+    assert stats["t_env_s"] == round(snap["seconds"]["sebulba.env_step"], 3)
+    assert sampler.t_fetch == sampler.clock.seconds("sebulba.fetch")
+    assert stats["fetch_waits"] == snap["counts"]["sebulba.fetch"]
+
+
+# ---------------------------------------------------------------------
+# the names inside the programs
+# ---------------------------------------------------------------------
+REHEARSAL = {"num_workers": 0, "min_iter_time_s": 0, "seed": 0}
+
+
+def _lowered(fn, *args) -> str:
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+def _anakin_text():
+    from ray_tpu.rllib.agents.registry import get_trainer_class
+    t = get_trainer_class("IMPALA")(config=dict(
+        REHEARSAL, env="SyntheticAtari-v0", anakin=True,
+        num_envs_per_worker=8, rollout_fragment_length=4,
+        train_batch_size=32, anakin_updates_per_call=2))
+    try:
+        opt, pol = t.optimizer, t.optimizer.policy
+        return {"anakin_fn": _lowered(
+            opt._anakin_fn, pol.params, pol.opt_state, opt._env_state,
+            opt._obs, opt._rng, opt._ep_rew, opt._ep_len)}
+    finally:
+        t.stop()
+
+
+def _sebulba_texts():
+    from ray_tpu.rllib.agents.registry import get_trainer_class
+    t = get_trainer_class("IMPALA")(config=dict(
+        REHEARSAL, env="SyntheticAtariFrames-v0", num_inline_actors=1,
+        num_envs_per_worker=4, device_frame_stack=4, obs_delta=False,
+        rollout_fragment_length=4, train_batch_size=16))
+    t.stop()  # the actor and learner threads; the programs stay
+    sampler = t.optimizer._inline_actors[0].sampler
+    pol, g = sampler.policy, sampler.groups[0]
+    batch = pol._device_batch(sampler.sample())
+    rng = pol._next_rng()
+    return {
+        "train_fn": _lowered(pol._train_fn, pol.params, pol.opt_state,
+                             pol._ef_state, batch, rng, pol.loss_state),
+        "select_fn": _lowered(sampler._select_fn, pol.params, g.obs_next,
+                              rng, True),
+        "apply_frame": _lowered(sampler._apply_fn, g.stack, g.host_obs,
+                                g.host_done),
+        "action_fn": _lowered(pol._action_fn, pol.params, g.obs_next, rng,
+                              True),
+    }
+
+
+def _delta_and_q8_texts():
+    import __graft_entry__
+    from ray_tpu.parallel import mesh as mesh_lib
+    from ray_tpu.rllib.agents.ppo.ppo import DEFAULT_CONFIG, PPOJaxPolicy
+    from ray_tpu.rllib.env.spaces import Box, Discrete
+    from ray_tpu.rllib.evaluation.device_sampler import apply_full
+    sampler = _sprite_sampler(delta=True)
+    g = sampler.groups[0]
+    packed = np.zeros((g.n, 3 * int(g.env.delta_budget) + 1), np.uint8)
+    cfg = dict(DEFAULT_CONFIG)
+    cfg.update({"_mesh": mesh_lib.make_mesh(2),
+                "model": {"fcnet_hiddens": [16]}, "allreduce_codec": "q8"})
+    q8 = PPOJaxPolicy(
+        Box(low=-np.inf, high=np.inf, shape=(8,), dtype=np.float32),
+        Discrete(4), cfg)
+    assert q8.allreduce_codec == "q8"
+    batch = q8._device_batch(__graft_entry__._synthetic_ppo_batch(
+        32, (8,), 4))
+    return {
+        "apply_delta": _lowered(sampler._apply_fn, g.stack, g.frames_d,
+                                packed),
+        "apply_full": _lowered(
+            apply_full, g.frames_d, np.zeros(1, np.int32),
+            np.zeros((1, sampler._hw), np.uint8)),
+        "train_fn_q8": _lowered(q8._train_fn, q8.params, q8.opt_state,
+                                q8._ef_state, batch, q8._next_rng(),
+                                q8.loss_state),
+        "sgd_fn": _lowered(q8._make_sgd_fn(1, 2, 16), q8.params,
+                           q8.opt_state, q8._ef_state, batch,
+                           q8._next_rng(), q8.loss_state),
+    }
+
+
+PROGRAM_SCOPES = [
+    ("anakin_fn", "anakin/env_step"), ("anakin_fn", "anakin/inference"),
+    ("anakin_fn", "anakin/loss"), ("anakin_fn", "anakin/update"),
+    ("train_fn", "train/loss"), ("train_fn", "train/update"),
+    ("train_fn_q8", "train/allreduce"), ("sgd_fn", "train/loss"),
+    ("sgd_fn", "train/update"), ("action_fn", "policy/action"),
+    ("select_fn", "sebulba/select"), ("apply_frame", "sebulba/apply"),
+    ("apply_delta", "sebulba/apply"), ("apply_full", "sebulba/apply"),
+]
+
+
+@pytest.fixture(scope="module")
+def lowered_texts():
+    """Every program lowered once, on the CPU at rehearsal sizes."""
+    texts = {}
+    for build in (_anakin_text, _sebulba_texts, _delta_and_q8_texts):
+        texts.update(_in_thread(build))
+    return texts
+
+
+@pytest.mark.parametrize("program,scope", PROGRAM_SCOPES)
+def test_program_ops_carry_their_scope(lowered_texts, program, scope):
+    text = lowered_texts[program]
+    # The scope is in the op metadata (a `loc("...")` of the lowered
+    # text), not only in a function's name.
+    named = [line for line in text.splitlines()
+             if line.startswith("#loc") and scope + "/" in line]
+    assert named, f"{program}: no op under {scope}"
+
+
+def test_phase_annotation_is_the_profilers_own(monkeypatch):
+    """With jax loaded, a phase opens `TraceAnnotation("ray_tpu.<name>")`:
+    the span a profiler session records on the device trace's clock."""
+    import jax.profiler
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            seen.append(name)
+
+        def __enter__(self):
+            seen.append("enter")
+
+        def __exit__(self, *exc):
+            seen.append("exit")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with profiling.phase("sebulba.upload"):
+        seen.append("body")
+    assert seen == ["ray_tpu.sebulba.upload", "enter", "body", "exit"]

@@ -128,9 +128,19 @@ class TestGroupedByteIdentity:
         for r, (bs, bg) in enumerate(zip(self._sample_rounds(serial),
                                          self._sample_rounds(grouped))):
             for col in bs:
-                np.testing.assert_array_equal(
-                    bs[col], bg[col],
-                    err_msg=f"column {col} diverged at round {r}")
+                # Integer and boolean columns (actions, dones, eps ids,
+                # t, lag) are exact. Float columns agree to rounding: a
+                # 2-row and a 4-row forward of the same rows differ in
+                # the last bits (2.3e-9 absolute on the CPU), which is
+                # batch-size-dependent summation order, not the sampler.
+                if bs[col].dtype.kind in "iub":
+                    np.testing.assert_array_equal(
+                        bs[col], bg[col],
+                        err_msg=f"column {col} diverged at round {r}")
+                else:
+                    np.testing.assert_allclose(
+                        bs[col], bg[col], rtol=1e-5, atol=0,
+                        err_msg=f"column {col} diverged at round {r}")
                 assert bs[col].dtype == bg[col].dtype, col
         # Both runs crossed episode boundaries (the comparison above
         # covered reset handling, not just steady-state stepping).
