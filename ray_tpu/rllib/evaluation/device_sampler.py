@@ -14,12 +14,23 @@ staging layer is `rllib/optimizers/aso_multi_gpu_learner.py:140`
   batch, retained in HBM. One "select" program per WINDOW of k steps:
   model forward at the newest observation -> k sampled action arrays,
   fetched in a single [k, N] D2H copy (started async at dispatch).
-- Every per-step device observation is RETAINED; at fragment end the
-  train batch's OBS / BOOTSTRAP_OBS / ACTION_DIST_INPUTS / ACTION_LOGP /
-  VF_PREDS columns are assembled device-side (`jnp.stack`) and handed to
-  the learner as jax arrays — `JaxPolicy._device_batch` passes them
-  through without a host round-trip. Host->device traffic per timestep
-  drops to one frame (k x smaller again under `DeviceFrameStack`).
+- Every per-step device observation and every window's logp /
+  dist_inputs / value handle is RETAINED as the programs returned it; at
+  fragment end ONE compiled `pack` program takes the handles of all
+  groups and returns the train batch's OBS / BOOTSTRAP_OBS /
+  ACTION_DIST_INPUTS / ACTION_LOGP / VF_PREDS columns, laid out as the
+  learner's batch — `JaxPolicy._device_batch` passes them through
+  without a host round-trip. Host->device traffic per timestep drops to
+  one frame (k x smaller again under `DeviceFrameStack`).
+- THE ACTOR THREAD DISPATCHES COMPILED PROGRAMS ONLY (`apply_*`,
+  `apply_full`, `select_fn`, `pack`) plus `jax.device_put` of host
+  buffers: no `jnp` call, no index of a device array, no `jax.random`
+  call runs outside `jit` in `sample()`. An eager op is a Python-level
+  dispatch of a one-primitive program under the GIL; a dozen of them a
+  group-step were a third of an actor thread, and those under
+  `policy._update_lock` capped all actors together (PERF.md, PR 26).
+  The window's key is folded from `(policy._host_rng, counter)` inside
+  `select_fn`; the host only increments the counter.
 - DELTA MODE (round 5; see `env/delta_obs.py`): when the env supports
   the delta protocol, the device retains the current frame batch in HBM
   and the host uploads only changed pixels ([N, K] uint16 indices +
@@ -87,6 +98,42 @@ def apply_full(frames, rows, fulls):
     return frames.at[rows].set(fulls, mode="drop")
 
 
+@jax.named_scope("sebulba/pack")
+def pack(obs, logp, di, val, boot):
+    """A fragment's device columns from the handles an actor retained,
+    per group: `obs` T step observations [n, ...]; `logp` T/k window
+    arrays [k, n]; `di`, `val` T/k window arrays [n, A], [n] (a window's
+    k steps share them, so each counts k times); `boot` the observation
+    after the last step. Rows are env-major within a group ([n*T]: env 0's
+    T steps, then env 1's), group 0 first."""
+    k = logp[0][0].shape[0]
+
+    def rows(per_group):  # G x [n, T, ...] -> [G*n*T, ...]
+        a = jnp.concatenate(per_group)
+        return a.reshape((-1,) + a.shape[2:])
+
+    def windows(per_group):
+        return rows([jnp.repeat(jnp.stack(w, axis=1), k, axis=1)
+                     for w in per_group])
+
+    return {
+        sb.OBS: rows([jnp.stack(steps, axis=1) for steps in obs]),
+        sb.ACTION_LOGP: rows([jnp.concatenate(w).T for w in logp]),
+        sb.ACTION_DIST_INPUTS: windows(di),
+        sb.VF_PREDS: windows(val),
+        sb.BOOTSTRAP_OBS: jnp.concatenate(boot),
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def pack_program(sharding):
+    """`pack` jitted with every column laid out as the learner's batch
+    (`sharding` = `policy._bsharded`, so `_device_batch` has nothing to
+    move). One function per mesh, shared by every sampler: jit keeps a
+    program per fragment shape."""
+    return jax.jit(pack, out_shardings=sharding)
+
+
 def _full_bucket(count: int, n: int) -> int:
     """Rows of the full-row scatter that takes `count` of `n` rows: the
     next power of two, at most n."""
@@ -112,7 +159,8 @@ class _EnvGroup:
         self.host_done = np.ones(n, bool)
         # Dispatched select outputs: (actions[k,n], logp[k,n], di, val).
         self.pending = None
-        # Fetched window caches consumed sub-step by sub-step.
+        # The fetched window: its actions are consumed sub-step by
+        # sub-step; the device handles are retained whole for the pack.
         self.win_actions = None  # host [k, n]
         self.win_logp = None     # device [k, n]
         self.win_di = None       # device [n, A]
@@ -212,6 +260,8 @@ class DeviceSebulbaSampler:
         for g in self.groups:
             self._dispatch_apply(g)
             self._dispatch_select(g)
+        self._pack_fn = pack_program(policy._bsharded)
+        self._warm_pack()
 
     # ------------------------------------------------------------------
     def _build_fns(self):
@@ -266,12 +316,15 @@ class DeviceSebulbaSampler:
             self._apply_fn = jax.jit(apply_frame)
 
         @jax.named_scope("sebulba/select")
-        def select_fn(params, obs, rng, explore):
+        def select_fn(params, obs, base, counter, explore):
             """Model forward at the newest obs, then k sampled action
             arrays. All k actions of a window are selected from THIS
             observation's distribution — sub-step j executes with lag j,
             and these dist_inputs/logp are the true behavior policy that
-            V-trace corrects against."""
+            V-trace corrects against. The window's key is derived here,
+            `fold_in(base, counter)` as `policy._next_rng()` derives it,
+            so the caller hands over a host integer and no eager op."""
+            rng = jax.random.fold_in(base, counter)
             dist_inputs, value = policy.apply(params, obs)
             dist = policy.dist_class(dist_inputs)
             if k == 1:
@@ -316,6 +369,19 @@ class DeviceSebulbaSampler:
                 jax.device_put(np.full(b, g.n, np.int32), policy._repl),
                 jax.device_put(np.zeros((b, self._hw), np.uint8),
                                policy._repl))
+
+    def _warm_pack(self):
+        """Run the fragment's pack once on the primed handles, which have
+        the shapes and shardings of every later step's and window's: the
+        first fragment would otherwise compile it in the middle of a
+        run."""
+        steps, wins = self.T, self.T // self.k
+        jax.block_until_ready(self._pack_fn(
+            [[g.obs_next] * steps for g in self.groups],
+            [[g.pending[1]] * wins for g in self.groups],
+            [[g.pending[2]] * wins for g in self.groups],
+            [[g.pending[3]] * wins for g in self.groups],
+            [g.obs_next for g in self.groups]))
 
     # ------------------------------------------------------------------
     def _dispatch_apply(self, g: _EnvGroup):
@@ -373,14 +439,16 @@ class DeviceSebulbaSampler:
     def _dispatch_select(self, g: _EnvGroup):
         """Dispatch the selection window for the group's newest obs and
         start the D2H action copy so the eventual fetch is a cache hit.
-        Reads live params — serialized against learner updates."""
+        Reads live params — serialized against learner updates: the lock
+        covers that read and the one call, nothing else."""
         policy = self.policy
         with phase("sebulba.lock_wait") as step:
+            counter = policy._next_rng_counter()
             policy._update_lock.acquire()
             try:
                 step.then("sebulba.select")
                 out = self._select_fn(
-                    policy.params, g.obs_next, policy._next_rng(),
+                    policy.params, g.obs_next, policy._host_rng, counter,
                     self.explore)
             finally:
                 policy._update_lock.release()
@@ -422,10 +490,13 @@ class DeviceSebulbaSampler:
                     # the double-buffering that hides the round-trip.
                     self._consume_window(g)
                 with phase("sebulba.record"):
+                    # Handles as they are: nothing here touches the
+                    # device, `pack` takes the windows whole.
                     obs_buf[gi].append(g.obs_next)
-                    logp_buf[gi].append(g.win_logp[jw])
-                    di_buf[gi].append(g.win_di)
-                    vf_buf[gi].append(g.win_val)
+                    if jw == 0:
+                        logp_buf[gi].append(g.win_logp)
+                        di_buf[gi].append(g.win_di)
+                        vf_buf[gi].append(g.win_val)
                     actions = g.win_actions[jw]
                 with phase("sebulba.env_step"):
                     if self.delta:
@@ -476,17 +547,9 @@ class DeviceSebulbaSampler:
 
             # Each group's obs_next is the post-fragment bootstrap
             # observation AND step 0 of the next fragment — computed once.
-            boot_obs = (self.groups[0].obs_next if G == 1 else
-                        jnp.concatenate(
-                            [g.obs_next for g in self.groups], axis=0))
-
-            def dpack(gbufs):
-                parts = []
-                for g, bufs in zip(self.groups, gbufs):
-                    a = jnp.stack(bufs)  # [T, n, ...]
-                    parts.append(jnp.swapaxes(a, 0, 1).reshape(
-                        (g.n * T,) + a.shape[2:]))
-                return parts[0] if G == 1 else jnp.concatenate(parts, axis=0)
+            device_columns = self._pack_fn(
+                obs_buf, logp_buf, di_buf, vf_buf,
+                [g.obs_next for g in self.groups])
 
             def hpack(gbufs):
                 parts = []
@@ -501,11 +564,7 @@ class DeviceSebulbaSampler:
                     [np.swapaxes(a, 0, 1).reshape(-1) for a in arrs])
 
             return SampleBatch({
-                sb.OBS: dpack(obs_buf),
-                sb.ACTION_LOGP: dpack(logp_buf),
-                sb.ACTION_DIST_INPUTS: dpack(di_buf),
-                sb.VF_PREDS: dpack(vf_buf),
-                sb.BOOTSTRAP_OBS: boot_obs,
+                **device_columns,
                 sb.ACTIONS: hpack(act_host),
                 sb.REWARDS: hpack(rew_buf),
                 sb.DONES: hpack(done_buf),

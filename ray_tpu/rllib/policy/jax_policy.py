@@ -26,6 +26,7 @@ for XLA:
 from __future__ import annotations
 
 import functools
+import itertools
 import logging
 import threading
 from typing import Callable, Dict, Optional
@@ -108,7 +109,9 @@ class JaxPolicy(Policy):
 
         seed = seed if seed is not None else config.get("seed") or 0
         self._host_rng = jax.random.PRNGKey(seed)
-        self._rng_counter = 0
+        # `next()` of it is one C call, so atomic among threads without a
+        # lock of its own.
+        self._rng_counter = itertools.count(1)
 
         model_cfg = dict(catalog.MODEL_DEFAULTS)
         model_cfg.update(config.get("model") or {})
@@ -243,9 +246,14 @@ class JaxPolicy(Policy):
         return [np.zeros((batch_size, self.cell_size), np.float32),
                 np.zeros((batch_size, self.cell_size), np.float32)]
 
+    def _next_rng_counter(self) -> np.uint32:
+        """The next value of the key counter. A program that folds it into
+        `_host_rng` itself (`fold_in(base, counter)`) draws the key
+        `_next_rng()` would have returned, without an eager op."""
+        return np.uint32(next(self._rng_counter))
+
     def _next_rng(self):
-        self._rng_counter += 1
-        return jax.random.fold_in(self._host_rng, self._rng_counter)
+        return jax.random.fold_in(self._host_rng, self._next_rng_counter())
 
     def _build_jitted_fns(self):
         if self.recurrent:

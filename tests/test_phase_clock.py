@@ -7,6 +7,10 @@ the names it puts on its time (ISSUE 25):
 - every step of an inline actor's loop is a phase: after two fragments
   each has run, phases + `other_s` is the thread's `wall_s`, and
   `t_fetch_s` / `t_env_s` are views of the same clock;
+- an inline actor's thread dispatches compiled programs only (ISSUE 26):
+  a profiler session over two `sample()` calls sees its four programs
+  and no eager op, nothing is lowered, and what `record` retains are the
+  select program's own window handles;
 - every program carries its `jax.named_scope`s in its lowered op
   metadata, so that no refactor drops one unnoticed.
 
@@ -166,7 +170,7 @@ ACTOR_PHASES = ("sebulba.fetch", "sebulba.record", "sebulba.env_step",
                 "sebulba.select", "sebulba.pack", "sebulba.enqueue")
 
 
-def _sprite_sampler(delta: bool):
+def _sprite_sampler(delta: bool, onchip_steps: int = 1):
     from ray_tpu.rllib.agents.pg.pg import DEFAULT_CONFIG, PGJaxPolicy
     from ray_tpu.rllib.env.delta_obs import BatchedSpriteAtari
     from ray_tpu.rllib.evaluation.device_sampler import DeviceSebulbaSampler
@@ -180,7 +184,8 @@ def _sprite_sampler(delta: bool):
     policy = PGJaxPolicy(envs[0].observation_space, envs[0].action_space,
                          cfg)
     sampler = DeviceSebulbaSampler(envs, policy, rollout_fragment_length=4,
-                                   use_delta=delta)
+                                   use_delta=delta,
+                                   onchip_steps=onchip_steps)
     assert sampler.delta == delta
     return sampler
 
@@ -227,6 +232,96 @@ def test_every_step_of_the_actor_loop_is_a_phase(delta):
 
 
 # ---------------------------------------------------------------------
+# the rule of the actor loop: compiled programs only
+# ---------------------------------------------------------------------
+SAMPLER_PROGRAMS = {"apply_delta", "apply_frame", "apply_full", "select_fn",
+                    "pack"}
+LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+def _programs_dispatched(body, tmp_path) -> dict:
+    """Run `body` under a profiler session; the jitted functions whose
+    call the host trace holds, by name, with their counts. An op issued
+    outside `jit` is one too: jax runs it as a one-primitive program
+    named after the primitive (`dynamic_slice`, `_threefry_fold_in`)."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    calls = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("PjitFunction("):
+                    name = event.name[len("PjitFunction("):-1]
+                    calls[name] = calls.get(name, 0) + 1
+    return calls
+
+
+def test_the_profiler_sees_an_eager_op(tmp_path):
+    """The detector of the next test, shown to detect: one index of a
+    device array outside `jit` is a program in the host trace."""
+    import jax.numpy as jnp
+    x = jnp.arange(6.0).reshape(2, 3)
+    calls = _programs_dispatched(lambda: x[1].block_until_ready(),
+                                 tmp_path)
+    assert calls and not set(calls) & SAMPLER_PROGRAMS, calls
+
+
+@pytest.mark.parametrize("delta,k", [(True, 1), (False, 1), (True, 2)],
+                         ids=["delta", "frames", "delta-k2"])
+def test_the_actor_thread_dispatches_compiled_programs_only(
+        delta, k, tmp_path):
+    import jax.monitoring
+    sampler = _sprite_sampler(delta, onchip_steps=k)
+    lowered = []
+
+    def on_event(event, duration, **kw):
+        if event == LOWERED:
+            lowered.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        calls = _programs_dispatched(
+            lambda: (sampler.sample(), sampler.sample()), tmp_path)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert not lowered, "a program was lowered after the constructor"
+    apply_fn = "apply_delta" if delta else "apply_frame"
+    assert {apply_fn, "select_fn", "pack"} <= set(calls), calls
+    assert set(calls) <= SAMPLER_PROGRAMS, (
+        f"eager ops on the actor thread: "
+        f"{sorted(set(calls) - SAMPLER_PROGRAMS)}")
+
+
+@pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+def test_record_retains_the_window_handles_themselves(k):
+    sampler = _sprite_sampler(delta=True, onchip_steps=k)
+    windows, packed = [], []
+    consume, pack_fn = sampler._consume_window, sampler._pack_fn
+    sampler._consume_window = (
+        lambda g: windows.append((g, g.pending)) or consume(g))
+    sampler._pack_fn = lambda *a: packed.append(a) or pack_fn(*a)
+    sampler.sample()
+    (obs, logp, di, val, boot), = packed
+    for gi, g in enumerate(sampler.groups):
+        mine = [pending for group, pending in windows if group is g]
+        assert len(mine) == sampler.T // k == len(logp[gi])
+        assert len(obs[gi]) == sampler.T
+        for w, (_, logp_d, di_d, val_d) in enumerate(mine):
+            assert logp[gi][w] is logp_d
+            assert di[gi][w] is di_d
+            assert val[gi][w] is val_d
+        assert boot[gi] is g.obs_next
+
+
+# ---------------------------------------------------------------------
 # the names inside the programs
 # ---------------------------------------------------------------------
 REHEARSAL = {"num_workers": 0, "min_iter_time_s": 0, "seed": 0}
@@ -266,11 +361,13 @@ def _sebulba_texts():
         "train_fn": _lowered(pol._train_fn, pol.params, pol.opt_state,
                              pol._ef_state, batch, rng, pol.loss_state),
         "select_fn": _lowered(sampler._select_fn, pol.params, g.obs_next,
-                              rng, True),
+                              pol._host_rng, pol._next_rng_counter(), True),
         "apply_frame": _lowered(sampler._apply_fn, g.stack, g.host_obs,
                                 g.host_done),
         "action_fn": _lowered(pol._action_fn, pol.params, g.obs_next, rng,
                               True),
+        "pack": _lowered(sampler._pack_fn, [[g.obs_next]], [[g.pending[1]]],
+                         [[g.pending[2]]], [[g.pending[3]]], [g.obs_next]),
     }
 
 
@@ -315,6 +412,7 @@ PROGRAM_SCOPES = [
     ("sgd_fn", "train/update"), ("action_fn", "policy/action"),
     ("select_fn", "sebulba/select"), ("apply_frame", "sebulba/apply"),
     ("apply_delta", "sebulba/apply"), ("apply_full", "sebulba/apply"),
+    ("pack", "sebulba/pack"),
 ]
 
 

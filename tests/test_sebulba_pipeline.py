@@ -11,8 +11,14 @@
 - Tier-1 smoke: the transfer-accounting dict carries the lag fields and
   per-actor action-fetch time never exceeds wall-clock, so the
   accounting can't silently rot.
+- Compiled programs only (ISSUE 26): the fragment's one `pack` program
+  is byte-identical to the numpy expression the host columns use;
+  `select_fn` derives the key `_next_rng()` would have drawn from
+  `(base, counter)`; counters taken by concurrent threads never collide.
 """
 
+import sys
+import threading
 import time
 
 import numpy as np
@@ -255,6 +261,165 @@ class TestOnChipSelection:
         np.testing.assert_array_equal(
             boot[:, :, :, -1].reshape(2 * N_PER, -1), canon)
         assert batch.count == 2 * N_PER * T
+
+
+# ---------------------------------------------------------------------
+# Compiled programs only: the pack, the key, the counter
+# ---------------------------------------------------------------------
+def _sprite_policy(env, seed=0, mesh=None):
+    from ray_tpu.rllib.agents.pg.pg import DEFAULT_CONFIG, PGJaxPolicy
+    cfg = dict(DEFAULT_CONFIG)
+    cfg.update({"model": {"fcnet_hiddens": [8],
+                          "conv_filters": ((4, 8, 4), (8, 4, 2))},
+                "seed": seed})
+    if mesh is not None:
+        cfg["_mesh"] = mesh
+    return PGJaxPolicy(env.observation_space, env.action_space, cfg)
+
+
+def _sprite_envs(groups: int, stack: int, n: int = 2):
+    """Episodes shorter than a fragment, so reset rows are packed too."""
+    from ray_tpu.rllib.env.delta_obs import BatchedSpriteAtari
+    from ray_tpu.rllib.env.device_frame_stack import DeviceFrameStack
+    envs = [BatchedSpriteAtari(n, episode_len=3, seed=7 + g)
+            for g in range(groups)]
+    return [DeviceFrameStack(e, stack) for e in envs] if stack else envs
+
+
+def _numpy_pack(gbufs):
+    """The expression `sample()` uses for its host columns: per group
+    `np.stack` of T per-step arrays [n, ...] -> env-major [n*T, ...]
+    rows, groups concatenated in order."""
+    parts = []
+    for bufs in gbufs:
+        a = np.stack(bufs)
+        parts.append(np.swapaxes(a, 0, 1).reshape(
+            (a.shape[0] * a.shape[1],) + a.shape[2:]))
+    return parts[0] if len(parts) == 1 else np.concatenate(parts, axis=0)
+
+
+class TestCompiledPack:
+    @pytest.mark.parametrize("stack", [0, 4], ids=["stack0", "stack4"])
+    @pytest.mark.parametrize("delta", [False, True],
+                             ids=["frames", "delta"])
+    @pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+    @pytest.mark.parametrize("groups", [1, 2], ids=["G1", "G2"])
+    def test_pack_is_byte_identical_to_numpy(self, groups, k, delta,
+                                             stack):
+        self._check(groups, k, delta, stack, mesh=None)
+
+    def test_pack_over_a_mesh_is_byte_identical_to_numpy(self):
+        """Two devices: a group's rows are sharded over the mesh going
+        in, the fragment's rows (group 0 first) coming out."""
+        from ray_tpu.parallel import mesh as mesh_lib
+        self._check(2, 2, True, 4, mesh=mesh_lib.make_mesh(2))
+
+    def _check(self, groups, k, delta, stack, mesh):
+        T = 4
+        envs = _sprite_envs(groups, stack)
+        sampler = DeviceSebulbaSampler(
+            envs, _sprite_policy(envs[0], mesh=mesh),
+            rollout_fragment_length=T, use_delta=delta, onchip_steps=k)
+        assert sampler.delta == delta and sampler.frame_stack == stack
+        taken = []
+        pack_fn = sampler._pack_fn
+        sampler._pack_fn = lambda *a: taken.append(a) or pack_fn(*a)
+        batch = sampler.sample()
+        (obs, logp, di, val, boot), = taken
+        host = lambda handles: [np.asarray(h) for h in handles]
+        # Per-step lists, as the parent's appends built them: step t of
+        # window w has logp[w][t % k] and the window's one di / value.
+        want = {
+            sb.OBS: _numpy_pack([host(g) for g in obs]),
+            sb.ACTION_LOGP: _numpy_pack(
+                [[w[j] for w in host(g) for j in range(k)] for g in logp]),
+            sb.ACTION_DIST_INPUTS: _numpy_pack(
+                [[w for w in host(g) for _ in range(k)] for g in di]),
+            sb.VF_PREDS: _numpy_pack(
+                [[w for w in host(g) for _ in range(k)] for g in val]),
+            sb.BOOTSTRAP_OBS: np.concatenate(host(boot), axis=0),
+        }
+        n = envs[0].num_envs
+        assert want[sb.OBS].shape == (
+            groups * n * T,) + envs[0].observation_space.shape
+        for col, ref in want.items():
+            got = np.asarray(batch[col])
+            assert got.dtype == ref.dtype and got.shape == ref.shape, col
+            assert got.tobytes() == ref.tobytes(), col
+            assert batch[col].sharding == sampler.policy._bsharded, col
+        if mesh is not None:
+            assert len(batch[sb.OBS].sharding.device_set) == mesh.size
+
+
+class TestKeyFromCounter:
+    @pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+    def test_select_draws_the_key_next_rng_would(self, k):
+        """`select_fn(.., base, counter, explore=True)` against the
+        policy's own action program given `fold_in(base, counter)`, the
+        key the parent passed in: same actions, logp, dist inputs and
+        value, bit for bit."""
+        import jax
+        envs = _sprite_envs(1, 0)
+        policy = _sprite_policy(envs[0], seed=3)
+        sampler = DeviceSebulbaSampler(
+            envs, policy, rollout_fragment_length=2 * k, explore=True,
+            onchip_steps=k)
+        obs = sampler.groups[0].obs_next
+        base = policy._host_rng
+        seen = set()
+        for counter in (5, 6, 2 ** 31 + 11):
+            acts, logp, di, val = sampler._select_fn(
+                policy.params, obs, base, np.uint32(counter), True)
+            key = jax.random.fold_in(base, counter)
+            keys = [key] if k == 1 else list(jax.random.split(key, k))
+            assert acts.shape == (k, envs[0].num_envs)
+            for j, kj in enumerate(keys):
+                a, lp, d, v = policy._action_fn(
+                    policy.params, obs, kj, True)
+                for got, ref in ((acts[j], a), (logp[j], lp), (di, d),
+                                 (val, v)):
+                    got, ref = np.asarray(got), np.asarray(ref)
+                    assert got.dtype == ref.dtype
+                    assert got.tobytes() == ref.tobytes()
+            seen.add(np.asarray(acts).tobytes())
+        assert len(seen) > 1, "every counter sampled the same actions"
+
+    def test_next_rng_folds_the_same_counter(self):
+        import jax
+        policy = _sprite_policy(_sprite_envs(1, 0)[0])
+        at = int(policy._next_rng_counter())
+        np.testing.assert_array_equal(
+            np.asarray(policy._next_rng()),
+            np.asarray(jax.random.fold_in(policy._host_rng, at + 1)))
+        assert int(policy._next_rng_counter()) == at + 2
+
+    def test_concurrent_counters_are_distinct_and_gap_free(self):
+        policy = _sprite_policy(_sprite_envs(1, 0)[0])
+        first = int(policy._next_rng_counter()) + 1
+        threads, each = 4, 5000
+        got = [[] for _ in range(threads)]
+        start = threading.Barrier(threads)
+
+        def take(out):
+            start.wait(timeout=60)
+            for _ in range(each):
+                out.append(int(policy._next_rng_counter()))
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            workers = [threading.Thread(target=take, args=(g,), daemon=True)
+                       for g in got]
+            for w in workers:
+                w.start()
+            for w in workers:
+                w.join(60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(w.is_alive() for w in workers)
+        assert all(len(g) == each for g in got)
+        assert sorted(c for g in got for c in g) == list(
+            range(first, first + threads * each))
 
 
 # ---------------------------------------------------------------------
