@@ -29,7 +29,20 @@ MODEL_DEFAULTS = {
     # the default f32 each network keeps its own default (the Vision
     # trunk stays bf16 for the MXU); "bf16"/"f32" force it everywhere.
     "compute_dtype": "auto",
+    # A model family by name (`CUSTOM_MODELS`) with its own settings;
+    # overrides the choice by observation space.
+    "custom_model": None,
+    "custom_model_config": {},
 }
+
+
+def _olmoe(obs_space, num_outputs, cfg, dtype):
+    from .transformer import olmoe_from_config
+    return olmoe_from_config(num_outputs, cfg, dtype)
+
+
+# name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
+CUSTOM_MODELS = {"olmoe": _olmoe}
 
 
 def _resolve_compute_dtype(cfg):
@@ -97,6 +110,14 @@ def get_model(obs_space, num_outputs: int, model_config: dict = None):
     """
     cfg = dict(MODEL_DEFAULTS)
     cfg.update(model_config or {})
+    if cfg["custom_model"]:
+        if cfg["custom_model"] not in CUSTOM_MODELS:
+            raise ValueError(
+                f"unknown custom_model {cfg['custom_model']!r}; known: "
+                f"{sorted(CUSTOM_MODELS)}")
+        return CUSTOM_MODELS[cfg["custom_model"]](
+            obs_space, num_outputs, dict(cfg["custom_model_config"] or {}),
+            _resolve_compute_dtype(cfg))
     if cfg["use_lstm"]:
         # Recurrent trunk: JaxPolicy drives it through the recurrent path
         # (state threading in the sampler + sequence-major training,

@@ -27,6 +27,8 @@ def make_async_optimizer(workers, config):
             num_envs=config["_anakin_num_envs"],
             rollout_fragment_length=config["rollout_fragment_length"],
             updates_per_call=config.get("anakin_updates_per_call", 10),
+            sgd_minibatch_size=config.get("sgd_minibatch_size", 0),
+            num_sgd_iter=config.get("num_sgd_iter", 1),
             seed=config.get("seed") or 0)
     return AsyncSamplesOptimizer(
         workers,
@@ -107,10 +109,6 @@ def validate_config(config):
             raise ValueError(
                 "anakin mode is fully device-resident; num_workers must "
                 "be 0 (env slots come from num_envs_per_worker)")
-        if (config.get("model") or {}).get("use_lstm"):
-            raise ValueError(
-                "anakin mode currently supports feedforward policies "
-                "only; use the inline-actor (Sebulba) path for LSTM")
         # The device-resident env slots are the optimizer's; the local
         # RolloutWorker keeps a single probe env (spaces only).
         config["_anakin_num_envs"] = config.get("num_envs_per_worker", 1)
@@ -124,10 +122,9 @@ def validate_config(config):
                 "overriding train_batch_size=%s",
                 effective, config.get("train_batch_size"))
         config["train_batch_size"] = effective
-    if (config.get("model") or {}).get("use_lstm"):
-        # Recurrent IMPALA trains on the packed fragments themselves:
-        # one fragment = one LSTM sequence.
-        config["_train_seq_len"] = config["rollout_fragment_length"]
+    # A stateful policy (LSTM, transformer) trains on the packed fragments
+    # themselves: one fragment = one sequence.
+    config["_train_seq_len"] = config["rollout_fragment_length"]
     if config["train_batch_size"] % config["rollout_fragment_length"] != 0:
         raise ValueError(
             "train_batch_size must be a multiple of "

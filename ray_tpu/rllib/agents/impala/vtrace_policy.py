@@ -92,6 +92,11 @@ def _time_major(x, seq_len: int):
 
 
 def forward_with_bootstrap(policy, params, batch, T: int):
+    """`forward_counted` without the model's counters."""
+    return forward_counted(policy, params, batch, T)[:3]
+
+
+def forward_counted(policy, params, batch, T: int):
     """Model forward over a packed [B*T] fragment batch plus the
     per-fragment bootstrap value.
 
@@ -99,10 +104,12 @@ def forward_with_bootstrap(policy, params, batch, T: int):
     [B, ...] (VectorSampler / Anakin batches), or a full per-row NEW_OBS
     column whose last row per fragment is the bootstrap observation
     (remote-worker pack mode). Returns (dist_inputs[B*T, O],
-    values[B*T], bootstrap_value[B]).
+    values[B*T], bootstrap_value[B], what the model counted in the pass).
     """
+    counters = {}
     if policy.recurrent:
-        dist_bt, val_bt, carry = policy.apply_sequences(params, batch)
+        (dist_bt, val_bt, carry), counters = policy.apply_sequences(
+            params, batch)
         dist_inputs = dist_bt.reshape(-1, dist_bt.shape[-1])
         values_flat = val_bt.reshape(-1)
         B = batch[sb.OBS].shape[0] // T
@@ -126,7 +133,7 @@ def forward_with_bootstrap(policy, params, batch, T: int):
         else:
             boot_obs = _time_major(batch[sb.NEW_OBS], T)[-1]
         _, bootstrap_value = policy.apply(params, boot_obs)
-    return dist_inputs, values_flat, bootstrap_value
+    return dist_inputs, values_flat, bootstrap_value, counters
 
 
 def vtrace_loss(policy, params, batch, rng, loss_state):
@@ -134,26 +141,38 @@ def vtrace_loss(policy, params, batch, rng, loss_state):
     T = cfg["rollout_fragment_length"]
     gamma = cfg["gamma"]
 
-    dist_inputs, values_flat, bootstrap_value = forward_with_bootstrap(
+    dist_inputs, values_flat, bootstrap_value, counters = forward_counted(
         policy, params, batch, T)
 
-    behaviour_logits = _time_major(batch[sb.ACTION_DIST_INPUTS], T)
-    target_logits = _time_major(dist_inputs, T)
-    actions = _time_major(batch[sb.ACTIONS], T)
+    # Time-major [T, B] log-probabilities of the taken actions and the
+    # target policy's entropy. Logits narrow enough for the trajectory to
+    # keep are laid out time-major whole: that undoes the transpose the
+    # batch was packed with, so XLA moves nothing. Logits a vocabulary wide
+    # are reduced row by row first and only the scalars a step are moved;
+    # their behaviour policy left the taken action's log-probability.
+    actions = batch[sb.ACTIONS]
+    if sb.ACTION_DIST_INPUTS in batch:
+        actions = _time_major(actions, T)
+        target_dist = policy.dist_class(_time_major(dist_inputs, T))
+        target_logp = target_dist.logp(actions)
+        behaviour_logp = policy.dist_class(_time_major(
+            batch[sb.ACTION_DIST_INPUTS], T)).logp(actions)
+    else:
+        target_dist = policy.dist_class(dist_inputs)
+        target_logp = _time_major(target_dist.logp(actions), T)
+        behaviour_logp = _time_major(batch[sb.ACTION_LOGP], T)
+    log_rhos = target_logp - behaviour_logp
     rewards = _time_major(batch[sb.REWARDS], T)
     dones = _time_major(batch[sb.DONES], T)
     values = _time_major(values_flat, T)
     discounts = gamma * (1.0 - dones)
 
-    returns, log_rhos, target_logp = vtrace.from_logits(
-        behaviour_policy_logits=behaviour_logits,
-        target_policy_logits=target_logits,
-        actions=actions,
+    returns = vtrace.from_importance_weights(
+        log_rhos=log_rhos,
         discounts=discounts,
         rewards=rewards,
         values=values,
         bootstrap_value=bootstrap_value,
-        dist_class=policy.dist_class,
         clip_rho_threshold=cfg["vtrace_clip_rho_threshold"],
         clip_pg_rho_threshold=cfg["vtrace_clip_pg_rho_threshold"],
         lambda_=cfg["lambda"])
@@ -163,12 +182,13 @@ def vtrace_loss(policy, params, batch, rng, loss_state):
     pi_loss = -jnp.sum(target_logp * pg_advantages)
     delta = values - vs
     vf_loss = 0.5 * jnp.sum(delta ** 2)
-    entropy = jnp.sum(policy.dist_class(target_logits).entropy())
+    entropy = jnp.sum(target_dist.entropy())
 
     total = (pi_loss
              + cfg["vf_loss_coeff"] * vf_loss
              - cfg["entropy_coeff"] * entropy)
     n = values_flat.shape[0]
+    rhos = jnp.exp(log_rhos)
     stats = {
         "total_loss": total,
         "policy_loss": pi_loss / n,
@@ -176,6 +196,11 @@ def vtrace_loss(policy, params, batch, rng, loss_state):
         "entropy": entropy / n,
         "mean_kl_behaviour": jnp.mean(-log_rhos),
         "vtrace_mean_vs": jnp.mean(vs),
+        # The importance ratios V-trace corrects by: 1 wherever the batch
+        # is on-policy, off it from the second minibatch of a rollout on.
+        "is_ratio_mean": jnp.mean(rhos),
+        "is_ratio_max": jnp.max(rhos),
+        **counters,
     }
     return total, stats
 

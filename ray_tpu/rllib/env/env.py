@@ -182,6 +182,46 @@ class SyntheticAtari(Env):
         return self._frame(), reward, self._t >= self.episode_len, {}
 
 
+def init_token_bigram(obj, vocab_size: int, episode_len: int, seed: int):
+    """Shared TokenBigram parameters + spaces (host and JAX variants): the
+    seeded rule `(a * token + b) mod vocab_size`, a scalar int32 token
+    observation, one action a token of the vocabulary."""
+    obj.vocab_size = vocab_size
+    obj.episode_len = episode_len
+    rng = np.random.default_rng(seed)
+    obj.a = int(rng.integers(1, vocab_size))
+    obj.b = int(rng.integers(0, vocab_size))
+    obj.observation_space = Box(0, vocab_size - 1, shape=(), dtype=np.int32)
+    obj.action_space = Discrete(vocab_size)
+
+
+class TokenBigram(Env):
+    """Token env for language-model policies: the observation is the
+    current token id (a scalar int32 Box: token ids are embedded by the
+    policy, not one-hot encoded), the action the next token, and the next
+    observation is the action taken. Reward 1 where the action is
+    `(a * token + b) mod vocab_size` for the env's seeded `a`, `b`;
+    an episode is `episode_len` tokens. `jax_env.py:JaxTokenBigram` is the
+    same env on the device."""
+
+    def __init__(self, vocab_size: int = 50304, episode_len: int = 1024,
+                 seed: int = 0):
+        init_token_bigram(self, vocab_size, episode_len, seed)
+        self._rng = np.random.default_rng()
+
+    def reset(self):
+        self._t = 0
+        self._token = int(self._rng.integers(self.vocab_size))
+        return np.int32(self._token)
+
+    def step(self, action):
+        target = (self.a * self._token + self.b) % self.vocab_size
+        reward = 1.0 if int(action) == target else 0.0
+        self._t += 1
+        self._token = int(action)
+        return np.int32(self._token), reward, self._t >= self.episode_len, {}
+
+
 class RepeatInitialObs(Env):
     """Cue-recall memory task (parity: the reference's
     `RepeatInitialObsEnv` LSTM example env): a one-hot cue appears only at

@@ -133,6 +133,40 @@ class JaxCartPole(JaxEnv):
         return {"s": s, "t": t}, s, jnp.float32(1.0), done
 
 
+class JaxTokenBigram(JaxEnv):
+    """On-device token env (same dynamics as `env.py:TokenBigram`): the
+    observation is the current token id, the action the next token, and
+    the next observation is the action taken. Reward 1 where the action is
+    `(a * token + b) mod vocab_size` for the env's seeded `a`, `b`; an
+    episode is `episode_len` tokens and restarts from a random first
+    token. It costs next to nothing by design: what a token policy's
+    rollout takes is the policy's."""
+
+    def __init__(self, vocab_size: int = 50304, episode_len: int = 1024,
+                 seed: int = 0):
+        from .env import init_token_bigram
+        init_token_bigram(self, vocab_size, episode_len, seed)
+
+    def reset(self, rng):
+        token = jax.random.randint(rng, (), 0, self.vocab_size, jnp.int32)
+        return {"t": jnp.zeros((), jnp.int32), "token": token}, token
+
+    def step(self, state, action, rng):
+        token = state["token"]
+        # (a * token + b) mod V without leaving int32: a * token can reach
+        # 2.5e9 at a 50k vocabulary.
+        target = (jnp.uint32(self.a) * token.astype(jnp.uint32)
+                  + jnp.uint32(self.b)) % jnp.uint32(self.vocab_size)
+        action = action.astype(jnp.int32)
+        reward = (action == target.astype(jnp.int32)).astype(jnp.float32)
+        t = state["t"] + 1
+        done = t >= self.episode_len
+        first = jax.random.randint(rng, (), 0, self.vocab_size, jnp.int32)
+        token = jnp.where(done, first, action)
+        return {"t": jnp.where(done, 0, t), "token": token}, token, \
+            reward, done
+
+
 # -- registry ------------------------------------------------------------
 _JAX_REGISTRY = {}
 
@@ -161,5 +195,10 @@ register_jax_env("SyntheticAtari-v0",
                  lambda cfg: JaxSyntheticAtari(
                      episode_len=cfg.get("episode_len", 1000),
                      num_actions=cfg.get("num_actions", 6)))
+register_jax_env("TokenBigram-v0",
+                 lambda cfg: JaxTokenBigram(
+                     vocab_size=cfg.get("vocab_size", 50304),
+                     episode_len=cfg.get("episode_len", 1024),
+                     seed=cfg.get("seed", 0)))
 register_jax_env("CartPole-v0", lambda cfg: JaxCartPole(max_steps=200))
 register_jax_env("CartPole-v1", lambda cfg: JaxCartPole(max_steps=500))

@@ -14,7 +14,7 @@ from __future__ import annotations
 from typing import Callable, Dict
 
 from .env import (CartPole, Pendulum, RepeatInitialObs, StatelessCartPole,
-                  SyntheticAtari)
+                  SyntheticAtari, TokenBigram)
 
 _REGISTRY: Dict[str, Callable] = {}
 
@@ -164,6 +164,11 @@ register_env("SyntheticAtari-v0",
              lambda cfg: SyntheticAtari(
                  episode_len=cfg.get("episode_len", 1000),
                  num_actions=cfg.get("num_actions", 6)))
+register_env("TokenBigram-v0",
+             lambda cfg: TokenBigram(
+                 vocab_size=cfg.get("vocab_size", 50304),
+                 episode_len=cfg.get("episode_len", 1024),
+                 seed=cfg.get("seed", 0)))
 register_env("SyntheticAtariFrames-v0",
              lambda cfg: SyntheticAtari(
                  episode_len=cfg.get("episode_len", 1000),

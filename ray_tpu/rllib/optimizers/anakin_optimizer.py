@@ -15,12 +15,28 @@ across chips, params replicated, gradient all-reduce inserted by XLA —
 the same sharding contract as `JaxPolicy._train_fn`.
 
 Semantics: on-policy IMPALA — each scan iteration rolls out under the
-current params and immediately updates them, so V-trace's importance
-ratios are 1 and the correction is a no-op. The V-trace loss program is
-kept anyway: it is byte-for-byte the same loss the async (Sebulba /
-remote-worker) paths feed off-policy, so one loss serves two feeding
-architectures and the correction engages automatically wherever rollout
-and learner params diverge.
+current params and then learns from that rollout. With one update a
+rollout (`sgd_minibatch_size` 0) V-trace's importance ratios are 1 and the
+correction is a no-op. With `sgd_minibatch_size` set, the rollout is
+consumed as minibatches of whole fragments, each an optimizer update
+inside the same program (`num_sgd_iter` passes), so from the second
+minibatch on the learner's params have left the rollout's and the
+correction runs (`is_ratio_mean` / `is_ratio_max` in the stats). The loss
+is byte-for-byte the one the async (Sebulba / remote-worker) paths feed
+off-policy: one loss serves both feeding architectures.
+
+Policy state. A stateful policy (`policy.recurrent`: an LSTM's `(c, h)`, a
+transformer's key/value cache) has its state carried through the rollout
+scan as a pytree and reset where the previous step ended an episode. A
+recurrent policy's fragments are replayed by the learner from the state
+the rollout began them with (`state_in`). A policy whose state is a window
+of `context_len` positions is replayed from an empty window, so its
+fragments must be whole episodes: the optimizer refuses anything else.
+
+Trajectory. A step keeps obs, action, reward, done, and the behaviour
+policy's distribution inputs; where those are too wide to keep
+(`policy.keeps_dist_inputs`, decided from the action space's size) it
+keeps the taken action's log-probability and the value in their place.
 """
 
 from __future__ import annotations
@@ -44,6 +60,8 @@ class AnakinOptimizer(PolicyOptimizer):
     def __init__(self, workers, jax_env, num_envs: int,
                  rollout_fragment_length: int,
                  updates_per_call: int = 10,
+                 sgd_minibatch_size: int = 0,
+                 num_sgd_iter: int = 1,
                  seed: int = 0):
         super().__init__(workers)
         self.policy = workers.local_worker.policy
@@ -51,6 +69,16 @@ class AnakinOptimizer(PolicyOptimizer):
         self.num_envs = num_envs
         self.T = rollout_fragment_length
         self.updates_per_call = updates_per_call
+        # Minibatches of whole fragments a rollout (1 = one update on all
+        # of it), and passes over them.
+        batch = num_envs * rollout_fragment_length
+        self.minibatch = sgd_minibatch_size or batch
+        if batch % self.minibatch or self.minibatch % self.T:
+            raise ValueError(
+                f"sgd_minibatch_size ({self.minibatch}) must tile the "
+                f"rollout ({num_envs} envs x {self.T} steps) in whole "
+                "fragments")
+        self.num_sgd_iter = num_sgd_iter
         self.learner_stats: Dict = {}
         self._ep_reward_mean = float("nan")
         self._ep_len_mean = float("nan")
@@ -67,6 +95,17 @@ class AnakinOptimizer(PolicyOptimizer):
             raise ValueError(
                 f"num_envs ({num_envs}) must divide evenly across the "
                 f"learner mesh ({mesh_size} devices)")
+        window = getattr(policy.model, "context_len", None)
+        if window is not None:
+            episode = getattr(jax_env, "episode_len", None)
+            if not episode or episode > window or self.T % episode:
+                raise ValueError(
+                    "a policy with a context window learns each fragment "
+                    "from an empty window: rollout_fragment_length "
+                    f"({self.T}) must be whole episodes of the env "
+                    f"(episode_len {episode}) and an episode must fit the "
+                    f"window ({window} positions)")
+        self._replays_state = policy.recurrent and window is None
 
         # Device-resident env state: one slot per env, batch-sharded.
         vreset = jax.vmap(self.env.reset)
@@ -82,6 +121,12 @@ class AnakinOptimizer(PolicyOptimizer):
             jnp.zeros(num_envs, jnp.float32), policy._bsharded)
         self._ep_len = jax.device_put(
             jnp.zeros(num_envs, jnp.int32), policy._bsharded)
+        # (state, reset) of a stateful policy, () of a feedforward one:
+        # every slot starts an episode.
+        self._pstate = jax.device_put(
+            (policy.initial_state(num_envs),
+             jnp.ones(num_envs, jnp.float32)), policy._bsharded) \
+            if policy.recurrent else ()
         self._anakin_fn = self._build_fn()
 
     # ------------------------------------------------------------------
@@ -95,60 +140,68 @@ class AnakinOptimizer(PolicyOptimizer):
             """[T, N, ...] -> env-major flat [N*T, ...]."""
             return jnp.swapaxes(x, 0, 1).reshape((N * T,) + x.shape[2:])
 
-        # Every op of the program sits under one of four scopes
-        # (`jax.named_scope`: op metadata, nothing at run time), so a
-        # trace attributes device time by name. Scopes nest where a scan
-        # is called inside one; an op belongs to the innermost (last)
-        # `anakin/<scope>` of its name.
-        def one_update(carry, _):
-            (params, opt_state, env_state, obs, rng,
-             ep_rew, ep_len, ep_acc) = carry
+        num_mb = (N * T) // self.minibatch
+        mb_frags = self.minibatch // T
+        stateful = policy.recurrent
 
-            def step_fn(scarry, _):
-                env_state, obs, rng, ep_rew, ep_len, ep_acc = scarry
-                with jax.named_scope("anakin/inference"):
-                    rng, akey, ekey = jax.random.split(rng, 3)
-                    dist_inputs, _ = policy.apply(params, obs)
-                    action = policy.dist_class(dist_inputs).sample(akey)
-                with jax.named_scope("anakin/env_step"):
-                    env_state, next_obs, reward, done = vstep(
-                        env_state, action, jax.random.split(ekey, N))
-                    # Episode bookkeeping (completed-episode sums +
-                    # counts).
-                    ep_rew = ep_rew + reward
-                    ep_len = ep_len + 1
-                    donef = done.astype(jnp.float32)
-                    ep_acc = (ep_acc[0] + jnp.sum(donef * ep_rew),
-                              ep_acc[1] + jnp.sum(donef * ep_len),
-                              ep_acc[2] + jnp.sum(donef))
-                    ep_rew = jnp.where(done, 0.0, ep_rew)
-                    ep_len = jnp.where(done, 0, ep_len)
-                out = (obs, action, reward, done, dist_inputs)
-                return (env_state, next_obs, rng, ep_rew, ep_len,
-                        ep_acc), out
-
-            # The rollout loop's own ops (stacking the trajectory) count
-            # as env_step.
+        def rollout_step(params, scarry):
+            """One env step of all slots under `params`."""
+            env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate = scarry
+            with jax.named_scope("anakin/inference"):
+                rng, akey, ekey = jax.random.split(rng, 3)
+                if stateful:
+                    dist_inputs, value, state = policy.step_state(
+                        params, obs, *pstate)
+                else:
+                    dist_inputs, value = policy.apply(params, obs)
+                dist = policy.dist_class(dist_inputs)
+                action = dist.sample(akey)
+                kept = (dist_inputs,) if policy.keeps_dist_inputs \
+                    else (dist.logp(action), value)
             with jax.named_scope("anakin/env_step"):
-                (env_state, obs, rng, ep_rew, ep_len, ep_acc), traj = \
-                    jax.lax.scan(
-                        step_fn,
-                        (env_state, obs, rng, ep_rew, ep_len, ep_acc),
-                        None, length=T)
+                env_state, next_obs, reward, done = vstep(
+                    env_state, action, jax.random.split(ekey, N))
+                # Episode bookkeeping (completed-episode sums +
+                # counts).
+                ep_rew = ep_rew + reward
+                ep_len = ep_len + 1
+                donef = done.astype(jnp.float32)
+                ep_acc = (ep_acc[0] + jnp.sum(donef * ep_rew),
+                          ep_acc[1] + jnp.sum(donef * ep_len),
+                          ep_acc[2] + jnp.sum(donef))
+                ep_rew = jnp.where(done, 0.0, ep_rew)
+                ep_len = jnp.where(done, 0, ep_len)
+                if stateful:
+                    pstate = (state, donef)
+            out = (obs, action, reward, done) + kept
+            return (env_state, next_obs, rng, ep_rew, ep_len, ep_acc,
+                    pstate), out
+
+        def batch_of(traj, obs, pstate_in):
+            """The rollout as the learner's packed fragment batch."""
+            obs_t, act_t, rew_t, done_t, *kept = traj
+            batch = {
+                sb.OBS: em(obs_t),
+                sb.ACTIONS: em(act_t),
+                sb.REWARDS: em(rew_t),
+                sb.DONES: em(done_t).astype(jnp.float32),
+                sb.BOOTSTRAP_OBS: obs,
+            }
+            if policy.keeps_dist_inputs:
+                # Behaviour log-probs equal target log-probs
+                # on-policy; losses that want them recompute from
+                # the logits.
+                batch[sb.ACTION_DIST_INPUTS] = em(kept[0])
+            else:
+                batch[sb.ACTION_LOGP] = em(kept[0])
+                batch[sb.VF_PREDS] = em(kept[1])
+            if self._replays_state:
+                batch[sb.STATE_IN], batch["reset_in"] = pstate_in
+            return batch
+
+        def learn(params, opt_state, batch, lkey):
+            """One optimizer update on `batch`."""
             with jax.named_scope("anakin/loss"):
-                obs_t, act_t, rew_t, done_t, logits_t = traj
-                batch = {
-                    sb.OBS: em(obs_t),
-                    sb.ACTIONS: em(act_t),
-                    sb.REWARDS: em(rew_t),
-                    sb.DONES: em(done_t).astype(jnp.float32),
-                    sb.ACTION_DIST_INPUTS: em(logits_t),
-                    # Behaviour log-probs equal target log-probs
-                    # on-policy; losses that want them recompute from
-                    # the logits.
-                    sb.BOOTSTRAP_OBS: obs,
-                }
-                rng, lkey = jax.random.split(rng)
                 (loss, stats), grads = jax.value_and_grad(
                     policy._loss_fn, argnums=1, has_aux=True)(
                         policy, params, batch, lkey, policy.loss_state)
@@ -156,38 +209,101 @@ class AnakinOptimizer(PolicyOptimizer):
                 updates, opt_state = policy.optimizer.update(
                     grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
+            return params, opt_state, stats
+
+        def learn_minibatches(params, opt_state, batch, lkey):
+            """`num_sgd_iter` passes over the rollout, `num_mb` updates a
+            pass, fragments in rollout order. Row columns are [N*T, ..],
+            fragment columns (BOOTSTRAP_OBS, STATE_IN, reset_in) [N, ..]."""
+            def split(x):
+                rows = self.minibatch if x.shape[0] == N * T else mb_frags
+                return x.reshape((num_mb, rows) + x.shape[1:])
+            mbs = jax.tree.map(split, batch)
+
+            def mb_step(carry, mb):
+                params, opt_state = carry
+                params, opt_state, stats = learn(
+                    params, opt_state, mb, lkey)
+                return (params, opt_state), stats
+
+            def one_pass(carry, _):
+                return jax.lax.scan(mb_step, carry, mbs)
+
+            (params, opt_state), stats = jax.lax.scan(
+                one_pass, (params, opt_state), None,
+                length=self.num_sgd_iter)
+            return params, opt_state, reduce_stats(stats)
+
+        def reduce_stats(stats):
+            """Scalar stats of several updates as one: a `*_max` is the
+            largest, anything else the mean."""
+            return {k: jnp.max(v) if k.endswith("_max") else jnp.mean(v)
+                    for k, v in stats.items()}
+
+        # Every op of the program sits under one of the scopes
+        # `anakin/{inference,env_step,loss,update}` (`jax.named_scope`: op
+        # metadata, nothing at run time), so a trace attributes device
+        # time by name; a rollout with its own update loop also has
+        # `anakin/decode` and `anakin/learn` around the two halves. Scopes
+        # nest where a scan is called inside one; an op belongs to the
+        # innermost (last) `anakin/<scope>` of its name.
+        def one_update(carry, _):
+            (params, opt_state, env_state, obs, rng,
+             ep_rew, ep_len, ep_acc, pstate) = carry
+            pstate_in = pstate
+
+            # The rollout loop's own ops (stacking the trajectory) count
+            # as env_step.
+            with jax.named_scope("anakin/decode" if stateful
+                                 else "anakin/env_step"):
+                (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate), \
+                    traj = jax.lax.scan(
+                        lambda c, _: rollout_step(params, c),
+                        (env_state, obs, rng, ep_rew, ep_len, ep_acc,
+                         pstate),
+                        None, length=T)
+            with jax.named_scope("anakin/loss"):
+                batch = batch_of(traj, obs, pstate_in)
+                rng, lkey = jax.random.split(rng)
+            if num_mb == 1 and self.num_sgd_iter == 1:
+                params, opt_state, stats = learn(
+                    params, opt_state, batch, lkey)
+            else:
+                with jax.named_scope("anakin/learn"):
+                    params, opt_state, stats = learn_minibatches(
+                        params, opt_state, batch, lkey)
             return (params, opt_state, env_state, obs, rng,
-                    ep_rew, ep_len, ep_acc), stats
+                    ep_rew, ep_len, ep_acc, pstate), stats
 
         @jax.named_scope("anakin/update")
         def anakin_fn(params, opt_state, env_state, obs, rng,
-                      ep_rew, ep_len):
+                      ep_rew, ep_len, pstate):
             ep_acc = (jnp.zeros((), jnp.float32),
                       jnp.zeros((), jnp.float32),
                       jnp.zeros((), jnp.float32))
             carry, stats = jax.lax.scan(
                 one_update,
                 (params, opt_state, env_state, obs, rng,
-                 ep_rew, ep_len, ep_acc),
+                 ep_rew, ep_len, ep_acc, pstate),
                 None, length=M)
             (params, opt_state, env_state, obs, rng,
-             ep_rew, ep_len, ep_acc) = carry
-            # Mean over the M updates for scalar stats.
-            stats = jax.tree.map(lambda x: jnp.mean(x), stats)
+             ep_rew, ep_len, ep_acc, pstate) = carry
+            # One value a call for the M rollouts' scalar stats.
+            stats = reduce_stats(stats)
             stats["_ep_reward_sum"] = ep_acc[0]
             stats["_ep_len_sum"] = ep_acc[1]
             stats["_ep_count"] = ep_acc[2]
             return params, opt_state, env_state, obs, rng, ep_rew, \
-                ep_len, stats
+                ep_len, pstate, stats
 
         repl, bshard = policy._repl, policy._bsharded
         return jax.jit(
             anakin_fn,
-            donate_argnums=(0, 1, 2, 3, 4, 5, 6),
+            donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7),
             in_shardings=(repl, repl, bshard, bshard, repl, bshard,
-                          bshard),
+                          bshard, bshard),
             out_shardings=(repl, repl, bshard, bshard, repl, bshard,
-                           bshard, repl))
+                           bshard, bshard, repl))
 
     # ------------------------------------------------------------------
     def step(self) -> dict:
@@ -198,9 +314,10 @@ class AnakinOptimizer(PolicyOptimizer):
             with phase("anakin.call"):
                 (policy.params, policy.opt_state, self._env_state,
                  self._obs, self._rng, self._ep_rew, self._ep_len,
-                 stats) = self._anakin_fn(
+                 self._pstate, stats) = self._anakin_fn(
                     policy.params, policy.opt_state, self._env_state,
-                    self._obs, self._rng, self._ep_rew, self._ep_len)
+                    self._obs, self._rng, self._ep_rew, self._ep_len,
+                    self._pstate)
             with phase("anakin.readback"):
                 stats = {k: float(v) for k, v in stats.items()}
         policy._batch_on = len(self._obs.sharding.device_set)

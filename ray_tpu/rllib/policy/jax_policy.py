@@ -55,6 +55,12 @@ _DEVICE_COLUMNS = (
     "seq_mask", "state_in_c", "state_in_h",
 )
 
+# A trajectory keeps the behaviour policy's distribution inputs of a step
+# only while a row of them is this narrow. Wider (a vocabulary: 200 kB a
+# step) it keeps the taken action's log-probability and the value, and the
+# losses read ACTION_LOGP.
+MAX_KEPT_DIST_INPUTS = 4096
+
 
 def default_optimizer(config: dict) -> optax.GradientTransformation:
     clip = config.get("grad_clip")
@@ -84,6 +90,7 @@ class JaxPolicy(Policy):
                  seed: Optional[int] = None):
         super().__init__(observation_space, action_space, config)
         self.dist_class, self.dist_dim = get_action_dist(action_space)
+        self.keeps_dist_inputs = self.dist_dim <= MAX_KEPT_DIST_INPUTS
         # Compute dtype resolves BEFORE the model is built so catalog
         # networks thread it through their flax layers (bf16 trunk
         # activations, not just bf16-cast weights). Custom make_model
@@ -216,28 +223,56 @@ class JaxPolicy(Policy):
         initial state and done-driven resets, flatten back to [N]."""
         if not self.recurrent:
             return self.apply(params, batch[sb.OBS])
-        dist_bt, val_bt, _ = self.apply_sequences(params, batch)
+        (dist_bt, val_bt, _), _ = self.apply_sequences(params, batch)
         O = dist_bt.shape[-1]
         return dist_bt.reshape(-1, O), val_bt.reshape(-1)
 
     def apply_sequences(self, params, batch):
-        """Recurrent forward over [B, L] sequences.
+        """Stateful forward over [B, L] sequences.
 
-        Returns (dist_inputs[B,L,O], value[B,L], final_carry). Initial
-        state is each sequence's first-row recorded state; resets fire
-        WITHIN a sequence where the previous step was done (packed
-        fragments cross episodes; padded chunks never do)."""
+        Returns ((dist_inputs[B,L,O], value[B,L], final_carry), what the
+        model counted in the pass: its "counters" collection, {} for a
+        model that counts nothing). Initial
+        state is each sequence's recorded one: `state_in`, a pytree with a
+        row a sequence (device-resident rollouts), or the first row of
+        the per-step `state_in_c/h` columns (host samplers); a batch with
+        neither starts every sequence from the model's initial state.
+        Resets fire WITHIN a sequence where the previous step was done
+        (packed fragments cross episodes; padded chunks never do), and at
+        its first step where `reset_in` says the step before it was."""
         L = self.train_seq_len
         obs = batch[sb.OBS]
         B = obs.shape[0] // L
         obs_bt = obs.reshape((B, L) + obs.shape[1:])
-        state = (batch["state_in_c"].reshape(B, L, -1)[:, 0],
-                 batch["state_in_h"].reshape(B, L, -1)[:, 0])
+        if sb.STATE_IN in batch:
+            state = batch[sb.STATE_IN]
+        elif "state_in_c" in batch:
+            state = (batch["state_in_c"].reshape(B, L, -1)[:, 0],
+                     batch["state_in_h"].reshape(B, L, -1)[:, 0])
+        else:
+            state = self.model.initial_state(B)
         dones = batch[sb.DONES].reshape(B, L)
         # reset before step t iff step t-1 (same sequence) was terminal
-        reset = jnp.concatenate(
-            [jnp.zeros((B, 1), jnp.float32), dones[:, :-1]], axis=1)
-        return self.apply(params, obs_bt, state, reset)
+        first = batch["reset_in"][:, None] if "reset_in" in batch \
+            else jnp.zeros((B, 1), jnp.float32)
+        reset = jnp.concatenate([first, dones[:, :-1]], axis=1)
+        out, counted = self.apply(params, obs_bt, state, reset,
+                                  mutable=["counters"])
+        return out, {k: v[-1] for k, v in
+                     counted.get("counters", {}).items()}
+
+    def initial_state(self, batch_size: int):
+        """The model's rollout state for `batch_size` rows, as the pytree
+        its forward takes (() for feedforward policies)."""
+        return self.model.initial_state(batch_size) if self.recurrent else ()
+
+    def step_state(self, params, obs, state, reset):
+        """One rollout step of a stateful policy: obs [B], reset [B] (1
+        where the previous step ended an episode) -> (dist_inputs [B, O],
+        value [B], state)."""
+        dist_bt, val_bt, state = self.apply(
+            params, obs[:, None], state, reset[:, None])
+        return dist_bt[:, 0], val_bt[:, 0], state
 
     def get_initial_state(self, batch_size: int = 1):
         """Per-env rollout state columns ([] for feedforward policies)."""

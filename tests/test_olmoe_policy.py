@@ -1,0 +1,398 @@
+"""The OLMoE token policy at a tiny size on the CPU: the model against the
+plain reference (`benchmark/lib/reference_olmoe.py`), decode through the
+cache against the causal pass, the V-trace loss and its gradients, the
+dropless dispatch under skewed routing, V-trace from ACTION_LOGP, the
+trainer on the fused Anakin path, and the Nature-CNN Anakin program's
+outputs as they were before any of it.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from lib import reference_olmoe as reference  # noqa: E402
+
+from ray_tpu.models import catalog  # noqa: E402
+from ray_tpu.models.transformer import dropless_experts  # noqa: E402
+from ray_tpu.rllib import sample_batch as sb  # noqa: E402
+from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
+from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss  # noqa: E402
+
+NET = dict(vocab_size=128, hidden_size=64, num_attention_heads=4,
+           num_key_value_heads=4, num_hidden_layers=2, num_experts=8,
+           num_experts_per_tok=2, intermediate_size=32,
+           max_position_embeddings=16, rope_theta=10000.0,
+           rms_norm_eps=1e-5, norm_topk_prob=False)
+B, S = 3, 16
+
+
+def build(dtype):
+    model = catalog.get_model(None, NET["vocab_size"], {
+        "custom_model": "olmoe", "custom_model_config": NET,
+        "compute_dtype": dtype})
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (B, S), 0, NET["vocab_size"])
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :1],
+                        model.initial_state(B), jnp.zeros((B, 1)))
+    return model, params, tokens
+
+
+def token_trainer_config(**over):
+    cfg = dict(
+        env="TokenBigram-v0",
+        env_config={"vocab_size": NET["vocab_size"], "episode_len": S},
+        anakin=True, num_workers=0, num_envs_per_worker=8,
+        rollout_fragment_length=S, train_batch_size=8 * S,
+        sgd_minibatch_size=2 * S, num_sgd_iter=1,
+        anakin_updates_per_call=1, min_iter_time_s=0, lr=6e-4, seed=3,
+        model={"custom_model": "olmoe", "custom_model_config": NET})
+    cfg.update(over)
+    return cfg
+
+
+# -- the model against the reference ------------------------------------
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_causal_pass_matches_reference(dtype):
+    """float32 block: to float32 accuracy, same expert sets. bfloat16
+    block: the limits written beside the reference (outputs against the
+    reference held to the system's experts; its choice of experts against
+    the reference's own)."""
+    model, params, tokens = build(dtype)
+    (logits, values, _), kept = model.apply(
+        params, tokens, None, jnp.zeros((B, S)), mutable=["routing"])
+    experts = kept["routing"]["experts"][-1]
+    want = reference.forward(params["params"], tokens, NET)
+    routing = reference.routing_verdict(experts, want[2], want[3])
+    held = reference.forward(params["params"], tokens, NET, experts=experts)
+    verdict = reference.compare((logits, values), held[:2])
+    if dtype == "f32":
+        assert routing["router_flips"] == 0.0
+        assert max(verdict["errors"].values()) < 1e-5, verdict
+    else:
+        # 96 (token, layer) pairs: one flip is 1 %, and it is a near-tie.
+        assert routing["router_flips"] <= 0.1
+        assert routing["max_flip_gap"] <= reference.MAX_FLIP_GAP
+        assert verdict["ok"], verdict
+
+
+@pytest.mark.parametrize("wrong", ["drop_last_expert", "renormalise",
+                                   "float8_e4m3"])
+def test_limits_refuse_wrong_mathematics(wrong):
+    """The comparison fails a dropped expert, renormalised weights and a
+    block computed a precision lower (the reference, so altered, against
+    itself; the router held to the same experts)."""
+    _, params, tokens = build("f32")
+    want = reference.forward(params["params"], tokens, NET)
+    if wrong == "float8_e4m3":
+        got = reference.forward(params["params"], tokens, NET,
+                                round_to=wrong, experts=want[2])
+    else:
+        got = reference.forward(params["params"], tokens, NET, mutate=wrong)
+    assert not reference.compare(got[:2], want[:2])["ok"]
+
+
+def test_a_wrong_router_is_refused_by_its_flips():
+    """Experts chosen from probabilities that are off by more than a
+    rounding are not near-ties of the reference's."""
+    _, params, tokens = build("f32")
+    want = reference.forward(params["params"], tokens, NET)
+    probs = np.asarray(want[3])
+    noisy = probs * np.random.default_rng(0).uniform(0.7, 1.3, probs.shape)
+    experts = np.argsort(-noisy, axis=-1)[..., :NET["num_experts_per_tok"]]
+    verdict = reference.routing_verdict(experts, want[2], want[3])
+    assert not verdict["ok"] and verdict["max_flip_gap"] > 0.05
+    same = reference.routing_verdict(want[2], want[2], want[3])
+    assert same == {"router_flips": 0.0, "max_flip_gap": 0.0, "ok": True}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_decode_through_cache_matches_causal_pass(dtype):
+    """Every position decoded one token at a time against the cache."""
+    model, params, tokens = build(dtype)
+    logits, values, _ = model.apply(params, tokens, None, jnp.zeros((B, S)))
+    state, got_l, got_v = model.initial_state(B), [], []
+    for t in range(S):
+        step_l, step_v, state = model.apply(
+            params, tokens[:, t], state, jnp.zeros(B), method="decode")
+        got_l.append(step_l)
+        got_v.append(step_v)
+    tol = 1e-5 if dtype == "f32" else reference.TOLERANCE
+    assert reference.relative_error(jnp.stack(got_l, 1), logits) <= tol
+    assert reference.relative_error(jnp.stack(got_v, 1), values) <= tol
+    assert np.all(np.asarray(state["pos"]) == S)
+
+
+def test_prefill_then_decode_and_reset_inside_a_fragment():
+    """A causal pass returns a cache a decode can continue from, and a
+    reset inside a fragment starts a fresh episode: positions restart and
+    nothing attends across the boundary."""
+    model, params, tokens = build("f32")
+    full, _, _ = model.apply(params, tokens, None, jnp.zeros((B, S)))
+    _, _, state = model.apply(params, tokens[:, :10], None,
+                              jnp.zeros((B, 10)))
+    for t in range(10, S):
+        step, _, state = model.apply(
+            params, tokens[:, t:t + 1], state, jnp.zeros((B, 1)))
+        assert reference.relative_error(step[:, 0], full[:, t]) < 1e-5
+    # Two episodes of 8 in one fragment == the two halves on their own.
+    reset = jnp.zeros((B, S)).at[:, 8].set(1.0)
+    both, _, state = model.apply(params, tokens, None, reset)
+    second, _, _ = model.apply(params, tokens[:, 8:], None,
+                               jnp.zeros((B, 8)))
+    assert reference.relative_error(both[:, 8:], second) < 1e-5
+    assert reference.relative_error(both[:, :8], full[:, :8]) < 1e-5
+    assert np.all(np.asarray(state["pos"]) == 8)
+    # ... and the decode honours the same reset.
+    state = model.initial_state(B)
+    for t in range(S):
+        step, _, state = model.apply(
+            params, tokens[:, t:t + 1], state, reset[:, t:t + 1])
+        assert reference.relative_error(step[:, 0], both[:, t]) < 1e-5
+
+
+@pytest.mark.parametrize("routing", ["one_expert_takes_all",
+                                     "one_expert_takes_none", "random"])
+def test_dropless_dispatch_equals_dense_masked_loop(routing):
+    """Sort, grouped product, un-sort == every expert on every token times
+    a 0/1 mask, whatever the group sizes (a full group, an empty one)."""
+    M, H, W, E, k = 24, 16, 8, 4, 2
+    rng = np.random.default_rng(0)
+    n = jnp.asarray(rng.normal(size=(M, H)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(E, H, W)), jnp.float32)
+                    for _ in range(2))
+    w_down = jnp.asarray(rng.normal(size=(E, W, H)), jnp.float32)
+    if routing == "one_expert_takes_all":
+        top_i = np.stack([np.full(M, 2), rng.integers(0, 2, M)], axis=1)
+    elif routing == "one_expert_takes_none":
+        top_i = np.stack([rng.permutation(M) % 3, np.full(M, 0)], axis=1)
+        top_i[:, 1] = (top_i[:, 0] + 1) % 3  # expert 3 gets nothing
+    else:
+        top_i = np.stack([rng.permutation(E)[:k] for _ in range(M)])
+    top_i = jnp.asarray(top_i, jnp.int32)
+    top_p = jnp.asarray(rng.uniform(0.05, 0.5, size=(M, k)), jnp.float32)
+    got, group_sizes = dropless_experts(n, top_p, top_i, w_gate, w_up, w_down)
+    want = jnp.zeros((M, H))
+    for e in range(E):
+        weight = jnp.sum(jnp.where(top_i == e, top_p, 0.0), axis=1)
+        out = (jax.nn.silu(n @ w_gate[e]) * (n @ w_up[e])) @ w_down[e]
+        want = want + weight[:, None] * out
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    assert int(jnp.sum(group_sizes)) == M * k
+    if routing == "one_expert_takes_all":
+        assert int(group_sizes[2]) == M
+    if routing == "one_expert_takes_none":
+        assert int(group_sizes[3]) == 0
+
+
+# -- the loss -------------------------------------------------------------
+@pytest.fixture(scope="module")
+def token_trainer():
+    trainer = IMPALATrainer(config=token_trainer_config(
+        model={"custom_model": "olmoe", "custom_model_config": NET,
+               "compute_dtype": "f32"}))
+    yield trainer
+    trainer.stop()
+
+
+def test_vtrace_minibatch_loss_and_gradients_match_reference(token_trainer):
+    """One minibatch of whole episodes through the system's loss (packed
+    rows, ACTION_LOGP, the bootstrap step from the final cache) and
+    through `jax.grad` of the plain reference."""
+    policy = token_trainer.get_policy()
+    cfg = policy.config
+    rng = np.random.default_rng(5)
+    tokens = rng.integers(0, NET["vocab_size"], size=(B, S))
+    actions = rng.integers(0, NET["vocab_size"], size=(B, S))
+    rewards = rng.integers(0, 2, size=(B, S)).astype(np.float32)
+    behaviour_logp = rng.uniform(-5.5, -4.0, size=(B, S)).astype(np.float32)
+    dones = np.zeros((B, S), np.float32)
+    dones[:, -1] = 1.0
+    batch = {
+        sb.OBS: jnp.asarray(tokens.reshape(-1), jnp.int32),
+        sb.ACTIONS: jnp.asarray(actions.reshape(-1), jnp.int32),
+        sb.REWARDS: jnp.asarray(rewards.reshape(-1)),
+        sb.DONES: jnp.asarray(dones.reshape(-1)),
+        sb.ACTION_LOGP: jnp.asarray(behaviour_logp.reshape(-1)),
+        sb.BOOTSTRAP_OBS: jnp.asarray(tokens[:, 0], jnp.int32),
+    }
+    params = jax.tree.map(jnp.asarray, policy.get_weights())
+
+    def system(p):
+        return vtrace_loss(policy, p, batch, None, {})
+
+    (total, stats), grads = jax.value_and_grad(system, has_aux=True)(params)
+    ref_batch = {"tokens": tokens, "actions": actions, "rewards": rewards,
+                 "behaviour_logp": behaviour_logp}
+    (want_total, parts), want_grads = jax.value_and_grad(
+        lambda p: reference.vtrace_loss(p, ref_batch, NET, cfg),
+        has_aux=True)(params["params"])
+    np.testing.assert_allclose(total, want_total, rtol=1e-4)
+    np.testing.assert_allclose(
+        stats["entropy"] * B * S, parts["entropy"], rtol=1e-4)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads["params"])
+    want_flat = jax.tree.leaves(want_grads)
+    assert len(flat) == len(want_flat)
+    for (path, got), want in zip(flat, want_flat):
+        scale = float(jnp.max(jnp.abs(want))) + 1e-8
+        assert float(jnp.max(jnp.abs(got - want))) <= 2e-3 * scale, path
+    assert stats["expert_load_mean"] == B * S * 2 / NET["num_experts"]
+
+
+def test_vtrace_from_logp_equals_vtrace_from_dist_inputs():
+    """A 6-action batch: behaviour log-probabilities read from ACTION_LOGP
+    give the loss, the stats and the gradients that the stored behaviour
+    logits give."""
+    trainer = IMPALATrainer(config=dict(
+        env="SyntheticAtari-v0", num_workers=0, rollout_fragment_length=5,
+        train_batch_size=20, min_iter_time_s=0, seed=2))
+    try:
+        policy = trainer.get_policy()
+        assert policy.dist_dim == 6
+        rng = np.random.default_rng(1)
+        n, T = 20, 5
+        logits = rng.normal(size=(n, 6)).astype(np.float32)
+        actions = rng.integers(0, 6, size=n)
+        logp = jax.nn.log_softmax(logits)[np.arange(n), actions]
+        batch = {
+            sb.OBS: jnp.asarray(rng.integers(
+                0, 256, size=(n, 84, 84, 4)), jnp.uint8),
+            sb.ACTIONS: jnp.asarray(actions),
+            sb.REWARDS: jnp.asarray(rng.normal(size=n), jnp.float32),
+            sb.DONES: jnp.asarray(rng.integers(0, 2, size=n), jnp.float32),
+            sb.BOOTSTRAP_OBS: jnp.asarray(rng.integers(
+                0, 256, size=(n // T, 84, 84, 4)), jnp.uint8),
+        }
+        with_logits = dict(batch, **{sb.ACTION_DIST_INPUTS: logits})
+        with_logp = dict(batch, **{sb.ACTION_LOGP: logp})
+        params = policy.params
+        out = [jax.value_and_grad(
+            lambda p, b=b: vtrace_loss(policy, p, b, None, {}),
+            has_aux=True)(params) for b in (with_logits, with_logp)]
+        ((loss_a, stats_a), grads_a), ((loss_b, stats_b), grads_b) = out
+        np.testing.assert_allclose(loss_a, loss_b, rtol=1e-6)
+        for key in stats_a:
+            np.testing.assert_allclose(stats_a[key], stats_b[key],
+                                       rtol=1e-5, atol=1e-7)
+        for a, b in zip(jax.tree.leaves(grads_a), jax.tree.leaves(grads_b)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+        assert float(stats_a["is_ratio_max"]) != 1.0  # off-policy batch
+    finally:
+        trainer.stop()
+
+
+# -- the loop -------------------------------------------------------------
+def test_token_trainer_trains_on_the_fused_path(token_trainer):
+    """`IMPALATrainer(anakin, TokenBigram-v0)` by config alone: two
+    iterations, a finite loss, a rising count."""
+    counts = []
+    for _ in range(2):
+        result = token_trainer.train()
+        stats = result["info"]["learner"]
+        assert np.isfinite(stats["total_loss"])
+        counts.append(result["timesteps_total"])
+    assert counts[1] > counts[0] > 0
+    assert counts[1] - counts[0] == 8 * S
+    # Four minibatches a rollout: the later ones are off-policy, so the
+    # importance ratios have left 1.
+    assert stats["is_ratio_max"] > 1.0
+    assert stats["expert_load_max"] >= stats["expert_load_mean"] > 0
+
+
+def test_wide_action_space_keeps_logp_not_logits():
+    """Decided from the action space's size: a 50,304-way policy's
+    trajectory carries ACTION_LOGP and VF_PREDS, a 6-way one its logits."""
+    import ray_tpu.rllib.policy.jax_policy as jp
+    wide = dict(NET, vocab_size=jp.MAX_KEPT_DIST_INPUTS + 8)
+    trainer = IMPALATrainer(config=token_trainer_config(
+        env_config={"vocab_size": wide["vocab_size"], "episode_len": S},
+        num_envs_per_worker=2, train_batch_size=2 * S,
+        sgd_minibatch_size=S,
+        model={"custom_model": "olmoe", "custom_model_config": wide}))
+    try:
+        policy = trainer.get_policy()
+        assert not policy.keeps_dist_inputs
+        seen = {}
+        loss_fn = policy._loss_fn
+
+        def spy(pol, params, batch, rng, loss_state):
+            seen.update({k: v.shape for k, v in batch.items()
+                         if hasattr(v, "shape")})
+            return loss_fn(pol, params, batch, rng, loss_state)
+
+        policy._loss_fn = spy
+        trainer.optimizer._anakin_fn = trainer.optimizer._build_fn()
+        result = trainer.train()
+        assert np.isfinite(result["info"]["learner"]["total_loss"])
+        assert sb.ACTION_DIST_INPUTS not in seen
+        assert seen[sb.ACTION_LOGP] == (S,) and seen[sb.VF_PREDS] == (S,)
+    finally:
+        trainer.stop()
+
+
+def test_context_window_policy_needs_whole_episodes():
+    with pytest.raises(ValueError, match="whole episodes"):
+        IMPALATrainer(config=token_trainer_config(
+            env_config={"vocab_size": NET["vocab_size"], "episode_len": 12}))
+
+
+@pytest.mark.parametrize("minibatch", [0, 40])
+def test_lstm_policy_trains_on_the_fused_path(minibatch):
+    """The LSTM's (c, h) is a case of the carried policy state: replayed
+    from `state_in`, the one-update rollout is exactly on-policy."""
+    trainer = IMPALATrainer(config=dict(
+        env="CartPole-v0", anakin=True, num_workers=0,
+        num_envs_per_worker=8, rollout_fragment_length=10,
+        train_batch_size=80, sgd_minibatch_size=minibatch,
+        anakin_updates_per_call=2, min_iter_time_s=0, seed=1,
+        model={"use_lstm": True, "lstm_cell_size": 16,
+               "fcnet_hiddens": [16]}))
+    try:
+        stats = trainer.train()["info"]["learner"]
+        assert np.isfinite(stats["total_loss"])
+        if minibatch == 0:
+            assert stats["is_ratio_max"] == pytest.approx(1.0, abs=1e-5)
+        else:
+            assert stats["is_ratio_max"] > 1.0
+    finally:
+        trainer.stop()
+
+
+def test_nature_cnn_anakin_outputs_unchanged():
+    """The Nature-CNN path through the same functions is the program it
+    was: for a fixed seed the stats of two calls are those of the parent
+    commit (24c7a04, recorded from its tree on this CPU)."""
+    trainer = IMPALATrainer(config=dict(
+        env="SyntheticAtari-v0", env_config={"episode_len": 8},
+        anakin=True, num_workers=0, num_envs_per_worker=4,
+        rollout_fragment_length=4, train_batch_size=16,
+        anakin_updates_per_call=2, min_iter_time_s=0, lr=6e-4,
+        grad_clip=40.0, seed=7))
+    want = [
+        {"entropy": 1.79152250289917, "mean_kl_behaviour": 0.0,
+         "policy_loss": -0.16051942110061646,
+         "total_loss": -0.646298885345459, "vf_loss": 0.27608194947242737,
+         "vtrace_mean_vs": 0.3140600919723511},
+        {"entropy": 1.791407823562622, "mean_kl_behaviour": 0.0,
+         "policy_loss": -0.019576922059059143,
+         "total_loss": 0.47002220153808594, "vf_loss": 0.1337347775697708,
+         "vtrace_mean_vs": 0.37526535987854004},
+    ]
+    try:
+        assert trainer.get_policy().keeps_dist_inputs
+        for expected in want:
+            stats = trainer.train()["info"]["learner"]
+            for key, value in expected.items():
+                assert stats[key] == pytest.approx(value, rel=1e-4,
+                                                   abs=1e-6), key
+            assert stats["is_ratio_max"] == 1.0  # one update: on-policy
+    finally:
+        trainer.stop()

@@ -341,7 +341,7 @@ def _anakin_text():
         opt, pol = t.optimizer, t.optimizer.policy
         return {"anakin_fn": _lowered(
             opt._anakin_fn, pol.params, pol.opt_state, opt._env_state,
-            opt._obs, opt._rng, opt._ep_rew, opt._ep_len)}
+            opt._obs, opt._rng, opt._ep_rew, opt._ep_len, opt._pstate)}
     finally:
         t.stop()
 
