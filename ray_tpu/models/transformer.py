@@ -12,10 +12,16 @@ language-model head's, and a value head reads the same final hidden vector.
           rotate-half RoPE; causal softmax(q k^T / sqrt(head_dim)) v; W_o
     MoE:  p = softmax(n W_r) in float32; the k largest p; weights are those
           p as they are unless `norm_topk_prob`; sum_e p_e W_down,e
-          (silu(W_gate,e n) * W_up,e n). Dropless: tokens are sorted by
+          (silu(W_gate,e n) * W_up,e n). Dropless: no capacity, no token
+          dropped or re-routed. One sum, two blockings, chosen from the
+          static shape (`experts_batched`). Grouped: tokens are sorted by
           expert, multiplied group by group (`jax.lax.ragged_dot`: each
-          expert's rows, however many) and un-sorted; no capacity, no token
-          dropped or re-routed.
+          expert's rows, however many) and un-sorted; the learner's
+          minibatch and a prefill. Batched: every row through every expert
+          in products batched over the experts, each term weighted p_e or
+          exactly 0 before the sum; a decode step, whose groups of a few
+          rows would each cost the grouped product an MXU tile while the
+          step is bound by reading every expert's weights once anyway.
 
 Departures from the published model: the value head (OLMoE has none); no
 auxiliary router loss (the RL objective has no place for it; the
@@ -93,18 +99,65 @@ def route(n, router, k, renormalise):
     return top_p, top_i
 
 
+# The grouped form's costs in units of the batched form's, whose cost is a
+# row through one expert: a group costs GROUP_COST_ROWS whatever it holds
+# (at 16 rows a group the three `ragged_dot`s run at 37 % of the bandwidth
+# their weights could be read with) and a routed row GROUPED_ROW_COST (the
+# grouped product runs further from the MXU's peak than the batched one).
+# Fitted to a sweep of both forms on a v5e at the published widths, 64
+# experts, 8 a token (PERF.md section 5): forward, batched 2.4x faster at
+# 128 rows and 1.3x at 512, 2 % slower at 768 and 1.3x at 1,024; forward
+# and backward, 1.4x faster at 512 and 1.1x slower at 1,024.
+GROUP_COST_ROWS = 540
+GROUPED_ROW_COST = 1.7
+
+
+def experts_batched(M: int, k: int, E: int) -> bool:
+    """Whether `M` rows, each routed to `k` of `E` experts, go through the
+    batched form (`M * E` rows of work) or the grouped one (`M * k` sorted
+    rows in `E` groups): a function of the static shape alone."""
+    return M * E <= GROUP_COST_ROWS * E + GROUPED_ROW_COST * M * k
+
+
 def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down):
     """sum_e p_e W_down,e (silu(W_gate,e n) * W_up,e n) for rows n [M, H]
     routed to `top_i` [M, k] with weights `top_p`; expert weights
     [E, H, W] / [E, W, H] already in n's dtype. Returns ([M, H], rows a
-    group [E])."""
+    group [E]).
+
+    Two forms of that sum, chosen by `experts_batched(M, k, E)`; both take
+    operands in n's dtype, accumulate in float32, weight in float32 and
+    compute every chosen expert of every row.
+
+    Grouped: the M*k (row, expert) pairs sorted by expert, three
+    `ragged_dot`s over the E groups, un-sorted, the k terms of a row
+    weighted and summed.
+
+    Batched: c[m, e] = p[m, j] where top_i[m, j] == e, else 0;
+    a[e, m] = silu(n W_gate,e) * (n W_up,e) for all M rows and every
+    expert; out[m] = sum_e c[m, e] a[e, m] W_down,e, the weight applied to
+    a and e folded into the contraction: one product of [M, E*W] against
+    W_down as [E*W, H]. The same sum: an expert a row did not choose has
+    weight exactly 0. It does E/k times the matrix work and reads each
+    expert's weights once, where they lie."""
     M, k = top_i.shape
     E = w_gate.shape[0]
     with jax.named_scope("policy/dispatch"):
-        expert = top_i.reshape(-1)
-        order = jnp.argsort(expert, stable=True)
+        group_sizes = jnp.zeros(E, jnp.int32).at[top_i.reshape(-1)].add(1)
+    if experts_batched(M, k, E):
+        with jax.named_scope("policy/dispatch"):
+            chosen = top_i[:, :, None] == jnp.arange(E)
+            c = jnp.sum(jnp.where(chosen, top_p[:, :, None], 0.0), axis=1)
+        with jax.named_scope("policy/experts_batched"):
+            gate = jnp.einsum("mh,ehw->emw", n, w_gate)
+            up = jnp.einsum("mh,ehw->emw", n, w_up)
+            a = (c.T[:, :, None] * (jax.nn.silu(gate) * up)).astype(n.dtype)
+            mixed = jnp.einsum("emw,ewh->mh", a, w_down,
+                               preferred_element_type=jnp.float32)
+        return mixed.astype(n.dtype), group_sizes
+    with jax.named_scope("policy/dispatch"):
+        order = jnp.argsort(top_i.reshape(-1), stable=True)
         rows = n[order // k]
-        group_sizes = jnp.zeros(E, jnp.int32).at[expert].add(1)
     with jax.named_scope("policy/experts"):
         gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
         up = jax.lax.ragged_dot(rows, w_up, group_sizes)
@@ -186,6 +239,17 @@ class OlmoeNetwork(nn.Module):
                          jnp.zeros(shape, self.compute_dtype))
                         for _ in range(self.num_layers)),
             "pos": jnp.zeros(batch_size, jnp.int32),
+        }
+
+    def decode_counters(self, batch_size: int) -> dict:
+        """What a decode step of `batch_size` rows is, from its static
+        shape: the mean rows an expert group holds and whether the experts
+        multiply in the batched form (1.0) or the grouped one (0.0)."""
+        k, E = self.experts_per_token, self.num_experts
+        return {
+            "decode_rows_per_expert": batch_size * k / E,
+            "decode_experts_batched": float(
+                experts_batched(batch_size, k, E)),
         }
 
     def __call__(self, obs, state, reset):

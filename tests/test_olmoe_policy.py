@@ -7,6 +7,7 @@ outputs as they were before any of it.
 """
 
 import os
+import subprocess
 import sys
 
 import jax
@@ -22,7 +23,8 @@ if BENCH not in sys.path:
 from lib import reference_olmoe as reference  # noqa: E402
 
 from ray_tpu.models import catalog  # noqa: E402
-from ray_tpu.models.transformer import dropless_experts  # noqa: E402
+from ray_tpu.models.transformer import (  # noqa: E402
+    dropless_experts, experts_batched)
 from ray_tpu.rllib import sample_batch as sb  # noqa: E402
 from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
 from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss  # noqa: E402
@@ -159,38 +161,141 @@ def test_prefill_then_decode_and_reset_inside_a_fragment():
         assert reference.relative_error(step[:, 0], both[:, t]) < 1e-5
 
 
-@pytest.mark.parametrize("routing", ["one_expert_takes_all",
-                                     "one_expert_takes_none", "random"])
-def test_dropless_dispatch_equals_dense_masked_loop(routing):
-    """Sort, grouped product, un-sort == every expert on every token times
-    a 0/1 mask, whatever the group sizes (a full group, an empty one)."""
-    M, H, W, E, k = 24, 16, 8, 4, 2
+ROUTINGS = ["one_expert_takes_all", "one_expert_takes_none", "random"]
+# The form is forced by the shape, as the program's is: (M, E) on either
+# side of `experts_batched` at k = 2.
+FORM_SHAPES = {"batched": (24, 4), "grouped": (1024, 8)}
+# Of the output's scale. float32: summation order (both forms read under
+# 1e-6). bfloat16: operands and intermediate products are rounded to 8 bits
+# of mantissa, so the block's own limit against the float32 reference; both
+# forms read 0.6-0.8 % forward and 0.7-1.3 % in the gradients here.
+FORM_TOLERANCE = {"f32": 2e-4, "bf16": reference.TOLERANCE}
+# The three routings in the batched form in float32 keep the ids they had
+# before there were two forms.
+FORM_CASES = [
+    pytest.param(routing, form, dtype,
+                 id=routing if (form, dtype) == ("batched", "f32")
+                 else f"{routing}-{form}-{dtype}")
+    for form in FORM_SHAPES for dtype in FORM_TOLERANCE
+    for routing in ROUTINGS]
+
+
+def expert_case(routing, form, dtype):
+    """Seeded rows, weights and a routing for `dropless_experts`, in the
+    shape that takes `form`; (n, top_p, top_i, w_gate, w_up, w_down)."""
+    (M, E), (H, W, k) = FORM_SHAPES[form], (16, 8, 2)
+    assert experts_batched(M, k, E) == (form == "batched")
+    cd = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
     rng = np.random.default_rng(0)
-    n = jnp.asarray(rng.normal(size=(M, H)), jnp.float32)
-    w_gate, w_up = (jnp.asarray(rng.normal(size=(E, H, W)), jnp.float32)
+    n = jnp.asarray(rng.normal(size=(M, H)), cd)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(E, H, W)), cd)
                     for _ in range(2))
-    w_down = jnp.asarray(rng.normal(size=(E, W, H)), jnp.float32)
+    w_down = jnp.asarray(rng.normal(size=(E, W, H)), cd)
     if routing == "one_expert_takes_all":
         top_i = np.stack([np.full(M, 2), rng.integers(0, 2, M)], axis=1)
     elif routing == "one_expert_takes_none":
         top_i = np.stack([rng.permutation(M) % 3, np.full(M, 0)], axis=1)
-        top_i[:, 1] = (top_i[:, 0] + 1) % 3  # expert 3 gets nothing
+        top_i[:, 1] = (top_i[:, 0] + 1) % 3  # experts from 3 on get nothing
     else:
         top_i = np.stack([rng.permutation(E)[:k] for _ in range(M)])
     top_i = jnp.asarray(top_i, jnp.int32)
     top_p = jnp.asarray(rng.uniform(0.05, 0.5, size=(M, k)), jnp.float32)
-    got, group_sizes = dropless_experts(n, top_p, top_i, w_gate, w_up, w_down)
-    want = jnp.zeros((M, H))
-    for e in range(E):
+    return n, top_p, top_i, w_gate, w_up, w_down
+
+
+def dense_masked_loop(n, top_p, top_i, w_gate, w_up, w_down):
+    """Every expert on every token times its weight or 0, in float32."""
+    n, w_gate, w_up, w_down = (
+        a.astype(jnp.float32) for a in (n, w_gate, w_up, w_down))
+    want = jnp.zeros(n.shape)
+    for e in range(w_gate.shape[0]):
         weight = jnp.sum(jnp.where(top_i == e, top_p, 0.0), axis=1)
         out = (jax.nn.silu(n @ w_gate[e]) * (n @ w_up[e])) @ w_down[e]
         want = want + weight[:, None] * out
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    return want
+
+
+@pytest.mark.parametrize("routing,form,dtype", FORM_CASES)
+def test_dropless_dispatch_equals_dense_masked_loop(routing, form, dtype):
+    """Both forms of the expert product (sort, grouped product, un-sort;
+    batched products over all experts) == every expert on every token times
+    a 0/1 mask, whatever the group sizes (a full group, an empty one)."""
+    case = expert_case(routing, form, dtype)
+    got, group_sizes = dropless_experts(*case)
+    assert got.dtype == case[0].dtype
+    assert reference.relative_error(
+        got.astype(jnp.float32), dense_masked_loop(*case)) \
+        <= FORM_TOLERANCE[dtype]
+    M, k = case[2].shape
     assert int(jnp.sum(group_sizes)) == M * k
     if routing == "one_expert_takes_all":
         assert int(group_sizes[2]) == M
     if routing == "one_expert_takes_none":
-        assert int(group_sizes[3]) == 0
+        assert int(jnp.sum(group_sizes[3:])) == 0
+
+
+@pytest.mark.parametrize("dtype", FORM_TOLERANCE)
+@pytest.mark.parametrize("form", FORM_SHAPES)
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_dropless_dispatch_gradients_equal_dense_masked_loop(
+        routing, form, dtype):
+    """Both forms are differentiable, and their gradients with respect to
+    the rows and the three weight tensors are the dense masked loop's."""
+    n, top_p, top_i, *weights = expert_case(routing, form, dtype)
+    target = jnp.asarray(
+        np.random.default_rng(1).normal(size=n.shape), jnp.float32)
+
+    def loss(experts):
+        return lambda n, *w: jnp.sum(
+            experts(n, top_p, top_i, *w).astype(jnp.float32) * target)
+
+    got = jax.grad(loss(lambda *a: dropless_experts(*a)[0]),
+                   argnums=(0, 1, 2, 3))(n, *weights)
+    want = jax.grad(loss(dense_masked_loop), argnums=(0, 1, 2, 3))(
+        n, *weights)
+    for name, g, w in zip(("n", "w_gate", "w_up", "w_down"), got, want):
+        assert g.dtype == n.dtype
+        assert reference.relative_error(g.astype(jnp.float32), w) \
+            <= FORM_TOLERANCE[dtype], name
+
+
+def test_the_form_is_chosen_from_the_static_shape():
+    """At the published widths a decode step of 128 sequences (16 rows an
+    expert) traces to no grouped product, the learner's causal pass over a
+    minibatch of 8,192 tokens to three; nothing but shapes is built."""
+    net = dict(NET, vocab_size=50304, hidden_size=2048,
+               num_attention_heads=16, num_key_value_heads=16,
+               num_hidden_layers=1, num_experts=64, num_experts_per_tok=8,
+               intermediate_size=1024, max_position_embeddings=1024)
+    model = catalog.get_model(None, net["vocab_size"], {
+        "custom_model": "olmoe", "custom_model_config": net})
+    assert experts_batched(128, 8, 64) and not experts_batched(8192, 8, 64)
+    # No decode flag: a causal pass over 4 tokens is batched too, a decode
+    # of 2,048 sequences grouped.
+    assert experts_batched(4, 8, 64) and not experts_batched(2048, 8, 64)
+    assert model.decode_counters(128) == {
+        "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0}
+    assert model.decode_counters(2048)["decode_experts_batched"] == 0.0
+
+    def shapes(b, t):
+        return (jax.ShapeDtypeStruct((b, t), jnp.int32),
+                jax.eval_shape(lambda: model.initial_state(b)),
+                jax.ShapeDtypeStruct((b, t), jnp.float32))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), *shapes(1, 1))
+    decode = str(jax.make_jaxpr(model.apply)(params, *shapes(128, 1)))
+    learn = str(jax.make_jaxpr(model.apply)(params, *shapes(8, 1024)))
+    assert decode.count("= ragged_dot_general[") == 0
+    assert learn.count("= ragged_dot_general[") == 3
+
+
+def test_policies_without_experts_never_import_the_transformer():
+    """The Nature-CNN cells' entry point does not load the module."""
+    subprocess.run(
+        [sys.executable, "-c",
+         "import ray_tpu.rllib.agents.impala, sys; "
+         "assert 'ray_tpu.models.transformer' not in sys.modules"],
+        check=True, timeout=300,
+        cwd=os.path.dirname(BENCH), env=dict(os.environ, JAX_PLATFORMS="cpu"))
 
 
 # -- the loss -------------------------------------------------------------
@@ -305,6 +410,11 @@ def test_token_trainer_trains_on_the_fused_path(token_trainer):
     # importance ratios have left 1.
     assert stats["is_ratio_max"] > 1.0
     assert stats["expert_load_max"] >= stats["expert_load_mean"] > 0
+    # The rollout's decode step, from its static shape: 8 rows to 2 of 8
+    # experts, multiplied in the batched form.
+    kept = token_trainer.optimizer.learner_stats
+    assert kept["decode_rows_per_expert"] == 2.0
+    assert kept["decode_experts_batched"] == 1.0
 
 
 def test_wide_action_space_keeps_logp_not_logits():
@@ -394,5 +504,6 @@ def test_nature_cnn_anakin_outputs_unchanged():
                 assert stats[key] == pytest.approx(value, rel=1e-4,
                                                    abs=1e-6), key
             assert stats["is_ratio_max"] == 1.0  # one update: on-policy
+            assert not [k for k in stats if k.startswith("decode_")]
     finally:
         trainer.stop()
