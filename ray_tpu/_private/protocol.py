@@ -525,5 +525,13 @@ def connect(path: str, my_addr: str, handler: Callable,
     hello = {"kind": "hello", "addr": my_addr}
     if hello_extra:
         hello.update(hello_extra)
-    _send_msg(sock, pickle.dumps(hello, protocol=PICKLE_PROTOCOL))
+    try:
+        _send_msg(sock, pickle.dumps(hello, protocol=PICKLE_PROTOCOL))
+    except OSError as e:
+        # A dying peer's listen backlog accepts the dial and the hello
+        # meets a closed socket: that is a closed connection, which
+        # every caller handles, not a raw EPIPE for user code.
+        sock.close()
+        raise ConnectionClosed(
+            f"{path} closed the connection during the hello: {e}") from e
     return Connection(sock, handler, peer_addr=path, on_close=on_close)

@@ -1051,7 +1051,7 @@ class Runtime:
         # Normal tasks whose results have not all been pushed back yet
         # (task_id -> returns still outstanding): lets the owner answer
         # "is anything producing this object?" without asking the head.
-        self._inflight_tasks: Dict[TaskID, int] = {}
+        self._inflight_tasks: Dict[TaskID, Set[ObjectID]] = {}
         self._freed_returns: Dict[TaskID, Set[ObjectID]] = {}
         self._lineage_lock = make_lock("Runtime._lineage_lock")
         self._lineage_max = config.get("RAY_TPU_LINEAGE_MAX_SPECS")
@@ -1563,7 +1563,7 @@ class Runtime:
                 return False
             self._reconstruct_budget[tid] -= 1
             self._reconstructing.add(tid)
-            self._inflight_tasks[tid] = spec.num_returns
+            self._inflight_tasks[tid] = set(spec.return_ids())
         logger.info("reconstructing lost object %s by re-executing %s",
                     oid.hex()[:16], spec.describe())
         spec.leased = False  # re-execution routes through the head
@@ -2139,7 +2139,7 @@ class Runtime:
         with self._lineage_lock:
             self._result_specs[spec.task_id] = spec
             self._reconstruct_budget[spec.task_id] = max_retries
-            self._inflight_tasks[spec.task_id] = num_returns
+            self._inflight_tasks[spec.task_id] = set(spec.return_ids())
             while len(self._result_specs) > self._lineage_max:
                 old_tid, _ = self._result_specs.popitem(last=False)
                 self._reconstruct_budget.pop(old_tid, None)
@@ -2284,7 +2284,7 @@ class Runtime:
     def _on_leased_result(self, tid: TaskID):
         """A leased task completed: free its pipeline slot, feed the
         lease more queued work, start the idle linger clock."""
-        next_push = None
+        next_push = ()
         with self._lease_lock:
             entry = self._leased_tid_addr.pop(tid, None)
             if entry is None:
@@ -2300,19 +2300,27 @@ class Runtime:
             sample = (time.monotonic() - t_push) / max(1, pos)
             g.ema_latency_s = sample if g.ema_latency_s is None \
                 else 0.8 * g.ema_latency_s + 0.2 * sample
-            g.leases.get(addr, set()).discard(tid)
-            # Refill toward the (possibly freshly-deepened) target depth.
-            depth = self._lease_depth(g)
-            while g.queued and len(g.leases.get(addr, ())) < depth:
-                spec = g.queued.popleft()
-                self._record_leased_locked(g, addr, spec)
-                if next_push is None:
-                    next_push = []
-                next_push.append((addr, spec))
-            if not g.leases.get(addr) and not g.queued:
-                g.idle_since[addr] = time.monotonic()
-        for item in (next_push or ()):
+            next_push = self._free_lease_slot_locked(g, addr, tid)
+        for item in next_push:
             self._push_leased(*item)
+
+    def _free_lease_slot_locked(self, g: "_LeaseGroup", addr: str,
+                                tid: TaskID) -> list:
+        """Caller holds _lease_lock. `tid` no longer occupies a slot of
+        the lease on `addr`: refill the lease toward the (possibly
+        freshly-deepened) target depth from the queue, or start its
+        idle clock so that it lingers out and its resources return to
+        the head. Returns the (addr, spec) pairs to push."""
+        g.leases.get(addr, set()).discard(tid)
+        pushes = []
+        depth = self._lease_depth(g)
+        while g.queued and len(g.leases.get(addr, ())) < depth:
+            spec = g.queued.popleft()
+            self._record_leased_locked(g, addr, spec)
+            pushes.append((addr, spec))
+        if not g.leases.get(addr) and not g.queued:
+            g.idle_since[addr] = time.monotonic()
+        return pushes
 
     def _on_lease_worker_lost(self, addr: str):
         """A leased worker died/vanished: retry its in-flight tasks via
@@ -2460,8 +2468,15 @@ class Runtime:
             spec = pend.pop(tid, None) if pend is not None else None
             key = self._lease_by_addr.get(addr)
             g = self._lease_groups.get(key) if key is not None else None
-            if g is not None:
-                g.leases.get(addr, set()).discard(tid)
+            # The slot is free again: without the refill the tasks
+            # queued behind this one would wait for ever, and without
+            # the idle clock a lease that holds its node's whole
+            # resources would never return them, so the head could
+            # not place the resubmission below.
+            next_push = self._free_lease_slot_locked(g, addr, tid) \
+                if g is not None else ()
+        for item in next_push:
+            self._push_leased(*item)
         if spec is None:
             return
         if spec.retries_used < spec.max_retries:
@@ -2926,48 +2941,52 @@ class Runtime:
                 if entry is not None and entry.pending_push is None:
                     entry.pending_push = msg
                     return
-        # Idempotence gate: delivery is at-least-once (duplicated wire
+        # Idempotence: delivery is at-least-once (duplicated wire
         # frames, a probe-triggered resubmit racing the original push,
-        # reconstruction racing a slow result). The FIRST delivery
-        # wins and runs the completion bookkeeping exactly once; a
-        # replay must not double-decrement the in-flight count, feed
-        # the lease pipeline twice, or overwrite a delivered value.
-        # One exception: a real result may upgrade an error cell (a
-        # task wrongly declared lost whose result then arrives) —
-        # cell-only, no second round of bookkeeping.
-        upgrade_only = False
+        # reconstruction racing a slow result). The first delivery of
+        # an awaited result runs the completion bookkeeping, and every
+        # step of it is keyed so that a replay finds nothing left to
+        # do: it must not complete the task twice, feed the lease
+        # pipeline twice, or overwrite a delivered value. Whether a
+        # cell exists does NOT say whether the result was delivered:
+        # the bytes of a result in the shared store can be picked up
+        # (a striped transfer sealing, get() finding the sealed entry)
+        # before the push_result that announces them is handled, and
+        # that push still has to free the task's lease slot. A real
+        # result may upgrade an error cell (a task wrongly declared
+        # lost whose result then arrives).
+        tid = oid.task_id()
         existing = self.memory.get_if_exists(oid)
-        if existing is not None:
-            prior: _Cell = existing.value
-            if prior.kind != "error" or msg.get("error") is not None:
-                from . import metrics as metrics_mod
-                metrics_mod.inc("push_result_duplicates")
-                return
-            upgrade_only = True
-        if msg.get("error") is not None:
-            cell = _Cell("error", msg["error"])
-        elif msg.get("in_shm"):
-            cell = _Cell("shm")
-        else:
-            cell = _Cell("raw", msg["data"])
-        self.memory.put(oid, cell)
-        if not upgrade_only:
-            # Clear pending-actor-task tracking + release arg pins.
-            with self._pending_lock:
-                for pending in self._pending_to_addr.values():
-                    pending.pop(oid.task_id(), None)
-            self._unpin_task_args(oid.task_id())
-            with self._lineage_lock:
-                self._reconstructing.discard(oid.task_id())
-                left = self._inflight_tasks.get(oid.task_id())
-                task_complete = left is not None and left <= 1
-                if left is not None:
-                    if left <= 1:
-                        self._inflight_tasks.pop(oid.task_id(), None)
-                    else:
-                        self._inflight_tasks[oid.task_id()] = left - 1
-            if task_complete or left is None:
-                self._on_leased_result(oid.task_id())
+        keep_cell = existing is not None and (
+            existing.value.kind != "error" or msg.get("error") is not None)
+        if not keep_cell:
+            if msg.get("error") is not None:
+                cell = _Cell("error", msg["error"])
+            elif msg.get("in_shm"):
+                cell = _Cell("shm")
+            else:
+                cell = _Cell("raw", msg["data"])
+            self.memory.put(oid, cell)
+        # Clear pending-actor-task tracking + release arg pins.
+        with self._pending_lock:
+            for pending in self._pending_to_addr.values():
+                pending.pop(tid, None)
+        self._unpin_task_args(tid)
+        with self._lineage_lock:
+            awaited = self._inflight_tasks.get(tid)
+            first = awaited is not None and oid in awaited
+            if first:
+                self._reconstructing.discard(tid)
+                awaited.discard(oid)
+                if not awaited:
+                    del self._inflight_tasks[tid]
+            task_done = not awaited  # untracked, or its last result
+        if keep_cell and not first:
+            from . import metrics as metrics_mod
+            metrics_mod.inc("push_result_duplicates")
+            return
+        if task_done:
+            self._on_leased_result(tid)
         # Forward to any borrower that asked before we had it.
         with self._waiters_lock:
             waiters = self._object_waiters.pop(oid, ())

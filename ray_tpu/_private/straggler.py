@@ -20,6 +20,13 @@ with sigma = 1.4826 * MAD (the normal-consistency constant), floored at
 a fraction of the median so a fleet of identical actors (MAD = 0) still
 flags a genuinely divergent one instead of dividing by zero.
 
+A reading becomes a flag only when the next window repeats it
+(CONFIRM_WINDOWS): throughput is a count of whole fragments over the
+window, so in a short window on a busy host a healthy actor thread that
+was not scheduled reads one fragment, or none, below its peers, and a
+single window cannot tell that from a slow actor. A slow actor is slow
+in every window; a starved one is not starved twice in a row.
+
 Consumers (rllib/optimizers/async_samples_optimizer.py): verdicts bump
 `straggler_flags_total` (+ a per-actor `straggler_flags.<tag>` series),
 annotate the flagged worker's task records via task_events.ANNOTATE,
@@ -50,6 +57,8 @@ MAD_SIGMA = 1.4826
 # sigma floor as a fraction of |median|: identical fleets (MAD = 0)
 # still flag an actor deviating by more than k * floor * median.
 SIGMA_FLOOR_FRAC = 0.05
+# Consecutive windows an actor must read as an outlier to be flagged.
+CONFIRM_WINDOWS = 2
 
 
 def median(values: List[float]) -> float:
@@ -67,15 +76,17 @@ def robust_sigma(values: List[float], med: Optional[float] = None) -> float:
 
 
 class StragglerDetector:
-    """Stateless per-window verdicts + cumulative per-actor flag counts.
+    """Per-window verdicts + cumulative per-actor flag counts.
 
     `update()` takes one window's per-actor samples:
 
         {tag: {"throughput": steps/s, "fetch_latency_s": s-or-None}}
 
-    and returns {tag: verdict} where a verdict carries `flagged`, the
-    `reasons` that tripped ("throughput" / "fetch_latency"), and the
-    fleet baseline it was judged against.
+    and returns {tag: verdict} where a verdict carries the `reasons`
+    that tripped in this window ("throughput" / "fetch_latency"), the
+    fleet baseline it was judged against, `outlier_windows` (how many
+    windows in a row it has tripped) and `flagged`: tripped in
+    CONFIRM_WINDOWS windows in a row.
     """
 
     def __init__(self, k: Optional[float] = None,
@@ -86,15 +97,17 @@ class StragglerDetector:
             if min_peers is None else min_peers
         self.flag_counts: Dict[str, int] = {}
         self.windows = 0
+        self._outlier_windows: Dict[str, int] = {}
 
     def update(self, samples: Dict[str, dict]) -> Dict[str, dict]:
         self.windows += 1
         out: Dict[str, dict] = {
-            tag: {"flagged": False, "reasons": [],
+            tag: {"flagged": False, "reasons": [], "outlier_windows": 0,
                   "throughput": s.get("throughput"),
                   "fetch_latency_s": s.get("fetch_latency_s")}
             for tag, s in samples.items()}
         if len(samples) < max(2, self.min_peers):
+            self._outlier_windows = {}
             return out
 
         thr = {t: s["throughput"] for t, s in samples.items()
@@ -105,7 +118,6 @@ class StragglerDetector:
             for tag, v in thr.items():
                 out[tag]["throughput_median"] = med
                 if v < med - self.k * sigma:
-                    out[tag]["flagged"] = True
                     out[tag]["reasons"].append("throughput")
 
         lat = {t: s["fetch_latency_s"] for t, s in samples.items()
@@ -116,10 +128,14 @@ class StragglerDetector:
             for tag, v in lat.items():
                 out[tag]["fetch_latency_median"] = med
                 if v > med + self.k * sigma:
-                    out[tag]["flagged"] = True
-                    if "fetch_latency" not in out[tag]["reasons"]:
-                        out[tag]["reasons"].append("fetch_latency")
+                    out[tag]["reasons"].append("fetch_latency")
 
+        self._outlier_windows = {
+            t: self._outlier_windows.get(t, 0) + 1
+            for t, v in out.items() if v["reasons"]}
+        for tag, n in self._outlier_windows.items():
+            out[tag]["outlier_windows"] = n
+            out[tag]["flagged"] = n >= CONFIRM_WINDOWS
         flagged = [t for t, v in out.items() if v["flagged"]]
         if flagged:
             from . import metrics

@@ -20,6 +20,10 @@ from typing import Optional
 import numpy as np
 
 
+class ExternalEnvClosed(RuntimeError):
+    """The sampler that consumed this env has stopped (`close()`)."""
+
+
 class ExternalEnv(threading.Thread):
     def __init__(self, observation_space, action_space):
         super().__init__(daemon=True, name="external-env-run")
@@ -37,6 +41,7 @@ class ExternalEnv(threading.Thread):
         self._pending_logged_action = None
         self._awaiting_action = False
         self._pending_obs = None
+        self._closed = threading.Event()
 
     # -- user-side API (called from run()) -------------------------------
     def run(self):
@@ -48,9 +53,8 @@ class ExternalEnv(threading.Thread):
 
     def get_action(self, episode_id: str, observation):
         """Block until the policy provides an action for `observation`."""
-        self._obs_q.put(("obs", observation, self._take_reward(),
-                         self._pending_logged_action))
-        action = self._action_q.get()
+        self._put_event("obs", observation)
+        action = self._take_action()
         self._pending_logged_action = None
         return action
 
@@ -61,18 +65,40 @@ class ExternalEnv(threading.Thread):
         into the recorded batch and recomputes logp under the current
         policy (parity: the reference's ExternalEnv stores the logged
         action in the trajectory, `rllib/env/external_env.py`)."""
-        self._obs_q.put(("obs", observation, self._take_reward(),
-                         self._pending_logged_action))
-        self._action_q.get()  # discard the policy's choice
+        self._put_event("obs", observation)
+        self._take_action()  # discard the policy's choice
         self._pending_logged_action = action
 
     def log_returns(self, episode_id: str, reward: float):
         self._episode_reward += float(reward)
 
     def end_episode(self, episode_id: str, observation):
-        self._obs_q.put(("done", observation, self._take_reward(),
-                         self._pending_logged_action))
+        self._put_event("done", observation)
         self._pending_logged_action = None
+
+    def _put_event(self, kind: str, observation):
+        event = (kind, observation, self._take_reward(),
+                 self._pending_logged_action)
+        while True:
+            self._check_open()
+            try:
+                return self._obs_q.put(event, timeout=0.1)
+            except queue.Full:
+                pass
+
+    def _take_action(self):
+        while True:
+            self._check_open()
+            try:
+                return self._action_q.get(timeout=0.1)
+            except queue.Empty:
+                pass
+
+    def _check_open(self):
+        if self._closed.is_set():
+            raise ExternalEnvClosed(
+                "the sampler of this ExternalEnv has stopped: no policy "
+                "will answer this episode")
 
     def _take_reward(self) -> float:
         r = self._episode_reward
@@ -110,7 +136,12 @@ class ExternalEnv(threading.Thread):
         return obs, reward, done, info
 
     def close(self):
-        pass
+        """The sampler has stopped (RolloutWorker.stop): the user
+        loop's request in flight, and every later one, raises
+        ExternalEnvClosed instead of waiting for an action that nobody
+        will choose. Behind a PolicyServer the client sees it as an
+        HTTP 500 at once, not as its own request timeout."""
+        self._closed.set()
 
     def seed(self, seed=None):
         pass

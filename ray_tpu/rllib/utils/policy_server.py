@@ -82,8 +82,12 @@ def _make_handler(external_env, auth_token=None):
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
-            except Exception:
-                self.send_error(500, traceback.format_exc())
+            except Exception as e:
+                # The status line names the error (the client's
+                # HTTPError shows only that line); the body has the
+                # traceback.
+                reason = f"{type(e).__name__}: {e}".splitlines()[0]
+                self.send_error(500, reason, traceback.format_exc())
 
         def log_message(self, *args):
             pass

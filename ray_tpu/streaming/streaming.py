@@ -120,8 +120,13 @@ class EdgeSender:
                     # retention — retired-but-uncovered first, then the
                     # unacked window (this item included) — and keep
                     # draining the re-pushed stream.
-                    self.covered = max(self.covered,
-                                       int(ack["replay_from"]))
+                    # The receiver's own count is the authority, also
+                    # DOWNWARDS: without checkpoints a restart forgets
+                    # what the old incarnation acked, and a `covered`
+                    # kept above it hides the hole from `_replay`, which
+                    # then never marks the resync and is refused again,
+                    # for ever and at full speed.
+                    self.covered = int(ack["replay_from"])
                     self._trim_retired()
                     self._replay()
                     continue

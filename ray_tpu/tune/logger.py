@@ -1,8 +1,7 @@
 """Result loggers.
 
 Parity: `python/ray/tune/logger.py` — `JsonLogger` (:100), `CSVLogger`
-(:277), `TBXLogger` (:315), `UnifiedLogger` (:383). TensorBoard output
-uses torch's SummaryWriter when available (the image has torch).
+(:277), `TBXLogger` (:315), `UnifiedLogger` (:383).
 """
 
 from __future__ import annotations
@@ -11,6 +10,9 @@ import csv
 import json
 import logging
 import os
+import socket
+import struct
+import time
 from typing import List, Optional
 
 import numpy as np
@@ -108,32 +110,86 @@ class CSVLogger(Logger):
         self._file.close()
 
 
+def _crc32c_table():
+    table = []
+    for n in range(256):
+        for _ in range(8):
+            n = (n >> 1) ^ (0x82F63B78 if n & 1 else 0)
+        table.append(n)
+    return table
+
+
+_CRC32C = _crc32c_table()
+
+
+def _masked_crc32c(data: bytes) -> bytes:
+    crc = 0xFFFFFFFF
+    for b in data:
+        crc = _CRC32C[(crc ^ b) & 0xFF] ^ (crc >> 8)
+    crc ^= 0xFFFFFFFF
+    masked = (((crc >> 15) | (crc << 17)) + 0xA282EAD8) & 0xFFFFFFFF
+    return struct.pack("<I", masked)
+
+
+def _varint(n: int) -> bytes:
+    out = bytearray()
+    while n > 0x7F:
+        out.append((n & 0x7F) | 0x80)
+        n >>= 7
+    out.append(n)
+    return bytes(out)
+
+
+def _len_field(number: int, payload: bytes) -> bytes:
+    return _varint(number << 3 | 2) + _varint(len(payload)) + payload
+
+
+def _event(wall_time: float, step: int = 0, file_version: str = "",
+           scalars=()) -> bytes:
+    """One serialized `tensorboard.Event` (event.proto: wall_time=1
+    double, step=2 int64, file_version=3, summary=5; summary.proto:
+    Summary.value=1, Value.tag=1, Value.simple_value=2 float)."""
+    out = b"\x09" + struct.pack("<d", wall_time)
+    if step:
+        out += b"\x10" + _varint(step)
+    if file_version:
+        out += _len_field(3, file_version.encode())
+    if scalars:
+        out += _len_field(5, b"".join(
+            _len_field(1, _len_field(1, tag.encode())
+                       + b"\x15" + struct.pack("<f", value))
+            for tag, value in scalars))
+    return out
+
+
 class TBXLogger(Logger):
-    """TensorBoard scalars via torch.utils.tensorboard (optional)."""
+    """TensorBoard scalars: an `events.out.tfevents.*` file of TFRecord
+    frames written here byte by byte, so a trial's process imports no
+    framework for it (torch's SummaryWriter cost every trial ~10 s)."""
 
     def _init(self):
-        try:
-            from torch.utils.tensorboard import SummaryWriter
-            self._writer = SummaryWriter(self.logdir)
-        except Exception:
-            logger.debug("tensorboard writer unavailable; TBXLogger off")
-            self._writer = None
+        now = time.time()
+        self._file = open(os.path.join(
+            self.logdir, "events.out.tfevents.%010d.%s.%d" % (
+                now, socket.gethostname(), os.getpid())), "ab")
+        self._write(_event(now, file_version="brain.Event:2"))
+
+    def _write(self, event: bytes):
+        header = struct.pack("<Q", len(event))
+        self._file.write(header + _masked_crc32c(header)
+                         + event + _masked_crc32c(event))
+        self._file.flush()
 
     def on_result(self, result: dict):
-        if self._writer is None:
-            return
-        step = result.get("training_iteration", 0)
-        for k, v in _flatten(result).items():
-            if isinstance(v, (int, float, np.number)) and np.isfinite(v):
-                self._writer.add_scalar(k, float(v), global_step=step)
-
-    def flush(self):
-        if self._writer is not None:
-            self._writer.flush()
+        scalars = [(k, float(v)) for k, v in _flatten(result).items()
+                   if isinstance(v, (int, float, np.number))
+                   and np.isfinite(v)]
+        self._write(_event(time.time(),
+                           int(result.get("training_iteration", 0)),
+                           scalars=scalars))
 
     def close(self):
-        if self._writer is not None:
-            self._writer.close()
+        self._file.close()
 
 
 DEFAULT_LOGGERS = (JsonLogger, CSVLogger, TBXLogger)

@@ -3,10 +3,25 @@
 Forces JAX onto a virtual 8-device CPU platform (mirroring the reference's
 in-process multi-node `cluster_utils.Cluster` trick, SURVEY.md §4.2: fake
 topology so collective code runs in CI without real hardware).
+
+Waiting, the suite's one rule: nothing under `tests/` waits without a
+bound. Every test runs under `TIME_LIMIT_S` (a test that truly needs more
+carries `@pytest.mark.time_limit(seconds)`), so a `get`, `wait`, `join()`
+or `proc.wait()` that blocks on another process may omit its own timeout
+and is then left to that limit by design; it passes one where the test
+asserts how long the thing may take. A wait for "until X has happened" is
+`wait_until(lambda: X, timeout)`, never a `time.sleep` of a guessed
+length; a sleep stays where it IS the test (a heartbeat interval, a chaos
+delay, a load held for a duration).
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
 import sys
+import tempfile
+import time
 
 # Must happen before jax initializes its backend.
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -29,8 +44,102 @@ except Exception:
 
 import pytest
 
+# Seconds one test may take, set-up and teardown included: more than twice
+# the slowest tier-1 test of a whole six-worker run (CHANGES.md, PR 30).
+TIME_LIMIT_S = 180
+# After the limit a raise has this long to unwind the test and its
+# fixtures; then the worker process dumps its stacks and exits (xdist
+# reports the test failed and starts another worker).
+UNWIND_S = 60
+
+
+def wait_until(predicate, timeout, msg="condition"):
+    """Poll `predicate` until it returns something true, and return that;
+    fail the test, naming `msg`, when `timeout` seconds pass first."""
+    deadline = time.monotonic() + timeout
+    while True:
+        value = predicate()
+        if value:
+            return value
+        if time.monotonic() >= deadline:
+            pytest.fail(f"{msg}: not met within {timeout} s")
+        time.sleep(0.02)
+
+
+def _all_stacks() -> str:
+    with tempfile.TemporaryFile(mode="w+") as f:
+        faulthandler.dump_traceback(file=f, all_threads=True)
+        f.seek(0)
+        return f.read()
+
+
+def _stop_runtime_and_children():
+    """The runtime's processes are this process's descendants: a head
+    spawns its workers, a `Cluster` its node agents, an agent its workers.
+    Shut down in order, then kill whatever of them is still there."""
+    import psutil
+    import ray_tpu
+    children = psutil.Process().children(recursive=True)
+    try:
+        ray_tpu.shutdown()
+    except BaseException as e:  # the limit again, or a wedged runtime
+        sys.stderr.write(f"ray_tpu.shutdown() after a time limit: {e!r}\n")
+    for proc in children:
+        try:
+            proc.kill()
+        except psutil.NoSuchProcess:
+            pass
+    psutil.wait_procs(children, timeout=10)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    """The suite's only time limit. A timer in this (the main) thread
+    raises into the test at its limit with every thread's stack in the
+    message, and again every `UNWIND_S / 2` while fixtures unwind; behind
+    it faulthandler's own thread ends a process whose main thread a raise
+    cannot reach (stuck in native code), its dump going to
+    `<tmpdir>/ray_tpu_tests_hard_limit.<pid>.log`."""
+    marker = item.get_closest_marker("time_limit")
+    limit = float(marker.args[0]) if marker else TIME_LIMIT_S
+    fired = []
+
+    def on_limit(signum, frame):
+        signal.setitimer(signal.ITIMER_REAL, UNWIND_S / 2)
+        stacks = "" if fired else _all_stacks()
+        fired.append(True)
+        pytest.fail(f"{item.nodeid} passed its time limit of {limit:g} s"
+                    f"\n{stacks}", pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, on_limit)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    faulthandler.dump_traceback_later(limit + UNWIND_S, exit=True,
+                                      file=item.config.hard_limit_log)
+    try:
+        yield
+        if fired:
+            _stop_runtime_and_children()
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+def pytest_unconfigure(config):
+    # A process that gets here was not ended by the hard limit.
+    config.hard_limit_log.close()
+    with contextlib.suppress(FileNotFoundError):  # a tmp cleaner's
+        os.unlink(config.hard_limit_log.name)
+
 
 def pytest_configure(config):
+    config.hard_limit_log = open(os.path.join(
+        tempfile.gettempdir(),
+        f"ray_tpu_tests_hard_limit.{os.getpid()}.log"), "w")
+    config.addinivalue_line(
+        "markers",
+        "time_limit(seconds): this test's own time limit, in place of "
+        "conftest.TIME_LIMIT_S")
     config.addinivalue_line(
         "markers",
         "soak: long-running chaos workload (opt-in via RAY_TPU_SOAK=1; "

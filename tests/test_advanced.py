@@ -72,7 +72,6 @@ def test_named_actor_name_reuse_after_death(ray_start):
     h = A.options(name="reusable").remote()
     assert ray.get(h.ping.remote()) == "a"
     ray.kill(h)
-    time.sleep(1.0)
     deadline = time.time() + 30
     while True:
         try:

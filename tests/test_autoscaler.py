@@ -8,6 +8,7 @@ verbs (reference scripts.py:622).
 import time
 
 import pytest
+from conftest import wait_until
 
 from ray_tpu.autoscaler import LoadMetrics, NodeProvider, StandardAutoscaler
 
@@ -201,10 +202,11 @@ class TestDemandShape:
                 return 1
 
             ref = needs_gpux.remote()  # unplaceable: no GPUX anywhere
-            time.sleep(1.0)
-            load = node_mod._node.head.cluster_load()
-            assert any(d.get("GPUX") == 1.0
-                       for d in load["pending_demand"]), load
+            wait_until(
+                lambda: any(
+                    d.get("GPUX") == 1.0 for d in
+                    node_mod._node.head.cluster_load()["pending_demand"]),
+                timeout=30)
             del ref
         finally:
             ray_tpu.shutdown()

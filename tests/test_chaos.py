@@ -228,6 +228,38 @@ class TestIdempotence:
                             "data": serialization.dumps("real")})
         assert ray.get(r, timeout=60) == "real"
 
+    def test_result_picked_up_before_its_push_still_completes(
+            self, ray_start):
+        """The bytes of a result in the shared store can reach a cell
+        before the push_result that announces them is handled (a striped
+        transfer sealing; get() finding the sealed entry). That push is
+        the task's first delivery, not a replay: it must complete the
+        task and free its lease slot (PR 30)."""
+        from ray_tpu._private import worker_state as ws
+        from ray_tpu._private.ids import TaskID
+        from ray_tpu._private.runtime import _Cell
+        rt = ws.get_runtime()
+        tid = TaskID.generate()
+        first, second = tid.object_id(0), tid.object_id(1)
+        with rt._lineage_lock:
+            rt._inflight_tasks[tid] = {first, second}
+        with rt._lease_lock:
+            rt._leased_tid_addr[tid] = ("tcp://nowhere", time.monotonic(), 1)
+        picked_up = _Cell("value", "the result")
+        rt.memory.put(first, picked_up)
+        rt._on_push_result({"object_id": first, "in_shm": True})
+        assert rt.memory.get_if_exists(first).value is picked_up
+        assert rt._inflight_tasks[tid] == {second}
+        # Its replay changes nothing ...
+        rt._on_push_result({"object_id": first, "in_shm": True})
+        assert rt._inflight_tasks[tid] == {second}
+        assert tid in rt._leased_tid_addr
+        # ... and the task's last result frees the slot.
+        rt.memory.put(second, _Cell("value", "the other"))
+        rt._on_push_result({"object_id": second, "in_shm": True})
+        assert tid not in rt._inflight_tasks
+        assert tid not in rt._leased_tid_addr
+
     def test_duplicate_stripe_chunk_after_seal_ignored(self, ray_start):
         """A replayed chunk for an already-sealed object (overlapping
         retry stream finishing late) must not re-open a receive buffer

@@ -9,6 +9,8 @@ import subprocess
 import sys
 import time
 
+from conftest import wait_until
+
 from ray_tpu._private.debug import StallWatchdog
 
 
@@ -21,8 +23,10 @@ class TestStallWatchdog:
                 w.beat()
                 time.sleep(0.05)
             assert not w.stalled
-            time.sleep(1.2)  # stop beating
+            # No more beats: the dump comes ...
+            wait_until(lambda: "STALL" in out.getvalue(), timeout=30)
             assert w.stalled
+            time.sleep(0.7)  # ... and two more timeouts bring no second
             text = out.getvalue()
             assert "STALL" in text and "test-loop" in text
             # Exactly one dump per stall.
@@ -62,12 +66,14 @@ def test_sigusr1_dumps_all_thread_stacks():
         line = proc.stdout.readline()
         assert line.startswith("PID")
         pid = int(line.split()[1])
-        time.sleep(0.5)
+        # init() installed the handler before the PID line was printed.
         os.kill(pid, signal.SIGUSR1)
-        time.sleep(1.0)
+        # The dump's first line arrives on stderr; then the process
+        # must still be alive.
+        err = proc.stderr.readline()
         assert proc.poll() is None, "process must survive the dump"
         proc.terminate()
-        _, err = proc.communicate(timeout=20)
+        err += proc.communicate(timeout=20)[1]
         assert "Current thread" in err or "Thread" in err
     finally:
         if proc.poll() is None:

@@ -73,7 +73,6 @@ def test_dead_actor_new_calls_fail(ray_start):
     d = Doomed.remote()
     assert ray.get(d.ping.remote()) == "pong"
     d.die.remote()
-    time.sleep(1.0)
     with pytest.raises(ray.ActorDiedError):
         ray.get(d.ping.remote(), timeout=60)
 
@@ -106,3 +105,26 @@ def test_unpicklable_error_still_reported(ray_start):
 
     with pytest.raises(ray.TaskError):
         ray.get(weird_error.remote(), timeout=60)
+
+
+def test_dial_of_a_dying_peer_is_a_closed_connection(tmp_path, monkeypatch):
+    """A peer that dies between accepting the dial (its listen backlog
+    does that) and reading the hello: `connect` raises ConnectionClosed,
+    which every caller handles (a call to a dying actor then fails with
+    ActorDiedError), not a raw BrokenPipeError (PR 30)."""
+    import socket
+
+    from ray_tpu._private import protocol
+    path = str(tmp_path / "peer.sock")
+    server = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+    server.bind(path)
+    server.listen(1)
+    send_msg = protocol._send_msg
+
+    def peer_dies_first(sock, payload):
+        server.close()
+        send_msg(sock, payload)
+
+    monkeypatch.setattr(protocol, "_send_msg", peer_dies_first)
+    with pytest.raises(protocol.ConnectionClosed):
+        protocol.connect(path, "caller", lambda conn, msg: None)

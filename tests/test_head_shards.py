@@ -19,6 +19,7 @@ import types
 import zlib
 
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 from ray_tpu._private import config, head_shards, metrics, protocol
@@ -30,15 +31,6 @@ from ray_tpu._private.ids import ObjectID
 
 def _counter(name):
     return metrics.snapshot()["counters"].get(name, 0.0)
-
-
-def _wait_until(fn, timeout=10.0, msg="condition"):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if fn():
-            return
-        time.sleep(0.02)
-    pytest.fail(f"timed out waiting for {msg}")
 
 
 @pytest.fixture
@@ -202,8 +194,9 @@ class TestLocationPubSub:
         rpcs0 = _counter("object_dir_rpcs")
         head._h_object_location_add(
             None, {"object_id": oid, "addr": "tcp://a2", "node_id": "n2"})
-        _wait_until(lambda: len(rt._dir_locations(oid) or ()) == 2,
-                    msg="published add delta to reach the client cache")
+        wait_until(lambda: len(rt._dir_locations(oid) or ()) == 2,
+                   timeout=10,
+                   msg="published add delta to reach the client cache")
         assert _counter("object_dir_rpcs") == rpcs0
 
     def test_evict_delta_invalidates_cache(self, ray_start):
@@ -213,14 +206,15 @@ class TestLocationPubSub:
         for addr in ("tcp://e1", "tcp://e2"):
             head._h_object_location_add(
                 None, {"object_id": oid, "addr": addr, "node_id": "nE"})
-        _wait_until(lambda: len(rt._dir_locations(oid) or ()) == 2,
-                    msg="both replicas visible")
+        wait_until(lambda: len(rt._dir_locations(oid) or ()) == 2,
+                   timeout=10, msg="both replicas visible")
         rpcs0 = _counter("object_dir_rpcs")
         head._h_object_location_remove(
             None, {"object_id": oid, "addr": "tcp://e1"})
-        _wait_until(
+        wait_until(
             lambda: [a for a, _ in rt._dir_locations(oid) or ()]
             == ["tcp://e2"],
+            timeout=10,
             msg="published remove delta to invalidate the cached copy")
         assert _counter("object_dir_rpcs") == rpcs0
 
@@ -235,11 +229,12 @@ class TestLocationPubSub:
         head._h_object_location_add(
             None, {"object_id": oid, "addr": dead_addr,
                    "node_id": "nD"})
-        _wait_until(lambda: rt._dir_locations(oid), msg="replica cached")
+        wait_until(lambda: rt._dir_locations(oid), timeout=10,
+                   msg="replica cached")
         rpcs0 = _counter("object_dir_rpcs")
         conn.close()  # head publishes drop_addr on every shard channel
-        _wait_until(lambda: not rt._dir_locations(oid),
-                    msg="drop_addr delta to scrub the dead registrant")
+        wait_until(lambda: not rt._dir_locations(oid), timeout=10,
+                   msg="drop_addr delta to scrub the dead registrant")
         assert _counter("object_dir_rpcs") == rpcs0
 
     def test_cache_disabled_falls_back_to_rpc_per_lookup(self, ray_start):

@@ -119,3 +119,54 @@ class TestLeases:
             assert not ws.get_runtime()._lease_groups
         finally:
             ray_tpu.shutdown()
+
+
+class TestLeasedResultDelivery:
+    def test_large_results_of_whole_node_tasks_all_arrive(self):
+        """Three tasks that each take a node's whole resources and return
+        a result too large to inline: one lease, depth two, the third
+        task queued behind it. The results' bytes reach the caller's
+        store (striped) before or beside the push_result that announces
+        them; that push must still free the lease slot, or the third task
+        is never dispatched (PR 30: a cell already in the memory store
+        made the push a 'duplicate')."""
+        import numpy as np
+        from ray_tpu.cluster_utils import Cluster
+        cluster = Cluster(head_resources={"CPU": 1})
+        try:
+            cluster.add_node(resources={"CPU": 2})
+
+            @ray_tpu.remote(resources={"CPU": 2})
+            def big():
+                return np.zeros(2_000_000, np.uint8)
+
+            out = ray_tpu.get([big.remote() for _ in range(3)], timeout=15)
+            assert [o.nbytes for o in out] == [2_000_000] * 3
+            from ray_tpu._private import metrics
+            assert not metrics.snapshot()["counters"].get(
+                "leased_tasks_recovered")
+        finally:
+            cluster.shutdown()
+
+    def test_recovered_tasks_free_their_slots_and_lease(self, monkeypatch):
+        """Result pushes that ARE lost (chaos drops the first two) while
+        one lease holds all the CPUs and a third task is queued behind it:
+        the probe's recovery must hand a freed slot to the queued task,
+        and let the emptied lease linger out so that the head can place
+        the two resubmissions."""
+        monkeypatch.setenv("RAY_TPU_LEASED_PROBE_S", "1.0")
+        monkeypatch.setenv("RAY_TPU_LEASE_LINGER_S", "0.4")
+        ray_tpu.init(num_cpus=2, chaos="seed=5;exec.after:drop_result:once1;"
+                                       "exec.after:drop_result:once2")
+        try:
+            @ray_tpu.remote(resources={"CPU": 2})
+            def f(x):
+                return x + 1
+
+            out = ray_tpu.get([f.remote(i) for i in range(3)], timeout=30)
+            assert out == [1, 2, 3]
+            from ray_tpu._private import metrics
+            assert metrics.snapshot()["counters"].get(
+                "leased_tasks_recovered", 0) == 2
+        finally:
+            ray_tpu.shutdown()

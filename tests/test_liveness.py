@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 from ray_tpu.exceptions import ObjectLostError
@@ -115,7 +116,8 @@ class TestHeartbeatLiveness:
                 return "done"
 
             ref = pinned.remote()
-            time.sleep(1.0)  # let it dispatch to the tagged node
+            # It has been dispatched to the tagged node.
+            wait_until(lambda: ray_tpu.tasks(state="RUNNING"), timeout=60)
             # Wedge the agent: connection stays open, heartbeats stop.
             os.kill(node.proc.pid, signal.SIGSTOP)
             try:

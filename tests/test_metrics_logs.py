@@ -14,6 +14,7 @@ import time
 import urllib.request
 
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 
@@ -100,12 +101,16 @@ class TestMetrics:
                 return 0
 
             ray_tpu.get([f.remote() for _ in range(4)], timeout=30)
-            time.sleep(1.0)
-            text = urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/metrics", timeout=10) \
-                .read().decode()
-            assert "# TYPE ray_tpu_tasks_submitted counter" in text
-            assert "ray_tpu_workers_registered" in text
+
+            def scrape():
+                return urllib.request.urlopen(
+                    f"http://127.0.0.1:{port}/metrics", timeout=10) \
+                    .read().decode()
+
+            # The processes push their counters on their own cadence.
+            wait_until(lambda: "# TYPE ray_tpu_tasks_submitted counter"
+                       in scrape(), timeout=30)
+            assert "ray_tpu_workers_registered" in scrape()
             js = urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/metrics.json", timeout=10) \
                 .read().decode()
@@ -141,9 +146,13 @@ class TestMetrics:
 
             with pytest.raises(Exception):
                 ray_tpu.get(boom.remote(), timeout=30)
-            time.sleep(1.2)
-            page = urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/", timeout=10).read().decode()
+            def fetch():
+                return urllib.request.urlopen(
+                    f"http://127.0.0.1:{port}/", timeout=10).read().decode()
+
+            # The worker reports the error to the head asynchronously.
+            wait_until(lambda: "dashboard-test-error" in fetch(), timeout=30)
+            page = fetch()
             assert "<h1>ray_tpu" in page
             assert "node0" in page
             assert "dash_actor" in page       # named actor row
@@ -241,17 +250,21 @@ class TestMetrics:
                 return 0
 
             ray_tpu.get(f.remote(), timeout=30)
-            time.sleep(0.8)
             from ray_tpu._private import node as node_mod
             addr = node_mod._node.head.sock_path
             import io
             from contextlib import redirect_stdout
             from ray_tpu.scripts.scripts import main as cli_main
-            buf = io.StringIO()
-            with redirect_stdout(buf):
-                cli_main(["stat", "--metrics", "--address", addr])
-            out = buf.getvalue()
-            assert "tasks_submitted" in out
+
+            def stat():
+                buf = io.StringIO()
+                with redirect_stdout(buf):
+                    cli_main(["stat", "--metrics", "--address", addr])
+                return buf.getvalue()
+
+            # One metrics interval (0.3 s here) has to pass first.
+            wait_until(lambda: "tasks_submitted" in stat(), timeout=30)
+            out = stat()
             assert "gauges:" in out
         finally:
             ray_tpu.shutdown()

@@ -11,6 +11,7 @@ import os
 
 import numpy as np
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 from ray_tpu.cluster_utils import Cluster
@@ -141,8 +142,9 @@ class TestNodeFailure:
 
         refs = [slow.options(num_cpus=1, max_retries=3).remote()
                 for _ in range(4)]
-        import time
-        time.sleep(0.8)  # let them get scheduled (some on node1)
+        # They are scheduled, some of them on node1.
+        wait_until(lambda: any(t["node"] == "node1" for t in
+                               ray_tpu.tasks(state="RUNNING")), timeout=60)
         cluster.remove_node(handle)
         results = ray_tpu.get(refs, timeout=120)
         assert all(r in ("node0",) for r in results)

@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 from ray_tpu._private import chaos, metrics, protocol, serialization
@@ -30,15 +31,6 @@ def _counter(name):
     return metrics.snapshot()["counters"].get(name, 0.0)
 
 
-def _wait_until(fn, timeout=10.0, msg="condition"):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if fn():
-            return
-        time.sleep(0.02)
-    pytest.fail(f"timed out waiting for {msg}")
-
-
 # ======================================================================
 # directory: register on seal, deregister on evict, resolution order
 # ======================================================================
@@ -51,17 +43,17 @@ class TestDirectory:
         with rt._replica_lock:
             rt._replica_expected.add(oid)
         rt.shm.put_blob(oid, b"x" * 4096)
-        _wait_until(
+        wait_until(
             lambda: head.object_location_counts().get(oid.hex()) == 1,
-            msg="directory registration")
+            timeout=10, msg="directory registration")
         with rt._replica_lock:
             assert oid in rt._replica_oids
         # Eviction (any shm delete: free, chaos evict, corrupt
         # recovery) deregisters through the store hook.
         rt.shm.delete(oid)
-        _wait_until(
+        wait_until(
             lambda: oid.hex() not in head.object_location_counts(),
-            msg="directory deregistration")
+            timeout=10, msg="directory deregistration")
 
     def test_owned_seals_do_not_register(self, ray_start):
         head = node_mod._node.head
@@ -452,9 +444,9 @@ class TestClusterBroadcast:
         ref = ray_tpu.put(blob)
         out = ray_tpu.get(borrowers[0].fetch.remote(ref), timeout=90)
         assert out["sum"] == int(blob.sum())
-        _wait_until(
+        wait_until(
             lambda: head.object_location_counts().get(ref.id.hex(), 0)
-            >= 1, msg="replica registration from remote node")
+            >= 1, timeout=10, msg="replica registration from remote node")
 
     def test_same_node_borrower_zero_wire_bytes(self, bcast_cluster):
         """A borrower process on the owner's node serves the fetch
@@ -514,10 +506,10 @@ class TestChaosReplicaFetch:
             assert ray_tpu.get(a.fetch.remote(ref), timeout=90) \
                 == expected
             head = cluster.node.head
-            _wait_until(
+            wait_until(
                 lambda: head.object_location_counts().get(
                     ref.id.hex(), 0) >= 1,
-                msg="replica registration")
+                timeout=10, msg="replica registration")
             # Second borrower routes at the replica; chaos kills that
             # fetch; the owner fallback must still deliver the value.
             c = SecondBorrower.remote()

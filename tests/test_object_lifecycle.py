@@ -12,6 +12,7 @@ import sys
 
 import numpy as np
 import pytest
+from conftest import wait_until
 
 
 @pytest.fixture
@@ -192,10 +193,12 @@ class TestBorrows:
         # Pass as a nested structure so the worker receives the REF
         # (top-level args are resolved to values before execution).
         assert ray.get(h.hold.remote([big])) == "held"
+        # Borrow registration is async: the actor's add_borrow has to
+        # reach this (the owning) process before the driver's ref goes.
+        rt = ray._private.worker_state.get_runtime()
+        wait_until(lambda: big.id in rt._borrows, timeout=30)
         del big
         gc.collect()
-        import time
-        time.sleep(0.3)  # borrow registration is async
         for _ in range(6):
             r = ray.put(np.zeros(1 << 18))
             del r

@@ -13,6 +13,7 @@ import sys
 import time
 
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 
@@ -27,7 +28,9 @@ class TestTimeline:
         assert ray_tpu.get([work.remote(i) for i in range(3)]) == [0, 1, 2]
         with ray_tpu.profile("driver-span"):
             pass
-        time.sleep(1.3)  # profiler flush interval
+        # The workers' profilers flush on their own interval.
+        wait_until(lambda: {"work", "inner-span", "driver-span"}
+                   <= {e["name"] for e in ray_tpu.timeline()}, timeout=30)
         path = str(tmp_path / "trace.json")
         ray_tpu.timeline(path)
         events = json.load(open(path))
@@ -44,7 +47,6 @@ class TestTimeline:
             return 1
 
         ray_tpu.get(f.remote())
-        time.sleep(1.3)
         events = ray_tpu.timeline()
         assert isinstance(events, list)
 

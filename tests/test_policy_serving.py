@@ -10,9 +10,11 @@ through RemoteVectorEnv.
 import socket
 import threading
 import time
+import urllib.error
 
 import numpy as np
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 from ray_tpu.rllib.env.env import CartPole
@@ -51,11 +53,12 @@ class TestPolicyServing:
             def run(self):
                 PolicyServer(self, "127.0.0.1", port).serve_forever()
 
-        register_env("CartPoleServing-v0", lambda cfg: Serving())
-
         results = []
         errors = []
         holder = {}
+        trained = threading.Event()
+
+        register_env("CartPoleServing-v0", lambda cfg: Serving())
 
         def train_loop():
             # Constructed here: the first env reset blocks until the
@@ -78,6 +81,14 @@ class TestPolicyServing:
                     results.append(trainer.train())
             except Exception as e:  # pragma: no cover
                 errors.append(e)
+            finally:
+                trained.set()
+                # Stopping the trainer closes the serving env: the
+                # client's request in flight fails at once (an HTTP
+                # 500 carrying ExternalEnvClosed) where it used to
+                # wait out its whole timeout.
+                if "trainer" in holder:
+                    holder["trainer"].stop()
 
         t = threading.Thread(target=train_loop, daemon=True)
         t.start()
@@ -103,29 +114,29 @@ class TestPolicyServing:
         steps = 0
         first = True
         try:
-            while t.is_alive() and steps < 5000:
+            while not trained.is_set() and steps < 5000:
                 if not first:
                     eid = client.start_episode()
                 first = False
                 obs = env.reset()
                 done = False
-                while not done and t.is_alive():
+                while not done and not trained.is_set():
                     action = client.get_action(eid, obs)
                     obs, reward, done, _ = env.step(int(action))
                     client.log_returns(eid, reward)
                     steps += 1
                 if done:
                     client.end_episode(eid, obs)
-        except OSError:
-            # The train loop finished while our request was in flight;
-            # the serving env has no consumer anymore.
-            assert not t.is_alive()
+        except urllib.error.HTTPError as e:
+            # The train loop finished while our request was in flight.
+            assert trained.is_set() and e.code == 500, e
+            assert "ExternalEnvClosed" in str(e), e
         t.join(timeout=120)
+        assert not t.is_alive()
         assert not errors, errors
         assert len(results) == 3
         assert results[-1]["episode_reward_mean"] > 0
         assert results[-1]["timesteps_this_iter"] >= 200
-        holder["trainer"].stop()
 
     def test_log_action_roundtrip(self, ray_session):
         """Off-policy logging commands reach the env adapter."""
@@ -142,9 +153,10 @@ class TestPolicyServing:
         env = Serving()
         env._loop_started = True
         env.start()
-        time.sleep(0.5)
         client = PolicyClient(f"127.0.0.1:{port}")
-        eid = client.start_episode()
+
+        # refused until the server thread listens
+        eid = wait_until(lambda: _try(client.start_episode), timeout=30)
 
         # Drain framework side on a thread (acts as the sampler).
         consumed = []
@@ -162,6 +174,50 @@ class TestPolicyServing:
         client.end_episode(eid, np.ones(2, np.float32))
         t.join(timeout=30)
         assert len(consumed) == 2
+
+    def test_close_fails_the_request_in_flight(self):
+        """When the sampler stops (`close()`), the client's get_action
+        in flight and every later request fail at once, naming the
+        cause: they do not wait out the client's request timeout."""
+        port = _free_port()
+
+        class Serving(ExternalEnv):
+            def __init__(self):
+                super().__init__(Box(-np.ones(2, np.float32),
+                                     np.ones(2, np.float32)), Discrete(2))
+
+            def run(self):
+                PolicyServer(self, "127.0.0.1", port).serve_forever()
+
+        env = Serving()
+        client = PolicyClient(f"127.0.0.1:{port}", timeout=60)
+        raised = []
+
+        def drive():
+            eid = wait_until(lambda: _try(client.start_episode),
+                             timeout=30)
+            for _ in range(2):  # the one in flight, then a later one
+                try:
+                    client.get_action(eid, np.zeros(2, np.float32))
+                except urllib.error.HTTPError as e:
+                    raised.append((e.code, str(e)))
+
+        t = threading.Thread(target=drive, daemon=True)
+        t.start()
+        env.reset()  # the sampler holds the first observation
+        t0 = time.monotonic()
+        env.close()
+        t.join(timeout=30)
+        assert not t.is_alive() and time.monotonic() - t0 < 10
+        assert [c for c, _ in raised] == [500, 500], raised
+        assert all("ExternalEnvClosed" in m for _, m in raised), raised
+
+
+def _try(call):
+    try:
+        return call()
+    except OSError:
+        return None
 
 
 class TestRemoteVectorEnv:

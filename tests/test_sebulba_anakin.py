@@ -345,8 +345,13 @@ class TestEndToEnd:
             "min_iter_time_s": 0,
             "seed": 0,
         })
+        # The budget is experience, 320 steps an iteration. The actor
+        # runs ahead of the learner by what the host's scheduler gives
+        # it, so the iterations it takes vary: 15-22 on an idle box, 35
+        # seen beside twelve busy processes. The loop ends as soon as
+        # it has learned.
         best = 0.0
-        for _ in range(25):
+        for _ in range(100):
             r = t.train()
             rew = r.get("episode_reward_mean")
             if rew == rew:  # not nan

@@ -4,6 +4,7 @@ Parity: `streaming/python/streaming.py` (ExecutionGraph + operators).
 """
 
 import pytest
+from conftest import wait_until
 
 import ray_tpu
 
@@ -176,6 +177,44 @@ class TestOperatorDeath:
         assert set(got) >= {x * 2 for x in range(60)} or \
             len(set(got)) >= 55, got
 
+    def test_push_after_uncheckpointed_restart_resyncs(self, ray_start):
+        """No checkpoints: the restarted receiver has applied nothing,
+        the sender still holds the old incarnation's acks. The first
+        push after the restart is refused (`replay_from` 0) and must
+        come back marked as a resync; it used to be replayed unmarked
+        and refused again, without end (PR 30: 59,000 calls in 40 s,
+        then a hang, under `test_pipeline_survives_operator_kill`)."""
+        import threading
+
+        from ray_tpu.streaming.streaming import EdgeSender, _OperatorActor
+
+        op = ray_tpu.remote(_OperatorActor).options(max_restarts=1).remote(
+            "sink", None, [], 0, 4)
+        sender = EdgeSender(op, "e0", 4)
+        for i in range(1, 7):
+            sender.push(i)
+        sender.drain_all()
+        assert sender.covered == 6
+        ray_tpu.kill(op, no_restart=False)
+
+        def restarted_empty():
+            try:
+                return ray_tpu.get(op.sink_values.remote(), timeout=10) == []
+            except Exception:
+                return False
+
+        wait_until(restarted_empty, timeout=60)
+        done = threading.Event()
+
+        def push_and_drain():
+            sender.push(7)
+            sender.drain_all()
+            done.set()
+
+        threading.Thread(target=push_and_drain, daemon=True).start()
+        assert done.wait(30), f"still replaying after {sender.seq} pushes"
+        assert ray_tpu.get(op.sink_values.remote(), timeout=30) == [7]
+
     def test_restart_budget_exhaustion_fails_pipeline(self, ray_start):
         import pytest as _pytest
 
@@ -188,12 +227,24 @@ class TestOperatorDeath:
                 pass
 
         s = Sink.remote()
+        ray_tpu.get(s.process.remote(0), timeout=60)  # constructed
+        ray_tpu.kill(s, no_restart=True)
+
+        def death_observed():
+            try:
+                ray_tpu.get(s.process.remote(0), timeout=10)
+            except ActorDiedError:
+                return True
+            return False
+
+        # A push that is acknowledged before the kill lands drains
+        # without error, so the item goes out once the death is known.
+        wait_until(death_observed, timeout=60)
         sender = EdgeSender(s, "e0", 2)
         sender.push(1)
-        ray_tpu.kill(s, no_restart=True)
         with _pytest.raises(ActorDiedError):
             while sender.inflight:
-                sender.drain_oldest(redeliver_timeout_s=5.0)
+                sender.drain_oldest(redeliver_timeout_s=1.0)
 
 
 class TestWindowsAndState:

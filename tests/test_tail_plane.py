@@ -28,6 +28,8 @@ import time
 import urllib.request
 from contextlib import redirect_stdout
 
+import pytest
+
 import ray_tpu
 from ray_tpu._private import metrics
 from ray_tpu._private.straggler import StragglerDetector, robust_sigma
@@ -163,35 +165,70 @@ class TestHistogramPrimitive:
 
 
 class TestStragglerDetector:
+    SLOW_A1 = {
+        "a0": {"throughput": 100.0},
+        "a1": {"throughput": 8.0},
+        "a2": {"throughput": 98.0},
+        "a3": {"throughput": 103.0},
+    }
+
     def test_flags_slow_actor_only(self):
         det = StragglerDetector(k=3.0, min_peers=3)
-        v = det.update({
-            "a0": {"throughput": 100.0},
-            "a1": {"throughput": 8.0},
-            "a2": {"throughput": 98.0},
-            "a3": {"throughput": 103.0},
-        })
+        # One window's reading is not yet a flag; the next one that
+        # repeats it is (straggler.CONFIRM_WINDOWS).
+        v = det.update(self.SLOW_A1)
+        assert v["a1"]["reasons"] == ["throughput"]
+        assert v["a1"]["outlier_windows"] == 1 and not v["a1"]["flagged"]
+        assert det.flag_counts == {}
+        v = det.update(self.SLOW_A1)
         assert v["a1"]["flagged"] and v["a1"]["reasons"] == ["throughput"]
         assert not any(v[t]["flagged"] for t in ("a0", "a2", "a3"))
         assert det.flag_counts == {"a1": 1}
+
+    @pytest.mark.parametrize("starved", [0.0, 40.0])
+    def test_one_starved_window_is_not_a_flag(self, starved):
+        # A healthy actor thread that a busy host did not schedule
+        # reads one fragment, or none, below its peers in a short
+        # window. It is not flagged, and it does not break a1's run.
+        det = StragglerDetector(k=3.0, min_peers=3)
+        fleet = {**self.SLOW_A1, "a4": {"throughput": 101.0}}
+        windows = [fleet,
+                   {**fleet, "a2": {"throughput": starved}},
+                   fleet,
+                   {**fleet, "a3": {"throughput": starved}},
+                   fleet]
+        ever, tripped = set(), set()
+        for w in windows:
+            v = det.update(w)
+            ever |= {t for t, x in v.items() if x["flagged"]}
+            tripped |= {t for t, x in v.items() if x["reasons"]}
+        assert tripped == {"a1", "a2", "a3"}
+        assert ever == {"a1"}
+        assert det.flag_counts == {"a1": 4}
+        # a1 recovers: its run of outlier windows starts again.
+        v = det.update({**fleet, "a1": {"throughput": 99.0}})
+        assert not v["a1"]["flagged"] and v["a1"]["outlier_windows"] == 0
+        assert not det.update(fleet)["a1"]["flagged"]
 
     def test_identical_fleet_flags_divergent(self):
         # MAD = 0 -> the sigma floor (5% of median) still catches a
         # genuinely divergent actor instead of dividing by zero.
         det = StragglerDetector(k=3.0, min_peers=3)
-        v = det.update({t: {"throughput": 100.0}
-                        for t in ("a0", "a1", "a2")} |
-                       {"a3": {"throughput": 50.0}})
-        assert v["a3"]["flagged"]
+        window = {t: {"throughput": 100.0} for t in ("a0", "a1", "a2")} \
+            | {"a3": {"throughput": 50.0}}
+        det.update(window)
+        assert det.update(window)["a3"]["flagged"]
 
     def test_fetch_latency_flag(self):
         det = StragglerDetector(k=3.0, min_peers=3)
-        v = det.update({
+        window = {
             "a0": {"throughput": 100.0, "fetch_latency_s": 0.010},
             "a1": {"throughput": 100.0, "fetch_latency_s": 0.011},
             "a2": {"throughput": 100.0, "fetch_latency_s": 0.300},
             "a3": {"throughput": 100.0, "fetch_latency_s": 0.009},
-        })
+        }
+        det.update(window)
+        v = det.update(window)
         assert v["a2"]["flagged"]
         assert "fetch_latency" in v["a2"]["reasons"]
 
@@ -415,11 +452,16 @@ class TestStragglerChaosDrill:
             while time.monotonic() < deadline:
                 result = t.train()
                 report = result.get("stragglers") or {}
-                if report.get("flagged") == ["a1"]:
+                # The evaluation this drill is about: a1 alone, for its
+                # throughput. On a loaded box a window's spread can be
+                # wide enough to hide even a1's, or flag it for its
+                # fetch latency alone; the next window decides again.
+                if report.get("flagged") == ["a1"] and "throughput" in \
+                        report["per_actor"]["a1"]["reasons"]:
                     break
             assert report.get("flagged") == ["a1"], report
             verdict = report["per_actor"]["a1"]
-            assert "throughput" in verdict["reasons"]
+            assert "throughput" in verdict["reasons"], report
             assert verdict["throughput"] < verdict["throughput_median"]
             assert report["flag_counts"].get("a1", 0) >= 1
             snap = metrics.snapshot()

@@ -3,7 +3,9 @@
 Parity model: `python/ray/tune/tests/` (trial_runner/scheduler tests).
 """
 
+import json
 import os
+import struct
 import time
 
 import numpy as np
@@ -102,6 +104,82 @@ def _quadratic(config, reporter):
         reporter(score=-(config["x"] - 3.0) ** 2, training_iteration=i + 1)
 
 
+def _crc32c(data: bytes) -> int:
+    """CRC-32C bit by bit (reflected polynomial 0x82F63B78)."""
+    crc = 0xFFFFFFFF
+    for byte in data:
+        crc ^= byte
+        for _ in range(8):
+            crc = (crc >> 1) ^ (0x82F63B78 & -(crc & 1))
+    return crc ^ 0xFFFFFFFF
+
+
+def _tfrecords(data: bytes) -> list:
+    """A TFRecord file's payloads: u64 length, masked CRC of the length,
+    payload, masked CRC of the payload."""
+    def masked(chunk):
+        crc = _crc32c(chunk)
+        return ((crc >> 15 | crc << 17) + 0xA282EAD8) & 0xFFFFFFFF
+
+    records = []
+    while data:
+        length, length_crc = struct.unpack("<QI", data[:12])
+        assert length_crc == masked(data[:8])
+        record = data[12:12 + length]
+        assert len(record) == length
+        crc, = struct.unpack("<I", data[12 + length:16 + length])
+        assert crc == masked(record)
+        records.append(record)
+        data = data[16 + length:]
+    return records
+
+
+def _proto_fields(buf: bytes):
+    """(field number, value) of one protobuf message: varints as ints,
+    64-bit, length-delimited and 32-bit fields as their bytes."""
+    def varint(i):
+        n = shift = 0
+        while True:
+            n |= (buf[i] & 0x7F) << shift
+            shift += 7
+            i += 1
+            if not buf[i - 1] & 0x80:
+                return n, i
+
+    i = 0
+    while i < len(buf):
+        key, i = varint(i)
+        wire = key & 7
+        if wire == 0:
+            value, i = varint(i)
+        else:
+            if wire == 2:
+                size, i = varint(i)
+            else:
+                size = {1: 8, 5: 4}[wire]
+            value, i = buf[i:i + size], i + size
+        yield key >> 3, value
+
+
+def _decode_event(record: bytes) -> dict:
+    """event.proto: wall_time=1, step=2, file_version=3, summary=5;
+    summary.proto: value=1 of {tag=1, simple_value=2}."""
+    event = {"step": 0, "file_version": "", "scalars": {}}
+    for number, value in _proto_fields(record):
+        if number == 1:
+            event["wall_time"], = struct.unpack("<d", value)
+        elif number == 2:
+            event["step"] = value
+        elif number == 3:
+            event["file_version"] = value.decode()
+        elif number == 5:
+            for _, summary_value in _proto_fields(value):
+                fields = dict(_proto_fields(summary_value))
+                event["scalars"][fields[1].decode()], = \
+                    struct.unpack("<f", fields[2])
+    return event
+
+
 class TestTuneRun:
     def test_function_trainable_grid(self, ray_start, tmp_path):
         from ray_tpu import tune
@@ -118,6 +196,65 @@ class TestTuneRun:
         # Json logs written per trial
         dfs = analysis.trial_dataframes()
         assert all(len(rows) >= 1 for rows in dfs.values())
+
+    def test_trial_logdir_holds_tensorboard_scalars(self, ray_start,
+                                                   tmp_path):
+        """Every reported scalar reaches an `events.out.tfevents.*` file
+        in the trial's logdir, at its `training_iteration`, in records a
+        TensorBoard reads: framing, checksums and protos are decoded by
+        the reader above, written from the formats and sharing nothing
+        with the writer, and by TensorBoard's own classes as well where
+        the package is installed."""
+        import glob
+        from ray_tpu import tune
+        analysis = tune.run(
+            _quadratic, name="tb", config={"x": 5.0},
+            stop={"training_iteration": 5}, local_dir=str(tmp_path))
+        trial, = analysis.trials
+        path, = glob.glob(os.path.join(trial.logdir,
+                                       "events.out.tfevents.*"))
+        records = _tfrecords(open(path, "rb").read())
+        events = [_decode_event(r) for r in records]
+        assert events[0]["file_version"] == "brain.Event:2"
+        assert [e["step"] for e in events[1:]] == [1, 2, 3, 4, 5]
+        for e in events:
+            assert abs(e["wall_time"] - time.time()) < 600
+        # Every number of every result (result.json has them all).
+        rows = [json.loads(line) for line in
+                open(os.path.join(trial.logdir, "result.json"))]
+        assert len(rows) == 5
+        for e, row in zip(events[1:], rows):
+            assert e["scalars"]["score"] == -4.0
+            assert e["scalars"]["training_iteration"] == e["step"]
+            numbers = {k: v for k, v in row.items()
+                       if isinstance(v, (int, float))}
+            assert len(numbers) >= 5, row
+            for k, v in numbers.items():
+                assert e["scalars"][k] == np.float32(v), (k, row)
+        assert _crc32c(b"123456789") == 0xE3069283  # Castagnoli's check
+        try:
+            from tensorboard.compat.proto import event_pb2
+        except ImportError:
+            return
+        theirs = [event_pb2.Event.FromString(r) for r in records]
+        assert [(e.step, {v.tag: v.simple_value for v in e.summary.value})
+                for e in theirs] == [(e["step"], e["scalars"])
+                                     for e in events]
+
+    def test_trial_process_imports_no_framework(self, ray_start, tmp_path):
+        """Logging a trial's results costs its process no import of torch
+        or tensorflow (PR 30: ~10 s a trial before its first result)."""
+        from ray_tpu import tune
+
+        def loaded(config, reporter):
+            import sys
+            reporter(frameworks=",".join(
+                m for m in ("torch", "tensorflow") if m in sys.modules))
+
+        analysis = tune.run(loaded, name="mods",
+                            stop={"training_iteration": 1},
+                            local_dir=str(tmp_path))
+        assert analysis.trials[0].last_result["frameworks"] == ""
 
     def test_trainable_class_checkpointing(self, ray_start, tmp_path):
         from ray_tpu import tune
