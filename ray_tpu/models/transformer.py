@@ -39,12 +39,17 @@ One set of parameters, two forms (the stateful-policy protocol of
 * `decode`: one token a row against a key/value cache of `context_len`
   positions a layer ([B, S, heads, head_dim], `compute_dtype`); appends the
   position's K/V and returns its logits and value. The rollout's form.
+  Its attention reads the cache positions [0, n) only, n the furthest
+  position any row of the batch holds, rounded up to a block of
+  `DECODE_CACHE_BLOCK` positions and chosen inside the step from `pos`
+  (`cached_attention`); each row masks what it does not hold itself.
 
 Both return the cache, so a decode can follow a causal pass.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -110,6 +115,63 @@ def route(n, router, k, renormalise):
 # and backward, 1.4x faster at 512 and 1.1x slower at 1,024.
 GROUP_COST_ROWS = 540
 GROUPED_ROW_COST = 1.7
+
+
+# Positions in a block of the caches a decode step's attention reads (see
+# `cached_attention`). A window that fills from empty is read `1/2 + b/(2S)`
+# of, so a smaller block reads less; every block of the window is one more
+# branch of the step's `switch`, traced wherever a decode step is (the
+# rollout, and the learner's bootstrap step under `value_and_grad`) on
+# every start, compile cache or not. On a v5e at the published widths, 128
+# rows, a window of 1,024 (PERF.md section 5), a decode step / the token
+# cell's warm set-up once the chip is open: the window read whole 3.11 ms
+# / 22.5 s; blocks of 512 2.64 ms; of 256 2.44 ms / 23.1 s; of 128 2.35 ms
+# / 25.1 s; of 64 2.32 ms.
+DECODE_CACHE_BLOCK = 256
+
+
+def cached_attention(q, k_cache, v_cache, pos):
+    """softmax(q k^T / sqrt(head_dim)) v of one query a row, q [B, heads,
+    head_dim], over the positions [0, pos[b]] that row holds of the caches
+    [B, S, heads, head_dim]. Returns ([B, heads, head_dim] in q's dtype, the
+    positions read).
+
+    Read, scored and multiplied are the positions [0, n) alone: n is the
+    furthest position any row holds, rounded up to whole blocks. A position
+    a row does not hold has weight exp(-inf) = 0 in both sums, so leaving
+    those beyond every row unread changes no term; within [0, n) each row
+    masks its own. One `switch` over the static prefixes: every branch is
+    the same arithmetic on a shorter axis (float32 scores and softmax, the
+    weights normalised, cast, then multiplied), and a `switch` has a
+    transpose, which a loop whose trip count comes from data has not (the
+    learner differentiates its bootstrap step through this).
+
+    Both products are written as what they are, a matrix times one vector
+    a row and head: operands in q's dtype, multiplied and summed in
+    float32. Outside a conditional XLA:TPU makes that of the einsum itself;
+    inside one it made the scores a convolution over a transposed copy of
+    the prefix, and the step was slower than with the window read whole
+    (3.08 against 2.97 ms, PERF.md section 5). In this form the fusion
+    that multiplies the prefix reads it where it lies."""
+    S = k_cache.shape[1]
+    ends = tuple(range(DECODE_CACHE_BLOCK, S, DECODE_CACHE_BLOCK)) + (S,)
+    f32 = jnp.float32
+
+    def attend(n, q, k_cache, v_cache, pos):
+        held = jnp.arange(n)[None, :] <= pos[:, None]
+        k, v = k_cache[:, :n].astype(f32), v_cache[:, :n].astype(f32)
+        # [B, n, heads]
+        scores = jnp.sum(q[:, None].astype(f32) * k, axis=-1) * (
+            q.shape[-1] ** -0.5)
+        scores = jnp.where(held[:, :, None], scores, -jnp.inf)
+        attn = jax.nn.softmax(scores, axis=1).astype(q.dtype)
+        return jnp.sum(attn[..., None].astype(f32) * v,
+                       axis=1).astype(q.dtype)
+
+    block = jnp.minimum(jnp.max(pos) // DECODE_CACHE_BLOCK, len(ends) - 1)
+    o = jax.lax.switch(block, [functools.partial(attend, n) for n in ends],
+                       q, k_cache, v_cache, pos)
+    return o, jnp.asarray(ends)[block]
 
 
 def experts_batched(M: int, k: int, E: int) -> bool:
@@ -243,13 +305,15 @@ class OlmoeNetwork(nn.Module):
 
     def decode_counters(self, batch_size: int) -> dict:
         """What a decode step of `batch_size` rows is, from its static
-        shape: the mean rows an expert group holds and whether the experts
-        multiply in the batched form (1.0) or the grouped one (0.0)."""
+        shape: the mean rows an expert group holds, whether the experts
+        multiply in the batched form (1.0) or the grouped one (0.0), and
+        the positions in a block of the caches its attention reads."""
         k, E = self.experts_per_token, self.num_experts
         return {
             "decode_rows_per_expert": batch_size * k / E,
             "decode_experts_batched": float(
                 experts_batched(batch_size, k, E)),
+            "decode_cache_block": min(DECODE_CACHE_BLOCK, self.context_len),
         }
 
     def __call__(self, obs, state, reset):
@@ -290,12 +354,13 @@ class OlmoeNetwork(nn.Module):
             value = jnp.dot(y, self.value_w) + self.value_b
         return logits, value
 
-    def _count(self, experts, loads=None):
+    def _count(self, experts, loads=None, read=None):
         """What a pass counted, kept only where the caller asks for the
         collection (and never among the variables `init` returns): the
         experts chosen [layers, ..., k], for the reference check; in the
         learner's form also the rows of the fullest expert group over the
-        layers, and the mean group."""
+        layers, and the mean group; in a decode step the share of the
+        window's positions its attention read."""
         if self.is_initializing():
             return
         self.sow("routing", "experts", jnp.stack(experts))
@@ -303,6 +368,9 @@ class OlmoeNetwork(nn.Module):
             loads = jnp.stack(loads).astype(jnp.float32)
             self.sow("counters", "expert_load_max", jnp.max(loads))
             self.sow("counters", "expert_load_mean", jnp.mean(loads))
+        if read is not None:
+            self.sow("counters", "decode_cache_read_share",
+                     read.astype(jnp.float32) / self.context_len)
 
     # -- the two forms --------------------------------------------------
     def causal(self, tokens, reset):
@@ -358,7 +426,6 @@ class OlmoeNetwork(nn.Module):
         B = token.shape[0]
         pos = jnp.where(reset > 0, 0, state["pos"])
         rows = jnp.arange(B)
-        held = jnp.arange(self.context_len)[None, :] <= pos[:, None]
 
         x = self.embed[token].astype(cd)
         kv, experts = [], []
@@ -371,18 +438,12 @@ class OlmoeNetwork(nn.Module):
                 k = rope(k, pos, self.rope_theta)
                 k_cache = k_cache.at[rows, pos].set(k)
                 v_cache = v_cache.at[rows, pos].set(v)
-                scores = jnp.einsum(
-                    "bhd,bshd->bhs", q, k_cache,
-                    preferred_element_type=jnp.float32) * (
-                        q.shape[-1] ** -0.5)
-                scores = jnp.where(held[:, None], scores, -jnp.inf)
-                attn = jax.nn.softmax(scores, axis=-1).astype(cd)
-                o = jnp.einsum("bhs,bshd->bhd", attn, v_cache).reshape(B, -1)
-                h = x + jnp.dot(o, lp["wo"].astype(cd))
+                o, read = cached_attention(q, k_cache, v_cache, pos)
+                h = x + jnp.dot(o.reshape(B, -1), lp["wo"].astype(cd))
                 kv.append((k_cache, v_cache))
             x, _, top_i = self._moe(lp, h)
             experts.append(top_i)
-        self._count(experts)
+        self._count(experts, read=read)
         logits, value = self._heads(x)
         return logits, value, {"kv": tuple(kv), "pos": pos + 1}
 

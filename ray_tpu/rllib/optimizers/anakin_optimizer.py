@@ -149,12 +149,14 @@ class AnakinOptimizer(PolicyOptimizer):
         stateful = policy.recurrent
 
         def rollout_step(params, scarry):
-            """One env step of all slots under `params`."""
+            """One env step of all slots under `params`: the carry, and
+            (the step of the trajectory, what a stateful model counted)."""
             env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate = scarry
             with jax.named_scope("anakin/inference"):
                 rng, akey, ekey = jax.random.split(rng, 3)
+                counted = {}
                 if stateful:
-                    dist_inputs, value, state = policy.step_state(
+                    dist_inputs, value, state, counted = policy.step_state(
                         params, obs, *pstate)
                 else:
                     dist_inputs, value = policy.apply(params, obs)
@@ -179,7 +181,7 @@ class AnakinOptimizer(PolicyOptimizer):
                     pstate = (state, donef)
             out = (obs, action, reward, done) + kept
             return (env_state, next_obs, rng, ep_rew, ep_len, ep_acc,
-                    pstate), out
+                    pstate), (out, counted)
 
         def batch_of(traj, obs, pstate_in):
             """The rollout as the learner's packed fragment batch."""
@@ -261,7 +263,7 @@ class AnakinOptimizer(PolicyOptimizer):
             with jax.named_scope("anakin/decode" if stateful
                                  else "anakin/env_step"):
                 (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate), \
-                    traj = jax.lax.scan(
+                    (traj, counted) = jax.lax.scan(
                         lambda c, _: rollout_step(params, c),
                         (env_state, obs, rng, ep_rew, ep_len, ep_acc,
                          pstate),
@@ -276,6 +278,8 @@ class AnakinOptimizer(PolicyOptimizer):
                 with jax.named_scope("anakin/learn"):
                     params, opt_state, stats = learn_minibatches(
                         params, opt_state, batch, lkey)
+            # What the rollout's steps counted, as one value a rollout.
+            stats = {**stats, **reduce_stats(counted)}
             return (params, opt_state, env_state, obs, rng,
                     ep_rew, ep_len, ep_acc, pstate), stats
 

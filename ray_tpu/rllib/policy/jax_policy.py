@@ -256,6 +256,10 @@ class JaxPolicy(Policy):
         first = batch["reset_in"][:, None] if "reset_in" in batch \
             else jnp.zeros((B, 1), jnp.float32)
         reset = jnp.concatenate([first, dones[:, :-1]], axis=1)
+        return self._apply_counted(params, obs_bt, state, reset)
+
+    def _apply_counted(self, params, obs_bt, state, reset):
+        """`apply` of a stateful model and its "counters" collection."""
         out, counted = self.apply(params, obs_bt, state, reset,
                                   mutable=["counters"])
         return out, {k: v[-1] for k, v in
@@ -269,10 +273,10 @@ class JaxPolicy(Policy):
     def step_state(self, params, obs, state, reset):
         """One rollout step of a stateful policy: obs [B], reset [B] (1
         where the previous step ended an episode) -> (dist_inputs [B, O],
-        value [B], state)."""
-        dist_bt, val_bt, state = self.apply(
+        value [B], state, what the model counted in the step)."""
+        (dist_bt, val_bt, state), counted = self._apply_counted(
             params, obs[:, None], state, reset[:, None])
-        return dist_bt[:, 0], val_bt[:, 0], state
+        return dist_bt[:, 0], val_bt[:, 0], state, counted
 
     def get_initial_state(self, batch_size: int = 1):
         """Per-env rollout state columns ([] for feedforward policies)."""

@@ -22,7 +22,7 @@ if BENCH not in sys.path:
 
 from lib import reference_olmoe as reference  # noqa: E402
 
-from ray_tpu.models import catalog  # noqa: E402
+from ray_tpu.models import catalog, transformer  # noqa: E402
 from ray_tpu.models.transformer import (  # noqa: E402
     dropless_experts, experts_batched)
 from ray_tpu.rllib import sample_batch as sb  # noqa: E402
@@ -37,15 +37,24 @@ NET = dict(vocab_size=128, hidden_size=64, num_attention_heads=4,
 B, S = 3, 16
 
 
-def build(dtype):
-    model = catalog.get_model(None, NET["vocab_size"], {
-        "custom_model": "olmoe", "custom_model_config": NET,
+def build(dtype, net=NET):
+    window = net["max_position_embeddings"]
+    model = catalog.get_model(None, net["vocab_size"], {
+        "custom_model": "olmoe", "custom_model_config": net,
         "compute_dtype": dtype})
     tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, S), 0, NET["vocab_size"])
+        jax.random.PRNGKey(1), (B, window), 0, net["vocab_size"])
     params = model.init(jax.random.PRNGKey(0), tokens[:, :1],
                         model.initial_state(B), jnp.zeros((B, 1)))
     return model, params, tokens
+
+
+@pytest.fixture
+def blocks_of_4(monkeypatch):
+    """The tiny window of 16 positions as four blocks of the decode's
+    attention (the constant is read when a step is traced)."""
+    monkeypatch.setattr(transformer, "DECODE_CACHE_BLOCK", 4)
+    return 4
 
 
 def token_trainer_config(**over):
@@ -117,33 +126,49 @@ def test_a_wrong_router_is_refused_by_its_flips():
 
 
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_decode_through_cache_matches_causal_pass(dtype):
-    """Every position decoded one token at a time against the cache."""
+def test_decode_through_cache_matches_causal_pass(dtype, blocks_of_4):
+    """Every position decoded one token at a time against the cache, the
+    window four blocks of the decode's attention."""
     model, params, tokens = build(dtype)
     logits, values, _ = model.apply(params, tokens, None, jnp.zeros((B, S)))
-    state, got_l, got_v = model.initial_state(B), [], []
+    state, got_l, got_v, read = model.initial_state(B), [], [], []
+
+    def decode(token, state):
+        return model.apply(params, token, state, jnp.zeros(B),
+                           method="decode", mutable=["counters"])
+    if dtype == "f32":
+        # One program a step, for the time it saves. Not in bfloat16:
+        # XLA:CPU then rounds elsewhere than the causal pass's eager ops
+        # do, and one near-tie of the router moves a token's logits.
+        decode = jax.jit(decode)
     for t in range(S):
-        step_l, step_v, state = model.apply(
-            params, tokens[:, t], state, jnp.zeros(B), method="decode")
+        (step_l, step_v, state), kept = decode(tokens[:, t], state)
         got_l.append(step_l)
         got_v.append(step_v)
+        read.append(float(kept["counters"]["decode_cache_read_share"][-1]))
+    assert S // blocks_of_4 >= 3
+    assert read == [(t // blocks_of_4 + 1) * blocks_of_4 / S
+                    for t in range(S)]
     tol = 1e-5 if dtype == "f32" else reference.TOLERANCE
     assert reference.relative_error(jnp.stack(got_l, 1), logits) <= tol
     assert reference.relative_error(jnp.stack(got_v, 1), values) <= tol
     assert np.all(np.asarray(state["pos"]) == S)
 
 
-def test_prefill_then_decode_and_reset_inside_a_fragment():
+def test_prefill_then_decode_and_reset_inside_a_fragment(blocks_of_4):
     """A causal pass returns a cache a decode can continue from, and a
     reset inside a fragment starts a fresh episode: positions restart and
-    nothing attends across the boundary."""
+    nothing attends across the boundary (the decode then reads one block
+    of a cache whose later blocks still hold the episode before)."""
     model, params, tokens = build("f32")
+    decode = jax.jit(lambda token, state, reset: model.apply(
+        params, token, state, reset, mutable=["counters"]))
     full, _, _ = model.apply(params, tokens, None, jnp.zeros((B, S)))
     _, _, state = model.apply(params, tokens[:, :10], None,
                               jnp.zeros((B, 10)))
     for t in range(10, S):
-        step, _, state = model.apply(
-            params, tokens[:, t:t + 1], state, jnp.zeros((B, 1)))
+        (step, _, state), _ = decode(
+            tokens[:, t:t + 1], state, jnp.zeros((B, 1)))
         assert reference.relative_error(step[:, 0], full[:, t]) < 1e-5
     # Two episodes of 8 in one fragment == the two halves on their own.
     reset = jnp.zeros((B, S)).at[:, 8].set(1.0)
@@ -156,9 +181,131 @@ def test_prefill_then_decode_and_reset_inside_a_fragment():
     # ... and the decode honours the same reset.
     state = model.initial_state(B)
     for t in range(S):
-        step, _, state = model.apply(
-            params, tokens[:, t:t + 1], state, reset[:, t:t + 1])
+        (step, _, state), _ = decode(
+            tokens[:, t:t + 1], state, reset[:, t:t + 1])
         assert reference.relative_error(step[:, 0], both[:, t]) < 1e-5
+    # ... also after a prefill, and in one row of the batch only: that row
+    # restarts at position 0, the others go on from 10, and the blocks read
+    # are those of the furthest row.
+    _, _, state = model.apply(params, tokens[:, :10], None,
+                              jnp.zeros((B, 10)))
+    one_row = jnp.zeros((B, 1)).at[0, 0].set(1.0)
+    (step, _, state), kept = decode(tokens[:, 10:11], state, one_row)
+    alone, _, _ = model.apply(params, tokens[:1, 10:11],
+                              jnp.zeros((1, 1)), method="causal")
+    assert reference.relative_error(step[:1, 0], alone[:, 0]) < 1e-5
+    assert reference.relative_error(step[1:, 0], full[1:, 10]) < 1e-5
+    assert np.all(np.asarray(state["pos"]) == [1, 11, 11])
+    assert float(kept["counters"]["decode_cache_read_share"][-1]) == 12 / S
+
+
+# -- the decode's attention, blocked -------------------------------------
+def whole_window_attention(q, k_cache, v_cache, pos):
+    """The plain form of `transformer.cached_attention` (the decode's
+    arithmetic before the window was read in blocks): every position of the
+    window scored, masked, and multiplied by its weight."""
+    S = k_cache.shape[1]
+    held = jnp.arange(S)[None, :] <= pos[:, None]
+    scores = jnp.einsum(
+        "bhd,bshd->bhs", q, k_cache,
+        preferred_element_type=jnp.float32) * (q.shape[-1] ** -0.5)
+    scores = jnp.where(held[:, None], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhs,bshd->bhd", attn, v_cache), jnp.asarray(S)
+
+
+# The published head shape (16 heads of 128) over the cell's window at a
+# batch of 3, experts cut; and the rehearsal's tiny model, whose window of
+# 16 is cut into blocks of 4 by the test.
+PUBLISHED_HEADS = dict(NET, hidden_size=2048, num_attention_heads=16,
+                       num_key_value_heads=16, num_hidden_layers=1,
+                       max_position_embeddings=1024)
+BLOCKED_SIZES = {"published_heads": (PUBLISHED_HEADS, None),
+                 "rehearsal": (NET, 4)}
+# Where each of the three rows stands, in terms of the block b and the
+# window S (position p: the row holds p positions and this step writes p).
+ROW_POSITIONS = {
+    "pos_0": lambda b, S: [0, 0, 0],
+    "pos_b-1": lambda b, S: [b - 1] * 3,
+    "pos_b": lambda b, S: [b] * 3,
+    "pos_S-1": lambda b, S: [S - 1] * 3,
+    "rows_apart": lambda b, S: [0, 2 * b, b - 1],
+    "rows_apart_to_the_end": lambda b, S: [S - 1, 0, b],
+}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("where", ROW_POSITIONS)
+@pytest.mark.parametrize("size", BLOCKED_SIZES)
+def test_blocked_decode_step_equals_whole_window(size, where, dtype,
+                                                 monkeypatch):
+    """A decode step reading the blocks up to the furthest row == the same
+    step against the whole window, wherever the rows stand; positions past
+    a row's own hold another episode's keys and values, not zeros."""
+    net, block = BLOCKED_SIZES[size]
+    if block:
+        monkeypatch.setattr(transformer, "DECODE_CACHE_BLOCK", block)
+    block, window = transformer.DECODE_CACHE_BLOCK, \
+        net["max_position_embeddings"]
+    assert window // block >= 3
+    model, params, tokens = build(dtype, net)
+    state = model.initial_state(B)
+    keys = jax.random.split(jax.random.PRNGKey(2), 2 * len(state["kv"]))
+    filled = [jax.random.normal(k, state["kv"][0][0].shape).astype(
+        state["kv"][0][0].dtype) for k in keys]
+    pos = jnp.asarray(ROW_POSITIONS[where](block, window), jnp.int32)
+    state = {"kv": tuple(zip(filled[::2], filled[1::2])), "pos": pos}
+
+    def step():
+        return model.apply(params, tokens[:, 0], state, jnp.zeros(B),
+                           method="decode", mutable=["counters"])
+    (logits, values, after), kept = step()
+    monkeypatch.setattr(transformer, "cached_attention",
+                        whole_window_attention)
+    (want_logits, want_values, want_after), plain = step()
+    tol = 1e-5 if dtype == "f32" else reference.TOLERANCE
+    assert reference.relative_error(logits, want_logits) <= tol
+    assert reference.relative_error(values, want_values) <= tol
+    for got, want in zip(jax.tree.leaves(after), jax.tree.leaves(want_after)):
+        assert jnp.array_equal(got, want)  # the write at `pos`, pos + 1
+    blocks = int(max(pos)) // block + 1
+    assert float(kept["counters"]["decode_cache_read_share"][-1]) \
+        == blocks * block / window
+    assert float(plain["counters"]["decode_cache_read_share"][-1]) == 1.0
+
+
+@pytest.mark.parametrize("reset", [0.0, 1.0])
+def test_bootstrap_step_gradients_equal_whole_window(reset, blocks_of_4,
+                                                     monkeypatch):
+    """The learner's bootstrap step, one decode from the causal pass's
+    cache under `jax.value_and_grad` with the parameters and the carry
+    differentiated: it compiles, and value and gradients are those of the
+    whole-window form (a loop whose trip count came from `pos` would have
+    no transpose)."""
+    model, params, tokens = build("f32")
+    weights = jax.random.normal(jax.random.PRNGKey(3),
+                                (B, NET["vocab_size"]))
+
+    def loss(p):
+        _, _, carry = model.apply(p, tokens[:, :10], None,
+                                  jnp.zeros((B, 10)))
+        logits, value, _ = model.apply(
+            p, tokens[:, 10:11], carry, jnp.full((B, 1), reset))
+        return jnp.sum(value) + jnp.sum(logits[:, 0] * weights)
+
+    got, got_grads = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(transformer, "cached_attention",
+                        whole_window_attention)
+    want, want_grads = jax.jit(jax.value_and_grad(loss))(params)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got_grads)
+    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-8
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * scale, path
+    # Through the cache: without a reset the step attends the prefill's
+    # keys, and only then has their projection a gradient.
+    wk = np.asarray(got_grads["params"]["layer_1"]["wk"])
+    assert np.any(wk != 0.0) == (reset == 0.0)
 
 
 ROUTINGS = ["one_expert_takes_all", "one_expert_takes_none", "random"]
@@ -274,7 +421,8 @@ def test_the_form_is_chosen_from_the_static_shape():
     # of 2,048 sequences grouped.
     assert experts_batched(4, 8, 64) and not experts_batched(2048, 8, 64)
     assert model.decode_counters(128) == {
-        "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0}
+        "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0,
+        "decode_cache_block": transformer.DECODE_CACHE_BLOCK}
     assert model.decode_counters(2048)["decode_experts_batched"] == 0.0
 
     def shapes(b, t):
@@ -415,6 +563,30 @@ def test_token_trainer_trains_on_the_fused_path(token_trainer):
     kept = token_trainer.optimizer.learner_stats
     assert kept["decode_rows_per_expert"] == 2.0
     assert kept["decode_experts_batched"] == 1.0
+    # Its attention: the window of 16 is one block, read whole every step.
+    assert kept["decode_cache_block"] == S
+    assert kept["decode_cache_read_share"] == 1.0
+
+
+@pytest.mark.parametrize("episode_len,share", [(S, 0.5 + 4 / (2 * S)),
+                                               (1, 4 / S)])
+def test_decode_cache_counters_in_learner_stats(episode_len, share,
+                                                blocks_of_4):
+    """`decode_cache_read_share` is reduced on the device from the value
+    that selects the blocks: a window that fills from empty reads
+    1/2 + b/(2S) of itself over a rollout, one held at position 0 (every
+    step ends an episode) one block of four; `decode_cache_block` is the
+    host's constant."""
+    trainer = IMPALATrainer(config=token_trainer_config(
+        env_config={"vocab_size": NET["vocab_size"],
+                    "episode_len": episode_len}))
+    try:
+        trainer.train()
+        kept = trainer.optimizer.learner_stats
+        assert kept["decode_cache_block"] == blocks_of_4
+        assert kept["decode_cache_read_share"] == pytest.approx(share)
+    finally:
+        trainer.stop()
 
 
 def test_wide_action_space_keeps_logp_not_logits():

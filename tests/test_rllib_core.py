@@ -221,7 +221,10 @@ class TestSampler:
 
         venv = VectorEnv(lambda: CartPole(), 1)
         policy = RandomPolicy(venv.observation_space, venv.action_space, {})
-        sampler = SyncSampler(venv, policy, rollout_fragment_length=100)
+        # An episode ends after 200 steps at the latest, so a fragment of
+        # 201 holds two whatever the unseeded policy draws (one of 100 was
+        # a single episode once in ~20 whole runs).
+        sampler = SyncSampler(venv, policy, rollout_fragment_length=201)
         batch = sampler.sample()
         # Multiple episodes in the fragment → multiple eps ids.
         assert len(np.unique(batch[sb.EPS_ID])) >= 2
