@@ -41,8 +41,13 @@ def _olmoe(obs_space, num_outputs, cfg, dtype):
     return olmoe_from_config(num_outputs, cfg, dtype)
 
 
+def _glm4_moe_lite(obs_space, num_outputs, cfg, dtype):
+    from .transformer import glm4_moe_lite_from_config
+    return glm4_moe_lite_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
-CUSTOM_MODELS = {"olmoe": _olmoe}
+CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite}
 
 
 def _resolve_compute_dtype(cfg):

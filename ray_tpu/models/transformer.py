@@ -1,48 +1,101 @@
-"""Transformer policies in flax: the OLMoE sparse-expert block.
+"""Transformer token policies in flax: one decoder, two descriptions.
 
-`OlmoeNetwork` is OLMoE's decoder (arXiv:2409.02060, `model_type: olmoe`)
-as a token policy: observations are token ids, the action logits are the
-language-model head's, and a value head reads the same final hidden vector.
+`TokenDecoder` is a pre-norm decoder as a token policy: observations are
+token ids, the action logits are the language-model head's, and a value head
+reads the same final hidden vector.
 
     x = E[tokens]
-    per layer:  h = x + Attn(RMSNorm(x));  x = h + MoE(RMSNorm(h))
+    per layer:  h = x + Attention(RMSNorm(x));  x = h + FeedForward(RMSNorm(h))
     y = RMSNorm(x);  logits = y W_head (untied);  value = y w_v + b
-    Attn: q, k, v = n W_q, n W_k, n W_v (no bias); q_norm, k_norm: RMSNorm
-          over the whole projection before the split into heads;
-          rotate-half RoPE; causal softmax(q k^T / sqrt(head_dim)) v; W_o
-    MoE:  p = softmax(n W_r) in float32; the k largest p; weights are those
-          p as they are unless `norm_topk_prob`; sum_e p_e W_down,e
-          (silu(W_gate,e n) * W_up,e n). Dropless: no capacity, no token
-          dropped or re-routed. One sum, two blockings, chosen from the
-          static shape (`experts_batched`). Grouped: tokens are sorted by
-          expert, multiplied group by group (`jax.lax.ragged_dot`: each
-          expert's rows, however many) and un-sorted; the learner's
-          minibatch and a prefill. Batched: every row through every expert
-          in products batched over the experts, each term weighted p_e or
-          exactly 0 before the sum; a decode step, whose groups of a few
-          rows would each cost the grouped product an MXU tile while the
-          step is bound by reading every expert's weights once anyway.
 
-Departures from the published model: the value head (OLMoE has none); no
+It is assembled from parts that the published `config.json` of a family
+names; nothing else chooses between them.
+
+Attention, one of:
+  full heads (OLMoE, arXiv:2409.02060, `model_type: olmoe`):
+      q, k, v = n W_q, n W_k, n W_v (no bias); q_norm, k_norm: RMSNorm over
+      the whole projection before the split into heads; rotate-half RoPE;
+      causal softmax(q k^T / sqrt(head_dim)) v; W_o. The cache holds K and
+      V, [B, S, heads, head_dim] each a layer.
+  latent (MLA; `kv_lora_rank` given; `model_type: glm4_moe_lite`, the
+  DeepSeek-V2/V3 form, arXiv:2405.04434 section 2.1):
+      c_q = RMSNorm(n W_qa);  q = c_q W_qb -> heads x (nope | rope)
+      [c_kv | k_r] = n W_kva;  c_kv = RMSNorm(c_kv);  k_r = RoPE(k_r), one
+      rotary key shared by every head
+      [k_nope | v] = c_kv W_kvb -> heads x (nope | v_head_dim)
+      q_h = [q_nope | RoPE(q_rope)], k_h = [k_nope | k_r];
+      causal softmax(q_h k_h^T / sqrt(nope + rope)) v_h; heads joined; W_o.
+      Two forms of that one sum. Decompressed (a causal pass): k_nope and v
+      are made for every position. Absorbed (a decode step): W_kvb is split
+      by head into W_UK [c, nope] and W_UV [c, v]; a head's score against
+      position s is (W_UK q_nope) . c_kv,s + q_rope . k_r,s and its output
+      W_UV^T (sum_s a_s c_kv,s), so the cache holds c_kv after its norm and
+      k_r after RoPE, [B, S, kv_lora_rank + rope] a layer, and nothing else.
+
+Feed-forward, by layer: the first `first_k_dense_replace` layers a dense
+SwiGLU; the others routed experts, beside `n_shared_experts` shared ones
+that every token passes:
+      sum over the chosen e of w_e W_down,e (silu(W_gate,e n) * W_up,e n)
+Dropless: no capacity, no token dropped or re-routed. The layer may hold a
+share of the experts (`experts_held`, from `first_expert_held`): it routes
+over all of them, computes the chosen ones it holds and leaves out what the
+absent ones would add; that partial sum goes on, as on one chip of an
+expert-parallel stage without its exchange. One sum, two blockings, chosen
+from the static shape (`experts_batched`). Grouped: the (row, expert) pairs
+sorted by expert, absent experts' pairs last, multiplied group by group
+(`jax.lax.ragged_dot`) and un-sorted; the learner's minibatch and a
+prefill. Batched: every row through every held expert in products batched
+over the experts, each term weighted w_e or exactly 0 before the sum; a
+decode step, whose groups of a few rows would each cost the grouped product
+an MXU tile while the step is bound by reading every expert's weights once
+anyway.
+
+Router, float32, one of:
+  softmax (OLMoE): p = softmax(n W_r); the k largest p; weights are those
+      p as they are unless `norm_topk_prob`.
+  sigmoid with a selection bias (`topk_method: noaux_tc`; one group):
+      s = sigmoid(n W_r); the k largest of s + b choose; weights are s at
+      the chosen experts, without b, over their sum (`norm_topk_prob`),
+      times `routed_scaling_factor`. b is a constant of the model: no
+      gradient, no optimizer state (its balancing update belongs to
+      pre-training).
+
+The next-next-token module (`num_nextn_predict_layers`; DeepSeek-V3,
+arXiv:2412.19437 section 2.2), in the learner only: for position t with the
+trunk's hidden x_t (before the final norm) and the next token u_{t+1},
+      z_t = W_eh [RMSNorm_h(x_t) | RMSNorm_e(E[u_{t+1}])]
+one more expert layer of the model's own kind on z (positions as the
+trunk's), a final norm of its own, the trunk's embedding and head, and the
+cross-entropy against u_{t+2}, masked where t + 2 leaves the episode. As a
+token policy obs[t+1] is the action taken at t, so the causal pass's own
+`obs` is all it needs. x_t, E and W_head are read under `stop_gradient`: the
+module follows the policy and does not move it. Its loss goes to the
+"losses" collection (the caller's objective adds what a model puts there)
+and is computed only where the caller keeps that collection.
+
+Departures from the published models: the value head (neither has one); no
 auxiliary router loss (the RL objective has no place for it; the
 `expert_load_*` counters show what follows); parameters, router, final norm
 and heads are float32 and the block's activations `compute_dtype`
 (bfloat16: the repo's convention, as the Nature-CNN's trunk); key/value
-heads equal query heads (OLMoE's own layout; grouped heads are refused).
+heads equal query heads (both layouts; grouped heads are refused).
 
 One set of parameters, two forms (the stateful-policy protocol of
 `JaxPolicy`: `model(obs[B, T], state, reset[B, T])`):
 
 * `causal`: [B, T] tokens from an empty window, one pass; a `reset` inside
   the fragment starts a new episode (its own positions, no attention across
-  the boundary). The learner's form, and the prefill.
-* `decode`: one token a row against a key/value cache of `context_len`
-  positions a layer ([B, S, heads, head_dim], `compute_dtype`); appends the
-  position's K/V and returns its logits and value. The rollout's form.
-  Its attention reads the cache positions [0, n) only, n the furthest
-  position any row of the batch holds, rounded up to a block of
-  `DECODE_CACHE_BLOCK` positions and chosen inside the step from `pos`
-  (`cached_attention`); each row masks what it does not hold itself.
+  the boundary). The learner's form, and the prefill. With more than one
+  block, each is recomputed in the backward pass (`jax.checkpoint`): what a
+  backward pass holds is then one block's activations, which with one block
+  it holds anyway.
+* `decode`: one token a row against the caches of `context_len` positions
+  (`compute_dtype`); writes the position's entries and returns its logits
+  and value. The rollout's form. Its attention reads the cache positions
+  [0, n) only, n the furthest position any row of the batch holds, rounded
+  up to a block of `DECODE_CACHE_BLOCK` positions and chosen inside the
+  step from `pos` (`cached_attention`; a latent cache is read whole); each
+  row masks what it does not hold itself.
 
 Both return the cache, so a decode can follow a causal pass.
 """
@@ -58,7 +111,7 @@ import jax.numpy as jnp
 
 Dtype = Any
 
-# HF `config.json` keys the family is described by -> module fields.
+# HF `config.json` keys a family is described by -> module fields.
 OLMOE_CONFIG_KEYS = {
     "vocab_size": "vocab_size",
     "hidden_size": "hidden_size",
@@ -71,6 +124,41 @@ OLMOE_CONFIG_KEYS = {
     "rope_theta": "rope_theta",
     "rms_norm_eps": "rms_eps",
     "norm_topk_prob": "norm_topk_prob",
+}
+GLM4_MOE_LITE_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_hidden_layers": "num_layers",
+    "q_lora_rank": "q_lora_rank",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
+    "first_k_dense_replace": "dense_layers",
+    "intermediate_size": "dense_width",
+    "n_routed_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "n_shared_experts": "shared_experts",
+    "norm_topk_prob": "norm_topk_prob",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "num_nextn_predict_layers": "nextn_layers",
+    "max_position_embeddings": "context_len",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_eps",
+    # The deployment's, not the model's: the share of the routed experts
+    # this chip holds (all of them where not given).
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+}
+# Published keys that must say what the decoder does (a value it has no
+# part for is refused, not ignored).
+GLM4_MOE_LITE_FIXED = {
+    "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+    "hidden_act": "silu", "attention_bias": False, "rope_scaling": None,
+    "partial_rotary_factor": 1, "tie_word_embeddings": False,
+    "model_type": "glm4_moe_lite",
 }
 
 
@@ -92,15 +180,31 @@ def rope(x, positions, theta):
     return (x32 * jnp.cos(angles) + rotated * jnp.sin(angles)).astype(x.dtype)
 
 
-def route(n, router, k, renormalise):
-    """Float32 router: (weights [M, k], experts [M, k]) for rows n [M, H]."""
+def swiglu(n, w_gate, w_up, w_down):
+    """W_down (silu(W_gate n) * W_up n) for rows n, weights in n's dtype."""
+    return jnp.dot(jax.nn.silu(jnp.dot(n, w_gate)) * jnp.dot(n, w_up), w_down)
+
+
+def route(n, router, k, renormalise, bias=None, scale=1.0):
+    """Float32 router: (weights [M, k], experts [M, k]) for rows n [M, H].
+    Without `bias`: softmax, the k largest. With `bias` [E]: sigmoid
+    scores, the k largest of score + bias choose, the weights are the
+    scores alone; the bias is a constant here. Weights are divided by
+    their sum where `renormalise`, then times `scale`."""
     with jax.named_scope("policy/router"):
         logits = jnp.dot(n.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, k)
+        if bias is None:
+            top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, top_i = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias), k)
+            top_p = jnp.take_along_axis(scores, top_i, axis=-1)
         if renormalise:
             top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if scale != 1.0:
+            top_p = top_p * scale
     return top_p, top_i
 
 
@@ -122,19 +226,24 @@ GROUPED_ROW_COST = 1.7
 # of, so a smaller block reads less; every block of the window is one more
 # branch of the step's `switch`, traced wherever a decode step is (the
 # rollout, and the learner's bootstrap step under `value_and_grad`) on
-# every start, compile cache or not. On a v5e at the published widths, 128
-# rows, a window of 1,024 (PERF.md section 5), a decode step / the token
+# every start, compile cache or not. On a v5e at OLMoE's published widths,
+# 128 rows, a window of 1,024 (PERF.md section 5), a decode step / the token
 # cell's warm set-up once the chip is open: the window read whole 3.11 ms
 # / 22.5 s; blocks of 512 2.64 ms; of 256 2.44 ms / 23.1 s; of 128 2.35 ms
 # / 25.1 s; of 64 2.32 ms.
 DECODE_CACHE_BLOCK = 256
 
 
-def cached_attention(q, k_cache, v_cache, pos):
-    """softmax(q k^T / sqrt(head_dim)) v of one query a row, q [B, heads,
-    head_dim], over the positions [0, pos[b]] that row holds of the caches
-    [B, S, heads, head_dim]. Returns ([B, heads, head_dim] in q's dtype, the
-    positions read).
+def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
+    """softmax(q k^T * scale) v of one query a row, q [B, heads, d], over
+    the positions [0, pos[b]] that row holds of the caches. Returns
+    ([B, heads, value width] in q's dtype, the positions read). `scale` is
+    d ** -0.5 where not given.
+
+    Two kinds of cache. A head's own keys and values: `k_cache`, `v_cache`
+    [B, S, heads, d]. One latent head that all query heads share:
+    `k_cache` [B, S, d] and `v_cache` None; the values are then the first
+    `value_dim` of the same rows.
 
     Read, scored and multiplied are the positions [0, n) alone: n is the
     furthest position any row holds, rounded up to whole blocks. A position
@@ -146,29 +255,51 @@ def cached_attention(q, k_cache, v_cache, pos):
     transpose, which a loop whose trip count comes from data has not (the
     learner differentiates its bootstrap step through this).
 
-    Both products are written as what they are, a matrix times one vector
-    a row and head: operands in q's dtype, multiplied and summed in
-    float32. Outside a conditional XLA:TPU makes that of the einsum itself;
-    inside one it made the scores a convolution over a transposed copy of
-    the prefix, and the step was slower than with the window read whole
-    (3.08 against 2.97 ms, PERF.md section 5). In this form the fusion
-    that multiplies the prefix reads it where it lies."""
+    With a head's own keys both products are a matrix times one vector a
+    row and head, and are written as that: operands in q's dtype,
+    multiplied and summed in float32. Outside a conditional XLA:TPU makes
+    that of the einsum itself; inside one it made the scores a convolution
+    over a transposed copy of the prefix, and the step was slower than with
+    the window read whole (3.08 against 2.97 ms, PERF.md section 5). In
+    this form the fusion that multiplies the prefix reads it where it lies.
+    With a shared latent head they are matrix products, every query head
+    of a row against the same [n, d] rows, and are written as those, over
+    the whole window in one branch: wherever such a product stands in a
+    conditional XLA:TPU first copies the WHOLE window to another layout,
+    in every branch, whatever the prefix (glm4_moe_lite's widths on a v5e,
+    128 rows, a window of 1,024: a step 4.16 ms whole, 4.83 in blocks of
+    512, 5.11 of 256; PERF.md section 5). Reading a latent prefix where it
+    lies takes a kernel (ROADMAP R-A9), which brings its own blocks."""
     S = k_cache.shape[1]
-    ends = tuple(range(DECODE_CACHE_BLOCK, S, DECODE_CACHE_BLOCK)) + (S,)
+    size = S if v_cache is None else DECODE_CACHE_BLOCK
+    ends = tuple(range(size, S, size)) + (S,)
     f32 = jnp.float32
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
 
     def attend(n, q, k_cache, v_cache, pos):
         held = jnp.arange(n)[None, :] <= pos[:, None]
+        if v_cache is None:
+            k = k_cache[:, :n]
+            # [B, heads, n]
+            scores = jnp.einsum("bhd,bsd->bhs", q, k,
+                                preferred_element_type=f32) * scale
+            scores = jnp.where(held[:, None, :], scores, -jnp.inf)
+            attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            # Against the whole rows, the values cut out of the product
+            # (a product against `k[..., :value_dim]` copies the rows).
+            return jnp.einsum("bhs,bsd->bhd", attn, k,
+                              preferred_element_type=f32).astype(
+                                  q.dtype)[..., :value_dim]
         k, v = k_cache[:, :n].astype(f32), v_cache[:, :n].astype(f32)
         # [B, n, heads]
-        scores = jnp.sum(q[:, None].astype(f32) * k, axis=-1) * (
-            q.shape[-1] ** -0.5)
+        scores = jnp.sum(q[:, None].astype(f32) * k, axis=-1) * scale
         scores = jnp.where(held[:, :, None], scores, -jnp.inf)
         attn = jax.nn.softmax(scores, axis=1).astype(q.dtype)
         return jnp.sum(attn[..., None].astype(f32) * v,
                        axis=1).astype(q.dtype)
 
-    block = jnp.minimum(jnp.max(pos) // DECODE_CACHE_BLOCK, len(ends) - 1)
+    block = jnp.minimum(jnp.max(pos) // size, len(ends) - 1)
     o = jax.lax.switch(block, [functools.partial(attend, n) for n in ends],
                        q, k_cache, v_cache, pos)
     return o, jnp.asarray(ends)[block]
@@ -177,26 +308,36 @@ def cached_attention(q, k_cache, v_cache, pos):
 def experts_batched(M: int, k: int, E: int) -> bool:
     """Whether `M` rows, each routed to `k` of `E` experts, go through the
     batched form (`M * E` rows of work) or the grouped one (`M * k` sorted
-    rows in `E` groups): a function of the static shape alone."""
+    rows in `E` groups): a function of the static shape alone. A layer
+    that holds a share of the experts asks the same question of the same
+    numbers: with `held` of them here the batched form is `M * held` rows
+    of work and the grouped one `held` groups of `M * k * held / E`
+    expected rows, the same inequality times `held / E`."""
     return M * E <= GROUP_COST_ROWS * E + GROUPED_ROW_COST * M * k
 
 
-def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down):
+def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
+                     num_experts=None):
     """sum_e p_e W_down,e (silu(W_gate,e n) * W_up,e n) for rows n [M, H]
-    routed to `top_i` [M, k] with weights `top_p`; expert weights
-    [E, H, W] / [E, W, H] already in n's dtype. Returns ([M, H], rows a
-    group [E]).
+    routed to `top_i` [M, k] of `num_experts` with weights `top_p`, over
+    the experts held here: `first` .. `first + E - 1`, whose weights
+    [E, H, W] / [E, W, H] are given in n's dtype (all of them where
+    `num_experts` is not given). What an absent expert would add is left
+    out. Returns ([M, H], rows a held group [E]).
 
-    Two forms of that sum, chosen by `experts_batched(M, k, E)`; both take
-    operands in n's dtype, accumulate in float32, weight in float32 and
-    compute every chosen expert of every row.
+    Two forms of that sum, chosen by `experts_batched(M, k,
+    num_experts)`; both take operands in n's dtype, accumulate in float32,
+    weight in float32 and compute every chosen held expert of every row.
 
-    Grouped: the M*k (row, expert) pairs sorted by expert, three
-    `ragged_dot`s over the E groups, un-sorted, the k terms of a row
-    weighted and summed.
+    Grouped: the M*k (row, expert) pairs sorted by expert, those of absent
+    experts last and in no group, three `ragged_dot`s over the E groups,
+    un-sorted, the k terms of a row weighted (an absent one by nothing:
+    its rows of the products are made 0 on the way in and on the way out,
+    in both passes: `landed`) and summed. Shapes are static, so all M*k
+    sorted rows are gathered, as if every pair landed here.
 
-    Batched: c[m, e] = p[m, j] where top_i[m, j] == e, else 0;
-    a[e, m] = silu(n W_gate,e) * (n W_up,e) for all M rows and every
+    Batched: c[m, e] = p[m, j] where top_i[m, j] == first + e, else 0;
+    a[e, m] = silu(n W_gate,e) * (n W_up,e) for all M rows and every held
     expert; out[m] = sum_e c[m, e] a[e, m] W_down,e, the weight applied to
     a and e folded into the contraction: one product of [M, E*W] against
     W_down as [E*W, H]. The same sum: an expert a row did not choose has
@@ -204,11 +345,17 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down):
     expert's weights once, where they lie."""
     M, k = top_i.shape
     E = w_gate.shape[0]
+    share = num_experts is not None and (first, E) != (0, num_experts)
+    local = top_i - first if share else top_i
     with jax.named_scope("policy/dispatch"):
-        group_sizes = jnp.zeros(E, jnp.int32).at[top_i.reshape(-1)].add(1)
-    if experts_batched(M, k, E):
+        if share:
+            here = (local >= 0) & (local < E)
+            local = jnp.where(here, local, E)  # past every group
+        # An index past the last group is dropped by the scatter.
+        group_sizes = jnp.zeros(E, jnp.int32).at[local.reshape(-1)].add(1)
+    if experts_batched(M, k, num_experts or E):
         with jax.named_scope("policy/dispatch"):
-            chosen = top_i[:, :, None] == jnp.arange(E)
+            chosen = local[:, :, None] == jnp.arange(E)
             c = jnp.sum(jnp.where(chosen, top_p[:, :, None], 0.0), axis=1)
         with jax.named_scope("policy/experts_batched"):
             gate = jnp.einsum("mh,ehw->emw", n, w_gate)
@@ -217,72 +364,170 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down):
             mixed = jnp.einsum("emw,ewh->mh", a, w_down,
                                preferred_element_type=jnp.float32)
         return mixed.astype(n.dtype), group_sizes
+    def landed(x):
+        """Sorted rows [M*k, ..] with those past the last group made 0,
+        and their cotangents with them. A `ragged_dot` computes no row
+        that is in no group, and neither do its transposes: XLA:CPU
+        leaves 0 there, XLA:TPU whatever the memory held, so what the
+        backward pass handed back for the absent pairs was not 0 until
+        it was made so (a v5e, the cell's minibatch: gradients 35 times
+        the reference's norm and unrelated to them; PERF.md section 6).
+        A select, so that nothing that memory held can reach a product."""
+        if not share:
+            return x
+        return jnp.where(
+            (jnp.arange(M * k) < jnp.sum(group_sizes))[:, None], x, 0)
+
     with jax.named_scope("policy/dispatch"):
-        order = jnp.argsort(top_i.reshape(-1), stable=True)
-        rows = n[order // k]
+        order = jnp.argsort(local.reshape(-1), stable=True)
+        rows = landed(n[order // k])
     with jax.named_scope("policy/experts"):
-        gate = jax.lax.ragged_dot(rows, w_gate, group_sizes)
-        up = jax.lax.ragged_dot(rows, w_up, group_sizes)
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, group_sizes)
+        gate = landed(jax.lax.ragged_dot(rows, w_gate, group_sizes))
+        up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
+        out = jax.lax.ragged_dot(landed(jax.nn.silu(gate) * up), w_down,
+                                 group_sizes)
     with jax.named_scope("policy/dispatch"):
         unsorted = out[jnp.argsort(order)].reshape(M, k, -1)
+        if share:
+            unsorted = jnp.where(here[:, :, None], unsorted, 0)
         mixed = jnp.einsum("mkh,mk->mh", unsorted.astype(jnp.float32), top_p)
     return mixed.astype(n.dtype), group_sizes
 
 
-class OlmoeLayerParams(nn.Module):
-    """One layer's parameters, by the names the equations use."""
+# The router's selection bias at initialisation: the published model's is
+# what its balancing left there. Small beside the scores' spread (sigmoid
+# of a unit normal: ~0.2), large enough that choosing by score + bias and
+# weighing by score differ.
+ROUTER_BIAS_SCALE = 0.02
 
-    hidden_size: int
-    num_experts: int
-    expert_width: int
+
+class DecoderLayerParams(nn.Module):
+    """One layer's parameters, by the names the equations use: `shapes` is
+    ((name, kind, shape), ...), kind one of ones / dense / experts (a
+    leading axis of experts) / bias (a constant of the model, small and
+    seeded, in the "constants" collection: no gradient, no optimizer
+    state)."""
+
+    shapes: tuple
 
     def setup(self):
-        H, E, W = self.hidden_size, self.num_experts, self.expert_width
-        dense = nn.initializers.lecun_normal()
-        experts = nn.initializers.lecun_normal(batch_axis=(0,))
-        ones = nn.initializers.ones
-        shapes = {
-            "attn_norm": (ones, (H,)), "q_norm": (ones, (H,)),
-            "k_norm": (ones, (H,)), "mlp_norm": (ones, (H,)),
-            "wq": (dense, (H, H)), "wk": (dense, (H, H)),
-            "wv": (dense, (H, H)), "wo": (dense, (H, H)),
-            "router": (dense, (H, E)),
-            "w_gate": (experts, (E, H, W)), "w_up": (experts, (E, H, W)),
-            "w_down": (experts, (E, W, H)),
-        }
-        self.tensors = {name: self.param(name, init, shape)
-                        for name, (init, shape) in shapes.items()}
+        inits = {"ones": nn.initializers.ones,
+                 "dense": nn.initializers.lecun_normal(),
+                 "experts": nn.initializers.lecun_normal(batch_axis=(0,))}
+        tensors = {}
+        for name, kind, shape in self.shapes:
+            if kind == "bias":
+                tensors[name] = self.variable(
+                    "constants", name, lambda s=shape: ROUTER_BIAS_SCALE
+                    * jax.random.normal(self.make_rng("params"), s)).value
+            else:
+                tensors[name] = self.param(name, inits[kind], shape)
+        self.tensors = tensors
 
     def __call__(self) -> dict:
         return self.tensors
 
 
-class OlmoeNetwork(nn.Module):
-    """OLMoE as a token policy (see the module docstring)."""
+class TokenDecoder(nn.Module):
+    """A decoder as a token policy (see the module docstring). The
+    defaults are OLMoE's parts."""
 
     num_outputs: int
     vocab_size: int = 50304
     hidden_size: int = 2048
     num_heads: int = 16
     num_layers: int = 16
-    num_experts: int = 64
+    # Attention: full heads with QK-norm, or latent where `kv_lora_rank`.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Feed-forward: `dense_layers` leading dense layers, then experts.
+    dense_layers: int = 0
+    dense_width: int = 0
+    num_experts: int = 64  # the router's outputs
     experts_per_token: int = 8
     expert_width: int = 1024
+    experts_held: int = 0  # 0: all of them
+    first_expert_held: int = 0
+    shared_experts: int = 0
+    # Router: softmax, or sigmoid with a selection bias.
+    selection_bias: bool = False
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    # The next-next-token module, its loss's weight in the objective.
+    nextn_layers: int = 0
+    nextn_loss_weight: float = 0.1
     context_len: int = 4096
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
-    norm_topk_prob: bool = False
     compute_dtype: Dtype = jnp.bfloat16
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def latent_width(self) -> int:
+        """Values a position a layer of the latent cache (0: full heads)."""
+        return self.kv_lora_rank and self.kv_lora_rank + self.qk_rope_head_dim
+
+    def _layer_shapes(self, dense: bool) -> tuple:
+        H, heads = self.hidden_size, self.num_heads
+        shapes = [("attn_norm", "ones", (H,)), ("mlp_norm", "ones", (H,))]
+        if self.kv_lora_rank:
+            rq, rkv = self.q_lora_rank, self.kv_lora_rank
+            nope, rot, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                             self.v_head_dim)
+            shapes += [
+                ("wq_a", "dense", (H, rq)), ("q_a_norm", "ones", (rq,)),
+                ("wq_b", "dense", (rq, heads * (nope + rot))),
+                ("wkv_a", "dense", (H, rkv + rot)),
+                ("kv_a_norm", "ones", (rkv,)),
+                ("wkv_b", "dense", (rkv, heads * (nope + vd))),
+                ("wo", "dense", (heads * vd, H))]
+        else:
+            shapes += [("q_norm", "ones", (H,)), ("k_norm", "ones", (H,))]
+            shapes += [(w, "dense", (H, H)) for w in ("wq", "wk", "wv", "wo")]
+        if dense:
+            D = self.dense_width
+            return tuple(shapes + [
+                ("dense_gate", "dense", (H, D)), ("dense_up", "dense", (H, D)),
+                ("dense_down", "dense", (D, H))])
+        E, W = self.held, self.expert_width
+        shapes += [("router", "dense", (H, self.num_experts)),
+                   ("w_gate", "experts", (E, H, W)),
+                   ("w_up", "experts", (E, H, W)),
+                   ("w_down", "experts", (E, W, H))]
+        if self.selection_bias:
+            shapes.append(("router_bias", "bias", (self.num_experts,)))
+        if self.shared_experts:
+            SW = self.shared_experts * W
+            shapes += [("shared_gate", "dense", (H, SW)),
+                       ("shared_up", "dense", (H, SW)),
+                       ("shared_down", "dense", (SW, H))]
+        return tuple(shapes)
 
     def setup(self):
         H = self.hidden_size
+        if self.first_expert_held + self.held > self.num_experts:
+            raise ValueError(
+                f"experts {self.first_expert_held} .. "
+                f"{self.first_expert_held + self.held - 1} are not among "
+                f"the router's {self.num_experts}")
         self.embed = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab_size, H))
         self.layers = [
-            OlmoeLayerParams(H, self.num_experts, self.expert_width,
-                             name=f"layer_{i}")
+            DecoderLayerParams(self._layer_shapes(i < self.dense_layers),
+                               name=f"layer_{i}")
             for i in range(self.num_layers)]
+        self.nextn = [
+            DecoderLayerParams(self._layer_shapes(False) + (
+                ("hnorm", "ones", (H,)), ("enorm", "ones", (H,)),
+                ("eh_proj", "dense", (2 * H, H)),
+                ("final_norm", "ones", (H,))), name=f"nextn_{i}")
+            for i in range(self.nextn_layers)]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (H,))
         self.head = self.param(
             "head", nn.initializers.normal(0.01), (H, self.num_outputs))
@@ -292,29 +537,40 @@ class OlmoeNetwork(nn.Module):
 
     # -- the protocol ---------------------------------------------------
     def initial_state(self, batch_size: int):
-        """An empty window: per layer a K and a V cache, and each row's
-        count of positions held."""
-        shape = (batch_size, self.context_len, self.num_heads,
-                 self.hidden_size // self.num_heads)
+        """An empty window: a layer's caches (K and V of every head, or
+        the one latent), and each row's count of positions held."""
+        B, S = batch_size, self.context_len
+        if self.kv_lora_rank:
+            shapes = ((B, S, self.latent_width),)
+        else:
+            shapes = ((B, S, self.num_heads,
+                       self.hidden_size // self.num_heads),) * 2
         return {
-            "kv": tuple((jnp.zeros(shape, self.compute_dtype),
-                         jnp.zeros(shape, self.compute_dtype))
+            "kv": tuple(tuple(jnp.zeros(s, self.compute_dtype)
+                              for s in shapes)
                         for _ in range(self.num_layers)),
             "pos": jnp.zeros(batch_size, jnp.int32),
         }
 
     def decode_counters(self, batch_size: int) -> dict:
         """What a decode step of `batch_size` rows is, from its static
-        shape: the mean rows an expert group holds, whether the experts
-        multiply in the batched form (1.0) or the grouped one (0.0), and
-        the positions in a block of the caches its attention reads."""
+        shape: the mean rows a held expert group holds, whether the
+        experts multiply in the batched form (1.0) or the grouped one
+        (0.0), the positions in a block of the caches its attention
+        reads, and with a latent cache its bytes a position."""
         k, E = self.experts_per_token, self.num_experts
-        return {
+        out = {
             "decode_rows_per_expert": batch_size * k / E,
             "decode_experts_batched": float(
                 experts_batched(batch_size, k, E)),
-            "decode_cache_block": min(DECODE_CACHE_BLOCK, self.context_len),
+            "decode_cache_block": self.context_len if self.kv_lora_rank
+            else min(DECODE_CACHE_BLOCK, self.context_len),
         }
+        if self.kv_lora_rank:
+            out["latent_cache_bytes_per_token"] = (
+                self.num_layers * self.latent_width
+                * jnp.dtype(self.compute_dtype).itemsize)
+        return out
 
     def __call__(self, obs, state, reset):
         """obs [B, T] token ids, reset [B, T] (1 where an episode starts
@@ -326,7 +582,7 @@ class OlmoeNetwork(nn.Module):
             return logits[:, None], value[:, None], state
         return self.causal(obs, reset)
 
-    # -- shared pieces --------------------------------------------------
+    # -- attention, both kinds, both forms --------------------------------
     def _qkv(self, lp, n):
         cd, eps = self.compute_dtype, self.rms_eps
         heads = n.shape[:-1] + (self.num_heads, -1)
@@ -335,16 +591,148 @@ class OlmoeNetwork(nn.Module):
         v = jnp.dot(n, lp["wv"].astype(cd))
         return q.reshape(heads), k.reshape(heads), v.reshape(heads)
 
-    def _moe(self, lp, h):
-        """h + MoE(RMSNorm(h)) for rows h [M, H]; (out, rows a group,
-        experts [M, k])."""
+    def _latents(self, lp, n, positions):
+        """(c_q, the cache's rows [c_kv | k_r]) of rows n at `positions`."""
+        cd, eps = self.compute_dtype, self.rms_eps
+        with jax.named_scope("policy/mla_latent"):
+            c_q = rms_norm(jnp.dot(n, lp["wq_a"].astype(cd)),
+                           lp["q_a_norm"], eps, cd)
+            kv = jnp.dot(n, lp["wkv_a"].astype(cd))
+            c_kv = rms_norm(kv[..., :self.kv_lora_rank], lp["kv_a_norm"],
+                            eps, cd)
+            k_r = rope(kv[..., None, self.kv_lora_rank:], positions,
+                       self.rope_theta)[..., 0, :]
+            return c_q, jnp.concatenate([c_kv, k_r], axis=-1)
+
+    def _queries(self, lp, c_q, positions):
+        """(q_nope [..., heads, nope], RoPE(q_rope) [..., heads, rope])."""
+        q = jnp.dot(c_q, lp["wq_b"].astype(self.compute_dtype)).reshape(
+            c_q.shape[:-1] + (self.num_heads, -1))
+        nope = self.qk_nope_head_dim
+        return q[..., :nope], rope(q[..., nope:], positions, self.rope_theta)
+
+    def _wkv_b(self, lp):
+        """W_kvb by head: (W_UK [c, heads, nope], W_UV [c, heads, v])."""
+        w = lp["wkv_b"].astype(self.compute_dtype).reshape(
+            self.kv_lora_rank, self.num_heads, -1)
+        return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
+
+    def _attend_causal(self, lp, x, positions, mask, cache_rows):
+        """x + Attention(RMSNorm(x)) over a fragment [B, T, H] from an
+        empty window; (h, the layer's caches)."""
+        cd, eps = self.compute_dtype, self.rms_eps
+        B, T, _ = x.shape
+        if not self.kv_lora_rank:
+            with jax.named_scope("policy/attention"):
+                n = rms_norm(x, lp["attn_norm"], eps, cd)
+                q, k, v = self._qkv(lp, n)
+                q = rope(q, positions, self.rope_theta)
+                k = rope(k, positions, self.rope_theta)
+                scores = jnp.einsum(
+                    "bqhd,bkhd->bhqk", q, k,
+                    preferred_element_type=jnp.float32) * (
+                        q.shape[-1] ** -0.5)
+                scores = jnp.where(mask[:, None], scores, -jnp.inf)
+                attn = jax.nn.softmax(scores, axis=-1).astype(cd)
+                o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, T, -1)
+                h = x + jnp.dot(o, lp["wo"].astype(cd))
+                caches = tuple(
+                    jnp.take_along_axis(
+                        a, cache_rows[:, :, None, None], axis=1)
+                    for a in (k, v))
+            return h, caches
+        # Latent attention, decompressed: keys and values of every head
+        # are made from the latents for every position.
+        n = rms_norm(x, lp["attn_norm"], eps, cd)
+        c_q, latent = self._latents(lp, n, positions)
+        with jax.named_scope("policy/mla_latent"):
+            caches = (jnp.take_along_axis(
+                latent, cache_rows[:, :, None], axis=1),)
+        with jax.named_scope("policy/mla_expand"):
+            q_nope, q_rope = self._queries(lp, c_q, positions)
+            w_uk, w_uv = self._wkv_b(lp)
+            c_kv, k_r = (latent[..., :self.kv_lora_rank],
+                         latent[..., self.kv_lora_rank:])
+            k_nope = jnp.einsum("bsc,chd->bshd", c_kv, w_uk)
+            v = jnp.einsum("bsc,chd->bshd", c_kv, w_uv)
+        with jax.named_scope("policy/mla_attend"):
+            scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_r,
+                                   preferred_element_type=jnp.float32))
+            scores = scores * (
+                self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+            scores = jnp.where(mask[:, None], scores, -jnp.inf)
+            attn = jax.nn.softmax(scores, axis=-1).astype(cd)
+            o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, T, -1)
+        with jax.named_scope("policy/mla_expand"):
+            h = x + jnp.dot(o, lp["wo"].astype(cd))
+        return h, caches
+
+    def _attend_step(self, lp, x, pos, caches):
+        """x + Attention(RMSNorm(x)) of one token a row, x [B, H], against
+        the layer's caches, this position written first; (h, the caches,
+        the positions read)."""
+        cd, eps = self.compute_dtype, self.rms_eps
+        B = x.shape[0]
+        rows = jnp.arange(B)
+        if not self.kv_lora_rank:
+            k_cache, v_cache = caches
+            with jax.named_scope("policy/attention"):
+                n = rms_norm(x, lp["attn_norm"], eps, cd)
+                q, k, v = self._qkv(lp, n)
+                q = rope(q, pos, self.rope_theta)
+                k = rope(k, pos, self.rope_theta)
+                k_cache = k_cache.at[rows, pos].set(k)
+                v_cache = v_cache.at[rows, pos].set(v)
+                o, read = cached_attention(q, k_cache, v_cache, pos)
+                h = x + jnp.dot(o.reshape(B, -1), lp["wo"].astype(cd))
+            return h, (k_cache, v_cache), read
+        # Latent attention, absorbed: W_UK goes into the query and W_UV
+        # onto the weighted latents, so the cache's rows are read as they
+        # lie.
+        (cache,) = caches
+        n = rms_norm(x, lp["attn_norm"], eps, cd)
+        c_q, latent = self._latents(lp, n, pos)
+        with jax.named_scope("policy/mla_latent"):
+            cache = cache.at[rows, pos].set(latent)
+        with jax.named_scope("policy/mla_expand"):
+            q_nope, q_rope = self._queries(lp, c_q, pos)
+            w_uk, w_uv = self._wkv_b(lp)
+            q = jnp.concatenate(
+                [jnp.einsum("bhd,chd->bhc", q_nope, w_uk), q_rope], axis=-1)
+        with jax.named_scope("policy/mla_attend"):
+            o, read = cached_attention(
+                q, cache, None, pos,
+                scale=(self.qk_nope_head_dim
+                       + self.qk_rope_head_dim) ** -0.5,
+                value_dim=self.kv_lora_rank)
+        with jax.named_scope("policy/mla_expand"):
+            o = jnp.einsum("bhc,chd->bhd", o, w_uv).reshape(B, -1)
+            h = x + jnp.dot(o, lp["wo"].astype(cd))
+        return h, (cache,), read
+
+    # -- feed-forward -----------------------------------------------------
+    def _feed_forward(self, lp, h):
+        """h + FeedForward(RMSNorm(h)) for rows h [M, H]; (out, rows a held
+        group, experts [M, k]), the last two None of a dense layer."""
         cd = self.compute_dtype
         n = rms_norm(h, lp["mlp_norm"], self.rms_eps, cd)
-        top_p, top_i = route(n, lp["router"], self.experts_per_token,
-                             self.norm_topk_prob)
+        if "dense_gate" in lp:
+            with jax.named_scope("policy/dense_mlp"):
+                return h + swiglu(n, *(lp[w].astype(cd) for w in (
+                    "dense_gate", "dense_up", "dense_down"))), None, None
+        top_p, top_i = route(
+            n, lp["router"], self.experts_per_token, self.norm_topk_prob,
+            lp.get("router_bias"), self.routed_scaling_factor)
         moe, group_sizes = dropless_experts(
             n, top_p, top_i, lp["w_gate"].astype(cd), lp["w_up"].astype(cd),
-            lp["w_down"].astype(cd))
+            lp["w_down"].astype(cd), self.first_expert_held,
+            self.num_experts)
+        if "shared_gate" in lp:
+            with jax.named_scope("policy/shared_expert"):
+                moe = moe + swiglu(n, *(lp[w].astype(cd) for w in (
+                    "shared_gate", "shared_up", "shared_down")))
         return h + moe, group_sizes, top_i
 
     def _heads(self, x):
@@ -357,24 +745,31 @@ class OlmoeNetwork(nn.Module):
     def _count(self, experts, loads=None, read=None):
         """What a pass counted, kept only where the caller asks for the
         collection (and never among the variables `init` returns): the
-        experts chosen [layers, ..., k], for the reference check; in the
-        learner's form also the rows of the fullest expert group over the
-        layers, and the mean group; in a decode step the share of the
-        window's positions its attention read."""
+        experts chosen [expert layers, ..., k], for the reference check;
+        in the learner's form also the rows of the fullest held expert
+        group over the layers and the mean group, and where the layer
+        holds a share, the share of the (row, expert) pairs that landed
+        here; in a decode step the share of the window's positions its
+        attention read."""
         if self.is_initializing():
             return
-        self.sow("routing", "experts", jnp.stack(experts))
-        if loads is not None:
+        if experts:
+            self.sow("routing", "experts", jnp.stack(experts))
+        if loads:
             loads = jnp.stack(loads).astype(jnp.float32)
             self.sow("counters", "expert_load_max", jnp.max(loads))
             self.sow("counters", "expert_load_mean", jnp.mean(loads))
+            if self.held != self.num_experts:
+                pairs = experts[0].size
+                self.sow("counters", "experts_held_row_share",
+                         jnp.mean(jnp.sum(loads, axis=-1)) / pairs)
         if read is not None:
             self.sow("counters", "decode_cache_read_share",
                      read.astype(jnp.float32) / self.context_len)
 
     # -- the two forms --------------------------------------------------
     def causal(self, tokens, reset):
-        cd, eps = self.compute_dtype, self.rms_eps
+        cd = self.compute_dtype
         B, T = tokens.shape
         S = self.context_len
         if T > S:
@@ -389,80 +784,139 @@ class OlmoeNetwork(nn.Module):
         positions = steps - start
         mask = (steps[:, None] >= steps[None, :])[None] & (
             episode[:, :, None] == episode[:, None, :])
-        # Where the last episode's K/V go in the cache: its own positions.
+        # Where the last episode's entries go in the cache: its own
+        # positions.
         cache_rows = jnp.clip(start[:, -1:] + jnp.arange(S), 0, T - 1)
+
+        def block(lp, x):
+            h, caches = self._attend_causal(
+                lp, x, positions, mask, cache_rows)
+            out, group_sizes, top_i = self._feed_forward(
+                lp, h.reshape(B * T, -1))
+            return out.reshape(B, T, -1), caches, group_sizes, top_i
+        if self.num_layers + self.nextn_layers > 1:
+            block = jax.checkpoint(block)
 
         x = self.embed[tokens].astype(cd)
         kv, loads, experts = [], [], []
         for layer in self.layers:
-            lp = layer()
-            with jax.named_scope("policy/attention"):
-                n = rms_norm(x, lp["attn_norm"], eps, cd)
-                q, k, v = self._qkv(lp, n)
-                q = rope(q, positions, self.rope_theta)
-                k = rope(k, positions, self.rope_theta)
-                scores = jnp.einsum(
-                    "bqhd,bkhd->bhqk", q, k,
-                    preferred_element_type=jnp.float32) * (
-                        q.shape[-1] ** -0.5)
-                scores = jnp.where(mask[:, None], scores, -jnp.inf)
-                attn = jax.nn.softmax(scores, axis=-1).astype(cd)
-                o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, T, -1)
-                h = x + jnp.dot(o, lp["wo"].astype(cd))
-                kv.append(tuple(
-                    jnp.take_along_axis(
-                        a, cache_rows[:, :, None, None], axis=1)
-                    for a in (k, v)))
-            out, group_sizes, top_i = self._moe(lp, h.reshape(B * T, -1))
-            x = out.reshape(B, T, -1)
+            x, caches, group_sizes, top_i = block(layer(), x)
+            kv.append(caches)
+            if top_i is not None:
+                loads.append(group_sizes)
+                experts.append(top_i.reshape(B, T, -1))
+        if self.nextn and (self.is_initializing()
+                           or self.is_mutable_collection("losses")):
+            group_sizes, top_i = self._next_next_token(
+                block, x, tokens, episode)
             loads.append(group_sizes)
             experts.append(top_i.reshape(B, T, -1))
         self._count(experts, loads)
         logits, value = self._heads(x)
         return logits, value, {"kv": tuple(kv), "pos": positions[:, -1] + 1}
 
-    def decode(self, token, state, reset):
+    def _next_next_token(self, block, x, tokens, episode):
+        """The module's loss over a fragment (see the module docstring),
+        into "losses" (weighted, a sum over the positions, as the
+        objective's other terms), "counters" (`mtp_loss`, a position's
+        mean) and "routing" (position by position, for the reference
+        check); (rows a held group, experts) of its expert layer."""
         cd, eps = self.compute_dtype, self.rms_eps
-        B = token.shape[0]
-        pos = jnp.where(reset > 0, 0, state["pos"])
-        rows = jnp.arange(B)
+        T = tokens.shape[1]
+        (module,) = self.nextn
+        lp = module()
+        with jax.named_scope("policy/mtp"):
+            following = jnp.roll(tokens, -1, axis=1)
+            target = jnp.roll(tokens, -2, axis=1)
+            valid = (jnp.arange(T) + 2 < T)[None] & (
+                jnp.roll(episode, -2, axis=1) == episode)
+            embed = jax.lax.stop_gradient(self.embed)
+            z = jnp.dot(jnp.concatenate([
+                rms_norm(jax.lax.stop_gradient(x), lp["hnorm"], eps, cd),
+                rms_norm(embed[following], lp["enorm"], eps, cd)], axis=-1),
+                lp["eh_proj"].astype(cd))
+        z, _, group_sizes, top_i = block(lp, z)
+        with jax.named_scope("policy/mtp"):
+            y = rms_norm(z, lp["final_norm"], eps, jnp.float32)
+            logp = jax.nn.log_softmax(
+                jnp.dot(y, jax.lax.stop_gradient(self.head)), axis=-1)
+            nll = -jnp.take_along_axis(logp, target[..., None], axis=-1)
+            by_position = jnp.where(valid, nll[..., 0], 0.0)
+            nll = jnp.sum(by_position)
+        if not self.is_initializing():
+            self.sow("routing", "nextn_nll", by_position)
+            self.sow("losses", "next_next_token",
+                     self.nextn_loss_weight * nll)
+            self.sow("counters", "mtp_loss",
+                     nll / jnp.maximum(jnp.sum(valid), 1))
+        return group_sizes, top_i
 
-        x = self.embed[token].astype(cd)
+    def decode(self, token, state, reset):
+        pos = jnp.where(reset > 0, 0, state["pos"])
+        x = self.embed[token].astype(self.compute_dtype)
         kv, experts = [], []
-        for layer, (k_cache, v_cache) in zip(self.layers, state["kv"]):
+        for layer, caches in zip(self.layers, state["kv"]):
             lp = layer()
-            with jax.named_scope("policy/attention"):
-                n = rms_norm(x, lp["attn_norm"], eps, cd)
-                q, k, v = self._qkv(lp, n)
-                q = rope(q, pos, self.rope_theta)
-                k = rope(k, pos, self.rope_theta)
-                k_cache = k_cache.at[rows, pos].set(k)
-                v_cache = v_cache.at[rows, pos].set(v)
-                o, read = cached_attention(q, k_cache, v_cache, pos)
-                h = x + jnp.dot(o.reshape(B, -1), lp["wo"].astype(cd))
-                kv.append((k_cache, v_cache))
-            x, _, top_i = self._moe(lp, h)
-            experts.append(top_i)
+            h, caches, read = self._attend_step(lp, x, pos, caches)
+            kv.append(caches)
+            x, _, top_i = self._feed_forward(lp, h)
+            if top_i is not None:
+                experts.append(top_i)
+        if self.is_initializing():
+            for module in self.nextn:
+                module()
         self._count(experts, read=read)
         logits, value = self._heads(x)
         return logits, value, {"kv": tuple(kv), "pos": pos + 1}
 
 
-def olmoe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
-    """`OlmoeNetwork` from a `custom_model_config` that speaks the
-    published `config.json`'s own keys (unknown keys are refused)."""
-    unknown = set(cfg) - set(OLMOE_CONFIG_KEYS) - {"num_key_value_heads"}
+def _refuse_unknown(cfg: dict, known, family: str) -> None:
+    unknown = set(cfg) - set(known)
     if unknown:
         raise ValueError(
-            f"custom_model_config keys {sorted(unknown)} are not OLMoE's; "
-            f"known: {sorted(OLMOE_CONFIG_KEYS)}")
+            f"custom_model_config keys {sorted(unknown)} are not {family}'s; "
+            f"known: {sorted(known)}")
+
+
+def _refuse_grouped_heads(cfg: dict, family: str, default_heads: int) -> None:
     kv = cfg.get("num_key_value_heads")
-    if kv is not None and kv != cfg.get("num_attention_heads", 16):
+    if kv is not None and kv != cfg.get("num_attention_heads", default_heads):
         raise ValueError(
-            "OlmoeNetwork has as many key/value heads as query heads "
-            f"(OLMoE's layout); got num_key_value_heads={kv}")
+            "TokenDecoder has as many key/value heads as query heads "
+            f"({family}'s layout); got num_key_value_heads={kv}")
+
+
+def olmoe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks OLMoE's
+    published `config.json`'s own keys (unknown keys are refused)."""
+    _refuse_unknown(cfg, set(OLMOE_CONFIG_KEYS) | {"num_key_value_heads"},
+                    "OLMoE")
+    _refuse_grouped_heads(cfg, "OLMoE", 16)
     fields = {OLMOE_CONFIG_KEYS[k]: v for k, v in cfg.items()
               if k in OLMOE_CONFIG_KEYS}
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
-    return OlmoeNetwork(num_outputs=num_outputs, **fields)
+    return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def glm4_moe_lite_from_config(num_outputs: int, cfg: dict,
+                              compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks
+    `glm4_moe_lite`'s published `config.json`'s own keys, and the two that
+    state the chip's share of the experts (unknown keys are refused, and
+    so is a published key whose value the decoder has no part for)."""
+    known = (set(GLM4_MOE_LITE_CONFIG_KEYS) | set(GLM4_MOE_LITE_FIXED)
+             | {"num_key_value_heads"})
+    _refuse_unknown(cfg, known, "glm4_moe_lite")
+    _refuse_grouped_heads(cfg, "glm4_moe_lite", 20)
+    for key, only in GLM4_MOE_LITE_FIXED.items():
+        if key in cfg and cfg[key] != only:
+            raise ValueError(
+                f"custom_model_config {key}={cfg[key]!r}: TokenDecoder has "
+                f"{only!r} alone")
+    fields = {GLM4_MOE_LITE_CONFIG_KEYS[k]: v for k, v in cfg.items()
+              if k in GLM4_MOE_LITE_CONFIG_KEYS}
+    fields["selection_bias"] = True  # `topk_method: noaux_tc`
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)

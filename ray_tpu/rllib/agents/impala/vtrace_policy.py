@@ -92,7 +92,7 @@ def _time_major(x, seq_len: int):
 
 
 def forward_with_bootstrap(policy, params, batch, T: int):
-    """`forward_counted` without the model's counters."""
+    """`forward_counted` without what the model counted or lost."""
     return forward_counted(policy, params, batch, T)[:3]
 
 
@@ -104,12 +104,14 @@ def forward_counted(policy, params, batch, T: int):
     [B, ...] (VectorSampler / Anakin batches), or a full per-row NEW_OBS
     column whose last row per fragment is the bootstrap observation
     (remote-worker pack mode). Returns (dist_inputs[B*T, O],
-    values[B*T], bootstrap_value[B], what the model counted in the pass).
+    values[B*T], bootstrap_value[B], what the model counted in the pass,
+    the loss terms of the model's own: sums over the batch, weighted, for
+    the objective to add).
     """
-    counters = {}
+    counters, model_losses = {}, {}
     if policy.recurrent:
-        (dist_bt, val_bt, carry), counters = policy.apply_sequences(
-            params, batch)
+        (dist_bt, val_bt, carry), counters, model_losses = \
+            policy.apply_sequences(params, batch)
         dist_inputs = dist_bt.reshape(-1, dist_bt.shape[-1])
         values_flat = val_bt.reshape(-1)
         B = batch[sb.OBS].shape[0] // T
@@ -133,7 +135,7 @@ def forward_counted(policy, params, batch, T: int):
         else:
             boot_obs = _time_major(batch[sb.NEW_OBS], T)[-1]
         _, bootstrap_value = policy.apply(params, boot_obs)
-    return dist_inputs, values_flat, bootstrap_value, counters
+    return dist_inputs, values_flat, bootstrap_value, counters, model_losses
 
 
 def vtrace_loss(policy, params, batch, rng, loss_state):
@@ -141,8 +143,8 @@ def vtrace_loss(policy, params, batch, rng, loss_state):
     T = cfg["rollout_fragment_length"]
     gamma = cfg["gamma"]
 
-    dist_inputs, values_flat, bootstrap_value, counters = forward_counted(
-        policy, params, batch, T)
+    dist_inputs, values_flat, bootstrap_value, counters, model_losses = \
+        forward_counted(policy, params, batch, T)
 
     # Time-major [T, B] log-probabilities of the taken actions and the
     # target policy's entropy. Logits narrow enough for the trajectory to
@@ -187,6 +189,8 @@ def vtrace_loss(policy, params, batch, rng, loss_state):
     total = (pi_loss
              + cfg["vf_loss_coeff"] * vf_loss
              - cfg["entropy_coeff"] * entropy)
+    for term in model_losses.values():
+        total = total + term
     n = values_flat.shape[0]
     rhos = jnp.exp(log_rhos)
     stats = {

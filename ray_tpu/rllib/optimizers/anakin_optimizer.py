@@ -134,6 +134,21 @@ class AnakinOptimizer(PolicyOptimizer):
         self._anakin_fn = self._build_fn()
 
     # ------------------------------------------------------------------
+    def learn(self, params, opt_state, batch, lkey):
+        """One optimizer update on `batch` (packed fragments, as the
+        rollout leaves them): what the fused program runs a minibatch,
+        traceable. Returns (params, opt_state, the loss's stats)."""
+        policy = self.policy
+        with jax.named_scope("anakin/loss"):
+            (loss, stats), grads = jax.value_and_grad(
+                policy._loss_fn, argnums=1, has_aux=True)(
+                    policy, params, batch, lkey, policy.loss_state)
+        with jax.named_scope("anakin/update"):
+            updates, opt_state = policy.optimizer.update(
+                grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, stats
+
     def _build_fn(self):
         policy = self.policy
         env = self.env
@@ -205,17 +220,7 @@ class AnakinOptimizer(PolicyOptimizer):
                 batch[sb.STATE_IN], batch["reset_in"] = pstate_in
             return batch
 
-        def learn(params, opt_state, batch, lkey):
-            """One optimizer update on `batch`."""
-            with jax.named_scope("anakin/loss"):
-                (loss, stats), grads = jax.value_and_grad(
-                    policy._loss_fn, argnums=1, has_aux=True)(
-                        policy, params, batch, lkey, policy.loss_state)
-            with jax.named_scope("anakin/update"):
-                updates, opt_state = policy.optimizer.update(
-                    grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            return params, opt_state, stats
+        learn = self.learn
 
         def learn_minibatches(params, opt_state, batch, lkey):
             """`num_sgd_iter` passes over the rollout, `num_mb` updates a

@@ -139,12 +139,22 @@ class JaxPolicy(Policy):
             dummy = np.zeros((1, 1) + tuple(obs_shape), dtype=obs_dtype)
             dummy_state = self.model.initial_state(1)
             dummy_mask = np.zeros((1, 1), np.float32)
-            self.params = self.model.init(
+            # One program: a model of several hundred million parameters
+            # is thousands of eager operations otherwise (most of two
+            # minutes on a chip's host).
+            self.params = jax.jit(self.model.init)(
                 self._next_rng(), dummy, dummy_state, dummy_mask)
         else:
             dummy = np.zeros((1,) + tuple(obs_shape), dtype=obs_dtype)
             self.params = self.model.init(self._next_rng(), dummy)
         self.optimizer = (optimizer_fn or default_optimizer)(config)
+        if set(self.params) - {"params"}:
+            # What a model keeps outside "params" is constant: no update,
+            # no optimizer state (and no part in the gradient's norm).
+            self.optimizer = optax.multi_transform(
+                {"params": self.optimizer, "constant": optax.set_to_zero()},
+                {k: "params" if k == "params" else "constant"
+                 for k in self.params})
         self.opt_state = self.optimizer.init(self.params)
 
         # Mesh + layout: the param/opt-state shardings resolve through
@@ -223,7 +233,7 @@ class JaxPolicy(Policy):
         initial state and done-driven resets, flatten back to [N]."""
         if not self.recurrent:
             return self.apply(params, batch[sb.OBS])
-        (dist_bt, val_bt, _), _ = self.apply_sequences(params, batch)
+        (dist_bt, val_bt, _), _, _ = self.apply_sequences(params, batch)
         O = dist_bt.shape[-1]
         return dist_bt.reshape(-1, O), val_bt.reshape(-1)
 
@@ -232,7 +242,8 @@ class JaxPolicy(Policy):
 
         Returns ((dist_inputs[B,L,O], value[B,L], final_carry), what the
         model counted in the pass: its "counters" collection, {} for a
-        model that counts nothing). Initial
+        model that counts nothing, and the loss terms of its own that the
+        objective is to add: its "losses" collection, as a rule {}). Initial
         state is each sequence's recorded one: `state_in`, a pytree with a
         row a sequence (device-resident rollouts), or the first row of
         the per-step `state_in_c/h` columns (host samplers); a batch with
@@ -259,11 +270,15 @@ class JaxPolicy(Policy):
         return self._apply_counted(params, obs_bt, state, reset)
 
     def _apply_counted(self, params, obs_bt, state, reset):
-        """`apply` of a stateful model and its "counters" collection."""
-        out, counted = self.apply(params, obs_bt, state, reset,
-                                  mutable=["counters"])
-        return out, {k: v[-1] for k, v in
-                     counted.get("counters", {}).items()}
+        """`apply` of a stateful model, its "counters" collection and its
+        "losses" collection (a model computes a loss of its own only where
+        the caller keeps that collection, as this one does)."""
+        out, kept = self.apply(params, obs_bt, state, reset,
+                               mutable=["counters", "losses"])
+        counters, losses = (
+            {k: v[-1] for k, v in kept.get(name, {}).items()}
+            for name in ("counters", "losses"))
+        return out, counters, losses
 
     def initial_state(self, batch_size: int):
         """The model's rollout state for `batch_size` rows, as the pytree
@@ -274,7 +289,7 @@ class JaxPolicy(Policy):
         """One rollout step of a stateful policy: obs [B], reset [B] (1
         where the previous step ended an episode) -> (dist_inputs [B, O],
         value [B], state, what the model counted in the step)."""
-        (dist_bt, val_bt, state), counted = self._apply_counted(
+        (dist_bt, val_bt, state), counted, _ = self._apply_counted(
             params, obs[:, None], state, reset[:, None])
         return dist_bt[:, 0], val_bt[:, 0], state, counted
 

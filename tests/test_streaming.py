@@ -241,8 +241,10 @@ class TestOperatorDeath:
         # without error, so the item goes out once the death is known.
         wait_until(death_observed, timeout=60)
         sender = EdgeSender(s, "e0", 2)
-        sender.push(1)
+        # Once the caller's actor table says DEAD the submit itself
+        # raises; before that the push goes out and its drain does.
         with _pytest.raises(ActorDiedError):
+            sender.push(1)
             while sender.inflight:
                 sender.drain_oldest(redeliver_timeout_s=1.0)
 
