@@ -80,6 +80,19 @@ and heads are float32 and the block's activations `compute_dtype`
 (bfloat16: the repo's convention, as the Nature-CNN's trunk); key/value
 heads equal query heads (both layouts; grouped heads are refused).
 
+A causal pass's attention, either layout (`causal_attention`; the latent
+one decompressed, every head's key [k_nope | k_r]): one sum, two blockings,
+as the experts'. Plain: the [B, heads, T, T] scores as one float32 array,
+masked, normalised, cast, multiplied. Fused: the library's TPU splash
+attention kernel and its backward kernel, tiles of `CAUSAL_TILE` queries
+against tiles of keys with the running maximum and sum in VMEM, tiles above
+the diagonal skipped, the episode as segment ids; no [T, T] array is
+written in either pass. Chosen from the static shape (`causal_fused`: whole
+tiles, at least two, widths of whole MXU tiles) and the platform the
+program is lowered for (a TPU: fused; anything else, and every fragment of
+a test or a rehearsal: plain). Queries, keys and values are made
+head-major, [B, heads, T, d], the layout the kernel reads.
+
 One set of parameters, two forms (the stateful-policy protocol of
 `JaxPolicy`: `model(obs[B, T], state, reset[B, T])`):
 
@@ -88,7 +101,8 @@ One set of parameters, two forms (the stateful-policy protocol of
   the boundary). The learner's form, and the prefill. With more than one
   block, each is recomputed in the backward pass (`jax.checkpoint`): what a
   backward pass holds is then one block's activations, which with one block
-  it holds anyway.
+  it holds anyway; beside them it keeps the fused attention's output and
+  log-sum-exp, so that kernel runs once a block.
 * `decode`: one token a row against the caches of `context_len` positions
   (`compute_dtype`); writes the position's entries and returns its logits
   and value. The rollout's form. Its attention reads the cache positions
@@ -162,22 +176,28 @@ GLM4_MOE_LITE_FIXED = {
 }
 
 
-def rms_norm(x, weight, eps, dtype):
+def rms_norm(x, weight, eps, dtype, axes=(-1,)):
+    """RMSNorm over `axes` of x (the last one; or the axes a projection's
+    width lies split over, `weight` shaped to broadcast against x)."""
     x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=axes, keepdims=True)
     return (weight * (x32 * jax.lax.rsqrt(var + eps))).astype(dtype)
 
 
-def rope(x, positions, theta):
-    """Rotate-half RoPE. x: [..., heads, head_dim]; positions: x.shape[:-2]."""
+def rope(x, positions, theta, scale=1.0, head_major=False):
+    """Rotate-half RoPE, times `scale` before the cast back to x's dtype.
+    x: [..., heads, head_dim] with positions x.shape[:-2]; or, head-major,
+    [..., heads, T, head_dim] with positions [..., T]."""
     dim = x.shape[-1]
     inv_freq = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
-    angles = jnp.concatenate([angles, angles], axis=-1)[..., None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    angles = angles[..., None, :, :] if head_major else angles[..., None, :]
     x32 = x.astype(jnp.float32)
     half = dim // 2
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return (x32 * jnp.cos(angles) + rotated * jnp.sin(angles)).astype(x.dtype)
+    out = x32 * jnp.cos(angles) + rotated * jnp.sin(angles)
+    return (out if scale == 1.0 else out * scale).astype(x.dtype)
 
 
 def swiglu(n, w_gate, w_up, w_down):
@@ -303,6 +323,98 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     o = jax.lax.switch(block, [functools.partial(attend, n) for n in ends],
                        q, k_cache, v_cache, pos)
     return o, jnp.asarray(ends)[block]
+
+
+# Positions in a tile of the fused causal attention (see
+# `causal_attention`): queries and keys alike, in the forward kernel and in
+# the backward one.
+CAUSAL_TILE = 512
+# The name under which the fused form's output and log-sum-exp are kept
+# across a recomputed block (`jax.checkpoint`'s policy in `causal`).
+CAUSAL_KEPT = "causal_attention_kept"
+
+
+def causal_fused(T: int, d_qk: int, d_v: int) -> bool:
+    """Whether a causal pass over `T` positions whose heads are `d_qk`
+    (queries, keys) and `d_v` (values) wide can take the fused form of
+    `causal_attention`: a function of the static shape alone. Whole tiles
+    and at least two of them (one tile is the plain form with a kernel's
+    set-up on top), and widths the MXU takes whole."""
+    return (T % CAUSAL_TILE == 0 and T >= 2 * CAUSAL_TILE
+            and d_qk % 128 == 0 and d_v % 128 == 0)
+
+
+def _causal_plain(q, k, v, episode, scale):
+    steps = jnp.arange(q.shape[2])
+    mask = (steps[:, None] >= steps[None, :])[None] & (
+        episode[:, :, None] == episode[:, None, :])
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask[:, None], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", attn, v)
+
+
+def _causal_fused(q, k, v, episode, scale):
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+    heads, T = q.shape[1:3]
+    t = CAUSAL_TILE
+    kernel = splash.make_splash_mha(
+        splash.MultiHeadMask([splash.CausalMask((T, T))] * heads),
+        block_sizes=splash.BlockSizes(
+            block_q=t, block_kv=t, block_kv_compute=t, block_q_dkv=t,
+            block_kv_dkv=t, block_kv_dkv_compute=t,
+            use_fused_bwd_kernel=True),
+        head_shards=1, q_seq_shards=1, residual_checkpoint_name=CAUSAL_KEPT)
+    segments = episode.astype(jnp.int32)
+    # The kernel takes no scale.
+    return jax.vmap(lambda q, k, v, s: kernel(
+        q, k, v, segment_ids=splash.SegmentIds(s, s)))(
+            q * scale, k, v, segments)
+
+
+def causal_attention(q, k, v, episode, scale):
+    """softmax(q k^T * scale) v over a fragment from an empty window, head
+    by head: q, k [B, heads, T, d_qk], v [B, heads, T, d_v], `episode`
+    [B, T] the number of the episode a step belongs to (it never falls
+    along a row). Position t attends to the positions s <= t of its own
+    episode, so a step that starts an episode attends to itself alone.
+    Returns [B, heads, T, d_v] in q's dtype.
+
+    Two forms of that one sum; both multiply operands in q's dtype and
+    accumulate in float32, take the softmax's maximum, exponentials and
+    sum in float32, and differentiate with probabilities cast to q's dtype
+    where they enter a product.
+
+    Plain: the [B, heads, T, T] scores as one float32 array, scaled,
+    masked, normalised, cast, multiplied. At the cells' learner shapes
+    that array is 0.5-0.7 GB, written, read and cast through HBM in the
+    forward pass, again where a block is recomputed, and differentiated.
+
+    Fused (the library's TPU splash attention kernel and its one backward
+    kernel, under `vmap` over B): tiles of `CAUSAL_TILE` queries against
+    tiles of keys, the running maximum and sum of a tile's rows in VMEM,
+    tiles wholly above the diagonal skipped, the causal mask the kernel's
+    static mask and the episode its segment ids; the backward pass
+    recomputes a tile's probabilities from the saved log-sum-exp a row.
+    Nothing [T, T]-sized is written in either pass. The kernel takes no
+    scale, so q is multiplied by it first, in q's dtype: exact where the
+    scale is a power of two (the latent layout's 1/16); a caller whose
+    scale is not folds it into q while q is still float32 and passes 1.
+    The output and the log-sum-exp carry the name `CAUSAL_KEPT`, so that
+    a caller who recomputes the pass around it may keep the two and run
+    the forward kernel once.
+
+    The form is chosen from what the program can see: `causal_fused` of
+    the static shape, and the platform the program is lowered for (the
+    kernel is Mosaic's, so a program lowered for anything but a TPU keeps
+    the plain form)."""
+    if not causal_fused(q.shape[2], q.shape[3], v.shape[3]):
+        return _causal_plain(q, k, v, episode, scale)
+    return jax.lax.platform_dependent(
+        q, k, v, episode,
+        tpu=functools.partial(_causal_fused, scale=scale),
+        default=functools.partial(_causal_plain, scale=scale))
 
 
 def experts_batched(M: int, k: int, E: int) -> bool:
@@ -552,19 +664,30 @@ class TokenDecoder(nn.Module):
             "pos": jnp.zeros(batch_size, jnp.int32),
         }
 
-    def decode_counters(self, batch_size: int) -> dict:
-        """What a decode step of `batch_size` rows is, from its static
-        shape: the mean rows a held expert group holds, whether the
-        experts multiply in the batched form (1.0) or the grouped one
-        (0.0), the positions in a block of the caches its attention
-        reads, and with a latent cache its bytes a position."""
+    def static_counters(self, batch_size: int, fragment_len: int,
+                        platform: str) -> dict:
+        """What the program is, from its static shapes and the platform
+        it is compiled for. A decode step of `batch_size` rows: the mean
+        rows a held expert group holds, whether the experts multiply in
+        the batched form (1.0) or the grouped one (0.0), the positions in
+        a block of the caches its attention reads, and with a latent
+        cache its bytes a position. A causal pass over fragments of
+        `fragment_len` tokens: whether its attention takes the fused form
+        (1.0) or the plain one (0.0)."""
         k, E = self.experts_per_token, self.num_experts
+        if self.kv_lora_rank:
+            widths = (self.qk_nope_head_dim + self.qk_rope_head_dim,
+                      self.v_head_dim)
+        else:
+            widths = (self.hidden_size // self.num_heads,) * 2
         out = {
             "decode_rows_per_expert": batch_size * k / E,
             "decode_experts_batched": float(
                 experts_batched(batch_size, k, E)),
             "decode_cache_block": self.context_len if self.kv_lora_rank
             else min(DECODE_CACHE_BLOCK, self.context_len),
+            "causal_attention_fused": float(
+                platform == "tpu" and causal_fused(fragment_len, *widths)),
         }
         if self.kv_lora_rank:
             out["latent_cache_bytes_per_token"] = (
@@ -617,56 +740,74 @@ class TokenDecoder(nn.Module):
             self.kv_lora_rank, self.num_heads, -1)
         return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
 
-    def _attend_causal(self, lp, x, positions, mask, cache_rows):
+    def _attend_causal(self, lp, x, positions, episode, cache_rows):
         """x + Attention(RMSNorm(x)) over a fragment [B, T, H] from an
-        empty window; (h, the layer's caches)."""
+        empty window; (h, the layer's caches). Head-major throughout: the
+        projections write queries, keys and values as [B, heads, T, d],
+        which `causal_attention` reads, and `W_o` contracts its output
+        over (head, d) as it lies, so that no transposed copy of any of
+        them is made on the way."""
         cd, eps = self.compute_dtype, self.rms_eps
-        B, T, _ = x.shape
+        H, heads = x.shape[-1], self.num_heads
+
+        def by_head(n, w):
+            """n [B, T, r] W [r, heads * d] -> [B, heads, T, d]."""
+            return jnp.einsum("btr,rhd->bhtd", n,
+                              w.astype(cd).reshape(w.shape[0], heads, -1))
+
+        def joined(o):
+            """x + [B, heads, T, d] W_o."""
+            return x + jnp.einsum("bhtd,hdo->bto", o,
+                                  lp["wo"].astype(cd).reshape(heads, -1, H))
+
         if not self.kv_lora_rank:
             with jax.named_scope("policy/attention"):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
-                q, k, v = self._qkv(lp, n)
-                q = rope(q, positions, self.rope_theta)
-                k = rope(k, positions, self.rope_theta)
-                scores = jnp.einsum(
-                    "bqhd,bkhd->bhqk", q, k,
-                    preferred_element_type=jnp.float32) * (
-                        q.shape[-1] ** -0.5)
-                scores = jnp.where(mask[:, None], scores, -jnp.inf)
-                attn = jax.nn.softmax(scores, axis=-1).astype(cd)
-                o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, T, -1)
-                h = x + jnp.dot(o, lp["wo"].astype(cd))
+                # QK-norm over the whole projection: heads and d.
+                q, k = (rms_norm(by_head(n, lp[w]),
+                                 lp[g].reshape(heads, 1, -1), eps, cd,
+                                 axes=(1, 3))
+                        for w, g in (("wq", "q_norm"), ("wk", "k_norm")))
+                # The softmax's scale goes onto q in RoPE's float32.
+                q = rope(q, positions, self.rope_theta, q.shape[-1] ** -0.5,
+                         head_major=True)
+                k = rope(k, positions, self.rope_theta, head_major=True)
+                v = by_head(n, lp["wv"])
+                h = joined(causal_attention(q, k, v, episode, 1.0))
                 caches = tuple(
-                    jnp.take_along_axis(
-                        a, cache_rows[:, :, None, None], axis=1)
+                    jnp.take_along_axis(jnp.swapaxes(a, 1, 2),
+                                        cache_rows[:, :, None, None], axis=1)
                     for a in (k, v))
             return h, caches
         # Latent attention, decompressed: keys and values of every head
-        # are made from the latents for every position.
+        # are made from the latents for every position, the one rotary
+        # key copied to every head's.
         n = rms_norm(x, lp["attn_norm"], eps, cd)
         c_q, latent = self._latents(lp, n, positions)
         with jax.named_scope("policy/mla_latent"):
             caches = (jnp.take_along_axis(
                 latent, cache_rows[:, :, None], axis=1),)
         with jax.named_scope("policy/mla_expand"):
-            q_nope, q_rope = self._queries(lp, c_q, positions)
+            nope = self.qk_nope_head_dim
+            q = by_head(c_q, lp["wq_b"])
+            q = jnp.concatenate([
+                q[..., :nope], rope(q[..., nope:], positions,
+                                    self.rope_theta, head_major=True)],
+                axis=-1)
             w_uk, w_uv = self._wkv_b(lp)
             c_kv, k_r = (latent[..., :self.kv_lora_rank],
                          latent[..., self.kv_lora_rank:])
-            k_nope = jnp.einsum("bsc,chd->bshd", c_kv, w_uk)
-            v = jnp.einsum("bsc,chd->bshd", c_kv, w_uv)
+            k = jnp.concatenate([
+                by_head(c_kv, w_uk), jnp.broadcast_to(
+                    k_r[:, None], (k_r.shape[0], heads) + k_r.shape[1:])],
+                axis=-1)
+            v = by_head(c_kv, w_uv)
         with jax.named_scope("policy/mla_attend"):
-            scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_r,
-                                   preferred_element_type=jnp.float32))
-            scores = scores * (
-                self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
-            scores = jnp.where(mask[:, None], scores, -jnp.inf)
-            attn = jax.nn.softmax(scores, axis=-1).astype(cd)
-            o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, T, -1)
+            o = causal_attention(
+                q, k, v, episode,
+                (nope + self.qk_rope_head_dim) ** -0.5)
         with jax.named_scope("policy/mla_expand"):
-            h = x + jnp.dot(o, lp["wo"].astype(cd))
+            h = joined(o)
         return h, caches
 
     def _attend_step(self, lp, x, pos, caches):
@@ -782,20 +923,24 @@ class TokenDecoder(nn.Module):
         episode = jnp.cumsum(starts, axis=1)
         start = jax.lax.cummax(jnp.where(starts, steps, 0), axis=1)
         positions = steps - start
-        mask = (steps[:, None] >= steps[None, :])[None] & (
-            episode[:, :, None] == episode[:, None, :])
         # Where the last episode's entries go in the cache: its own
         # positions.
         cache_rows = jnp.clip(start[:, -1:] + jnp.arange(S), 0, T - 1)
 
         def block(lp, x):
             h, caches = self._attend_causal(
-                lp, x, positions, mask, cache_rows)
+                lp, x, positions, episode, cache_rows)
             out, group_sizes, top_i = self._feed_forward(
                 lp, h.reshape(B * T, -1))
             return out.reshape(B, T, -1), caches, group_sizes, top_i
         if self.num_layers + self.nextn_layers > 1:
-            block = jax.checkpoint(block)
+            # Recomputed in the backward pass, but for what the fused
+            # attention keeps (its output and log-sum-exp: 85 MB a block
+            # at 8 x 1,024 tokens of 20 heads x 256, where the plain
+            # form's scores were 1 GB): its forward kernel runs once.
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.save_only_these_names(
+                    CAUSAL_KEPT))
 
         x = self.embed[tokens].astype(cd)
         kv, loads, experts = [], [], []

@@ -106,10 +106,12 @@ class AnakinOptimizer(PolicyOptimizer):
                     f"(episode_len {episode}) and an episode must fit the "
                     f"window ({window} positions)")
         self._replays_state = policy.recurrent and window is None
-        # Trace-time facts of the rollout's decode step, where the model
-        # states any: host values put beside the program's stats.
-        counters = getattr(policy.model, "decode_counters", None)
-        self._decode_counters = counters(num_envs) if counters else {}
+        # Trace-time facts of the rollout's decode step and the learner's
+        # pass over a fragment, where the model states any: host values
+        # put beside the program's stats.
+        counters = getattr(policy.model, "static_counters", None)
+        self._static_counters = {} if counters is None else counters(
+            num_envs, self.T, policy.mesh.devices.flat[0].platform)
 
         # Device-resident env state: one slot per env, batch-sharded.
         vreset = jax.vmap(self.env.reset)
@@ -350,7 +352,7 @@ class AnakinOptimizer(PolicyOptimizer):
             self._ep_reward_mean = rew_sum / cnt
             self._ep_len_mean = len_sum / cnt
             self._episodes_total += int(cnt)
-        stats.update(self._decode_counters)
+        stats.update(self._static_counters)
         self.learner_stats = stats
         return stats
 

@@ -178,7 +178,7 @@ def test_absorbed_decode_through_latent_cache_matches_causal_pass(dtype):
     assert np.all(np.asarray(state["pos"]) == S)
     assert [[c.shape for c in layer] for layer in state["kv"]] == [
         [(B, S, LATENT)]] * NET["num_hidden_layers"]
-    assert model.decode_counters(B)["latent_cache_bytes_per_token"] == (
+    assert model.static_counters(B, S, "cpu")["latent_cache_bytes_per_token"] == (
         NET["num_hidden_layers"] * LATENT * (4 if dtype == "f32" else 2))
 
 
@@ -449,9 +449,10 @@ def test_the_cell_s_forms_are_chosen_from_the_static_shape():
                max_position_embeddings=1024)
     model = catalog.get_model(None, net["vocab_size"], {
         "custom_model": "glm4_moe_lite", "custom_model_config": net})
-    assert model.decode_counters(128) == {
+    assert model.static_counters(128, 1024, "tpu") == {
         "decode_rows_per_expert": 8.0, "decode_experts_batched": 1.0,
-        "decode_cache_block": 1024, "latent_cache_bytes_per_token": 5760}
+        "decode_cache_block": 1024, "latent_cache_bytes_per_token": 5760,
+        "causal_attention_fused": 1.0}
 
     def shapes(b, t):
         return (jax.ShapeDtypeStruct((b, t), jnp.int32),
@@ -748,6 +749,7 @@ def test_glm_token_trainer_trains_on_the_fused_path(token_trainer):
     assert kept["decode_experts_batched"] == 1.0
     assert kept["decode_cache_block"] == S
     assert kept["decode_cache_read_share"] == 1.0
+    assert kept["causal_attention_fused"] == 0.0
     assert kept["latent_cache_bytes_per_token"] == 3 * LATENT * 4
     # The rollout's state: the latents, [rows, window, 24] a layer.
     state, _ = token_trainer.optimizer._pstate

@@ -420,10 +420,12 @@ def test_the_form_is_chosen_from_the_static_shape():
     # No decode flag: a causal pass over 4 tokens is batched too, a decode
     # of 2,048 sequences grouped.
     assert experts_batched(4, 8, 64) and not experts_batched(2048, 8, 64)
-    assert model.decode_counters(128) == {
+    assert model.static_counters(128, 1024, "tpu") == {
         "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0,
-        "decode_cache_block": transformer.DECODE_CACHE_BLOCK}
-    assert model.decode_counters(2048)["decode_experts_batched"] == 0.0
+        "decode_cache_block": transformer.DECODE_CACHE_BLOCK,
+        "causal_attention_fused": 1.0}
+    assert model.static_counters(
+        2048, 1024, "tpu")["decode_experts_batched"] == 0.0
 
     def shapes(b, t):
         return (jax.ShapeDtypeStruct((b, t), jnp.int32),
@@ -566,6 +568,9 @@ def test_token_trainer_trains_on_the_fused_path(token_trainer):
     # Its attention: the window of 16 is one block, read whole every step.
     assert kept["decode_cache_block"] == S
     assert kept["decode_cache_read_share"] == 1.0
+    # The learner's attention: 16 tokens are no two tiles, and this is
+    # no TPU.
+    assert kept["causal_attention_fused"] == 0.0
 
 
 @pytest.mark.parametrize("episode_len,share", [(S, 0.5 + 4 / (2 * S)),
