@@ -46,8 +46,14 @@ def _glm4_moe_lite(obs_space, num_outputs, cfg, dtype):
     return glm4_moe_lite_from_config(num_outputs, cfg, dtype)
 
 
+def _smallthinker(obs_space, num_outputs, cfg, dtype):
+    from .transformer import smallthinker_from_config
+    return smallthinker_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
-CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite}
+CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite,
+                 "smallthinker": _smallthinker}
 
 
 def _resolve_compute_dtype(cfg):

@@ -1,4 +1,4 @@
-"""Transformer token policies in flax: one decoder, two descriptions.
+"""Transformer token policies in flax: one decoder, three descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
@@ -12,11 +12,24 @@ It is assembled from parts that the published `config.json` of a family
 names; nothing else chooses between them.
 
 Attention, one of:
-  full heads (OLMoE, arXiv:2409.02060, `model_type: olmoe`):
-      q, k, v = n W_q, n W_k, n W_v (no bias); q_norm, k_norm: RMSNorm over
-      the whole projection before the split into heads; rotate-half RoPE;
-      causal softmax(q k^T / sqrt(head_dim)) v; W_o. The cache holds K and
-      V, [B, S, heads, head_dim] each a layer.
+  a head's own keys and values (OLMoE, arXiv:2409.02060, `model_type:
+  olmoe`; SmallThinker, arXiv:2507.20984, `model_type: smallthinker`):
+      q, k, v = n W_q, n W_k, n W_v (no bias), `num_heads` query heads and
+      `num_kv_heads` key/value heads of `head_dim` (OLMoE: as many, of
+      hidden / heads; SmallThinker: 28 over 4 of 128, query head h
+      reading key/value head h // 7); `qk_norm` (OLMoE): q_norm, k_norm,
+      RMSNorm over the whole projection before the split into heads;
+      causal softmax(q k^T / sqrt(head_dim)) v; W_o. A KIND A LAYER
+      (`window_layout`, `rope_layout`; OLMoE: every layer full and rotary;
+      SmallThinker: a period of four, the first full and without
+      positions, the next three windowed and rotary): rotate-half RoPE on
+      q and k, or nothing; the sum over every s <= t of the episode, or
+      over those with t - s < `sliding_window`. The cache holds K and V,
+      [B, S, key/value heads, head_dim] each a layer, S the context's
+      positions in a full layer and a RING of the window in a window
+      layer: position p lies in slot p mod the window, written over the
+      one that has just left it; keys are cached rotated, so the ring is
+      read in whatever order it lies.
   latent (MLA; `kv_lora_rank` given; `model_type: glm4_moe_lite`, the
   DeepSeek-V2/V3 form, arXiv:2405.04434 section 2.1):
       c_q = RMSNorm(n W_qa);  q = c_q W_qb -> heads x (nope | rope)
@@ -35,30 +48,37 @@ Attention, one of:
 Feed-forward, by layer: the first `first_k_dense_replace` layers a dense
 SwiGLU; the others routed experts, beside `n_shared_experts` shared ones
 that every token passes:
-      sum over the chosen e of w_e W_down,e (silu(W_gate,e n) * W_up,e n)
-Dropless: no capacity, no token dropped or re-routed. The layer may hold a
-share of the experts (`experts_held`, from `first_expert_held`): it routes
-over all of them, computes the chosen ones it holds and leaves out what the
-absent ones would add; that partial sum goes on, as on one chip of an
-expert-parallel stage without its exchange. One sum, two blockings, chosen
-from the static shape (`experts_batched`). Grouped: the (row, expert) pairs
-sorted by expert, absent experts' pairs last, multiplied group by group
-(`jax.lax.ragged_dot`) and un-sorted; the learner's minibatch and a
-prefill. Batched: every row through every held expert in products batched
-over the experts, each term weighted w_e or exactly 0 before the sum; a
-decode step, whose groups of a few rows would each cost the grouped product
-an MXU tile while the step is bound by reading every expert's weights once
-anyway.
+      sum over the chosen e of w_e W_down,e (act(W_gate,e n) * W_up,e n)
+(`hidden_act`: SiLU, SwiGLU; or ReLU, SmallThinker's ReGLU). Dropless: no
+capacity, no token dropped or re-routed. The layer may hold a share of the
+experts (`experts_held`, from `first_expert_held`): it routes over all of
+them, computes the chosen ones it holds and leaves out what the absent ones
+would add; that partial sum goes on, as on one chip of an expert-parallel
+stage without its exchange. One sum, two blockings, chosen from the static
+shape (`experts_batched`). Grouped: the (row, expert) pairs sorted by
+expert, absent experts' pairs last, multiplied group by group
+(`jax.lax.ragged_dot`) and un-sorted; the learner's minibatch and a prefill.
+Batched: every row through every held expert in products batched over the
+experts, each term weighted w_e or exactly 0 before the sum; a decode step,
+whose groups of a few rows would each cost the grouped product an MXU tile
+while the step is bound by reading every expert's weights once anyway.
 
 Router, float32, one of:
-  softmax (OLMoE): p = softmax(n W_r); the k largest p; weights are those
-      p as they are unless `norm_topk_prob`.
+  softmax (OLMoE, SmallThinker): p = softmax(n W_r); the k largest p;
+      weights are those p as they are unless `norm_topk_prob` (over
+      their sum: the softmax over the chosen logits alone).
   sigmoid with a selection bias (`topk_method: noaux_tc`; one group):
       s = sigmoid(n W_r); the k largest of s + b choose; weights are s at
       the chosen experts, without b, over their sum (`norm_topk_prob`),
       times `routed_scaling_factor`. b is a constant of the model: no
       gradient, no optimizer state (its balancing update belongs to
       pre-training).
+
+The router reads the block's post-attention norm, n = RMSNorm(h); or
+(`router_before_attention`, SmallThinker) the attention's own normalised
+input, n = RMSNorm(x), so that its choice is known before the attention
+runs, and the experts then take that choice with the post-attention norm
+as their input.
 
 The next-next-token module (`num_nextn_predict_layers`; DeepSeek-V3,
 arXiv:2412.19437 section 2.2), in the learner only: for position t with the
@@ -73,25 +93,28 @@ module follows the policy and does not move it. Its loss goes to the
 "losses" collection (the caller's objective adds what a model puts there)
 and is computed only where the caller keeps that collection.
 
-Departures from the published models: the value head (neither has one); no
+Departures from the published models: the value head (none has one); no
 auxiliary router loss (the RL objective has no place for it; the
 `expert_load_*` counters show what follows); parameters, router, final norm
 and heads are float32 and the block's activations `compute_dtype`
-(bfloat16: the repo's convention, as the Nature-CNN's trunk); key/value
-heads equal query heads (both layouts; grouped heads are refused).
+(bfloat16: the repo's convention, as the Nature-CNN's trunk). The OLMoE and
+glm4_moe_lite descriptions have as many key/value heads as query heads and
+refuse another count (their references have no grouped form; latent
+attention has no key/value heads to group).
 
-A causal pass's attention, either layout (`causal_attention`; the latent
-one decompressed, every head's key [k_nope | k_r]): one sum, two blockings,
-as the experts'. Plain: the [B, heads, T, T] scores as one float32 array,
+A causal pass's attention, either layout (`causal_attention`; the latent one
+decompressed, every head's key [k_nope | k_r]): one sum, two blockings, as
+the experts'. Plain: the [B, heads, T, T] scores as one float32 array,
 masked, normalised, cast, multiplied. Fused: the library's TPU splash
 attention kernel and its backward kernel, tiles of `CAUSAL_TILE` queries
 against tiles of keys with the running maximum and sum in VMEM, tiles above
-the diagonal skipped, the episode as segment ids; no [T, T] array is
-written in either pass. Chosen from the static shape (`causal_fused`: whole
-tiles, at least two, widths of whole MXU tiles) and the platform the
-program is lowered for (a TPU: fused; anything else, and every fragment of
-a test or a rehearsal: plain). Queries, keys and values are made
-head-major, [B, heads, T, d], the layout the kernel reads.
+the diagonal and tiles a window leaves out skipped, the episode as segment
+ids, grouped heads a group at a time in the kernel's one-key-head form; no
+[T, T] array is written in either pass. Chosen from the static shape
+(`causal_fused`: whole tiles, at least two, widths of whole MXU tiles) and
+the platform the program is lowered for (a TPU: fused; anything else, and
+every fragment of a test or a rehearsal: plain). Queries, keys and values
+are made head-major, [B, heads, T, d], the layout the kernel reads.
 
 One set of parameters, two forms (the stateful-policy protocol of
 `JaxPolicy`: `model(obs[B, T], state, reset[B, T])`):
@@ -108,8 +131,8 @@ One set of parameters, two forms (the stateful-policy protocol of
   and value. The rollout's form. Its attention reads the cache positions
   [0, n) only, n the furthest position any row of the batch holds, rounded
   up to a block of `DECODE_CACHE_BLOCK` positions and chosen inside the
-  step from `pos` (`cached_attention`; a latent cache is read whole); each
-  row masks what it does not hold itself.
+  step from `pos` (`cached_attention`; a latent cache and grouped heads'
+  caches are read whole); each row masks what it does not hold itself.
 
 Both return the cache, so a decode can follow a causal pass.
 """
@@ -166,6 +189,43 @@ GLM4_MOE_LITE_CONFIG_KEYS = {
     "experts_held": "experts_held",
     "first_expert_held": "first_expert_held",
 }
+SMALLTHINKER_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "num_hidden_layers": "num_layers",
+    "sliding_window_size": "sliding_window",
+    "sliding_window_layout": "window_layout",
+    "rope_layout": "rope_layout",
+    "moe_num_primary_experts": "num_experts",
+    "moe_num_active_primary_experts": "experts_per_token",
+    "moe_ffn_hidden_size": "expert_width",
+    "norm_topk_prob": "norm_topk_prob",
+    "max_position_embeddings": "context_len",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_eps",
+    # The deployment's: the share of the experts this chip holds.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+}
+# What SmallThinker-21BA3B's published `config.json` says, for the keys a
+# `custom_model_config` leaves out.
+SMALLTHINKER_PUBLISHED = {
+    "vocab_size": 151936, "hidden_size": 2560, "num_attention_heads": 28,
+    "num_key_value_heads": 4, "head_dim": 128, "num_hidden_layers": 52,
+    "sliding_window_size": 4096, "sliding_window_layout": [0, 1, 1, 1] * 13,
+    "rope_layout": [0, 1, 1, 1] * 13, "moe_num_primary_experts": 64,
+    "moe_num_active_primary_experts": 6, "moe_ffn_hidden_size": 768,
+    "norm_topk_prob": True, "max_position_embeddings": 16384,
+    "rope_theta": 1500000, "rms_norm_eps": 1e-6,
+}
+SMALLTHINKER_FIXED = {
+    "moe_primary_router_apply_softmax": True, "hidden_act": "relu",
+    "rope_scaling": None, "tie_word_embeddings": False,
+    "model_type": "smallthinker",
+}
 # Published keys that must say what the decoder does (a value it has no
 # part for is refused, not ignored).
 GLM4_MOE_LITE_FIXED = {
@@ -200,9 +260,14 @@ def rope(x, positions, theta, scale=1.0, head_major=False):
     return (out if scale == 1.0 else out * scale).astype(x.dtype)
 
 
-def swiglu(n, w_gate, w_up, w_down):
-    """W_down (silu(W_gate n) * W_up n) for rows n, weights in n's dtype."""
-    return jnp.dot(jax.nn.silu(jnp.dot(n, w_gate)) * jnp.dot(n, w_up), w_down)
+# The gate's activation in a gated feed-forward, by its published name
+# (`hidden_act`): SwiGLU's, or ReGLU's.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def swiglu(n, w_gate, w_up, w_down, act=jax.nn.silu):
+    """W_down (act(W_gate n) * W_up n) for rows n, weights in n's dtype."""
+    return jnp.dot(act(jnp.dot(n, w_gate)) * jnp.dot(n, w_up), w_down)
 
 
 def route(n, router, k, renormalise, bias=None, scale=1.0):
@@ -260,10 +325,15 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     ([B, heads, value width] in q's dtype, the positions read). `scale` is
     d ** -0.5 where not given.
 
-    Two kinds of cache. A head's own keys and values: `k_cache`, `v_cache`
-    [B, S, heads, d]. One latent head that all query heads share:
-    `k_cache` [B, S, d] and `v_cache` None; the values are then the first
-    `value_dim` of the same rows.
+    Three kinds of cache. A head's own keys and values: `k_cache`,
+    `v_cache` [B, S, heads, d]. Grouped heads: [B, S, groups, d], query
+    head h reading cached head h // (heads // groups). One latent head
+    that all query heads share: `k_cache` [B, S, d] and `v_cache` None;
+    the values are then the first `value_dim` of the same rows.
+
+    The cache may be a ring (`TokenDecoder.initial_state`): its slots are
+    read in whatever order they lie, and a row whose position has passed
+    the ring's length holds every slot.
 
     Read, scored and multiplied are the positions [0, n) alone: n is the
     furthest position any row holds, rounded up to whole blocks. A position
@@ -288,10 +358,19 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     conditional XLA:TPU first copies the WHOLE window to another layout,
     in every branch, whatever the prefix (glm4_moe_lite's widths on a v5e,
     128 rows, a window of 1,024: a step 4.16 ms whole, 4.83 in blocks of
-    512, 5.11 of 256; PERF.md section 5). Reading a latent prefix where it
-    lies takes a kernel (ROADMAP R-A9), which brings its own blocks."""
+    512, 5.11 of 256; PERF.md section 5). Grouped heads are matrix
+    products too, a group's queries against its cached head, and take the
+    one whole-cache branch for the same reason, measured at 28 query
+    heads over 4 cached ones of 128, 16 rows (a v5e, PERF.md section 5): a
+    ring of 4,096 read whole 0.21 ms a step (79 % of the HBM's bandwidth),
+    a prefix of three quarters of it 0.55; a cache of 8,192 whole 0.40, a
+    prefix of five eighths 1.08; the same sums as a multiply-and-sum, which
+    does seven products and a cross-lane sum an element, 1.5 and 3.2 whole.
+    Reading a latent or a grouped prefix where it lies takes a kernel
+    (ROADMAP R-A9), which brings its own blocks."""
     S = k_cache.shape[1]
-    size = S if v_cache is None else DECODE_CACHE_BLOCK
+    grouped = v_cache is not None and k_cache.shape[2] != q.shape[1]
+    size = S if v_cache is None or grouped else DECODE_CACHE_BLOCK
     ends = tuple(range(size, S, size)) + (S,)
     f32 = jnp.float32
     if scale is None:
@@ -311,6 +390,8 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
             return jnp.einsum("bhs,bsd->bhd", attn, k,
                               preferred_element_type=f32).astype(
                                   q.dtype)[..., :value_dim]
+        if grouped:
+            return attend_grouped(q, k_cache[:, :n], v_cache[:, :n], held)
         k, v = k_cache[:, :n].astype(f32), v_cache[:, :n].astype(f32)
         # [B, n, heads]
         scores = jnp.sum(q[:, None].astype(f32) * k, axis=-1) * scale
@@ -318,6 +399,23 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
         attn = jax.nn.softmax(scores, axis=1).astype(q.dtype)
         return jnp.sum(attn[..., None].astype(f32) * v,
                        axis=1).astype(q.dtype)
+
+    def attend_grouped(q, k, v, held):
+        """`heads // groups` query heads against each cached head (query
+        head h against cached head h // their number): a group's queries
+        as the rows of one matrix against that head's [n, d] keys, then
+        its weights against the values, a cached row read once for all
+        the queries of its group."""
+        B, heads, d = q.shape
+        q = q.reshape(B, k.shape[2], -1, d)
+        # [B, groups, heads a group, n]
+        scores = jnp.einsum("bgrd,bsgd->bgrs", q, k,
+                            preferred_element_type=f32) * scale
+        scores = jnp.where(held[:, None, None, :], scores, -jnp.inf)
+        attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bgrs,bsgd->bgrd", attn, v,
+                          preferred_element_type=f32).astype(
+                              q.dtype).reshape(B, heads, -1)
 
     block = jnp.minimum(jnp.max(pos) // size, len(ends) - 1)
     o = jax.lax.switch(block, [functools.partial(attend, n) for n in ends],
@@ -344,10 +442,23 @@ def causal_fused(T: int, d_qk: int, d_v: int) -> bool:
             and d_qk % 128 == 0 and d_v % 128 == 0)
 
 
-def _causal_plain(q, k, v, episode, scale):
+def _causal_plain(q, k, v, episode, scale, window=0):
     steps = jnp.arange(q.shape[2])
     mask = (steps[:, None] >= steps[None, :])[None] & (
         episode[:, :, None] == episode[:, None, :])
+    if window:
+        mask = mask & (steps[:, None] - steps[None, :] < window)[None]
+    if k.shape[1] != q.shape[1]:
+        # Grouped heads: query head h against key/value head h // their
+        # number a group.
+        B, heads, T, d = q.shape
+        q = q.reshape(B, k.shape[1], -1, T, d)
+        scores = jnp.einsum("bgrqd,bgkd->bgrqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+        attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bgrqk,bgkd->bgrqd", attn, v).reshape(
+            B, heads, T, -1)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     scores = jnp.where(mask[:, None], scores, -jnp.inf)
@@ -355,31 +466,63 @@ def _causal_plain(q, k, v, episode, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", attn, v)
 
 
-def _causal_fused(q, k, v, episode, scale):
+def _causal_fused(q, k, v, episode, scale, window=0):
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
-    heads, T = q.shape[1:3]
+    B, heads, T, _ = q.shape
+    groups = k.shape[1]
     t = CAUSAL_TILE
-    kernel = splash.make_splash_mha(
-        splash.MultiHeadMask([splash.CausalMask((T, T))] * heads),
+    # The kernel's static mask: which tiles it visits at all, and the
+    # mask it computes inside those the boundary crosses.
+    mask = (splash.LocalMask((T, T), (window - 1, 0), 0) if window
+            else splash.CausalMask((T, T)))
+    settings = dict(
         block_sizes=splash.BlockSizes(
             block_q=t, block_kv=t, block_kv_compute=t, block_q_dkv=t,
             block_kv_dkv=t, block_kv_dkv_compute=t,
             use_fused_bwd_kernel=True),
         head_shards=1, q_seq_shards=1, residual_checkpoint_name=CAUSAL_KEPT)
     segments = episode.astype(jnp.int32)
+    # Grouped heads: the kernel's form with one key/value head, a group
+    # of query heads at a time.
+    grouped = groups != heads
+    make = splash.make_splash_mqa if grouped else splash.make_splash_mha
+    kernel = make(splash.MultiHeadMask(
+        [mask] * (heads // groups if grouped else heads)), **settings)
+
+    def a_row(q, k, v, s):
+        return kernel(q, k, v, segment_ids=splash.SegmentIds(s, s))
     # The kernel takes no scale.
-    return jax.vmap(lambda q, k, v, s: kernel(
-        q, k, v, segment_ids=splash.SegmentIds(s, s)))(
-            q * scale, k, v, segments)
+    if not grouped:
+        return jax.vmap(a_row)(q * scale, k, v, segments)
+    return jax.vmap(jax.vmap(a_row, in_axes=(0, 0, 0, None)))(
+        (q * scale).reshape(B, groups, -1, T, q.shape[3]), k, v,
+        segments).reshape(B, heads, T, -1)
 
 
-def causal_attention(q, k, v, episode, scale):
+def causal_window_tiles(T: int, window: int) -> tuple:
+    """(tiles of `CAUSAL_TILE` x `CAUSAL_TILE` the fused form visits over
+    `T` positions with a window of `window` of them, tiles it visits with
+    the causal mask alone): a tile is visited where any of its queries
+    may attend to any of its keys."""
+    n, t = T // CAUSAL_TILE, CAUSAL_TILE
+    causal = n * (n + 1) // 2
+    if not window:
+        return causal, causal
+    # Tiles i - j apart: the nearest query and key are (i - j) t - (t - 1)
+    # apart.
+    return sum(n - apart for apart in range(n)
+               if apart * t - (t - 1) < window), causal
+
+
+def causal_attention(q, k, v, episode, scale, window=0):
     """softmax(q k^T * scale) v over a fragment from an empty window, head
-    by head: q, k [B, heads, T, d_qk], v [B, heads, T, d_v], `episode`
-    [B, T] the number of the episode a step belongs to (it never falls
-    along a row). Position t attends to the positions s <= t of its own
-    episode, so a step that starts an episode attends to itself alone.
-    Returns [B, heads, T, d_v] in q's dtype.
+    by head: q [B, heads, T, d_qk], k [B, groups, T, d_qk], v [B, groups,
+    T, d_v] (query head h reads key/value head h // (heads // groups);
+    as a rule groups = heads), `episode` [B, T] the number of the episode
+    a step belongs to (it never falls along a row). Position t attends to
+    the positions s <= t of its own episode, and with a `window` to those
+    among them with t - s < window; so a step that starts an episode
+    attends to itself alone. Returns [B, heads, T, d_v] in q's dtype.
 
     Two forms of that one sum; both multiply operands in q's dtype and
     accumulate in float32, take the softmax's maximum, exponentials and
@@ -394,8 +537,10 @@ def causal_attention(q, k, v, episode, scale):
     Fused (the library's TPU splash attention kernel and its one backward
     kernel, under `vmap` over B): tiles of `CAUSAL_TILE` queries against
     tiles of keys, the running maximum and sum of a tile's rows in VMEM,
-    tiles wholly above the diagonal skipped, the causal mask the kernel's
-    static mask and the episode its segment ids; the backward pass
+    tiles wholly above the diagonal, and those wholly outside a window,
+    skipped (`causal_window_tiles`), the causal or window mask the
+    kernel's static mask and the episode its segment ids; with grouped
+    heads the kernel's one-key/value-head form a group; the backward pass
     recomputes a tile's probabilities from the saved log-sum-exp a row.
     Nothing [T, T]-sized is written in either pass. The kernel takes no
     scale, so q is multiplied by it first, in q's dtype: exact where the
@@ -410,11 +555,11 @@ def causal_attention(q, k, v, episode, scale):
     kernel is Mosaic's, so a program lowered for anything but a TPU keeps
     the plain form)."""
     if not causal_fused(q.shape[2], q.shape[3], v.shape[3]):
-        return _causal_plain(q, k, v, episode, scale)
+        return _causal_plain(q, k, v, episode, scale, window)
     return jax.lax.platform_dependent(
         q, k, v, episode,
-        tpu=functools.partial(_causal_fused, scale=scale),
-        default=functools.partial(_causal_plain, scale=scale))
+        tpu=functools.partial(_causal_fused, scale=scale, window=window),
+        default=functools.partial(_causal_plain, scale=scale, window=window))
 
 
 def experts_batched(M: int, k: int, E: int) -> bool:
@@ -429,8 +574,8 @@ def experts_batched(M: int, k: int, E: int) -> bool:
 
 
 def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
-                     num_experts=None):
-    """sum_e p_e W_down,e (silu(W_gate,e n) * W_up,e n) for rows n [M, H]
+                     num_experts=None, act=jax.nn.silu):
+    """sum_e p_e W_down,e (act(W_gate,e n) * W_up,e n) for rows n [M, H]
     routed to `top_i` [M, k] of `num_experts` with weights `top_p`, over
     the experts held here: `first` .. `first + E - 1`, whose weights
     [E, H, W] / [E, W, H] are given in n's dtype (all of them where
@@ -472,7 +617,7 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
         with jax.named_scope("policy/experts_batched"):
             gate = jnp.einsum("mh,ehw->emw", n, w_gate)
             up = jnp.einsum("mh,ehw->emw", n, w_up)
-            a = (c.T[:, :, None] * (jax.nn.silu(gate) * up)).astype(n.dtype)
+            a = (c.T[:, :, None] * (act(gate) * up)).astype(n.dtype)
             mixed = jnp.einsum("emw,ewh->mh", a, w_down,
                                preferred_element_type=jnp.float32)
         return mixed.astype(n.dtype), group_sizes
@@ -496,7 +641,7 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     with jax.named_scope("policy/experts"):
         gate = landed(jax.lax.ragged_dot(rows, w_gate, group_sizes))
         up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
-        out = jax.lax.ragged_dot(landed(jax.nn.silu(gate) * up), w_down,
+        out = jax.lax.ragged_dot(landed(act(gate) * up), w_down,
                                  group_sizes)
     with jax.named_scope("policy/dispatch"):
         unsorted = out[jnp.argsort(order)].reshape(M, k, -1)
@@ -549,7 +694,19 @@ class TokenDecoder(nn.Module):
     hidden_size: int = 2048
     num_heads: int = 16
     num_layers: int = 16
-    # Attention: full heads with QK-norm, or latent where `kv_lora_rank`.
+    # Attention: a head's own keys and values, or latent where
+    # `kv_lora_rank`. A head's own: `num_kv_heads` of them (0: as many as
+    # query heads) of `head_dim` (0: hidden_size // num_heads), QK-norm or
+    # none; a kind a layer, by the layer's entry in two layouts (the
+    # leading `num_layers` entries of a longer layout are read; an empty
+    # one: every layer full and rotary): attending within `sliding_window`
+    # positions or to the whole episode, RoPE or no positions at all.
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    qk_norm: bool = True
+    sliding_window: int = 0
+    window_layout: tuple = ()
+    rope_layout: tuple = ()
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -564,8 +721,11 @@ class TokenDecoder(nn.Module):
     experts_held: int = 0  # 0: all of them
     first_expert_held: int = 0
     shared_experts: int = 0
-    # Router: softmax, or sigmoid with a selection bias.
+    hidden_act: str = "silu"  # the gate's, in every gated feed-forward
+    # Router: softmax, or sigmoid with a selection bias; on the block's
+    # post-attention norm, or on the attention's own normalised input.
     selection_bias: bool = False
+    router_before_attention: bool = False
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
     # The next-next-token module, its loss's weight in the objective.
@@ -585,6 +745,27 @@ class TokenDecoder(nn.Module):
         """Values a position a layer of the latent cache (0: full heads)."""
         return self.kv_lora_rank and self.kv_lora_rank + self.qk_rope_head_dim
 
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def head_width(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_heads
+
+    def layer_kind(self, i: int) -> tuple:
+        """(the window layer `i` attends within, 0 for the whole episode;
+        whether its queries and keys are rotated)."""
+        window = bool(self.window_layout) and bool(self.window_layout[i])
+        return (self.sliding_window if window else 0,
+                not self.rope_layout or bool(self.rope_layout[i]))
+
+    def cache_len(self, i: int) -> int:
+        """Positions layer `i`'s cache holds: the context, or a window
+        layer's ring of its window."""
+        window = self.layer_kind(i)[0]
+        return min(window or self.context_len, self.context_len)
+
     def _layer_shapes(self, dense: bool) -> tuple:
         H, heads = self.hidden_size, self.num_heads
         shapes = [("attn_norm", "ones", (H,)), ("mlp_norm", "ones", (H,))]
@@ -600,8 +781,11 @@ class TokenDecoder(nn.Module):
                 ("wkv_b", "dense", (rkv, heads * (nope + vd))),
                 ("wo", "dense", (heads * vd, H))]
         else:
-            shapes += [("q_norm", "ones", (H,)), ("k_norm", "ones", (H,))]
-            shapes += [(w, "dense", (H, H)) for w in ("wq", "wk", "wv", "wo")]
+            q, kv = heads * self.head_width, self.kv_heads * self.head_width
+            if self.qk_norm:
+                shapes += [("q_norm", "ones", (q,)), ("k_norm", "ones", (kv,))]
+            shapes += [("wq", "dense", (H, q)), ("wk", "dense", (H, kv)),
+                       ("wv", "dense", (H, kv)), ("wo", "dense", (q, H))]
         if dense:
             D = self.dense_width
             return tuple(shapes + [
@@ -628,6 +812,10 @@ class TokenDecoder(nn.Module):
                 f"experts {self.first_expert_held} .. "
                 f"{self.first_expert_held + self.held - 1} are not among "
                 f"the router's {self.num_experts}")
+        if self.num_heads % self.kv_heads:
+            raise ValueError(
+                f"{self.num_heads} query heads do not fall into "
+                f"{self.kv_heads} key/value heads' groups")
         self.embed = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab_size, H))
         self.layers = [
@@ -649,18 +837,22 @@ class TokenDecoder(nn.Module):
 
     # -- the protocol ---------------------------------------------------
     def initial_state(self, batch_size: int):
-        """An empty window: a layer's caches (K and V of every head, or
-        the one latent), and each row's count of positions held."""
-        B, S = batch_size, self.context_len
-        if self.kv_lora_rank:
-            shapes = ((B, S, self.latent_width),)
-        else:
-            shapes = ((B, S, self.num_heads,
-                       self.hidden_size // self.num_heads),) * 2
+        """An empty window: a layer's caches (K and V of every key/value
+        head, or the one latent), and each row's count of positions held.
+        A layer's caches are as long as what it attends to: the context's
+        positions, or a window layer's RING of its window, where position
+        p lies in slot p mod the window."""
+        B = batch_size
+
+        def shapes(i):
+            S = self.cache_len(i)
+            if self.kv_lora_rank:
+                return ((B, S, self.latent_width),)
+            return ((B, S, self.kv_heads, self.head_width),) * 2
         return {
             "kv": tuple(tuple(jnp.zeros(s, self.compute_dtype)
-                              for s in shapes)
-                        for _ in range(self.num_layers)),
+                              for s in shapes(i))
+                        for i in range(self.num_layers)),
             "pos": jnp.zeros(batch_size, jnp.int32),
         }
 
@@ -673,18 +865,23 @@ class TokenDecoder(nn.Module):
         a block of the caches its attention reads, and with a latent
         cache its bytes a position. A causal pass over fragments of
         `fragment_len` tokens: whether its attention takes the fused form
-        (1.0) or the plain one (0.0)."""
+        (1.0) or the plain one (0.0). A model with window layers: how
+        many they are, the query heads a key/value head, the bytes of
+        cache a position of the context that all layers hold together (a
+        ring counts for its own length), and the share of the causal
+        tiles that the fused form visits in a window layer."""
         k, E = self.experts_per_token, self.num_experts
         if self.kv_lora_rank:
             widths = (self.qk_nope_head_dim + self.qk_rope_head_dim,
                       self.v_head_dim)
         else:
-            widths = (self.hidden_size // self.num_heads,) * 2
+            widths = (self.head_width,) * 2
         out = {
             "decode_rows_per_expert": batch_size * k / E,
             "decode_experts_batched": float(
                 experts_batched(batch_size, k, E)),
-            "decode_cache_block": self.context_len if self.kv_lora_rank
+            "decode_cache_block": self.context_len if (
+                self.kv_lora_rank or self.kv_heads != self.num_heads)
             else min(DECODE_CACHE_BLOCK, self.context_len),
             "causal_attention_fused": float(
                 platform == "tpu" and causal_fused(fragment_len, *widths)),
@@ -693,6 +890,20 @@ class TokenDecoder(nn.Module):
             out["latent_cache_bytes_per_token"] = (
                 self.num_layers * self.latent_width
                 * jnp.dtype(self.compute_dtype).itemsize)
+        windows = [i for i in range(self.num_layers) if self.layer_kind(i)[0]]
+        if windows:
+            position = (2 * self.kv_heads * self.head_width
+                        * jnp.dtype(self.compute_dtype).itemsize)
+            kept, causal = causal_window_tiles(
+                fragment_len, self.sliding_window) if out[
+                    "causal_attention_fused"] else (1, 1)
+            out.update(
+                window_layers=len(windows),
+                kv_groups=self.num_heads // self.kv_heads,
+                kv_cache_bytes_per_token=position * sum(
+                    self.cache_len(i) for i in range(self.num_layers))
+                / self.context_len,
+                causal_window_tiles_kept=kept / causal)
         return out
 
     def __call__(self, obs, state, reset):
@@ -709,10 +920,21 @@ class TokenDecoder(nn.Module):
     def _qkv(self, lp, n):
         cd, eps = self.compute_dtype, self.rms_eps
         heads = n.shape[:-1] + (self.num_heads, -1)
-        q = rms_norm(jnp.dot(n, lp["wq"].astype(cd)), lp["q_norm"], eps, cd)
-        k = rms_norm(jnp.dot(n, lp["wk"].astype(cd)), lp["k_norm"], eps, cd)
+        groups = n.shape[:-1] + (self.kv_heads, -1)
+
+        def projected(w, norm):
+            a = jnp.dot(n, lp[w].astype(cd))
+            return rms_norm(a, lp[norm], eps, cd) if self.qk_norm else a
+        q, k = projected("wq", "q_norm"), projected("wk", "k_norm")
         v = jnp.dot(n, lp["wv"].astype(cd))
-        return q.reshape(heads), k.reshape(heads), v.reshape(heads)
+        return q.reshape(heads), k.reshape(groups), v.reshape(groups)
+
+    def _attention_scope(self, window: int) -> str:
+        """The name a layer's own-heads attention has in a trace: by its
+        kind where the model has more than one."""
+        if not self.window_layout:
+            return "policy/attention"
+        return "policy/attention_window" if window else "policy/attention_full"
 
     def _latents(self, lp, n, positions):
         """(c_q, the cache's rows [c_kv | k_r]) of rows n at `positions`."""
@@ -740,9 +962,12 @@ class TokenDecoder(nn.Module):
             self.kv_lora_rank, self.num_heads, -1)
         return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
 
-    def _attend_causal(self, lp, x, positions, episode, cache_rows):
+    def _attend_causal(self, lp, x, positions, episode, cache_rows,
+                       window=0, rotary=True):
         """x + Attention(RMSNorm(x)) over a fragment [B, T, H] from an
-        empty window; (h, the layer's caches). Head-major throughout: the
+        empty window; (h, the layer's caches: the rows `cache_rows` of the
+        fragment). A head's own keys: within `window` positions where
+        given, rotated where `rotary`. Head-major throughout: the
         projections write queries, keys and values as [B, heads, T, d],
         which `causal_attention` reads, and `W_o` contracts its output
         over (head, d) as it lies, so that no transposed copy of any of
@@ -750,7 +975,7 @@ class TokenDecoder(nn.Module):
         cd, eps = self.compute_dtype, self.rms_eps
         H, heads = x.shape[-1], self.num_heads
 
-        def by_head(n, w):
+        def by_head(n, w, heads=heads):
             """n [B, T, r] W [r, heads * d] -> [B, heads, T, d]."""
             return jnp.einsum("btr,rhd->bhtd", n,
                               w.astype(cd).reshape(w.shape[0], heads, -1))
@@ -761,19 +986,28 @@ class TokenDecoder(nn.Module):
                                   lp["wo"].astype(cd).reshape(heads, -1, H))
 
         if not self.kv_lora_rank:
-            with jax.named_scope("policy/attention"):
+            groups = self.kv_heads
+            with jax.named_scope(self._attention_scope(window)):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
-                # QK-norm over the whole projection: heads and d.
-                q, k = (rms_norm(by_head(n, lp[w]),
-                                 lp[g].reshape(heads, 1, -1), eps, cd,
-                                 axes=(1, 3))
-                        for w, g in (("wq", "q_norm"), ("wk", "k_norm")))
-                # The softmax's scale goes onto q in RoPE's float32.
-                q = rope(q, positions, self.rope_theta, q.shape[-1] ** -0.5,
-                         head_major=True)
-                k = rope(k, positions, self.rope_theta, head_major=True)
-                v = by_head(n, lp["wv"])
-                h = joined(causal_attention(q, k, v, episode, 1.0))
+
+                def projected(w, norm, heads):
+                    a = by_head(n, lp[w], heads)
+                    if not self.qk_norm:
+                        return a
+                    # QK-norm over the whole projection: heads and d.
+                    return rms_norm(a, lp[norm].reshape(heads, 1, -1), eps,
+                                    cd, axes=(1, 3))
+                q = projected("wq", "q_norm", heads)
+                k = projected("wk", "k_norm", groups)
+                scale = q.shape[-1] ** -0.5
+                if rotary:
+                    # The softmax's scale goes onto q in RoPE's float32.
+                    q = rope(q, positions, self.rope_theta, scale,
+                             head_major=True)
+                    k = rope(k, positions, self.rope_theta, head_major=True)
+                    scale = 1.0
+                v = by_head(n, lp["wv"], groups)
+                h = joined(causal_attention(q, k, v, episode, scale, window))
                 caches = tuple(
                     jnp.take_along_axis(jnp.swapaxes(a, 1, 2),
                                         cache_rows[:, :, None, None], axis=1)
@@ -810,22 +1044,27 @@ class TokenDecoder(nn.Module):
             h = joined(o)
         return h, caches
 
-    def _attend_step(self, lp, x, pos, caches):
+    def _attend_step(self, lp, x, pos, caches, window=0, rotary=True):
         """x + Attention(RMSNorm(x)) of one token a row, x [B, H], against
         the layer's caches, this position written first; (h, the caches,
-        the positions read)."""
+        the positions read). A window layer's caches are a ring: position
+        p is written to slot p mod its length, over position p - length,
+        which has just left the window; keys are rotated before they are
+        cached, so the ring is read in whatever order it lies."""
         cd, eps = self.compute_dtype, self.rms_eps
         B = x.shape[0]
         rows = jnp.arange(B)
         if not self.kv_lora_rank:
             k_cache, v_cache = caches
-            with jax.named_scope("policy/attention"):
+            with jax.named_scope(self._attention_scope(window)):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
                 q, k, v = self._qkv(lp, n)
-                q = rope(q, pos, self.rope_theta)
-                k = rope(k, pos, self.rope_theta)
-                k_cache = k_cache.at[rows, pos].set(k)
-                v_cache = v_cache.at[rows, pos].set(v)
+                if rotary:
+                    q = rope(q, pos, self.rope_theta)
+                    k = rope(k, pos, self.rope_theta)
+                slot = pos % k_cache.shape[1] if window else pos
+                k_cache = k_cache.at[rows, slot].set(k)
+                v_cache = v_cache.at[rows, slot].set(v)
                 o, read = cached_attention(q, k_cache, v_cache, pos)
                 h = x + jnp.dot(o.reshape(B, -1), lp["wo"].astype(cd))
             return h, (k_cache, v_cache), read
@@ -854,26 +1093,41 @@ class TokenDecoder(nn.Module):
         return h, (cache,), read
 
     # -- feed-forward -----------------------------------------------------
-    def _feed_forward(self, lp, h):
+    def _route(self, lp, n):
+        return route(
+            n, lp["router"], self.experts_per_token, self.norm_topk_prob,
+            lp.get("router_bias"), self.routed_scaling_factor)
+
+    def _route_ahead(self, lp, x):
+        """The routing of rows x [.., H] where the router reads the
+        attention's normalised input (None where it reads the block's
+        post-attention norm: `_feed_forward` then routes itself)."""
+        if not self.router_before_attention:
+            return None
+        n = rms_norm(x, lp["attn_norm"], self.rms_eps, self.compute_dtype)
+        return self._route(lp, n.reshape(-1, n.shape[-1]))
+
+    def _feed_forward(self, lp, h, routing=None):
         """h + FeedForward(RMSNorm(h)) for rows h [M, H]; (out, rows a held
-        group, experts [M, k]), the last two None of a dense layer."""
+        group, experts [M, k]), the last two None of a dense layer.
+        `routing`: (weights, experts) chosen ahead of the attention."""
         cd = self.compute_dtype
+        act = ACTIVATIONS[self.hidden_act]
         n = rms_norm(h, lp["mlp_norm"], self.rms_eps, cd)
         if "dense_gate" in lp:
             with jax.named_scope("policy/dense_mlp"):
                 return h + swiglu(n, *(lp[w].astype(cd) for w in (
-                    "dense_gate", "dense_up", "dense_down"))), None, None
-        top_p, top_i = route(
-            n, lp["router"], self.experts_per_token, self.norm_topk_prob,
-            lp.get("router_bias"), self.routed_scaling_factor)
+                    "dense_gate", "dense_up", "dense_down")),
+                    act=act), None, None
+        top_p, top_i = routing or self._route(lp, n)
         moe, group_sizes = dropless_experts(
             n, top_p, top_i, lp["w_gate"].astype(cd), lp["w_up"].astype(cd),
             lp["w_down"].astype(cd), self.first_expert_held,
-            self.num_experts)
+            self.num_experts, act)
         if "shared_gate" in lp:
             with jax.named_scope("policy/shared_expert"):
                 moe = moe + swiglu(n, *(lp[w].astype(cd) for w in (
-                    "shared_gate", "shared_up", "shared_down")))
+                    "shared_gate", "shared_up", "shared_down")), act=act)
         return h + moe, group_sizes, top_i
 
     def _heads(self, x):
@@ -883,15 +1137,17 @@ class TokenDecoder(nn.Module):
             value = jnp.dot(y, self.value_w) + self.value_b
         return logits, value
 
-    def _count(self, experts, loads=None, read=None):
+    def _count(self, experts, loads=None, reads=None):
         """What a pass counted, kept only where the caller asks for the
         collection (and never among the variables `init` returns): the
         experts chosen [expert layers, ..., k], for the reference check;
         in the learner's form also the rows of the fullest held expert
         group over the layers and the mean group, and where the layer
         holds a share, the share of the (row, expert) pairs that landed
-        here; in a decode step the share of the window's positions its
-        attention read."""
+        here; in a decode step the share of the context's positions its
+        attention read, the mean over the layers (`reads`: the positions
+        each layer read), and where the model has window layers the same
+        of its full layers and of its window layers apart."""
         if self.is_initializing():
             return
         if experts:
@@ -904,9 +1160,19 @@ class TokenDecoder(nn.Module):
                 pairs = experts[0].size
                 self.sow("counters", "experts_held_row_share",
                          jnp.mean(jnp.sum(loads, axis=-1)) / pairs)
-        if read is not None:
+        window = [bool(self.layer_kind(i)[0])
+                  for i in range(len(reads or ()))]
+        if reads and not (any(window) and not all(window)):
+            # Layers of one kind read alike.
             self.sow("counters", "decode_cache_read_share",
-                     read.astype(jnp.float32) / self.context_len)
+                     reads[-1].astype(jnp.float32) / self.context_len)
+        elif reads:
+            shares = jnp.stack(reads).astype(jnp.float32) / self.context_len
+            window = jnp.asarray(window)
+            for kind, of in (("", None), ("_full", ~window),
+                             ("_window", window)):
+                self.sow("counters", "decode_cache_read_share" + kind,
+                         jnp.mean(shares, where=of))
 
     # -- the two forms --------------------------------------------------
     def causal(self, tokens, reset):
@@ -927,11 +1193,21 @@ class TokenDecoder(nn.Module):
         # positions.
         cache_rows = jnp.clip(start[:, -1:] + jnp.arange(S), 0, T - 1)
 
-        def block(lp, x):
+        def ring_rows(R):
+            """The same for a ring of R slots: slot j holds the last of
+            the episode's positions that is j mod R (none yet: a row the
+            decode will not read)."""
+            last = positions[:, -1:]
+            held = last - jnp.mod(last - jnp.arange(R), R)
+            return jnp.clip(start[:, -1:] + held, 0, T - 1)
+
+        def block(lp, x, kind=(0, True)):
+            routing = self._route_ahead(lp, x)
+            rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
             h, caches = self._attend_causal(
-                lp, x, positions, episode, cache_rows)
+                lp, x, positions, episode, rows, *kind)
             out, group_sizes, top_i = self._feed_forward(
-                lp, h.reshape(B * T, -1))
+                lp, h.reshape(B * T, -1), routing)
             return out.reshape(B, T, -1), caches, group_sizes, top_i
         if self.num_layers + self.nextn_layers > 1:
             # Recomputed in the backward pass, but for what the fused
@@ -940,12 +1216,13 @@ class TokenDecoder(nn.Module):
             # form's scores were 1 GB): its forward kernel runs once.
             block = jax.checkpoint(
                 block, policy=jax.checkpoint_policies.save_only_these_names(
-                    CAUSAL_KEPT))
+                    CAUSAL_KEPT), static_argnums=(2,))
 
         x = self.embed[tokens].astype(cd)
         kv, loads, experts = [], [], []
-        for layer in self.layers:
-            x, caches, group_sizes, top_i = block(layer(), x)
+        for i, layer in enumerate(self.layers):
+            x, caches, group_sizes, top_i = block(
+                layer(), x, self.layer_kind(i))
             kv.append(caches)
             if top_i is not None:
                 loads.append(group_sizes)
@@ -980,7 +1257,7 @@ class TokenDecoder(nn.Module):
                 rms_norm(jax.lax.stop_gradient(x), lp["hnorm"], eps, cd),
                 rms_norm(embed[following], lp["enorm"], eps, cd)], axis=-1),
                 lp["eh_proj"].astype(cd))
-        z, _, group_sizes, top_i = block(lp, z)
+        z, _, group_sizes, top_i = block(lp, z, (0, True))
         with jax.named_scope("policy/mtp"):
             y = rms_norm(z, lp["final_norm"], eps, jnp.float32)
             logp = jax.nn.log_softmax(
@@ -999,18 +1276,21 @@ class TokenDecoder(nn.Module):
     def decode(self, token, state, reset):
         pos = jnp.where(reset > 0, 0, state["pos"])
         x = self.embed[token].astype(self.compute_dtype)
-        kv, experts = [], []
-        for layer, caches in zip(self.layers, state["kv"]):
+        kv, experts, reads = [], [], []
+        for i, (layer, caches) in enumerate(zip(self.layers, state["kv"])):
             lp = layer()
-            h, caches, read = self._attend_step(lp, x, pos, caches)
+            routing = self._route_ahead(lp, x)
+            h, caches, read = self._attend_step(
+                lp, x, pos, caches, *self.layer_kind(i))
             kv.append(caches)
-            x, _, top_i = self._feed_forward(lp, h)
+            reads.append(read)
+            x, _, top_i = self._feed_forward(lp, h, routing)
             if top_i is not None:
                 experts.append(top_i)
         if self.is_initializing():
             for module in self.nextn:
                 module()
-        self._count(experts, read=read)
+        self._count(experts, reads=reads)
         logits, value = self._heads(x)
         return logits, value, {"kv": tuple(kv), "pos": pos + 1}
 
@@ -1029,6 +1309,14 @@ def _refuse_grouped_heads(cfg: dict, family: str, default_heads: int) -> None:
         raise ValueError(
             "TokenDecoder has as many key/value heads as query heads "
             f"({family}'s layout); got num_key_value_heads={kv}")
+
+
+def _refuse_other_values(cfg: dict, fixed: dict) -> None:
+    for key, only in fixed.items():
+        if key in cfg and cfg[key] != only:
+            raise ValueError(
+                f"custom_model_config {key}={cfg[key]!r}: TokenDecoder has "
+                f"{only!r} alone")
 
 
 def olmoe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
@@ -1054,14 +1342,42 @@ def glm4_moe_lite_from_config(num_outputs: int, cfg: dict,
              | {"num_key_value_heads"})
     _refuse_unknown(cfg, known, "glm4_moe_lite")
     _refuse_grouped_heads(cfg, "glm4_moe_lite", 20)
-    for key, only in GLM4_MOE_LITE_FIXED.items():
-        if key in cfg and cfg[key] != only:
-            raise ValueError(
-                f"custom_model_config {key}={cfg[key]!r}: TokenDecoder has "
-                f"{only!r} alone")
+    _refuse_other_values(cfg, GLM4_MOE_LITE_FIXED)
     fields = {GLM4_MOE_LITE_CONFIG_KEYS[k]: v for k, v in cfg.items()
               if k in GLM4_MOE_LITE_CONFIG_KEYS}
     fields["selection_bias"] = True  # `topk_method: noaux_tc`
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def smallthinker_from_config(num_outputs: int, cfg: dict,
+                             compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks
+    `smallthinker`'s published `config.json`'s own keys (a key left out
+    has SmallThinker-21BA3B's value), and the two that state the chip's
+    share of the experts; unknown keys are refused, and so is a published
+    key whose value the decoder has no part for. The family's parts:
+    grouped key/value heads of their own width, no QK-norm; a layer
+    windowed or full, rotary or position-free, by its entry in the two
+    layouts (whose leading `num_hidden_layers` entries are read, so that
+    a cut in depth keeps the leading layers' kinds); a softmax router
+    that reads the attention's normalised input; ReGLU experts."""
+    _refuse_unknown(
+        cfg, set(SMALLTHINKER_CONFIG_KEYS) | set(SMALLTHINKER_FIXED),
+        "smallthinker")
+    _refuse_other_values(cfg, SMALLTHINKER_FIXED)
+    fields = {SMALLTHINKER_CONFIG_KEYS[k]: v
+              for k, v in {**SMALLTHINKER_PUBLISHED, **cfg}.items()
+              if k in SMALLTHINKER_CONFIG_KEYS}
+    for layout in ("window_layout", "rope_layout"):
+        fields[layout] = tuple(bool(kind) for kind in fields[layout])
+        if len(fields[layout]) < fields["num_layers"]:
+            raise ValueError(
+                f"the {layout.replace('_', ' ')} has {len(fields[layout])} "
+                f"entries for {fields['num_layers']} layers")
+    fields.update(qk_norm=False, router_before_attention=True,
+                  hidden_act="relu")
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=num_outputs, **fields)

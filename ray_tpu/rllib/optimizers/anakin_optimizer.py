@@ -29,9 +29,11 @@ Policy state. A stateful policy (`policy.recurrent`: an LSTM's `(c, h)`, a
 transformer's key/value cache) has its state carried through the rollout
 scan as a pytree and reset where the previous step ended an episode. A
 recurrent policy's fragments are replayed by the learner from the state
-the rollout began them with (`state_in`). A policy whose state is a window
-of `context_len` positions is replayed from an empty window, so its
-fragments must be whole episodes: the optimizer refuses anything else.
+the rollout began them with (`state_in`). A policy whose state is a context
+of `context_len` positions (a transformer's caches, whatever each layer
+keeps of them: every position, or a ring of its own window) is replayed
+from an empty context, so its fragments must be whole episodes: the
+optimizer refuses anything else.
 
 Trajectory. A step keeps obs, action, reward, done, and the behaviour
 policy's distribution inputs; where those are too wide to keep
@@ -95,17 +97,17 @@ class AnakinOptimizer(PolicyOptimizer):
             raise ValueError(
                 f"num_envs ({num_envs}) must divide evenly across the "
                 f"learner mesh ({mesh_size} devices)")
-        window = getattr(policy.model, "context_len", None)
-        if window is not None:
+        context = getattr(policy.model, "context_len", None)
+        if context is not None:
             episode = getattr(jax_env, "episode_len", None)
-            if not episode or episode > window or self.T % episode:
+            if not episode or episode > context or self.T % episode:
                 raise ValueError(
-                    "a policy with a context window learns each fragment "
-                    "from an empty window: rollout_fragment_length "
+                    "a policy with a context of positions learns each "
+                    "fragment from an empty one: rollout_fragment_length "
                     f"({self.T}) must be whole episodes of the env "
                     f"(episode_len {episode}) and an episode must fit the "
-                    f"window ({window} positions)")
-        self._replays_state = policy.recurrent and window is None
+                    f"context ({context} positions)")
+        self._replays_state = policy.recurrent and context is None
         # Trace-time facts of the rollout's decode step and the learner's
         # pass over a fragment, where the model states any: host values
         # put beside the program's stats.

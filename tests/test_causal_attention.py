@@ -24,8 +24,9 @@ from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss
 def interpreted(monkeypatch):
     """The fused form's kernels run by the Pallas interpreter."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
-    monkeypatch.setattr(splash, "make_splash_mha", functools.partial(
-        splash.make_splash_mha, interpret=True))
+    for make in ("make_splash_mha", "make_splash_mqa"):
+        monkeypatch.setattr(splash, make, functools.partial(
+            getattr(splash, make), interpret=True))
 
 
 @pytest.fixture
@@ -107,6 +108,61 @@ def test_fused_form_is_the_plain_form(layout, tiles, case, interpreted):
                 form[0][0], np.asarray(v[0], np.float32))
             assert np.max(np.abs(form[1][0])) <= 1e-4
             assert np.max(np.abs(form[2][0])) <= 1e-4
+
+
+@pytest.mark.parametrize("groups,window", [
+    (1, 0), (2, 0), (4, 700), (1, CAUSAL_TILE), (2, 1), (4, 3 * CAUSAL_TILE)])
+def test_fused_form_over_grouped_heads_and_a_window_is_the_plain_form(
+        groups, window, interpreted):
+    """4 query heads over `groups` key/value heads, within `window`
+    positions (0: the whole episode; one tile, a tile and a part, a
+    single position, more than the fragment), an episode that starts in
+    mid tile: forward and gradients."""
+    T, heads, d = 3 * CAUSAL_TILE, 4, 128
+    keys = jax.random.split(jax.random.PRNGKey(groups + window), 4)
+    q = jax.random.normal(keys[0], (B, heads, T, d), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (B, groups, T, d), jnp.bfloat16)
+            for key in keys[1:3])
+    weight = jax.random.normal(keys[3], (B, heads, T, d), jnp.float32)
+    episode = episodes("reset_in_mid_tile", T)
+
+    def run(form):
+        def loss(q, k, v):
+            out = form(q, k, v, episode, d ** -0.5, window)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return [np.asarray(a, np.float32) for a in (out,) + grads]
+    fused, plain = run(transformer._causal_fused), run(
+        transformer._causal_plain)
+    assert all(np.isfinite(a).all() for a in fused)
+    assert np.max(np.abs(fused[0] - plain[0])) <= FORWARD_LIMIT
+    for got, want in zip(fused[1:], plain[1:]):
+        # (A probability of 1 has no gradient: to the sums' rounding.)
+        assert np.linalg.norm(got - want) <= max(
+            GRADIENT_LIMIT * np.linalg.norm(want), 1e-3)
+    if window == 1:  # each step attends to itself alone
+        per = heads // groups
+        np.testing.assert_array_equal(
+            plain[0], np.repeat(np.asarray(v, np.float32), per, axis=1))
+
+
+def test_the_limits_refuse_a_window_layer_computed_as_a_full_one(
+        interpreted):
+    """A window layer computed as a full one, or with another window,
+    is outside the limits that the two forms agree within."""
+    T, d = 2 * CAUSAL_TILE, 128
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k, v = (jax.random.normal(key, (1, 2, T, d), jnp.bfloat16)
+               for key in keys)
+    episode = jnp.ones((1, T), jnp.int32)
+    out = {window: np.asarray(transformer._causal_fused(
+        q, k, v, episode, d ** -0.5, window), np.float32)
+        for window in (0, 600, 601)}
+    assert np.max(np.abs(out[0] - out[600])) > 4 * FORWARD_LIMIT
+    # One position more in a window of 600 moves the late rows alone.
+    assert np.array_equal(out[600][:, :, :600], out[601][:, :, :600])
+    assert np.max(np.abs(out[600] - out[601])) > 0
 
 
 def test_the_limits_refuse_attention_across_an_episode_s_start(interpreted):
