@@ -1,0 +1,226 @@
+"""A decode step's attention over the positions a row holds of its cache.
+
+    o[b, g, r] = sum_s softmax_s(q[b, g, r] . k[b, g, s] * scale) v[b, g, s]
+                 over s < lengths[b]
+
+for one query a row and cached head: q [B, G, R, d_qk] (R query heads read
+cached head g), k [B, G, S, d_qk], v [B, G, S, d_v]; or `v` None, and the
+values are the first `value_dim` lanes of the same rows (a latent cache:
+G = 1, every query head of a row against the row's [S, d_qk] latents).
+`lengths` [B] int32, 1 <= lengths[b] <= S: a held position is
+s < lengths[b], nothing else is masked. One sum, two forms:
+
+* `whole_window`: the two matrix products over all S positions, the scores
+  [B, G, R, S] one float32 array, masked, normalised, cast, multiplied.
+  Every position of the cache is read, by each product.
+* `prefix_kernel` (Pallas, TPU): an online softmax over blocks of `block`
+  positions. A grid step holds one block of `rows` rows' cache in VMEM,
+  fetched once, and both products read it there; the blocks beyond the
+  furthest position that any of a step's rows holds are not fetched (their
+  index map names the last block that is, and the pipeline issues no copy
+  for a block it has). A row of a step that stops earlier than the step's
+  furthest masks all of a later block.
+
+Both multiply operands in q's dtype and accumulate in float32, take the
+maximum, the exponentials and their sum in float32, cast the probabilities
+to q's dtype where they enter the second product, and return q's dtype. They
+differ in where the probabilities are rounded: normalised first
+(`whole_window`), or each block's against the running maximum and the sum
+divided out of the float32 result (`prefix_kernel`).
+
+`decode_attention` is the kernel form with the other's derivative: a
+`pallas_call` that prefetches scalars has no JVP, and a decode step is
+differentiated where a learner takes its bootstrap value through one.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Positions in a block of the cache, and the most rows a grid step holds.
+# On a v5e at the latent widths (20 query heads, 576 / 512 wide, 128 rows,
+# a window of 1,024, bfloat16; one layer's attention in a scan that carries
+# its cache, ms a step with the window filling from empty / held whole;
+# PERF.md section 5 has the sweep): the two products over the whole window
+# 0.526 / 0.526; the kernel at blocks of 512 x 8 rows 0.222 / 0.252, 256 x
+# 16 0.188 / 0.252, 128 x 16 0.171 / 0.251, 128 x 32 0.169 / 0.253, 64 x 64
+# 0.160 / 0.257. A window held whole streams at 600 GB/s whatever the
+# block; a smaller block reads less of a window that fills from empty
+# (1/2 + block / 2S of it on the mean), and a grid step costs ~0.35 us
+# whether its block is fetched or not.
+BLOCK = 128
+ROWS = 16
+# Two buffers of ROWS x BLOCK x 640 lanes x 2 bytes are 5.2 MB, the float32
+# scores and probabilities of a step 0.4 MB; the compiler's own limit is
+# 16 MB of the chip's 128, and larger blocks were measured under this one.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def rows_a_step(B: int) -> int:
+    """The rows of the batch a grid step holds: the largest divisor of `B`
+    up to `ROWS`."""
+    return max(r for r in range(1, min(B, ROWS) + 1) if B % r == 0)
+
+
+def last_blocks(lengths, block: int, rows: int):
+    """[B // rows] int32: the last block of positions that any row of a
+    grid step's `rows` rows holds."""
+    furthest = jnp.max(lengths.reshape(-1, rows), axis=1)
+    return jnp.maximum(furthest - 1, 0) // block
+
+
+def positions_fetched(lengths):
+    """The positions of the blocks `prefix_kernel` fetches of a row's
+    cache at its own block and rows a step, the mean over the rows."""
+    last = last_blocks(lengths, BLOCK, rows_a_step(lengths.shape[0]))
+    return jnp.mean((last + 1).astype(jnp.float32)) * BLOCK
+
+
+def whole_window(q, k, v, lengths, scale, value_dim=None):
+    """The sum as two matrix products over the whole window."""
+    f32 = jnp.float32
+    held = jnp.arange(k.shape[2])[None, :] < lengths[:, None]
+    scores = jnp.einsum("bgrd,bgsd->bgrs", q, k,
+                        preferred_element_type=f32) * scale
+    scores = jnp.where(held[:, None, None, :], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    if v is not None:
+        return jnp.einsum("bgrs,bgsd->bgrd", attn, v,
+                          preferred_element_type=f32).astype(q.dtype)
+    # Against the whole rows, the values cut out of the product (a product
+    # against `k[..., :value_dim]` copies the rows).
+    return jnp.einsum("bgrs,bgsd->bgrd", attn, k,
+                      preferred_element_type=f32).astype(
+                          q.dtype)[..., :value_dim]
+
+
+def _kernel(last_ref, lengths_ref, q_ref, k_ref, *rest, scale, block,
+            value_dim):
+    """One grid step (i, g, j): block j of the cache of the rows of step
+    i, cached head g, against those rows' queries; the running maximum,
+    sum and weighted values of a row's heads stay in VMEM across j."""
+    if value_dim is None:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        v_ref, (o_ref, m_ref, l_ref, acc_ref) = None, rest
+    i, j = pl.program_id(0), pl.program_id(2)
+    f32 = jnp.float32
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # The steps beyond the last block held run nothing; their block index
+    # is the last one's, which the pipeline has, so it copies nothing.
+    @pl.when(j <= last_ref[i])
+    def _():
+        # [rows, 1, 1]
+        lengths = lengths_ref[...]
+        q, k = q_ref[...], k_ref[...]
+        # [rows, R, block]
+        s = jnp.einsum("trd,tsd->trs", q, k,
+                       preferred_element_type=f32) * scale
+        at = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(at < lengths, s, -jnp.inf)
+        # A row always holds position 0, so after block 0 its maximum is
+        # finite, and a later block it holds nothing of adds exp(-inf).
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_next
+        v = k[:, :, :value_dim] if v_ref is None else v_ref[...]
+        # What lies beyond a row's length is not the row's: 0 x NaN would
+        # be NaN. (The select is hidden behind the products: the step
+        # measured 0.2470 ms with it and 0.2474 without.)
+        at = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (v.shape[0], block, 1), 1)
+        v = jnp.where(at < lengths, v, jnp.zeros_like(v))
+        acc_ref[...] = alpha * acc_ref[...] + jnp.einsum(
+            "trs,tsd->trd", p.astype(q.dtype), v, preferred_element_type=f32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def prefix_kernel(q, k, v, lengths, scale, value_dim=None, *, block=None,
+                  rows=None, interpret=False):
+    """The sum as the kernel over the blocks held: `block` positions
+    (`BLOCK`) of `rows` rows (`rows_a_step`) a grid step; `interpret` runs
+    it by the Pallas interpreter (a test on a CPU)."""
+    B, G, R, d_qk = q.shape
+    S = k.shape[2]
+    block = block or BLOCK
+    rows = rows or rows_a_step(B)
+    if S % block or B % rows:
+        raise ValueError(
+            f"{S} positions are not whole blocks of {block}, or {B} rows "
+            f"not whole steps of {rows}")
+    d_v = value_dim if v is None else v.shape[3]
+    lengths = lengths.astype(jnp.int32)
+
+    def held(i, g, j, last):
+        return i, g, jnp.minimum(j, last[i]), 0
+
+    def whole(i, g, j, last):
+        return i, g, 0, 0
+
+    cached = [pl.BlockSpec((rows, None, block, d_qk), held)]
+    operands = [k]
+    if v is not None:
+        cached.append(pl.BlockSpec((rows, None, block, d_v), held))
+        operands.append(v)
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, block=block,
+                          value_dim=None if v is not None else value_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            # The index maps and the steps that run nothing read scalars;
+            # the mask reads the lengths as a vector.
+            num_scalar_prefetch=1,
+            grid=(B // rows, G, S // block),
+            in_specs=[pl.BlockSpec((rows, 1, 1), lambda i, g, j, last: (
+                i, 0, 0)), pl.BlockSpec((rows, None, R, d_qk), whole)]
+            + cached,
+            out_specs=pl.BlockSpec((rows, None, R, d_v), whole),
+            scratch_shapes=[pltpu.VMEM((rows, R, 1), jnp.float32),
+                            pltpu.VMEM((rows, R, 1), jnp.float32),
+                            pltpu.VMEM((rows, R, d_v), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, G, R, d_v), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="decode_attention",
+        interpret=interpret,
+    )(last_blocks(lengths, block, rows), lengths.reshape(B, 1, 1), q,
+      *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def decode_attention(q, k, v, lengths, scale, value_dim=None):
+    """`prefix_kernel`, differentiable: the pullback is `whole_window`'s."""
+    return prefix_kernel(q, k, v, lengths, scale, value_dim)
+
+
+def _forward(q, k, v, lengths, scale, value_dim):
+    return decode_attention(q, k, v, lengths, scale, value_dim), (
+        q, k, v, lengths)
+
+
+def _backward(scale, value_dim, kept, g):
+    q, k, v, lengths = kept
+    _, pullback = jax.vjp(
+        lambda q, k, v: whole_window(q, k, v, lengths, scale, value_dim),
+        q, k, v)
+    return (*pullback(g), None)
+
+
+decode_attention.defvjp(_forward, _backward)

@@ -131,8 +131,12 @@ One set of parameters, two forms (the stateful-policy protocol of
   and value. The rollout's form. Its attention reads the cache positions
   [0, n) only, n the furthest position any row of the batch holds, rounded
   up to a block of `DECODE_CACHE_BLOCK` positions and chosen inside the
-  step from `pos` (`cached_attention`; a latent cache and grouped heads'
-  caches are read whole); each row masks what it does not hold itself.
+  step from `pos` (`cached_attention`); each row masks what it does not
+  hold itself. A latent cache is read by a kernel of the repo's own
+  (`models/decode_attention.py`) where the program is lowered for a TPU
+  and the window is whole blocks: each block of latent rows once for both
+  products, the blocks up to the furthest position that the rows of a
+  grid step hold. Grouped heads' caches are read whole.
 
 Both return the cache, so a decode can follow a causal pass.
 """
@@ -145,6 +149,8 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.models import decode_attention
 
 Dtype = Any
 
@@ -306,8 +312,10 @@ GROUP_COST_ROWS = 540
 GROUPED_ROW_COST = 1.7
 
 
-# Positions in a block of the caches a decode step's attention reads (see
-# `cached_attention`). A window that fills from empty is read `1/2 + b/(2S)`
+# Positions in a block of the caches of a head's own keys and values that
+# a decode step's attention reads (see `cached_attention`; a latent cache's
+# block is the kernel's, `decode_attention.BLOCK`, and grouped heads' caches
+# have none). A window that fills from empty is read `1/2 + b/(2S)`
 # of, so a smaller block reads less; every block of the window is one more
 # branch of the step's `switch`, traced wherever a decode step is (the
 # rollout, and the learner's bootstrap step under `value_and_grad`) on
@@ -353,43 +361,42 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     the window read whole (3.08 against 2.97 ms, PERF.md section 5). In
     this form the fusion that multiplies the prefix reads it where it lies.
     With a shared latent head they are matrix products, every query head
-    of a row against the same [n, d] rows, and are written as those, over
-    the whole window in one branch: wherever such a product stands in a
-    conditional XLA:TPU first copies the WHOLE window to another layout,
-    in every branch, whatever the prefix (glm4_moe_lite's widths on a v5e,
-    128 rows, a window of 1,024: a step 4.16 ms whole, 4.83 in blocks of
-    512, 5.11 of 256; PERF.md section 5). Grouped heads are matrix
-    products too, a group's queries against its cached head, and take the
-    one whole-cache branch for the same reason, measured at 28 query
-    heads over 4 cached ones of 128, 16 rows (a v5e, PERF.md section 5): a
-    ring of 4,096 read whole 0.21 ms a step (79 % of the HBM's bandwidth),
-    a prefix of three quarters of it 0.55; a cache of 8,192 whole 0.40, a
-    prefix of five eighths 1.08; the same sums as a multiply-and-sum, which
-    does seven products and a cross-lane sum an element, 1.5 and 3.2 whole.
-    Reading a latent or a grouped prefix where it lies takes a kernel
-    (ROADMAP R-A9), which brings its own blocks."""
+    of a row against the same [n, d] rows, and wherever such a product
+    stands in a conditional XLA:TPU first copies the WHOLE window to
+    another layout, in every branch, whatever the prefix (glm4_moe_lite's
+    widths on a v5e, 128 rows, a window of 1,024: a step 4.16 ms whole,
+    4.83 in blocks of 512, 5.11 of 256; PERF.md section 5). So a latent
+    cache takes no `switch`, and one of two forms of the same sum
+    (`models/decode_attention.py`), chosen from what the program can see:
+    `decode_fused` of the static shape, and the platform the program is
+    lowered for. A kernel (a TPU, whole blocks): an online softmax over
+    blocks of `decode_attention.BLOCK` positions, each block of latent
+    rows fetched once for both products, the blocks beyond the furthest
+    position that the rows of a grid step hold not fetched; the positions
+    read are those blocks', the mean over the rows. The two products over
+    the whole window anywhere else (and under a gradient: the kernel form
+    carries their derivative). Grouped heads are matrix products too, a
+    group's queries against its cached head, and take one whole-cache
+    branch, measured at 28 query heads over 4 cached ones of 128, 16 rows
+    (a v5e, PERF.md section 5): a ring of 4,096 read whole 0.21 ms a step
+    (79 % of the HBM's bandwidth), a prefix of three quarters of it 0.55; a
+    cache of 8,192 whole 0.40, a prefix of five eighths 1.08; the same
+    sums as a multiply-and-sum, which does seven products and a
+    cross-lane sum an element, 1.5 and 3.2 whole. The kernel would read a
+    grouped prefix where it lies if the caches were head-major,
+    [B, groups, S, d] (ROADMAP R-A9)."""
     S = k_cache.shape[1]
-    grouped = v_cache is not None and k_cache.shape[2] != q.shape[1]
-    size = S if v_cache is None or grouped else DECODE_CACHE_BLOCK
-    ends = tuple(range(size, S, size)) + (S,)
     f32 = jnp.float32
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if v_cache is None:
+        return _latent_attention(q, k_cache, pos, scale, value_dim)
+    grouped = k_cache.shape[2] != q.shape[1]
+    size = S if grouped else DECODE_CACHE_BLOCK
+    ends = tuple(range(size, S, size)) + (S,)
 
     def attend(n, q, k_cache, v_cache, pos):
         held = jnp.arange(n)[None, :] <= pos[:, None]
-        if v_cache is None:
-            k = k_cache[:, :n]
-            # [B, heads, n]
-            scores = jnp.einsum("bhd,bsd->bhs", q, k,
-                                preferred_element_type=f32) * scale
-            scores = jnp.where(held[:, None, :], scores, -jnp.inf)
-            attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            # Against the whole rows, the values cut out of the product
-            # (a product against `k[..., :value_dim]` copies the rows).
-            return jnp.einsum("bhs,bsd->bhd", attn, k,
-                              preferred_element_type=f32).astype(
-                                  q.dtype)[..., :value_dim]
         if grouped:
             return attend_grouped(q, k_cache[:, :n], v_cache[:, :n], held)
         k, v = k_cache[:, :n].astype(f32), v_cache[:, :n].astype(f32)
@@ -421,6 +428,43 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     o = jax.lax.switch(block, [functools.partial(attend, n) for n in ends],
                        q, k_cache, v_cache, pos)
     return o, jnp.asarray(ends)[block]
+
+
+def decode_fused(S: int, R: int, d_qk: int, value_dim: int) -> bool:
+    """Whether a decode step of `R` query heads a row over a latent cache
+    of `S` positions, `d_qk` wide of which the first `value_dim` are the
+    values, can take the kernel form of `cached_attention`: a function of
+    the static shape alone. Whole blocks and at least two of them (one
+    block is the whole window with a kernel's set-up on top), values of
+    whole lane tiles, and the rest of a row (the rotary key) of whole
+    half tiles, which is what the kernel was compiled and measured at;
+    any number of query heads."""
+    block = decode_attention.BLOCK
+    return (S % block == 0 and S >= 2 * block and value_dim % 128 == 0
+            and d_qk > value_dim and (d_qk - value_dim) % 64 == 0)
+
+
+def _latent_attention(q, cache, pos, scale, value_dim):
+    """`cached_attention` of a latent cache: (o, the positions read)."""
+    S = cache.shape[1]
+    # One cached head, every query head of the row against it.
+    q, cache, lengths = q[:, None], cache[:, None], pos + 1
+
+    def whole(q, cache, lengths):
+        return decode_attention.whole_window(
+            q, cache, None, lengths, scale, value_dim), jnp.asarray(
+                S, jnp.float32)
+
+    def kernel(q, cache, lengths):
+        return decode_attention.decode_attention(
+            q, cache, None, lengths, scale,
+            value_dim), decode_attention.positions_fetched(lengths)
+    if decode_fused(S, q.shape[2], q.shape[3], value_dim):
+        o, read = jax.lax.platform_dependent(
+            q, cache, lengths, tpu=kernel, default=whole)
+    else:
+        o, read = whole(q, cache, lengths)
+    return o[:, 0], read
 
 
 # Positions in a tile of the fused causal attention (see
@@ -862,27 +906,37 @@ class TokenDecoder(nn.Module):
         it is compiled for. A decode step of `batch_size` rows: the mean
         rows a held expert group holds, whether the experts multiply in
         the batched form (1.0) or the grouped one (0.0), the positions in
-        a block of the caches its attention reads, and with a latent
-        cache its bytes a position. A causal pass over fragments of
-        `fragment_len` tokens: whether its attention takes the fused form
-        (1.0) or the plain one (0.0). A model with window layers: how
+        a block of the caches its attention reads, whether that attention
+        is the kernel over a latent cache (1.0) or XLA's products (0.0),
+        and with a latent cache its bytes a position. A causal pass over
+        fragments of `fragment_len` tokens: whether its attention takes
+        the fused form (1.0) or the plain one (0.0). A model with window layers: how
         many they are, the query heads a key/value head, the bytes of
         cache a position of the context that all layers hold together (a
         ring counts for its own length), and the share of the causal
         tiles that the fused form visits in a window layer."""
         k, E = self.experts_per_token, self.num_experts
+        kernel = False
         if self.kv_lora_rank:
             widths = (self.qk_nope_head_dim + self.qk_rope_head_dim,
                       self.v_head_dim)
+            kernel = platform == "tpu" and decode_fused(
+                self.context_len, self.num_heads, self.latent_width,
+                self.kv_lora_rank)
         else:
             widths = (self.head_width,) * 2
+        if kernel:
+            block = decode_attention.BLOCK
+        elif self.kv_lora_rank or self.kv_heads != self.num_heads:
+            block = self.context_len
+        else:
+            block = min(DECODE_CACHE_BLOCK, self.context_len)
         out = {
             "decode_rows_per_expert": batch_size * k / E,
             "decode_experts_batched": float(
                 experts_batched(batch_size, k, E)),
-            "decode_cache_block": self.context_len if (
-                self.kv_lora_rank or self.kv_heads != self.num_heads)
-            else min(DECODE_CACHE_BLOCK, self.context_len),
+            "decode_cache_block": block,
+            "decode_attention_kernel": float(kernel),
             "causal_attention_fused": float(
                 platform == "tpu" and causal_fused(fragment_len, *widths)),
         }

@@ -1,0 +1,390 @@
+"""A decode step's attention over a latent cache
+(`models/decode_attention.py`, `transformer.cached_attention`): the kernel,
+run by the Pallas interpreter on the CPU, against the two products over the
+whole window, over rows whose lengths differ and caches of both kinds; what
+lies beyond a row's length; the rule that chooses the form and the platform
+it follows; gradients through the kernel form; a whole `TokenDecoder`
+decode through it against the causal pass; and what the counters say.
+"""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from lib import reference_glm4_moe_lite as reference  # noqa: E402
+
+from ray_tpu.models import catalog, decode_attention, transformer  # noqa: E402
+from ray_tpu.models.transformer import decode_fused  # noqa: E402
+from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
+
+BLOCK = 8
+WINDOW = 4 * BLOCK
+# (cached heads, query heads a cached one, d_qk, d_v, values cached apart):
+# the second token cell's latent rows, a rehearsal's, and grouped heads
+# with values of their own.
+LAYOUTS = {
+    "latent_576_512": (1, 20, 576, 512, False),
+    "latent_rehearsal": (1, 4, 24, 16, False),
+    "grouped_4x7_128": (4, 7, 128, 128, True),
+    "grouped_2x3_64_32": (2, 3, 64, 32, True),
+}
+# Rows two a grid step: a step whose rows both end in block 0; one with a
+# row at a block's last position and one at the next block's first; a row
+# that holds the window whole beside one of a single position; a block's
+# edge exactly, beside the middle of the last block.
+LENGTHS = [1, BLOCK - 1, BLOCK, BLOCK + 1, WINDOW, 1, 2 * BLOCK,
+           WINDOW - 3]
+# float32: the two forms are one sum to rounding. bfloat16: they round the
+# probabilities in different places (the chip read 0.008 at the cell's
+# widths and outputs of 2.9).
+LIMITS = {"f32": 1e-5, "bf16": 0.03}
+
+
+def operands(layout, dtype, key=0):
+    G, R, d_qk, d_v, apart = LAYOUTS[layout]
+    dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    B = len(LENGTHS)
+    keys = jax.random.split(jax.random.PRNGKey(key), 3)
+    q = jax.random.normal(keys[0], (B, G, R, d_qk), dtype)
+    k = jax.random.normal(keys[1], (B, G, WINDOW, d_qk), dtype)
+    v = jax.random.normal(keys[2], (B, G, WINDOW, d_v), dtype) if apart \
+        else None
+    return q, k, v, jnp.asarray(LENGTHS, jnp.int32), d_qk ** -0.5, (
+        None if apart else d_v)
+
+
+def kernel(q, k, v, lengths, scale, value_dim, rows=2):
+    return decode_attention.prefix_kernel(
+        q, k, v, lengths, scale, value_dim, block=BLOCK, rows=rows,
+        interpret=True)
+
+
+def f32(a):
+    return np.asarray(a, np.float32)
+
+
+# -- the two forms of the one sum ------------------------------------------
+@pytest.mark.parametrize("rows", [1, 2, 8])
+@pytest.mark.parametrize("dtype", LIMITS)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_kernel_is_the_whole_window_form(layout, dtype, rows):
+    args = operands(layout, dtype)
+    got = kernel(*args, rows=rows)
+    want = decode_attention.whole_window(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.max(np.abs(f32(got) - f32(want))) <= LIMITS[dtype]
+    # A row of one position attends to it alone.
+    q, k, v, *_ = args
+    alone = (k[..., :want.shape[-1]] if v is None else v)[0, :, :1]
+    np.testing.assert_allclose(
+        f32(got[0]), np.broadcast_to(f32(alone), got[0].shape),
+        atol=LIMITS[dtype])
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_what_lies_beyond_a_row_s_length_changes_nothing(layout):
+    """NaN in every position a row does not hold, in the block its length
+    ends in and in those beyond, which are not fetched."""
+    q, k, v, lengths, scale, value_dim = operands(layout, "bf16")
+    beyond = (jnp.arange(WINDOW)[None, :] >= lengths[:, None])[
+        :, None, :, None]
+    spoiled = [None if a is None else jnp.where(beyond, jnp.nan, a)
+               for a in (k, v)]
+    got = kernel(q, *spoiled, lengths, scale, value_dim)
+    assert np.isfinite(f32(got)).all()
+    np.testing.assert_array_equal(
+        f32(got), f32(kernel(q, k, v, lengths, scale, value_dim)))
+
+
+def test_the_limit_refuses_a_length_off_by_one():
+    args = list(operands("latent_576_512", "f32"))
+    want = f32(decode_attention.whole_window(*args))
+    args[3] = jnp.minimum(args[3] + 1, WINDOW)
+    got = f32(kernel(*args))
+    moved = np.max(np.abs(got - want), axis=(1, 2, 3))
+    # Every row but the one that holds the whole window.
+    assert (moved[np.asarray(LENGTHS) < WINDOW] > 1e3 * LIMITS["f32"]).all()
+    assert moved[LENGTHS.index(WINDOW)] <= LIMITS["f32"]
+
+
+def test_blocks_beyond_the_furthest_row_of_a_step_are_not_fetched():
+    """`last_blocks` is what the index maps clamp to and what the read
+    counter counts; rows two a step."""
+    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    np.testing.assert_array_equal(
+        decode_attention.last_blocks(lengths, BLOCK, 2), [0, 1, 3, 3])
+    np.testing.assert_array_equal(
+        decode_attention.last_blocks(lengths, BLOCK, 8), [3])
+    assert decode_attention.rows_a_step(128) == decode_attention.ROWS
+    assert decode_attention.rows_a_step(8) == 8
+    assert decode_attention.rows_a_step(3) == 3
+    assert decode_attention.rows_a_step(34) == 2
+
+
+def test_whole_blocks_and_whole_steps_or_an_error():
+    q, k, v, lengths, scale, value_dim = operands("latent_rehearsal", "f32")
+    with pytest.raises(ValueError, match="whole blocks"):
+        decode_attention.prefix_kernel(
+            q, k, v, lengths, scale, value_dim, block=5, rows=2,
+            interpret=True)
+    with pytest.raises(ValueError, match="whole steps"):
+        decode_attention.prefix_kernel(
+            q, k, v, lengths, scale, value_dim, block=BLOCK, rows=3,
+            interpret=True)
+
+
+# -- the rule ----------------------------------------------------------------
+@pytest.mark.parametrize("S,R,d_qk,value_dim,fused", [
+    (1024, 20, 576, 512, True),    # the second token cell's decode step
+    (16, 4, 24, 16, False),        # its rehearsal's, and a test's
+    (128, 20, 576, 512, False),    # one block
+    (256, 20, 576, 512, True),     # two
+    (1000, 20, 576, 512, False),   # no whole blocks
+    (1024, 20, 576, 500, False),   # values of no whole lane tiles
+    (1024, 20, 544, 512, False),   # a rotary key of no whole half tile
+    (1024, 20, 512, 512, False),   # no rotary key: no latent layout
+    (4096, 128, 576, 512, True),   # DeepSeek-V2's heads
+    (2048, 16, 320, 256, True),
+])
+def test_decode_fused_is_a_rule_of_the_static_shape(S, R, d_qk, value_dim,
+                                                    fused):
+    assert decode_attention.BLOCK == 128
+    assert decode_fused(S, R, d_qk, value_dim) == fused
+
+
+@pytest.mark.parametrize("platform,S,kernel_there", [
+    ("cpu", 1024, False), ("tpu", 1024, True), ("tpu", 1000, False)])
+def test_the_form_follows_the_platform_the_program_is_lowered_for(
+        platform, S, kernel_there):
+    """Lowered for a TPU, whole blocks: the kernel, and no product against
+    the [B, S, 576] window. Anywhere else the two products and no kernel."""
+    q = jax.ShapeDtypeStruct((8, 20, 576), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((8, S, 576), jnp.bfloat16)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32)
+    lowered = jax.jit(functools.partial(
+        transformer.cached_attention, v_cache=None, scale=576 ** -0.5,
+        value_dim=512)).trace(q, cache, pos=pos).lower(
+            lowering_platforms=(platform,)).as_text()
+    assert ("tpu_custom_call" in lowered) == kernel_there
+    assert ("dot_general" in lowered) != kernel_there
+
+
+@pytest.mark.parametrize("kind", ["heads_of_their_own", "grouped"])
+def test_the_other_kinds_of_cache_take_no_kernel(kind):
+    heads, groups = (16, 16) if kind == "heads_of_their_own" else (28, 4)
+    q = jax.ShapeDtypeStruct((8, heads, 128), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((8, 1024, groups, 128), jnp.bfloat16)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32)
+    lowered = jax.jit(transformer.cached_attention).trace(
+        q, cache, cache, pos).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in lowered
+
+
+# -- through `cached_attention`, on this CPU ----------------------------------
+@pytest.fixture
+def kernel_here(monkeypatch):
+    """A program lowered for this CPU takes the branch a TPU's would, its
+    kernel run by the Pallas interpreter over blocks of `BLOCK`, two rows
+    a grid step; the rule takes a test's widths."""
+    monkeypatch.setattr(decode_attention, "BLOCK", BLOCK)
+    monkeypatch.setattr(decode_attention, "ROWS", 2)
+    monkeypatch.setattr(decode_attention, "prefix_kernel", functools.partial(
+        decode_attention.prefix_kernel, interpret=True))
+    monkeypatch.setattr(
+        transformer, "decode_fused",
+        lambda S, R, d_qk, value_dim: S % BLOCK == 0 and S >= 2 * BLOCK)
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args))
+
+
+def latent(dtype, B=4):
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(keys[0], (B, 4, 24), dtype)
+    cache = jax.random.normal(keys[1], (B, WINDOW, 24), dtype)
+    weight = jax.random.normal(keys[2], (B, 4, 16), jnp.float32)
+    pos = jnp.asarray([0, BLOCK - 1, BLOCK, WINDOW - 1][:B], jnp.int32)
+    return q, cache, pos, weight
+
+
+def test_cached_attention_reads_the_blocks_held(kernel_here, monkeypatch):
+    q, cache, pos, _ = latent(jnp.float32)
+    got, read = transformer.cached_attention(
+        q, cache, None, pos, scale=0.25, value_dim=16)
+    # Rows two a step: blocks [0, 0] and [1, 3] are the last held.
+    assert float(read) == (1 + 4) / 2 * BLOCK
+    monkeypatch.undo()
+    want, whole = transformer.cached_attention(
+        q, cache, None, pos, scale=0.25, value_dim=16)
+    assert float(whole) == WINDOW
+    np.testing.assert_allclose(f32(got), f32(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_gradients_through_the_kernel_form_are_the_plain_form_s(
+        dtype, kernel_here, monkeypatch):
+    """The bootstrap step's `value_and_grad`: the kernel's output with the
+    two products' pullback."""
+    q, cache, pos, weight = latent(
+        {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype])
+
+    def run():
+        def loss(q, cache):
+            out, _ = transformer.cached_attention(
+                q, cache, None, pos, scale=0.25, value_dim=16)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(q, cache)
+        return [f32(a) for a in (out,) + grads]
+    fused = run()
+    monkeypatch.undo()
+    plain = run()
+    assert np.max(np.abs(fused[0] - plain[0])) <= LIMITS[dtype]
+    for got, want in zip(fused[1:], plain[1:]):
+        np.testing.assert_array_equal(got, want)
+    # Nothing flows to what a row does not hold.
+    assert not fused[2][0, 1:].any() and fused[2][0, 0].any()
+
+
+# -- a whole decode ------------------------------------------------------------
+NET = dict(vocab_size=96, hidden_size=64, num_attention_heads=4,
+           num_key_value_heads=4, num_hidden_layers=3, q_lora_rank=24,
+           kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=8,
+           v_head_dim=16, first_k_dense_replace=1, intermediate_size=96,
+           n_routed_experts=8, experts_held=2, first_expert_held=0,
+           num_experts_per_tok=2, moe_intermediate_size=32,
+           n_shared_experts=1, topk_method="noaux_tc", n_group=1,
+           topk_group=1, norm_topk_prob=True, routed_scaling_factor=1.8,
+           num_nextn_predict_layers=1, max_position_embeddings=WINDOW,
+           rope_theta=1e6, rms_norm_eps=1e-5)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_a_decode_through_the_kernel_form_is_the_causal_pass(
+        dtype, kernel_here):
+    """Every position of a rehearsal-sized glm4_moe_lite decoded one token
+    at a time through the kernel form, against the causal pass's
+    decompressed keys and values; the share of the window each step read."""
+    rows = 4
+    model = catalog.get_model(None, NET["vocab_size"], {
+        "custom_model": "glm4_moe_lite", "custom_model_config": NET,
+        "compute_dtype": dtype})
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (rows, WINDOW), 0, NET["vocab_size"])
+    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
+                           model.initial_state(rows), jnp.zeros((rows, 1)))
+    logits, values, _ = model.apply(
+        variables, tokens, None, jnp.zeros((rows, WINDOW)))
+
+    @jax.jit
+    def decode(token, state):
+        return model.apply(variables, token, state, jnp.zeros(rows),
+                           method="decode", mutable=["counters"])
+    state, got_l, got_v, read = model.initial_state(rows), [], [], []
+    for t in range(WINDOW):
+        (step_l, step_v, state), kept = decode(tokens[:, t], state)
+        got_l.append(step_l)
+        got_v.append(step_v)
+        read.append(float(kept["counters"]["decode_cache_read_share"][-1]))
+    assert read == [(t // BLOCK + 1) * BLOCK / WINDOW for t in range(WINDOW)]
+    got_l, got_v = jnp.stack(got_l, 1), jnp.stack(got_v, 1)
+    if dtype == "f32":
+        assert reference.relative_error(got_l, logits) <= 1e-5
+        assert reference.relative_error(got_v, values) <= 1e-5
+        return
+    # bfloat16: where a token's experts tie within the rounding, one of
+    # them changes and that position's logits with it (the two products
+    # over the whole window read the same here: 8 of 128 positions).
+    by_position = np.max(np.abs(f32(got_l) - f32(logits)), axis=-1) / np.max(
+        np.abs(f32(logits)))
+    assert np.median(by_position) <= reference.TOLERANCE / 3
+    assert np.mean(by_position > reference.TOLERANCE) <= 0.15
+
+
+# -- the counters ---------------------------------------------------------------
+def test_static_counters_name_the_kernel_and_its_block():
+    cell = dict(NET, vocab_size=19360, hidden_size=2048,
+                num_attention_heads=20, num_key_value_heads=20,
+                num_hidden_layers=5, q_lora_rank=768, kv_lora_rank=512,
+                qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+                intermediate_size=10240, n_routed_experts=64, experts_held=8,
+                num_experts_per_tok=4, moe_intermediate_size=1536,
+                max_position_embeddings=1024)
+    for net, platform, kernel_there, block in [
+            (cell, "tpu", 1.0, decode_attention.BLOCK),
+            (cell, "cpu", 0.0, 1024), (NET, "tpu", 0.0, WINDOW)]:
+        model = catalog.get_model(None, net["vocab_size"], {
+            "custom_model": "glm4_moe_lite", "custom_model_config": net})
+        counters = model.static_counters(128, 1024, platform)
+        assert counters["decode_attention_kernel"] == kernel_there
+        assert counters["decode_cache_block"] == block
+        assert counters["latent_cache_bytes_per_token"] == (
+            net["num_hidden_layers"]
+            * (net["kv_lora_rank"] + net["qk_rope_head_dim"]) * 2)
+
+
+def test_learner_stats_report_what_the_kernel_read(kernel_here):
+    """The trainer on the fused Anakin path with the kernel form in its
+    rollout and under its learner's bootstrap step: a window that fills
+    from empty reads 1/2 + block / 2S of itself over a rollout."""
+    trainer = IMPALATrainer(config=dict(
+        env="TokenBigram-v0",
+        env_config={"vocab_size": NET["vocab_size"], "episode_len": WINDOW},
+        anakin=True, num_workers=0, num_envs_per_worker=4,
+        rollout_fragment_length=WINDOW, train_batch_size=4 * WINDOW,
+        sgd_minibatch_size=2 * WINDOW, num_sgd_iter=1,
+        anakin_updates_per_call=1, min_iter_time_s=0, lr=6e-4, seed=3,
+        model={"custom_model": "glm4_moe_lite", "custom_model_config": NET,
+               "compute_dtype": "f32"}))
+    try:
+        result = trainer.train()
+        assert np.isfinite(result["info"]["learner"]["total_loss"])
+        kept = trainer.optimizer.learner_stats
+        assert kept["decode_cache_read_share"] == pytest.approx(
+            0.5 + BLOCK / (2 * WINDOW))
+        # The host's counters are of the platform the trainer runs on.
+        assert kept["decode_attention_kernel"] == 0.0
+    finally:
+        trainer.stop()
+
+
+# -- the chip's compiler --------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows", [128, 8])
+def test_the_kernel_compiles_for_a_v5e_at_the_cell_s_widths(rows, one_chip):
+    """Mosaic takes the contraction of 576, the value slice of 512 and the
+    block as they stand (the rollout's 128 rows, the bootstrap step's 8),
+    and the cache enters as it lies: no copy of the window."""
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        transformer.cached_attention, v_cache=None, scale=576 ** -0.5,
+        value_dim=512)).trace(
+            shaped(rows, 20, 576), shaped(rows, 1024, 576),
+            pos=shaped(rows, dtype=jnp.int32)).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in compiled
+    assert not [line for line in compiled.splitlines()
+                if " copy(" in line and f"bf16[{rows},1,1024,576]" in
+                line.split(" copy(")[0]]

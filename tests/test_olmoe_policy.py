@@ -423,7 +423,7 @@ def test_the_form_is_chosen_from_the_static_shape():
     assert model.static_counters(128, 1024, "tpu") == {
         "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0,
         "decode_cache_block": transformer.DECODE_CACHE_BLOCK,
-        "causal_attention_fused": 1.0}
+        "decode_attention_kernel": 0.0, "causal_attention_fused": 1.0}
     assert model.static_counters(
         2048, 1024, "tpu")["decode_experts_batched"] == 0.0
 

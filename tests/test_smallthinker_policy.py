@@ -342,8 +342,8 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "custom_model": "smallthinker", "custom_model_config": net})
     assert model.static_counters(16, 8192, "tpu") == {
         "decode_rows_per_expert": 1.5, "decode_experts_batched": 1.0,
-        "decode_cache_block": 8192, "causal_attention_fused": 1.0,
-        "window_layers": 3, "kv_groups": 7,
+        "decode_cache_block": 8192, "decode_attention_kernel": 0.0,
+        "causal_attention_fused": 1.0, "window_layers": 3, "kv_groups": 7,
         "kv_cache_bytes_per_token": 5120.0,
         "causal_window_tiles_kept": 108 / 136}
     state = jax.eval_shape(lambda: model.initial_state(16))
