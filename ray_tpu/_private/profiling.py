@@ -38,6 +38,13 @@ that is a `jax.profiler.TraceAnnotation` ("ray_tpu.<name>", on the
 device trace's clock while a profiler session runs, a no-op outside
 one) and an entry in the calling thread's `PhaseClock` (always on;
 cumulative seconds and counts that partition the thread's wall time).
+
+`device_account.py`, beside this file, reads those annotations and the
+programs' `jax.named_scope`s back out of a trace: device seconds by
+scope, module and collective, and the chip's idle seconds by the phase
+each loop thread had open. `run_capture()` returns it as
+`device_account` for the trace it took, and `scripts profile
+--summarize` prints it.
 """
 
 from __future__ import annotations
@@ -505,7 +512,10 @@ def run_capture(duration_s: float, hz: Optional[float] = None,
     for `duration_s` plus, when `xla_dir` is given and the process owns
     a device, a `jax.profiler` trace over the same window. Returns the
     sampler result augmented with pid/HBM/XLA fields — the per-process
-    payload a coordinated capture ships back to the head."""
+    payload a coordinated capture ships back to the head. Where a trace
+    was written, `device_account` is its reduction by the program's own
+    names (`device_account.account`; None where it holds no device op);
+    whatever goes wrong with the trace or its account is `xla_error`."""
     from . import config
     duration_s = max(0.05, min(float(duration_s),
                                config.get("RAY_TPU_PROFILE_MAX_S")))
@@ -517,7 +527,12 @@ def run_capture(duration_s: float, hz: Optional[float] = None,
         try:
             import jax
             os.makedirs(xla_dir, exist_ok=True)
-            jax.profiler.start_trace(xla_dir)
+            # The benchmark's options, so that the two traces are of one
+            # kind: host events, no Python frames (the sampler has those).
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 2
+            jax.profiler.start_trace(xla_dir, profiler_options=options)
             tracing = True
             xla_trace_dir = xla_dir
         except Exception as e:
@@ -538,6 +553,12 @@ def run_capture(duration_s: float, hz: Optional[float] = None,
     out["pid"] = os.getpid()
     out["duration_s"] = duration_s
     out["xla_trace_dir"] = xla_trace_dir
+    if xla_trace_dir:
+        try:
+            from . import device_account
+            out["device_account"] = device_account.account(xla_trace_dir)
+        except Exception as e:
+            xla_error = "%s: %s" % (type(e).__name__, e)
     if xla_error:
         out["xla_error"] = xla_error
     hbm = device_memory_stats()

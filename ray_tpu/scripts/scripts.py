@@ -471,7 +471,10 @@ def cmd_dump(args):
 
 def _print_profile_summary(bundle: dict, top: int = 8):
     """Top-N hottest frames per process — the bundle usable without
-    flamegraph tooling."""
+    flamegraph tooling — and, under a process that took a device trace,
+    its account: the ten heaviest scopes, collective seconds, idle by
+    family and phase."""
+    from ray_tpu._private import device_account
     from ray_tpu._private.profiling import top_frames
     procs = bundle.get("processes") or []
     print(f"capture {bundle.get('capture_id')}: "
@@ -495,6 +498,10 @@ def _print_profile_summary(bundle: dict, top: int = 8):
         for frame, count, share in top_frames(p.get("folded") or {},
                                               n=top):
             print(f"   {share:6.1%} {count:>6d}  {frame}")
+        if p.get("device_account"):
+            for line in device_account.render(p["device_account"], top=10,
+                                              indent="   "):
+                print(line)
         for d in p.get("hbm") or []:
             print(f"   hbm {d['device']} ({d.get('kind') or d.get('platform')}): "
                   f"used={d.get('used')} peak={d.get('peak')} "
@@ -842,8 +849,11 @@ def main(argv=None):
     p.add_argument("--top", type=int, default=8,
                    help="frames per process in the summary")
     p.add_argument("--summarize", default=None, metavar="BUNDLE",
-                   help="pretty-print an existing bundle JSON instead "
-                        "of capturing")
+                   help="print an existing bundle JSON instead of "
+                        "capturing: top frames a process and, where a "
+                        "process traced a device, its seconds by "
+                        "named_scope, collective seconds, and idle "
+                        "seconds by the phase each loop thread had open")
     p.set_defaults(fn=cmd_profile)
 
     args = parser.parse_args(argv)

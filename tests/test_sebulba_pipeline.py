@@ -499,3 +499,48 @@ class TestPipelineSmoke:
         # thread, so the ratio can undershoot but never overshoot).
         assert 0 < st["policy_lag_sum"] / st["steps"] <= 2.0
         t.stop()
+
+    def test_the_frames_feed_trains_over_a_mesh_of_four(self, ray_session):
+        """`impala_sebulba_frames_x4` at a tiny size: inline actor threads
+        shard full-frame uploads and fragments over a `dp` mesh of four
+        devices while the learner's update all-reduces on it; params and
+        batch are reported on all four, and the learner's clock is
+        partitioned by the four phases its metrics read."""
+        import math
+        from ray_tpu.rllib.agents.registry import get_trainer_class
+        t = get_trainer_class("IMPALA")(config={
+            "env": "SyntheticAtariFrames-v0",
+            "num_workers": 0,
+            "num_inline_actors": 2,
+            "num_envs_per_worker": 8,
+            "device_frame_stack": 4,
+            "obs_delta": False,
+            "rollout_fragment_length": 4,
+            "train_batch_size": 32,
+            "learner_queue_size": 2,
+            "num_tpus_for_learner": 4,
+            "min_iter_time_s": 0,
+            "seed": 0,
+        })
+        try:
+            opt = t.optimizer
+            deadline = time.monotonic() + 90
+            result = {}
+            while opt.num_steps_trained < 3 * 32:
+                assert time.monotonic() < deadline, "trained too few steps"
+                result = t.train()
+            assert result["device"]["count"] == 4
+            assert result["device"]["params_on"] == 4
+            assert result["device"]["batch_on"] == 4
+            assert math.isfinite(result["info"]["learner"]["total_loss"])
+            learner = opt.learner.clock.snapshot()
+            for name in ("learner.dequeue", "learner.h2d",
+                         "learner.lock_wait", "learner.train",
+                         "learner.readback"):
+                assert learner["counts"].get(name, 0) >= 3, name
+            sampler = opt._inline_actors[0].sampler
+            assert len(sampler.policy._bsharded.device_set) == 4
+            phases = sampler.transfer_stats()["phases"]["counts"]
+            assert phases["sebulba.upload"] > 0 and phases["sebulba.select"] > 0
+        finally:
+            t.stop()
