@@ -129,7 +129,18 @@ def forward_counted(policy, params, batch, T: int):
             params, last_new_obs[:, None], carry, last_done[:, None])
         bootstrap_value = boot_bt[:, 0]
     else:
-        dist_inputs, values_flat = policy.apply(params, batch[sb.OBS])
+        if sb.OBS_TIME_MAJOR in batch:
+            # The model is row-wise, so it reads the observations where
+            # the rollout wrote them; only its narrow outputs are put in
+            # OBS's (env-major) row order.
+            view = batch[sb.OBS_TIME_MAJOR]
+            dist_inputs, values_flat = (
+                sb.packed_from_time_major(
+                    x.reshape(view.shape[:3] + x.shape[1:]))
+                for x in policy.apply(
+                    params, view.reshape((-1,) + view.shape[3:])))
+        else:
+            dist_inputs, values_flat = policy.apply(params, batch[sb.OBS])
         if sb.BOOTSTRAP_OBS in batch:
             boot_obs = batch[sb.BOOTSTRAP_OBS]
         else:

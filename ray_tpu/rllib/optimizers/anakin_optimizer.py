@@ -39,6 +39,32 @@ Trajectory. A step keeps obs, action, reward, done, and the behaviour
 policy's distribution inputs; where those are too wide to keep
 (`policy.keeps_dist_inputs`, decided from the action space's size) it
 keeps the taken action's log-probability and the value in their place.
+
+Packing. The learner's batch is packed fragments, env-major: row
+`n * T + t` is step `t` of env slot `n`. The columns that are a scalar or
+a short vector a step (actions, rewards, dones, log-probabilities,
+values, narrow logits; a token or CartPole's four numbers as the
+observation) are stacked `[T, N, ..]` by the rollout's scan and
+transposed (`em`): 65,536 scalars a column, and the transposition is the
+one `vtrace_loss` undoes. Observations that are arrays a step (frames:
+`obs.ndim > 2`) are not stacked. On the chip every activation of such a
+program is laid out batch-minor (the rows lie along the lanes), so
+env-major rows interleave the `T` steps along the lanes: a transposed
+copy of every frame whatever the dtype, and no in-place write can make
+it (a 4-byte write every `4 T` bytes). What a row-wise model needs is
+the frames, not their order. So where the policy is feed-forward, the
+rollout is learned in one update and a device's env slots fill whole
+lane tiles, step `t` writes its frames in place, in the env's dtype, at
+rows `[t * N, (t + 1) * N)` of a buffer `[T * N, ..]` a device
+(`write_step`: one aligned `dynamic_update_slice` along the lanes,
+carried through both scans so that it is zeroed once a call). The batch
+hands that buffer over as `sb.OBS_TIME_MAJOR`; `sb.OBS` is its
+env-major view, bit for bit what `em` of the stacked frames was, which
+XLA never lays out unless a loss reads it. `vtrace_policy.forward_counted`
+feeds the model the rows as they lie and puts its logits and values in
+`sb.OBS`'s order. Every other case (scalar observations, stateful
+policies, minibatches, env counts that leave a lane tile part-filled)
+stacks and transposes as before.
 """
 
 from __future__ import annotations
@@ -54,6 +80,12 @@ import optax
 from ..._private.profiling import PhaseClock, phase
 from .. import sample_batch as sb
 from .policy_optimizer import PolicyOptimizer
+
+# Rows of one lane tile of the chip's layouts: a step's in-place write of
+# `N` rows a device is a plain aligned store only where `N` is whole tiles
+# (XLA:TPU read and wrote the whole buffer a step otherwise: 6.4 ms for
+# 1.85 GB against 0.24 ms).
+LANE_TILE = 128
 
 
 class AnakinOptimizer(PolicyOptimizer):
@@ -93,7 +125,8 @@ class AnakinOptimizer(PolicyOptimizer):
         policy = self.policy
         mesh_size = int(policy.mesh.devices.size) \
             if policy.mesh is not None else 1
-        if num_envs % max(1, mesh_size):
+        self._mesh_size = max(1, mesh_size)
+        if num_envs % self._mesh_size:
             raise ValueError(
                 f"num_envs ({num_envs}) must divide evenly across the "
                 f"learner mesh ({mesh_size} devices)")
@@ -166,11 +199,27 @@ class AnakinOptimizer(PolicyOptimizer):
         num_mb = (N * T) // self.minibatch
         mb_frags = self.minibatch // T
         stateful = policy.recurrent
+        one_update_a_rollout = num_mb == 1 and self.num_sgd_iter == 1
+        # Frames written where the learner reads them (module docstring):
+        # a buffer [D, T * n, ..], D the devices the env slots are sharded
+        # over, n the slots of one.
+        D, n = self._mesh_size, N // self._mesh_size
+        row = self._obs.shape[1:]
+        in_place = (len(row) > 1 and not stateful and one_update_a_rollout
+                    and n % LANE_TILE == 0)
 
-        def rollout_step(params, scarry):
-            """One env step of all slots under `params`: the carry, and
+        def write_step(frames, obs, t):
+            zero = jnp.zeros((), jnp.int32)
+            return jax.lax.dynamic_update_slice(
+                frames, obs.reshape((D, n) + row),
+                (zero, t * n) + (zero,) * len(row),
+                allow_negative_indices=False)
+
+        def rollout_step(params, scarry, t):
+            """Env step `t` of all slots under `params`: the carry, and
             (the step of the trajectory, what a stateful model counted)."""
-            env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate = scarry
+            (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate,
+             frames) = scarry
             with jax.named_scope("anakin/inference"):
                 rng, akey, ekey = jax.random.split(rng, 3)
                 counted = {}
@@ -198,15 +247,23 @@ class AnakinOptimizer(PolicyOptimizer):
                 ep_len = jnp.where(done, 0, ep_len)
                 if stateful:
                     pstate = (state, donef)
-            out = (obs, action, reward, done) + kept
+            if in_place:
+                with jax.named_scope("anakin/pack"):
+                    frames = write_step(frames, obs, t)
+            out = (None if in_place else obs, action, reward, done) + kept
             return (env_state, next_obs, rng, ep_rew, ep_len, ep_acc,
-                    pstate), (out, counted)
+                    pstate, frames), (out, counted)
 
-        def batch_of(traj, obs, pstate_in):
+        def batch_of(traj, obs, pstate_in, frames):
             """The rollout as the learner's packed fragment batch."""
             obs_t, act_t, rew_t, done_t, *kept = traj
+            if in_place:
+                view = frames.reshape((D, T, n) + row)
+                packed = sb.packed_from_time_major(view)
+            else:
+                packed = em(obs_t)
             batch = {
-                sb.OBS: em(obs_t),
+                sb.OBS: packed,
                 sb.ACTIONS: em(act_t),
                 sb.REWARDS: em(rew_t),
                 sb.DONES: em(done_t).astype(jnp.float32),
@@ -220,6 +277,8 @@ class AnakinOptimizer(PolicyOptimizer):
             else:
                 batch[sb.ACTION_LOGP] = em(kept[0])
                 batch[sb.VF_PREDS] = em(kept[1])
+            if in_place:
+                batch[sb.OBS_TIME_MAJOR] = view
             if self._replays_state:
                 batch[sb.STATE_IN], batch["reset_in"] = pstate_in
             return batch
@@ -256,31 +315,34 @@ class AnakinOptimizer(PolicyOptimizer):
                     for k, v in stats.items()}
 
         # Every op of the program sits under one of the scopes
-        # `anakin/{inference,env_step,loss,update}` (`jax.named_scope`: op
-        # metadata, nothing at run time), so a trace attributes device
-        # time by name; a rollout with its own update loop also has
-        # `anakin/decode` and `anakin/learn` around the two halves. Scopes
-        # nest where a scan is called inside one; an op belongs to the
-        # innermost (last) `anakin/<scope>` of its name.
+        # `anakin/{inference,env_step,pack,loss,update}` (`jax.named_scope`:
+        # op metadata, nothing at run time), so a trace attributes device
+        # time by name; `pack` is what lays the trajectory out for the
+        # learner (the in-place write, `em`, `batch_of`); a rollout with
+        # its own update loop also has `anakin/decode` and `anakin/learn`
+        # around the two halves. Scopes nest where a scan is called inside
+        # one; an op belongs to the innermost (last) `anakin/<scope>` of
+        # its name.
         def one_update(carry, _):
             (params, opt_state, env_state, obs, rng,
-             ep_rew, ep_len, ep_acc, pstate) = carry
+             ep_rew, ep_len, ep_acc, pstate, frames) = carry
             pstate_in = pstate
 
             # The rollout loop's own ops (stacking the trajectory) count
             # as env_step.
             with jax.named_scope("anakin/decode" if stateful
                                  else "anakin/env_step"):
-                (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate), \
-                    (traj, counted) = jax.lax.scan(
-                        lambda c, _: rollout_step(params, c),
+                (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate,
+                 frames), (traj, counted) = jax.lax.scan(
+                        lambda c, t: rollout_step(params, c, t),
                         (env_state, obs, rng, ep_rew, ep_len, ep_acc,
-                         pstate),
-                        None, length=T)
+                         pstate, frames),
+                        jnp.arange(T) if in_place else None, length=T)
+            with jax.named_scope("anakin/pack"):
+                batch = batch_of(traj, obs, pstate_in, frames)
             with jax.named_scope("anakin/loss"):
-                batch = batch_of(traj, obs, pstate_in)
                 rng, lkey = jax.random.split(rng)
-            if num_mb == 1 and self.num_sgd_iter == 1:
+            if one_update_a_rollout:
                 params, opt_state, stats = learn(
                     params, opt_state, batch, lkey)
             else:
@@ -290,7 +352,7 @@ class AnakinOptimizer(PolicyOptimizer):
             # What the rollout's steps counted, as one value a rollout.
             stats = {**stats, **reduce_stats(counted)}
             return (params, opt_state, env_state, obs, rng,
-                    ep_rew, ep_len, ep_acc, pstate), stats
+                    ep_rew, ep_len, ep_acc, pstate, frames), stats
 
         @jax.named_scope("anakin/update")
         def anakin_fn(params, opt_state, env_state, obs, rng,
@@ -298,13 +360,18 @@ class AnakinOptimizer(PolicyOptimizer):
             ep_acc = (jnp.zeros((), jnp.float32),
                       jnp.zeros((), jnp.float32),
                       jnp.zeros((), jnp.float32))
+            # Every slot of the buffer is overwritten each rollout: it is
+            # carried through the updates and zeroed once a call.
+            frames = jax.lax.with_sharding_constraint(
+                jnp.zeros((D, T * n) + row, obs.dtype),
+                policy._bsharded) if in_place else ()
             carry, stats = jax.lax.scan(
                 one_update,
                 (params, opt_state, env_state, obs, rng,
-                 ep_rew, ep_len, ep_acc, pstate),
+                 ep_rew, ep_len, ep_acc, pstate, frames),
                 None, length=M)
             (params, opt_state, env_state, obs, rng,
-             ep_rew, ep_len, ep_acc, pstate) = carry
+             ep_rew, ep_len, ep_acc, pstate, _) = carry
             # One value a call for the M rollouts' scalar stats.
             stats = reduce_stats(stats)
             stats["_ep_reward_sum"] = ep_acc[0]

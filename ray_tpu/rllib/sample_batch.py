@@ -45,9 +45,23 @@ BOOTSTRAP_OBS = "bootstrap_obs"
 # distribution that actually selected the action, so V-trace ratios
 # stay exact; this column only records the lag for accounting.
 POLICY_LAG = "policy_lag"
+# The observations of OBS where a fused rollout wrote them, shape
+# [groups, T, fragments / groups, ...]: one group of fragments a device,
+# time-major inside a group, so entry [g, t, b] is OBS's row
+# (g * fragments / groups + b) * T + t. A second view of the same rows,
+# never a second copy: a loss whose model is row-wise may read this one
+# and put the model's narrow outputs in OBS's order, and the packed OBS
+# is then never laid out.
+OBS_TIME_MAJOR = "obs_time_major"
 
 # Columns whose leading dimension is NOT the per-step row count.
-_NON_ROW_COLUMNS = (SEQ_LENS, BOOTSTRAP_OBS)
+_NON_ROW_COLUMNS = (SEQ_LENS, BOOTSTRAP_OBS, OBS_TIME_MAJOR)
+
+
+def packed_from_time_major(x):
+    """[groups, T, fragments / groups, ...], the order of OBS_TIME_MAJOR,
+    -> packed fragments [fragments * T, ...], the order of OBS."""
+    return x.swapaxes(1, 2).reshape((-1,) + x.shape[3:])
 
 
 class SampleBatch(dict):

@@ -109,6 +109,9 @@ def test_a_while_keeps_its_own_time_and_the_rows_add_up_to_busy(tmp_path):
     # otherwise the innermost of each kind
     ("jit(a)/anakin/env_step/anakin/inference/policy/attention/policy/router/x",
      "fusion", "anakin/inference|policy/router"),
+    # the in-place write of a step's frames, inside the rollout's scan
+    ("jit(a)/anakin/update/while/body/closed_call/anakin/env_step/while/body/"
+     "closed_call/anakin/pack/dynamic_update_slice", "fusion", "anakin/pack"),
     ("jit(s)/sebulba/select/policy/action/conv", "convolution",
      "sebulba/select|policy/action"),
     ("jit(t)/train/loss/train/allreduce/psum", "all-reduce", "train/allreduce"),

@@ -407,6 +407,7 @@ def _delta_and_q8_texts():
 PROGRAM_SCOPES = [
     ("anakin_fn", "anakin/env_step"), ("anakin_fn", "anakin/inference"),
     ("anakin_fn", "anakin/loss"), ("anakin_fn", "anakin/update"),
+    ("anakin_fn", "anakin/pack"),
     ("train_fn", "train/loss"), ("train_fn", "train/update"),
     ("train_fn_q8", "train/allreduce"), ("sgd_fn", "train/loss"),
     ("sgd_fn", "train/update"), ("action_fn", "policy/action"),

@@ -490,6 +490,193 @@ class TestEndToEnd:
 
 
 # ---------------------------------------------------------------------
+# The fused program's packing against stack-then-transpose
+# ---------------------------------------------------------------------
+def _stack_then_em(opt):
+    """The fused program in its plain form, kept here as the reference:
+    every column, frames included, stacked `[T, N, ..]` by the rollout's
+    scan and transposed env-major (`em`), then the optimizer's own
+    `learn`. Feed-forward policies that keep their logits. Returns
+    `run(params, opt_state, env_state, obs, rng)` -> (params, the
+    batches of the call's updates stacked, the call's stats)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    policy, env = opt.policy, opt.env
+    N, T, M = opt.num_envs, opt.T, opt.updates_per_call
+    vstep = jax.vmap(env.step)
+
+    def em(x):
+        return jnp.swapaxes(x, 0, 1).reshape((N * T,) + x.shape[2:])
+
+    def step(params, carry, _):
+        env_state, obs, rng = carry
+        rng, akey, ekey = jax.random.split(rng, 3)
+        dist_inputs, _ = policy.apply(params, obs)
+        action = policy.dist_class(dist_inputs).sample(akey)
+        env_state, next_obs, reward, done = vstep(
+            env_state, action, jax.random.split(ekey, N))
+        return (env_state, next_obs, rng), (
+            obs, action, reward, done, dist_inputs)
+
+    def update(carry, _):
+        params, opt_state, env_state, obs, rng = carry
+        (env_state, obs, rng), traj = jax.lax.scan(
+            functools.partial(step, params), (env_state, obs, rng), None,
+            length=T)
+        obs_t, act_t, rew_t, done_t, logits_t = traj
+        batch = {
+            sb.OBS: em(obs_t), sb.ACTIONS: em(act_t),
+            sb.REWARDS: em(rew_t),
+            sb.DONES: em(done_t).astype(jnp.float32),
+            sb.BOOTSTRAP_OBS: obs, sb.ACTION_DIST_INPUTS: em(logits_t)}
+        rng, lkey = jax.random.split(rng)
+        params, opt_state, stats = opt.learn(params, opt_state, batch, lkey)
+        return (params, opt_state, env_state, obs, rng), (batch, stats)
+
+    @jax.jit
+    def run(params, opt_state, env_state, obs, rng):
+        carry, (batches, stats) = jax.lax.scan(
+            update, (params, opt_state, env_state, obs, rng), None,
+            length=M)
+        return carry[0], batches, {
+            k: jnp.max(v) if k.endswith("_max") else jnp.mean(v)
+            for k, v in stats.items()}
+    return run
+
+
+def _frames_match(batch, T):
+    """The share of a `JaxSyntheticAtari` batch's rows whose frame shows
+    the target their reward was paid against (a bright band of 14 rows
+    names it; a frame of another env slot or step agrees once in six), in
+    `sb.OBS` and, where it is there, in `sb.OBS_TIME_MAJOR` read as its
+    comment says."""
+    import jax.numpy as jnp
+
+    def share(frames):
+        target = jnp.argmax(frames[:, ::14, 0, 0][:, :6] >= 128, axis=1)
+        return jnp.mean((batch[sb.REWARDS] > 0) == (
+            batch[sb.ACTIONS] == target))
+    out = share(batch[sb.OBS])
+    view = batch.get(sb.OBS_TIME_MAJOR)
+    if view is not None:
+        g, t, b = view.shape[:3]
+        assert t == T
+        out = jnp.minimum(out, share(jnp.swapaxes(view, 1, 2).reshape(
+            (g * b * t,) + view.shape[3:])))
+    return out
+
+
+# (env, env slots, steps, learner devices, frames written in place)
+PACK_CASES = {
+    "frames": ("SyntheticAtari-v0", 128, 4, 1, True),
+    # a lane tile part-filled: the frames are stacked like any column
+    "frames_8_envs": ("SyntheticAtari-v0", 8, 4, 1, False),
+    "cartpole": ("CartPole-v0", 8, 4, 1, False),
+    "frames_dp_mesh": ("SyntheticAtari-v0", 256, 2, 2, True),
+}
+
+
+@pytest.mark.parametrize("case", list(PACK_CASES))
+def test_fused_packing_is_stack_then_em(case):
+    """(a) The batch the fused program hands its learner is `em` of the
+    stacked trajectory in every column, the frames bit for bit and
+    `sb.OBS` in the env's dtype, and where the frames were written in
+    place `sb.OBS_TIME_MAJOR` is the same rows as the rollout wrote them. (b) One call leaves the
+    params and stats of the stack-then-`em` form. (c) Over a `dp` mesh
+    the buffer is sharded by env slot: the compiled program moves no
+    observation between devices."""
+    import jax
+    from ray_tpu.rllib.agents.registry import get_trainer_class
+    env, N, T, devices, in_place = PACK_CASES[case]
+    # The frames and the episode clock are integer functions of the seed:
+    # equal to the bit. Logits are float32 of two programs XLA compiled
+    # apart, and a sampled action (so a reward, and CartPole's next
+    # observation) may fall the other way on a tie within their rounding.
+    frames = env == "SyntheticAtari-v0"
+    exact = {sb.OBS, sb.BOOTSTRAP_OBS, sb.DONES} if frames else set()
+    trainer = get_trainer_class("IMPALA")(config=dict(
+        env=env, anakin=True, num_workers=0, num_envs_per_worker=N,
+        rollout_fragment_length=T, train_batch_size=N * T,
+        anakin_updates_per_call=2, num_tpus_for_learner=devices,
+        min_iter_time_s=0, seed=5))
+    try:
+        opt, policy = trainer.optimizer, trainer.get_policy()
+        state = (policy.params, policy.opt_state, opt._env_state, opt._obs,
+                 opt._rng)
+        want_params, want_batches, want_stats = jax.tree.map(
+            np.asarray, _stack_then_em(opt)(*state))
+
+        seen = []
+        loss_fn = policy._loss_fn
+
+        def spy(pol, params, batch, rng, loss_state):
+            """The loss, handing its batch out (one device: a mesh would
+            hand out shards) and saying, over a mesh too, how many rows'
+            frame is the one their reward was paid for."""
+            if devices == 1:
+                jax.debug.callback(lambda b: seen.append(b), batch)
+            loss, stats = loss_fn(pol, params, batch, rng, loss_state)
+            if frames:
+                stats = dict(stats, rows_with_their_frame=_frames_match(
+                    batch, T))
+            return loss, stats
+
+        policy._loss_fn = spy
+        opt._anakin_fn = opt._build_fn()
+        args = state + (opt._ep_rew, opt._ep_len, opt._pstate)
+        if devices > 1:
+            text = opt._anakin_fn.lower(*args).compile().as_text()
+            for moved in ("all-gather", "all-to-all", "collective-permute"):
+                assert moved not in text, moved
+            assert "all-reduce" in text  # the gradients', the only one
+        got_params, *_, got_stats = opt._anakin_fn(*args)
+        jax.block_until_ready(got_params)
+
+        for i, batch in enumerate(seen):
+            view = batch.pop(sb.OBS_TIME_MAJOR, None)
+            assert (view is not None) == in_place
+            assert set(batch) == set(want_batches)
+            for key, column in batch.items():
+                column, want = np.asarray(column), want_batches[key][i]
+                assert column.dtype == want.dtype, key
+                if key in exact:
+                    np.testing.assert_array_equal(column, want, err_msg=key)
+                else:
+                    off = ~np.isclose(column, want, atol=1e-4)
+                    assert off.mean() <= 0.02, (key, off.mean())
+            if in_place:
+                # [1, T, N, ..] here: row n * T + t of OBS at [0, t, n].
+                want = want_batches[sb.OBS][i].reshape(
+                    (N, T) + view.shape[3:]).swapaxes(0, 1)[None]
+                np.testing.assert_array_equal(np.asarray(view), want)
+        assert len(seen) == (2 if devices == 1 else 0)
+        if frames:
+            assert want_batches[sb.OBS].dtype == np.uint8
+
+        # The model saw the same rows in another order and summed their
+        # gradients in that order: equal up to float32's rounding, which
+        # the optimizer's normalised step (about `lr` an element and
+        # update) passes on where a gradient is near zero: a few elements
+        # in a hundred may move the other way (over a mesh, where the
+        # bfloat16 trunk is tiled by another batch), none by more than two
+        # updates can.
+        for key, want in want_stats.items():
+            np.testing.assert_allclose(
+                float(got_stats[key]), want, rtol=1e-3, err_msg=key)
+        if frames:
+            assert float(got_stats["rows_with_their_frame"]) == 1.0
+        lr = trainer.config["lr"]
+        for got, want in zip(jax.tree.leaves(got_params),
+                             jax.tree.leaves(want_params)):
+            off = np.abs(np.asarray(got) - want)
+            assert off.max() <= 2.5 * lr and (off > 0.25 * lr).mean() <= 0.05
+    finally:
+        trainer.stop()
+
+
+# ---------------------------------------------------------------------
 # JaxEnv parity
 # ---------------------------------------------------------------------
 class TestJaxEnvs:
