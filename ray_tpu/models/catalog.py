@@ -51,9 +51,14 @@ def _smallthinker(obs_space, num_outputs, cfg, dtype):
     return smallthinker_from_config(num_outputs, cfg, dtype)
 
 
+def _lfm2_moe(obs_space, num_outputs, cfg, dtype):
+    from .transformer import lfm2_moe_from_config
+    return lfm2_moe_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
 CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite,
-                 "smallthinker": _smallthinker}
+                 "smallthinker": _smallthinker, "lfm2_moe": _lfm2_moe}
 
 
 def _resolve_compute_dtype(cfg):

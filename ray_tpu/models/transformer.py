@@ -1,15 +1,38 @@
-"""Transformer token policies in flax: one decoder, three descriptions.
+"""Transformer token policies in flax: one decoder, four descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
 reads the same final hidden vector.
 
     x = E[tokens]
-    per layer:  h = x + Attention(RMSNorm(x));  x = h + FeedForward(RMSNorm(h))
-    y = RMSNorm(x);  logits = y W_head (untied);  value = y w_v + b
+    per layer:  h = x + Op(RMSNorm(x));  x = h + FeedForward(RMSNorm(h))
+    y = RMSNorm(x);  logits = y W_head (untied), or y E^T (`tie_embeddings`,
+    lfm2_moe: the head IS the embedding, one parameter that both the lookup
+    and the logits differentiate);  value = y w_v + b
 
 It is assembled from parts that the published `config.json` of a family
 names; nothing else chooses between them.
+
+Op, a layer's operator: attention in every layer, or, a layer at a time by
+`layer_types` (LFM2, `model_type: lfm2_moe`: 18 of its 24 layers), the
+gated short convolution. For n = the normalised input [T, H]:
+      [b | c | u] = n W_in          W_in [H, 3 H], no bias, thirds in that
+                                    order
+      g = b * u
+      v_t = sum_{j < L} w[:, j] * g_{t - (L-1) + j}    L = `conv_taps` (3),
+                                    w [H, L] depthwise, no bias; g before
+                                    the episode's first position is 0;
+                                    w[:, L-1] meets the current position
+      out = (c * v) W_out           no activation anywhere in the operator
+  Its whole state is the last L - 1 gated inputs of a row, [B, L - 1, H]
+  in `compute_dtype`, whatever the sequence's length: no positions axis,
+  so a row is reset by zeroing it, not by `pos`. Two forms: over a
+  fragment (`_conv_causal`) L shifted products, a tap that would reach
+  back across a `reset` reading 0, returning the last episode's last
+  L - 1 gated inputs (zeros where the episode is shorter); a step
+  (`_conv_step`) appends g_t, multiplies and drops g_{t-(L-1)}. The taps
+  are multiplied and summed in float32: elementwise work XLA fuses, no
+  kernel of the repo's own.
 
 Attention, one of:
   a head's own keys and values (OLMoE, arXiv:2409.02060, `model_type:
@@ -17,8 +40,11 @@ Attention, one of:
       q, k, v = n W_q, n W_k, n W_v (no bias), `num_heads` query heads and
       `num_kv_heads` key/value heads of `head_dim` (OLMoE: as many, of
       hidden / heads; SmallThinker: 28 over 4 of 128, query head h
-      reading key/value head h // 7); `qk_norm` (OLMoE): q_norm, k_norm,
-      RMSNorm over the whole projection before the split into heads;
+      reading key/value head h // 7; LFM2: 32 over 8 of 64); `qk_norm`
+      (OLMoE): q_norm, k_norm, RMSNorm over the whole projection before
+      the split into heads; `qk_norm: "head"` (LFM2): RMSNorm over EACH
+      head's own `head_dim` values, one weight [head_dim] for all heads,
+      before RoPE;
       causal softmax(q k^T / sqrt(head_dim)) v; W_o. A KIND A LAYER
       (`window_layout`, `rope_layout`; OLMoE: every layer full and rotary;
       SmallThinker: a period of four, the first full and without
@@ -67,9 +93,11 @@ Router, float32, one of:
   softmax (OLMoE, SmallThinker): p = softmax(n W_r); the k largest p;
       weights are those p as they are unless `norm_topk_prob` (over
       their sum: the softmax over the chosen logits alone).
-  sigmoid with a selection bias (`topk_method: noaux_tc`; one group):
+  sigmoid with a selection bias (`topk_method: noaux_tc`; one group;
+  LFM2's `use_expert_bias`):
       s = sigmoid(n W_r); the k largest of s + b choose; weights are s at
-      the chosen experts, without b, over their sum (`norm_topk_prob`),
+      the chosen experts, without b, over their sum (`norm_topk_prob`;
+      plus `topk_eps` where the description divides so: LFM2's 1e-6),
       times `routed_scaling_factor`. b is a constant of the model: no
       gradient, no optimizer state (its balancing update belongs to
       pre-training).
@@ -97,7 +125,9 @@ Departures from the published models: the value head (none has one); no
 auxiliary router loss (the RL objective has no place for it; the
 `expert_load_*` counters show what follows); parameters, router, final norm
 and heads are float32 and the block's activations `compute_dtype`
-(bfloat16: the repo's convention, as the Nature-CNN's trunk). The OLMoE and
+(bfloat16: the repo's convention, as the Nature-CNN's trunk); lfm2_moe's
+tied head is assumed (the catalog's row drops the key; the family's dense
+configs tie), and its selection bias is frozen. The OLMoE and
 glm4_moe_lite descriptions have as many key/value heads as query heads and
 refuse another count (their references have no grouped form; latent
 attention has no key/value heads to group).
@@ -138,7 +168,12 @@ One set of parameters, two forms (the stateful-policy protocol of
   products, the blocks up to the furthest position that the rows of a
   grid step hold. Grouped heads' caches are read whole.
 
-Both return the cache, so a decode can follow a causal pass.
+Both return the state, so a decode can follow a causal pass: {"kv": a
+layer's caches (none for a convolution layer), "pos"}, and where the model
+has convolution layers {"conv": a layer's last gated inputs (none for an
+attention layer)} beside them, a key of its own: every leaf of "kv" has a
+positions axis, no leaf of "conv" has. `JaxPolicy` and the Anakin optimizer
+carry the whole as one pytree.
 """
 
 from __future__ import annotations
@@ -232,6 +267,48 @@ SMALLTHINKER_FIXED = {
     "rope_scaling": None, "tie_word_embeddings": False,
     "model_type": "smallthinker",
 }
+LFM2_MOE_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "num_hidden_layers": "num_layers",
+    "layer_types": "layer_types",
+    "conv_L_cache": "conv_taps",
+    "num_dense_layers": "dense_layers",
+    "intermediate_size": "dense_width",
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "norm_topk_prob": "norm_topk_prob",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "max_position_embeddings": "context_len",
+    "rope_theta": "rope_theta",
+    "norm_eps": "rms_eps",
+    # The deployment's: the share of the experts this chip holds.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+}
+# What LFM2-8B-A1B's published `config.json` says, for the keys a
+# `custom_model_config` leaves out.
+LFM2_MOE_PUBLISHED = {
+    "vocab_size": 65536, "hidden_size": 2048, "num_attention_heads": 32,
+    "num_key_value_heads": 8, "num_hidden_layers": 24,
+    "layer_types": ["conv", "conv", "full_attention", "conv"] * 5 + [
+        "conv", "full_attention", "conv", "conv"],
+    "conv_L_cache": 3, "num_dense_layers": 2, "intermediate_size": 7168,
+    "num_experts": 32, "num_experts_per_tok": 4,
+    "moe_intermediate_size": 1792, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "max_position_embeddings": 128000,
+    "rope_theta": 1000000, "norm_eps": 1e-5,
+}
+LFM2_MOE_FIXED = {
+    "conv_bias": False, "use_expert_bias": True, "rope_scaling": None,
+    "tie_embedding": True, "tie_word_embeddings": True,
+    "model_type": "lfm2_moe",
+}
+# The operators a layer of `layer_types` may name.
+LAYER_TYPES = ("conv", "full_attention")
 # Published keys that must say what the decoder does (a value it has no
 # part for is refused, not ignored).
 GLM4_MOE_LITE_FIXED = {
@@ -276,12 +353,13 @@ def swiglu(n, w_gate, w_up, w_down, act=jax.nn.silu):
     return jnp.dot(act(jnp.dot(n, w_gate)) * jnp.dot(n, w_up), w_down)
 
 
-def route(n, router, k, renormalise, bias=None, scale=1.0):
+def route(n, router, k, renormalise, bias=None, scale=1.0, eps=0.0):
     """Float32 router: (weights [M, k], experts [M, k]) for rows n [M, H].
     Without `bias`: softmax, the k largest. With `bias` [E]: sigmoid
     scores, the k largest of score + bias choose, the weights are the
     scores alone; the bias is a constant here. Weights are divided by
-    their sum where `renormalise`, then times `scale`."""
+    their sum (plus `eps`, where the description has one) where
+    `renormalise`, then times `scale`."""
     with jax.named_scope("policy/router"):
         logits = jnp.dot(n.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
@@ -293,7 +371,8 @@ def route(n, router, k, renormalise, bias=None, scale=1.0):
                 scores + jax.lax.stop_gradient(bias), k)
             top_p = jnp.take_along_axis(scores, top_i, axis=-1)
         if renormalise:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            top_p = top_p / (total + eps if eps else total)
         if scale != 1.0:
             top_p = top_p * scale
     return top_p, top_i
@@ -481,9 +560,11 @@ def causal_fused(T: int, d_qk: int, d_v: int) -> bool:
     (queries, keys) and `d_v` (values) wide can take the fused form of
     `causal_attention`: a function of the static shape alone. Whole tiles
     and at least two of them (one tile is the plain form with a kernel's
-    set-up on top), and widths the MXU takes whole."""
-    return (T % CAUSAL_TILE == 0 and T >= 2 * CAUSAL_TILE
-            and d_qk % 128 == 0 and d_v % 128 == 0)
+    set-up on top), and widths the MXU takes whole, or half of one (64,
+    lfm2_moe's heads: the one narrower width the kernel and its backward
+    were compiled for a v5e and compared with the reference at)."""
+    return (T % CAUSAL_TILE == 0 and T >= 2 * CAUSAL_TILE and all(
+        d % 128 == 0 or d == 64 for d in (d_qk, d_v)))
 
 
 def _causal_plain(q, k, v, episode, scale, window=0):
@@ -705,8 +786,9 @@ ROUTER_BIAS_SCALE = 0.02
 class DecoderLayerParams(nn.Module):
     """One layer's parameters, by the names the equations use: `shapes` is
     ((name, kind, shape), ...), kind one of ones / dense / experts (a
-    leading axis of experts) / bias (a constant of the model, small and
-    seeded, in the "constants" collection: no gradient, no optimizer
+    leading axis of experts) / taps (a depthwise filter [channels, taps],
+    a channel's fan-in its taps) / bias (a constant of the model, small
+    and seeded, in the "constants" collection: no gradient, no optimizer
     state)."""
 
     shapes: tuple
@@ -714,7 +796,8 @@ class DecoderLayerParams(nn.Module):
     def setup(self):
         inits = {"ones": nn.initializers.ones,
                  "dense": nn.initializers.lecun_normal(),
-                 "experts": nn.initializers.lecun_normal(batch_axis=(0,))}
+                 "experts": nn.initializers.lecun_normal(batch_axis=(0,)),
+                 "taps": nn.initializers.lecun_normal(in_axis=1, out_axis=0)}
         tensors = {}
         for name, kind, shape in self.shapes:
             if kind == "bias":
@@ -740,14 +823,17 @@ class TokenDecoder(nn.Module):
     num_layers: int = 16
     # Attention: a head's own keys and values, or latent where
     # `kv_lora_rank`. A head's own: `num_kv_heads` of them (0: as many as
-    # query heads) of `head_dim` (0: hidden_size // num_heads), QK-norm or
-    # none; a kind a layer, by the layer's entry in two layouts (the
-    # leading `num_layers` entries of a longer layout are read; an empty
-    # one: every layer full and rotary): attending within `sliding_window`
+    # query heads) of `head_dim` (0: hidden_size // num_heads), QK-norm (of
+    # the projection, or of a head) or none; a kind a layer, by the
+    # layer's entry in two layouts (the leading `num_layers` entries of a
+    # longer layout are read; an empty one: every layer full and
+    # rotary): attending within `sliding_window`
     # positions or to the whole episode, RoPE or no positions at all.
     num_kv_heads: int = 0
     head_dim: int = 0
-    qk_norm: bool = True
+    # True: over the whole projection; "head": over each head, one weight
+    # [head_dim]; False: none.
+    qk_norm: Any = True
     sliding_window: int = 0
     window_layout: tuple = ()
     rope_layout: tuple = ()
@@ -756,6 +842,11 @@ class TokenDecoder(nn.Module):
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # The operator a layer, where not every layer is an attention: each of
+    # the leading `num_layers` entries one of `LAYER_TYPES`; "conv" is the
+    # gated short convolution of `conv_taps` taps.
+    layer_types: tuple = ()
+    conv_taps: int = 3
     # Feed-forward: `dense_layers` leading dense layers, then experts.
     dense_layers: int = 0
     dense_width: int = 0
@@ -771,10 +862,12 @@ class TokenDecoder(nn.Module):
     selection_bias: bool = False
     router_before_attention: bool = False
     norm_topk_prob: bool = False
+    topk_eps: float = 0.0  # beside the chosen weights' sum, where divided
     routed_scaling_factor: float = 1.0
     # The next-next-token module, its loss's weight in the objective.
     nextn_layers: int = 0
     nextn_loss_weight: float = 0.1
+    tie_embeddings: bool = False  # the head is the embedding, transposed
     context_len: int = 4096
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
@@ -797,23 +890,37 @@ class TokenDecoder(nn.Module):
     def head_width(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
-    def layer_kind(self, i: int) -> tuple:
-        """(the window layer `i` attends within, 0 for the whole episode;
-        whether its queries and keys are rotated)."""
+    def layer_kind(self, i: int):
+        """"conv" where layer `i`'s operator is the short convolution; of
+        an attention layer (the window it attends within, 0 for the whole
+        episode; whether its queries and keys are rotated)."""
+        if self.layer_types and self.layer_types[i] == "conv":
+            return "conv"
         window = bool(self.window_layout) and bool(self.window_layout[i])
         return (self.sliding_window if window else 0,
                 not self.rope_layout or bool(self.rope_layout[i]))
 
+    @property
+    def attention_layers(self) -> tuple:
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) != "conv")
+
     def cache_len(self, i: int) -> int:
         """Positions layer `i`'s cache holds: the context, or a window
-        layer's ring of its window."""
+        layer's ring of its window (an attention layer's)."""
         window = self.layer_kind(i)[0]
         return min(window or self.context_len, self.context_len)
 
-    def _layer_shapes(self, dense: bool) -> tuple:
+    def _layer_shapes(self, dense: bool, conv: bool = False) -> tuple:
+        """`attn_norm` is the norm ahead of the layer's operator, whichever
+        that is."""
         H, heads = self.hidden_size, self.num_heads
         shapes = [("attn_norm", "ones", (H,)), ("mlp_norm", "ones", (H,))]
-        if self.kv_lora_rank:
+        if conv:
+            shapes += [("conv_in", "dense", (H, 3 * H)),
+                       ("conv_w", "taps", (H, self.conv_taps)),
+                       ("conv_out", "dense", (H, H))]
+        elif self.kv_lora_rank:
             rq, rkv = self.q_lora_rank, self.kv_lora_rank
             nope, rot, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                              self.v_head_dim)
@@ -826,7 +933,10 @@ class TokenDecoder(nn.Module):
                 ("wo", "dense", (heads * vd, H))]
         else:
             q, kv = heads * self.head_width, self.kv_heads * self.head_width
-            if self.qk_norm:
+            if self.qk_norm == "head":
+                shapes += [("q_norm", "ones", (self.head_width,)),
+                           ("k_norm", "ones", (self.head_width,))]
+            elif self.qk_norm:
                 shapes += [("q_norm", "ones", (q,)), ("k_norm", "ones", (kv,))]
             shapes += [("wq", "dense", (H, q)), ("wk", "dense", (H, kv)),
                        ("wv", "dense", (H, kv)), ("wo", "dense", (q, H))]
@@ -862,9 +972,15 @@ class TokenDecoder(nn.Module):
                 f"{self.kv_heads} key/value heads' groups")
         self.embed = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab_size, H))
+        for kind in self.layer_types[:self.num_layers]:
+            if kind not in LAYER_TYPES:
+                raise ValueError(
+                    f"layer type {kind!r}: TokenDecoder has {LAYER_TYPES}")
         self.layers = [
-            DecoderLayerParams(self._layer_shapes(i < self.dense_layers),
-                               name=f"layer_{i}")
+            DecoderLayerParams(
+                self._layer_shapes(i < self.dense_layers,
+                                   self.layer_kind(i) == "conv"),
+                name=f"layer_{i}")
             for i in range(self.num_layers)]
         self.nextn = [
             DecoderLayerParams(self._layer_shapes(False) + (
@@ -873,8 +989,14 @@ class TokenDecoder(nn.Module):
                 ("final_norm", "ones", (H,))), name=f"nextn_{i}")
             for i in range(self.nextn_layers)]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (H,))
-        self.head = self.param(
-            "head", nn.initializers.normal(0.01), (H, self.num_outputs))
+        if not self.tie_embeddings:
+            self.head = self.param(
+                "head", nn.initializers.normal(0.01), (H, self.num_outputs))
+        elif self.num_outputs != self.vocab_size or self.nextn_layers:
+            raise ValueError(
+                f"a head tied to the embedding gives {self.vocab_size} "
+                f"logits, not {self.num_outputs}, and no next-next-token "
+                "module reads it")
         self.value_w = self.param(
             "value_w", nn.initializers.normal(0.02), (H,))
         self.value_b = self.param("value_b", nn.initializers.zeros, ())
@@ -885,20 +1007,36 @@ class TokenDecoder(nn.Module):
         head, or the one latent), and each row's count of positions held.
         A layer's caches are as long as what it attends to: the context's
         positions, or a window layer's RING of its window, where position
-        p lies in slot p mod the window."""
+        p lies in slot p mod the window. A convolution layer has no cache
+        (its entry of "kv" is empty); its state, the last `conv_taps` - 1
+        gated inputs of a row, [B, taps - 1, hidden], is its entry of
+        "conv", a key a model without such layers does not have: every
+        leaf of "kv" has a positions axis, no leaf of "conv" has."""
         B = batch_size
 
         def shapes(i):
+            if self.layer_kind(i) == "conv":
+                return ()
             S = self.cache_len(i)
             if self.kv_lora_rank:
                 return ((B, S, self.latent_width),)
             return ((B, S, self.kv_heads, self.head_width),) * 2
-        return {
-            "kv": tuple(tuple(jnp.zeros(s, self.compute_dtype)
-                              for s in shapes(i))
-                        for i in range(self.num_layers)),
-            "pos": jnp.zeros(batch_size, jnp.int32),
-        }
+        return self._policy_state(
+            (tuple(jnp.zeros(s, self.compute_dtype) for s in shapes(i))
+             for i in range(self.num_layers)),
+            (jnp.zeros((B, self.conv_taps - 1, self.hidden_size),
+                       self.compute_dtype)
+             if self.layer_kind(i) == "conv" else ()
+             for i in range(self.num_layers)),
+            jnp.zeros(batch_size, jnp.int32))
+
+    def _policy_state(self, kv, conv, pos) -> dict:
+        """The policy state of a layer's caches, a layer's convolution
+        state and the rows' positions."""
+        state = {"kv": tuple(kv), "pos": pos}
+        if self.layer_types:
+            state["conv"] = tuple(conv)
+        return state
 
     def static_counters(self, batch_size: int, fragment_len: int,
                         platform: str) -> dict:
@@ -910,11 +1048,14 @@ class TokenDecoder(nn.Module):
         is the kernel over a latent cache (1.0) or XLA's products (0.0),
         and with a latent cache its bytes a position. A causal pass over
         fragments of `fragment_len` tokens: whether its attention takes
-        the fused form (1.0) or the plain one (0.0). A model with window layers: how
-        many they are, the query heads a key/value head, the bytes of
-        cache a position of the context that all layers hold together (a
-        ring counts for its own length), and the share of the causal
-        tiles that the fused form visits in a window layer."""
+        the fused form (1.0) or the plain one (0.0). A model with caches of
+        a head's own keys and values: the bytes of cache a position of the
+        context that its attention layers hold together (a ring counts
+        for its own length). A model with window layers: how many they
+        are, the query heads a key/value head, and the share of the
+        causal tiles that the fused form visits in a window layer. A
+        model with convolution layers: how many they are, and the bytes
+        of their state a row, whatever the length."""
         k, E = self.experts_per_token, self.num_experts
         kernel = False
         if self.kv_lora_rank:
@@ -944,20 +1085,28 @@ class TokenDecoder(nn.Module):
             out["latent_cache_bytes_per_token"] = (
                 self.num_layers * self.latent_width
                 * jnp.dtype(self.compute_dtype).itemsize)
-        windows = [i for i in range(self.num_layers) if self.layer_kind(i)[0]]
+        itemsize = jnp.dtype(self.compute_dtype).itemsize
+        attention = self.attention_layers
+        if not self.kv_lora_rank:
+            out["kv_cache_bytes_per_token"] = (
+                2 * self.kv_heads * self.head_width * itemsize
+                * sum(self.cache_len(i) for i in attention)
+                / self.context_len)
+        windows = [i for i in attention if self.layer_kind(i)[0]]
         if windows:
-            position = (2 * self.kv_heads * self.head_width
-                        * jnp.dtype(self.compute_dtype).itemsize)
             kept, causal = causal_window_tiles(
                 fragment_len, self.sliding_window) if out[
                     "causal_attention_fused"] else (1, 1)
             out.update(
                 window_layers=len(windows),
                 kv_groups=self.num_heads // self.kv_heads,
-                kv_cache_bytes_per_token=position * sum(
-                    self.cache_len(i) for i in range(self.num_layers))
-                / self.context_len,
                 causal_window_tiles_kept=kept / causal)
+        if len(attention) < self.num_layers:
+            convs = self.num_layers - len(attention)
+            out.update(
+                conv_layers=convs,
+                conv_state_bytes_per_row=convs * (self.conv_taps - 1)
+                * self.hidden_size * itemsize)
         return out
 
     def __call__(self, obs, state, reset):
@@ -978,10 +1127,15 @@ class TokenDecoder(nn.Module):
 
         def projected(w, norm):
             a = jnp.dot(n, lp[w].astype(cd))
-            return rms_norm(a, lp[norm], eps, cd) if self.qk_norm else a
+            return rms_norm(a, lp[norm], eps, cd) if self.qk_norm is True \
+                else a
         q, k = projected("wq", "q_norm"), projected("wk", "k_norm")
         v = jnp.dot(n, lp["wv"].astype(cd))
-        return q.reshape(heads), k.reshape(groups), v.reshape(groups)
+        q, k = q.reshape(heads), k.reshape(groups)
+        if self.qk_norm == "head":
+            q = rms_norm(q, lp["q_norm"], eps, cd)
+            k = rms_norm(k, lp["k_norm"], eps, cd)
+        return q, k, v.reshape(groups)
 
     def _attention_scope(self, window: int) -> str:
         """The name a layer's own-heads attention has in a trace: by its
@@ -1046,6 +1200,9 @@ class TokenDecoder(nn.Module):
 
                 def projected(w, norm, heads):
                     a = by_head(n, lp[w], heads)
+                    if self.qk_norm == "head":
+                        # Over each head's own values, one weight for all.
+                        return rms_norm(a, lp[norm], eps, cd)
                     if not self.qk_norm:
                         return a
                     # QK-norm over the whole projection: heads and d.
@@ -1146,11 +1303,60 @@ class TokenDecoder(nn.Module):
             h = x + jnp.dot(o, lp["wo"].astype(cd))
         return h, (cache,), read
 
+    # -- the short convolution, both forms ---------------------------------
+    def _conv_gates(self, lp, x):
+        """(g = b * u, c) of rows x [.., H]: [b | c | u] = RMSNorm(x) W_in."""
+        cd = self.compute_dtype
+        n = rms_norm(x, lp["attn_norm"], self.rms_eps, cd)
+        b, c, u = jnp.split(jnp.dot(n, lp["conv_in"].astype(cd)), 3, axis=-1)
+        return b * u, c
+
+    def _conv_causal(self, lp, x, positions):
+        """x + (c * v) W_out over a fragment [B, T, H] from empty states,
+        v_t = sum_j w[:, j] g_{t - (L - 1) + j}, a tap that would reach
+        before its episode's first step (`positions`: a step's place in
+        its episode) reading 0; (h, the state a decode continues from: the
+        last episode's last L - 1 gated inputs [B, L - 1, H], 0 where the
+        episode is shorter). The taps are multiplied and summed in
+        float32: elementwise work beside the two projections."""
+        cd, L = self.compute_dtype, self.conv_taps
+        T = x.shape[1]
+        with jax.named_scope("policy/short_conv"):
+            g, c = self._conv_gates(lp, x)
+            w = lp["conv_w"].astype(jnp.float32)
+            # back[:, L - 1 - s + t] = g_{t - s}, zeros before the fragment.
+            back = jnp.pad(g, ((0, 0), (L - 1, 0), (0, 0)))
+            v = jnp.zeros(g.shape, jnp.float32)
+            for j in range(L):
+                s = L - 1 - j
+                v = v + w[:, j] * jnp.where(
+                    (positions >= s)[..., None], back[:, j:j + T],
+                    0).astype(jnp.float32)
+            h = x + jnp.dot(c * v.astype(cd), lp["conv_out"].astype(cd))
+            # Slot j of the state is g_{T - (L - 1) + j}.
+            held = positions[:, -1:] >= (L - 2 - jnp.arange(L - 1))
+            state = jnp.where(held[..., None], back[:, T:], 0)
+        return h, state
+
+    def _conv_step(self, lp, x, state, reset):
+        """The same of one token a row, x [B, H], against the row's state
+        [B, L - 1, H], zeroed first where `reset`; (h, the state with g_t
+        appended and its oldest entry dropped)."""
+        cd = self.compute_dtype
+        with jax.named_scope("policy/short_conv"):
+            g, c = self._conv_gates(lp, x)
+            state = jnp.where((reset > 0)[:, None, None], 0, state)
+            taps = jnp.concatenate([state, g[:, None]], axis=1)
+            v = jnp.sum(taps.astype(jnp.float32)
+                        * lp["conv_w"].astype(jnp.float32).T, axis=1)
+            h = x + jnp.dot(c * v.astype(cd), lp["conv_out"].astype(cd))
+        return h, taps[:, 1:]
+
     # -- feed-forward -----------------------------------------------------
     def _route(self, lp, n):
         return route(
             n, lp["router"], self.experts_per_token, self.norm_topk_prob,
-            lp.get("router_bias"), self.routed_scaling_factor)
+            lp.get("router_bias"), self.routed_scaling_factor, self.topk_eps)
 
     def _route_ahead(self, lp, x):
         """The routing of rows x [.., H] where the router reads the
@@ -1187,7 +1393,10 @@ class TokenDecoder(nn.Module):
     def _heads(self, x):
         with jax.named_scope("policy/head"):
             y = rms_norm(x, self.final_norm, self.rms_eps, jnp.float32)
-            logits = jnp.dot(y, self.head)
+            if self.tie_embeddings:
+                logits = jnp.einsum("...h,vh->...v", y, self.embed)
+            else:
+                logits = jnp.dot(y, self.head)
             value = jnp.dot(y, self.value_w) + self.value_b
         return logits, value
 
@@ -1199,9 +1408,10 @@ class TokenDecoder(nn.Module):
         group over the layers and the mean group, and where the layer
         holds a share, the share of the (row, expert) pairs that landed
         here; in a decode step the share of the context's positions its
-        attention read, the mean over the layers (`reads`: the positions
-        each layer read), and where the model has window layers the same
-        of its full layers and of its window layers apart."""
+        attention read, the mean over the attention layers (`reads`:
+        {layer: the positions it read}), and where the model has window
+        layers the same of its full layers and of its window layers
+        apart."""
         if self.is_initializing():
             return
         if experts:
@@ -1214,8 +1424,8 @@ class TokenDecoder(nn.Module):
                 pairs = experts[0].size
                 self.sow("counters", "experts_held_row_share",
                          jnp.mean(jnp.sum(loads, axis=-1)) / pairs)
-        window = [bool(self.layer_kind(i)[0])
-                  for i in range(len(reads or ()))]
+        window = [bool(self.layer_kind(i)[0]) for i in reads or ()]
+        reads = list((reads or {}).values())
         if reads and not (any(window) and not all(window)):
             # Layers of one kind read alike.
             self.sow("counters", "decode_cache_read_share",
@@ -1256,10 +1466,15 @@ class TokenDecoder(nn.Module):
             return jnp.clip(start[:, -1:] + held, 0, T - 1)
 
         def block(lp, x, kind=(0, True)):
+            """One layer; `caches` are its caches, or the convolution's
+            state."""
             routing = self._route_ahead(lp, x)
-            rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
-            h, caches = self._attend_causal(
-                lp, x, positions, episode, rows, *kind)
+            if kind == "conv":
+                h, caches = self._conv_causal(lp, x, positions)
+            else:
+                rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
+                h, caches = self._attend_causal(
+                    lp, x, positions, episode, rows, *kind)
             out, group_sizes, top_i = self._feed_forward(
                 lp, h.reshape(B * T, -1), routing)
             return out.reshape(B, T, -1), caches, group_sizes, top_i
@@ -1273,11 +1488,12 @@ class TokenDecoder(nn.Module):
                     CAUSAL_KEPT), static_argnums=(2,))
 
         x = self.embed[tokens].astype(cd)
-        kv, loads, experts = [], [], []
+        kv, conv, loads, experts = [], [], [], []
         for i, layer in enumerate(self.layers):
-            x, caches, group_sizes, top_i = block(
-                layer(), x, self.layer_kind(i))
-            kv.append(caches)
+            kind = self.layer_kind(i)
+            x, caches, group_sizes, top_i = block(layer(), x, kind)
+            kv.append(() if kind == "conv" else caches)
+            conv.append(caches if kind == "conv" else ())
             if top_i is not None:
                 loads.append(group_sizes)
                 experts.append(top_i.reshape(B, T, -1))
@@ -1289,7 +1505,8 @@ class TokenDecoder(nn.Module):
             experts.append(top_i.reshape(B, T, -1))
         self._count(experts, loads)
         logits, value = self._heads(x)
-        return logits, value, {"kv": tuple(kv), "pos": positions[:, -1] + 1}
+        return logits, value, self._policy_state(
+            kv, conv, positions[:, -1] + 1)
 
     def _next_next_token(self, block, x, tokens, episode):
         """The module's loss over a fragment (see the module docstring),
@@ -1330,14 +1547,19 @@ class TokenDecoder(nn.Module):
     def decode(self, token, state, reset):
         pos = jnp.where(reset > 0, 0, state["pos"])
         x = self.embed[token].astype(self.compute_dtype)
-        kv, experts, reads = [], [], []
+        kv, conv, experts, reads = [], [], [], {}
         for i, (layer, caches) in enumerate(zip(self.layers, state["kv"])):
             lp = layer()
+            kind = self.layer_kind(i)
             routing = self._route_ahead(lp, x)
-            h, caches, read = self._attend_step(
-                lp, x, pos, caches, *self.layer_kind(i))
+            if kind == "conv":
+                h, held = self._conv_step(lp, x, state["conv"][i], reset)
+            else:
+                h, caches, reads[i] = self._attend_step(
+                    lp, x, pos, caches, *kind)
+                held = ()
             kv.append(caches)
-            reads.append(read)
+            conv.append(held)
             x, _, top_i = self._feed_forward(lp, h, routing)
             if top_i is not None:
                 experts.append(top_i)
@@ -1346,7 +1568,7 @@ class TokenDecoder(nn.Module):
                 module()
         self._count(experts, reads=reads)
         logits, value = self._heads(x)
-        return logits, value, {"kv": tuple(kv), "pos": pos + 1}
+        return logits, value, self._policy_state(kv, conv, pos + 1)
 
 
 def _refuse_unknown(cfg: dict, known, family: str) -> None:
@@ -1432,6 +1654,36 @@ def smallthinker_from_config(num_outputs: int, cfg: dict,
                 f"entries for {fields['num_layers']} layers")
     fields.update(qk_norm=False, router_before_attention=True,
                   hidden_act="relu")
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def lfm2_moe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks `lfm2_moe`'s
+    published `config.json`'s own keys (a key left out has LFM2-8B-A1B's
+    value), and the two that state the chip's share of the experts;
+    unknown keys are refused, and so is a published key whose value the
+    decoder has no part for. The family's parts: an operator a layer by
+    `layer_types` (whose leading `num_hidden_layers` entries are read),
+    the gated short convolution or grouped-head attention with QK-norm
+    over each head; `num_dense_layers` leading dense layers; sigmoid
+    scores with a selection bias, renormalised over their sum + 1e-6; the
+    head tied to the embedding."""
+    _refuse_unknown(cfg, set(LFM2_MOE_CONFIG_KEYS) | set(LFM2_MOE_FIXED),
+                    "lfm2_moe")
+    _refuse_other_values(cfg, LFM2_MOE_FIXED)
+    fields = {LFM2_MOE_CONFIG_KEYS[k]: v
+              for k, v in {**LFM2_MOE_PUBLISHED, **cfg}.items()
+              if k in LFM2_MOE_CONFIG_KEYS}
+    fields["layer_types"] = tuple(fields["layer_types"])
+    if len(fields["layer_types"]) < fields["num_layers"]:
+        raise ValueError(
+            f"layer_types has {len(fields['layer_types'])} entries for "
+            f"{fields['num_layers']} layers")
+    # The sum of the chosen scores is never 0 in the source's division.
+    fields.update(qk_norm="head", selection_bias=True, tie_embeddings=True,
+                  topk_eps=1e-6)
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=num_outputs, **fields)

@@ -183,7 +183,8 @@ def test_the_limits_refuse_attention_across_an_episode_s_start(interpreted):
     (16, 128, 128, False),     # a rehearsal's or a test's fragment
     (512, 128, 128, False),    # one tile
     (1000, 128, 128, False),   # no whole tiles
-    (1024, 64, 64, False),
+    (1024, 64, 64, True),      # the fourth one's: half a lane tile
+    (1024, 96, 96, False),
     (1536, 128, 256, True),
 ])
 def test_causal_fused_is_a_rule_of_the_static_shape(T, d_qk, d_v, fused):

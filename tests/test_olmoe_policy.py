@@ -423,7 +423,11 @@ def test_the_form_is_chosen_from_the_static_shape():
     assert model.static_counters(128, 1024, "tpu") == {
         "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0,
         "decode_cache_block": transformer.DECODE_CACHE_BLOCK,
-        "decode_attention_kernel": 0.0, "causal_attention_fused": 1.0}
+        "decode_attention_kernel": 0.0, "causal_attention_fused": 1.0,
+        # K and V of 16 heads of 128 in bfloat16, a layer (PR 38: every
+        # model with caches of a head's own says so).
+        "kv_cache_bytes_per_token": 2 * 16 * 128 * 2.0 * net[
+            "num_hidden_layers"]}
     assert model.static_counters(
         2048, 1024, "tpu")["decode_experts_batched"] == 0.0
 
