@@ -31,6 +31,16 @@ divided out of the float32 result (`prefix_kernel`).
 `decode_attention` is the kernel form with the other's derivative: a
 `pallas_call` that prefetches scalars has no JVP, and a decode step is
 differentiated where a learner takes its bootstrap value through one.
+
+Grouped heads whose caches lie position-major, [B, S, groups, d], take the
+same kernel with G = 1 (`grouped_kernel`): a position's cached heads are
+one row of groups * d contiguous lanes, every query head of a row is
+scored against that whole row with zeros outside its own cached head's
+lanes, and one grid step covers a block of positions for all the heads,
+where the head-major form above, grid (rows, G, blocks) with few query
+rows a cached head, paid 2.25 ns a cached position and head whatever the
+bytes (PERF.md section 7, PR 35). `attend_grouped` is its whole-cache form
+and `grouped_decode_attention` the kernel with that form's derivative.
 """
 
 from __future__ import annotations
@@ -52,10 +62,15 @@ from jax.experimental.pallas import tpu as pltpu
 # 0.160 / 0.257. A window held whole streams at 600 GB/s whatever the
 # block; a smaller block reads less of a window that fills from empty
 # (1/2 + block / 2S of it on the mean), and a grid step costs ~0.35 us
-# whether its block is fetched or not.
+# whether its block is fetched or not. Grouped heads' rows, 512 lanes each of
+# K and V (64 rows of 32 query heads over 8 cached ones of 64, 4,096
+# positions; XLA's two products 1.471 / 1.471): 128 x 8 0.436 / 0.756, 128 x
+# 16 0.427 / 0.757 (709 GB/s), 128 x 32 0.423 / 0.759, 256 x 16 0.438 /
+# 0.758, 512 x 16 0.465 / 0.760, 1,024 x 8 0.525 / 0.760: the same choice.
 BLOCK = 128
 ROWS = 16
-# Two buffers of ROWS x BLOCK x 640 lanes x 2 bytes are 5.2 MB, the float32
+# Two buffers of ROWS x BLOCK x 640 lanes x 2 bytes are 5.2 MB (grouped
+# heads' K and V, 2 x 512 lanes: 8.4 MB), the float32
 # scores and probabilities of a step 0.4 MB; the compiler's own limit is
 # 16 MB of the chip's 128, and larger blocks were measured under this one.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
@@ -224,3 +239,72 @@ def _backward(scale, value_dim, kept, g):
 
 
 decode_attention.defvjp(_forward, _backward)
+
+
+# -- grouped heads: caches [B, S, groups, d], position-major ----------------
+def attend_grouped(q, k, v, lengths, scale):
+    """`heads // groups` query heads against each cached head (query head
+    h against cached head h // their number), q [B, heads, d] over k, v
+    [B, S, groups, d]: a group's queries as the rows of one matrix against
+    that head's [S, d] keys, then its weights against the values, a cached
+    row read once for all the queries of its group; every position of the
+    cache is read, by each product."""
+    f32 = jnp.float32
+    B, heads, d = q.shape
+    held = jnp.arange(k.shape[1])[None, :] < lengths[:, None]
+    q = q.reshape(B, k.shape[2], -1, d)
+    # [B, groups, heads a group, S]
+    scores = jnp.einsum("bgrd,bsgd->bgrs", q, k,
+                        preferred_element_type=f32) * scale
+    scores = jnp.where(held[:, None, None, :], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrs,bsgd->bgrd", attn, v,
+                      preferred_element_type=f32).astype(
+                          q.dtype).reshape(B, heads, -1)
+
+
+def grouped_kernel(q, k, v, lengths, scale, **kernel):
+    """`attend_grouped`'s sum by `prefix_kernel`, the caches where they
+    lie: a position's `groups` cached heads are one row of groups * d
+    contiguous lanes, [B, 1, S, groups * d]; the queries are made
+    block-diagonal, [B, 1, heads, groups * d], head h zero outside the d
+    lanes of its cached head, so that one product scores all the heads of
+    a row against a position's whole row (the zeros are `groups` times the
+    owed matrix FLOPs, where the step is bound by the cache's bytes). Of
+    the output's row, head h keeps the d lanes of its own cached head; the
+    others are other heads' values under h's weights: finite, dropped."""
+    B, heads, d = q.shape
+    S, groups = k.shape[1:3]
+    # [1, heads, groups, 1]: whether cached head g is query head h's.
+    own = (jnp.arange(heads)[:, None] // (heads // groups)
+           == jnp.arange(groups)[None, :])[None, :, :, None]
+    q = jnp.where(own, q[:, :, None, :], jnp.zeros((), q.dtype))
+    o = prefix_kernel(
+        q.reshape(B, 1, heads, groups * d), k.reshape(B, 1, S, groups * d),
+        v.reshape(B, 1, S, groups * d), lengths, scale, **kernel)
+    o = o.reshape(B, heads, groups, d)
+    # One term a sum and zeros: exact.
+    return jnp.sum(jnp.where(own, o, jnp.zeros((), o.dtype)), axis=2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_decode_attention(q, k, v, lengths, scale):
+    """`grouped_kernel`, differentiable: the pullback is `attend_grouped`'s
+    (the block-diagonal `whole_window`'s would pay `groups` times the
+    products, over the caches in another view)."""
+    return grouped_kernel(q, k, v, lengths, scale)
+
+
+def _grouped_forward(q, k, v, lengths, scale):
+    return grouped_decode_attention(q, k, v, lengths, scale), (
+        q, k, v, lengths)
+
+
+def _grouped_backward(scale, kept, g):
+    q, k, v, lengths = kept
+    _, pullback = jax.vjp(
+        lambda q, k, v: attend_grouped(q, k, v, lengths, scale), q, k, v)
+    return (*pullback(g), None)
+
+
+grouped_decode_attention.defvjp(_grouped_forward, _grouped_backward)

@@ -162,11 +162,12 @@ One set of parameters, two forms (the stateful-policy protocol of
   [0, n) only, n the furthest position any row of the batch holds, rounded
   up to a block of `DECODE_CACHE_BLOCK` positions and chosen inside the
   step from `pos` (`cached_attention`); each row masks what it does not
-  hold itself. A latent cache is read by a kernel of the repo's own
+  hold itself. A latent cache, and grouped heads' caches (stored flat, a
+  position's cached heads one row), are read by a kernel of the repo's own
   (`models/decode_attention.py`) where the program is lowered for a TPU
-  and the window is whole blocks: each block of latent rows once for both
+  and the window is whole blocks: each block of rows once for both
   products, the blocks up to the furthest position that the rows of a
-  grid step hold. Grouped heads' caches are read whole.
+  grid step hold.
 
 Both return the state, so a decode can follow a causal pass: {"kv": a
 layer's caches (none for a convolution layer), "pos"}, and where the model
@@ -393,16 +394,16 @@ GROUPED_ROW_COST = 1.7
 
 # Positions in a block of the caches of a head's own keys and values that
 # a decode step's attention reads (see `cached_attention`; a latent cache's
-# block is the kernel's, `decode_attention.BLOCK`, and grouped heads' caches
-# have none). A window that fills from empty is read `1/2 + b/(2S)`
-# of, so a smaller block reads less; every block of the window is one more
-# branch of the step's `switch`, traced wherever a decode step is (the
-# rollout, and the learner's bootstrap step under `value_and_grad`) on
-# every start, compile cache or not. On a v5e at OLMoE's published widths,
-# 128 rows, a window of 1,024 (PERF.md section 5), a decode step / the token
-# cell's warm set-up once the chip is open: the window read whole 3.11 ms
-# / 22.5 s; blocks of 512 2.64 ms; of 256 2.44 ms / 23.1 s; of 128 2.35 ms
-# / 25.1 s; of 64 2.32 ms.
+# and grouped heads' caches' block is the kernel's, `decode_attention.BLOCK`,
+# and where they take no kernel they have none). A window that fills from
+# empty is read `1/2 + b/(2S)` of, so a smaller block reads less; every
+# block of the window is one more branch of the step's `switch`, traced
+# wherever a decode step is (the rollout, and the learner's bootstrap step
+# under `value_and_grad`) on every start, compile cache or not. On a v5e at
+# OLMoE's published widths, 128 rows, a window of 1,024 (PERF.md section 5),
+# a decode step / the token cell's warm set-up once the chip is open: the
+# window read whole 3.11 ms / 22.5 s; blocks of 512 2.64 ms; of 256 2.44 ms
+# / 23.1 s; of 128 2.35 ms / 25.1 s; of 64 2.32 ms.
 DECODE_CACHE_BLOCK = 256
 
 
@@ -455,29 +456,43 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     read are those blocks', the mean over the rows. The two products over
     the whole window anywhere else (and under a gradient: the kernel form
     carries their derivative). Grouped heads are matrix products too, a
-    group's queries against its cached head, and take one whole-cache
-    branch, measured at 28 query heads over 4 cached ones of 128, 16 rows
-    (a v5e, PERF.md section 5): a ring of 4,096 read whole 0.21 ms a step
-    (79 % of the HBM's bandwidth), a prefix of three quarters of it 0.55; a
-    cache of 8,192 whole 0.40, a prefix of five eighths 1.08; the same
-    sums as a multiply-and-sum, which does seven products and a
-    cross-lane sum an element, 1.5 and 3.2 whole. The kernel would read a
-    grouped prefix where it lies if the caches were head-major,
-    [B, groups, S, d] (ROADMAP R-A9)."""
+    group's queries against its cached head, and take no `switch` either
+    (a prefix taken in a branch is copied first and costs more than the
+    whole cache; as a multiply-and-sum they are seven products and a
+    cross-lane sum an element, bound by the vector unit: PERF.md section
+    5). They take the same kernel by the same kind of choice,
+    `grouped_fused` of the static shape and the platform: a position's
+    cached heads are one row of groups * d contiguous lanes (8 x 64 = 4 x
+    128 = 512, the width the kernel streams best), so the caches are
+    viewed [B, 1, S, groups * d], the queries made block-diagonal (head h
+    zero outside its cached head's d lanes), and one product scores all
+    the heads of a row against a position's whole row; of the output's row
+    head h keeps its own d lanes (`decode_attention.grouped_kernel`). The
+    lengths are min(pos + 1, S): a ring's slots fill upward from 0. Its
+    derivative is the two products' over the caches by head. The view is a
+    bitcast only of a cache that is STORED flat (`TokenDecoder
+    .initial_state`), which is why it is. Measured alone, in a scan that
+    carries the caches and writes a row a step (a v5e, PERF.md section 5;
+    ms a step, the cache filling from empty / held whole): 64 rows, 32
+    query heads over 8 cached ones of 64, 4,096 positions: XLA's two
+    products over [B, S, 8, 64] 1.471 / 1.471 (each cached row half a lane
+    tile), the kernel 0.427 / 0.757 (709 GB/s of 819); 16 rows, 28 over 4
+    of 128, a cache of 8,192: 0.387 against 0.195 / 0.367; a ring of
+    4,096: 0.205 against 0.105 / 0.190. Everywhere else the two products
+    over the whole cache (`decode_attention.attend_grouped`)."""
     S = k_cache.shape[1]
     f32 = jnp.float32
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if v_cache is None:
         return _latent_attention(q, k_cache, pos, scale, value_dim)
-    grouped = k_cache.shape[2] != q.shape[1]
-    size = S if grouped else DECODE_CACHE_BLOCK
+    if k_cache.shape[2] != q.shape[1]:
+        return _grouped_attention(q, k_cache, v_cache, pos, scale)
+    size = DECODE_CACHE_BLOCK
     ends = tuple(range(size, S, size)) + (S,)
 
     def attend(n, q, k_cache, v_cache, pos):
         held = jnp.arange(n)[None, :] <= pos[:, None]
-        if grouped:
-            return attend_grouped(q, k_cache[:, :n], v_cache[:, :n], held)
         k, v = k_cache[:, :n].astype(f32), v_cache[:, :n].astype(f32)
         # [B, n, heads]
         scores = jnp.sum(q[:, None].astype(f32) * k, axis=-1) * scale
@@ -486,27 +501,47 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
         return jnp.sum(attn[..., None].astype(f32) * v,
                        axis=1).astype(q.dtype)
 
-    def attend_grouped(q, k, v, held):
-        """`heads // groups` query heads against each cached head (query
-        head h against cached head h // their number): a group's queries
-        as the rows of one matrix against that head's [n, d] keys, then
-        its weights against the values, a cached row read once for all
-        the queries of its group."""
-        B, heads, d = q.shape
-        q = q.reshape(B, k.shape[2], -1, d)
-        # [B, groups, heads a group, n]
-        scores = jnp.einsum("bgrd,bsgd->bgrs", q, k,
-                            preferred_element_type=f32) * scale
-        scores = jnp.where(held[:, None, None, :], scores, -jnp.inf)
-        attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bgrs,bsgd->bgrd", attn, v,
-                          preferred_element_type=f32).astype(
-                              q.dtype).reshape(B, heads, -1)
-
     block = jnp.minimum(jnp.max(pos) // size, len(ends) - 1)
     o = jax.lax.switch(block, [functools.partial(attend, n) for n in ends],
                        q, k_cache, v_cache, pos)
     return o, jnp.asarray(ends)[block]
+
+
+def grouped_fused(S: int, groups: int, heads: int, d: int) -> bool:
+    """Whether a decode step of `heads` query heads a row over `groups`
+    cached heads `d` wide, `S` positions of them, can take the kernel form
+    of `cached_attention`: a function of the static shape alone. Whole
+    blocks and at least two of them; a position's cached heads whole lane
+    tiles together, each at least half a tile (the widths the kernel was
+    compiled and measured at: 8 x 64 and 4 x 128); whole groups of query
+    heads. No cache's length is left out: a ring of 4,096 held whole, the
+    shape nearest to losing, read 0.190 ms a step against XLA's 0.205
+    (`cached_attention` has the sweep)."""
+    block = decode_attention.BLOCK
+    return (S % block == 0 and S >= 2 * block and heads % groups == 0
+            and (groups * d) % 128 == 0 and d % 64 == 0)
+
+
+def _grouped_attention(q, k_cache, v_cache, pos, scale):
+    """`cached_attention` of grouped heads' caches: (o, the positions
+    read)."""
+    S, groups = k_cache.shape[1:3]
+    # Slots fill upward from 0, so a ring is a prefix until its position
+    # passes its length, and holds every slot from then on.
+    lengths = jnp.minimum(pos + 1, S)
+
+    def whole(q, k_cache, v_cache, lengths):
+        return decode_attention.attend_grouped(
+            q, k_cache, v_cache, lengths, scale), jnp.asarray(S, jnp.float32)
+
+    def kernel(q, k_cache, v_cache, lengths):
+        return decode_attention.grouped_decode_attention(
+            q, k_cache, v_cache, lengths,
+            scale), decode_attention.positions_fetched(lengths)
+    if grouped_fused(S, groups, q.shape[1], q.shape[2]):
+        return jax.lax.platform_dependent(
+            q, k_cache, v_cache, lengths, tpu=kernel, default=whole)
+    return whole(q, k_cache, v_cache, lengths)
 
 
 def decode_fused(S: int, R: int, d_qk: int, value_dim: int) -> bool:
@@ -1011,7 +1046,12 @@ class TokenDecoder(nn.Module):
         (its entry of "kv" is empty); its state, the last `conv_taps` - 1
         gated inputs of a row, [B, taps - 1, hidden], is its entry of
         "conv", a key a model without such layers does not have: every
-        leaf of "kv" has a positions axis, no leaf of "conv" has."""
+        leaf of "kv" has a positions axis, no leaf of "conv" has. Grouped
+        heads' caches are stored flat, [B, S, groups * d], a position's
+        cached heads one row of contiguous lanes: what the decode's kernel
+        reads as it lies (`cached_attention`; as [B, S, groups, d] XLA:TPU
+        tiles the last two axes, pads heads of 64 to whole lane tiles and
+        copies the cache to the kernel's view every step)."""
         B = batch_size
 
         def shapes(i):
@@ -1020,6 +1060,8 @@ class TokenDecoder(nn.Module):
             S = self.cache_len(i)
             if self.kv_lora_rank:
                 return ((B, S, self.latent_width),)
+            if self.kv_heads != self.num_heads:
+                return ((B, S, self.kv_heads * self.head_width),) * 2
             return ((B, S, self.kv_heads, self.head_width),) * 2
         return self._policy_state(
             (tuple(jnp.zeros(s, self.compute_dtype) for s in shapes(i))
@@ -1045,8 +1087,9 @@ class TokenDecoder(nn.Module):
         rows a held expert group holds, whether the experts multiply in
         the batched form (1.0) or the grouped one (0.0), the positions in
         a block of the caches its attention reads, whether that attention
-        is the kernel over a latent cache (1.0) or XLA's products (0.0),
-        and with a latent cache its bytes a position. A causal pass over
+        is the kernel (1.0: over a latent cache, or over the grouped caches
+        of every attention layer) or XLA's products (0.0), and with a
+        latent cache its bytes a position. A causal pass over
         fragments of `fragment_len` tokens: whether its attention takes
         the fused form (1.0) or the plain one (0.0). A model with caches of
         a head's own keys and values: the bytes of cache a position of the
@@ -1066,6 +1109,11 @@ class TokenDecoder(nn.Module):
                 self.kv_lora_rank)
         else:
             widths = (self.head_width,) * 2
+            kernel = (platform == "tpu"
+                      and self.kv_heads != self.num_heads and all(
+                          grouped_fused(self.cache_len(i), self.kv_heads,
+                                        self.num_heads, self.head_width)
+                          for i in self.attention_layers))
         if kernel:
             block = decode_attention.BLOCK
         elif self.kv_lora_rank or self.kv_heads != self.num_heads:
@@ -1223,6 +1271,9 @@ class TokenDecoder(nn.Module):
                     jnp.take_along_axis(jnp.swapaxes(a, 1, 2),
                                         cache_rows[:, :, None, None], axis=1)
                     for a in (k, v))
+                if groups != heads:
+                    caches = tuple(a.reshape(a.shape[:2] + (-1,))
+                                   for a in caches)
             return h, caches
         # Latent attention, decompressed: keys and values of every head
         # are made from the latents for every position, the one rotary
@@ -1274,9 +1325,16 @@ class TokenDecoder(nn.Module):
                     q = rope(q, pos, self.rope_theta)
                     k = rope(k, pos, self.rope_theta)
                 slot = pos % k_cache.shape[1] if window else pos
-                k_cache = k_cache.at[rows, slot].set(k)
-                v_cache = v_cache.at[rows, slot].set(v)
-                o, read = cached_attention(q, k_cache, v_cache, pos)
+                # Grouped heads' caches are stored flat: a position's row
+                # is written whole, and read through its view by head.
+                k_cache = k_cache.at[rows, slot].set(
+                    k.reshape(k_cache.shape[:1] + k_cache.shape[2:]))
+                v_cache = v_cache.at[rows, slot].set(
+                    v.reshape(v_cache.shape[:1] + v_cache.shape[2:]))
+                by_head = k_cache.shape[:2] + k.shape[1:]
+                o, read = cached_attention(
+                    q, k_cache.reshape(by_head), v_cache.reshape(by_head),
+                    pos)
                 h = x + jnp.dot(o.reshape(B, -1), lp["wo"].astype(cd))
             return h, (k_cache, v_cache), read
         # Latent attention, absorbed: W_UK goes into the query and W_UV

@@ -166,3 +166,30 @@ def ray_local():
     ray_tpu.init(local_mode=True)
     yield ray_tpu
     ray_tpu.shutdown()
+
+
+# Positions in a block of the decode attention's kernel under `kernel_here`.
+KERNEL_BLOCK = 8
+
+
+@pytest.fixture
+def kernel_here(monkeypatch):
+    """A program lowered for this CPU takes the branch a TPU's would, the
+    decode attention's kernel (`models/decode_attention.py`) run by the
+    Pallas interpreter over blocks of `KERNEL_BLOCK` positions, two rows a
+    grid step; both rules take a test's widths."""
+    import functools
+
+    from ray_tpu.models import decode_attention, transformer
+
+    def whole_blocks(S, *widths):
+        return S % KERNEL_BLOCK == 0 and S >= 2 * KERNEL_BLOCK
+    monkeypatch.setattr(decode_attention, "BLOCK", KERNEL_BLOCK)
+    monkeypatch.setattr(decode_attention, "ROWS", 2)
+    monkeypatch.setattr(decode_attention, "prefix_kernel", functools.partial(
+        decode_attention.prefix_kernel, interpret=True))
+    monkeypatch.setattr(transformer, "decode_fused", whole_blocks)
+    monkeypatch.setattr(transformer, "grouped_fused", whole_blocks)
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args))

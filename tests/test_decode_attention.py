@@ -1,10 +1,13 @@
-"""A decode step's attention over a latent cache
+"""A decode step's attention over a latent cache or grouped heads' caches
 (`models/decode_attention.py`, `transformer.cached_attention`): the kernel,
 run by the Pallas interpreter on the CPU, against the two products over the
 whole window, over rows whose lengths differ and caches of both kinds; what
-lies beyond a row's length; the rule that chooses the form and the platform
-it follows; gradients through the kernel form; a whole `TokenDecoder`
+lies beyond a row's length; grouped heads as block-diagonal queries against
+a position's whole row; the rules that choose the form and the platform
+they follow; gradients through the kernel forms; a whole `TokenDecoder`
 decode through it against the causal pass; and what the counters say.
+(`kernel_here` is `conftest.py`'s: the grouped models' test files use it
+too.)
 """
 
 import functools
@@ -24,10 +27,11 @@ if BENCH not in sys.path:
 from lib import reference_glm4_moe_lite as reference  # noqa: E402
 
 from ray_tpu.models import catalog, decode_attention, transformer  # noqa: E402
-from ray_tpu.models.transformer import decode_fused  # noqa: E402
+from ray_tpu.models.transformer import decode_fused, grouped_fused  # noqa: E402
 from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
 
-BLOCK = 8
+from conftest import KERNEL_BLOCK as BLOCK  # noqa: E402
+
 WINDOW = 4 * BLOCK
 # (cached heads, query heads a cached one, d_qk, d_v, values cached apart):
 # the second token cell's latent rows, a rehearsal's, and grouped heads
@@ -143,6 +147,69 @@ def test_whole_blocks_and_whole_steps_or_an_error():
             interpret=True)
 
 
+# -- grouped heads: block-diagonal queries against a position's whole row ----
+# (cached heads, query heads a cached one, d): the fourth token cell's heads
+# and the third's.
+GROUPED = {"8x4_64": (8, 4, 64), "4x7_128": (4, 7, 128)}
+
+
+def grouped_operands(layout, dtype, key=3):
+    G, R, d = GROUPED[layout]
+    dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    B = len(LENGTHS)
+    keys = jax.random.split(jax.random.PRNGKey(key), 3)
+    q = jax.random.normal(keys[0], (B, G * R, d), dtype)
+    k = jax.random.normal(keys[1], (B, WINDOW, G, d), dtype)
+    v = jax.random.normal(keys[2], (B, WINDOW, G, d), dtype)
+    return q, k, v, jnp.asarray(LENGTHS, jnp.int32), d ** -0.5
+
+
+def grouped(q, k, v, lengths, scale, rows=2):
+    return decode_attention.grouped_kernel(
+        q, k, v, lengths, scale, block=BLOCK, rows=rows, interpret=True)
+
+
+@pytest.mark.parametrize("rows", [1, 2, 8])
+@pytest.mark.parametrize("dtype", LIMITS)
+@pytest.mark.parametrize("layout", GROUPED)
+def test_block_diagonal_form_is_attend_grouped(layout, dtype, rows):
+    """Rows at unequal lengths, one of a single position, one that holds
+    the window whole."""
+    args = grouped_operands(layout, dtype)
+    got = grouped(*args, rows=rows)
+    want = decode_attention.attend_grouped(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.max(np.abs(f32(got) - f32(want))) <= LIMITS[dtype]
+    # A row of one position attends to it alone: each head reads the
+    # values of its own cached head.
+    q, k, v, *_ = args
+    G, R, d = GROUPED[layout]
+    np.testing.assert_allclose(
+        f32(got[0]).reshape(G, R, d),
+        np.broadcast_to(f32(v[0, 0])[:, None], (G, R, d)),
+        atol=LIMITS[dtype])
+
+
+@pytest.mark.parametrize("layout", GROUPED)
+def test_lanes_of_other_groups_never_leak(layout):
+    """Every other cached head's values poisoned: a group's heads read
+    what they read of clean ones; and NaN beyond a row's length, in keys
+    and values alike, changes nothing."""
+    q, k, v, lengths, scale = grouped_operands(layout, "bf16")
+    G, R, d = GROUPED[layout]
+    clean = f32(grouped(q, k, v, lengths, scale)).reshape(-1, G, R, d)
+    for g in range(G):
+        others = (jnp.arange(G) != g)[None, None, :, None]
+        got = f32(grouped(q, k, jnp.where(others, jnp.nan, v), lengths,
+                          scale)).reshape(-1, G, R, d)
+        np.testing.assert_array_equal(got[:, g], clean[:, g])
+    beyond = (jnp.arange(WINDOW)[None, :] >= lengths[:, None])[
+        :, :, None, None]
+    got = grouped(q, jnp.where(beyond, jnp.nan, k),
+                  jnp.where(beyond, jnp.nan, v), lengths, scale)
+    np.testing.assert_array_equal(f32(got).reshape(clean.shape), clean)
+
+
 # -- the rule ----------------------------------------------------------------
 @pytest.mark.parametrize("S,R,d_qk,value_dim,fused", [
     (1024, 20, 576, 512, True),    # the second token cell's decode step
@@ -160,6 +227,27 @@ def test_decode_fused_is_a_rule_of_the_static_shape(S, R, d_qk, value_dim,
                                                     fused):
     assert decode_attention.BLOCK == 128
     assert decode_fused(S, R, d_qk, value_dim) == fused
+
+
+@pytest.mark.parametrize("S,groups,heads,d,fused", [
+    (4096, 8, 32, 64, True),     # the fourth token cell's one cache
+    (8192, 4, 28, 128, True),    # the third's full cache
+    (4096, 4, 28, 128, True),    # and its rings
+    (24, 2, 8, 16, False),       # a rehearsal's, and a test's
+    (128, 8, 32, 64, False),     # one block
+    (256, 8, 32, 64, True),      # two
+    (4000, 8, 32, 64, False),    # no whole blocks
+    (4096, 3, 24, 64, False),    # a position's heads no whole lane tiles
+    (4096, 16, 32, 32, False),   # heads under half a tile
+    (4096, 4, 8, 96, False),     # heads of no whole half tiles
+    (4096, 8, 30, 64, False),    # no whole groups of query heads
+    (4096, 2, 64, 64, True),     # one lane tile a position
+    (32768, 8, 64, 128, True),
+])
+def test_grouped_fused_is_a_rule_of_the_static_shape(S, groups, heads, d,
+                                                     fused):
+    assert decode_attention.BLOCK == 128
+    assert grouped_fused(S, groups, heads, d) == fused
 
 
 @pytest.mark.parametrize("platform,S,kernel_there", [
@@ -181,33 +269,23 @@ def test_the_form_follows_the_platform_the_program_is_lowered_for(
 
 @pytest.mark.parametrize("kind", ["heads_of_their_own", "grouped"])
 def test_the_other_kinds_of_cache_take_no_kernel(kind):
+    """A head's own keys and values take the `switch`; grouped heads took
+    no kernel until PR 39 and take it since, lowered for a TPU and nowhere
+    else, with no product against the caches left."""
     heads, groups = (16, 16) if kind == "heads_of_their_own" else (28, 4)
     q = jax.ShapeDtypeStruct((8, heads, 128), jnp.bfloat16)
     cache = jax.ShapeDtypeStruct((8, 1024, groups, 128), jnp.bfloat16)
     pos = jax.ShapeDtypeStruct((8,), jnp.int32)
-    lowered = jax.jit(transformer.cached_attention).trace(
-        q, cache, cache, pos).lower(lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" not in lowered
+    traced = jax.jit(transformer.cached_attention).trace(q, cache, cache, pos)
+    lowered = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert ("tpu_custom_call" in lowered) == (kind == "grouped")
+    if kind == "grouped":
+        assert "dot_general" not in lowered
+        off = traced.lower(lowering_platforms=("cpu",)).as_text()
+        assert "tpu_custom_call" not in off and "dot_general" in off
 
 
 # -- through `cached_attention`, on this CPU ----------------------------------
-@pytest.fixture
-def kernel_here(monkeypatch):
-    """A program lowered for this CPU takes the branch a TPU's would, its
-    kernel run by the Pallas interpreter over blocks of `BLOCK`, two rows
-    a grid step; the rule takes a test's widths."""
-    monkeypatch.setattr(decode_attention, "BLOCK", BLOCK)
-    monkeypatch.setattr(decode_attention, "ROWS", 2)
-    monkeypatch.setattr(decode_attention, "prefix_kernel", functools.partial(
-        decode_attention.prefix_kernel, interpret=True))
-    monkeypatch.setattr(
-        transformer, "decode_fused",
-        lambda S, R, d_qk, value_dim: S % BLOCK == 0 and S >= 2 * BLOCK)
-    monkeypatch.setattr(
-        jax.lax, "platform_dependent",
-        lambda *args, tpu, default: tpu(*args))
-
-
 def latent(dtype, B=4):
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(keys[0], (B, 4, 24), dtype)
@@ -254,6 +332,60 @@ def test_gradients_through_the_kernel_form_are_the_plain_form_s(
         np.testing.assert_array_equal(got, want)
     # Nothing flows to what a row does not hold.
     assert not fused[2][0, 1:].any() and fused[2][0, 0].any()
+
+
+def grouped_caches(dtype, layout="8x4_64", B=4):
+    """Caches [B, S, G, d] of a ring of `WINDOW` slots: a row at its first
+    position, one at a block's edge, one at the ring's last slot, and one
+    whose position has passed the ring's length and holds every slot."""
+    G, R, d = GROUPED[layout]
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = jax.random.normal(keys[0], (B, G * R, d), dtype)
+    k = jax.random.normal(keys[1], (B, WINDOW, G, d), dtype)
+    v = jax.random.normal(keys[2], (B, WINDOW, G, d), dtype)
+    weight = jax.random.normal(keys[3], (B, G * R, d), jnp.float32)
+    pos = jnp.asarray([0, BLOCK, WINDOW - 1, 3 * WINDOW + 5][:B], jnp.int32)
+    return q, k, v, pos, weight
+
+
+@pytest.mark.parametrize("layout", GROUPED)
+def test_cached_attention_reads_a_grouped_ring_s_blocks_held(
+        layout, kernel_here, monkeypatch):
+    q, k, v, pos, _ = grouped_caches(jnp.float32, layout)
+    got, read = transformer.cached_attention(q, k, v, pos)
+    # Rows two a step: blocks [0, 1] and [3, 3 (every slot)] the last held.
+    assert float(read) == (2 + 4) / 2 * BLOCK
+    monkeypatch.undo()
+    want, whole = transformer.cached_attention(q, k, v, pos)
+    assert float(whole) == WINDOW
+    np.testing.assert_allclose(f32(got), f32(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_gradients_through_the_grouped_kernel_form_are_attend_grouped_s(
+        dtype, kernel_here, monkeypatch):
+    """The kernel's output with the pullback of the two products over the
+    caches by head, not of the block-diagonal ones."""
+    q, k, v, pos, weight = grouped_caches(
+        {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype])
+
+    def run():
+        def loss(q, k, v):
+            out, _ = transformer.cached_attention(q, k, v, pos)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return [f32(a) for a in (out,) + grads]
+    fused = run()
+    monkeypatch.undo()
+    plain = run()
+    assert np.max(np.abs(fused[0] - plain[0])) <= LIMITS[dtype]
+    for got, want in zip(fused[1:], plain[1:]):
+        np.testing.assert_array_equal(got, want)
+    # Nothing flows to the values a row does not hold; every slot of a
+    # ring that has turned is held.
+    assert not fused[3][0, 1:].any() and fused[3][0, 0].any()
+    assert fused[3][3].any(axis=(1, 2)).all()
 
 
 # -- a whole decode ------------------------------------------------------------
@@ -371,20 +503,72 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def shaped(sharding, *shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
 @pytest.mark.parametrize("rows", [128, 8])
 def test_the_kernel_compiles_for_a_v5e_at_the_cell_s_widths(rows, one_chip):
     """Mosaic takes the contraction of 576, the value slice of 512 and the
     block as they stand (the rollout's 128 rows, the bootstrap step's 8),
     and the cache enters as it lies: no copy of the window."""
-    def shaped(*shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     compiled = jax.jit(functools.partial(
         transformer.cached_attention, v_cache=None, scale=576 ** -0.5,
         value_dim=512)).trace(
-            shaped(rows, 20, 576), shaped(rows, 1024, 576),
-            pos=shaped(rows, dtype=jnp.int32)).lower(
+            shaped(one_chip, rows, 20, 576), shaped(one_chip, rows, 1024, 576),
+            pos=shaped(one_chip, rows, dtype=jnp.int32)).lower(
                 lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in compiled
     assert not [line for line in compiled.splitlines()
                 if " copy(" in line and f"bf16[{rows},1,1024,576]" in
                 line.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("rows,heads,groups,d,S", [
+    (64, 32, 8, 64, 4096),     # the fourth token cell's rollout
+    (2, 32, 8, 64, 4096),      # and its bootstrap step
+    (16, 28, 4, 128, 8192),    # the third's full cache
+    (16, 28, 4, 128, 4096),    # its rings
+    (1, 28, 4, 128, 8192),     # its bootstrap step
+])
+def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
+        rows, heads, groups, d, S, one_chip):
+    """Mosaic takes 32 and 28 query rows against rows of 512 lanes, and a
+    cache stored flat enters as it lies: its view by head and the kernel's
+    view of that are bitcasts, no copy of the cache."""
+    def step(q, k_cache, v_cache, pos):
+        by_head = (rows, S, groups, d)
+        return transformer.cached_attention(
+            q, k_cache.reshape(by_head), v_cache.reshape(by_head), pos)
+    flat = shaped(one_chip, rows, S, groups * d)
+    compiled = jax.jit(step).trace(
+        shaped(one_chip, rows, heads, d), flat, flat,
+        shaped(one_chip, rows, dtype=jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in compiled
+    assert not cache_copies(compiled, rows, S)
+
+
+def cache_copies(compiled, rows, S):
+    """The lines of a compiled program that lay a [rows, S, ..] or
+    [rows, 1, S, ..] bfloat16 array out anew."""
+    def result(line):
+        return line.split(" = ", 1)[1][:60] if " = " in line else ""
+    return [line for line in compiled.splitlines()
+            if any(f" {op}(" in line for op in (
+                "copy", "copy-start", "reshape", "transpose"))
+            and any(shape in result(line) for shape in (
+                f"bf16[{rows},{S},", f"bf16[{rows},1,{S},"))]
+
+
+def test_a_cache_stored_by_head_would_be_copied_every_step(one_chip):
+    """Why grouped caches are stored flat: [B, S, 8, 64] is tiled over its
+    last two axes, and the kernel's view of it is another layout."""
+    rows, heads, groups, d, S = 64, 32, 8, 64, 4096
+    by_head = shaped(one_chip, rows, S, groups, d)
+    compiled = jax.jit(transformer.cached_attention).trace(
+        shaped(one_chip, rows, heads, d), by_head, by_head,
+        shaped(one_chip, rows, dtype=jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "tpu_custom_call" in compiled
+    assert cache_copies(compiled, rows, S)

@@ -45,7 +45,8 @@ NET = dict(vocab_size=96, hidden_size=64, num_attention_heads=8,
            moe_intermediate_size=32, norm_topk_prob=True,
            routed_scaling_factor=1, use_expert_bias=True,
            max_position_embeddings=S, rope_theta=1e6, norm_eps=1e-5)
-CONV_STATE, CACHE = (2, 64), (S, 2, 8)
+# Grouped heads' caches are stored flat: 2 cached heads of 8 a row.
+CONV_STATE, CACHE = (2, 64), (S, 2 * 8)
 # A reset inside the fragment, and an episode one token long after it.
 RESET = jnp.zeros((B, S)).at[:, 11].set(1.0).at[:, 12].set(1.0)
 
@@ -176,6 +177,33 @@ def test_decode_through_both_kinds_of_state_matches_reference(dtype):
         jnp.float32 if dtype == "f32" else jnp.bfloat16)
     # The attention layer alone reads a cache: grouped, so all of it.
     assert counted[-1] == {"decode_cache_read_share": 1.0}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_a_decode_through_the_kernel_form_is_the_causal_pass(
+        dtype, kernel_here):
+    """The grouped cache through the kernel form (`conftest.kernel_here`:
+    three blocks of 8 positions, interpreted), a reset and an episode one
+    token long inside the fragment: a step reads the blocks up to the
+    furthest position a row holds of its own episode, and the logits are
+    the causal pass's over the same resets."""
+    model, variables, tokens = build(dtype)
+    system, state, counted = decode_routed(model, variables, tokens, RESET,
+                                           jit=dtype == "f32")
+    # Episodes start at 0, 11 and 12: a row's position within its own.
+    place = [t if t < 11 else 0 if t == 11 else t - 12 for t in range(S)]
+    assert [step["decode_cache_read_share"] for step in counted] == [
+        pytest.approx(8 * (at // 8 + 1) / S) for at in place]
+    assert state_shapes(state) == ([CACHE] * 2, [CONV_STATE] * 4)
+    if dtype == "f32":
+        causal, _, _ = causal_routed(model, variables, tokens, RESET)
+        assert reference.relative_error(system[0], causal[0]) < 1e-5
+        assert reference.relative_error(system[1], causal[1]) < 1e-5
+        assert np.array_equal(system[2], causal[2])
+    else:
+        outputs, routing = judged(system, variables, tokens, starts=RESET)
+        assert routing["router_flips"] <= 0.1
+        assert outputs["ok"], outputs
 
 
 def test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over():
@@ -382,14 +410,16 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "custom_model": "lfm2_moe", "custom_model_config": net})
     assert model.static_counters(64, 4096, "tpu") == {
         "decode_rows_per_expert": 8.0, "decode_experts_batched": 1.0,
-        "decode_cache_block": 4096, "decode_attention_kernel": 0.0,
+        "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 2048.0,
         "conv_layers": 4, "conv_state_bytes_per_row": 32768}
-    assert model.static_counters(64, 4096, "cpu")[
-        "causal_attention_fused"] == 0.0
+    # Off a TPU the cache is read whole, by XLA's products.
+    off = model.static_counters(64, 4096, "cpu")
+    assert (off["causal_attention_fused"], off["decode_cache_block"],
+            off["decode_attention_kernel"]) == (0.0, 4096, 0.0)
     state = jax.eval_shape(lambda: model.initial_state(64))
     assert [c.shape for c in jax.tree.leaves(state["kv"])] == [
-        (64, 4096, 8, 64)] * 2
+        (64, 4096, 8 * 64)] * 2
     assert [c.shape for c in jax.tree.leaves(state["conv"])] == [
         (64, 2, 2048)] * 4
     variables = jax.eval_shape(
@@ -612,6 +642,25 @@ def test_lfm2_token_trainer_trains_on_the_fused_path(token_trainer):
     caches = jax.tree.leaves(state["kv"])
     assert sum(c.nbytes for c in caches) / (4 * S) == 128
     assert sum(c.nbytes for c in jax.tree.leaves(state["conv"])) / 4 == 2048
+
+
+def test_learner_stats_report_what_the_grouped_kernel_read(kernel_here):
+    """The trainer on the fused Anakin path with the kernel form in its
+    rollout and under its learner's bootstrap step: the one cache, three
+    blocks of 8, fills from empty every rollout and is read 1/2 + block /
+    2S of."""
+    trainer = IMPALATrainer(config=token_trainer_config())
+    try:
+        result = trainer.train()
+        assert np.isfinite(result["info"]["learner"]["total_loss"])
+        kept = trainer.optimizer.learner_stats
+        assert kept["decode_cache_read_share"] == pytest.approx(
+            0.5 + 8 / (2 * S))
+        # The host's counters are of the platform the trainer runs on.
+        assert kept["decode_attention_kernel"] == 0.0
+        assert kept["decode_cache_block"] == S
+    finally:
+        trainer.stop()
 
 
 @pytest.mark.parametrize("cfg,match", [

@@ -43,7 +43,8 @@ NET = dict(vocab_size=96, hidden_size=64, num_attention_heads=8,
            moe_num_active_primary_experts=2, moe_ffn_hidden_size=32,
            norm_topk_prob=True, max_position_embeddings=S,
            rope_theta=1.5e6, rms_norm_eps=1e-6)
-CACHES = [(S, 2, 16)] + [(WINDOW, 2, 16)] * 3
+# Grouped heads' caches are stored flat: 2 cached heads of 16 a row.
+CACHES = [(S, 2 * 16)] + [(WINDOW, 2 * 16)] * 3
 
 
 def build(dtype, net=NET, sharp=1.0):
@@ -152,6 +153,38 @@ def test_decode_through_a_full_cache_and_three_rings_matches_reference(dtype):
         "decode_cache_read_share": pytest.approx((1 + 3 / 3) / 4),
         "decode_cache_read_share_full": 1.0,
         "decode_cache_read_share_window": pytest.approx(1 / 3)}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_a_decode_through_the_kernel_form_is_the_causal_pass(
+        dtype, kernel_here):
+    """The grouped caches through the kernel form (`conftest.kernel_here`:
+    blocks of 8 positions, interpreted): a window of 16 under 24
+    positions, so the full cache is three blocks and a ring two, which
+    turns at position 16 and holds every slot from then on. A step reads
+    the blocks its rows hold; the logits are the causal pass's, which
+    keeps every position and masks the window."""
+    net = dict(NET, sliding_window_size=2 * WINDOW)
+    model, variables, tokens = build(dtype, net)
+    system, state, counted = decode_routed(model, variables, tokens,
+                                           jit=dtype == "f32")
+    for t, step in enumerate(counted):
+        held = 8 * (t // 8 + 1)
+        assert step["decode_cache_read_share_full"] == pytest.approx(
+            held / S)
+        assert step["decode_cache_read_share_window"] == pytest.approx(
+            min(held, 2 * WINDOW) / S)
+    assert [c.shape[1:] for c in jax.tree.leaves(state["kv"])] == (
+        [(S, 32)] * 2 + [(2 * WINDOW, 32)] * 6)
+    if dtype == "f32":
+        causal, _, _ = causal_routed(model, variables, tokens)
+        assert reference.relative_error(system[0], causal[0]) < 1e-5
+        assert reference.relative_error(system[1], causal[1]) < 1e-5
+        assert np.array_equal(system[2], causal[2])
+    else:
+        outputs, routing = judged(system, variables, tokens, net)
+        assert routing["router_flips"] <= 0.1
+        assert outputs["ok"], outputs
 
 
 def test_grouped_caches_are_read_whole_and_ungrouped_rings_in_blocks(
@@ -342,13 +375,17 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "custom_model": "smallthinker", "custom_model_config": net})
     assert model.static_counters(16, 8192, "tpu") == {
         "decode_rows_per_expert": 1.5, "decode_experts_batched": 1.0,
-        "decode_cache_block": 8192, "decode_attention_kernel": 0.0,
+        "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "window_layers": 3, "kv_groups": 7,
         "kv_cache_bytes_per_token": 5120.0,
         "causal_window_tiles_kept": 108 / 136}
+    # Off a TPU the caches are read whole, by XLA's products.
+    off = model.static_counters(16, 8192, "cpu")
+    assert (off["decode_cache_block"], off["decode_attention_kernel"]) == (
+        8192, 0.0)
     state = jax.eval_shape(lambda: model.initial_state(16))
     assert [c.shape for c in jax.tree.leaves(state["kv"])] == (
-        [(16, 8192, 4, 128)] * 2 + [(16, 4096, 4, 128)] * 6)
+        [(16, 8192, 4 * 128)] * 2 + [(16, 4096, 4 * 128)] * 6)
     variables = jax.eval_shape(
         model.init, jax.random.PRNGKey(0),
         jax.ShapeDtypeStruct((1, 1), jnp.int32),
@@ -531,6 +568,28 @@ def test_smallthinker_token_trainer_trains_on_the_fused_path(token_trainer):
     state, _ = token_trainer.optimizer._pstate
     assert [c.shape for c in jax.tree.leaves(state["kv"])] == [
         (4,) + shape for shape in CACHES for _ in range(2)]
+
+
+def test_learner_stats_report_what_the_grouped_kernel_read(kernel_here):
+    """The trainer on the fused Anakin path with the kernel form in its
+    rollout and under its learner's bootstrap step, a layer at a time: the
+    full cache, three blocks of 8, fills from empty and is read 1/2 +
+    block / 2S of; a ring of one block takes no kernel and is read
+    whole."""
+    trainer = IMPALATrainer(config=token_trainer_config())
+    try:
+        result = trainer.train()
+        assert np.isfinite(result["info"]["learner"]["total_loss"])
+        kept = trainer.optimizer.learner_stats
+        assert kept["decode_cache_read_share_full"] == pytest.approx(
+            0.5 + 8 / (2 * S))
+        assert kept["decode_cache_read_share_window"] == pytest.approx(
+            WINDOW / S)
+        # The host's counters are of the platform the trainer runs on.
+        assert kept["decode_attention_kernel"] == 0.0
+        assert kept["decode_cache_block"] == S
+    finally:
+        trainer.stop()
 
 
 @pytest.mark.parametrize("cfg,match", [
