@@ -56,9 +56,15 @@ def _lfm2_moe(obs_space, num_outputs, cfg, dtype):
     return lfm2_moe_from_config(num_outputs, cfg, dtype)
 
 
+def _kimi_linear(obs_space, num_outputs, cfg, dtype):
+    from .transformer import kimi_linear_from_config
+    return kimi_linear_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
 CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite,
-                 "smallthinker": _smallthinker, "lfm2_moe": _lfm2_moe}
+                 "smallthinker": _smallthinker, "lfm2_moe": _lfm2_moe,
+                 "kimi_linear": _kimi_linear}
 
 
 def _resolve_compute_dtype(cfg):

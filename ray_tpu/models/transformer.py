@@ -1,4 +1,4 @@
-"""Transformer token policies in flax: one decoder, four descriptions.
+"""Transformer token policies in flax: one decoder, five descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
@@ -14,8 +14,10 @@ It is assembled from parts that the published `config.json` of a family
 names; nothing else chooses between them.
 
 Op, a layer's operator: attention in every layer, or, a layer at a time by
-`layer_types` (LFM2, `model_type: lfm2_moe`: 18 of its 24 layers), the
-gated short convolution. For n = the normalised input [T, H]:
+`layer_types`, the gated short convolution (LFM2, `model_type: lfm2_moe`:
+18 of its 24 layers) or Kimi Delta Attention (Kimi-Linear, `model_type:
+kimi_linear`, arXiv:2510.26692: 20 of its 27, further down). The gated
+short convolution, for n = the normalised input [T, H]:
       [b | c | u] = n W_in          W_in [H, 3 H], no bias, thirds in that
                                     order
       g = b * u
@@ -33,6 +35,48 @@ gated short convolution. For n = the normalised input [T, H]:
   (`_conv_step`) appends g_t, multiplies and drops g_{t-(L-1)}. The taps
   are multiplied and summed in float32: elementwise work XLA fuses, no
   kernel of the repo's own.
+
+Kimi Delta Attention ("kda"), a gated delta rule with a decay a channel of
+the key; `kda_heads` heads, d_k = d_v = `kda_head_dim`, P = heads x d:
+      [q~ | k~ | v~] = n W_qkv      [H, 3 P], no bias, thirds in that order
+      q', k', v' = silu(conv(.))    depthwise causal, `kda_taps` (4) taps
+                                    [3 P, taps], the last on the current
+                                    position; 0 before the episode's first
+      q = q' / sqrt(|q'|^2 + 1e-6) a head, times d_k^-1/2;  k likewise, no
+                                    scale
+      g = -exp(A_log[head]) * softplus((n W_fa) W_fb + dt_bias)   in R^P:
+                                    the LOG decay a channel, <= 0
+      beta = sigmoid(n W_b)         one a head
+      S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+      o_t = S_t^T q_t               S [d_k, d_v] a head, 0 where an episode
+                                    begins
+      out = (RMSNorm_head(o) * sigmoid((n W_ga) W_gb)) W_out    the norm
+                                    over each head's d_v, one weight [d_v]
+  Its state is that matrix, [B, heads, d_k, d_v] in FLOAT32 whatever
+  `compute_dtype` (every step adds to it: in bfloat16 a few hundred steps
+  leave the logits 25 % from the reference, tests/test_kimi_linear_policy),
+  and the convolutions' last taps - 1 inputs, [B, taps - 1, 3 P] in
+  `compute_dtype`; no positions axis. Two forms that agree. A step
+  (`kda_step`): decay S by rows, u = beta (v - S^T k), S += k u^T, read
+  q; elementwise work and two sums, three passes over S. A fragment
+  (`kda_chunked`): chunks of `kda_chunk` (64) positions; inside a chunk
+  the delta rule in its triangular (UT / WY) form, one unit lower
+  triangular solve a head of (I + Diag(beta) kk) against [beta V | beta
+  k_in]; between chunks a `lax.scan` that carries S, three matrix
+  products a step; both loops' bodies recomputed in the backward pass, so
+  that the scan's residuals are the chunk states. WHY PAIRS ARE FORMED
+  FROM DIFFERENCES OF LOG DECAYS: the decay is a channel's, so the
+  factorised product (q_i exp(G_i)) . (k_j exp(-G_j)) of cumulative log
+  decays G overflows float32 as soon as one channel loses e^88 inside a
+  chunk, and at this gate's range (exp(A_log) up to 16, softplus of a few
+  units) ONE position can lose e^-50. So no exp(-G) is formed: a pair in
+  different sub-blocks of `KDA_SUB_BLOCK` (16) positions goes through the
+  point between them, exp(sum of g over i's sub-block up to i) times
+  exp(sum over j's after j, plus the sub-blocks between), two factors <=
+  1 and one matrix product; a pair inside a sub-block by its own exp(G_i -
+  G_j), channel by channel. Every exponent is a sum of g's, <= 0. An
+  episode that begins inside a fragment cuts the scan: pairs of different
+  episodes are 0 and the carried state is dropped at the boundary.
 
 Attention, one of:
   a head's own keys and values (OLMoE, arXiv:2409.02060, `model_type:
@@ -70,6 +114,16 @@ Attention, one of:
       position s is (W_UK q_nope) . c_kv,s + q_rope . k_r,s and its output
       W_UV^T (sum_s a_s c_kv,s), so the cache holds c_kv after its norm and
       k_r after RoPE, [B, S, kv_lora_rank + rope] a layer, and nothing else.
+      It is the ATTENTION LAYERS' kind, not the model's: a model may have
+      one such layer among operators of another kind. Kimi-Linear's
+      differs from the second configuration's in three ways: no query
+      latent (`q_lora_rank` 0: q = n W_q, no W_qa, no norm), no rotation
+      anywhere (`rope_layout` all false, the source's `mla_use_nope`: q_rope
+      and k_r are used as they are made, and position-free), and heads of
+      128 + 64 = 192, which is no width the fused causal form takes: its
+      causal pass pads q and k with zeros to 256 (q . k is what it was;
+      `_latent_key_width`), or the plain form's [T, T] scores would be
+      4.3 GB at the cell's minibatch.
 
 Feed-forward, by layer: the first `first_k_dense_replace` layers a dense
 SwiGLU; the others routed experts, beside `n_shared_experts` shared ones
@@ -97,7 +151,8 @@ Router, float32, one of:
   LFM2's `use_expert_bias`):
       s = sigmoid(n W_r); the k largest of s + b choose; weights are s at
       the chosen experts, without b, over their sum (`norm_topk_prob`;
-      plus `topk_eps` where the description divides so: LFM2's 1e-6),
+      plus `topk_eps` where the description divides so: LFM2's 1e-6,
+      Kimi-Linear's 1e-20),
       times `routed_scaling_factor`. b is a constant of the model: no
       gradient, no optimizer state (its balancing update belongs to
       pre-training).
@@ -127,7 +182,9 @@ auxiliary router loss (the RL objective has no place for it; the
 and heads are float32 and the block's activations `compute_dtype`
 (bfloat16: the repo's convention, as the Nature-CNN's trunk); lfm2_moe's
 tied head is assumed (the catalog's row drops the key; the family's dense
-configs tie), and its selection bias is frozen. The OLMoE and
+configs tie), and its selection bias is frozen, as kimi_linear's is;
+kimi_linear's low ranks, epsilons and the decay's initial draw are assumed
+(the configuration's file lists them). The OLMoE and
 glm4_moe_lite descriptions have as many key/value heads as query heads and
 refuse another count (their references have no grouped form; latent
 attention has no key/value heads to group).
@@ -170,11 +227,12 @@ One set of parameters, two forms (the stateful-policy protocol of
   grid step hold.
 
 Both return the state, so a decode can follow a causal pass: {"kv": a
-layer's caches (none for a convolution layer), "pos"}, and where the model
-has convolution layers {"conv": a layer's last gated inputs (none for an
-attention layer)} beside them, a key of its own: every leaf of "kv" has a
-positions axis, no leaf of "conv" has. `JaxPolicy` and the Anakin optimizer
-carry the whole as one pytree.
+layer's caches (none for a layer that is no attention), "pos"}, and, a key
+a kind and only the kinds the model has, {"conv": a convolution layer's
+last gated inputs, a KDA layer's convolutions' last inputs (none for an
+attention layer)} and {"kda": a KDA layer's float32 matrices} beside them:
+every leaf of "kv" has a positions axis, no leaf of "conv" or "kda" has.
+`JaxPolicy` and the Anakin optimizer carry the whole as one pytree.
 """
 
 from __future__ import annotations
@@ -308,8 +366,58 @@ LFM2_MOE_FIXED = {
     "tie_embedding": True, "tie_word_embeddings": True,
     "model_type": "lfm2_moe",
 }
+KIMI_LINEAR_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_hidden_layers": "num_layers",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
+    "first_k_dense_replace": "dense_layers",
+    "intermediate_size": "dense_width",
+    "num_experts": "num_experts",
+    "num_experts_per_token": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "num_shared_experts": "shared_experts",
+    "moe_renormalize": "norm_topk_prob",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "model_max_length": "context_len",
+    "rope_theta": "rope_theta",  # kept; no layer rotates (`mla_use_nope`)
+    "rms_norm_eps": "rms_eps",
+    # The deployment's: the share of the experts this chip holds, and the
+    # positions in a chunk of the learner's scan.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+    "kda_chunk": "kda_chunk",
+}
+# What Kimi-Linear-48B-A3B's published `config.json` says, for the keys a
+# `custom_model_config` leaves out.
+KIMI_LINEAR_PUBLISHED = {
+    "vocab_size": 163840, "hidden_size": 2304, "num_attention_heads": 32,
+    "num_hidden_layers": 27, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "first_k_dense_replace": 1,
+    "intermediate_size": 9216, "num_experts": 256,
+    "num_experts_per_token": 8, "moe_intermediate_size": 1024,
+    "num_shared_experts": 1, "moe_renormalize": True,
+    "routed_scaling_factor": 2.446, "model_max_length": 1048576,
+    "rope_theta": 10000, "rms_norm_eps": 1e-5,
+    "linear_attn_config": {
+        "full_attn_layers": [4, 8, 12, 16, 20, 24, 27], "head_dim": 128,
+        "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                       21, 22, 23, 25, 26],
+        "num_heads": 32, "short_conv_kernel_size": 4},
+}
+KIMI_LINEAR_FIXED = {
+    "mla_use_nope": True, "q_lora_rank": None, "num_expert_group": 1,
+    "topk_group": 1, "use_grouped_topk": True, "moe_layer_freq": 1,
+    "moe_router_activation_func": "sigmoid", "num_nextn_predict_layers": 0,
+    "hidden_act": "silu", "rope_scaling": None, "tie_word_embeddings": False,
+    "model_type": "kimi_linear",
+}
 # The operators a layer of `layer_types` may name.
-LAYER_TYPES = ("conv", "full_attention")
+LAYER_TYPES = ("conv", "full_attention", "kda")
 # Published keys that must say what the decoder does (a value it has no
 # part for is refused, not ignored).
 GLM4_MOE_LITE_FIXED = {
@@ -722,6 +830,266 @@ def causal_attention(q, k, v, episode, scale, window=0):
         default=functools.partial(_causal_plain, scale=scale, window=window))
 
 
+def _taps_causal(g, w, positions):
+    """A depthwise causal convolution over a fragment: g [B, T, channels],
+    taps w [channels, L], the last one on the current position, a tap that
+    would reach before its episode's first step (`positions`: a step's
+    place in its episode) reading 0. Returns (v_t = sum_j w[:, j]
+    g_{t - (L - 1) + j}, multiplied and summed in float32; g with L - 1
+    zeros ahead of it, for `_taps_tail`)."""
+    L, T = w.shape[1], g.shape[1]
+    w = w.astype(jnp.float32)
+    # back[:, L - 1 - s + t] = g_{t - s}, zeros before the fragment.
+    back = jnp.pad(g, ((0, 0), (L - 1, 0), (0, 0)))
+    v = jnp.zeros(g.shape, jnp.float32)
+    for j in range(L):
+        s = L - 1 - j
+        v = v + w[:, j] * jnp.where(
+            (positions >= s)[..., None], back[:, j:j + T],
+            0).astype(jnp.float32)
+    return v, back
+
+
+def _taps_tail(back, positions):
+    """The state a decode continues `_taps_causal` from: the last
+    episode's last L - 1 inputs [B, L - 1, channels], 0 where the episode
+    is shorter."""
+    T = positions.shape[1]
+    L = back.shape[1] - T + 1
+    # Slot j of the state is g_{T - (L - 1) + j}.
+    held = positions[:, -1:] >= (L - 2 - jnp.arange(L - 1))
+    return jnp.where(held[..., None], back[:, T:], 0)
+
+
+def _taps_step(g, w, state, reset):
+    """The same of one position a row, g [B, channels], against the row's
+    last L - 1 inputs `state`, zeroed first where `reset`; (v float32, the
+    L inputs the taps met: all but the oldest are the new state)."""
+    state = jnp.where((reset > 0)[:, None, None], 0, state)
+    taps = jnp.concatenate([state, g[:, None]], axis=1)
+    return jnp.sum(taps.astype(jnp.float32)
+                   * w.astype(jnp.float32).T, axis=1), taps
+
+
+# Positions in a sub-block of a chunk of the KDA scan (see `kda_chunked`).
+KDA_SUB_BLOCK = 16
+
+
+def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
+    """One chunk of `kda_chunked` up to the state it begins with, for every
+    row and head at once: q, k, g [B, heads, C, d_k] (g <= 0 the float32
+    log decay a channel), v [B, heads, C, d_v], beta [B, heads, C, 1],
+    `episode` [B, C] and `before` [B], the episode of the position ahead
+    of the chunk. With G_i the sum of g over the chunk's positions up to
+    and including i:
+
+        kk[i, j] = sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])     j < i
+        qk[i, j] = sum_c q_i[c] k_j[c] exp(G_i[c] - G_j[c])     j <= i
+            both 0 where i and j lie in different episodes;
+        q_in[i]  = q_i * exp(G_i), k_in[i] = k_i * exp(G_i): what meets the
+            state the chunk begins with, 0 where an episode has begun
+            inside the chunk by position i;
+        k_out[j] = k_j * exp(G_C - G_j): what position j leaves in the
+            state the chunk ends with, 0 where j's episode ends inside it;
+        keep     = exp(G_C), 0 where an episode begins inside the chunk;
+    and, solved here, [w_v | w_k] = (I + Diag(beta) kk)^-1 Diag(beta)
+    [V | k_in]: one unit lower triangular solve a head. Returns (into =
+    [w_k ; q_in] as one operand of 2 C rows, w_v, qk, k_out, keep).
+
+    No exp(-G) is ever formed: a channel may lose e^50 in ONE position, so
+    exp(G_i) * exp(-G_j) overflows float32 inside a chunk while the pair's
+    own exp(G_i - G_j) <= 1 is harmless. Every exponent here is a sum of
+    g's, never a difference of two cumulative sums across sub-blocks, so
+    it is <= 0 by construction and loses nothing to cancellation: in
+    sub-blocks of `sub` positions, a pair of different sub-blocks I > J
+    through the point between them,
+        exp(G_i - G_j) = exp(sum of g over I up to i)
+                         * exp(sum over J after j + the sub-blocks between),
+    two factors <= 1 and so one matrix product a pair of sub-blocks; a
+    pair inside one sub-block from the difference of the sub-block's own
+    cumulative sums, channel by channel, elementwise ([sub, sub, d] a
+    sub-block: why the sub-blocks are small). Matrix operands are cast to
+    `dtype`, sums are float32."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    lead, (C, d) = g.shape[:-2], g.shape[-2:]
+    n = C // sub
+    by_sub = lead + (n, sub, d)
+    gs, qs, ks = g.reshape(by_sub), q.reshape(by_sub), k.reshape(by_sub)
+    # Sums of g: over a sub-block up to i; over it after j; over whole
+    # sub-blocks ahead of I, behind J, and strictly between J and I.
+    within = jnp.cumsum(gs, axis=-2)
+    behind = jnp.flip(jnp.cumsum(jnp.flip(gs, -2), axis=-2), -2)
+    after = jnp.concatenate(
+        [behind[..., 1:, :], jnp.zeros_like(gs[..., :1, :])], axis=-2)
+    whole = within[..., -1, :]  # [.., n, d]
+    order = jnp.arange(n)
+
+    def over(mask):
+        """Sums of whole sub-blocks' g, those `mask` [.., K] names.
+        Selected and added in float32, not multiplied by 0 / 1: on a TPU a
+        float32 matrix product rounds its operands to bfloat16 by default,
+        and a log decay of -50 rounded to eight bits is a decay 20 % off."""
+        at = whole.reshape(lead + (1,) * (mask.ndim - 1) + (n, d))
+        return jnp.sum(jnp.where(mask[..., None], at, 0.0), axis=-2)
+    ahead = over(order[None, :] < order[:, None])  # [.., I, d]: K < I
+    astern = over(order[None, :] > order[:, None])  # [.., J, d]: K > J
+    between = over((order[None, :, None] < order[None, None, :])
+                   & (order[None, None, :] < order[:, None, None]))
+    # Inside a sub-block, channel by channel.
+    steps = jnp.arange(sub)
+    lower = steps[:, None] >= steps[None, :]
+    decay = jnp.exp(jnp.where(
+        lower[..., None],
+        within[..., :, None, :] - within[..., None, :, :], -jnp.inf))
+    k_decayed = ks[..., None, :, :] * decay  # [.., n, i, j, d]
+    kk_in = jnp.sum(ks[..., :, None, :] * k_decayed, axis=-1)
+    qk_in = jnp.sum(qs[..., :, None, :] * k_decayed, axis=-1)
+    # Between sub-blocks, through the point ahead of sub-block I.
+    left = jnp.exp(within)
+    right = (ks[..., None, :, :, :] * jnp.exp(
+        after[..., None, :, :, :] + between[..., None, :])).astype(dtype)
+    kk_off = jnp.einsum("...Iic,...IJjc->...IiJj", (ks * left).astype(dtype),
+                        right, preferred_element_type=f32)
+    qk_off = jnp.einsum("...Iic,...IJjc->...IiJj", (qs * left).astype(dtype),
+                        right, preferred_element_type=f32)
+    same_sub = (order[:, None] == order[None, :])[:, None, :, None]
+    earlier = (order[:, None] > order[None, :])[:, None, :, None]
+
+    def joined(inside, off):
+        return jnp.where(
+            same_sub, inside[..., :, :, None, :],
+            jnp.where(earlier, off, 0.0)).reshape(lead + (C, C))
+    same = (episode[:, :, None] == episode[:, None, :])[:, None]
+    at = jnp.arange(C)
+    kk = jnp.where(same & (at[:, None] > at[None, :]),
+                   joined(kk_in, kk_off), 0.0)
+    qk = jnp.where(same, joined(qk_in, qk_off), 0.0)
+    # Against the states at the chunk's two ends.
+    since = jnp.exp(ahead[..., None, :] + within).reshape(lead + (C, d))
+    until = jnp.exp(after + astern[..., None, :]).reshape(lead + (C, d))
+    carried = (episode == before[:, None])[:, None, :, None]
+    lasting = (episode == episode[:, -1:])[:, None, :, None]
+    q_in = jnp.where(carried, q * since, 0.0).astype(dtype)
+    k_in = jnp.where(carried, k * since, 0.0)
+    k_out = jnp.where(lasting, k * until, 0.0).astype(dtype)
+    keep = jnp.where((episode[:, -1] == before)[:, None, None],
+                     jnp.exp(jnp.sum(whole, axis=-2)), 0.0)
+    solved = jax.scipy.linalg.solve_triangular(
+        jnp.eye(C, dtype=f32) + beta * kk,
+        jnp.concatenate([beta * v, beta * k_in], axis=-1),
+        lower=True, unit_diagonal=True)
+    w_v, w_k = solved[..., :v.shape[-1]], solved[..., v.shape[-1]:]
+    return (jnp.concatenate([w_k.astype(dtype), q_in], axis=-2), w_v,
+            qk.astype(dtype), k_out, keep)
+
+
+def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
+    """Kimi Delta Attention over a fragment from an empty state, in chunks:
+    q, k, g [B, T, heads, d_k], v [B, T, heads, d_v], beta [B, T, heads]
+    (q and k normalised, q scaled; g <= 0 the LOG decay a channel of the
+    key, float32, as beta is), `episode` [B, T] the number of the episode
+    a step belongs to (it never falls along a row). A head's state S
+    [d_k, d_v] is 0 where an episode begins and
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    Returns (o [B, T, heads, d_v] in `dtype`, S after the last position
+    [B, heads, d_k, d_v] float32: what a decode continues from).
+
+    With u_t = beta_t (v_t - (Diag(exp(g_t)) S_{t-1})^T k_t) the update
+    is S_t = Diag(exp(g_t)) S_{t-1} + k_t u_t^T, so inside a chunk of C
+    positions that begins with S_0 (`_kda_chunk` has the names)
+
+        (I + Diag(beta) kk) U = Diag(beta) (V - k_in S_0)
+        O = q_in S_0 + qk U
+        S_C = Diag(keep) S_0 + k_out^T U
+
+    the delta rule in its triangular (UT / WY) form: one unit lower
+    triangular solve a chunk and head, with [beta V | beta k_in] on the
+    right so that it is made once, ahead of the states. Two phases: the
+    chunks' decayed products and solves, a chunk at a time (`lax.map`);
+    the scan over the chunks that carries S, three matrix products a
+    step. Both bodies are recomputed in the backward pass: what either
+    holds at once is one chunk's ([sub, sub, d_k] products a sub-block,
+    not the fragment's), and the scan's residuals are the chunk states.
+    An episode that begins inside the fragment cuts both: pairs of
+    different episodes are 0, a position reads S_0 only while no episode
+    has begun in its chunk, and S_C keeps of S_0 and of its own positions
+    what belongs to the chunk's last episode. T need not be whole chunks:
+    the tail is padded with positions that decay nothing and write
+    nothing (g = 0, beta = 0). Matrix operands are cast to `dtype`, sums,
+    decays and S are float32."""
+    f32 = jnp.float32
+    B, T, heads, d_k = q.shape
+    sub = min(KDA_SUB_BLOCK, chunk)
+    assert chunk % sub == 0, (chunk, sub)
+    pad = -T % chunk
+    N = (T + pad) // chunk
+
+    def chunks(a, mode="constant"):
+        """[B, T, ..] -> [N, B, .., chunk] chunk-major, heads ahead of
+        the positions; the tail padded with zeros, or with the last
+        position's value."""
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2),
+                    mode=mode)
+        a = jnp.moveaxis(a.reshape((B, N, chunk) + a.shape[2:]), 1, 0)
+        return a if a.ndim == 3 else jnp.moveaxis(a, 2, 3)
+    q, k, v, g = (chunks(a) for a in (q, k, v, g))  # [N, B, heads, C, d]
+    beta = chunks(beta[..., None])  # [N, B, heads, C, 1]
+    episode = chunks(episode, mode="edge")  # [N, B, C]
+    # The episode ahead of a chunk; ahead of the first the state is 0
+    # whatever it is called.
+    before = jnp.concatenate([episode[:1, :, 0], episode[:-1, :, -1]])
+    with jax.named_scope("policy/kda_state"):
+        terms = jax.lax.map(
+            jax.checkpoint(lambda xs: _kda_chunk(*xs, sub=sub, dtype=dtype)),
+            (q, k, v, g, beta, episode, before))
+
+        def a_chunk(S, xs):
+            into, w_v, qk, k_out, keep = xs
+            met = jnp.einsum("bhik,bhkv->bhiv", into, S.astype(dtype),
+                             preferred_element_type=f32)
+            u = w_v - met[:, :, :chunk]
+            o = met[:, :, chunk:] + jnp.einsum(
+                "bhij,bhjv->bhiv", qk, u.astype(dtype),
+                preferred_element_type=f32)
+            S = keep[..., None] * S + jnp.einsum(
+                "bhjk,bhjv->bhkv", k_out, u.astype(dtype),
+                preferred_element_type=f32)
+            return S, o.astype(dtype)
+        S, o = jax.lax.scan(
+            jax.checkpoint(a_chunk),
+            jnp.zeros((B, heads, d_k, v.shape[-1]), f32), terms)
+    # [N, B, heads, C, d_v] -> [B, T, heads, d_v]
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(
+        (B, N * chunk, heads, -1))
+    return o[:, :T], S
+
+
+def kda_step(S, q, k, v, g, beta):
+    """The same recurrence, one position: S [B, heads, d_k, d_v] float32
+    against q, k, g [B, heads, d_k], v [B, heads, d_v], beta [B, heads];
+    (o [B, heads, d_v], the state after the position). Decay by rows of
+    d_k, one outer product subtracted and added, q read:
+
+        S' = Diag(exp(g)) S;  u = beta (v - S'^T k);  S = S' + k u^T
+        o = S^T q = S'^T q + (k . q) u
+
+    written so that S is passed over three times and no more: both
+    products against S' are sums over the one read of it (what q reads
+    of the new state is what it reads of the decayed one, plus u times a
+    scalar), then one read and one write make S."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    decayed = jnp.exp(g)[..., None] * S
+    from_k = jnp.sum(decayed * k[..., None], axis=-2)
+    from_q = jnp.sum(decayed * q[..., None], axis=-2)
+    u = beta[..., None] * (v - from_k)
+    o = from_q + jnp.sum(k * q, axis=-1, keepdims=True) * u
+    return o, decayed + k[..., None] * u[..., None, :]
+
+
 def experts_batched(M: int, k: int, E: int) -> bool:
     """Whether `M` rows, each routed to `k` of `E` experts, go through the
     batched form (`M * E` rows of work) or the grouped one (`M * k` sorted
@@ -818,13 +1186,28 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
 ROUTER_BIAS_SCALE = 0.02
 
 
+def _decay_inits() -> dict:
+    """A KDA layer's decay at initialisation, the family's Mamba-style
+    draw: A = exp(a_log) uniform in [1, 16] a head, and dt_bias the inverse
+    softplus of a step drawn log-uniform in [0.001, 0.1] a channel, so
+    that g = -A softplus(. + dt_bias) starts between -0.001 and -1.6."""
+    def a_log(key, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+    def dt_bias(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, dtype, jnp.log(0.001), jnp.log(0.1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return {"a_log": a_log, "dt_bias": dt_bias}
+
+
 class DecoderLayerParams(nn.Module):
     """One layer's parameters, by the names the equations use: `shapes` is
     ((name, kind, shape), ...), kind one of ones / dense / experts (a
     leading axis of experts) / taps (a depthwise filter [channels, taps],
     a channel's fan-in its taps) / bias (a constant of the model, small
     and seeded, in the "constants" collection: no gradient, no optimizer
-    state)."""
+    state) / a_log, dt_bias (a KDA layer's decay: `_decay_inits`)."""
 
     shapes: tuple
 
@@ -832,7 +1215,8 @@ class DecoderLayerParams(nn.Module):
         inits = {"ones": nn.initializers.ones,
                  "dense": nn.initializers.lecun_normal(),
                  "experts": nn.initializers.lecun_normal(batch_axis=(0,)),
-                 "taps": nn.initializers.lecun_normal(in_axis=1, out_axis=0)}
+                 "taps": nn.initializers.lecun_normal(in_axis=1, out_axis=0),
+                 **_decay_inits()}
         tensors = {}
         for name, kind, shape in self.shapes:
             if kind == "bias":
@@ -882,6 +1266,14 @@ class TokenDecoder(nn.Module):
     # gated short convolution of `conv_taps` taps.
     layer_types: tuple = ()
     conv_taps: int = 3
+    # "kda", Kimi Delta Attention: `kda_heads` heads (0: `num_heads`) whose
+    # keys and values are both `kda_head_dim` wide (which is also the rank
+    # of the decay's and the output gate's projections), short convolutions
+    # of `kda_taps` taps, the learner's scan in chunks of `kda_chunk`.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_taps: int = 4
+    kda_chunk: int = 64
     # Feed-forward: `dense_layers` leading dense layers, then experts.
     dense_layers: int = 0
     dense_width: int = 0
@@ -926,11 +1318,15 @@ class TokenDecoder(nn.Module):
         return self.head_dim or self.hidden_size // self.num_heads
 
     def layer_kind(self, i: int):
-        """"conv" where layer `i`'s operator is the short convolution; of
-        an attention layer (the window it attends within, 0 for the whole
-        episode; whether its queries and keys are rotated)."""
-        if self.layer_types and self.layer_types[i] == "conv":
-            return "conv"
+        """"conv" where layer `i`'s operator is the short convolution,
+        "kda" where it is Kimi Delta Attention; of an attention layer (the
+        window it attends within, 0 for the whole episode; whether its
+        queries and keys are rotated)."""
+        if self.layer_types and self.layer_types[i] != "full_attention":
+            if self.layer_types[i] not in LAYER_TYPES:
+                raise ValueError(f"layer type {self.layer_types[i]!r}: "
+                                 f"TokenDecoder has {LAYER_TYPES}")
+            return self.layer_types[i]
         window = bool(self.window_layout) and bool(self.window_layout[i])
         return (self.sliding_window if window else 0,
                 not self.rope_layout or bool(self.rope_layout[i]))
@@ -938,7 +1334,17 @@ class TokenDecoder(nn.Module):
     @property
     def attention_layers(self) -> tuple:
         return tuple(i for i in range(self.num_layers)
-                     if self.layer_kind(i) != "conv")
+                     if not isinstance(self.layer_kind(i), str))
+
+    @property
+    def kda_layers(self) -> tuple:
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) == "kda")
+
+    @property
+    def kda_width(self) -> int:
+        """A KDA layer's projections' width: heads x head_dim."""
+        return (self.kda_heads or self.num_heads) * self.kda_head_dim
 
     def cache_len(self, i: int) -> int:
         """Positions layer `i`'s cache holds: the context, or a window
@@ -946,22 +1352,38 @@ class TokenDecoder(nn.Module):
         window = self.layer_kind(i)[0]
         return min(window or self.context_len, self.context_len)
 
-    def _layer_shapes(self, dense: bool, conv: bool = False) -> tuple:
+    def _layer_shapes(self, dense: bool, kind=(0, True)) -> tuple:
         """`attn_norm` is the norm ahead of the layer's operator, whichever
-        that is."""
+        that is (`kind`: `layer_kind`'s)."""
         H, heads = self.hidden_size, self.num_heads
         shapes = [("attn_norm", "ones", (H,)), ("mlp_norm", "ones", (H,))]
-        if conv:
+        if kind == "conv":
             shapes += [("conv_in", "dense", (H, 3 * H)),
                        ("conv_w", "taps", (H, self.conv_taps)),
                        ("conv_out", "dense", (H, H))]
+        elif kind == "kda":
+            P, d = self.kda_width, self.kda_head_dim
+            shapes += [
+                # [W_q | W_k | W_v], and their convolutions' taps
+                ("kda_qkv", "dense", (H, 3 * P)),
+                ("kda_conv", "taps", (3 * P, self.kda_taps)),
+                ("kda_fa", "dense", (H, d)), ("kda_fb", "dense", (d, P)),
+                ("kda_a_log", "a_log", (P // d,)),
+                ("kda_dt_bias", "dt_bias", (P,)),
+                ("kda_b", "dense", (H, P // d)),
+                ("kda_ga", "dense", (H, d)), ("kda_gb", "dense", (d, P)),
+                ("kda_o_norm", "ones", (d,)),
+                ("kda_out", "dense", (P, H))]
         elif self.kv_lora_rank:
             rq, rkv = self.q_lora_rank, self.kv_lora_rank
             nope, rot, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                              self.v_head_dim)
+            # The queries through a latent of their own, or straight.
             shapes += [
                 ("wq_a", "dense", (H, rq)), ("q_a_norm", "ones", (rq,)),
-                ("wq_b", "dense", (rq, heads * (nope + rot))),
+                ("wq_b", "dense", (rq, heads * (nope + rot)))] if rq else [
+                ("wq", "dense", (H, heads * (nope + rot)))]
+            shapes += [
                 ("wkv_a", "dense", (H, rkv + rot)),
                 ("kv_a_norm", "ones", (rkv,)),
                 ("wkv_b", "dense", (rkv, heads * (nope + vd))),
@@ -1007,14 +1429,10 @@ class TokenDecoder(nn.Module):
                 f"{self.kv_heads} key/value heads' groups")
         self.embed = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab_size, H))
-        for kind in self.layer_types[:self.num_layers]:
-            if kind not in LAYER_TYPES:
-                raise ValueError(
-                    f"layer type {kind!r}: TokenDecoder has {LAYER_TYPES}")
         self.layers = [
             DecoderLayerParams(
                 self._layer_shapes(i < self.dense_layers,
-                                   self.layer_kind(i) == "conv"),
+                                   self.layer_kind(i)),
                 name=f"layer_{i}")
             for i in range(self.num_layers)]
         self.nextn = [
@@ -1051,11 +1469,17 @@ class TokenDecoder(nn.Module):
         cached heads one row of contiguous lanes: what the decode's kernel
         reads as it lies (`cached_attention`; as [B, S, groups, d] XLA:TPU
         tiles the last two axes, pads heads of 64 to whole lane tiles and
-        copies the cache to the kernel's view every step)."""
+        copies the cache to the kernel's view every step). A KDA layer
+        has no cache either: its state is a matrix a head, [B, heads, d_k,
+        d_v] in float32 whatever `compute_dtype` (it is summed into over
+        thousands of steps), its entry of "kda", and the last `kda_taps` -
+        1 inputs of its three convolutions, [B, taps - 1, 3 x heads x d]
+        in `compute_dtype`, its entry of "conv"."""
         B = batch_size
+        kinds = [self.layer_kind(i) for i in range(self.num_layers)]
 
         def shapes(i):
-            if self.layer_kind(i) == "conv":
+            if isinstance(kinds[i], str):
                 return ()
             S = self.cache_len(i)
             if self.kv_lora_rank:
@@ -1063,21 +1487,27 @@ class TokenDecoder(nn.Module):
             if self.kv_heads != self.num_heads:
                 return ((B, S, self.kv_heads * self.head_width),) * 2
             return ((B, S, self.kv_heads, self.head_width),) * 2
+        tails = {"conv": (self.conv_taps - 1, self.hidden_size),
+                 "kda": (self.kda_taps - 1, 3 * self.kda_width)}
+        d = self.kda_head_dim
         return self._policy_state(
             (tuple(jnp.zeros(s, self.compute_dtype) for s in shapes(i))
              for i in range(self.num_layers)),
-            (jnp.zeros((B, self.conv_taps - 1, self.hidden_size),
-                       self.compute_dtype)
-             if self.layer_kind(i) == "conv" else ()
-             for i in range(self.num_layers)),
+            (jnp.zeros((B,) + tails[kind], self.compute_dtype)
+             if isinstance(kind, str) else () for kind in kinds),
+            (jnp.zeros((B, self.kda_width // d, d, d), jnp.float32)
+             if kind == "kda" else () for kind in kinds),
             jnp.zeros(batch_size, jnp.int32))
 
-    def _policy_state(self, kv, conv, pos) -> dict:
+    def _policy_state(self, kv, conv, kda, pos) -> dict:
         """The policy state of a layer's caches, a layer's convolution
-        state and the rows' positions."""
+        state, a layer's matrix state and the rows' positions: a key a
+        kind, and only the kinds the model has."""
         state = {"kv": tuple(kv), "pos": pos}
         if self.layer_types:
             state["conv"] = tuple(conv)
+        if "kda" in self.layer_types[:self.num_layers]:
+            state["kda"] = tuple(kda)
         return state
 
     def static_counters(self, batch_size: int, fragment_len: int,
@@ -1097,14 +1527,17 @@ class TokenDecoder(nn.Module):
         for its own length). A model with window layers: how many they
         are, the query heads a key/value head, and the share of the
         causal tiles that the fused form visits in a window layer. A
-        model with convolution layers: how many they are, and the bytes
-        of their state a row, whatever the length."""
+        model with convolution state (gated short convolutions, or KDA's
+        three): the layers that have it, and the bytes of it a row,
+        whatever the length, from the state's own leaves. A model with KDA
+        layers: how many they are, the bytes of their matrix states a
+        row, and the positions in a chunk of the learner's scan."""
         k, E = self.experts_per_token, self.num_experts
         kernel = False
+        attention = self.attention_layers
         if self.kv_lora_rank:
-            widths = (self.qk_nope_head_dim + self.qk_rope_head_dim,
-                      self.v_head_dim)
-            kernel = platform == "tpu" and decode_fused(
+            widths = (self._latent_key_width(fragment_len), self.v_head_dim)
+            kernel = platform == "tpu" and bool(attention) and decode_fused(
                 self.context_len, self.num_heads, self.latent_width,
                 self.kv_lora_rank)
         else:
@@ -1113,7 +1546,7 @@ class TokenDecoder(nn.Module):
                       and self.kv_heads != self.num_heads and all(
                           grouped_fused(self.cache_len(i), self.kv_heads,
                                         self.num_heads, self.head_width)
-                          for i in self.attention_layers))
+                          for i in attention))
         if kernel:
             block = decode_attention.BLOCK
         elif self.kv_lora_rank or self.kv_heads != self.num_heads:
@@ -1129,12 +1562,10 @@ class TokenDecoder(nn.Module):
             "causal_attention_fused": float(
                 platform == "tpu" and causal_fused(fragment_len, *widths)),
         }
+        itemsize = jnp.dtype(self.compute_dtype).itemsize
         if self.kv_lora_rank:
             out["latent_cache_bytes_per_token"] = (
-                self.num_layers * self.latent_width
-                * jnp.dtype(self.compute_dtype).itemsize)
-        itemsize = jnp.dtype(self.compute_dtype).itemsize
-        attention = self.attention_layers
+                len(attention) * self.latent_width * itemsize)
         if not self.kv_lora_rank:
             out["kv_cache_bytes_per_token"] = (
                 2 * self.kv_heads * self.head_width * itemsize
@@ -1150,11 +1581,17 @@ class TokenDecoder(nn.Module):
                 kv_groups=self.num_heads // self.kv_heads,
                 causal_window_tiles_kept=kept / causal)
         if len(attention) < self.num_layers:
-            convs = self.num_layers - len(attention)
-            out.update(
-                conv_layers=convs,
-                conv_state_bytes_per_row=convs * (self.conv_taps - 1)
-                * self.hidden_size * itemsize)
+            state = jax.eval_shape(lambda: self.initial_state(1))
+
+            def held(key):
+                return sum(a.size * a.dtype.itemsize
+                           for a in jax.tree.leaves(state[key]))
+            out.update(conv_layers=self.num_layers - len(attention),
+                       conv_state_bytes_per_row=held("conv"))
+            if "kda" in state:
+                out.update(kda_layers=len(self.kda_layers),
+                           kda_state_bytes_per_row=held("kda"),
+                           kda_chunk=self.kda_chunk)
         return out
 
     def __call__(self, obs, state, reset):
@@ -1192,25 +1629,51 @@ class TokenDecoder(nn.Module):
             return "policy/attention"
         return "policy/attention_window" if window else "policy/attention_full"
 
-    def _latents(self, lp, n, positions):
-        """(c_q, the cache's rows [c_kv | k_r]) of rows n at `positions`."""
+    def _latents(self, lp, n, positions, rotary=True):
+        """(c_q, the cache's rows [c_kv | k_r]) of rows n at `positions`:
+        c_q the queries' own latent, or n itself where they have none
+        (`q_lora_rank` 0); k_r rotated where `rotary`."""
         cd, eps = self.compute_dtype, self.rms_eps
         with jax.named_scope("policy/mla_latent"):
             c_q = rms_norm(jnp.dot(n, lp["wq_a"].astype(cd)),
-                           lp["q_a_norm"], eps, cd)
+                           lp["q_a_norm"], eps, cd) if self.q_lora_rank \
+                else n
             kv = jnp.dot(n, lp["wkv_a"].astype(cd))
             c_kv = rms_norm(kv[..., :self.kv_lora_rank], lp["kv_a_norm"],
                             eps, cd)
-            k_r = rope(kv[..., None, self.kv_lora_rank:], positions,
-                       self.rope_theta)[..., 0, :]
+            if rotary:
+                k_r = rope(kv[..., None, self.kv_lora_rank:], positions,
+                           self.rope_theta)[..., 0, :]
+            else:
+                k_r = kv[..., self.kv_lora_rank:]
             return c_q, jnp.concatenate([c_kv, k_r], axis=-1)
 
-    def _queries(self, lp, c_q, positions):
-        """(q_nope [..., heads, nope], RoPE(q_rope) [..., heads, rope])."""
-        q = jnp.dot(c_q, lp["wq_b"].astype(self.compute_dtype)).reshape(
+    def _wq(self, lp):
+        """The weights that make the queries of `_latents`' c_q."""
+        return lp["wq_b" if self.q_lora_rank else "wq"]
+
+    def _queries(self, lp, c_q, positions, rotary=True):
+        """(q_nope [..., heads, nope], q_rope [..., heads, rope], rotated
+        where `rotary`)."""
+        q = jnp.dot(c_q, self._wq(lp).astype(self.compute_dtype)).reshape(
             c_q.shape[:-1] + (self.num_heads, -1))
         nope = self.qk_nope_head_dim
-        return q[..., :nope], rope(q[..., nope:], positions, self.rope_theta)
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        if rotary:
+            q_rope = rope(q_rope, positions, self.rope_theta)
+        return q_nope, q_rope
+
+    def _latent_key_width(self, T: int) -> int:
+        """The width a latent layer's decompressed queries and keys have in
+        a causal pass over `T` positions: nope + rope, or, where that is
+        no width the fused form takes and the next whole lane tile is (192:
+        256), that, the rest of a head zeros: q . k is what it was, and a
+        pass that would write [T, T] scores runs the kernel instead."""
+        width = self.qk_nope_head_dim + self.qk_rope_head_dim
+        padded = width + -width % 128
+        fits = causal_fused(T, width, self.v_head_dim)
+        return padded if not fits and causal_fused(
+            T, padded, self.v_head_dim) else width
 
     def _wkv_b(self, lp):
         """W_kvb by head: (W_UK [c, heads, nope], W_UV [c, heads, v])."""
@@ -1279,17 +1742,18 @@ class TokenDecoder(nn.Module):
         # are made from the latents for every position, the one rotary
         # key copied to every head's.
         n = rms_norm(x, lp["attn_norm"], eps, cd)
-        c_q, latent = self._latents(lp, n, positions)
+        c_q, latent = self._latents(lp, n, positions, rotary)
         with jax.named_scope("policy/mla_latent"):
             caches = (jnp.take_along_axis(
                 latent, cache_rows[:, :, None], axis=1),)
         with jax.named_scope("policy/mla_expand"):
             nope = self.qk_nope_head_dim
-            q = by_head(c_q, lp["wq_b"])
-            q = jnp.concatenate([
-                q[..., :nope], rope(q[..., nope:], positions,
-                                    self.rope_theta, head_major=True)],
-                axis=-1)
+            q = by_head(c_q, self._wq(lp))
+            if rotary:
+                q = jnp.concatenate([
+                    q[..., :nope], rope(q[..., nope:], positions,
+                                        self.rope_theta, head_major=True)],
+                    axis=-1)
             w_uk, w_uv = self._wkv_b(lp)
             c_kv, k_r = (latent[..., :self.kv_lora_rank],
                          latent[..., self.kv_lora_rank:])
@@ -1298,6 +1762,11 @@ class TokenDecoder(nn.Module):
                     k_r[:, None], (k_r.shape[0], heads) + k_r.shape[1:])],
                 axis=-1)
             v = by_head(c_kv, w_uv)
+            # Zeros up to a width the fused form takes, where there is one.
+            spare = self._latent_key_width(q.shape[2]) - q.shape[3]
+            if spare:
+                q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, spare),))
+                        for a in (q, k))
         with jax.named_scope("policy/mla_attend"):
             o = causal_attention(
                 q, k, v, episode,
@@ -1342,11 +1811,11 @@ class TokenDecoder(nn.Module):
         # lie.
         (cache,) = caches
         n = rms_norm(x, lp["attn_norm"], eps, cd)
-        c_q, latent = self._latents(lp, n, pos)
+        c_q, latent = self._latents(lp, n, pos, rotary)
         with jax.named_scope("policy/mla_latent"):
             cache = cache.at[rows, pos].set(latent)
         with jax.named_scope("policy/mla_expand"):
-            q_nope, q_rope = self._queries(lp, c_q, pos)
+            q_nope, q_rope = self._queries(lp, c_q, pos, rotary)
             w_uk, w_uv = self._wkv_b(lp)
             q = jnp.concatenate(
                 [jnp.einsum("bhd,chd->bhc", q_nope, w_uk), q_rope], axis=-1)
@@ -1377,23 +1846,12 @@ class TokenDecoder(nn.Module):
         last episode's last L - 1 gated inputs [B, L - 1, H], 0 where the
         episode is shorter). The taps are multiplied and summed in
         float32: elementwise work beside the two projections."""
-        cd, L = self.compute_dtype, self.conv_taps
-        T = x.shape[1]
+        cd = self.compute_dtype
         with jax.named_scope("policy/short_conv"):
             g, c = self._conv_gates(lp, x)
-            w = lp["conv_w"].astype(jnp.float32)
-            # back[:, L - 1 - s + t] = g_{t - s}, zeros before the fragment.
-            back = jnp.pad(g, ((0, 0), (L - 1, 0), (0, 0)))
-            v = jnp.zeros(g.shape, jnp.float32)
-            for j in range(L):
-                s = L - 1 - j
-                v = v + w[:, j] * jnp.where(
-                    (positions >= s)[..., None], back[:, j:j + T],
-                    0).astype(jnp.float32)
+            v, back = _taps_causal(g, lp["conv_w"], positions)
             h = x + jnp.dot(c * v.astype(cd), lp["conv_out"].astype(cd))
-            # Slot j of the state is g_{T - (L - 1) + j}.
-            held = positions[:, -1:] >= (L - 2 - jnp.arange(L - 1))
-            state = jnp.where(held[..., None], back[:, T:], 0)
+            state = _taps_tail(back, positions)
         return h, state
 
     def _conv_step(self, lp, x, state, reset):
@@ -1403,12 +1861,82 @@ class TokenDecoder(nn.Module):
         cd = self.compute_dtype
         with jax.named_scope("policy/short_conv"):
             g, c = self._conv_gates(lp, x)
-            state = jnp.where((reset > 0)[:, None, None], 0, state)
-            taps = jnp.concatenate([state, g[:, None]], axis=1)
-            v = jnp.sum(taps.astype(jnp.float32)
-                        * lp["conv_w"].astype(jnp.float32).T, axis=1)
+            v, taps = _taps_step(g, lp["conv_w"], state, reset)
             h = x + jnp.dot(c * v.astype(cd), lp["conv_out"].astype(cd))
         return h, taps[:, 1:]
+
+    # -- Kimi Delta Attention, both forms -----------------------------------
+    def _kda_inputs(self, lp, x):
+        """(n = RMSNorm(x), [q~ | k~ | v~] = n W_qkv ahead of their
+        convolutions) of rows x [.., H]."""
+        cd = self.compute_dtype
+        n = rms_norm(x, lp["attn_norm"], self.rms_eps, cd)
+        return n, jnp.dot(n, lp["kda_qkv"].astype(cd))
+
+    def _kda_heads(self, lp, n, mixed):
+        """(q, k, v, g, beta) by head of rows' normalised input n and their
+        convolved projections `mixed` [.., 3 P] (float32, ahead of the
+        activation): q, k, v = silu(.), q and k of unit length a head (eps
+        1e-6 under the root) and q times d_k^-1/2, made in float32 and
+        kept in `compute_dtype`; float32, the LOG decay a channel g =
+        -exp(A_log) softplus((n W_fa) W_fb + dt_bias) <= 0 and beta =
+        sigmoid(n W_b)."""
+        cd, f32, d = self.compute_dtype, jnp.float32, self.kda_head_dim
+        by_head = n.shape[:-1] + (-1, d)
+        q, k, v = (a.reshape(by_head)
+                   for a in jnp.split(jax.nn.silu(mixed), 3, axis=-1))
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        rate = jnp.dot(jnp.dot(n, lp["kda_fa"].astype(cd)),
+                       lp["kda_fb"].astype(cd)).astype(f32)
+        g = -jnp.exp(lp["kda_a_log"])[:, None] * jax.nn.softplus(
+            rate + lp["kda_dt_bias"]).reshape(by_head)
+        beta = jax.nn.sigmoid(
+            jnp.dot(n, lp["kda_b"].astype(cd)).astype(f32))
+        return ((unit(q) * d ** -0.5).astype(cd), unit(k).astype(cd),
+                v.astype(cd), g, beta)
+
+    def _kda_output(self, lp, x, n, o):
+        """x + (RMSNorm_head(o) * sigmoid((n W_ga) W_gb)) W_out for a
+        head's outputs o [.., heads, d_v]: the norm over each head's own
+        values, one weight [d_v]."""
+        cd = self.compute_dtype
+        gate = jax.nn.sigmoid(jnp.dot(
+            jnp.dot(n, lp["kda_ga"].astype(cd)),
+            lp["kda_gb"].astype(cd)).astype(jnp.float32))
+        o = rms_norm(o, lp["kda_o_norm"], self.rms_eps, jnp.float32)
+        o = (o.reshape(gate.shape) * gate).astype(cd)
+        return x + jnp.dot(o, lp["kda_out"].astype(cd))
+
+    def _kda_causal(self, lp, x, positions, episode):
+        """x + KDA(RMSNorm(x)) over a fragment [B, T, H] from empty states
+        (`kda_chunked`); (h, (the three convolutions' last taps - 1 inputs
+        [B, taps - 1, 3 P], the matrix state after the last position
+        [B, heads, d_k, d_v] float32): what a decode continues from)."""
+        with jax.named_scope("policy/kda"):
+            n, mixed = self._kda_inputs(lp, x)
+            mixed, back = _taps_causal(mixed, lp["kda_conv"], positions)
+            q, k, v, g, beta = self._kda_heads(lp, n, mixed)
+            o, S = kda_chunked(q, k, v, g, beta, episode, self.kda_chunk,
+                               self.compute_dtype)
+            return self._kda_output(lp, x, n, o), (
+                _taps_tail(back, positions), S)
+
+    def _kda_step(self, lp, x, tails, S, reset):
+        """The same of one token a row, x [B, H], against the row's
+        states, zeroed first where `reset` (`kda_step`); (h, the
+        convolutions' inputs with this one appended and the oldest dropped,
+        the matrix state)."""
+        with jax.named_scope("policy/kda"):
+            n, mixed = self._kda_inputs(lp, x)
+            mixed, taps = _taps_step(mixed, lp["kda_conv"], tails, reset)
+            q, k, v, g, beta = self._kda_heads(lp, n, mixed)
+            with jax.named_scope("policy/kda_state"):
+                S = jnp.where((reset > 0)[:, None, None, None], 0.0, S)
+                o, S = kda_step(S, q, k, v, g, beta)
+            return self._kda_output(lp, x, n, o), taps[:, 1:], S
 
     # -- feed-forward -----------------------------------------------------
     def _route(self, lp, n):
@@ -1525,10 +2053,12 @@ class TokenDecoder(nn.Module):
 
         def block(lp, x, kind=(0, True)):
             """One layer; `caches` are its caches, or the convolution's
-            state."""
+            state, or KDA's two."""
             routing = self._route_ahead(lp, x)
             if kind == "conv":
                 h, caches = self._conv_causal(lp, x, positions)
+            elif kind == "kda":
+                h, caches = self._kda_causal(lp, x, positions, episode)
             else:
                 rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
                 h, caches = self._attend_causal(
@@ -1546,12 +2076,15 @@ class TokenDecoder(nn.Module):
                     CAUSAL_KEPT), static_argnums=(2,))
 
         x = self.embed[tokens].astype(cd)
-        kv, conv, loads, experts = [], [], [], []
+        kv, conv, kda, loads, experts = [], [], [], [], []
         for i, layer in enumerate(self.layers):
             kind = self.layer_kind(i)
             x, caches, group_sizes, top_i = block(layer(), x, kind)
-            kv.append(() if kind == "conv" else caches)
-            conv.append(caches if kind == "conv" else ())
+            if kind == "kda":
+                caches, S = caches
+            kv.append(() if isinstance(kind, str) else caches)
+            conv.append(caches if isinstance(kind, str) else ())
+            kda.append(S if kind == "kda" else ())
             if top_i is not None:
                 loads.append(group_sizes)
                 experts.append(top_i.reshape(B, T, -1))
@@ -1564,7 +2097,7 @@ class TokenDecoder(nn.Module):
         self._count(experts, loads)
         logits, value = self._heads(x)
         return logits, value, self._policy_state(
-            kv, conv, positions[:, -1] + 1)
+            kv, conv, kda, positions[:, -1] + 1)
 
     def _next_next_token(self, block, x, tokens, episode):
         """The module's loss over a fragment (see the module docstring),
@@ -1605,19 +2138,23 @@ class TokenDecoder(nn.Module):
     def decode(self, token, state, reset):
         pos = jnp.where(reset > 0, 0, state["pos"])
         x = self.embed[token].astype(self.compute_dtype)
-        kv, conv, experts, reads = [], [], [], {}
+        kv, conv, kda, experts, reads = [], [], [], [], {}
         for i, (layer, caches) in enumerate(zip(self.layers, state["kv"])):
             lp = layer()
             kind = self.layer_kind(i)
             routing = self._route_ahead(lp, x)
+            held, S = (), ()
             if kind == "conv":
                 h, held = self._conv_step(lp, x, state["conv"][i], reset)
+            elif kind == "kda":
+                h, held, S = self._kda_step(
+                    lp, x, state["conv"][i], state["kda"][i], reset)
             else:
                 h, caches, reads[i] = self._attend_step(
                     lp, x, pos, caches, *kind)
-                held = ()
             kv.append(caches)
             conv.append(held)
+            kda.append(S)
             x, _, top_i = self._feed_forward(lp, h, routing)
             if top_i is not None:
                 experts.append(top_i)
@@ -1626,7 +2163,7 @@ class TokenDecoder(nn.Module):
                 module()
         self._count(experts, reads=reads)
         logits, value = self._heads(x)
-        return logits, value, self._policy_state(kv, conv, pos + 1)
+        return logits, value, self._policy_state(kv, conv, kda, pos + 1)
 
 
 def _refuse_unknown(cfg: dict, known, family: str) -> None:
@@ -1742,6 +2279,52 @@ def lfm2_moe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
     # The sum of the chosen scores is never 0 in the source's division.
     fields.update(qk_norm="head", selection_bias=True, tie_embeddings=True,
                   topk_eps=1e-6)
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def kimi_linear_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks
+    `kimi_linear`'s published `config.json`'s own keys (a key left out has
+    Kimi-Linear-48B-A3B's value), and the three that state the deployment
+    (the chip's share of the experts; the positions in a chunk of the
+    learner's scan); unknown keys are refused, and so is a published key
+    whose value the decoder has no part for (`num_expert_group` > 1, a
+    next-token module, a query latent, rotated latent attention). The
+    family's parts: an operator a layer by `linear_attn_config`'s two
+    lists of 1-indexed layers (those up to `num_hidden_layers` are read),
+    Kimi Delta Attention or latent attention without a query latent and
+    without positions; `first_k_dense_replace` leading dense layers;
+    sigmoid scores with a selection bias in one group, renormalised over
+    their sum + 1e-20 and scaled; one shared expert; an untied head."""
+    known = (set(KIMI_LINEAR_CONFIG_KEYS) | set(KIMI_LINEAR_FIXED)
+             | {"linear_attn_config", "num_key_value_heads", "head_dim"})
+    _refuse_unknown(cfg, known, "kimi_linear")
+    _refuse_grouped_heads(cfg, "kimi_linear", 32)
+    _refuse_other_values(cfg, KIMI_LINEAR_FIXED)
+    merged = {**KIMI_LINEAR_PUBLISHED, **cfg}
+    fields = {KIMI_LINEAR_CONFIG_KEYS[k]: v for k, v in merged.items()
+              if k in KIMI_LINEAR_CONFIG_KEYS}
+    linear = {**KIMI_LINEAR_PUBLISHED["linear_attn_config"],
+              **cfg.get("linear_attn_config", {})}
+    _refuse_unknown(linear, KIMI_LINEAR_PUBLISHED["linear_attn_config"],
+                    "kimi_linear's linear_attn_config")
+    kinds = {**{i: "full_attention" for i in linear["full_attn_layers"]},
+             **{i: "kda" for i in linear["kda_layers"]}}
+    layers = range(1, fields["num_layers"] + 1)
+    if set(linear["full_attn_layers"]) & set(linear["kda_layers"]) or any(
+            i not in kinds for i in layers):
+        raise ValueError(
+            f"linear_attn_config names each of the {fields['num_layers']} "
+            f"layers once: kda_layers {linear['kda_layers']}, "
+            f"full_attn_layers {linear['full_attn_layers']}")
+    layer_types = tuple(kinds[i] for i in layers)
+    fields.update(
+        layer_types=layer_types, rope_layout=(False,) * len(layer_types),
+        kda_heads=linear["num_heads"], kda_head_dim=linear["head_dim"],
+        kda_taps=linear["short_conv_kernel_size"], selection_bias=True,
+        topk_eps=1e-20)
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=num_outputs, **fields)
