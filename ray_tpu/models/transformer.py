@@ -136,8 +136,10 @@ them, computes the chosen ones it holds and leaves out what the absent ones
 would add; that partial sum goes on, as on one chip of an expert-parallel
 stage without its exchange. One sum, two blockings, chosen from the static
 shape (`experts_batched`). Grouped: the (row, expert) pairs sorted by
-expert, absent experts' pairs last, multiplied group by group
-(`jax.lax.ragged_dot`) and un-sorted; the learner's minibatch and a prefill.
+expert, absent experts' pairs last, the landed ones' rows (up to a static
+size chosen from the landed count, `dispatch_rows`) multiplied group by
+group (`jax.lax.ragged_dot`) and added to their rows of the sum; the
+learner's minibatch and a prefill.
 Batched: every row through every held expert in products batched over the
 experts, each term weighted w_e or exactly 0 before the sum; a decode step,
 whose groups of a few rows would each cost the grouped product an MXU tile
@@ -1097,8 +1099,46 @@ def experts_batched(M: int, k: int, E: int) -> bool:
     that holds a share of the experts asks the same question of the same
     numbers: with `held` of them here the batched form is `M * held` rows
     of work and the grouped one `held` groups of `M * k * held / E`
-    expected rows, the same inequality times `held / E`."""
+    expected rows, the same inequality times `held / E`. (The grouped form
+    of a share works on the rows that landed, by `dispatch_rows`, so its
+    side of the inequality is what it costs, not a worst case.)"""
     return M * E <= GROUP_COST_ROWS * E + GROUPED_ROW_COST * M * k
+
+
+# What the grouped form of a layer that holds a share of the experts
+# compiles: row counts, as multiples of the expected number of (row, expert)
+# pairs that land here, `M * k * held / E`, rounded up to whole tiles of
+# DISPATCH_TILE rows; the whole `M * k` is always the last, so no pair is
+# ever dropped. Every size is one more branch of a `switch` that Python
+# traces on every start. On a v5e at the four cells' shapes (PERF.md section
+# 5) a layer's forward and backward cost 4-9 ms + 0.2-0.4 ms a thousand
+# rows, so a size twice too long costs a seventh to a quarter more and the
+# steps need not be fine; at random weights a layer lands 0.45-1.9 times
+# the expected count and one (Kimi's first expert layer) 2.2-2.6 times.
+DISPATCH_MULTIPLES = (1.25, 2.0, 4.0)
+DISPATCH_TILE = 128
+
+
+def dispatch_rows(M: int, k: int, held: int, E: int) -> tuple:
+    """The sorted row counts R that the grouped form of `M` rows, each
+    routed to `k` of `E` experts of which `held` are here, is compiled
+    for, ascending: a function of the static shape alone. A pass takes the
+    first that holds the pairs that landed (`dispatch_index`); the last is
+    `M * k`, and the only one where every expert is here or the shape
+    takes the batched form, which gathers no rows."""
+    pairs = M * k
+    if held == E or experts_batched(M, k, E):
+        return (pairs,)
+    expected = pairs * held / E
+    sizes = {-(-int(m * expected) // DISPATCH_TILE) * DISPATCH_TILE
+             for m in DISPATCH_MULTIPLES}
+    return tuple(sorted({R for R in sizes if R < pairs} | {pairs}))
+
+
+def dispatch_index(count, sizes):
+    """Which of `sizes` (`dispatch_rows`) a layer on which `count` pairs
+    landed takes: the first that holds them."""
+    return sum((count > R).astype(jnp.int32) for R in sizes[:-1])
 
 
 def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
@@ -1108,18 +1148,24 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     the experts held here: `first` .. `first + E - 1`, whose weights
     [E, H, W] / [E, W, H] are given in n's dtype (all of them where
     `num_experts` is not given). What an absent expert would add is left
-    out. Returns ([M, H], rows a held group [E]).
+    out. Returns ([M, H], rows a held group [E], the sorted rows that
+    were gathered).
 
     Two forms of that sum, chosen by `experts_batched(M, k,
     num_experts)`; both take operands in n's dtype, accumulate in float32,
     weight in float32 and compute every chosen held expert of every row.
 
     Grouped: the M*k (row, expert) pairs sorted by expert, those of absent
-    experts last and in no group, three `ragged_dot`s over the E groups,
-    un-sorted, the k terms of a row weighted (an absent one by nothing:
-    its rows of the products are made 0 on the way in and on the way out,
-    in both passes: `landed`) and summed. Shapes are static, so all M*k
-    sorted rows are gathered, as if every pair landed here.
+    experts last and in no group; the first R sorted pairs' rows gathered,
+    three `ragged_dot`s over the E groups, and each output row weighted
+    and scatter-added to its row of the float32 sum. Where every expert
+    is here R is M*k. A layer that holds a share gathers, multiplies and
+    adds the pairs that landed on it: R is the first of `dispatch_rows`'
+    static sizes that holds the landed count (a `switch` around
+    everything after the sort); the last size is M*k, so no pair is
+    dropped, whatever the router does. The rows between the landed count
+    and R are in no group; they are made 0 on the way into the products
+    and on the way out, in both passes (`landed`), and add nothing.
 
     Batched: c[m, e] = p[m, j] where top_i[m, j] == first + e, else 0;
     a[e, m] = silu(n W_gate,e) * (n W_up,e) for all M rows and every held
@@ -1148,35 +1194,85 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             a = (c.T[:, :, None] * (act(gate) * up)).astype(n.dtype)
             mixed = jnp.einsum("emw,ewh->mh", a, w_down,
                                preferred_element_type=jnp.float32)
-        return mixed.astype(n.dtype), group_sizes
-    def landed(x):
-        """Sorted rows [M*k, ..] with those past the last group made 0,
-        and their cotangents with them. A `ragged_dot` computes no row
-        that is in no group, and neither do its transposes: XLA:CPU
-        leaves 0 there, XLA:TPU whatever the memory held, so what the
-        backward pass handed back for the absent pairs was not 0 until
-        it was made so (a v5e, the cell's minibatch: gradients 35 times
-        the reference's norm and unrelated to them; PERF.md section 6).
-        A select, so that nothing that memory held can reach a product."""
-        if not share:
-            return x
-        return jnp.where(
-            (jnp.arange(M * k) < jnp.sum(group_sizes))[:, None], x, 0)
+        return mixed.astype(n.dtype), group_sizes, jnp.int32(M * k)
+
+    def grouped(R, n, top_p, w_gate, w_up, w_down, order, count,
+                group_sizes):
+        """The sum over the first R sorted pairs, [M, H] float32."""
+        def landed(x):
+            """Sorted rows [R, ..] with those past the last group made 0,
+            and their cotangents with them. A `ragged_dot` computes no row
+            that is in no group, and neither do its transposes: XLA:CPU
+            leaves 0 there, XLA:TPU whatever the memory held, so what the
+            backward pass handed back for the absent pairs was not 0 until
+            it was made so (a v5e, the cell's minibatch: gradients 35 times
+            the reference's norm and unrelated to them; PERF.md section 6).
+            A select, so that nothing that memory held can reach a
+            product."""
+            if not share:
+                return x
+            return jnp.where((jnp.arange(R) < count)[:, None], x, 0)
+
+        with jax.named_scope("policy/dispatch"):
+            pairs = order[:R]
+            source = pairs // k
+            rows = landed(n[source])
+        with jax.named_scope("policy/experts"):
+            gate = landed(jax.lax.ragged_dot(rows, w_gate, group_sizes))
+            up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
+            out = jax.lax.ragged_dot(landed(act(gate) * up), w_down,
+                                     group_sizes)
+        with jax.named_scope("policy/dispatch"):
+            weighted = landed(out).astype(jnp.float32) \
+                * top_p.reshape(-1)[pairs][:, None]
+            return jnp.zeros((M, n.shape[1]), jnp.float32).at[source].add(
+                weighted)
 
     with jax.named_scope("policy/dispatch"):
         order = jnp.argsort(local.reshape(-1), stable=True)
-        rows = landed(n[order // k])
-    with jax.named_scope("policy/experts"):
-        gate = landed(jax.lax.ragged_dot(rows, w_gate, group_sizes))
-        up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
-        out = jax.lax.ragged_dot(landed(act(gate) * up), w_down,
-                                 group_sizes)
-    with jax.named_scope("policy/dispatch"):
-        unsorted = out[jnp.argsort(order)].reshape(M, k, -1)
-        if share:
-            unsorted = jnp.where(here[:, :, None], unsorted, 0)
-        mixed = jnp.einsum("mkh,mk->mh", unsorted.astype(jnp.float32), top_p)
-    return mixed.astype(n.dtype), group_sizes
+        count = jnp.sum(group_sizes)
+    sizes = dispatch_rows(M, k, E, num_experts or E)
+    floats, whole = (n, top_p, w_gate, w_up, w_down), \
+        (order, count, group_sizes)
+    if len(sizes) == 1:
+        return (grouped(M * k, *floats, *whole).astype(n.dtype),
+                group_sizes, jnp.int32(M * k))
+
+    # The size is a branch of a `switch`, and the backward pass another
+    # `switch` over the sizes' pullbacks, each its forward again and then
+    # its backward, from the operands. Left to differentiate the `switch`,
+    # JAX hands the backward pass the union of the branches' residuals,
+    # those of the branches not taken filled with zeros: as many bytes as
+    # the whole size makes, whichever ran (a v5e, the four cells' shapes:
+    # 2.2-4.4 ms a layer and update, of 4.3-13.9); and with each branch
+    # under `jax.checkpoint` a third conditional that copies the operands
+    # (the three weight tensors among them) to its outputs, to be the
+    # residuals. The branches hand back the float32 sum, so that the
+    # cotangent comes in as it would without them.
+    @jax.custom_vjp
+    def taken(index, *operands):
+        return jax.lax.switch(
+            index, [functools.partial(grouped, R) for R in sizes], *operands)
+
+    def pullback(R):
+        def pull(cotangent, n, top_p, w_gate, w_up, w_down, *whole):
+            return jax.vjp(lambda *floats: grouped(R, *floats, *whole),
+                           n, top_p, w_gate, w_up, w_down)[1](cotangent)
+        return pull
+
+    def backward(operands, cotangent):
+        index, *operands = operands
+        # Behind a barrier, or XLA moves the weight gradients' conversion
+        # to float32 into the branches, and every expert layer's are held
+        # at twice their bytes until the optimizer reads them.
+        return (None, *jax.lax.optimization_barrier(jax.lax.switch(
+            index, [pullback(R) for R in sizes], cotangent, *operands)),
+            *(None for _ in whole))
+
+    taken.defvjp(lambda *operands: (taken(*operands), operands), backward)
+    index = dispatch_index(count, sizes)
+    return (taken(index, *floats, *whole).astype(n.dtype), group_sizes,
+            jnp.asarray(sizes, jnp.int32)[index])
 
 
 # The router's selection bias at initialisation: the published model's is
@@ -1954,8 +2050,9 @@ class TokenDecoder(nn.Module):
         return self._route(lp, n.reshape(-1, n.shape[-1]))
 
     def _feed_forward(self, lp, h, routing=None):
-        """h + FeedForward(RMSNorm(h)) for rows h [M, H]; (out, rows a held
-        group, experts [M, k]), the last two None of a dense layer.
+        """h + FeedForward(RMSNorm(h)) for rows h [M, H]; (out, (rows a
+        held group, sorted rows gathered), experts [M, k]), the last two
+        None of a dense layer.
         `routing`: (weights, experts) chosen ahead of the attention."""
         cd = self.compute_dtype
         act = ACTIVATIONS[self.hidden_act]
@@ -1966,7 +2063,7 @@ class TokenDecoder(nn.Module):
                     "dense_gate", "dense_up", "dense_down")),
                     act=act), None, None
         top_p, top_i = routing or self._route(lp, n)
-        moe, group_sizes = dropless_experts(
+        moe, *load = dropless_experts(
             n, top_p, top_i, lp["w_gate"].astype(cd), lp["w_up"].astype(cd),
             lp["w_down"].astype(cd), self.first_expert_held,
             self.num_experts, act)
@@ -1974,7 +2071,7 @@ class TokenDecoder(nn.Module):
             with jax.named_scope("policy/shared_expert"):
                 moe = moe + swiglu(n, *(lp[w].astype(cd) for w in (
                     "shared_gate", "shared_up", "shared_down")), act=act)
-        return h + moe, group_sizes, top_i
+        return h + moe, tuple(load), top_i
 
     def _heads(self, x):
         with jax.named_scope("policy/head"):
@@ -1990,10 +2087,14 @@ class TokenDecoder(nn.Module):
         """What a pass counted, kept only where the caller asks for the
         collection (and never among the variables `init` returns): the
         experts chosen [expert layers, ..., k], for the reference check;
-        in the learner's form also the rows of the fullest held expert
+        in the learner's form (`loads`: an expert layer's load as
+        `_feed_forward` gives it) also the rows of the fullest held expert
         group over the layers and the mean group, and where the layer
         holds a share, the share of the (row, expert) pairs that landed
-        here; in a decode step the share of the context's positions its
+        here and the share that its product gathered (the rows that
+        `dropless_experts` reports over `M k`, the mean over the expert
+        layers; 1.0: the whole size, or the batched form, which sorts
+        none); in a decode step the share of the context's positions its
         attention read, the mean over the attention layers (`reads`:
         {layer: the positions it read}), and where the model has window
         layers the same of its full layers and of its window layers
@@ -2003,13 +2104,16 @@ class TokenDecoder(nn.Module):
         if experts:
             self.sow("routing", "experts", jnp.stack(experts))
         if loads:
-            loads = jnp.stack(loads).astype(jnp.float32)
+            loads, gathered = (jnp.stack(part).astype(jnp.float32)
+                               for part in zip(*loads))
             self.sow("counters", "expert_load_max", jnp.max(loads))
             self.sow("counters", "expert_load_mean", jnp.mean(loads))
             if self.held != self.num_experts:
                 pairs = experts[0].size
                 self.sow("counters", "experts_held_row_share",
                          jnp.mean(jnp.sum(loads, axis=-1)) / pairs)
+                self.sow("counters", "dispatch_rows_share",
+                         jnp.mean(gathered) / pairs)
         window = [bool(self.layer_kind(i)[0]) for i in reads or ()]
         reads = list((reads or {}).values())
         if reads and not (any(window) and not all(window)):
@@ -2063,9 +2167,9 @@ class TokenDecoder(nn.Module):
                 rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
                 h, caches = self._attend_causal(
                     lp, x, positions, episode, rows, *kind)
-            out, group_sizes, top_i = self._feed_forward(
+            out, load, top_i = self._feed_forward(
                 lp, h.reshape(B * T, -1), routing)
-            return out.reshape(B, T, -1), caches, group_sizes, top_i
+            return out.reshape(B, T, -1), caches, load, top_i
         if self.num_layers + self.nextn_layers > 1:
             # Recomputed in the backward pass, but for what the fused
             # attention keeps (its output and log-sum-exp: 85 MB a block
@@ -2079,20 +2183,20 @@ class TokenDecoder(nn.Module):
         kv, conv, kda, loads, experts = [], [], [], [], []
         for i, layer in enumerate(self.layers):
             kind = self.layer_kind(i)
-            x, caches, group_sizes, top_i = block(layer(), x, kind)
+            x, caches, load, top_i = block(layer(), x, kind)
             if kind == "kda":
                 caches, S = caches
             kv.append(() if isinstance(kind, str) else caches)
             conv.append(caches if isinstance(kind, str) else ())
             kda.append(S if kind == "kda" else ())
             if top_i is not None:
-                loads.append(group_sizes)
+                loads.append(load)
                 experts.append(top_i.reshape(B, T, -1))
         if self.nextn and (self.is_initializing()
                            or self.is_mutable_collection("losses")):
-            group_sizes, top_i = self._next_next_token(
+            load, top_i = self._next_next_token(
                 block, x, tokens, episode)
-            loads.append(group_sizes)
+            loads.append(load)
             experts.append(top_i.reshape(B, T, -1))
         self._count(experts, loads)
         logits, value = self._heads(x)
@@ -2104,7 +2208,8 @@ class TokenDecoder(nn.Module):
         into "losses" (weighted, a sum over the positions, as the
         objective's other terms), "counters" (`mtp_loss`, a position's
         mean) and "routing" (position by position, for the reference
-        check); (rows a held group, experts) of its expert layer."""
+        check); (its expert layer's load, as `_feed_forward` gives it,
+        experts)."""
         cd, eps = self.compute_dtype, self.rms_eps
         T = tokens.shape[1]
         (module,) = self.nextn
@@ -2119,7 +2224,7 @@ class TokenDecoder(nn.Module):
                 rms_norm(jax.lax.stop_gradient(x), lp["hnorm"], eps, cd),
                 rms_norm(embed[following], lp["enorm"], eps, cd)], axis=-1),
                 lp["eh_proj"].astype(cd))
-        z, _, group_sizes, top_i = block(lp, z, (0, True))
+        z, _, load, top_i = block(lp, z, (0, True))
         with jax.named_scope("policy/mtp"):
             y = rms_norm(z, lp["final_norm"], eps, jnp.float32)
             logp = jax.nn.log_softmax(
@@ -2133,7 +2238,7 @@ class TokenDecoder(nn.Module):
                      self.nextn_loss_weight * nll)
             self.sow("counters", "mtp_loss",
                      nll / jnp.maximum(jnp.sum(valid), 1))
-        return group_sizes, top_i
+        return load, top_i
 
     def decode(self, token, state, reset):
         pos = jnp.where(reset > 0, 0, state["pos"])

@@ -193,3 +193,40 @@ def kernel_here(monkeypatch):
     monkeypatch.setattr(
         jax.lax, "platform_dependent",
         lambda *args, tpu, default: tpu(*args))
+
+
+@pytest.fixture
+def grouped_pass_is_the_batched_pass(monkeypatch):
+    """A check for a token model that holds a share of its experts
+    (`model, variables, tokens`, float32): its causal pass made to take the
+    grouped form at the tests' sizes, in tiles of 4 rows, gathers a short
+    size's rows in some layer, counts them (`dispatch_rows_share`, between
+    what landed and 1), and gives the logits, values and parameter
+    gradients of the batched form, which gathers none."""
+    import jax.numpy as jnp
+    from ray_tpu.models import transformer
+
+    def check(model, variables, tokens):
+        def run():
+            def loss(params):
+                (logits, values, _), kept = model.apply(
+                    dict(variables, params=params), tokens, None,
+                    jnp.zeros(tokens.shape), mutable=["counters"])
+                return jnp.sum(jnp.sin(logits)) + jnp.sum(values), (
+                    logits, values, kept["counters"])
+            (_, (logits, values, counted)), grads = jax.value_and_grad(
+                loss, has_aux=True)(variables["params"])
+            return (logits, values, grads), {
+                k: float(v[-1]) for k, v in counted.items()}
+        want, counted = run()
+        assert counted["dispatch_rows_share"] == 1.0
+        monkeypatch.setattr(transformer, "GROUP_COST_ROWS", 0)
+        monkeypatch.setattr(transformer, "GROUPED_ROW_COST", 0.0)
+        monkeypatch.setattr(transformer, "DISPATCH_TILE", 4)
+        got, counted = run()
+        assert counted["experts_held_row_share"] \
+            <= counted["dispatch_rows_share"] < 1.0
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            scale = float(jnp.max(jnp.abs(w))) + 1e-8
+            assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * scale
+    return check

@@ -264,7 +264,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         routed, landed = jnp.zeros_like(rows), 0
         for first in range(0, E, held):
             s = share_of(lp, first)
-            part, sizes = dropless_experts(
+            part, sizes, _ = dropless_experts(
                 rows, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E)
             routed, landed = routed + part, landed + int(jnp.sum(sizes))
         assert landed == M * k  # every pair lands on exactly one share
@@ -326,7 +326,7 @@ def test_held_expert_dispatch_equals_dense_masked_loop(routing, form, dtype):
     whatever lands here (everything: the static worst case; nothing)."""
     case = held_case(routing, form, dtype)
     n, top_p, top_i, *weights, first, E = case
-    got, group_sizes = dropless_experts(*case)
+    got, group_sizes, _ = dropless_experts(*case)
     want = held_masked_loop(*case)
     assert got.dtype == n.dtype and group_sizes.shape == (3,)
     here = (np.asarray(top_i) >= first) & (np.asarray(top_i) < first + 3)
@@ -412,6 +412,101 @@ def test_grouped_form_reads_no_row_that_no_product_computed(
         assert bool(jnp.all(jnp.isfinite(g)))
         scale = float(jnp.max(jnp.abs(w))) or 1.0
         assert reference.relative_error(g, w, scale=scale) <= 2e-4
+
+
+# The grouped shape's ladder (4,096 rows x 2 of 8 experts, 3 held: 3,072
+# pairs expected, 8,192 in all) and how many pairs the routing lands here:
+# nothing, under the shortest size, on each size's edge and one over it,
+# every pair.
+LADDER = (3840, 6144, 8192)
+LANDED = {"nothing": (0, 3840), "under_the_shortest": (3000, 3840),
+          "on_the_first_edge": (3840, 3840),
+          "one_over_the_first": (3841, 6144),
+          "on_the_second_edge": (6144, 6144),
+          "one_over_the_second": (6145, 8192), "every_pair": (8192, 8192)}
+# The same with one short size, (3840, 8192).
+OF_TWO = {"on_the_edge_of_two": (3840, 3840), "one_over_of_two": (3841, 8192),
+          "most_of_two": (7681, 8192), "every_pair_of_two": (8192, 8192)}
+
+
+def landing(count):
+    """`held_case`'s grouped shape with a routing built so that exactly
+    `count` of its 8,192 (row, expert) pairs land on experts 2-4, a row's
+    two experts distinct, the rows shuffled."""
+    n, top_p, _, *rest = held_case("random", "grouped", "f32")
+    M = n.shape[0]
+    rng = np.random.default_rng(count)
+    first = np.arange(M) < min(count, M)
+    second = np.arange(M) < count - M
+    top_i = np.stack([
+        np.where(first, rng.integers(2, 4, M), rng.integers(0, 2, M)),
+        np.where(second, 4, rng.integers(5, 8, M))], 1)
+    return (n, top_p, jnp.asarray(rng.permutation(top_i), jnp.int32), *rest)
+
+
+def test_the_ladder_is_a_function_of_the_static_shape():
+    """Multiples of the expected landed count in whole tiles, the whole
+    last; one size where every expert is here, where the shape takes the
+    batched form, and where the first multiple already reaches M k."""
+    rows = transformer.dispatch_rows
+    assert rows(4096, 2, 3, 8) == LADDER
+    assert all(R % transformer.DISPATCH_TILE == 0 for R in LADDER)
+    # The four cells' minibatches (PERF.md section 5).
+    assert rows(8192, 4, 8, 64) == (5120, 8192, 16384, 32768)
+    assert rows(8192, 4, 8, 32) == (10240, 16384, 32768)
+    assert rows(8192, 6, 16, 64) == (15360, 24576, 49152)
+    assert rows(8192, 8, 8, 256) == (2560, 4096, 8192, 65536)
+    assert rows(8192, 8, 64, 64) == (65536,)  # OLMoE: all held
+    assert rows(128, 4, 8, 64) == (512,)  # a decode step: batched
+    assert rows(4096, 2, 5, 8) == (6400, 8192)  # 2 x 5,120 is past M k
+    assert rows(4096, 2, 7, 8) == (8192,)
+    for count, R in LANDED.values():
+        index = int(transformer.dispatch_index(jnp.int32(count), LADDER))
+        assert LADDER[index] == R
+
+
+@pytest.mark.parametrize("landed", [*LANDED, *OF_TWO])
+def test_a_share_s_dispatch_over_the_landed_rows_equals_the_masked_loop(
+        landed, monkeypatch):
+    """The grouped form over a share, under `jit` with the landed count a
+    traced value, whichever size of the ladder the count takes: values and
+    gradients (rows, weights of the sum, the three matrices) are the dense
+    masked loop's, with NaN in every row of a `ragged_dot` and of its
+    transpose that lies between the landed count and the size."""
+    monkeypatch.setattr(
+        jax.lax, "ragged_dot",
+        ragged_dot_that_computes_no_row_past_the_groups(jnp.nan))
+    if landed in OF_TWO:
+        monkeypatch.setattr(transformer, "DISPATCH_MULTIPLES", (1.25,))
+    count, R = {**LANDED, **OF_TWO}[landed]
+    n, top_p, top_i, *weights, first, E = landing(count)
+    assert transformer.dispatch_rows(*top_i.shape, 3, E) == (
+        LADDER if landed in LANDED else (LADDER[0], LADDER[-1]))
+    target = jnp.asarray(
+        np.random.default_rng(1).normal(size=n.shape), jnp.float32)
+
+    def loss(experts):
+        def of(top_i, n, top_p, *w):
+            y = experts(n, top_p, top_i, *w, first, E)
+            y, *load = y if isinstance(y, tuple) else (y, None, None)
+            return jnp.sum(y * target), load
+        return jax.jit(jax.value_and_grad(
+            of, argnums=(1, 2, 3, 4, 5), has_aux=True))
+    (got, (sizes, gathered)), got_g = loss(dropless_experts)(
+        top_i, n, top_p, *weights)
+    (want, _), want_g = loss(held_masked_loop)(top_i, n, top_p, *weights)
+    assert (int(jnp.sum(sizes)), int(gathered)) == (count, R)
+    for g, w in zip((got, *got_g), (want, *want_g)):
+        assert bool(jnp.all(jnp.isfinite(g)))
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        assert reference.relative_error(g, w, scale=scale) <= 2e-4
+    if not count:
+        assert not any(np.any(np.asarray(g)) for g in (got, *got_g))
+
+
+def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
+        grouped_pass_is_the_batched_pass):
+    grouped_pass_is_the_batched_pass(*build("f32"))
 
 
 # (M, k, E) -> the form: the shapes the OLMoE tests list and the sweep's
@@ -746,6 +841,9 @@ def test_glm_token_trainer_trains_on_the_fused_path(token_trainer):
     assert kept["expert_load_max"] >= kept["expert_load_mean"] > 0
     # 2 of 8 experts held: about a quarter of the (row, expert) pairs.
     assert 0.05 < kept["experts_held_row_share"] < 0.6
+    # What the learner's product gathered: all, in the batched form these
+    # sizes take.
+    assert kept["dispatch_rows_share"] == 1.0
     assert 3.0 < kept["mtp_loss"] < 6.0  # ln 96 = 4.56 at random weights
     assert kept["decode_rows_per_expert"] == 8 * 2 / 8
     assert kept["decode_experts_batched"] == 1.0

@@ -586,7 +586,7 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
         routed, landed = jnp.zeros_like(n), 0
         for first in range(0, E, held):
             s = share_of(first, held)
-            part, sizes = dropless_experts(
+            part, sizes, _ = dropless_experts(
                 n, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E)
             routed, landed = routed + part, landed + int(jnp.sum(sizes))
         assert landed == n.shape[0] * k
@@ -595,6 +595,11 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
             (whole - h - shared).reshape(-1, H)) < 1e-4
     assert transformer.experts_batched(rows.shape[0], k, E)
     assert not transformer.experts_batched(64 * rows.shape[0], k, E)
+
+
+def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
+        grouped_pass_is_the_batched_pass):
+    grouped_pass_is_the_batched_pass(*build("f32"))
 
 
 def published_cut():

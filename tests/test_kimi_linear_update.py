@@ -200,6 +200,9 @@ def test_kimi_linear_token_trainer_trains_on_the_fused_path(token_trainer):
     assert kept["expert_load_max"] >= kept["expert_load_mean"] > 0
     # 2 of 8 experts held: about a quarter of the (row, expert) pairs.
     assert 0.05 < kept["experts_held_row_share"] < 0.6
+    # What the learner's product gathered: all, in the batched form these
+    # sizes take.
+    assert kept["dispatch_rows_share"] == 1.0
     assert kept["decode_rows_per_expert"] == 4 * 2 / 8
     assert kept["decode_cache_read_share"] == 1.0
     assert kept["causal_attention_fused"] == 0.0

@@ -368,7 +368,7 @@ def test_dropless_dispatch_equals_dense_masked_loop(routing, form, dtype):
     batched products over all experts) == every expert on every token times
     a 0/1 mask, whatever the group sizes (a full group, an empty one)."""
     case = expert_case(routing, form, dtype)
-    got, group_sizes = dropless_experts(*case)
+    got, group_sizes, _ = dropless_experts(*case)
     assert got.dtype == case[0].dtype
     assert reference.relative_error(
         got.astype(jnp.float32), dense_masked_loop(*case)) \
@@ -569,6 +569,9 @@ def test_token_trainer_trains_on_the_fused_path(token_trainer):
     kept = token_trainer.optimizer.learner_stats
     assert kept["decode_rows_per_expert"] == 2.0
     assert kept["decode_experts_batched"] == 1.0
+    # Every expert is here: no share of the pairs to count.
+    assert "experts_held_row_share" not in kept
+    assert "dispatch_rows_share" not in kept
     # Its attention: the window of 16 is one block, read whole every step.
     assert kept["decode_cache_block"] == S
     assert kept["decode_cache_read_share"] == 1.0

@@ -349,7 +349,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         routed, landed = jnp.zeros_like(rows), 0
         for first in range(0, E, held):
             s = share_of(first, held)
-            part, sizes = dropless_experts(
+            part, sizes, _ = dropless_experts(
                 rows, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E,
                 jax.nn.relu)
             routed, landed = routed + part, landed + int(jnp.sum(sizes))
@@ -358,6 +358,11 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
             routed[:m.shape[0]], (whole - no_expert).reshape(-1, H)) < 1e-4
     assert transformer.experts_batched(m.shape[0], k, E)
     assert not transformer.experts_batched(64 * m.shape[0], k, E)
+
+
+def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
+        grouped_pass_is_the_batched_pass):
+    grouped_pass_is_the_batched_pass(*build("f32"))
 
 
 def test_the_cell_s_program_is_known_from_its_static_shapes():
@@ -555,6 +560,9 @@ def test_smallthinker_token_trainer_trains_on_the_fused_path(token_trainer):
     assert kept["expert_load_max"] >= kept["expert_load_mean"] > 0
     # 2 of 8 experts held: about a quarter of the (row, expert) pairs.
     assert 0.05 < kept["experts_held_row_share"] < 0.6
+    # What the learner's product gathered: all, in the batched form these
+    # sizes take.
+    assert kept["dispatch_rows_share"] == 1.0
     assert kept["decode_rows_per_expert"] == 4 * 2 / 8
     assert kept["decode_cache_block"] == S
     # One block a cache: the full layer's 24 positions, a ring's 8 of 24.
