@@ -61,10 +61,17 @@ the key; `kda_heads` heads, d_k = d_v = `kda_head_dim`, P = heads x d:
   q; elementwise work and two sums, three passes over S. A fragment
   (`kda_chunked`): chunks of `kda_chunk` (64) positions; inside a chunk
   the delta rule in its triangular (UT / WY) form, one unit lower
-  triangular solve a head of (I + Diag(beta) kk) against [beta V | beta
-  k_in]; between chunks a `lax.scan` that carries S, three matrix
-  products a step; both loops' bodies recomputed in the backward pass, so
-  that the scan's residuals are the chunk states. WHY PAIRS ARE FORMED
+  triangular system a chunk and head, (I + Diag(beta) kk) against [beta V
+  | beta k_in]. Two phases. The chunk phase makes each chunk's decayed
+  products, one chunk a `lax.map` step, then solves ALL the fragment's
+  systems in one call outside the loop (`unit_lower_solve`: the library's
+  triangular solve works a system a lane, so it is cheap only over
+  thousands; the inverse by forward substitution in blocks of a
+  sub-block's rows, multiplied in at float32 proper, and why not by a
+  product of powers). Between chunks a `lax.scan` that carries S, three
+  matrix products a step. Both steps are recomputed in the backward
+  pass, so that the scan's residuals are the chunk states and the chunk
+  phase's the systems and their solutions. WHY PAIRS ARE FORMED
   FROM DIFFERENCES OF LOG DECAYS: the decay is a channel's, so the
   factorised product (q_i exp(G_i)) . (k_j exp(-G_j)) of cumulative log
   decays G overflows float32 as soon as one channel loses e^88 inside a
@@ -877,6 +884,73 @@ def _taps_step(g, w, state, reset):
 KDA_SUB_BLOCK = 16
 
 
+def _exactly(subscripts, a, b):
+    """A float32 product that is one: on a TPU a float32 `einsum` rounds
+    its operands to bfloat16 unless told otherwise."""
+    return jnp.einsum(subscripts, a, b, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _unit_lower_inverse(L, block):
+    """M = (I + L)^-1 for strictly lower triangular L [.., C, C], float32,
+    by forward substitution in blocks of `block` rows, every system at
+    once: the diagonal blocks by the library's triangular solve against
+    the identity (told that the diagonal is 1, it does not read it), then
+    a block row at a time, M[I, :] = M[I, I] (E_I - L[I, < I] M[< I, :]),
+    two products a block row."""
+    lead, C = L.shape[:-2], L.shape[-1]
+    n = C // block
+    eye = jnp.broadcast_to(jnp.eye(block, dtype=L.dtype),
+                           lead + (block, block))
+    by_block = L.reshape(lead + (n, block, n, block))
+    diagonal = jnp.stack(
+        [by_block[..., I, :, I, :] for I in range(n)], axis=-3)
+    diagonal = jax.scipy.linalg.solve_triangular(
+        diagonal, jnp.broadcast_to(eye[..., None, :, :], diagonal.shape),
+        lower=True, unit_diagonal=True)
+    rows = []
+    for I in range(n):
+        done = I * block
+        ahead = [-_exactly(
+            "...ij,...jk->...ik", L[..., done:done + block, :done],
+            jnp.concatenate(rows, axis=-2)[..., :done])] if I else []
+        row = _exactly("...ij,...jk->...ik", diagonal[..., I, :, :],
+                       jnp.concatenate(ahead + [eye], axis=-1))
+        rows.append(jnp.pad(row, ((0, 0),) * (len(lead) + 1)
+                            + ((0, C - done - block),)))
+    return jnp.concatenate(rows, axis=-2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def unit_lower_solve(L, rhs, block):
+    """The X of (I + L) X = R for each R of the tuple `rhs` and every
+    system at once: L [.., C, C] strictly lower triangular, each R [.., C,
+    W], float32; `block` divides C. The inverse M is made by forward
+    substitution (`_unit_lower_inverse`) and multiplied in, so that the
+    pullback is products against what the forward pass made and no second
+    solve: dR = M^T dX, dL = the strictly lower part of -sum dR X^T. NOT
+    by the product (I - L)(I + L^2)(I + L^4)..: where L is the all-ones
+    triangle (equal keys, beta = 1, no decay) float32 cancels its powers
+    to nothing."""
+    return _unit_lower_solve_fwd(L, rhs, block)[0]
+
+
+def _unit_lower_solve_fwd(L, rhs, block):
+    inverse = _unit_lower_inverse(L, block)
+    X = tuple(_exactly("...ij,...jw->...iw", inverse, R) for R in rhs)
+    return X, (inverse, X)
+
+
+def _unit_lower_solve_bwd(block, kept, dX):
+    inverse, X = kept
+    d_rhs = tuple(_exactly("...ji,...jw->...iw", inverse, d) for d in dX)
+    return -jnp.tril(sum(_exactly("...iw,...jw->...ij", d, x)
+                         for d, x in zip(d_rhs, X)), -1), d_rhs
+
+
+unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
+
+
 def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
     """One chunk of `kda_chunked` up to the state it begins with, for every
     row and head at once: q, k, g [B, heads, C, d_k] (g <= 0 the float32
@@ -894,9 +968,9 @@ def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
         k_out[j] = k_j * exp(G_C - G_j): what position j leaves in the
             state the chunk ends with, 0 where j's episode ends inside it;
         keep     = exp(G_C), 0 where an episode begins inside the chunk;
-    and, solved here, [w_v | w_k] = (I + Diag(beta) kk)^-1 Diag(beta)
-    [V | k_in]: one unit lower triangular solve a head. Returns (into =
-    [w_k ; q_in] as one operand of 2 C rows, w_v, qk, k_out, keep).
+    and the chunk's triangular system, (I + L) [w_v | w_k] = Diag(beta) [V
+    | k_in] with L = Diag(beta) kk, which `kda_chunked` solves for every
+    chunk at once. Returns (L, beta V, beta k_in, q_in, qk, k_out, keep).
 
     No exp(-G) is ever formed: a channel may lose e^50 in ONE position, so
     exp(G_i) * exp(-G_j) overflows float32 inside a chunk while the pair's
@@ -977,13 +1051,8 @@ def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
     k_out = jnp.where(lasting, k * until, 0.0).astype(dtype)
     keep = jnp.where((episode[:, -1] == before)[:, None, None],
                      jnp.exp(jnp.sum(whole, axis=-2)), 0.0)
-    solved = jax.scipy.linalg.solve_triangular(
-        jnp.eye(C, dtype=f32) + beta * kk,
-        jnp.concatenate([beta * v, beta * k_in], axis=-1),
-        lower=True, unit_diagonal=True)
-    w_v, w_k = solved[..., :v.shape[-1]], solved[..., v.shape[-1]:]
-    return (jnp.concatenate([w_k.astype(dtype), q_in], axis=-2), w_v,
-            qk.astype(dtype), k_out, keep)
+    return (beta * kk, beta * v, beta * k_in, q_in, qk.astype(dtype), k_out,
+            keep)
 
 
 def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
@@ -1009,13 +1078,16 @@ def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
         S_C = Diag(keep) S_0 + k_out^T U
 
     the delta rule in its triangular (UT / WY) form: one unit lower
-    triangular solve a chunk and head, with [beta V | beta k_in] on the
-    right so that it is made once, ahead of the states. Two phases: the
-    chunks' decayed products and solves, a chunk at a time (`lax.map`);
-    the scan over the chunks that carries S, three matrix products a
-    step. Both bodies are recomputed in the backward pass: what either
-    holds at once is one chunk's ([sub, sub, d_k] products a sub-block,
-    not the fragment's), and the scan's residuals are the chunk states.
+    triangular system a chunk and head, with [beta V | beta k_in] on the
+    right so that it is solved once, ahead of the states. Two phases.
+    The chunks' decayed products (`_kda_chunk`), a chunk at a time
+    (`lax.map`), then ALL N x B x heads systems solved in one call
+    (`unit_lower_solve`); the scan over the chunks that carries S, three
+    matrix products a step. Both bodies are recomputed in the backward
+    pass: what either holds at once is one chunk's ([sub, sub, d_k]
+    products a sub-block, not the fragment's), the chunk phase's residuals
+    are the systems and their solutions ([N, B, heads, C, C + 2 d]
+    float32), the scan's the chunk states.
     An episode that begins inside the fragment cuts both: pairs of
     different episodes are 0, a position reads S_0 only while no episode
     has begun in its chunk, and S_C keeps of S_0 and of its own positions
@@ -1045,9 +1117,12 @@ def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
     # whatever it is called.
     before = jnp.concatenate([episode[:1, :, 0], episode[:-1, :, -1]])
     with jax.named_scope("policy/kda_state"):
-        terms = jax.lax.map(
+        L, *rhs, q_in, qk, k_out, keep = jax.lax.map(
             jax.checkpoint(lambda xs: _kda_chunk(*xs, sub=sub, dtype=dtype)),
             (q, k, v, g, beta, episode, before))
+        w_v, w_k = unit_lower_solve(L, tuple(rhs), sub)
+        terms = (jnp.concatenate([w_k.astype(dtype), q_in], axis=-2), w_v,
+                 qk, k_out, keep)
 
         def a_chunk(S, xs):
             into, w_v, qk, k_out, keep = xs

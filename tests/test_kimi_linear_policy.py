@@ -1,7 +1,8 @@
 """The `kimi_linear` token policy at a tiny size on the CPU: the model against
 the plain reference (`benchmark/lib/reference_kimi_linear.py`, whose KDA is the
 recurrence itself, one position at a time) in its causal form (a scan over
-chunks, the delta rule solved in its triangular form inside each) and in its
+chunks, the delta rule's triangular systems solved for every chunk at once
+ahead of it, outside every loop, against the library's solve) and in its
 decode through three kinds of state (a KDA layer's matrix a head and its
 convolutions' last inputs, the latent layer's cache); the scan's backward pass
 against `jax.grad` through the recurrence, for every KDA parameter; a decode
@@ -447,21 +448,29 @@ def test_gates_that_lose_e100_inside_a_chunk_stay_finite_and_agree():
     assert not np.isfinite(np.asarray(jnp.exp(-lost))).all()
 
 
+def fragment_of_episodes(seed=43, T=37, heads=3, d=8, rows=2):
+    """Seeded operands of `kda_chunked` with episodes that begin inside a
+    chunk of 8 (5, 6, 20, 34), at a chunk's edge (16), one position long
+    (5), and a tail that is no whole chunk (37 = 4 x 8 + 5)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k = (jax.random.normal(key, (rows, T, heads, d)) for key in keys[:2])
+    q, k = (a / jnp.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
+    v = jax.random.normal(keys[2], (rows, T, heads, d))
+    g = -40.0 * jax.random.uniform(keys[3], (rows, T, heads, d)) ** 3
+    beta = jax.random.uniform(keys[4], (rows, T, heads))
+    starts = np.zeros((rows, T), bool)
+    starts[0, [5, 6, 20]] = True
+    starts[1, [16, 34]] = True
+    starts[:, 0] = True
+    return (q, k, v, g, beta), starts
+
+
 def test_the_chunked_scan_is_the_recurrence_at_any_chunk_and_sub_block():
     """`kda_chunked` alone against `kda_step` one position at a time:
     chunks of one sub-block and of several, a fragment that ends inside a
     chunk, episodes that begin anywhere."""
-    keys = jax.random.split(jax.random.PRNGKey(3), 5)
-    T, heads, d = 37, 3, 8
-    q, k = (jax.random.normal(key, (2, T, heads, d)) for key in keys[:2])
-    q, k = (a / jnp.linalg.norm(a, axis=-1, keepdims=True) for a in (q, k))
-    v = jax.random.normal(keys[2], (2, T, heads, d))
-    g = -40.0 * jax.random.uniform(keys[3], (2, T, heads, d)) ** 3
-    beta = jax.random.uniform(keys[4], (2, T, heads))
-    starts = np.zeros((2, T), bool)
-    starts[0, [5, 6, 20]] = True
-    starts[1, [16, 34]] = True
-    starts[:, 0] = True
+    (q, k, v, g, beta), starts = fragment_of_episodes(seed=3)
+    T, heads, d = q.shape[1:]
     episode = jnp.cumsum(jnp.asarray(starts), axis=1)
 
     def recurrence():
@@ -478,6 +487,153 @@ def test_the_chunked_scan_is_the_recurrence_at_any_chunk_and_sub_block():
         got, state = transformer.kda_chunked(q, k, v, g, beta, episode, chunk)
         assert reference.relative_error(got, want) < 1e-5, chunk
         assert reference.relative_error(state, want_state) < 1e-5, chunk
+
+
+def read_by(run, operands):
+    """(outputs, final state, gradients by q, k, v, g, beta) of a scalar
+    that reads every output and every entry of the final state."""
+    def scalar(*operands):
+        o, S = run(*operands)
+        return (jnp.sum(jnp.sin(o) * jnp.arange(1, o.shape[1] + 1)[
+            None, :, None, None]) + jnp.sum(jnp.cos(S))), (o, S)
+    grads, (o, S) = jax.jit(jax.grad(
+        scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
+    return (o, S) + grads
+
+
+@pytest.fixture(scope="module")
+def by_the_recurrence():
+    operands, starts = fragment_of_episodes()
+
+    def recurrence(*operands):
+        def a_position(S, xs):
+            q, k, v, g, beta, start = xs
+            o, S = transformer.kda_step(
+                jnp.where(start[:, None, None, None], 0.0, S), q, k, v, g,
+                beta)
+            return S, o
+        q = operands[0]
+        S, o = jax.lax.scan(
+            a_position, jnp.zeros(q.shape[:1] + q.shape[2:] + q.shape[-1:]),
+            tuple(jnp.moveaxis(a, 1, 0)
+                  for a in operands + (jnp.asarray(starts),)))
+        return jnp.moveaxis(o, 0, 1), S
+    return read_by(recurrence, operands)
+
+
+# Chunks of one sub-block (the solve is its diagonal block alone), of two
+# and of four (block rows by products).
+@pytest.mark.parametrize("chunk", [4, 8, 16])
+def test_the_chunked_scan_s_gradients_are_the_recurrence_s(
+        chunk, by_the_recurrence):
+    """`kda_chunked`'s outputs, final state and gradients by q, k, v, g and
+    beta are `kda_step`'s one position at a time: resets inside a chunk, at
+    a chunk's edge, and a tail that is not a whole chunk."""
+    operands, starts = fragment_of_episodes()
+    episode = jnp.cumsum(jnp.asarray(starts), axis=1)
+    got = read_by(
+        lambda *operands: transformer.kda_chunked(*operands, episode, chunk),
+        operands)
+    names = ("o", "S", "dq", "dk", "dv", "dg", "dbeta")
+    for name, mine, want in zip(names, got, by_the_recurrence):
+        assert reference.relative_error(mine, want) < 2e-5, (name, chunk)
+
+
+def systems_of_a_chunk(case):
+    """(L, (R, R')) of the chunk phase's systems (I + L) X = R, as
+    `_kda_chunk` makes them from seeded keys: chunks of 64 in sub-blocks of
+    16, as the cell's."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    rows, heads, C, d = 2, 3, 64, 16
+    q, k, v = (jax.random.normal(key, (rows, heads, C, d))
+               for key in keys[:3])
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -2.0 * jax.random.uniform(keys[3], (rows, heads, C, d)) ** 2
+    beta = jax.random.uniform(keys[3], (rows, heads, C, 1))
+    if case == "equal_keys":
+        # Every key the same, beta = 1, nothing decays: L is the all-ones
+        # triangle, whose powers reach 1e17 (the product form of the
+        # inverse cancels them to nothing in float32).
+        k = jnp.broadcast_to(k[:, :, :1], k.shape)
+        g, beta = jnp.zeros_like(g), jnp.ones_like(beta)
+    elif case == "fast_decays":
+        g = -50.0 * jax.random.uniform(keys[3], (rows, heads, C, d))
+    episode = jnp.zeros((rows, C), jnp.int32)
+    L, *rhs = jax.jit(lambda *operands: transformer._kda_chunk(
+        *operands, sub=16, dtype=jnp.float32)[:3])(
+            q, k, v, g, beta, episode, episode[:, 0])
+    return L, tuple(rhs)
+
+
+@pytest.mark.parametrize("case", ["equal_keys", "fast_decays", "drawn"])
+def test_the_solve_in_blocks_is_the_triangular_solve_in_float32(case):
+    """`unit_lower_solve` (the inverse in blocks of 16 rows, multiplied
+    in) against `jax.scipy.linalg.solve_triangular` of the whole systems:
+    the all-ones triangle, log decays down to -50 a position, and drawn
+    ones; its pullback against the library's."""
+    L, rhs = systems_of_a_chunk(case)
+    C = L.shape[-1]
+    if case == "equal_keys":
+        assert np.array_equal(np.asarray(L[0, 0]), np.tril(np.ones((C, C)), -1))
+
+    def library(L, rhs):
+        return tuple(jax.scipy.linalg.solve_triangular(
+            jnp.eye(C) + jnp.tril(L, -1), R, lower=True, unit_diagonal=True)
+            for R in rhs)
+    def mine(L, rhs):
+        return transformer.unit_lower_solve(L, rhs, 16)
+    for got, want in zip(jax.jit(mine)(L, rhs), jax.jit(library)(L, rhs)):
+        assert reference.relative_error(got, want) < 1e-5
+
+    def scalar(solve):
+        return lambda L, rhs: sum(
+            jnp.sum(X * jnp.cos(jnp.arange(X.size, dtype=jnp.float32))
+                    .reshape(X.shape)) for X in solve(L, rhs))
+    got, want = (jax.tree.leaves(jax.jit(jax.grad(
+        scalar(solve), argnums=(0, 1)))(L, rhs)) for solve in (mine, library))
+    for mine_, want_ in zip(got, want):
+        assert reference.relative_error(mine_, want_) < 1e-5
+
+
+def loops_of(text):
+    """The bodies of a StableHLO module's `while` ops, as text."""
+    bodies, at = [], 0
+    while True:
+        at = text.find("stablehlo.while", at)
+        if at < 0:
+            return bodies
+        # Two regions follow: `cond { .. } do { .. }`.
+        start = text.index(" do {", at)
+        depth, end = 0, start + 4
+        while True:
+            depth += {"{": 1, "}": -1}.get(text[end], 0)
+            end += 1
+            if depth == 0:
+                break
+        bodies.append(text[start:end])
+        at = start
+
+
+def test_no_loop_of_the_lowered_scan_holds_a_triangular_solve():
+    """At the rehearsal's size (2 fragments of 32 tokens, 4 heads of 16,
+    chunks of 8) the lowered gradient has the chunk phase's loop and the
+    scan's, forward and transposed, and no loop's body holds a triangular
+    solve."""
+    shape = (2, 32, 4, 16)
+    operands = [jax.ShapeDtypeStruct(shape, jnp.float32)] * 4 + [
+        jax.ShapeDtypeStruct(shape[:3], jnp.float32)]
+    episode = jnp.ones(shape[:2], jnp.int32)
+
+    def scalar(*operands):
+        o, S = transformer.kda_chunked(*operands, episode, 8)
+        return jnp.sum(o) + jnp.sum(S)
+    text = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(
+        *operands).as_text()
+    bodies = loops_of(text)
+    assert len(bodies) == 4
+    assert all(len(body) > 1000 for body in bodies)
+    assert "triangular" in text
+    assert not any("triangular" in body for body in bodies)
 
 
 @pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
