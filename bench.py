@@ -118,12 +118,9 @@ def bench_kernel(n_dev: int, curve_minibatches=(128, 512, 1024, 2048)):
     curve, extras). The headline (rate, mfu_pct) is the per-chip
     minibatch 1024 operating point — the roofline analysis (PERF.md
     round 6) puts the 40% MFU gate at mb >= 1024; the r4-r6 256-row
-    point stays in `extras["kernel_per_chip_mb256"]` for continuity.
-    `extras["allreduce_bytes_per_update"]` carries the collective-plane
-    accounting (fp32 vs q8 payload + timed standalone probes)."""
+    point stays in `extras["kernel_per_chip_mb256"]` for continuity."""
     import jax
     from __graft_entry__ import _synthetic_ppo_batch
-    from ray_tpu.parallel import collectives
     from ray_tpu.parallel import mesh as mesh_lib
     from ray_tpu.rllib.agents.ppo.ppo import DEFAULT_CONFIG, PPOJaxPolicy
     from ray_tpu.rllib.env.spaces import Box, Discrete
@@ -152,7 +149,7 @@ def bench_kernel(n_dev: int, curve_minibatches=(128, 512, 1024, 2048)):
         policy._train_fn,
         jax.tree.map(lambda x: x.copy(), policy.params),
         jax.tree.map(lambda x: x.copy(), policy.opt_state),
-        policy._ef_state, dev_batch, rng, policy.loss_state)
+        dev_batch, rng, policy.loss_state)
     train_flops_per_row = train_flops / batch_size
     obs_probe = np.zeros((256,) + obs_shape, np.uint8)
     fwd_flops = compiled_flops(
@@ -173,15 +170,14 @@ def bench_kernel(n_dev: int, curve_minibatches=(128, 512, 1024, 2048)):
             params = jax.tree.map(lambda x: x.copy(), policy.params)
             opt_state = jax.tree.map(lambda x: x.copy(),
                                      policy.opt_state)
-            ef = jax.tree.map(lambda x: x.copy(), policy._ef_state)
             for _ in range(3):
-                params, opt_state, ef, stats = update(
-                    params, opt_state, ef, db, rng, policy.loss_state)
+                params, opt_state, stats = update(
+                    params, opt_state, db, rng, policy.loss_state)
             float(stats["total_loss"])  # sync
             t0 = time.perf_counter()
             for _ in range(iters):
-                params, opt_state, ef, stats = update(
-                    params, opt_state, ef, db, rng, policy.loss_state)
+                params, opt_state, stats = update(
+                    params, opt_state, db, rng, policy.loss_state)
             float(stats["total_loss"])  # readback forces completion
             return (time.perf_counter() - t0) / iters
 
@@ -213,24 +209,9 @@ def bench_kernel(n_dev: int, curve_minibatches=(128, 512, 1024, 2048)):
     rate = headline["rows_per_s_per_chip"]
     mfu = headline["mfu_pct"]
 
-    # Collective-plane accounting: per-sender bytes one gradient
-    # all-reduce of this param tree puts on the wire under each codec
-    # (analytic), plus a timed standalone exchange per codec when the
-    # mesh is real.
-    allreduce = {
-        "fp32": collectives.payload_bytes(policy.params, "fp32"),
-        "q8": collectives.payload_bytes(policy.params, "q8"),
-    }
-    allreduce["ratio"] = round(allreduce["fp32"] / allreduce["q8"], 2)
-    if n_dev >= 2:
-        for codec in ("fp32", "q8"):
-            allreduce[f"{codec}_probe_ms"] = round(
-                1e3 * collectives.allreduce_probe_s(
-                    policy.params, mesh, codec), 3)
     extras = {
         "headline_minibatch_per_chip": headline_mb,
         "kernel_per_chip_mb256": round(rate256, 1),
-        "allreduce_bytes_per_update": allreduce,
     }
     return (rate, mfu, train_flops_per_row, fwd_flops_per_row, curve,
             extras)
@@ -645,10 +626,6 @@ def main():
         # Per-chip minibatch-size -> MFU curve (roofline companion,
         # PERF.md round 8; per-row FLOPs constant across points).
         "kernel_mfu_curve": mfu_curve,
-        # Per-sender gradient all-reduce payload per codec (analytic
-        # bytes + timed standalone probes; parallel/collectives.py).
-        "allreduce_bytes_per_update":
-            kernel_extras["allreduce_bytes_per_update"],
         # Encoder-level weight-sync cost on the flagship tree (bytes a
         # worker receives per broadcast, per codec arm) — the delta
         # plane's r06+ trajectory line.

@@ -93,14 +93,7 @@ _def("RAY_TPU_PARAM_SHARDING", str, "replicate",
      "(shard large params + optax moments over the dp axis so each "
      "replica owns only its slice of the weight update)")
 
-# --- in-mesh collective plane (parallel/collectives.py) ---------------
-_def("RAY_TPU_ALLREDUCE_CODEC", str, "fp32",
-     "Gradient all-reduce codec when trainers leave allreduce_codec="
-     "'auto': fp32 (XLA's implicit full-precision psum) | q8 (explicit "
-     "EQuARX-style block-quantized exchange — int8 payload + per-"
-     "Q8_BLOCK f32 scales with sender-side error feedback, ~3.9x fewer "
-     "bytes per update; requires replicated params, falls back to fp32 "
-     "on fsdp layouts and single-device meshes)")
+# --- learner compute precision (parallel/precision.py) ----------------
 _def("RAY_TPU_COMPUTE_DTYPE", str, "f32",
      "Learner forward/backward compute dtype when trainers leave "
      "compute_dtype='auto': f32 | bf16 (parameters cast to bfloat16 at "

@@ -80,16 +80,6 @@ that imported jax; absent on CPU-only hosts) and `node_mem_frac`
 with per-node series); counter `straggler_profiles_total`
 (RAY_TPU_STRAGGLER_PROFILE auto-captures fired).
 
-Collective-plane series (parallel/collectives.py, fed by both learner
-stacks): counters `allreduce_bytes` (analytic per-sender payload of
-every gradient all-reduce — 4 bytes/elem under fp32, ~1.03 bytes/elem
-under the q8 codec) and `allreduce_ms` (estimated collective wall time,
-from a once-per-learner timed standalone probe on grad-shaped zeros —
-a collective fused into the jitted update cannot be timed from the
-host); histogram `learner_allreduce_s.<codec>` (the same probe sample,
-codec-labeled, one observation per update). Snapshotted into bench.py
-kernel and MULTICHIP blocks as `allreduce_bytes_per_update`.
-
 Fleet-plane series (_private/fleet.py FleetController): gauge
 `fleet_size` (live remote-sampler count; default sum roll-up so
 several optimizers' fleets read as one cluster total), counters

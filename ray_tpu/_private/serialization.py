@@ -204,10 +204,10 @@ def wire_decode(codec: int, payload, base=None):
 Q8_BLOCK = 1024
 # Positive floor for per-block scales. An all-zero block has amax 0; a
 # zero scale would round-trip 0/0 = NaN through dequantize on any
-# nonzero quantized value, so every scale is clamped here (and in the
-# jnp mirror, parallel/collectives.py) to this epsilon. Zero blocks
-# still reconstruct to exactly 0.0 (q == 0 either way), so the clamp
-# changes no payload semantics — it only removes the zero-scale case.
+# nonzero quantized value, so every scale is clamped here to this
+# epsilon. Zero blocks still reconstruct to exactly 0.0 (q == 0 either
+# way), so the clamp changes no payload semantics — it only removes the
+# zero-scale case.
 Q8_SCALE_EPS = 1e-30
 _Q8HDR = struct.Struct("<I")
 

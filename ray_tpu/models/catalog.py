@@ -72,8 +72,8 @@ def _resolve_compute_dtype(cfg):
     each network's own default)."""
     value = cfg.get("compute_dtype", "auto")
     explicit = value not in (None, "auto")
-    from ..parallel import collectives
-    dtype = collectives.resolve_compute_dtype(value)
+    from ..parallel import precision
+    dtype = precision.resolve_compute_dtype(value)
     import jax.numpy as jnp
     if not explicit and dtype == jnp.float32:
         return None

@@ -1,3 +1,3 @@
 from .mesh import (batch_sharded, make_mesh, pad_to_multiple,  # noqa: F401
                    put_batch, put_replicated, replicated)
-from . import collectives  # noqa: F401
+from . import precision  # noqa: F401

@@ -78,6 +78,19 @@ def batch_sharded(mesh: Mesh, axis: str = "dp") -> NamedSharding:
     return NamedSharding(mesh, P(axis))
 
 
+def refuse_allreduce_codec(config: dict) -> None:
+    """A learner's config may not name a gradient exchange: the only one
+    is the psum XLA inserts from `batch_sharded`. `deep_merge` takes
+    unknown keys silently, so the key the q8 exchange was selected by is
+    refused by name where a learner reads its config."""
+    if "allreduce_codec" in config:
+        raise ValueError(
+            f"config key 'allreduce_codec' "
+            f"({config['allreduce_codec']!r}) is no longer an option: the "
+            "gradient exchange is XLA's psum, inserted from the batch's "
+            "sharding; remove the key")
+
+
 def put_replicated(tree, mesh: Mesh):
     sharding = replicated(mesh)
     return jax.device_put(tree, sharding)

@@ -211,11 +211,9 @@ class DQNPolicy(JaxPolicy):
         caller can refresh replay priorities."""
         dev_batch = self._device_batch(batch)
         with self._update_lock:
-            (self.params, self.opt_state, self._ef_state,
-             stats) = self._train_fn(
-                self.params, self.opt_state, self._ef_state, dev_batch,
+            self.params, self.opt_state, stats = self._train_fn(
+                self.params, self.opt_state, dev_batch,
                 self._next_rng(), self.loss_state)
-        self._account_allreduce(1)
         self.global_timestep += batch.count
         stats = dict(stats)
         td = np.asarray(stats.pop("td_error"))

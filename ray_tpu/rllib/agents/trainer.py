@@ -63,13 +63,6 @@ COMMON_CONFIG = {
     # their optax moments over the dp mesh axis), or an explicit
     # [(regex, PartitionSpec)] rule list.
     "param_sharding": "auto",
-    # In-mesh gradient all-reduce codec (parallel/collectives.py):
-    # "auto" defers to RAY_TPU_ALLREDUCE_CODEC ("fp32" keeps XLA's
-    # implicit full-precision psum; "q8" swaps in the explicit block-
-    # quantized exchange with sender-side error feedback). q8 needs
-    # replicated params: sharded (fsdp) layouts and single-device
-    # meshes fall back to fp32.
-    "allreduce_codec": "auto",
     # Learner compute dtype: "auto" defers to RAY_TPU_COMPUTE_DTYPE
     # ("f32" | "bf16"). bf16 casts parameters at the loss boundary
     # only — master weights, gradients and optax state stay f32.

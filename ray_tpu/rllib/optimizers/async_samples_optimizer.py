@@ -458,9 +458,9 @@ class AsyncSamplesOptimizer(PolicyOptimizer):
 
     def save_learner_state(self):
         """Checkpoint the FULL learner state through the object plane:
-        policy params + optax moments + loss state + timestep (and the
-        q8 all-reduce EF residuals when armed), plus the weight-sync
-        encoder's version counter / receiver-view base / EF residual.
+        policy params + optax moments + loss state + timestep, plus the
+        weight-sync encoder's version counter / receiver-view base / EF
+        residual.
         A learner restored from the returned ref RESUMES — the
         versioned broadcast stream continues, so surviving workers keep
         their delta path instead of full-resyncing."""

@@ -16,18 +16,15 @@ for XLA:
   (`rllib/optimizers/multi_gpu_impl.py:225`).
 - On a multi-device mesh, parameters are replicated and batches sharded on
   the "dp" axis; XLA inserts gradient all-reduces over ICI (the replacement
-  for in-graph tower averaging, `multi_gpu_impl.py:310`). The
-  `allreduce_codec` knob swaps that implicit fp32 psum for the explicit
-  q8 block-quantized exchange (parallel/collectives.py), and
-  `compute_dtype` runs the forward/backward in bf16 against fp32 master
-  weights.
+  for in-graph tower averaging, `multi_gpu_impl.py:310`): that psum is
+  the one gradient exchange. `compute_dtype` runs the forward/backward in
+  bf16 against fp32 master weights (parallel/precision.py).
 """
 
 from __future__ import annotations
 
 import functools
 import itertools
-import logging
 import threading
 from typing import Callable, Dict, Optional
 
@@ -35,17 +32,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import PartitionSpec as P
 
 from ..._private.profiling import phase
 from ...models import catalog
 from ...models.distributions import get_action_dist
-from ...parallel import collectives
 from ...parallel import mesh as mesh_lib
+from ...parallel import precision
 from .. import sample_batch as sb
 from .policy import Policy
-
-logger = logging.getLogger(__name__)
 
 # Columns that the device-side loss consumes; everything else stays host-side.
 _DEVICE_COLUMNS = (
@@ -89,13 +83,14 @@ class JaxPolicy(Policy):
                  postprocess_fn: Optional[Callable] = None,
                  seed: Optional[int] = None):
         super().__init__(observation_space, action_space, config)
+        mesh_lib.refuse_allreduce_codec(config)
         self.dist_class, self.dist_dim = get_action_dist(action_space)
         self.keeps_dist_inputs = self.dist_dim <= MAX_KEPT_DIST_INPUTS
         # Compute dtype resolves BEFORE the model is built so catalog
         # networks thread it through their flax layers (bf16 trunk
         # activations, not just bf16-cast weights). Custom make_model
         # models still get bf16 weights via the loss-boundary cast.
-        self.compute_dtype = collectives.resolve_compute_dtype(
+        self.compute_dtype = precision.resolve_compute_dtype(
             config.get("compute_dtype", "auto"))
         if make_model is not None:
             self.model = make_model(observation_space, action_space, config)
@@ -176,34 +171,6 @@ class JaxPolicy(Policy):
         self.opt_state = jax.device_put(self.opt_state, self._opt_sh)
         self._repl = mesh_lib.replicated(self.mesh)
         self._bsharded = mesh_lib.batch_sharded(self.mesh)
-
-        # Collective plane (parallel/collectives.py): the gradient
-        # exchange codec. The q8 all-reduce quantizes each sender's
-        # FULL local gradient, so it needs replicated params and a real
-        # mesh; anything else falls back to the implicit fp32 psum
-        # (which is also the byte-identical legacy program).
-        codec = collectives.resolve_codec(
-            config.get("allreduce_codec", "auto"))
-        ndev = int(self.mesh.shape[self.layout.batch_axis])
-        if codec == "q8" and (ndev < 2 or not self.layout.is_replicated()):
-            if ndev >= 2:
-                logger.warning(
-                    "allreduce_codec=q8 needs replicated params; the %r "
-                    "sharding table splits them — falling back to fp32",
-                    table)
-            codec = "fp32"
-        self.allreduce_codec = codec
-        # Per-replica error-feedback residuals, stacked on a leading
-        # mesh-sharded axis ({} for fp32: no residual to carry).
-        self._ef_state = (
-            collectives.ef_zeros(self.params, self.mesh,
-                                 self.layout.batch_axis)
-            if codec == "q8" else {})
-        self._ef_sh = collectives.ef_sharding(
-            self.mesh, self.layout.batch_axis)
-        self._allreduce_payload = collectives.payload_bytes(
-            self.params, codec)
-        self._allreduce_probe = None
 
         # Mutable device scalars consumed by the loss (adaptive KL etc.).
         self.loss_state: Dict = {
@@ -356,53 +323,18 @@ class JaxPolicy(Policy):
         # casts the f32 master params at this boundary only: autodiff
         # transposes the cast, so gradients (and optax state) stay f32.
         cdt = self.compute_dtype
-        codec = self.allreduce_codec
-        axis = self.layout.batch_axis
-        ndev = int(self.mesh.shape[axis])
 
         @jax.named_scope("train/loss")
         def local_loss_grad(params, batch, rng, loss_state):
             def lf(p):
                 if cdt != jnp.float32:
-                    p = collectives.cast_float_tree(p, cdt)
+                    p = precision.cast_float_tree(p, cdt)
                 return self._loss_fn(self, p, batch, rng, loss_state)
             (loss, stats), grads = jax.value_and_grad(
                 lf, has_aux=True)(params)
-            return loss, stats, grads
+            return loss, dict(stats), grads
 
-        # loss_grad(params, batch, rng, loss_state, ef) ->
-        # (loss, stats, grads, ef): the collective seam. fp32 keeps the
-        # legacy implicit psum (XLA reduces grads from batch sharding);
-        # q8 makes the exchange explicit via shard_map so each sender
-        # quantizes (grad + carried residual) before it travels.
-        if codec == "q8":
-            def loss_grad(params, batch, rng, loss_state, ef):
-                def per_replica(params, batch, rng, loss_state, ef):
-                    ef = jax.tree.map(lambda e: e[0], ef)
-                    loss, stats, grads = local_loss_grad(
-                        params, batch, rng, loss_state)
-                    with jax.named_scope("train/allreduce"):
-                        grads, ef = collectives.pmean_quantized(
-                            grads, ef, axis, ndev)
-                        loss, stats = jax.lax.pmean(
-                            (loss, dict(stats)), axis)
-                    return loss, stats, grads, jax.tree.map(
-                        lambda e: e[None], ef)
-                # check_vma=False: the summed output IS replicated
-                # (every replica sums the same gathered payload) but
-                # shard_map cannot infer that through all_gather + sum.
-                return jax.shard_map(
-                    per_replica, mesh=self.mesh,
-                    in_specs=(P(), P(axis), P(), P(), P(axis)),
-                    out_specs=(P(), P(), P(), P(axis)),
-                    check_vma=False)(params, batch, rng, loss_state, ef)
-        else:
-            def loss_grad(params, batch, rng, loss_state, ef):
-                loss, stats, grads = local_loss_grad(
-                    params, batch, rng, loss_state)
-                return loss, dict(stats), grads, ef
-
-        self._loss_grad = loss_grad
+        self._loss_grad = local_loss_grad
 
         @jax.named_scope("train/update")
         def apply_update(params, opt_state, grads, stats):
@@ -415,24 +347,20 @@ class JaxPolicy(Policy):
 
         self._apply_update = apply_update
 
-        def train_fn(params, opt_state, ef, batch, rng, loss_state):
-            loss, stats, grads, ef = loss_grad(
-                params, batch, rng, loss_state, ef)
-            params, opt_state, stats = apply_update(
-                params, opt_state, grads, stats)
-            return params, opt_state, ef, stats
+        def train_fn(params, opt_state, batch, rng, loss_state):
+            loss, stats, grads = local_loss_grad(
+                params, batch, rng, loss_state)
+            return apply_update(params, opt_state, grads, stats)
 
         self._train_fn = jax.jit(
-            train_fn, donate_argnums=(0, 1, 2),
-            in_shardings=(self._param_sh, self._opt_sh, self._ef_sh,
+            train_fn, donate_argnums=(0, 1),
+            in_shardings=(self._param_sh, self._opt_sh,
                           self._bsharded, self._repl, self._repl),
-            out_shardings=(self._param_sh, self._opt_sh, self._ef_sh,
-                           self._repl))
+            out_shardings=(self._param_sh, self._opt_sh, self._repl))
 
         def grad_fn(params, batch, rng, loss_state):
             loss, stats, grads = local_loss_grad(
                 params, batch, rng, loss_state)
-            stats = dict(stats)
             return grads, stats
 
         self._grad_fn = jax.jit(
@@ -581,8 +509,8 @@ class JaxPolicy(Policy):
             self._update_lock.acquire()
             try:
                 step.then("learner.train")
-                self.params, self.opt_state, self._ef_state, stats = fn(
-                    self.params, self.opt_state, self._ef_state, dev_batch,
+                self.params, self.opt_state, stats = fn(
+                    self.params, self.opt_state, dev_batch,
                     self._next_rng(), self.loss_state)
             finally:
                 self._update_lock.release()
@@ -592,7 +520,6 @@ class JaxPolicy(Policy):
         with phase("learner.h2d"):
             dev_batch = self._device_batch(batch)
         stats = self._locked_update(self._train_fn, dev_batch)
-        self._account_allreduce(1)
         self.global_timestep += batch.count if hasattr(batch, "count") \
             else len(next(iter(batch.values())))
         with phase("learner.readback"):
@@ -639,37 +566,19 @@ class JaxPolicy(Policy):
         if key not in self._sgd_fns:
             self._sgd_fns[key] = self._make_sgd_fn(*key)
         stats = self._locked_update(self._sgd_fns[key], dev_batch)
-        self._account_allreduce(num_sgd_iter * num_mb)
         from ..sample_batch import real_count
         self.global_timestep += real_count(batch)
         with phase("learner.readback"):
             return {k: float(v) for k, v in stats.items()}
 
-    def _account_allreduce(self, n_updates: int) -> None:
-        """Collective-plane accounting for `n_updates` gradient
-        exchanges: `allreduce_bytes` is analytic (per-sender payload of
-        one all-reduce of the param-shaped grad tree under the active
-        codec); `allreduce_ms` / the `learner_allreduce_s.<codec>`
-        histogram come from a once-per-policy timed standalone probe —
-        a collective fused into the update program cannot be timed from
-        the host, so the estimate is measured on grad-shaped zeros."""
-        if int(self.mesh.shape[self.layout.batch_axis]) < 2:
-            return
-        if self._allreduce_probe is None:
-            self._allreduce_probe = collectives.allreduce_probe_s(
-                self.params, self.mesh, self.allreduce_codec,
-                self.layout.batch_axis)
-        collectives.account(self.allreduce_codec, self._allreduce_payload,
-                            n_updates, self._allreduce_probe)
-
     def _make_sgd_fn(self, num_sgd_iter: int, num_mb: int, mb_size: int,
                      seq_len: int = 1):
-        def sgd_fn(params, opt_state, ef, batch, rng, loss_state):
+        def sgd_fn(params, opt_state, batch, rng, loss_state):
             usable = num_mb * mb_size
             num_seq = usable // seq_len
 
             def epoch(carry, erng):
-                params, opt_state, ef = carry
+                params, opt_state = carry
                 # Permute whole sequences: rows within a seq_len block stay
                 # contiguous (seq_len=1 degenerates to row shuffling).
                 perm = jax.random.permutation(erng, num_seq)
@@ -689,30 +598,29 @@ class JaxPolicy(Policy):
                         (num_mb, mb_size // seq_len) + boot.shape[1:])
 
                 def mb_step(carry, mb):
-                    params, opt_state, ef = carry
-                    loss, stats, grads, ef = self._loss_grad(
-                        params, mb, erng, loss_state, ef)
+                    params, opt_state = carry
+                    loss, stats, grads = self._loss_grad(
+                        params, mb, erng, loss_state)
                     params, opt_state, stats = self._apply_update(
                         params, opt_state, grads, stats)
-                    return (params, opt_state, ef), stats
+                    return (params, opt_state), stats
 
-                (params, opt_state, ef), stats = jax.lax.scan(
-                    mb_step, (params, opt_state, ef), mbs)
-                return (params, opt_state, ef), jax.tree.map(
+                (params, opt_state), stats = jax.lax.scan(
+                    mb_step, (params, opt_state), mbs)
+                return (params, opt_state), jax.tree.map(
                     lambda s: s[-1], stats)  # stats of last minibatch
 
             rngs = jax.random.split(rng, num_sgd_iter)
-            (params, opt_state, ef), stats = jax.lax.scan(
-                epoch, (params, opt_state, ef), rngs)
-            return params, opt_state, ef, jax.tree.map(
+            (params, opt_state), stats = jax.lax.scan(
+                epoch, (params, opt_state), rngs)
+            return params, opt_state, jax.tree.map(
                 lambda s: s[-1], stats)
 
         return jax.jit(
-            sgd_fn, donate_argnums=(0, 1, 2),
-            in_shardings=(self._param_sh, self._opt_sh, self._ef_sh,
+            sgd_fn, donate_argnums=(0, 1),
+            in_shardings=(self._param_sh, self._opt_sh,
                           self._bsharded, self._repl, self._repl),
-            out_shardings=(self._param_sh, self._opt_sh, self._ef_sh,
-                           self._repl))
+            out_shardings=(self._param_sh, self._opt_sh, self._repl))
 
     def compute_gradients(self, batch):
         dev_batch = self._device_batch(batch)
@@ -738,30 +646,22 @@ class JaxPolicy(Policy):
             self.params = jax.device_put(weights, self._param_sh)
 
     def get_state(self):
-        state = {
+        return {
             "weights": self.get_weights(),
             "opt_state": jax.tree.map(np.asarray, self.opt_state),
             "loss_state": {k: float(v) for k, v in self.loss_state.items()},
             "global_timestep": self.global_timestep,
         }
-        if self._ef_state:
-            # q8 all-reduce error-feedback residuals: without them a
-            # restored learner re-accumulates quantization error from
-            # zero instead of resuming the compensated stream.
-            state["ef_state"] = jax.tree.map(np.asarray, self._ef_state)
-        return state
 
     def set_state(self, state):
+        # An older checkpoint may also hold "ef_state" (the removed q8
+        # exchange's residuals): every key but the four below is ignored.
         self.set_weights(state["weights"])
         self.opt_state = jax.device_put(
             jax.tree.map(jnp.asarray, state["opt_state"]), self._opt_sh)
         self.global_timestep = state.get("global_timestep", 0)
         for k, v in state.get("loss_state", {}).items():
             self.loss_state[k] = jnp.asarray(v, jnp.float32)
-        ef = state.get("ef_state")
-        if ef and self._ef_state:
-            self._ef_state = jax.device_put(
-                jax.tree.map(jnp.asarray, ef), self._ef_sh)
 
     def update_loss_state(self, **kwargs) -> None:
         for k, v in kwargs.items():

@@ -39,8 +39,8 @@ import optax
 import ray_tpu
 from ray_tpu.exceptions import RayError
 
-from ..parallel import collectives
 from ..parallel import mesh as mesh_lib
+from ..parallel import precision
 
 logger = logging.getLogger(__name__)
 
@@ -102,23 +102,9 @@ class JaxRunner:
                 "param_sharding tables other than 'replicate' are not "
                 "supported with use_jax_distributed yet")
 
-        # Collective plane (parallel/collectives.py): same knobs as the
-        # rllib policy stack. q8 quantizes each sender's full local
-        # gradient, so it needs replicated params on a real single-
-        # process mesh; everything else keeps the implicit fp32 psum.
-        self.compute_dtype = collectives.resolve_compute_dtype(
+        # Same knob as the rllib policy stack (parallel/precision.py).
+        self.compute_dtype = precision.resolve_compute_dtype(
             self.config.get("compute_dtype", "auto"))
-        codec = collectives.resolve_codec(
-            self.config.get("allreduce_codec", "auto"))
-        if codec == "q8" and (self.distributed or n_dev < 2
-                              or not self.layout.is_replicated()):
-            if self.distributed or not self.layout.is_replicated():
-                logger.warning(
-                    "allreduce_codec=q8 needs replicated params on a "
-                    "single-process mesh — falling back to fp32")
-            codec = "fp32"
-        self.allreduce_codec = codec
-        self._allreduce_probe = None
 
         self.model = self.model_creator(self.config)
         self.optimizer = self.optimizer_creator(self.config)
@@ -156,15 +142,6 @@ class JaxRunner:
             self.params = jax.device_put(host_params, self._param_sh)
             self.opt_state = jax.device_put(host_opt, self._opt_sh)
 
-        # Per-replica error-feedback residuals for the q8 exchange
-        # ({} under fp32) + analytic per-exchange payload bytes.
-        axis = self.layout.batch_axis
-        self._ef = (collectives.ef_zeros(host_params, self.mesh, axis)
-                    if codec == "q8" else {})
-        self._ef_sh = collectives.ef_sharding(self.mesh, axis)
-        self._allreduce_payload = collectives.payload_bytes(
-            host_params, codec)
-
         # bf16 compute casts the f32 master params at the loss boundary
         # only; autodiff transposes the cast so grads/optax stay f32.
         cdt = self.compute_dtype
@@ -172,54 +149,27 @@ class JaxRunner:
         def local_loss_grad(params, x, y):
             def batch_loss(p):
                 if cdt != jnp.float32:
-                    p = collectives.cast_float_tree(p, cdt)
+                    p = precision.cast_float_tree(p, cdt)
                 pred = self.model.apply(p, x)
                 return self.loss_fn(pred, y)
             return jax.value_and_grad(batch_loss)(params)
 
-        if codec == "q8":
-            from jax.sharding import PartitionSpec as P
-            ndev = int(self.mesh.shape[axis])
-
-            def loss_grad(params, x, y, ef):
-                def per_replica(params, x, y, ef):
-                    ef = jax.tree.map(lambda e: e[0], ef)
-                    loss, grads = local_loss_grad(params, x, y)
-                    grads, ef = collectives.pmean_quantized(
-                        grads, ef, axis, ndev)
-                    loss = jax.lax.pmean(loss, axis)
-                    return loss, grads, jax.tree.map(
-                        lambda e: e[None], ef)
-                # check_vma=False: the summed output IS replicated but
-                # that can't be inferred through all_gather + sum.
-                return jax.shard_map(
-                    per_replica, mesh=self.mesh,
-                    in_specs=(P(), P(axis), P(axis), P(axis)),
-                    out_specs=(P(), P(), P(axis)),
-                    check_vma=False)(params, x, y, ef)
-        else:
-            def loss_grad(params, x, y, ef):
-                loss, grads = local_loss_grad(params, x, y)
-                return loss, grads, ef
-
-        def train_step(params, opt_state, ef, x, y):
-            loss, grads, ef = loss_grad(params, x, y, ef)
+        def train_step(params, opt_state, x, y):
+            loss, grads = local_loss_grad(params, x, y)
             updates, opt_state = self.optimizer.update(
                 grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-            return params, opt_state, ef, loss
+            return params, opt_state, loss
 
         # Donated params/opt + dp-sharded batch: XLA inserts the gradient
-        # all-reduce over the mesh (ICI), replacing NCCL — or, under the
-        # q8 codec, the explicit quantized exchange above. Params/opt
+        # all-reduce over the mesh (ICI), replacing NCCL. Params/opt
         # take the layout-resolved shardings (replicated by default;
         # fsdp shards the weight update across the mesh).
         self._train_step = jax.jit(
-            train_step, donate_argnums=(0, 1, 2),
-            in_shardings=(self._param_sh, self._opt_sh, self._ef_sh,
+            train_step, donate_argnums=(0, 1),
+            in_shardings=(self._param_sh, self._opt_sh,
                           self._bshard, self._bshard),
-            out_shardings=(self._param_sh, self._opt_sh, self._ef_sh,
-                           self._repl))
+            out_shardings=(self._param_sh, self._opt_sh, self._repl))
 
         def grad_step(params, x, y):
             loss, grads = local_loss_grad(params, x, y)
@@ -232,7 +182,7 @@ class JaxRunner:
 
         def eval_step(params, x, y):
             if cdt != jnp.float32:
-                params = collectives.cast_float_tree(params, cdt)
+                params = precision.cast_float_tree(params, cdt)
             pred = self.model.apply(params, x)
             return self.loss_fn(pred, y)
 
@@ -285,7 +235,6 @@ class JaxRunner:
         losses = []
         t0 = time.time()
         count = 0
-        steps = 0
         for x, y in self._batches():
             if self.distributed:
                 from ..parallel import distributed as dist
@@ -293,9 +242,8 @@ class JaxRunner:
                 y = dist.process_local_batch(self._bshard, np.asarray(y))
             else:
                 x, y = jnp.asarray(x), jnp.asarray(y)
-            self.params, self.opt_state, self._ef, loss = self._train_step(
-                self.params, self.opt_state, self._ef, x, y)
-            steps += 1
+            self.params, self.opt_state, loss = self._train_step(
+                self.params, self.opt_state, x, y)
             if self.distributed:
                 # Scalar readback per step: replicated output, and a
                 # natural SPMD sync point. Count only this process's
@@ -308,19 +256,6 @@ class JaxRunner:
                 losses.append(loss)
                 count += len(x)
         self.epoch += 1
-        # Collective-plane accounting: one gradient exchange per step.
-        # The timed probe is once-per-runner and single-process only (a
-        # lazy cross-process collective would need SPMD lockstep).
-        if steps and int(self.mesh.devices.size) >= 2:
-            probe = None
-            if not self.distributed:
-                if self._allreduce_probe is None:
-                    self._allreduce_probe = collectives.allreduce_probe_s(
-                        self.params, self.mesh, self.allreduce_codec,
-                        self.layout.batch_axis)
-                probe = self._allreduce_probe
-            collectives.account(self.allreduce_codec,
-                                self._allreduce_payload, steps, probe)
         mean_loss = float(np.mean([float(l) for l in losses])) \
             if losses else 0.0
         return {"train_loss": mean_loss, "epoch": self.epoch,
@@ -453,6 +388,7 @@ class JaxTrainer:
         self._ctor_args = (model_creator, data_creator, optimizer_creator,
                            loss_creator)
         self.config = dict(config or {})
+        mesh_lib.refuse_allreduce_codec(self.config)
         self.batch_size = batch_size
         self.num_replicas = num_replicas
         self.num_devices_per_replica = num_devices_per_replica

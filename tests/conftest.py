@@ -66,6 +66,43 @@ def wait_until(predicate, timeout, msg="condition"):
         time.sleep(0.02)
 
 
+# A toy PPO policy on a mesh of the virtual CPU devices, and a batch for
+# it: what the tests of the learners' update programs share.
+TOY_OBS_SHAPE, TOY_NUM_ACTIONS = (8,), 4
+
+
+def toy_spaces():
+    import numpy as np
+
+    from ray_tpu.rllib.env.spaces import Box, Discrete
+    return (Box(low=-np.inf, high=np.inf, shape=TOY_OBS_SHAPE,
+                dtype=np.float32), Discrete(TOY_NUM_ACTIONS))
+
+
+def cpu_mesh(n=8):
+    import jax
+
+    from ray_tpu.parallel import mesh as mesh_lib
+    devices = jax.devices()[:n]
+    if len(devices) < n:
+        pytest.skip(f"need {n} devices, have {len(jax.devices())}")
+    return mesh_lib.make_mesh(devices=devices, axis_names=("dp",))
+
+
+def ppo_policy(mesh, overrides=None, hiddens=(16, 16)):
+    from ray_tpu.rllib.agents.ppo.ppo import DEFAULT_CONFIG, PPOJaxPolicy
+    config = dict(DEFAULT_CONFIG)
+    config.update({"_mesh": mesh, "model": {"fcnet_hiddens": list(hiddens)}})
+    config.update(overrides or {})
+    return PPOJaxPolicy(*toy_spaces(), config)
+
+
+def ppo_batch(n):
+    import __graft_entry__
+    return __graft_entry__._synthetic_ppo_batch(
+        n, TOY_OBS_SHAPE, TOY_NUM_ACTIONS)
+
+
 def _all_stacks() -> str:
     with tempfile.TemporaryFile(mode="w+") as f:
         faulthandler.dump_traceback(file=f, all_threads=True)

@@ -10,6 +10,8 @@ virtual CPU devices), so XLA inserts the gradient all-reduce.
 import numpy as np
 import pytest
 
+from conftest import cpu_mesh, ppo_batch, ppo_policy, toy_spaces
+
 
 class TestMultiDeviceLearner:
     def test_ppo_mesh4_trains(self):
@@ -66,42 +68,116 @@ class TestMultiDeviceLearner:
         assert np.isfinite(learner["total_loss"])
         t.stop()
 
-    def test_mesh4_matches_mesh1_loss(self):
-        """Same batch, same seed: the 4-device sharded update must compute
-        the same loss as the single-device program (all-reduce correctness).
-        """
-        from ray_tpu.rllib.agents.ppo.ppo import DEFAULT_CONFIG, PPOJaxPolicy
-        from ray_tpu.rllib.env.spaces import Box, Discrete
-        from ray_tpu.parallel import mesh as mesh_lib
-        import __graft_entry__ as ge
-        import jax
 
-        num_actions = 4
-        obs_shape = (8,)
-        batch = ge._synthetic_ppo_batch(64, obs_shape, num_actions)
-
-        def make_policy(n_dev):
-            cfg = dict(DEFAULT_CONFIG)
-            cfg.update({
-                "model": {"fcnet_hiddens": [16, 16]},
-                "num_sgd_iter": 1,
-                "sgd_minibatch_size": 64,
-                "train_batch_size": 64,
-                "seed": 0,
-            })
-            if n_dev > 1:
-                cfg["_mesh"] = mesh_lib.make_mesh(
-                    devices=jax.devices()[:n_dev], axis_names=("dp",))
-            return PPOJaxPolicy(
-                Box(low=-np.inf, high=np.inf, shape=obs_shape,
-                    dtype=np.float32),
-                Discrete(num_actions), cfg)
-
-        p1 = make_policy(1)
-        p4 = make_policy(4)
-        # Align initial weights.
-        p4.set_weights(p1.get_weights())
-        s1 = p1.sgd_learn(batch, num_sgd_iter=1, minibatch_size=64)
-        s4 = p4.sgd_learn(batch, num_sgd_iter=1, minibatch_size=64)
+# ---------------------------------------------------------------------
+# XLA's psum, inserted from the batch's sharding, is the one gradient
+# exchange: from equal weights, one update on a mesh leaves the loss and
+# every parameter where the one-device program leaves them.
+# ---------------------------------------------------------------------
+def _assert_same_update(one, mesh, loss_one, loss_mesh):
+    """`one` and `mesh` are parameter trees after the same update."""
+    import jax
+    np.testing.assert_allclose(loss_mesh, loss_one, rtol=2e-4)
+    flat_one = jax.tree_util.tree_leaves_with_path(one)
+    flat_mesh = jax.tree.leaves(mesh)
+    assert len(flat_one) == len(flat_mesh)
+    for (path, a), b in zip(flat_one, flat_mesh):
         np.testing.assert_allclose(
-            s1["total_loss"], s4["total_loss"], rtol=2e-4)
+            np.asarray(b), np.asarray(a), rtol=2e-4, atol=1e-6,
+            err_msg=jax.tree_util.keystr(path))
+
+
+UPDATES = {
+    "learn_on_batch": lambda p, batch: p.learn_on_batch(batch),
+    # Two minibatches an epoch: the second step starts from the first's
+    # parameters, and both policies draw the same permutation.
+    "sgd_learn": lambda p, batch: p.sgd_learn(
+        batch, num_sgd_iter=1, minibatch_size=32),
+}
+
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+@pytest.mark.parametrize("update", sorted(UPDATES))
+def test_mesh_update_matches_one_device(update, n_dev):
+    one, mesh = ppo_policy(cpu_mesh(1)), ppo_policy(cpu_mesh(n_dev))
+    mesh.set_weights(one.get_weights())
+    before = one.get_weights()
+    batch = ppo_batch(64)
+    s_one, s_mesh = UPDATES[update](one, batch), UPDATES[update](mesh, batch)
+    assert mesh.devices_in_use() == {"params_on": n_dev, "batch_on": n_dev}
+    _assert_same_update(one.get_weights(), mesh.get_weights(),
+                        s_one["total_loss"], s_mesh["total_loss"])
+    # ... and the update moved them: equal trees are not two untouched ones.
+    import jax
+    assert any(np.abs(a - b).max() > 1e-6 for a, b in zip(
+        jax.tree.leaves(before), jax.tree.leaves(one.get_weights())))
+
+
+def test_dqn_mesh_update_matches_one_device():
+    from ray_tpu.rllib import sample_batch as sb
+    from ray_tpu.rllib.agents.dqn.dqn import DEFAULT_CONFIG
+    from ray_tpu.rllib.agents.dqn.dqn_policy import DQNPolicy
+
+    def policy(n_dev):
+        cfg = dict(DEFAULT_CONFIG)
+        cfg.update({"model": {"fcnet_hiddens": [16]}, "hiddens": [16],
+                    "_mesh": cpu_mesh(n_dev)})
+        return DQNPolicy(*toy_spaces(), cfg)
+
+    one, mesh = policy(1), policy(2)
+    mesh.set_weights(one.get_weights())
+    batch = ppo_batch(64)
+    batch[sb.NEW_OBS] = np.roll(batch[sb.OBS], 1, axis=0)
+    (s_one, td_one), (s_mesh, td_mesh) = (
+        p.learn_with_td(batch) for p in (one, mesh))
+    _assert_same_update(one.get_weights()["online"],
+                        mesh.get_weights()["online"],
+                        s_one["loss"], s_mesh["loss"])
+    np.testing.assert_allclose(td_mesh, td_one, rtol=2e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_dev", [2, 4])
+def test_sgd_trainer_mesh_step_matches_one_device(n_dev):
+    import flax.linen as nn
+    import optax
+    from ray_tpu.sgd.jax_trainer import JaxTrainer
+
+    def data_creator(config):
+        rng = np.random.default_rng(0)
+        x = rng.standard_normal((64, 4)).astype(np.float32)
+        y = x @ np.array([[1.0], [-2.0], [0.5], [3.0]], np.float32) + 0.1
+        return (x, y), (x, y)
+
+    def trainer(num_devices):
+        # One batch an epoch: one train step.
+        return JaxTrainer(
+            model_creator=lambda config: nn.Dense(1),
+            data_creator=data_creator,
+            optimizer_creator=lambda config: optax.adam(1e-2),
+            loss_creator=lambda config: (
+                lambda out, target: ((out - target) ** 2).mean()),
+            num_replicas=0, batch_size=64,
+            num_devices_per_replica=num_devices, config={"seed": 0})
+
+    one, mesh = trainer(1), trainer(n_dev)
+    assert mesh.local_runner.mesh.devices.size == n_dev
+    s_one, s_mesh = one.train(), mesh.train()
+    assert s_one["num_samples"] == s_mesh["num_samples"] == 64
+    _assert_same_update(
+        one.get_model_weights(), mesh.get_model_weights(),
+        s_one["train_loss"], s_mesh["train_loss"])
+    one.shutdown()
+    mesh.shutdown()
+
+
+def test_xla_makes_the_exchange():
+    """The compiled `train_fn` of a 2-device policy holds an all-reduce
+    that nothing in the program wrote; a 1-device one holds none."""
+    def compiled(n_dev):
+        p = ppo_policy(cpu_mesh(n_dev))
+        return p._train_fn.lower(
+            p.params, p.opt_state, p._device_batch(ppo_batch(64)),
+            p._next_rng(), p.loss_state).compile().as_text()
+
+    assert "all-reduce" in compiled(2)
+    assert "all-reduce" not in compiled(1)

@@ -359,7 +359,7 @@ def _sebulba_texts():
     rng = pol._next_rng()
     return {
         "train_fn": _lowered(pol._train_fn, pol.params, pol.opt_state,
-                             pol._ef_state, batch, rng, pol.loss_state),
+                             batch, rng, pol.loss_state),
         "select_fn": _lowered(sampler._select_fn, pol.params, g.obs_next,
                               pol._host_rng, pol._next_rng_counter(), True),
         "apply_frame": _lowered(sampler._apply_fn, g.stack, g.host_obs,
@@ -371,36 +371,26 @@ def _sebulba_texts():
     }
 
 
-def _delta_and_q8_texts():
-    import __graft_entry__
-    from ray_tpu.parallel import mesh as mesh_lib
-    from ray_tpu.rllib.agents.ppo.ppo import DEFAULT_CONFIG, PPOJaxPolicy
-    from ray_tpu.rllib.env.spaces import Box, Discrete
+def _delta_texts():
+    from conftest import cpu_mesh, ppo_batch, ppo_policy
     from ray_tpu.rllib.evaluation.device_sampler import apply_full
     sampler = _sprite_sampler(delta=True)
     g = sampler.groups[0]
     packed = np.zeros((g.n, 3 * int(g.env.delta_budget) + 1), np.uint8)
-    cfg = dict(DEFAULT_CONFIG)
-    cfg.update({"_mesh": mesh_lib.make_mesh(2),
-                "model": {"fcnet_hiddens": [16]}, "allreduce_codec": "q8"})
-    q8 = PPOJaxPolicy(
-        Box(low=-np.inf, high=np.inf, shape=(8,), dtype=np.float32),
-        Discrete(4), cfg)
-    assert q8.allreduce_codec == "q8"
-    batch = q8._device_batch(__graft_entry__._synthetic_ppo_batch(
-        32, (8,), 4))
+    pol = ppo_policy(cpu_mesh(2), hiddens=(16,))
+    batch = pol._device_batch(ppo_batch(32))
     return {
         "apply_delta": _lowered(sampler._apply_fn, g.stack, g.frames_d,
                                 packed),
         "apply_full": _lowered(
             apply_full, g.frames_d, np.zeros(1, np.int32),
             np.zeros((1, sampler._hw), np.uint8)),
-        "train_fn_q8": _lowered(q8._train_fn, q8.params, q8.opt_state,
-                                q8._ef_state, batch, q8._next_rng(),
-                                q8.loss_state),
-        "sgd_fn": _lowered(q8._make_sgd_fn(1, 2, 16), q8.params,
-                           q8.opt_state, q8._ef_state, batch,
-                           q8._next_rng(), q8.loss_state),
+        "train_fn_mesh2": _lowered(pol._train_fn, pol.params,
+                                   pol.opt_state, batch, pol._next_rng(),
+                                   pol.loss_state),
+        "sgd_fn": _lowered(pol._make_sgd_fn(1, 2, 16), pol.params,
+                           pol.opt_state, batch, pol._next_rng(),
+                           pol.loss_state),
     }
 
 
@@ -409,8 +399,9 @@ PROGRAM_SCOPES = [
     ("anakin_fn", "anakin/loss"), ("anakin_fn", "anakin/update"),
     ("anakin_fn", "anakin/pack"),
     ("train_fn", "train/loss"), ("train_fn", "train/update"),
-    ("train_fn_q8", "train/allreduce"), ("sgd_fn", "train/loss"),
-    ("sgd_fn", "train/update"), ("action_fn", "policy/action"),
+    ("train_fn_mesh2", "train/loss"), ("train_fn_mesh2", "train/update"),
+    ("sgd_fn", "train/loss"), ("sgd_fn", "train/update"),
+    ("action_fn", "policy/action"),
     ("select_fn", "sebulba/select"), ("apply_frame", "sebulba/apply"),
     ("apply_delta", "sebulba/apply"), ("apply_full", "sebulba/apply"),
     ("pack", "sebulba/pack"),
@@ -421,7 +412,7 @@ PROGRAM_SCOPES = [
 def lowered_texts():
     """Every program lowered once, on the CPU at rehearsal sizes."""
     texts = {}
-    for build in (_anakin_text, _sebulba_texts, _delta_and_q8_texts):
+    for build in (_anakin_text, _sebulba_texts, _delta_texts):
         texts.update(_in_thread(build))
     return texts
 
