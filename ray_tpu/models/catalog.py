@@ -61,10 +61,15 @@ def _kimi_linear(obs_space, num_outputs, cfg, dtype):
     return kimi_linear_from_config(num_outputs, cfg, dtype)
 
 
+def _nemotron_h(obs_space, num_outputs, cfg, dtype):
+    from .transformer import nemotron_h_from_config
+    return nemotron_h_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
 CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite,
                  "smallthinker": _smallthinker, "lfm2_moe": _lfm2_moe,
-                 "kimi_linear": _kimi_linear}
+                 "kimi_linear": _kimi_linear, "nemotron_h": _nemotron_h}
 
 
 def _resolve_compute_dtype(cfg):

@@ -1,4 +1,4 @@
-"""Transformer token policies in flax: one decoder, five descriptions.
+"""Transformer token policies in flax: one decoder, six descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
@@ -6,6 +6,9 @@ reads the same final hidden vector.
 
     x = E[tokens]
     per layer:  h = x + Op(RMSNorm(x));  x = h + FeedForward(RMSNorm(h))
+    (or, `one_function_layers`, nemotron_h: a layer is ONE of the two, x +
+    Op(RMSNorm(x)) or x + FeedForward(RMSNorm(x)), by its entry of
+    `layer_types`, "experts" naming the feed-forward)
     y = RMSNorm(x);  logits = y W_head (untied), or y E^T (`tie_embeddings`,
     lfm2_moe: the head IS the embedding, one parameter that both the lookup
     and the logits differentiate);  value = y w_v + b
@@ -85,6 +88,41 @@ the key; `kda_heads` heads, d_k = d_v = `kda_head_dim`, P = heads x d:
   episode that begins inside a fragment cuts the scan: pairs of different
   episodes are 0 and the carried state is dropped at the boundary.
 
+Mamba-2 ("mamba2"; nemotron_h, `model_type: nemotron_h`: the state-space
+duality form, arXiv:2405.21060), a state-space layer with ONE decay a head;
+`ssm_heads` heads of `ssm_head_dim` channels (I = heads x channels), B and C
+shared by `ssm_groups` groups of heads, `ssm_state` values each:
+      [z | xBC | dt] = n W_in       [H, I + (I + 2 G N) + heads], no bias
+      xBC = silu(conv(xBC) + b)     ONE depthwise causal convolution over
+                                    x, B and C together, `ssm_taps` (4)
+                                    taps and a bias; 0 before the episode's
+                                    first position; x [heads, P], B, C
+                                    [G, N]; head h reads group h // (heads
+                                    / G)
+      dt = softplus(dt + dt_bias)   a head, float32, no clamp
+      a = exp(-exp(A_log) dt)       the decay, ONE a head
+      S_t = a_t S_{t-1} + (dt_t x_t) B_t^T;   y_t = S_t C_t + D x_t
+                                    S [P, N] a head, 0 where an episode
+                                    begins
+      out = GroupRMSNorm(y * silu(z)) W_out    the gate first, then the
+                                    norm over each group's I / G channels,
+                                    one weight [I]
+  Its state is that matrix, [B, heads, P, N] in FLOAT32 (its key of the
+  policy state is "ssm"), and the convolution's last taps - 1 inputs, [B,
+  taps - 1, I + 2 G N] in `compute_dtype`. Two forms that agree. A step
+  (`ssd_step`): the recurrence as written, S read once and written once.
+  A fragment (`ssd_chunked`): chunks of `ssm_chunk` (128) positions; inside
+  a chunk position t reads position s <= t of its episode with the weight
+  exp(sum of the log decays after s up to t) (C_t . B_s), a [C, C] matrix a
+  head against dt x; each chunk leaves sum_s exp(sum of the log decays
+  after s) (dt x)_s B_s^T; a `lax.scan` over the chunks carries S. No
+  solve, no delta, no decay a channel: what `kda_chunked` shares with it is
+  the skeleton (chunk terms by `lax.map`, the scan, both bodies recomputed
+  in the backward pass, whose residuals are the chunk states) and the rule
+  that every exponent is a SUM OF LOG DECAYS, <= 0, never a difference of
+  two cumulative sums (the pair sums by a masked cumulative sum over the
+  chunk: `_ssd_chunk`).
+
 Attention, one of:
   a head's own keys and values (OLMoE, arXiv:2409.02060, `model_type:
   olmoe`; SmallThinker, arXiv:2507.20984, `model_type: smallthinker`):
@@ -136,7 +174,10 @@ Feed-forward, by layer: the first `first_k_dense_replace` layers a dense
 SwiGLU; the others routed experts, beside `n_shared_experts` shared ones
 that every token passes:
       sum over the chosen e of w_e W_down,e (act(W_gate,e n) * W_up,e n)
-(`hidden_act`: SiLU, SwiGLU; or ReLU, SmallThinker's ReGLU). Dropless: no
+(`hidden_act`: SiLU, SwiGLU; or ReLU, SmallThinker's ReGLU); or, WITHOUT a
+gate matrix (`gated_feed_forward` false; nemotron_h's `mlp_hidden_act:
+relu2`), W_down,e relu(W_up,e n)^2, two products an expert, the shared
+expert (of a width of its own, `shared_width`) likewise. Dropless: no
 capacity, no token dropped or re-routed. The layer may hold a share of the
 experts (`experts_held`, from `first_expert_held`): it routes over all of
 them, computes the chosen ones it holds and leaves out what the absent ones
@@ -193,7 +234,11 @@ and heads are float32 and the block's activations `compute_dtype`
 tied head is assumed (the catalog's row drops the key; the family's dense
 configs tie), and its selection bias is frozen, as kimi_linear's is;
 kimi_linear's low ranks, epsilons and the decay's initial draw are assumed
-(the configuration's file lists them). The OLMoE and
+(the configuration's file lists them); nemotron_h is its config's ONE tower as
+an autoregressive policy (what the family's description adds, a second,
+denoising tower and decoding by diffusion over blocks, has no key there and
+is left out), its attention position-free, its decay's and time step's
+draws and the convolution's bias assumed. The OLMoE and
 glm4_moe_lite descriptions have as many key/value heads as query heads and
 refuse another count (their references have no grouped form; latent
 attention has no key/value heads to group).
@@ -240,8 +285,10 @@ layer's caches (none for a layer that is no attention), "pos"}, and, a key
 a kind and only the kinds the model has, {"conv": a convolution layer's
 last gated inputs, a KDA layer's convolutions' last inputs (none for an
 attention layer)} and {"kda": a KDA layer's float32 matrices} beside them:
-every leaf of "kv" has a positions axis, no leaf of "conv" or "kda" has.
-`JaxPolicy` and the Anakin optimizer carry the whole as one pytree.
+{"ssm": a Mamba-2 layer's float32 matrices, its convolution's last inputs
+under "conv"}: every leaf of "kv" has a positions axis, no leaf of "conv",
+"kda" or "ssm" has (`STATE_KINDS`). `JaxPolicy` and the Anakin optimizer
+carry the whole as one pytree.
 """
 
 from __future__ import annotations
@@ -425,8 +472,79 @@ KIMI_LINEAR_FIXED = {
     "hidden_act": "silu", "rope_scaling": None, "tie_word_embeddings": False,
     "model_type": "kimi_linear",
 }
-# The operators a layer of `layer_types` may name.
-LAYER_TYPES = ("conv", "full_attention", "kda")
+NEMOTRON_H_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "num_hidden_layers": "num_layers",
+    "mamba_num_heads": "ssm_heads",
+    "mamba_head_dim": "ssm_head_dim",
+    "n_groups": "ssm_groups",
+    "ssm_state_size": "ssm_state",
+    "conv_kernel": "ssm_taps",
+    "chunk_size": "ssm_chunk",
+    "n_routed_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "moe_shared_expert_intermediate_size": "shared_width",
+    "n_shared_experts": "shared_experts",
+    "norm_topk_prob": "norm_topk_prob",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "max_position_embeddings": "context_len",
+    "rope_theta": "rope_theta",  # kept; no layer rotates
+    "layer_norm_epsilon": "rms_eps",
+    # The deployment's: the share of the experts this chip holds.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+}
+# What Nemotron-Labs-TwoTower-30B-A3B-Base's published `config.json` says,
+# for the keys a `custom_model_config` leaves out.
+NEMOTRON_H_PUBLISHED = {
+    "vocab_size": 131072, "hidden_size": 2688, "num_attention_heads": 32,
+    "num_key_value_heads": 2, "head_dim": 128, "num_hidden_layers": 52,
+    "hybrid_override_pattern":
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+    "mamba_num_heads": 64, "mamba_head_dim": 64, "n_groups": 8,
+    "ssm_state_size": 128, "conv_kernel": 4, "chunk_size": 128,
+    "n_routed_experts": 128, "num_experts_per_tok": 6,
+    "moe_intermediate_size": 1856,
+    "moe_shared_expert_intermediate_size": 3712, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+    "max_position_embeddings": 262144, "rope_theta": 10000,
+    "layer_norm_epsilon": 1e-5,
+}
+NEMOTRON_H_FIXED = {
+    "n_group": 1, "topk_group": 1, "mamba_proj_bias": False,
+    "use_bias": False, "attention_bias": False, "mlp_bias": False,
+    "use_conv_bias": True, "sliding_window": None,
+    "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+    "time_step_limit": [0, None], "tie_word_embeddings": False,
+    "model_type": "nemotron_h",
+}
+# Published keys that no part of the decoder reads, each for its reason: an
+# initialiser's (the policy's own init draws the weights; the time step's
+# range is `_decay_inits`'), the dense feed-forward's width and `expand`
+# (no layer of the pattern is a dense feed-forward `-`, and the inner width
+# is heads x head_dim whatever `expand` says), a residual's storage, a
+# serving option, a kernel switch, the rotary share of a rotation that no
+# layer applies.
+NEMOTRON_H_UNREAD = (
+    "rescale_prenorm_residual", "time_step_min", "time_step_max",
+    "time_step_floor", "intermediate_size", "expand", "residual_in_fp32",
+    "num_logits_to_keep", "use_mamba_kernels", "partial_rotary_factor",
+    "norm_eps")
+# A layer of the family's `hybrid_override_pattern` by its letter.
+NEMOTRON_H_LAYERS = {"M": "mamba2", "E": "experts", "*": "full_attention"}
+# The operators a layer of `layer_types` may name; "experts" (a model of
+# `one_function_layers` alone) names a layer that is its feed-forward and
+# no operator.
+LAYER_TYPES = ("conv", "full_attention", "kda", "mamba2", "experts")
+# The kinds of state a layer may keep between positions, each a key of the
+# policy state beside "pos": caches with a positions axis; a convolution's
+# last inputs; a KDA layer's matrices; a Mamba-2 layer's.
+STATE_KINDS = ("kv", "conv", "kda", "ssm")
 # Published keys that must say what the decoder does (a value it has no
 # part for is refused, not ignored).
 GLM4_MOE_LITE_FIXED = {
@@ -461,13 +579,22 @@ def rope(x, positions, theta, scale=1.0, head_major=False):
     return (out if scale == 1.0 else out * scale).astype(x.dtype)
 
 
-# The gate's activation in a gated feed-forward, by its published name
-# (`hidden_act`): SwiGLU's, or ReGLU's.
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+def relu2(x):
+    """relu(x)^2: the un-gated feed-forward's activation."""
+    return jnp.square(jax.nn.relu(x))
+
+
+# A feed-forward's activation by its published name (`hidden_act`): the
+# gate's in a gated one (SwiGLU's, or ReGLU's), the only one in an un-gated
+# one (squared ReLU).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu, "relu2": relu2}
 
 
 def swiglu(n, w_gate, w_up, w_down, act=jax.nn.silu):
-    """W_down (act(W_gate n) * W_up n) for rows n, weights in n's dtype."""
+    """W_down (act(W_gate n) * W_up n) for rows n, weights in n's dtype;
+    without a gate matrix (`w_gate` None), W_down act(W_up n)."""
+    if w_gate is None:
+        return jnp.dot(act(jnp.dot(n, w_up)), w_down)
     return jnp.dot(act(jnp.dot(n, w_gate)) * jnp.dot(n, w_up), w_down)
 
 
@@ -1167,6 +1294,144 @@ def kda_step(S, q, k, v, g, beta):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
+def _ssd_chunk(x, Bm, Cm, la, episode, before, dtype):
+    """One chunk of `ssd_chunked` up to the state it begins with, for every
+    row and head at once, the heads by group: x [B, G, R, C, P] (dt x; R
+    heads a group), Bm, Cm [B, G, C, N], la [B, G, R, C] (<= 0, the float32
+    log decay a head), `episode` [B, C] and `before` [B], the episode of
+    the position ahead of the chunk. With seg[t, s] the sum of la over the
+    positions after s up to and including t:
+
+        y[t]  = sum_{s <= t} exp(seg[t, s]) (C_t . B_s) x_s, pairs of
+            different episodes 0;
+        left  = sum_s exp(seg[C - 1, s]) x_s B_s^T: what the chunk's
+            positions leave in the state it ends with, those of its last
+            episode alone;
+        keep  = exp(seg[C - 1, -1]), what it keeps of the state it begins
+            with: 0 where an episode begins inside the chunk;
+        into[t] = exp(seg[t, -1]), the weight of that state at position t:
+            0 where an episode has begun inside the chunk by t.
+    Returns (y [B, G, R, C, P], left [B, G, R, P, N], keep [B, G, R], into
+    [B, G, R, C]), float32.
+
+    Every exponent is a sum of log decays, <= 0 by construction: seg is a
+    cumulative sum along t of la placed at [r, s] for r > s (a [C, C]
+    array a head), not the difference of two cumulative sums, which
+    cancels where one burst of decay stands ahead of a quiet stretch; the
+    sums from the chunk's first position are the plain cumulative sum.
+    Selected, never multiplied by 0 / 1 (`_kda_chunk.over` has why). Matrix
+    operands are cast to `dtype`, sums are float32."""
+    f32 = jnp.float32
+    at = jnp.arange(la.shape[-1])
+    seg = jnp.cumsum(jnp.where(at[:, None] > at[None, :], la[..., :, None],
+                               0.0), axis=-2)  # [.., t, s]
+    cum = jnp.cumsum(la, axis=-1)
+    same = (episode[:, :, None] == episode[:, None, :]) & (
+        at[:, None] >= at[None, :])
+    decay = jnp.exp(jnp.where(same[:, None, None], seg, -jnp.inf))
+    cb = jnp.einsum("bgtn,bgsn->bgts", Cm, Bm, preferred_element_type=f32)
+    y = jnp.einsum("bgrts,bgrsp->bgrtp",
+                   (cb[:, :, None] * decay).astype(dtype), x,
+                   preferred_element_type=f32)
+    carried = (episode == before[:, None])[:, None, None]
+    lasting = (episode == episode[:, -1:])[:, None, None]
+    into = jnp.where(carried, jnp.exp(cum), 0.0)
+    until = jnp.where(lasting, jnp.exp(seg[..., -1, :]), 0.0)
+    left = jnp.einsum(
+        "bgrsp,bgsn->bgrpn",
+        (x.astype(f32) * until[..., None]).astype(dtype), Bm,
+        preferred_element_type=f32)
+    keep = jnp.where((episode[:, -1] == before)[:, None, None],
+                     jnp.exp(cum[..., -1]), 0.0)
+    return y, left, keep, into
+
+
+def ssd_chunked(x, Bm, Cm, la, episode, chunk, dtype=jnp.float32):
+    """Mamba-2's state-space layer over a fragment from an empty state, in
+    chunks: x [B, T, heads, P] (the input times its time step, dt x), Bm,
+    Cm [B, T, G, N] (head h reads group h // (heads / G)), la [B, T, heads]
+    (<= 0 the LOG decay a head, float32), `episode` [B, T] the number of
+    the episode a step belongs to (it never falls along a row). A head's
+    state S [P, N] is 0 where an episode begins and
+
+        S_t = exp(la_t) S_{t-1} + x_t B_t^T;   y_t = S_t C_t
+
+    Returns (y [B, T, heads, P] in `dtype`, S after the last position [B,
+    heads, P, N] float32: what a decode continues from). D x is the
+    caller's to add.
+
+    Two phases, `kda_chunked`'s skeleton. The chunks' own terms
+    (`_ssd_chunk`: what a chunk's positions give each other, and what they
+    leave behind), a chunk at a time (`lax.map`); then a `lax.scan` over
+    the chunks that carries S, one matrix product a step (what the state a
+    chunk begins with gives its positions). Both bodies are recomputed in
+    the backward pass: what either holds at once is one chunk's ([C, C]
+    pair weights a head), and the scan's residuals are the chunk states.
+    An episode that begins inside the fragment cuts both, as it cuts
+    `kda_chunked`. T need not be whole chunks: the tail is padded with
+    positions that decay nothing and write nothing (la = 0, x = 0)."""
+    f32 = jnp.float32
+    B, T, heads, P = x.shape
+    G, N = Bm.shape[2:]
+    R = heads // G
+    pad = -T % chunk
+    n = (T + pad) // chunk
+
+    def chunks(a, mode="constant"):
+        """[B, T, ..] -> [n, B, chunk, ..], the tail padded with zeros, or
+        with the last position's value."""
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2),
+                    mode=mode)
+        return jnp.moveaxis(a.reshape((B, n, chunk) + a.shape[2:]), 1, 0)
+    # Heads by group, ahead of the positions.
+    x = jnp.moveaxis(chunks(x).reshape(n, B, chunk, G, R, P), 2, 4)
+    la = jnp.moveaxis(chunks(la).reshape(n, B, chunk, G, R), 2, 4)
+    Bm, Cm = (jnp.moveaxis(chunks(a), 2, 3) for a in (Bm, Cm))
+    episode = chunks(episode, mode="edge")  # [n, B, C]
+    # The episode ahead of a chunk; ahead of the first the state is 0
+    # whatever it is called.
+    before = jnp.concatenate([episode[:1, :, 0], episode[:-1, :, -1]])
+    with jax.named_scope("policy/ssm_state"):
+        y, left, keep, into = jax.lax.map(
+            jax.checkpoint(lambda xs: _ssd_chunk(*xs, dtype=dtype)),
+            (x, Bm, Cm, la, episode, before))
+
+        def a_chunk(S, xs):
+            Cm, left, keep, into = xs
+            met = jnp.einsum("bgtn,bgrpn->bgrtp", Cm, S.astype(dtype),
+                             preferred_element_type=f32)
+            return (keep[..., None, None] * S + left,
+                    into[..., None] * met)
+        S, carried = jax.lax.scan(
+            jax.checkpoint(a_chunk), jnp.zeros((B, G, R, P, N), f32),
+            (Cm, left, keep, into))
+        y = (y + carried).astype(dtype)
+    # [n, B, G, R, C, P] -> [B, T, heads, P]
+    y = jnp.moveaxis(jnp.moveaxis(y, 4, 2), 0, 1).reshape(
+        B, n * chunk, heads, P)
+    return y[:, :T], S.reshape(B, heads, P, N)
+
+
+def ssd_step(S, x, Bm, Cm, la):
+    """The same recurrence, one position: S [B, heads, P, N] float32
+    against x [B, heads, P] (dt x), Bm, Cm [B, G, N], la [B, heads]; (y [B,
+    heads, P] float32, the state after the position):
+
+        S = exp(la) S + x B^T;   y = S C
+
+    the recurrence as written, elementwise work and one sum: S is read
+    once and written once."""
+    B, heads, P, N = S.shape
+    G = Bm.shape[1]
+    by_group = (B, G, heads // G)
+    x, Bm, Cm = (a.astype(jnp.float32) for a in (x, Bm, Cm))
+    S = (jnp.exp(la).reshape(by_group)[..., None, None]
+         * S.reshape(by_group + (P, N))
+         + x.reshape(by_group + (P, 1)) * Bm[:, :, None, None, :])
+    y = jnp.sum(S * Cm[:, :, None, None, :], axis=-1)
+    return y.reshape(B, heads, P), S.reshape(B, heads, P, N)
+
+
 def experts_batched(M: int, k: int, E: int) -> bool:
     """Whether `M` rows, each routed to `k` of `E` experts, go through the
     batched form (`M * E` rows of work) or the grouped one (`M * k` sorted
@@ -1192,6 +1457,13 @@ def experts_batched(M: int, k: int, E: int) -> bool:
 # the expected count and one (Kimi's first expert layer) 2.2-2.6 times.
 DISPATCH_MULTIPLES = (1.25, 2.0, 4.0)
 DISPATCH_TILE = 128
+# The grouped form's products take the experts' width in whole tiles of this
+# many: XLA:TPU's `ragged_dot` over 3,072 rows in 8 groups, hidden 2,688,
+# took 2.29 ms at a width of 1,856 (14.5 lane tiles), 2.07 at 1,920 (15) and
+# 0.90 at 2,048; a layer's recomputed forward and backward 22.6-26.4 ms as
+# published and 13.6-15.1 padded with zeros (a v5e, PERF.md section 7, "Left
+# by PR 45"). Every other family's width is whole tiles already.
+RAGGED_TILE = 256
 
 
 def dispatch_rows(M: int, k: int, held: int, E: int) -> tuple:
@@ -1223,8 +1495,10 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     the experts held here: `first` .. `first + E - 1`, whose weights
     [E, H, W] / [E, W, H] are given in n's dtype (all of them where
     `num_experts` is not given). What an absent expert would add is left
-    out. Returns ([M, H], rows a held group [E], the sorted rows that
-    were gathered).
+    out. Without a gate matrix (`w_gate` None) an expert is W_down,e
+    act(W_up,e n): two products where the gated one has three, in both
+    forms and in the pullbacks. Returns ([M, H], rows a held group [E], the
+    sorted rows that were gathered).
 
     Two forms of that sum, chosen by `experts_batched(M, k,
     num_experts)`; both take operands in n's dtype, accumulate in float32,
@@ -1240,7 +1514,9 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     everything after the sort); the last size is M*k, so no pair is
     dropped, whatever the router does. The rows between the landed count
     and R are in no group; they are made 0 on the way into the products
-    and on the way out, in both passes (`landed`), and add nothing.
+    and on the way out, in both passes (`landed`), and add nothing. The
+    experts' width is padded with zeros to whole tiles of `RAGGED_TILE`
+    (no width but nemotron_h's 1,856 needs it).
 
     Batched: c[m, e] = p[m, j] where top_i[m, j] == first + e, else 0;
     a[e, m] = silu(n W_gate,e) * (n W_up,e) for all M rows and every held
@@ -1250,7 +1526,7 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     weight exactly 0. It does E/k times the matrix work and reads each
     expert's weights once, where they lie."""
     M, k = top_i.shape
-    E = w_gate.shape[0]
+    E = w_up.shape[0]
     share = num_experts is not None and (first, E) != (0, num_experts)
     local = top_i - first if share else top_i
     with jax.named_scope("policy/dispatch"):
@@ -1264,16 +1540,30 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             chosen = local[:, :, None] == jnp.arange(E)
             c = jnp.sum(jnp.where(chosen, top_p[:, :, None], 0.0), axis=1)
         with jax.named_scope("policy/experts_batched"):
-            gate = jnp.einsum("mh,ehw->emw", n, w_gate)
+            if w_gate is not None:
+                gate = jnp.einsum("mh,ehw->emw", n, w_gate)
             up = jnp.einsum("mh,ehw->emw", n, w_up)
-            a = (c.T[:, :, None] * (act(gate) * up)).astype(n.dtype)
+            weight = c.T[:, :, None]
+            hidden = act(up) if w_gate is None else act(gate) * up
+            a = (weight * hidden).astype(n.dtype)
             mixed = jnp.einsum("emw,ewh->mh", a, w_down,
                                preferred_element_type=jnp.float32)
         return mixed.astype(n.dtype), group_sizes, jnp.int32(M * k)
 
-    def grouped(R, n, top_p, w_gate, w_up, w_down, order, count,
-                group_sizes):
-        """The sum over the first R sorted pairs, [M, H] float32."""
+    pad = -w_up.shape[-1] % RAGGED_TILE
+    if pad:
+        # Zero columns of W_up (and W_gate) and zero rows of W_down: act(0)
+        # is 0 for every activation here, and the sum is the same sum.
+        w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, pad)))
+        w_down = jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))
+        if w_gate is not None:
+            w_gate = jnp.pad(w_gate, ((0, 0), (0, 0), (0, pad)))
+
+    def grouped(R, n, top_p, *operands):
+        """The sum over the first R sorted pairs, [M, H] float32;
+        `operands`: the experts' matrices (`weights`), then `whole`."""
+        (*gate, w_up, w_down), (order, count, group_sizes) = (
+            operands[:-3], operands[-3:])
         def landed(x):
             """Sorted rows [R, ..] with those past the last group made 0,
             and their cotangents with them. A `ragged_dot` computes no row
@@ -1293,10 +1583,14 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             source = pairs // k
             rows = landed(n[source])
         with jax.named_scope("policy/experts"):
-            gate = landed(jax.lax.ragged_dot(rows, w_gate, group_sizes))
-            up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
-            out = jax.lax.ragged_dot(landed(act(gate) * up), w_down,
-                                     group_sizes)
+            if gate:
+                gate = landed(jax.lax.ragged_dot(rows, gate[0], group_sizes))
+                up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
+                hidden = act(gate) * up
+            else:
+                hidden = act(landed(
+                    jax.lax.ragged_dot(rows, w_up, group_sizes)))
+            out = jax.lax.ragged_dot(landed(hidden), w_down, group_sizes)
         with jax.named_scope("policy/dispatch"):
             weighted = landed(out).astype(jnp.float32) \
                 * top_p.reshape(-1)[pairs][:, None]
@@ -1307,8 +1601,8 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
         order = jnp.argsort(local.reshape(-1), stable=True)
         count = jnp.sum(group_sizes)
     sizes = dispatch_rows(M, k, E, num_experts or E)
-    floats, whole = (n, top_p, w_gate, w_up, w_down), \
-        (order, count, group_sizes)
+    weights = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    floats, whole = (n, top_p) + weights, (order, count, group_sizes)
     if len(sizes) == 1:
         return (grouped(M * k, *floats, *whole).astype(n.dtype),
                 group_sizes, jnp.int32(M * k))
@@ -1330,9 +1624,10 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             index, [functools.partial(grouped, R) for R in sizes], *operands)
 
     def pullback(R):
-        def pull(cotangent, n, top_p, w_gate, w_up, w_down, *whole):
+        def pull(cotangent, *operands):
+            floats, whole = operands[:-3], operands[-3:]
             return jax.vjp(lambda *floats: grouped(R, *floats, *whole),
-                           n, top_p, w_gate, w_up, w_down)[1](cotangent)
+                           *floats)[1](cotangent)
         return pull
 
     def backward(operands, cotangent):
@@ -1372,13 +1667,21 @@ def _decay_inits() -> dict:
     return {"a_log": a_log, "dt_bias": dt_bias}
 
 
+def _conv_bias_init(key, shape, dtype=jnp.float32):
+    """A depthwise convolution's bias at initialisation: uniform within
+    taps^-1/2 of 0 at the family's four taps (the source library's draw
+    for a convolution of that fan-in)."""
+    return jax.random.uniform(key, shape, dtype, -0.5, 0.5)
+
+
 class DecoderLayerParams(nn.Module):
     """One layer's parameters, by the names the equations use: `shapes` is
     ((name, kind, shape), ...), kind one of ones / dense / experts (a
     leading axis of experts) / taps (a depthwise filter [channels, taps],
     a channel's fan-in its taps) / bias (a constant of the model, small
     and seeded, in the "constants" collection: no gradient, no optimizer
-    state) / a_log, dt_bias (a KDA layer's decay: `_decay_inits`)."""
+    state) / a_log, dt_bias (a KDA or Mamba-2 layer's decay:
+    `_decay_inits`) / conv_bias (`_conv_bias_init`)."""
 
     shapes: tuple
 
@@ -1387,7 +1690,7 @@ class DecoderLayerParams(nn.Module):
                  "dense": nn.initializers.lecun_normal(),
                  "experts": nn.initializers.lecun_normal(batch_axis=(0,)),
                  "taps": nn.initializers.lecun_normal(in_axis=1, out_axis=0),
-                 **_decay_inits()}
+                 "conv_bias": _conv_bias_init, **_decay_inits()}
         tensors = {}
         for name, kind, shape in self.shapes:
             if kind == "bias":
@@ -1445,6 +1748,19 @@ class TokenDecoder(nn.Module):
     kda_head_dim: int = 128
     kda_taps: int = 4
     kda_chunk: int = 64
+    # "mamba2", the state-space layer: `ssm_heads` heads of `ssm_head_dim`
+    # channels, B and C of `ssm_state` values shared by `ssm_groups` groups
+    # of heads, one short convolution of `ssm_taps` taps over x, B and C
+    # together, the learner's scan in chunks of `ssm_chunk`.
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    ssm_taps: int = 4
+    ssm_chunk: int = 128
+    # A layer is its operator OR its feed-forward ("experts" in
+    # `layer_types`), x + f(RMSNorm(x)) with one f, not one after the other.
+    one_function_layers: bool = False
     # Feed-forward: `dense_layers` leading dense layers, then experts.
     dense_layers: int = 0
     dense_width: int = 0
@@ -1454,7 +1770,10 @@ class TokenDecoder(nn.Module):
     experts_held: int = 0  # 0: all of them
     first_expert_held: int = 0
     shared_experts: int = 0
+    shared_width: int = 0  # 0: `shared_experts` x `expert_width`
     hidden_act: str = "silu"  # the gate's, in every gated feed-forward
+    # False: no gate matrix, W_down act(W_up n), experts and shared alike.
+    gated_feed_forward: bool = True
     # Router: softmax, or sigmoid with a selection bias; on the block's
     # post-attention norm, or on the attention's own normalised input.
     selection_bias: bool = False
@@ -1490,9 +1809,10 @@ class TokenDecoder(nn.Module):
 
     def layer_kind(self, i: int):
         """"conv" where layer `i`'s operator is the short convolution,
-        "kda" where it is Kimi Delta Attention; of an attention layer (the
-        window it attends within, 0 for the whole episode; whether its
-        queries and keys are rotated)."""
+        "kda" where it is Kimi Delta Attention, "mamba2" where it is the
+        state-space layer, "experts" where the layer is its feed-forward
+        alone; of an attention layer (the window it attends within, 0 for
+        the whole episode; whether its queries and keys are rotated)."""
         if self.layer_types and self.layer_types[i] != "full_attention":
             if self.layer_types[i] not in LAYER_TYPES:
                 raise ValueError(f"layer type {self.layer_types[i]!r}: "
@@ -1517,6 +1837,16 @@ class TokenDecoder(nn.Module):
         """A KDA layer's projections' width: heads x head_dim."""
         return (self.kda_heads or self.num_heads) * self.kda_head_dim
 
+    @property
+    def ssm_width(self) -> int:
+        """A Mamba-2 layer's inner channels: heads x head_dim."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """The channels its one convolution runs over: x, B and C."""
+        return self.ssm_width + 2 * self.ssm_groups * self.ssm_state
+
     def cache_len(self, i: int) -> int:
         """Positions layer `i`'s cache holds: the context, or a window
         layer's ring of its window (an attention layer's)."""
@@ -1525,10 +1855,28 @@ class TokenDecoder(nn.Module):
 
     def _layer_shapes(self, dense: bool, kind=(0, True)) -> tuple:
         """`attn_norm` is the norm ahead of the layer's operator, whichever
-        that is (`kind`: `layer_kind`'s)."""
+        that is (`kind`: `layer_kind`'s), `mlp_norm` the one ahead of its
+        feed-forward; a layer of `one_function_layers` has one of the
+        two."""
         H, heads = self.hidden_size, self.num_heads
-        shapes = [("attn_norm", "ones", (H,)), ("mlp_norm", "ones", (H,))]
-        if kind == "conv":
+        feed_forward = not self.one_function_layers or kind == "experts"
+        shapes = [("attn_norm", "ones", (H,))] * (kind != "experts") + [
+            ("mlp_norm", "ones", (H,))] * feed_forward
+        if kind == "experts":
+            pass
+        elif kind == "mamba2":
+            I, C = self.ssm_width, self.ssm_conv_width
+            shapes += [
+                # [z | x B C | dt], and the one convolution's taps and bias
+                ("ssm_in", "dense", (H, I + C + self.ssm_heads)),
+                ("ssm_conv", "taps", (C, self.ssm_taps)),
+                ("ssm_conv_bias", "conv_bias", (C,)),
+                ("ssm_a_log", "a_log", (self.ssm_heads,)),
+                ("ssm_dt_bias", "dt_bias", (self.ssm_heads,)),
+                ("ssm_d", "ones", (self.ssm_heads,)),
+                ("ssm_norm", "ones", (I,)),
+                ("ssm_out", "dense", (I, H))]
+        elif kind == "conv":
             shapes += [("conv_in", "dense", (H, 3 * H)),
                        ("conv_w", "taps", (H, self.conv_taps)),
                        ("conv_out", "dense", (H, H))]
@@ -1568,23 +1916,26 @@ class TokenDecoder(nn.Module):
                 shapes += [("q_norm", "ones", (q,)), ("k_norm", "ones", (kv,))]
             shapes += [("wq", "dense", (H, q)), ("wk", "dense", (H, kv)),
                        ("wv", "dense", (H, kv)), ("wo", "dense", (q, H))]
+        if not feed_forward:
+            return tuple(shapes)
         if dense:
             D = self.dense_width
             return tuple(shapes + [
                 ("dense_gate", "dense", (H, D)), ("dense_up", "dense", (H, D)),
                 ("dense_down", "dense", (D, H))])
         E, W = self.held, self.expert_width
-        shapes += [("router", "dense", (H, self.num_experts)),
-                   ("w_gate", "experts", (E, H, W)),
-                   ("w_up", "experts", (E, H, W)),
-                   ("w_down", "experts", (E, W, H))]
+        gate = self.gated_feed_forward
+        shapes += [("router", "dense", (H, self.num_experts))] + [
+            ("w_gate", "experts", (E, H, W))] * gate + [
+            ("w_up", "experts", (E, H, W)),
+            ("w_down", "experts", (E, W, H))]
         if self.selection_bias:
             shapes.append(("router_bias", "bias", (self.num_experts,)))
         if self.shared_experts:
-            SW = self.shared_experts * W
-            shapes += [("shared_gate", "dense", (H, SW)),
-                       ("shared_up", "dense", (H, SW)),
-                       ("shared_down", "dense", (SW, H))]
+            SW = self.shared_width or self.shared_experts * W
+            shapes += [("shared_gate", "dense", (H, SW))] * gate + [
+                ("shared_up", "dense", (H, SW)),
+                ("shared_down", "dense", (SW, H))]
         return tuple(shapes)
 
     def setup(self):
@@ -1598,6 +1949,22 @@ class TokenDecoder(nn.Module):
             raise ValueError(
                 f"{self.num_heads} query heads do not fall into "
                 f"{self.kv_heads} key/value heads' groups")
+        kinds = self.layer_types[:self.num_layers]
+        if "experts" in kinds and not self.one_function_layers:
+            raise ValueError(
+                "a layer that is its feed-forward alone (\"experts\") "
+                "belongs to a model of one_function_layers")
+        if (self.one_function_layers or not self.gated_feed_forward) and (
+                self.dense_layers or self.nextn_layers
+                or self.router_before_attention):
+            raise ValueError(
+                "TokenDecoder has no dense layer, next-next-token module "
+                "or router ahead of the attention in a model whose layers "
+                "are one function each or whose feed-forward has no gate")
+        if "mamba2" in kinds and self.ssm_heads % self.ssm_groups:
+            raise ValueError(
+                f"{self.ssm_heads} state-space heads do not fall into "
+                f"{self.ssm_groups} groups")
         self.embed = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab_size, H))
         self.layers = [
@@ -1645,7 +2012,10 @@ class TokenDecoder(nn.Module):
         d_v] in float32 whatever `compute_dtype` (it is summed into over
         thousands of steps), its entry of "kda", and the last `kda_taps` -
         1 inputs of its three convolutions, [B, taps - 1, 3 x heads x d]
-        in `compute_dtype`, its entry of "conv"."""
+        in `compute_dtype`, its entry of "conv". A Mamba-2 layer likewise:
+        [B, heads, P, N] float32 under "ssm", its one convolution's last
+        inputs [B, taps - 1, I + 2 G N] under "conv". A layer that is its
+        feed-forward alone keeps nothing."""
         B = batch_size
         kinds = [self.layer_kind(i) for i in range(self.num_layers)]
 
@@ -1659,27 +2029,33 @@ class TokenDecoder(nn.Module):
                 return ((B, S, self.kv_heads * self.head_width),) * 2
             return ((B, S, self.kv_heads, self.head_width),) * 2
         tails = {"conv": (self.conv_taps - 1, self.hidden_size),
-                 "kda": (self.kda_taps - 1, 3 * self.kda_width)}
+                 "kda": (self.kda_taps - 1, 3 * self.kda_width),
+                 "mamba2": (self.ssm_taps - 1, self.ssm_conv_width)}
         d = self.kda_head_dim
-        return self._policy_state(
-            (tuple(jnp.zeros(s, self.compute_dtype) for s in shapes(i))
-             for i in range(self.num_layers)),
-            (jnp.zeros((B,) + tails[kind], self.compute_dtype)
-             if isinstance(kind, str) else () for kind in kinds),
-            (jnp.zeros((B, self.kda_width // d, d, d), jnp.float32)
-             if kind == "kda" else () for kind in kinds),
-            jnp.zeros(batch_size, jnp.int32))
+        matrices = {
+            "kda": ("kda", (self.kda_width // d, d, d)),
+            "ssm": ("mamba2", (self.ssm_heads, self.ssm_head_dim,
+                               self.ssm_state))}
+        held = {
+            "kv": [tuple(jnp.zeros(s, self.compute_dtype) for s in shapes(i))
+                   for i in range(self.num_layers)],
+            "conv": [jnp.zeros((B,) + tails[kind], self.compute_dtype)
+                     if kind in tails else () for kind in kinds]}
+        for key, (of, shape) in matrices.items():
+            held[key] = [jnp.zeros((B,) + shape, jnp.float32)
+                         if kind == of else () for kind in kinds]
+        return self._policy_state(held, jnp.zeros(batch_size, jnp.int32))
 
-    def _policy_state(self, kv, conv, kda, pos) -> dict:
-        """The policy state of a layer's caches, a layer's convolution
-        state, a layer's matrix state and the rows' positions: a key a
-        kind, and only the kinds the model has."""
-        state = {"kv": tuple(kv), "pos": pos}
-        if self.layer_types:
-            state["conv"] = tuple(conv)
-        if "kda" in self.layer_types[:self.num_layers]:
-            state["kda"] = tuple(kda)
-        return state
+    def _policy_state(self, held: dict, pos) -> dict:
+        """The policy state of what each layer holds, by kind ({kind: an
+        entry a layer, () where the layer has none} for every one of
+        `STATE_KINDS`), and the rows' positions: a key a kind, and only the
+        kinds the model has."""
+        kinds = self.layer_types[:self.num_layers]
+        has = {"kv": True, "conv": bool(self.layer_types),
+               "kda": "kda" in kinds, "ssm": "mamba2" in kinds}
+        return {**{kind: tuple(held[kind]) for kind in STATE_KINDS
+                   if has[kind]}, "pos": pos}
 
     def static_counters(self, batch_size: int, fragment_len: int,
                         platform: str) -> dict:
@@ -1699,10 +2075,12 @@ class TokenDecoder(nn.Module):
         are, the query heads a key/value head, and the share of the
         causal tiles that the fused form visits in a window layer. A
         model with convolution state (gated short convolutions, or KDA's
-        three): the layers that have it, and the bytes of it a row,
-        whatever the length, from the state's own leaves. A model with KDA
-        layers: how many they are, the bytes of their matrix states a
-        row, and the positions in a chunk of the learner's scan."""
+        three, or Mamba-2's one): the layers that have it, and the bytes of
+        it a row, whatever the length, from the state's own leaves. A model
+        with KDA layers, or with Mamba-2 layers: how many they are, the
+        bytes of their matrix states a row, and the positions in a chunk
+        of the learner's scan. A model whose heads are grouped: the query
+        heads a key/value head."""
         k, E = self.experts_per_token, self.num_experts
         kernel = False
         attention = self.attention_layers
@@ -1749,20 +2127,21 @@ class TokenDecoder(nn.Module):
                     "causal_attention_fused"] else (1, 1)
             out.update(
                 window_layers=len(windows),
-                kv_groups=self.num_heads // self.kv_heads,
                 causal_window_tiles_kept=kept / causal)
-        if len(attention) < self.num_layers:
-            state = jax.eval_shape(lambda: self.initial_state(1))
-
-            def held(key):
-                return sum(a.size * a.dtype.itemsize
-                           for a in jax.tree.leaves(state[key]))
-            out.update(conv_layers=self.num_layers - len(attention),
-                       conv_state_bytes_per_row=held("conv"))
-            if "kda" in state:
-                out.update(kda_layers=len(self.kda_layers),
-                           kda_state_bytes_per_row=held("kda"),
-                           kda_chunk=self.kda_chunk)
+        if not self.kv_lora_rank and self.kv_heads != self.num_heads:
+            out["kv_groups"] = self.num_heads // self.kv_heads
+        state = jax.eval_shape(lambda: self.initial_state(1))
+        chunks = {"conv": None, "kda": self.kda_chunk, "ssm": self.ssm_chunk}
+        for kind in STATE_KINDS[1:]:
+            layers = sum(bool(entry) for entry in state.get(kind, ()))
+            if not layers:
+                continue
+            out.update({f"{kind}_layers": layers,
+                        f"{kind}_state_bytes_per_row": sum(
+                            a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(state[kind]))})
+            if chunks[kind]:
+                out[f"{kind}_chunk"] = chunks[kind]
         return out
 
     def __call__(self, obs, state, reset):
@@ -2109,6 +2488,75 @@ class TokenDecoder(nn.Module):
                 o, S = kda_step(S, q, k, v, g, beta)
             return self._kda_output(lp, x, n, o), taps[:, 1:], S
 
+    # -- Mamba-2, both forms ------------------------------------------------
+    def _ssm_inputs(self, lp, x):
+        """(z, x B C ahead of their convolution, dt ahead of its bias) of
+        rows x [.., H]: [z | xBC | dt] = RMSNorm(x) W_in."""
+        cd = self.compute_dtype
+        n = rms_norm(x, lp["attn_norm"], self.rms_eps, cd)
+        mixed = jnp.dot(n, lp["ssm_in"].astype(cd))
+        inner, heads = self.ssm_width, self.ssm_heads
+        return mixed[..., :inner], mixed[..., inner:-heads], mixed[
+            ..., -heads:]
+
+    def _ssm_heads(self, lp, mixed, dt):
+        """(dt x and x by head, B and C by group, in `compute_dtype`; the
+        float32 LOG decay a head) of the convolved `mixed` [.., I + 2 G N]
+        (float32, ahead of its bias and activation) and the rows' dt [..,
+        heads]: xBC = silu(. + b), dt = softplus(. + dt_bias), the log
+        decay -exp(A_log) dt <= 0."""
+        cd, f32 = self.compute_dtype, jnp.float32
+        inner, G = self.ssm_width, self.ssm_groups
+        by = mixed.shape[:-1]
+        xBC = jax.nn.silu(mixed + lp["ssm_conv_bias"])
+        x = xBC[..., :inner].reshape(by + (self.ssm_heads, -1))
+        Bm, Cm = (a.reshape(by + (G, -1)).astype(cd)
+                  for a in jnp.split(xBC[..., inner:], 2, axis=-1))
+        dt = jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"])
+        return ((dt[..., None] * x).astype(cd), x.astype(cd), Bm, Cm,
+                -jnp.exp(lp["ssm_a_log"]) * dt)
+
+    def _ssm_output(self, lp, x, z, y, by_head):
+        """x + GroupRMSNorm((y + D x_h) * silu(z)) W_out for the state's
+        outputs y and the heads' inputs `by_head` [.., heads, P]: the gate
+        first, then the norm over each group's channels, one weight [I]."""
+        cd, f32, G = self.compute_dtype, jnp.float32, self.ssm_groups
+        y = y.astype(f32) + lp["ssm_d"][:, None] * by_head.astype(f32)
+        gated = (y.reshape(z.shape) * jax.nn.silu(z.astype(f32))).reshape(
+            z.shape[:-1] + (G, -1))
+        o = rms_norm(gated, lp["ssm_norm"].reshape(G, -1), self.rms_eps, cd)
+        return x + jnp.dot(o.reshape(z.shape), lp["ssm_out"].astype(cd))
+
+    def _ssm_causal(self, lp, x, positions, episode):
+        """x + Mamba2(RMSNorm(x)) over a fragment [B, T, H] from empty
+        states (`ssd_chunked`); (h, (the convolution's last taps - 1 inputs
+        [B, taps - 1, I + 2 G N], the matrix state after the last position
+        [B, heads, P, N] float32): what a decode continues from)."""
+        with jax.named_scope("policy/mamba2"):
+            z, xBC, dt = self._ssm_inputs(lp, x)
+            with jax.named_scope("policy/short_conv"):
+                mixed, back = _taps_causal(xBC, lp["ssm_conv"], positions)
+                tail = _taps_tail(back, positions)
+            dtx, by_head, Bm, Cm, la = self._ssm_heads(lp, mixed, dt)
+            y, S = ssd_chunked(dtx, Bm, Cm, la, episode, self.ssm_chunk,
+                               self.compute_dtype)
+            return self._ssm_output(lp, x, z, y, by_head), (tail, S)
+
+    def _ssm_step(self, lp, x, tails, S, reset):
+        """The same of one token a row, x [B, H], against the row's
+        states, zeroed first where `reset` (`ssd_step`); (h, the
+        convolution's inputs with this one appended and the oldest dropped,
+        the matrix state)."""
+        with jax.named_scope("policy/mamba2"):
+            z, xBC, dt = self._ssm_inputs(lp, x)
+            with jax.named_scope("policy/short_conv"):
+                mixed, taps = _taps_step(xBC, lp["ssm_conv"], tails, reset)
+            dtx, by_head, Bm, Cm, la = self._ssm_heads(lp, mixed, dt)
+            with jax.named_scope("policy/ssm_state"):
+                S = jnp.where((reset > 0)[:, None, None, None], 0.0, S)
+                y, S = ssd_step(S, dtx, Bm, Cm, la)
+            return self._ssm_output(lp, x, z, y, by_head), taps[:, 1:], S
+
     # -- feed-forward -----------------------------------------------------
     def _route(self, lp, n):
         return route(
@@ -2138,14 +2586,17 @@ class TokenDecoder(nn.Module):
                     "dense_gate", "dense_up", "dense_down")),
                     act=act), None, None
         top_p, top_i = routing or self._route(lp, n)
+
+        def matrices(*names):
+            """In the blocks' dtype; None for a gate the model has not."""
+            return (lp[w].astype(cd) if w in lp else None for w in names)
         moe, *load = dropless_experts(
-            n, top_p, top_i, lp["w_gate"].astype(cd), lp["w_up"].astype(cd),
-            lp["w_down"].astype(cd), self.first_expert_held,
-            self.num_experts, act)
-        if "shared_gate" in lp:
+            n, top_p, top_i, *matrices("w_gate", "w_up", "w_down"),
+            self.first_expert_held, self.num_experts, act)
+        if "shared_up" in lp:
             with jax.named_scope("policy/shared_expert"):
-                moe = moe + swiglu(n, *(lp[w].astype(cd) for w in (
-                    "shared_gate", "shared_up", "shared_down")), act=act)
+                moe = moe + swiglu(n, *matrices(
+                    "shared_gate", "shared_up", "shared_down"), act=act)
         return h + moe, tuple(load), top_i
 
     def _heads(self, x):
@@ -2232,16 +2683,23 @@ class TokenDecoder(nn.Module):
 
         def block(lp, x, kind=(0, True)):
             """One layer; `caches` are its caches, or the convolution's
-            state, or KDA's two."""
+            state, or KDA's or Mamba-2's two (nothing where the layer is
+            its feed-forward alone)."""
             routing = self._route_ahead(lp, x)
-            if kind == "conv":
+            if kind == "experts":
+                h, caches = x, ()
+            elif kind == "conv":
                 h, caches = self._conv_causal(lp, x, positions)
             elif kind == "kda":
                 h, caches = self._kda_causal(lp, x, positions, episode)
+            elif kind == "mamba2":
+                h, caches = self._ssm_causal(lp, x, positions, episode)
             else:
                 rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
                 h, caches = self._attend_causal(
                     lp, x, positions, episode, rows, *kind)
+            if "mlp_norm" not in lp:  # the operator alone
+                return h, caches, None, None
             out, load, top_i = self._feed_forward(
                 lp, h.reshape(B * T, -1), routing)
             return out.reshape(B, T, -1), caches, load, top_i
@@ -2255,15 +2713,18 @@ class TokenDecoder(nn.Module):
                     CAUSAL_KEPT), static_argnums=(2,))
 
         x = self.embed[tokens].astype(cd)
-        kv, conv, kda, loads, experts = [], [], [], [], []
+        held = {kind: [] for kind in STATE_KINDS}
+        loads, experts = [], []
         for i, layer in enumerate(self.layers):
             kind = self.layer_kind(i)
             x, caches, load, top_i = block(layer(), x, kind)
-            if kind == "kda":
-                caches, S = caches
-            kv.append(() if isinstance(kind, str) else caches)
-            conv.append(caches if isinstance(kind, str) else ())
-            kda.append(S if kind == "kda" else ())
+            matrix = ()
+            if kind in ("kda", "mamba2"):
+                caches, matrix = caches
+            held["kv"].append(() if isinstance(kind, str) else caches)
+            held["conv"].append(caches if isinstance(kind, str) else ())
+            held["kda"].append(matrix if kind == "kda" else ())
+            held["ssm"].append(matrix if kind == "mamba2" else ())
             if top_i is not None:
                 loads.append(load)
                 experts.append(top_i.reshape(B, T, -1))
@@ -2276,7 +2737,7 @@ class TokenDecoder(nn.Module):
         self._count(experts, loads)
         logits, value = self._heads(x)
         return logits, value, self._policy_state(
-            kv, conv, kda, positions[:, -1] + 1)
+            held, positions[:, -1] + 1)
 
     def _next_next_token(self, block, x, tokens, episode):
         """The module's loss over a fragment (see the module docstring),
@@ -2318,24 +2779,33 @@ class TokenDecoder(nn.Module):
     def decode(self, token, state, reset):
         pos = jnp.where(reset > 0, 0, state["pos"])
         x = self.embed[token].astype(self.compute_dtype)
-        kv, conv, kda, experts, reads = [], [], [], [], {}
+        held = {kind: [] for kind in STATE_KINDS}
+        experts, reads = [], {}
         for i, (layer, caches) in enumerate(zip(self.layers, state["kv"])):
             lp = layer()
             kind = self.layer_kind(i)
             routing = self._route_ahead(lp, x)
-            held, S = (), ()
-            if kind == "conv":
-                h, held = self._conv_step(lp, x, state["conv"][i], reset)
+            h, tails, matrix = x, (), ()
+            if kind == "experts":
+                pass
+            elif kind == "conv":
+                h, tails = self._conv_step(lp, x, state["conv"][i], reset)
             elif kind == "kda":
-                h, held, S = self._kda_step(
+                h, tails, matrix = self._kda_step(
                     lp, x, state["conv"][i], state["kda"][i], reset)
+            elif kind == "mamba2":
+                h, tails, matrix = self._ssm_step(
+                    lp, x, state["conv"][i], state["ssm"][i], reset)
             else:
                 h, caches, reads[i] = self._attend_step(
                     lp, x, pos, caches, *kind)
-            kv.append(caches)
-            conv.append(held)
-            kda.append(S)
-            x, _, top_i = self._feed_forward(lp, h, routing)
+            held["kv"].append(caches)
+            held["conv"].append(tails)
+            held["kda"].append(matrix if kind == "kda" else ())
+            held["ssm"].append(matrix if kind == "mamba2" else ())
+            x, top_i = h, None
+            if "mlp_norm" in lp:
+                x, _, top_i = self._feed_forward(lp, h, routing)
             if top_i is not None:
                 experts.append(top_i)
         if self.is_initializing():
@@ -2343,7 +2813,7 @@ class TokenDecoder(nn.Module):
                 module()
         self._count(experts, reads=reads)
         logits, value = self._heads(x)
-        return logits, value, self._policy_state(kv, conv, kda, pos + 1)
+        return logits, value, self._policy_state(held, pos + 1)
 
 
 def _refuse_unknown(cfg: dict, known, family: str) -> None:
@@ -2505,6 +2975,49 @@ def kimi_linear_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
         kda_heads=linear["num_heads"], kda_head_dim=linear["head_dim"],
         kda_taps=linear["short_conv_kernel_size"], selection_bias=True,
         topk_eps=1e-20)
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def nemotron_h_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks `nemotron_h`'s
+    published `config.json`'s own keys (a key left out has
+    Nemotron-Labs-TwoTower-30B-A3B-Base's value), and the two that state
+    the chip's share of the experts; unknown keys are refused, and so is a
+    published key whose value the decoder has no part for (a bias on a
+    projection, no bias on the convolution, expert groups, a window, a
+    clamp on the time step, a gated feed-forward). The family's parts: ONE
+    function a layer by the letters of `hybrid_override_pattern` (the
+    leading `num_hidden_layers` are read): `M` Mamba-2, `E` un-gated
+    squared-ReLU experts beside a shared one of a width of its own, `*`
+    grouped-head attention without positions and without QK-norm (a dense
+    feed-forward `-` is refused: the decoder has no un-gated dense one);
+    sigmoid scores with a selection bias in one group, renormalised over
+    their sum and scaled; an untied head. It is the config's one tower as
+    an autoregressive policy: the second, denoising tower that the
+    family's description speaks of has no key here."""
+    known = (set(NEMOTRON_H_CONFIG_KEYS) | set(NEMOTRON_H_FIXED)
+             | set(NEMOTRON_H_UNREAD) | {"hybrid_override_pattern"})
+    _refuse_unknown(cfg, known, "nemotron_h")
+    if "time_step_limit" in cfg:  # a tuple says what a list says
+        cfg = dict(cfg, time_step_limit=list(cfg["time_step_limit"]))
+    _refuse_other_values(cfg, NEMOTRON_H_FIXED)
+    merged = {**NEMOTRON_H_PUBLISHED, **cfg}
+    fields = {NEMOTRON_H_CONFIG_KEYS[k]: v for k, v in merged.items()
+              if k in NEMOTRON_H_CONFIG_KEYS}
+    pattern = merged["hybrid_override_pattern"]
+    unknown = set(pattern) - set(NEMOTRON_H_LAYERS)
+    if unknown or len(pattern) < fields["num_layers"]:
+        raise ValueError(
+            f"hybrid_override_pattern {pattern!r} names "
+            f"{fields['num_layers']} layers by {sorted(NEMOTRON_H_LAYERS)}; "
+            f"TokenDecoder has no layer {sorted(unknown)}")
+    layer_types = tuple(NEMOTRON_H_LAYERS[c] for c in pattern)
+    fields.update(
+        layer_types=layer_types, rope_layout=(False,) * len(layer_types),
+        one_function_layers=True, gated_feed_forward=False,
+        hidden_act="relu2", qk_norm=False, selection_bias=True)
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=num_outputs, **fields)

@@ -1,0 +1,58 @@
+"""The five accepted token configurations' fused programs are what they were
+before the sixth joined the decoder: `_anakin_fn` of each cell at its
+rehearsal size lowers to the text it lowered to at the parent of PR 45 (the
+texts' hashes were recorded there, from a `git archive` of that commit), so
+that what `dropless_experts`, `causal.block`, `decode` and the policy state's
+bookkeeping gained for a layer that is one function, an expert without a
+gate matrix and a fourth kind of state is shown not to reach them: no
+operation added, dropped or moved. A change that is MEANT to alter one of
+these programs records the new hash here, in the PR that measures the cell.
+"""
+
+import hashlib
+import importlib
+import json
+import os
+import sys
+
+import pytest
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+# sha256 of `_anakin_fn.lower(..).as_text()` (StableHLO, no locations) at the
+# cell's `rehearse_trainer_config`, seed 7, one CPU device.
+LOWERED = {
+    "olmoe_token_anakin":
+        "4fec4e16d174a38996ceabaf8c465abbd230402334e558fa3159482df9536be7",
+    "glm47_flash_token_anakin":
+        "6b4d8b098ce704eacf91de4658761e154d8eccdbcf55de25658f2155f8c825a3",
+    "smallthinker_token_anakin_8k":
+        "da33fc9b9a18d8bdbd3f76dd8fa291e8fd6129238a084c576b56263c2ad53149",
+    "lfm2_token_anakin_4k":
+        "43c36d8bd5802cec6d145ec2e336d051e5c5a4218cc2a0fd5bc451f2bd223ecd",
+    "kimi_linear_token_anakin_4k":
+        "b074e3a5eb77a14c26456ba836df77090edb2771a82888895a652b3ace05b2f0",
+}
+
+
+@pytest.mark.parametrize("cell", LOWERED)
+def test_an_accepted_token_cell_lowers_to_the_text_it_had(cell):
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        workload = json.load(f)
+    with open(os.path.join(BENCH, "configs",
+                           workload["config"] + ".json")) as f:
+        config = json.load(f)
+    driver = importlib.import_module("drivers." + workload["driver"])
+    session = driver.open_session(config, workload, 7, 1, True)
+    try:
+        opt, policy = session.optimizer, session.policy
+        text = opt._anakin_fn.lower(
+            policy.params, policy.opt_state, opt._env_state, opt._obs,
+            opt._rng, opt._ep_rew, opt._ep_len, opt._pstate).as_text()
+    finally:
+        session.close()
+    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED[cell], (
+        f"{cell}'s fused program changed: {len(text.splitlines())} lines")
