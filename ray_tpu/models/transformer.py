@@ -61,7 +61,12 @@ the key; `kda_heads` heads, d_k = d_v = `kda_head_dim`, P = heads x d:
   and the convolutions' last taps - 1 inputs, [B, taps - 1, 3 P] in
   `compute_dtype`; no positions axis. Two forms that agree. A step
   (`kda_step`): decay S by rows, u = beta (v - S^T k), S += k u^T, read
-  q; elementwise work and two sums, three passes over S. A fragment
+  q; elementwise work and two sums, three passes over S as XLA compiles
+  it. A decode step of a program lowered for a TPU, at states of whole
+  tiles, takes the same step as a kernel (`kda_decode_step`,
+  `models/state_step.py`): a tile of S is fetched into VMEM once, both
+  sums and the update happen there, and it goes back once, in place. A
+  fragment
   (`kda_chunked`): chunks of `kda_chunk` (64) positions; inside a chunk
   the delta rule in its triangular (UT / WY) form, one unit lower
   triangular system a chunk and head, (I + Diag(beta) kk) against [beta V
@@ -110,7 +115,9 @@ shared by `ssm_groups` groups of heads, `ssm_state` values each:
   Its state is that matrix, [B, heads, P, N] in FLOAT32 (its key of the
   policy state is "ssm"), and the convolution's last taps - 1 inputs, [B,
   taps - 1, I + 2 G N] in `compute_dtype`. Two forms that agree. A step
-  (`ssd_step`): the recurrence as written, S read once and written once.
+  (`ssd_step`): the recurrence as written, S read once and written once
+  by XLA's own fusions, at the rate a copy through VMEM reads (so it has
+  no kernel: `models/state_step.py`).
   A fragment (`ssd_chunked`): chunks of `ssm_chunk` (128) positions; inside
   a chunk position t reads position s <= t of its episode with the weight
   exp(sum of the log decays after s up to t) (C_t . B_s), a [C, C] matrix a
@@ -300,7 +307,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import decode_attention
+from ray_tpu.models import decode_attention, state_step
 
 Dtype = Any
 
@@ -1294,6 +1301,29 @@ def kda_step(S, q, k, v, g, beta):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
+def _kda_reset_step(S, q, k, v, g, beta, reset):
+    """`kda_step` of the states zeroed in the rows that `reset` [B] > 0,
+    as XLA compiles it: the select rides in the first pass."""
+    S = jnp.where((reset > 0)[:, None, None, None], 0.0, S)
+    return kda_step(S, q, k, v, g, beta)
+
+
+_kda_in_place = state_step.in_place(state_step.kda_kernel, _kda_reset_step)
+
+
+def kda_decode_step(S, q, k, v, g, beta, reset):
+    """A decode step of KDA's states, those of the rows that `reset` zeroed
+    first. Where the states are whole tiles (`state_step.whole_tiles`, of the
+    static shape) a program lowered for a TPU takes the kernel, S read once
+    and written once in place, with the plain form's derivative; everywhere
+    else the plain form, XLA's three passes."""
+    operands = (S, q, k, v, g, beta, reset)
+    if state_step.whole_tiles(*S.shape[1:]):
+        return jax.lax.platform_dependent(
+            *operands, tpu=_kda_in_place, default=_kda_reset_step)
+    return _kda_reset_step(*operands)
+
+
 def _ssd_chunk(x, Bm, Cm, la, episode, before, dtype):
     """One chunk of `ssd_chunked` up to the state it begins with, for every
     row and head at once, the heads by group: x [B, G, R, C, P] (dt x; R
@@ -2078,8 +2108,10 @@ class TokenDecoder(nn.Module):
         three, or Mamba-2's one): the layers that have it, and the bytes of
         it a row, whatever the length, from the state's own leaves. A model
         with KDA layers, or with Mamba-2 layers: how many they are, the
-        bytes of their matrix states a row, and the positions in a chunk
-        of the learner's scan. A model whose heads are grouped: the query
+        bytes of their matrix states a row, the positions in a chunk of
+        the learner's scan, and whether a decode step passes over the
+        states in place, by the kernel (1.0: KDA's, of whole tiles), or by
+        XLA's fusions (0.0). A model whose heads are grouped: the query
         heads a key/value head."""
         k, E = self.experts_per_token, self.num_experts
         kernel = False
@@ -2142,6 +2174,11 @@ class TokenDecoder(nn.Module):
                             for a in jax.tree.leaves(state[kind]))})
             if chunks[kind]:
                 out[f"{kind}_chunk"] = chunks[kind]
+        if "kda_layers" in out or "ssm_layers" in out:
+            out["state_step_kernel"] = float(
+                platform == "tpu" and "kda_layers" in out and all(
+                    state_step.whole_tiles(*S.shape[1:])
+                    for S in jax.tree.leaves(state["kda"])))
         return out
 
     def __call__(self, obs, state, reset):
@@ -2476,16 +2513,17 @@ class TokenDecoder(nn.Module):
 
     def _kda_step(self, lp, x, tails, S, reset):
         """The same of one token a row, x [B, H], against the row's
-        states, zeroed first where `reset` (`kda_step`); (h, the
-        convolutions' inputs with this one appended and the oldest dropped,
-        the matrix state)."""
+        states, zeroed first where `reset` (`kda_decode_step`: in a
+        program lowered for a TPU the kernel that reads a state once and
+        writes it once in place, elsewhere `kda_step` after a select); (h,
+        the convolutions' inputs with this one appended and the oldest
+        dropped, the matrix state)."""
         with jax.named_scope("policy/kda"):
             n, mixed = self._kda_inputs(lp, x)
             mixed, taps = _taps_step(mixed, lp["kda_conv"], tails, reset)
             q, k, v, g, beta = self._kda_heads(lp, n, mixed)
             with jax.named_scope("policy/kda_state"):
-                S = jnp.where((reset > 0)[:, None, None, None], 0.0, S)
-                o, S = kda_step(S, q, k, v, g, beta)
+                o, S = kda_decode_step(S, q, k, v, g, beta, reset)
             return self._kda_output(lp, x, n, o), taps[:, 1:], S
 
     # -- Mamba-2, both forms ------------------------------------------------
@@ -2544,15 +2582,17 @@ class TokenDecoder(nn.Module):
 
     def _ssm_step(self, lp, x, tails, S, reset):
         """The same of one token a row, x [B, H], against the row's
-        states, zeroed first where `reset` (`ssd_step`); (h, the
-        convolution's inputs with this one appended and the oldest dropped,
-        the matrix state)."""
+        states, zeroed first where `reset` (`ssd_step`, XLA's fusions on
+        every platform); (h, the convolution's inputs with this one
+        appended and the oldest dropped, the matrix state)."""
         with jax.named_scope("policy/mamba2"):
             z, xBC, dt = self._ssm_inputs(lp, x)
             with jax.named_scope("policy/short_conv"):
                 mixed, taps = _taps_step(xBC, lp["ssm_conv"], tails, reset)
             dtx, by_head, Bm, Cm, la = self._ssm_heads(lp, mixed, dt)
             with jax.named_scope("policy/ssm_state"):
+                # No kernel: these fusions pass over S once each way, at
+                # the rate of a copy through VMEM (`state_step`'s header).
                 S = jnp.where((reset > 0)[:, None, None, None], 0.0, S)
                 y, S = ssd_step(S, dtx, Bm, Cm, la)
             return self._ssm_output(lp, x, z, y, by_head), taps[:, 1:], S

@@ -572,3 +572,30 @@ def test_a_cache_stored_by_head_would_be_copied_every_step(one_chip):
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in compiled
     assert cache_copies(compiled, rows, S)
+
+
+@pytest.mark.parametrize("rows", [32, 2])
+def test_the_state_step_compiles_for_a_v5e_at_the_cell_s_widths(
+        rows, one_chip):
+    """The other kernel file's (`models/state_step.py`; here because this is
+    the one file that loads the chip's compiler): Mosaic takes the fifth
+    cell's 32 heads of [128, 128] (the rollout's 32 rows, the bootstrap
+    step's 2), the turned vectors and the one-lane slices as they stand, and
+    a scan's carried states go through it where they lie: no copy of one."""
+    def steps(S, q, k, v, g, beta, reset):
+        def one(S, _):
+            o, S = transformer.kda_decode_step(S, q, k, v, g, beta, reset)
+            return S, jnp.sum(o)
+        return jax.lax.scan(one, S, None, length=4)
+    f32 = jnp.float32
+    vector = shaped(one_chip, rows, 32, 128)
+    compiled = jax.jit(steps, donate_argnums=(0,)).trace(
+        shaped(one_chip, rows, 32, 128, 128, dtype=f32), vector, vector,
+        vector, shaped(one_chip, rows, 32, 128, dtype=f32),
+        shaped(one_chip, rows, 32, dtype=f32),
+        shaped(one_chip, rows, dtype=jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert "kda_state_step" in compiled and "tpu_custom_call" in compiled
+    assert not [line for line in compiled.splitlines()
+                if " copy(" in line and f"f32[{rows},32,128,128]" in
+                line.split(" copy(")[0]]

@@ -792,14 +792,15 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "causal_attention_fused": 1.0, "latent_cache_bytes_per_token": 1152,
         "conv_layers": 4, "conv_state_bytes_per_row": 294912,
         "kda_layers": 4, "kda_state_bytes_per_row": 8388608,
-        "kda_chunk": 64}
+        "kda_chunk": 64, "state_step_kernel": 1.0}
     assert not transformer.causal_fused(4096, 192, 128)
     assert model._latent_key_width(4096) == 256
     assert model._latent_key_width(S) == 192
     # Off a TPU the cache is read whole, by XLA's products.
     off = model.static_counters(32, 4096, "cpu")
     assert (off["causal_attention_fused"], off["decode_cache_block"],
-            off["decode_attention_kernel"]) == (0.0, 4096, 0.0)
+            off["decode_attention_kernel"], off["state_step_kernel"]) == (
+                0.0, 4096, 0.0, 0.0)
     state = jax.eval_shape(lambda: model.initial_state(32))
     assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["kv"])] == [
         ((32, 4096, 576), jnp.bfloat16)]

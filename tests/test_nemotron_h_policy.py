@@ -686,10 +686,12 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 1024.0,
         "kv_groups": 16, "conv_layers": 3,
         "conv_state_bytes_per_row": 110592, "ssm_layers": 3,
-        "ssm_state_bytes_per_row": 6291456, "ssm_chunk": 128}
+        "ssm_state_bytes_per_row": 6291456, "ssm_chunk": 128,
+        "state_step_kernel": 0.0}
     off = model.static_counters(128, 2048, "cpu")
     assert (off["causal_attention_fused"], off["decode_cache_block"],
-            off["decode_attention_kernel"]) == (0.0, 2048, 0.0)
+            off["decode_attention_kernel"], off["state_step_kernel"]) == (
+                0.0, 2048, 0.0, 0.0)
     assert transformer.grouped_fused(2048, 2, 32, 128)
     assert not transformer.experts_batched(8192, 6, 128)
     assert transformer.dispatch_rows(8192, 6, 8, 128) == (
