@@ -193,8 +193,9 @@ stage without its exchange. One sum, two blockings, chosen from the static
 shape (`experts_batched`). Grouped: the (row, expert) pairs sorted by
 expert, absent experts' pairs last, the landed ones' rows (up to a static
 size chosen from the landed count, `dispatch_rows`) multiplied group by
-group (`jax.lax.ragged_dot`) and added to their rows of the sum; the
-learner's minibatch and a prefill.
+group (`grouped_product`: the library's Pallas grouped-matmul kernels on a
+TPU at shapes that have tiles, `jax.lax.ragged_dot` elsewhere) and added to
+their rows of the sum; the learner's minibatch and a prefill.
 Batched: every row through every held expert in products batched over the
 experts, each term weighted w_e or exactly 0 before the sum; a decode step,
 whose groups of a few rows would each cost the grouped product an MXU tile
@@ -1492,8 +1493,153 @@ DISPATCH_TILE = 128
 # took 2.29 ms at a width of 1,856 (14.5 lane tiles), 2.07 at 1,920 (15) and
 # 0.90 at 2,048; a layer's recomputed forward and backward 22.6-26.4 ms as
 # published and 13.6-15.1 padded with zeros (a v5e, PERF.md section 7, "Left
-# by PR 45"). Every other family's width is whole tiles already.
+# by PR 45"). Every other family's width is whole tiles already. One rule:
+# the width is padded where the products are `ragged_dot` by the static
+# shape (`experts_fused` of the ladder's first size), and not where they
+# may be the kernels, which took 1,856 as published faster than 2,048
+# padded (a layer's recomputed forward and backward 6.3 against 7.3 ms, and
+# `ragged_dot`'s 12.6; PERF.md section 5).
 RAGGED_TILE = 256
+
+
+# The tiles of the grouped products' kernels (`grouped_product`,
+# `grouped_tiles`). On a v5e at the six cells' learner shapes, bf16 (PERF.md
+# section 5 has the sweep): 256 rows a tile beat 128 by 1-3 % and 512 by
+# 4-30 % (a group that starts inside a tile costs the tile twice); the
+# contraction whole, so that a group's matrix is fetched once a column tile
+# and not once a tile of rows; and the widest column tile up to 1,024 that
+# divides the width beat 512 by 3-14 % (1,792 = 2 x 896, 1,536 = 2 x 768,
+# 2,560 = 4 x 640) and a tile that leaves the last one part-filled.
+GROUPED_ROWS = (256, 128)
+GROUPED_COLUMNS = 1024
+# Bytes of VMEM a kernel's tiles may take, each operand's tile twice (the
+# pipeline's two buffers) and the float32 sums: the largest measured to
+# compile under the chip's 16 MiB (128 x 2,688 x 1,024).
+GROUPED_VMEM = 13.5 * 2 ** 20
+
+
+def _columns_tile(n: int) -> int:
+    """Columns in a tile over a width `n`: the widest whole number of lane
+    tiles up to `GROUPED_COLUMNS` that divides it, of at least three; else
+    `GROUPED_COLUMNS`, the last tile part-filled (nemotron_h's 1,856)."""
+    whole = [t for t in range(GROUPED_COLUMNS, 383, -128) if n % t == 0]
+    return whole[0] if whole else GROUPED_COLUMNS
+
+
+def _product_tiles(R: int, K: int, N: int):
+    """`gmm`'s tiles for [R, K] against [K, N] a group: the contraction
+    whole, the columns' tile, and the most rows that divide `R` and fit."""
+    tn = _columns_tile(N)
+    for tm in GROUPED_ROWS:
+        if R % tm == 0 and (4 * (tm * K + K * tn) + 8 * tm * tn
+                            <= GROUPED_VMEM):
+            return tm, K, tn
+    return None
+
+
+def grouped_tiles(R: int, K: int, N: int, dtype=jnp.bfloat16):
+    """The tiles `(rows, contraction, columns)` of the three kernels of one
+    grouped product of `R` sorted rows `K` wide against `[E, K, N]`
+    (`grouped_product`): the product's own, its pullback to the rows (`N`
+    contracted, `K` columns) and its pullback to the matrices (the rows
+    contracted, a `[K, N]` tile a group); or None where the product stays
+    XLA's `jax.lax.ragged_dot`. A function of the static shape alone:
+    operands of two bytes, whole tiles of rows, and widths of at least 512
+    in whole half lane tiles, which is what the kernels were measured at
+    (no shape of the six cells lost to `ragged_dot`, so the rule leaves
+    none of them out)."""
+    if jnp.dtype(dtype).itemsize != 2 or any(
+            d < 512 or d % 64 for d in (K, N)):
+        return None
+    forward, to_rows = _product_tiles(R, K, N), _product_tiles(R, N, K)
+    if forward is None or to_rows is None:
+        return None
+    rows = next(tm for tm in GROUPED_ROWS if R % tm == 0)
+    return forward, to_rows, (rows, _columns_tile(K), _columns_tile(N))
+
+
+def experts_fused(R: int, H: int, W: int, dtype=jnp.bfloat16) -> bool:
+    """Whether the grouped form's products over `R` gathered rows, hidden
+    `H` and the experts' width `W`, can be the library's grouped-matmul
+    kernel: a function of the static shape alone (beside `causal_fused`,
+    `grouped_fused`, `decode_fused`): the up product's shape has tiles,
+    and with it the down product's, whose kernels are the up product's
+    pullbacks' with the widths' places traded."""
+    return grouped_tiles(R, H, W, dtype) is not None
+
+
+def _megablox():
+    """The library's grouped matrix products for a TPU (Pallas), `gmm` and
+    `tgmm`. (The package's own name `gmm` is its `custom_vjp` over the two,
+    which takes one tiling for all three products, whose contracted and
+    column widths trade places.)"""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _gmm(lhs, rhs, group_sizes, tiles, transpose_rhs=False, interpret=False):
+    """Row r of group e of lhs [R, K] times rhs[e] [K, N] (`transpose_rhs`:
+    [N, K]), tile by tile over the tiles that hold rows of a group: [R, N]
+    in lhs' dtype, float32 sums; rows in no group are not written."""
+    return _megablox().gmm(lhs, rhs, group_sizes, lhs.dtype, tiles,
+                           transpose_rhs=transpose_rhs, interpret=interpret)
+
+
+def _tgmm(lhs, rhs, group_sizes, tiles, interpret=False):
+    """lhs [R, K]^T rhs [R, N] over each group's rows: [E, K, N] in lhs'
+    dtype, float32 sums, zeros for a group without rows; rows in no group
+    are masked out of both operands."""
+    # The library takes lhs as [K, R] and views it back: no copy is made.
+    return _megablox().tgmm(lhs.swapaxes(0, 1), rhs, group_sizes, lhs.dtype,
+                            tiles, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _fused_product(tiles, rows, w, group_sizes):
+    return jax.lax.platform_dependent(
+        rows, w, group_sizes, default=jax.lax.ragged_dot,
+        tpu=lambda rows, w, sizes: _gmm(rows, w, sizes, tiles[0]))
+
+
+def _fused_product_pullback(tiles, kept, cotangent):
+    def kernels(rows, w, sizes, cotangent):
+        return (_gmm(cotangent, w, sizes, tiles[1], transpose_rhs=True),
+                _tgmm(rows, cotangent, sizes, tiles[2]))
+
+    def plain(rows, w, sizes, cotangent):
+        return jax.vjp(lambda rows, w: jax.lax.ragged_dot(rows, w, sizes),
+                       rows, w)[1](cotangent)
+    return (*jax.lax.platform_dependent(
+        *kept, cotangent, tpu=kernels, default=plain), None)
+
+
+_fused_product.defvjp(
+    lambda tiles, *operands: (_fused_product(tiles, *operands), operands),
+    _fused_product_pullback)
+
+
+def grouped_product(rows, w, group_sizes):
+    """Row r of rows [R, K], sorted by group, times the matrix of its group
+    in w [E, K, N]; `group_sizes` [E] int32, the rows of each group in
+    turn: [R, N] in the operands' dtype, accumulated in float32. Rows past
+    the last group are in no product: their place in the result, and in
+    the gradient handed back for `rows`, holds whatever the memory held on
+    a TPU, in both forms (`dropless_experts`' `landed` is what keeps that
+    out); they add nothing to the matrices' gradient, and a group without
+    rows gets zeros.
+
+    Two forms of that one product. XLA's `jax.lax.ragged_dot` and its
+    transposes; and, where the static shape has tiles (`grouped_tiles`)
+    AND the program is lowered for a TPU (`jax.lax.platform_dependent`),
+    the library's Pallas grouped-matmul kernels (megablox): `gmm` for the
+    product and, against the transposed matrices, for the rows' gradient,
+    `tgmm` for the matrices' gradient, each with tiles of its own, over
+    the tiles of rows that hold a row of some group and no others."""
+    tiles = grouped_tiles(*rows.shape, w.shape[2], rows.dtype)
+    if tiles is None:
+        return jax.lax.ragged_dot(rows, w, group_sizes)
+    return _fused_product(tiles, rows, w, group_sizes)
 
 
 def dispatch_rows(M: int, k: int, held: int, E: int) -> tuple:
@@ -1536,7 +1682,10 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
 
     Grouped: the M*k (row, expert) pairs sorted by expert, those of absent
     experts last and in no group; the first R sorted pairs' rows gathered,
-    three `ragged_dot`s over the E groups, and each output row weighted
+    three products over the E groups (at the ladder's first size
+    `grouped_product`: the library's grouped-matmul kernels in a program
+    lowered for a TPU at shapes that have tiles; `jax.lax.ragged_dot` at
+    the other sizes and everywhere else), and each output row weighted
     and scatter-added to its row of the float32 sum. Where every expert
     is here R is M*k. A layer that holds a share gathers, multiplies and
     adds the pairs that landed on it: R is the first of `dispatch_rows`'
@@ -1544,9 +1693,12 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     everything after the sort); the last size is M*k, so no pair is
     dropped, whatever the router does. The rows between the landed count
     and R are in no group; they are made 0 on the way into the products
-    and on the way out, in both passes (`landed`), and add nothing. The
-    experts' width is padded with zeros to whole tiles of `RAGGED_TILE`
-    (no width but nemotron_h's 1,856 needs it).
+    and on the way out, in both passes (`landed`), and add nothing. Where
+    the products are `ragged_dot` by the static shape the experts' width
+    is padded with zeros to whole tiles of `RAGGED_TILE` (no width but
+    nemotron_h's 1,856 needs it): ahead of the `switch` where no size
+    takes the kernels, inside a later size's branch where the first
+    does.
 
     Batched: c[m, e] = p[m, j] where top_i[m, j] == first + e, else 0;
     a[e, m] = silu(n W_gate,e) * (n W_up,e) for all M rows and every held
@@ -1580,20 +1732,34 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
                                preferred_element_type=jnp.float32)
         return mixed.astype(n.dtype), group_sizes, jnp.int32(M * k)
 
+    sizes = dispatch_rows(M, k, E, num_experts or E)
+    # The kernels serve the size that the expected load takes, the ladder's
+    # first: every kernel a size compiles is traced anew at every start
+    # (~0.2 s each, eight a size; PERF.md section 6), for branches that a
+    # cell takes in none to a quarter of its layer-updates.
+    fused = experts_fused(sizes[0], *w_up.shape[1:], n.dtype)
     pad = -w_up.shape[-1] % RAGGED_TILE
-    if pad:
-        # Zero columns of W_up (and W_gate) and zero rows of W_down: act(0)
-        # is 0 for every activation here, and the sum is the same sum.
-        w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, pad)))
-        w_down = jnp.pad(w_down, ((0, 0), (0, pad), (0, 0)))
-        if w_gate is not None:
-            w_gate = jnp.pad(w_gate, ((0, 0), (0, 0), (0, pad)))
+
+    def padded(*weights):
+        """Zero columns of W_up (and W_gate) and zero rows of W_down: act(0)
+        is 0 for every activation here, and the sum is the same sum."""
+        if not pad:
+            return weights
+        return tuple(jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
+                     for w in weights[:-1]) + (
+            jnp.pad(weights[-1], ((0, 0), (0, pad), (0, 0))),)
 
     def grouped(R, n, top_p, *operands):
         """The sum over the first R sorted pairs, [M, H] float32;
         `operands`: the experts' matrices (`weights`), then `whole`."""
-        (*gate, w_up, w_down), (order, count, group_sizes) = (
-            operands[:-3], operands[-3:])
+        weights, (order, count, group_sizes) = operands[:-3], operands[-3:]
+        if fused and R == sizes[0]:
+            product = grouped_product
+        else:
+            product = jax.lax.ragged_dot
+            if fused:
+                weights = padded(*weights)
+        *gate, w_up, w_down = weights
         def landed(x):
             """Sorted rows [R, ..] with those past the last group made 0,
             and their cotangents with them. A `ragged_dot` computes no row
@@ -1614,13 +1780,12 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             rows = landed(n[source])
         with jax.named_scope("policy/experts"):
             if gate:
-                gate = landed(jax.lax.ragged_dot(rows, gate[0], group_sizes))
-                up = landed(jax.lax.ragged_dot(rows, w_up, group_sizes))
+                gate = landed(product(rows, gate[0], group_sizes))
+                up = landed(product(rows, w_up, group_sizes))
                 hidden = act(gate) * up
             else:
-                hidden = act(landed(
-                    jax.lax.ragged_dot(rows, w_up, group_sizes)))
-            out = jax.lax.ragged_dot(landed(hidden), w_down, group_sizes)
+                hidden = act(landed(product(rows, w_up, group_sizes)))
+            out = product(landed(hidden), w_down, group_sizes)
         with jax.named_scope("policy/dispatch"):
             weighted = landed(out).astype(jnp.float32) \
                 * top_p.reshape(-1)[pairs][:, None]
@@ -1630,8 +1795,9 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     with jax.named_scope("policy/dispatch"):
         order = jnp.argsort(local.reshape(-1), stable=True)
         count = jnp.sum(group_sizes)
-    sizes = dispatch_rows(M, k, E, num_experts or E)
     weights = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    if not fused:
+        weights = padded(*weights)
     floats, whole = (n, top_p) + weights, (order, count, group_sizes)
     if len(sizes) == 1:
         return (grouped(M * k, *floats, *whole).astype(n.dtype),
@@ -2088,7 +2254,7 @@ class TokenDecoder(nn.Module):
                    if has[kind]}, "pos": pos}
 
     def static_counters(self, batch_size: int, fragment_len: int,
-                        platform: str) -> dict:
+                        platform: str, learner_rows: int = 0) -> dict:
         """What the program is, from its static shapes and the platform
         it is compiled for. A decode step of `batch_size` rows: the mean
         rows a held expert group holds, whether the experts multiply in
@@ -2098,7 +2264,12 @@ class TokenDecoder(nn.Module):
         of every attention layer) or XLA's products (0.0), and with a
         latent cache its bytes a position. A causal pass over
         fragments of `fragment_len` tokens: whether its attention takes
-        the fused form (1.0) or the plain one (0.0). A model with caches of
+        the fused form (1.0) or the plain one (0.0); and over a minibatch
+        of `learner_rows` tokens (0: not said), whether the experts'
+        grouped products are the grouped-matmul kernel (1.0: at the first
+        of the sizes the dispatch compiles, the one the expected load
+        takes) or XLA's `ragged_dot` (0.0, and where the minibatch takes
+        the batched form). A model with caches of
         a head's own keys and values: the bytes of cache a position of the
         context that its attention layers hold together (a ring counts
         for its own length). A model with window layers: how many they
@@ -2143,6 +2314,13 @@ class TokenDecoder(nn.Module):
             "causal_attention_fused": float(
                 platform == "tpu" and causal_fused(fragment_len, *widths)),
         }
+        if learner_rows:
+            out["experts_grouped_kernel"] = float(
+                platform == "tpu"
+                and not experts_batched(learner_rows, k, E)
+                and experts_fused(
+                    dispatch_rows(learner_rows, k, self.held, E)[0],
+                    self.hidden_size, self.expert_width, self.compute_dtype))
         itemsize = jnp.dtype(self.compute_dtype).itemsize
         if self.kv_lora_rank:
             out["latent_cache_bytes_per_token"] = (

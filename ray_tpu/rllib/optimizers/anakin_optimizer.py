@@ -142,11 +142,12 @@ class AnakinOptimizer(PolicyOptimizer):
                     f"context ({context} positions)")
         self._replays_state = policy.recurrent and context is None
         # Trace-time facts of the rollout's decode step and the learner's
-        # pass over a fragment, where the model states any: host values
-        # put beside the program's stats.
+        # pass over a minibatch of fragments, where the model states any:
+        # host values put beside the program's stats.
         counters = getattr(policy.model, "static_counters", None)
         self._static_counters = {} if counters is None else counters(
-            num_envs, self.T, policy.mesh.devices.flat[0].platform)
+            num_envs, self.T, policy.mesh.devices.flat[0].platform,
+            self.minibatch)
 
         # Device-resident env state: one slot per env, batch-sharded.
         vreset = jax.vmap(self.env.reset)

@@ -566,8 +566,11 @@ def test_the_cell_s_forms_are_chosen_from_the_static_shape():
         lambda v, *a: model.apply(v, *a, mutable=["losses"]))(
             variables, *shapes(8, 1024)))
     assert decode.count("= ragged_dot_general[") == 0
+    assert decode.count("name=_fused_product") == 0
     # (The printer shows a recomputed block's body once for all its uses.)
-    grouped = learn.count("= ragged_dot_general[")
+    # A grouped product at these widths is `grouped_product`'s two forms,
+    # the kernel's and `ragged_dot`'s, under one name (PR 47).
+    grouped = learn.count("name=_fused_product")
     assert grouped > 0 and grouped % 3 == 0
 
 
@@ -844,6 +847,7 @@ def test_glm_token_trainer_trains_on_the_fused_path(token_trainer):
     # What the learner's product gathered: all, in the batched form these
     # sizes take.
     assert kept["dispatch_rows_share"] == 1.0
+    assert kept["experts_grouped_kernel"] == 0.0  # this is no TPU
     assert 3.0 < kept["mtp_loss"] < 6.0  # ln 96 = 4.56 at random weights
     assert kept["decode_rows_per_expert"] == 8 * 2 / 8
     assert kept["decode_experts_batched"] == 1.0

@@ -438,8 +438,11 @@ def test_the_form_is_chosen_from_the_static_shape():
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), *shapes(1, 1))
     decode = str(jax.make_jaxpr(model.apply)(params, *shapes(128, 1)))
     learn = str(jax.make_jaxpr(model.apply)(params, *shapes(8, 1024)))
+    # A grouped product at these widths is `grouped_product`'s two forms,
+    # the kernel's and `ragged_dot`'s, under one name (PR 47).
     assert decode.count("= ragged_dot_general[") == 0
-    assert learn.count("= ragged_dot_general[") == 3
+    assert decode.count("name=_fused_product") == 0
+    assert learn.count("name=_fused_product") == 3
 
 
 def test_policies_without_experts_never_import_the_transformer():
@@ -569,6 +572,7 @@ def test_token_trainer_trains_on_the_fused_path(token_trainer):
     kept = token_trainer.optimizer.learner_stats
     assert kept["decode_rows_per_expert"] == 2.0
     assert kept["decode_experts_batched"] == 1.0
+    assert kept["experts_grouped_kernel"] == 0.0  # this is no TPU
     # Every expert is here: no share of the pairs to count.
     assert "experts_held_row_share" not in kept
     assert "dispatch_rows_share" not in kept

@@ -563,6 +563,7 @@ def test_smallthinker_token_trainer_trains_on_the_fused_path(token_trainer):
     # What the learner's product gathered: all, in the batched form these
     # sizes take.
     assert kept["dispatch_rows_share"] == 1.0
+    assert kept["experts_grouped_kernel"] == 0.0  # this is no TPU
     assert kept["decode_rows_per_expert"] == 4 * 2 / 8
     assert kept["decode_cache_block"] == S
     # One block a cache: the full layer's 24 positions, a ring's 8 of 24.
