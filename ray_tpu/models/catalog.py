@@ -66,10 +66,16 @@ def _nemotron_h(obs_space, num_outputs, cfg, dtype):
     return nemotron_h_from_config(num_outputs, cfg, dtype)
 
 
+def _sdar_moe(obs_space, num_outputs, cfg, dtype):
+    from .transformer import sdar_moe_from_config
+    return sdar_moe_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
 CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite,
                  "smallthinker": _smallthinker, "lfm2_moe": _lfm2_moe,
-                 "kimi_linear": _kimi_linear, "nemotron_h": _nemotron_h}
+                 "kimi_linear": _kimi_linear, "nemotron_h": _nemotron_h,
+                 "sdar_moe": _sdar_moe}
 
 
 def _resolve_compute_dtype(cfg):

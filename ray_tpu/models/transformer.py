@@ -1,4 +1,4 @@
-"""Transformer token policies in flax: one decoder, six descriptions.
+"""Transformer token policies in flax: one decoder, seven descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
@@ -246,7 +246,10 @@ kimi_linear's low ranks, epsilons and the decay's initial draw are assumed
 an autoregressive policy (what the family's description adds, a second,
 denoising tower and decoding by diffusion over blocks, has no key there and
 is left out), its attention position-free, its decay's and time step's
-draws and the convolution's bias assumed. The OLMoE and
+draws and the convolution's bias assumed; sdar_moe's QK-norm a head
+(the Qwen3 body's; no key says it), its block of 4 and 2 passes, its
+sampler's rule and the MASK id are assumed (the configuration's file lists
+them), and its pre-training noise schedule enters nowhere. The OLMoE and
 glm4_moe_lite descriptions have as many key/value heads as query heads and
 refuse another count (their references have no grouped form; latent
 attention has no key/value heads to group).
@@ -287,6 +290,33 @@ One set of parameters, two forms (the stateful-policy protocol of
   and the window is whole blocks: each block of rows once for both
   products, the blocks up to the furthest position that the rows of a
   grid step hold.
+
+Generation by diffusion over blocks (`block_len` L, `denoise_steps` S;
+sdar_moe, `model_type: sdar_moe`: SDAR, arXiv:2510.06303, the objective and
+the mask of block diffusion, arXiv:2503.09573): the same parameters in two
+more forms, which take the place of `decode` and `causal` for such a model.
+The vocabulary's last id is the MASK id (its logit `MASK_LOGIT`: probability
+0); the logits at a position are the distribution of the token AT it.
+
+* `block_step`: one BLOCK of L positions a row. S denoising passes: the
+  block's positions enter as their token where given (an episode's first
+  position) or already unmasked and as the MASK id elsewhere, read the
+  caches' blocks before theirs and each other in both directions, and the L /
+  S still-masked positions with the highest top probability are unmasked,
+  each token drawn from its own distribution; then a commit pass of the
+  clean tokens, whose keys and values stay in the caches. S + 1 passes of L
+  rows a block. A pass writes its keys and values into the block's own slots
+  and its L x heads queries then read the cache up to the block's end, folded
+  into the cached heads' rows of queries, through the grouped decode kernel
+  as it is (`_attend_block`): no kernel of the block step's own, and no
+  merge of two softmaxes.
+* `block_causal`: whole episodes [B, T] on the sampler's trace (the pass
+  each position was unmasked at), one pass over S + 1 streams of T positions:
+  the clean one, block-causal, and one a denoising pass, whose queries read
+  the clean stream's keys of earlier blocks and their own block's
+  (`block_stream_attention`: the plain mask, or the splash kernel with the
+  mask computed inside it). Logits are taken at each position's own pass's
+  stream, values at each block's first position of pass 0's.
 
 Both return the state, so a decode can follow a causal pass: {"kv": a
 layer's caches (none for a layer that is no attention), "pos"}, and, a key
@@ -545,6 +575,52 @@ NEMOTRON_H_UNREAD = (
     "norm_eps")
 # A layer of the family's `hybrid_override_pattern` by its letter.
 NEMOTRON_H_LAYERS = {"M": "mamba2", "E": "experts", "*": "full_attention"}
+SDAR_MOE_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "num_hidden_layers": "num_layers",
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "norm_topk_prob": "norm_topk_prob",
+    "max_position_embeddings": "context_len",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_eps",
+    # The deployment's: the share of the experts this chip holds.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+    # The generation's, which `config.json` has no key for: the positions
+    # in a block and the denoising passes a block.
+    "block_length": "block_len",
+    "denoise_steps": "denoise_steps",
+}
+# What SDAR-30B-A3B-Chat's published `config.json` says, for the keys a
+# `custom_model_config` leaves out; the block is the family's Chat models'
+# published one, the steps this repo's choice.
+SDAR_MOE_PUBLISHED = {
+    "vocab_size": 151936, "hidden_size": 2048, "num_attention_heads": 32,
+    "num_key_value_heads": 4, "head_dim": 128, "num_hidden_layers": 48,
+    "num_experts": 128, "num_experts_per_tok": 8,
+    "moe_intermediate_size": 768, "norm_topk_prob": True,
+    "max_position_embeddings": 32768, "rope_theta": 1000000,
+    "rms_norm_eps": 1e-6, "block_length": 4, "denoise_steps": 2,
+}
+SDAR_MOE_FIXED = {
+    "attention_bias": False, "decoder_sparse_step": 1, "hidden_act": "silu",
+    "mlp_only_layers": [], "rope_scaling": None, "sliding_window": None,
+    "use_sliding_window": False, "tie_word_embeddings": False,
+    "model_type": "sdar_moe",
+}
+# Published keys that no part of the decoder reads: the dense feed-forward's
+# width (`mlp_only_layers` is empty and every layer sparse) and the layers a
+# window would reach (`use_sliding_window` false).
+SDAR_MOE_UNREAD = ("intermediate_size", "max_window_layers")
+# The MASK id's logit: its probability is exactly 0 in float32, as minus
+# infinity's is, and 0 x it is 0 where the entropy multiplies the two.
+MASK_LOGIT = -1e30
 # The operators a layer of `layer_types` may name; "experts" (a model of
 # `one_function_layers` alone) names a layer that is its feed-forward and
 # no operator.
@@ -860,6 +936,11 @@ def _causal_plain(q, k, v, episode, scale, window=0):
         episode[:, :, None] == episode[:, None, :])
     if window:
         mask = mask & (steps[:, None] - steps[None, :] < window)[None]
+    return _masked_plain(q, k, v, mask, scale)
+
+
+def _masked_plain(q, k, v, mask, scale):
+    """The plain form's sum under `mask` [B, queries, keys]."""
     if k.shape[1] != q.shape[1]:
         # Grouped heads: query head h against key/value head h // their
         # number a group.
@@ -878,15 +959,17 @@ def _causal_plain(q, k, v, episode, scale, window=0):
     return jnp.einsum("bhqk,bhkd->bhqd", attn, v)
 
 
-def _causal_fused(q, k, v, episode, scale, window=0):
+def _causal_fused(q, k, v, episode, scale, window=0, mask=None):
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
     B, heads, T, _ = q.shape
     groups = k.shape[1]
     t = CAUSAL_TILE
     # The kernel's static mask: which tiles it visits at all, and the
-    # mask it computes inside those the boundary crosses.
-    mask = (splash.LocalMask((T, T), (window - 1, 0), 0) if window
-            else splash.CausalMask((T, T)))
+    # mask it computes inside those the boundary crosses (`mask`: another
+    # than the causal or the window one, `block_stream_attention`'s).
+    if mask is None:
+        mask = (splash.LocalMask((T, T), (window - 1, 0), 0) if window
+                else splash.CausalMask((T, T)))
     settings = dict(
         block_sizes=splash.BlockSizes(
             block_q=t, block_kv=t, block_kv_compute=t, block_q_dkv=t,
@@ -972,6 +1055,78 @@ def causal_attention(q, k, v, episode, scale, window=0):
         q, k, v, episode,
         tpu=functools.partial(_causal_fused, scale=scale, window=window),
         default=functools.partial(_causal_plain, scale=scale, window=window))
+
+
+def block_stream_allowed(T: int, block: int):
+    """The mask of a block-diffusion learner's pass over `streams * T`
+    positions, the clean stream's T first and each noisy stream's T after it,
+    as a function of (query ids, key ids) that numpy and jax.numpy arrays
+    both pass through: a query of stream a in block b reads the CLEAN
+    stream's keys of the blocks before b and its OWN stream's keys of block
+    b, all of them. For the clean stream that is block-causal attention:
+    every position of its own and earlier blocks."""
+    def allowed(q, k):
+        q_stream, k_stream = q // T, k // T
+        q_block, k_block = (q % T) // block, (k % T) // block
+        return ((k_stream == 0) & (k_block < q_block)) | (
+            (k_stream == q_stream) & (k_block == q_block))
+    return allowed
+
+
+@functools.lru_cache(maxsize=None)
+def _block_stream_mask(T: int, block: int, streams: int):
+    """`block_stream_allowed` as a mask the splash kernel computes inside
+    itself, tile by tile, from the positions' numbers."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks)
+
+    class BlockStreamMask(masks._ComputableMask):
+        def __init__(self):
+            super().__init__((streams * T,) * 2,
+                             block_stream_allowed(T, block))
+
+        def __eq__(self, other):
+            return self is other
+
+        def __hash__(self):
+            return hash((type(self).__name__, T, block, streams))
+    return BlockStreamMask()
+
+
+def _streams_plain(q, k, v, episode, scale, block, streams):
+    n = q.shape[2]
+    ids = jnp.arange(n)
+    mask = block_stream_allowed(n // streams, block)(
+        ids[:, None], ids[None, :])[None] & (
+            episode[:, :, None] == episode[:, None, :])
+    return _masked_plain(q, k, v, mask, scale)
+
+
+def block_stream_attention(q, k, v, episode, scale, block, streams):
+    """softmax(q k^T * scale) v of a block-diffusion learner's pass: q
+    [B, heads, streams * T, d], k, v [B, groups, streams * T, d], the clean
+    stream's T positions first and each noisy stream's after it, `episode`
+    [B, streams * T] the episode a position belongs to (the clean stream's,
+    repeated). A query reads, within its episode, what
+    `block_stream_allowed` says: the clean stream's keys of earlier blocks
+    of `block` positions and its own stream's keys of its own block. Two
+    forms of that one sum, as `causal_attention`'s and chosen as its are
+    (`causal_fused` of the static shape, and the platform): the plain one's
+    scores are [streams * T, streams * T] a head; the fused one is the same
+    splash kernel with the mask computed inside it from the positions'
+    numbers, so that the tiles it visits are those in which any query may
+    read any key (T 2,048, two noisy streams: 38 of 144, where a causal
+    pass over T visits 10 of 16)."""
+    n = q.shape[2]
+    if not causal_fused(n, q.shape[3], v.shape[3]):
+        return _streams_plain(q, k, v, episode, scale, block, streams)
+    return jax.lax.platform_dependent(
+        q, k, v, episode,
+        tpu=functools.partial(
+            _causal_fused, scale=scale,
+            mask=_block_stream_mask(n // streams, block, streams)),
+        default=functools.partial(_streams_plain, scale=scale, block=block,
+                                  streams=streams))
 
 
 def _taps_causal(g, w, positions):
@@ -1981,6 +2136,11 @@ class TokenDecoder(nn.Module):
     nextn_layers: int = 0
     nextn_loss_weight: float = 0.1
     tie_embeddings: bool = False  # the head is the embedding, transposed
+    # Generation by diffusion over blocks: a step yields `block_len`
+    # positions a row, unmasked over `denoise_steps` passes (0: one token a
+    # step, autoregressive); the MASK id is the vocabulary's last.
+    block_len: int = 0
+    denoise_steps: int = 1
     context_len: int = 4096
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
@@ -1989,6 +2149,10 @@ class TokenDecoder(nn.Module):
     @property
     def held(self) -> int:
         return self.experts_held or self.num_experts
+
+    @property
+    def mask_id(self) -> int:
+        return self.vocab_size - 1
 
     @property
     def latent_width(self) -> int:
@@ -2161,6 +2325,18 @@ class TokenDecoder(nn.Module):
             raise ValueError(
                 f"{self.ssm_heads} state-space heads do not fall into "
                 f"{self.ssm_groups} groups")
+        if self.block_len and (
+                self.layer_types or self.kv_lora_rank or self.window_layout
+                or self.nextn_layers or self.router_before_attention
+                or self.block_len % self.denoise_steps
+                or self.context_len % self.block_len
+                or self.num_outputs != self.vocab_size):
+            raise ValueError(
+                "a block of positions a step is generated by a decoder whose "
+                "every layer is full attention over a head's own keys, in "
+                "denoising passes that divide the block, with logits over "
+                "the vocabulary whose last id is the MASK id, in a context "
+                "of whole blocks")
         self.embed = self.param(
             "embed", nn.initializers.normal(0.02), (self.vocab_size, H))
         self.layers = [
@@ -2283,10 +2459,21 @@ class TokenDecoder(nn.Module):
         the learner's scan, and whether a decode step passes over the
         states in place, by the kernel (1.0: KDA's, of whole tiles), or by
         XLA's fusions (0.0). A model whose heads are grouped: the query
-        heads a key/value head."""
+        heads a key/value head. A model that generates a block of positions
+        a step: the block, the denoising passes, the passes a generated
+        token costs the rollout ((denoise_steps + 1) / block_len) and the
+        rows a token costs the learner's layers (its streams: denoise_steps
+        + 1); its step's rows are `batch_size` blocks, and a cached head's
+        query rows a block's."""
         k, E = self.experts_per_token, self.num_experts
         kernel = False
         attention = self.attention_layers
+        step_rows = batch_size * (self.block_len or 1)
+        query_heads = self.num_heads * (self.block_len or 1)
+        # The learner's rows a position: a block model's streams.
+        streams = self.denoise_steps + 1 if self.block_len else 1
+        fragment_len, learner_rows = (
+            streams * fragment_len, streams * learner_rows)
         if self.kv_lora_rank:
             widths = (self._latent_key_width(fragment_len), self.v_head_dim)
             kernel = platform == "tpu" and bool(attention) and decode_fused(
@@ -2297,7 +2484,7 @@ class TokenDecoder(nn.Module):
             kernel = (platform == "tpu"
                       and self.kv_heads != self.num_heads and all(
                           grouped_fused(self.cache_len(i), self.kv_heads,
-                                        self.num_heads, self.head_width)
+                                        query_heads, self.head_width)
                           for i in attention))
         if kernel:
             block = decode_attention.BLOCK
@@ -2306,14 +2493,19 @@ class TokenDecoder(nn.Module):
         else:
             block = min(DECODE_CACHE_BLOCK, self.context_len)
         out = {
-            "decode_rows_per_expert": batch_size * k / E,
+            "decode_rows_per_expert": step_rows * k / E,
             "decode_experts_batched": float(
-                experts_batched(batch_size, k, E)),
+                experts_batched(step_rows, k, E)),
             "decode_cache_block": block,
             "decode_attention_kernel": float(kernel),
             "causal_attention_fused": float(
                 platform == "tpu" and causal_fused(fragment_len, *widths)),
         }
+        if self.block_len:
+            out.update(
+                block_len=self.block_len, denoise_steps=self.denoise_steps,
+                decode_passes_per_token=streams / self.block_len,
+                learner_rows_per_token=streams)
         if learner_rows:
             out["experts_grouped_kernel"] = float(
                 platform == "tpu"
@@ -2447,11 +2639,14 @@ class TokenDecoder(nn.Module):
         return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
 
     def _attend_causal(self, lp, x, positions, episode, cache_rows,
-                       window=0, rotary=True):
+                       window=0, rotary=True, streams=0):
         """x + Attention(RMSNorm(x)) over a fragment [B, T, H] from an
         empty window; (h, the layer's caches: the rows `cache_rows` of the
         fragment). A head's own keys: within `window` positions where
-        given, rotated where `rotary`. Head-major throughout: the
+        given, rotated where `rotary`; with `streams`, the fragment is a
+        block-diffusion learner's (`block_causal`: that many streams of T /
+        streams positions, read by `block_stream_attention`) and hands over
+        no caches. Head-major throughout: the
         projections write queries, keys and values as [B, heads, T, d],
         which `causal_attention` reads, and `W_o` contracts its output
         over (head, d) as it lies, so that no transposed copy of any of
@@ -2471,7 +2666,8 @@ class TokenDecoder(nn.Module):
 
         if not self.kv_lora_rank:
             groups = self.kv_heads
-            with jax.named_scope(self._attention_scope(window)):
+            with jax.named_scope("policy/block_attention" if streams
+                                 else self._attention_scope(window)):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
 
                 def projected(w, norm, heads):
@@ -2494,6 +2690,10 @@ class TokenDecoder(nn.Module):
                     k = rope(k, positions, self.rope_theta, head_major=True)
                     scale = 1.0
                 v = by_head(n, lp["wv"], groups)
+                if streams:
+                    return joined(block_stream_attention(
+                        q, k, v, episode, scale, self.block_len,
+                        streams)), ()
                 h = joined(causal_attention(q, k, v, episode, scale, window))
                 caches = tuple(
                     jnp.take_along_axis(jnp.swapaxes(a, 1, 2),
@@ -3034,6 +3234,212 @@ class TokenDecoder(nn.Module):
         return logits, value, self._policy_state(held, pos + 1)
 
 
+    # -- a block of positions a step: the two forms ----------------------
+    def _attend_block(self, lp, x, positions, caches, write_only=False):
+        """x + Attention(RMSNorm(x)) of a block of positions a row, x
+        [N, L, H] at `positions` [N, L], against the layer's caches: the
+        block's keys and values are written to their own slots first and
+        the L positions' queries then read every slot up to the block's
+        last, so that each reads the blocks before it and the whole of its
+        own, in both directions. One read of the cache serves the L
+        positions: they are folded into a cached head's rows of queries
+        (heads / groups x L of them a cached head) and go through
+        `cached_attention`'s grouped form as a decode step's do, kernel and
+        all. (h, the caches, the positions read); `write_only`: the keys
+        and values alone (a commit pass's last layer, whose output nothing
+        reads)."""
+        cd, eps = self.compute_dtype, self.rms_eps
+        N, L, _ = x.shape
+        k_cache, v_cache = caches
+        with jax.named_scope("policy/block_attention"):
+            n = rms_norm(x, lp["attn_norm"], eps, cd)
+            q, k, v = self._qkv(lp, n)
+            q = rope(q, positions, self.rope_theta)
+            k = rope(k, positions, self.rope_theta)
+            rows = jnp.arange(N)[:, None]
+            k_cache = k_cache.at[rows, positions].set(
+                k.reshape((N, L) + k_cache.shape[2:]))
+            v_cache = v_cache.at[rows, positions].set(
+                v.reshape((N, L) + v_cache.shape[2:]))
+            if write_only:
+                return None, (k_cache, v_cache), None
+            groups, d = self.kv_heads, self.head_width
+            by_head = k_cache.shape[:2] + (groups, d)
+            # [N, L, groups, heads a group, d] -> a cached head's rows.
+            q = jnp.swapaxes(q.reshape(N, L, groups, -1, d), 1, 2)
+            o, read = _grouped_attention(
+                q.reshape(N, -1, d), k_cache.reshape(by_head),
+                v_cache.reshape(by_head), positions[:, -1], d ** -0.5)
+            o = jnp.swapaxes(o.reshape(N, groups, L, -1, d), 1, 2)
+            h = x + jnp.dot(o.reshape(N, L, -1), lp["wo"].astype(cd))
+        return h, (k_cache, v_cache), read
+
+    def _block_pass(self, tokens, pos, caches, commit=False):
+        """One pass of a block step: `tokens` [N, L] at the positions
+        `pos` .. `pos + L - 1` through every layer; (the final hidden
+        vectors [N, L, H], the caches, the experts chosen [N, L, k] a layer
+        that chose, {layer: the positions its attention read}). A `commit` pass is run
+        for the keys and values it leaves: its last layer stops at them."""
+        N, L = tokens.shape
+        positions = pos[:, None] + jnp.arange(L)
+        x = self.embed[tokens].astype(self.compute_dtype)
+        held, experts, reads = [], [], {}
+        for i, (layer, layer_caches) in enumerate(zip(self.layers, caches)):
+            lp = layer()
+            last = commit and i == self.num_layers - 1
+            h, layer_caches, read = self._attend_block(
+                lp, x, positions, layer_caches, write_only=last)
+            held.append(layer_caches)
+            if last:
+                break
+            reads[i] = read
+            out, _, top_i = self._feed_forward(lp, h.reshape(N * L, -1))
+            x = out.reshape(N, L, -1)
+            experts.append(top_i.reshape(N, L, -1))
+        return x, tuple(held), experts, reads
+
+    def _token_logits(self, x):
+        """(logits over the vocabulary with the MASK id's at `MASK_LOGIT`,
+        values) of final hidden vectors x."""
+        logits, values = self._heads(x)
+        return jnp.where(jnp.arange(self.vocab_size) == self.mask_id,
+                         MASK_LOGIT, logits), values
+
+    def block_step(self, obs, state, reset, rng):
+        """The rollout's form of a model with a `block_len`: one BLOCK a row,
+        L = `block_len` positions generated by diffusion in S =
+        `denoise_steps` passes, then committed. obs [N] (the env's newest
+        token: read where a row begins an episode, whose first position it
+        is, GIVEN and never masked), reset [N] -> (tokens [N, L], their
+        log-probabilities [N, L] (0 of a given one), the pass each was
+        unmasked at [N, L] (-1 of a given one), value [N], state).
+
+        Pass s: every position of the block enters as its token where that
+        is given or was unmasked at a pass before s, as the MASK id
+        elsewhere; the logits at a position are the distribution of the
+        token AT it. Of the positions still masked the L / S with the highest
+        top probability are unmasked (ties to the lower position; the last
+        pass takes what is left), each token drawn from its own
+        distribution; which positions is a function of the pass's own
+        logits, so the block's probability is the product of its tokens'
+        at their own passes. The value is the value head's on the block's
+        first position in pass 0, which has seen the blocks before, the
+        given token and masks. After pass S - 1 the commit pass puts the
+        clean tokens through the layers for the keys and values that later
+        blocks read. A block costs S + 1 passes of L rows.
+
+        Every pass writes its keys and values into the block's own slots of
+        the caches, where the pass's queries read them (`_attend_block`);
+        the commit pass's are the ones that stay: of a denoising pass
+        nothing outlives the block."""
+        L, S = self.block_len, self.denoise_steps
+        pos = jnp.where(reset > 0, 0, state["pos"])
+        given = (pos == 0)[:, None] & (jnp.arange(L) == 0)
+        tokens = jnp.where(given, obs[:, None], self.mask_id).astype(jnp.int32)
+        steps = jnp.where(given, -1, S)  # S: still masked
+        logp = jnp.zeros(tokens.shape, jnp.float32)
+        caches = state["kv"]
+        experts, confident = [], []
+        before = jnp.arange(L)[:, None] < jnp.arange(L)[None, :]
+        for s in range(S):
+            with jax.named_scope("policy/block_denoise"):
+                x, caches, chosen_experts, reads = self._block_pass(
+                    tokens, pos, caches)
+                experts.append(jnp.stack(chosen_experts))
+                logits, values = self._token_logits(x)
+                if s == 0:
+                    value = values[:, 0]
+                logp_all = jax.nn.log_softmax(logits, axis=-1)
+                masked = steps == S
+                top = jnp.where(masked, jnp.max(logp_all, axis=-1), -jnp.inf)
+                # How many positions of the row come before position i in
+                # the order (top probability down, then position up).
+                ahead = jnp.sum(
+                    (top[:, :, None] > top[:, None, :])
+                    | ((top[:, :, None] == top[:, None, :]) & before),
+                    axis=1)
+                take = L // S if s < S - 1 else L
+                chosen = masked & (ahead < take)
+                drawn = jax.random.categorical(
+                    jax.random.fold_in(rng, s), logits, axis=-1)
+                tokens = jnp.where(chosen, drawn, tokens)
+                logp = jnp.where(chosen, jnp.take_along_axis(
+                    logp_all, drawn[..., None], axis=-1)[..., 0], logp)
+                steps = jnp.where(chosen, s, steps)
+                confident.append(jnp.sum(jnp.where(chosen, jnp.exp(top), 0.0))
+                                 / jnp.maximum(jnp.sum(chosen), 1))
+        with jax.named_scope("policy/block_commit"):
+            _, caches, commit_experts, _ = self._block_pass(
+                tokens, pos, caches, commit=True)
+        if not self.is_initializing():
+            self.sow("routing", "experts", jnp.stack(experts))
+            if commit_experts:  # none in a model of one layer
+                self.sow("routing", "commit_experts",
+                         jnp.stack(commit_experts))
+            self.sow("counters", "unmask_top_prob_mean",
+                     jnp.mean(jnp.stack(confident)))
+            self._count((), reads=reads)
+        return tokens, logp, steps, value, self._policy_state(
+            {"kv": caches}, pos + L)
+
+    def block_causal(self, tokens, steps, reset):
+        """The learner's form of a model with a `block_len`: a minibatch of
+        whole episodes [B, T] replayed on the sampler's own trace, `steps`
+        [B, T] the pass each position was unmasked at (-1: given). One pass
+        over S + 1 streams of T positions: the clean stream (block-causal),
+        and for each pass s the stream that pass saw, every position its
+        token where its step is below s and the MASK id elsewhere, whose
+        queries read the clean stream's keys of earlier blocks and their
+        own block's (`block_stream_attention`). Returns (logits [B, T, V],
+        each position's from the stream of the pass it was unmasked at (a
+        given position's from pass 0: nothing reads them), so that the head
+        runs over T rows; values [B, T / block_len], the value head on each
+        block's first position in pass 0). Every layer is recomputed in the
+        backward pass, as `causal`'s are."""
+        cd = self.compute_dtype
+        L, S = self.block_len, self.denoise_steps
+        B, T = tokens.shape
+        if T > self.context_len or T % L:
+            raise ValueError(
+                f"a fragment of {T} positions is whole blocks of {L} within "
+                f"the model's {self.context_len} positions")
+        at = jnp.arange(T)
+        starts = (reset > 0).at[:, 0].set(True)
+        episode = jnp.cumsum(starts, axis=1)
+        positions = at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
+        with jax.named_scope("policy/block_streams"):
+            inputs = jnp.concatenate([tokens] + [
+                jnp.where(steps < s, tokens, self.mask_id)
+                for s in range(S)], axis=1)
+            positions = jnp.tile(positions, (1, S + 1))
+            episode = jnp.tile(episode, (1, S + 1))
+
+        def block(lp, x):
+            h, _ = self._attend_causal(
+                lp, x, positions, episode, None, streams=S + 1)
+            out, load, top_i = self._feed_forward(
+                lp, h.reshape(B * (S + 1) * T, -1))
+            return out.reshape(x.shape), load, top_i
+        if self.num_layers > 1:
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.save_only_these_names(
+                    CAUSAL_KEPT))
+        x = self.embed[inputs].astype(cd)
+        loads, experts = [], []
+        for layer in self.layers:
+            x, load, top_i = block(layer(), x)
+            loads.append(load)
+            experts.append(top_i.reshape(B, (S + 1) * T, -1))
+        self._count(experts, loads)
+        with jax.named_scope("policy/block_streams"):
+            own = (1 + jnp.maximum(steps, 0)) * T + at
+            taken = jnp.take_along_axis(x, own[..., None], axis=1)
+            first = x[:, T:2 * T:L]
+        logits, _ = self._token_logits(taken)
+        _, values = self._heads(first)
+        return logits, values
+
+
 def _refuse_unknown(cfg: dict, known, family: str) -> None:
     unknown = set(cfg) - set(known)
     if unknown:
@@ -3239,3 +3645,38 @@ def nemotron_h_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def sdar_moe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks `sdar_moe`'s
+    published `config.json`'s own keys (a key left out has
+    SDAR-30B-A3B-Chat's value), the two that state the chip's share of the
+    experts and the two that state the generation, which the config has no
+    key for (`block_length`: the family's Chat models' 4; `denoise_steps`: 2,
+    this repo's choice); unknown keys are refused, and so is a published
+    key whose value the decoder has no part for. The family's parts (the
+    Qwen3 mixture-of-experts body): grouped-head attention of a `head_dim`
+    of its own with QK-norm over each head and RoPE, a softmax router over
+    every expert renormalised over the chosen, SwiGLU experts, no shared
+    expert, no dense layer, an untied head; generated and trained a BLOCK
+    at a time (`TokenDecoder.block_step`, `block_causal`). The vocabulary's
+    last id is the MASK id: the env draws from the ids below it, which is
+    what `num_outputs` has to say."""
+    known = (set(SDAR_MOE_CONFIG_KEYS) | set(SDAR_MOE_FIXED)
+             | set(SDAR_MOE_UNREAD))
+    _refuse_unknown(cfg, known, "sdar_moe")
+    if "mlp_only_layers" in cfg:  # a tuple says what a list says
+        cfg = dict(cfg, mlp_only_layers=list(cfg["mlp_only_layers"]))
+    _refuse_other_values(cfg, SDAR_MOE_FIXED)
+    fields = {SDAR_MOE_CONFIG_KEYS[k]: v
+              for k, v in {**SDAR_MOE_PUBLISHED, **cfg}.items()
+              if k in SDAR_MOE_CONFIG_KEYS}
+    if num_outputs != fields["vocab_size"] - 1:
+        raise ValueError(
+            f"sdar_moe's vocabulary of {fields['vocab_size']} ids ends in "
+            f"the MASK id: the env draws from {fields['vocab_size'] - 1}, "
+            f"not {num_outputs}")
+    fields.update(qk_norm="head")
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=fields["vocab_size"], **fields)

@@ -149,7 +149,83 @@ def forward_counted(policy, params, batch, T: int):
     return dist_inputs, values_flat, bootstrap_value, counters, model_losses
 
 
+def block_vtrace_loss(policy, params, batch, rng, loss_state):
+    """`vtrace_loss` of a policy whose step is a BLOCK of `policy.block_len`
+    positions a row (generation by diffusion over blocks): the block is one
+    action of the decision process and V-trace's time axis. Fragments are
+    whole episodes of T positions. The learner replays the sampler's trace
+    (`policy.apply_blocks`): a row's log-probability is its token's at the
+    pass it was unmasked at, log pi(block) the sum over its rows, log mu the
+    same sum of the rollout's, the block's reward the sum of its rows', its
+    discount gamma, done at the episode's last block (so the bootstrap value
+    never counts), its value the state's before the block; the entropy is
+    summed over the same rows. A GIVEN row (`sb.UNMASK_STEPS` -1: an
+    episode's first position) weighs nothing anywhere."""
+    cfg = policy.config
+    T, L = cfg["rollout_fragment_length"], policy.block_len
+    (logits, values), counters, model_losses = policy.apply_blocks(
+        params, batch)
+    B = logits.shape[0]
+
+    def blocks(x):
+        """[B * T] rows -> [T / L, B, L]: time-major blocks."""
+        return jnp.swapaxes(x.reshape(B, T // L, L), 0, 1)
+    generated = blocks(batch[sb.UNMASK_STEPS]) >= 0
+
+    def by_block(rows):
+        """The sum over each block's generated rows, [T / L, B]."""
+        return jnp.sum(jnp.where(generated, blocks(rows), 0.0), axis=-1)
+    logp_all = jax.nn.log_softmax(logits, axis=-1)
+    taken = jnp.take_along_axis(
+        logp_all, batch[sb.ACTIONS].reshape(B, T, 1).astype(jnp.int32),
+        axis=-1)
+    target_logp = by_block(taken.reshape(-1))
+    log_rhos = target_logp - by_block(batch[sb.ACTION_LOGP])
+    dones = jnp.max(blocks(batch[sb.DONES]), axis=-1)
+    values = values.T
+
+    returns = vtrace.from_importance_weights(
+        log_rhos=log_rhos,
+        discounts=cfg["gamma"] * (1.0 - dones),
+        rewards=by_block(batch[sb.REWARDS]),
+        values=values,
+        bootstrap_value=jnp.zeros(B, values.dtype),
+        clip_rho_threshold=cfg["vtrace_clip_rho_threshold"],
+        clip_pg_rho_threshold=cfg["vtrace_clip_pg_rho_threshold"],
+        lambda_=cfg["lambda"])
+    vs = jax.lax.stop_gradient(returns.vs)
+    pg_advantages = jax.lax.stop_gradient(returns.pg_advantages)
+
+    pi_loss = -jnp.sum(target_logp * pg_advantages)
+    vf_loss = 0.5 * jnp.sum((values - vs) ** 2)
+    entropy = jnp.sum(by_block(
+        -jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1).reshape(-1)))
+    total = (pi_loss
+             + cfg["vf_loss_coeff"] * vf_loss
+             - cfg["entropy_coeff"] * entropy)
+    for term in model_losses.values():
+        total = total + term
+    n = jnp.sum(generated)
+    rhos = jnp.exp(log_rhos)
+    stats = {
+        "total_loss": total,
+        "policy_loss": pi_loss / n,
+        "vf_loss": vf_loss / n,
+        "entropy": entropy / n,
+        "mean_kl_behaviour": jnp.mean(-log_rhos),
+        "vtrace_mean_vs": jnp.mean(vs),
+        "is_ratio_mean": jnp.mean(rhos),
+        "is_ratio_max": jnp.max(rhos),
+        # Rows of the minibatch that were given, not generated.
+        "given_rows": generated.size - n,
+        **counters,
+    }
+    return total, stats
+
+
 def vtrace_loss(policy, params, batch, rng, loss_state):
+    if policy.block_len:
+        return block_vtrace_loss(policy, params, batch, rng, loss_state)
     cfg = policy.config
     T = cfg["rollout_fragment_length"]
     gamma = cfg["gamma"]

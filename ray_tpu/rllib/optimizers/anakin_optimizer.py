@@ -33,12 +33,29 @@ the rollout began them with (`state_in`). A policy whose state is a context
 of `context_len` positions (a transformer's caches, whatever each layer
 keeps of them: every position, or a ring of its own window) is replayed
 from an empty context, so its fragments must be whole episodes: the
-optimizer refuses anything else.
+optimizer refuses anything else. Where a policy's episode has a position
+more than the env has steps (a block policy's, below), whole episodes are
+counted in positions.
+
+Block policies. A policy that declares a `block_len` L (generation by
+diffusion over blocks: `JaxPolicy.block_step_state`) yields L positions a
+slot a step, sampled inside the policy over several passes. The rollout
+scan is then over BLOCKS, T / L iterations a fragment of T positions, and
+the env is stepped inside an iteration once for each position the policy
+generated; an episode's first position is GIVEN (the env's own first
+observation, which the block's passes read unmasked): the env is not
+stepped for it, it earns nothing, its row weighs nothing in the loss, and
+`steps` counts ACTIONS, a fragment's rows less its given ones. An episode
+is whole blocks (env `episode_len` + 1 positions), so an episode ends where
+a block does.
 
 Trajectory. A step keeps obs, action, reward, done, and the behaviour
 policy's distribution inputs; where those are too wide to keep
 (`policy.keeps_dist_inputs`, decided from the action space's size) it
-keeps the taken action's log-probability and the value in their place.
+keeps the taken action's log-probability and the value in their place. A
+block policy's step keeps a row a position: the token there, its reward and
+done, its log-probability at the pass it was unmasked at and that pass's
+number (`sb.UNMASK_STEPS`, -1 where given), and the block's one value.
 
 Packing. The learner's batch is packed fragments, env-major: row
 `n * T + t` is step `t` of env slot `n`. The columns that are a scalar or
@@ -64,7 +81,9 @@ XLA never lays out unless a loss reads it. `vtrace_policy.forward_counted`
 feeds the model the rows as they lie and puts its logits and values in
 `sb.OBS`'s order. Every other case (scalar observations, stateful
 policies, minibatches, env counts that leave a lane tile part-filled)
-stacks and transposes as before.
+stacks and transposes as before. A block policy's columns are stacked
+[T / L, N, L] and packed alike: row `n * T + p` is POSITION p of slot n,
+and `sb.OBS` and `sb.ACTIONS` are both the token at it.
 """
 
 from __future__ import annotations
@@ -131,15 +150,30 @@ class AnakinOptimizer(PolicyOptimizer):
                 f"num_envs ({num_envs}) must divide evenly across the "
                 f"learner mesh ({mesh_size} devices)")
         context = getattr(policy.model, "context_len", None)
+        # Rows of a fragment that no action filled: a block policy's
+        # episode begins with a GIVEN position (the env's first
+        # observation), so its episode is one position longer than its
+        # actions.
+        block = policy.block_len
+        self._rows_given = 0
         if context is not None:
             episode = getattr(jax_env, "episode_len", None)
-            if not episode or episode > context or self.T % episode:
+            positions = episode and episode + bool(block)
+            if (not episode or positions > context or self.T % positions
+                    or positions % (block or 1)):
                 raise ValueError(
                     "a policy with a context of positions learns each "
                     "fragment from an empty one: rollout_fragment_length "
                     f"({self.T}) must be whole episodes of the env "
-                    f"(episode_len {episode}) and an episode must fit the "
-                    f"context ({context} positions)")
+                    f"(episode_len {episode}: {positions} positions, in "
+                    f"whole blocks of {block or 1}) and an episode must fit "
+                    f"the context ({context} positions)")
+            if block:
+                self._rows_given = self.T // positions
+        elif block:
+            raise ValueError(
+                "a policy that yields a block of positions a step states "
+                "its context (`context_len`)")
         self._replays_state = policy.recurrent and context is None
         # Trace-time facts of the rollout's decode step and the learner's
         # pass over a minibatch of fragments, where the model states any:
@@ -216,6 +250,20 @@ class AnakinOptimizer(PolicyOptimizer):
                 (zero, t * n) + (zero,) * len(row),
                 allow_negative_indices=False)
 
+        def tally(ep_rew, ep_len, ep_acc, reward, done, stepped=1):
+            """Episode bookkeeping of one env step of all slots (`stepped`:
+            1, or 0 for a slot that was not stepped): the running sums, the
+            completed episodes' sums and count, and `done` as float32."""
+            ep_rew = ep_rew + reward
+            ep_len = ep_len + stepped
+            donef = done.astype(jnp.float32)
+            ep_acc = (ep_acc[0] + jnp.sum(donef * ep_rew),
+                      ep_acc[1] + jnp.sum(donef * ep_len),
+                      ep_acc[2] + jnp.sum(donef))
+            ep_rew = jnp.where(done, 0.0, ep_rew)
+            ep_len = jnp.where(done, 0, ep_len)
+            return ep_rew, ep_len, ep_acc, donef
+
         def rollout_step(params, scarry, t):
             """Env step `t` of all slots under `params`: the carry, and
             (the step of the trajectory, what a stateful model counted)."""
@@ -236,16 +284,8 @@ class AnakinOptimizer(PolicyOptimizer):
             with jax.named_scope("anakin/env_step"):
                 env_state, next_obs, reward, done = vstep(
                     env_state, action, jax.random.split(ekey, N))
-                # Episode bookkeeping (completed-episode sums +
-                # counts).
-                ep_rew = ep_rew + reward
-                ep_len = ep_len + 1
-                donef = done.astype(jnp.float32)
-                ep_acc = (ep_acc[0] + jnp.sum(donef * ep_rew),
-                          ep_acc[1] + jnp.sum(donef * ep_len),
-                          ep_acc[2] + jnp.sum(donef))
-                ep_rew = jnp.where(done, 0.0, ep_rew)
-                ep_len = jnp.where(done, 0, ep_len)
+                ep_rew, ep_len, ep_acc, donef = tally(
+                    ep_rew, ep_len, ep_acc, reward, done)
                 if stateful:
                     pstate = (state, donef)
             if in_place:
@@ -255,8 +295,74 @@ class AnakinOptimizer(PolicyOptimizer):
             return (env_state, next_obs, rng, ep_rew, ep_len, ep_acc,
                     pstate, frames), (out, counted)
 
+        L = policy.block_len
+
+        def block_rollout_step(params, scarry, _):
+            """`rollout_step` of a policy whose step is a block of L
+            positions a slot: the policy samples the block
+            (`block_step_state`), then the env takes its tokens one by one,
+            but for a GIVEN one (the episode's first position: the env's own
+            observation), which the env is not stepped for and which earns
+            nothing. The block's rows are positions: (token, reward, done,
+            log-probability, unmask step) [N, L] and one value [N]."""
+            (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate,
+             frames) = scarry
+            with jax.named_scope("anakin/inference"):
+                rng, akey, ekey = jax.random.split(rng, 3)
+                tokens, logp, unmask, value, state, counted = \
+                    policy.block_step_state(params, obs, *pstate, akey)
+            with jax.named_scope("anakin/env_step"):
+                rewards, dones = [], []
+                for j in range(L):
+                    given = unmask[:, j] < 0
+                    stepped, next_obs, reward, done = vstep(
+                        env_state, tokens[:, j],
+                        jax.random.split(jax.random.fold_in(ekey, j), N))
+                    env_state = jax.tree.map(
+                        lambda old, new: jnp.where(given.reshape(
+                            (N,) + (1,) * (new.ndim - 1)), old, new),
+                        env_state, stepped)
+                    obs = jnp.where(given, obs, next_obs)
+                    reward = jnp.where(given, 0.0, reward)
+                    done = done & ~given
+                    ep_rew, ep_len, ep_acc, donef = tally(
+                        ep_rew, ep_len, ep_acc, reward, done,
+                        (~given).astype(ep_len.dtype))
+                    rewards.append(reward)
+                    dones.append(done)
+                # An episode is whole blocks: it ends with one.
+                pstate = (state, donef)
+            out = (tokens, jnp.stack(rewards, 1), jnp.stack(dones, 1), logp,
+                   unmask, value)
+            return (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate,
+                    frames), (out, counted)
+
+        def block_batch_of(traj, obs):
+            """A block policy's rollout as the learner's packed fragment
+            batch: a row a POSITION, `n * T + p` position p of slot n; the
+            token at it is what the model reads (`sb.OBS`) and what the
+            loss takes the log-probability of (`sb.ACTIONS`)."""
+            def rows(x):
+                """[T / L, N, L] -> [N * T]."""
+                return jnp.swapaxes(x, 0, 1).reshape(N * T)
+            tokens, rew, done, logp, unmask, value = traj
+            tokens = rows(tokens)
+            return {
+                sb.OBS: tokens,
+                sb.ACTIONS: tokens,
+                sb.REWARDS: rows(rew),
+                sb.DONES: rows(done).astype(jnp.float32),
+                sb.ACTION_LOGP: rows(logp),
+                sb.UNMASK_STEPS: rows(unmask),
+                # A block's value, at each of its rows.
+                sb.VF_PREDS: rows(jnp.repeat(value[..., None], L, axis=-1)),
+                sb.BOOTSTRAP_OBS: obs,
+            }
+
         def batch_of(traj, obs, pstate_in, frames):
             """The rollout as the learner's packed fragment batch."""
+            if L:
+                return block_batch_of(traj, obs)
             obs_t, act_t, rew_t, done_t, *kept = traj
             if in_place:
                 view = frames.reshape((D, T, n) + row)
@@ -285,6 +391,9 @@ class AnakinOptimizer(PolicyOptimizer):
             return batch
 
         learn = self.learn
+        # A scan iteration is an env step a slot, or a block of them.
+        a_step, scan_len = (block_rollout_step, T // L) if L \
+            else (rollout_step, T)
 
         def learn_minibatches(params, opt_state, batch, lkey):
             """`num_sgd_iter` passes over the rollout, `num_mb` updates a
@@ -335,10 +444,10 @@ class AnakinOptimizer(PolicyOptimizer):
                                  else "anakin/env_step"):
                 (env_state, obs, rng, ep_rew, ep_len, ep_acc, pstate,
                  frames), (traj, counted) = jax.lax.scan(
-                        lambda c, t: rollout_step(params, c, t),
+                        lambda c, t: a_step(params, c, t),
                         (env_state, obs, rng, ep_rew, ep_len, ep_acc,
                          pstate, frames),
-                        jnp.arange(T) if in_place else None, length=T)
+                        jnp.arange(T) if in_place else None, length=scan_len)
             with jax.named_scope("anakin/pack"):
                 batch = batch_of(traj, obs, pstate_in, frames)
             with jax.named_scope("anakin/loss"):
@@ -408,7 +517,9 @@ class AnakinOptimizer(PolicyOptimizer):
         policy._batch_on = len(self._obs.sharding.device_set)
         self._grad_time_total += time.perf_counter() - t0
         self._grad_calls += 1
-        n = self.updates_per_call * self.num_envs * self.T
+        # Steps are actions: a fragment's rows but for the given ones.
+        n = self.updates_per_call * self.num_envs * (
+            self.T - self._rows_given)
         self.num_steps_sampled += n
         self.num_steps_trained += n
         policy.global_timestep += n

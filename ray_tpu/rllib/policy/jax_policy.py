@@ -65,6 +65,12 @@ def default_optimizer(config: dict) -> optax.GradientTransformation:
     return tx
 
 
+def _newest(kept: dict, collection: str) -> dict:
+    """What a model sowed into `collection` in one `apply`: each name's
+    newest value ({} where it sowed nothing there)."""
+    return {k: v[-1] for k, v in kept.get(collection, {}).items()}
+
+
 class JaxPolicy(Policy):
     """A policy defined by a flax model + a loss function.
 
@@ -242,10 +248,7 @@ class JaxPolicy(Policy):
         the caller keeps that collection, as this one does)."""
         out, kept = self.apply(params, obs_bt, state, reset,
                                mutable=["counters", "losses"])
-        counters, losses = (
-            {k: v[-1] for k, v in kept.get(name, {}).items()}
-            for name in ("counters", "losses"))
-        return out, counters, losses
+        return out, _newest(kept, "counters"), _newest(kept, "losses")
 
     def initial_state(self, batch_size: int):
         """The model's rollout state for `batch_size` rows, as the pytree
@@ -255,10 +258,52 @@ class JaxPolicy(Policy):
     def step_state(self, params, obs, state, reset):
         """One rollout step of a stateful policy: obs [B], reset [B] (1
         where the previous step ended an episode) -> (dist_inputs [B, O],
-        value [B], state, what the model counted in the step)."""
+        value [B], state, what the model counted in the step). ONE action a
+        row: the caller samples it from `dist_inputs`. A policy whose step
+        is a block of positions a row has `block_step_state` instead."""
         (dist_bt, val_bt, state), counted, _ = self._apply_counted(
             params, obs[:, None], state, reset[:, None])
         return dist_bt[:, 0], val_bt[:, 0], state, counted
+
+    @property
+    def block_len(self) -> int:
+        """Positions a rollout step of this policy yields a row: a model
+        that generates by diffusion over blocks declares its block (0: one
+        action a row a step, `step_state`'s contract)."""
+        return getattr(self.model, "block_len", 0)
+
+    def block_step_state(self, params, obs, state, reset, rng):
+        """`step_state`'s sibling for a policy that declares a `block_len`
+        L: one rollout step is one BLOCK a row, sampled inside the model
+        (the passes that unmask it and the draws interleave, hence `rng`).
+        obs [B] (read where a row begins an episode: its first position is
+        given), reset [B] -> (actions [B, L], their log-probabilities at the
+        pass each was unmasked [B, L], that pass [B, L] (-1 and
+        log-probability 0 where the position was given, not chosen), ONE
+        value [B]: the state's before the block, state, what the model
+        counted)."""
+        (actions, logp, steps, value, state), kept = self.model.apply(
+            params, obs, state, reset, rng, method="block_step",
+            mutable=["counters"])
+        return actions, logp, steps, value, state, _newest(kept, "counters")
+
+    def apply_blocks(self, params, batch):
+        """The learner's pass of a policy that declares a `block_len` over
+        [B, L] sequences of whole episodes, replayed on the rollout's own
+        trace (`sb.UNMASK_STEPS`): ((logits [B, L, V] of each row's token
+        at the pass it was unmasked, value [B, L / block_len] a block), the
+        model's "counters", its "losses"). Resets as `apply_sequences`'."""
+        L = self.train_seq_len
+        tokens = batch[sb.OBS]
+        B = tokens.shape[0] // L
+        dones = batch[sb.DONES].reshape(B, L)
+        reset = jnp.concatenate(
+            [jnp.zeros((B, 1), jnp.float32), dones[:, :-1]], axis=1)
+        out, kept = self.model.apply(
+            params, tokens.reshape(B, L),
+            batch[sb.UNMASK_STEPS].reshape(B, L), reset,
+            method="block_causal", mutable=["counters", "losses"])
+        return out, _newest(kept, "counters"), _newest(kept, "losses")
 
     def get_initial_state(self, batch_size: int = 1):
         """Per-env rollout state columns ([] for feedforward policies)."""

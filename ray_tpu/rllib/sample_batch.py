@@ -45,6 +45,12 @@ BOOTSTRAP_OBS = "bootstrap_obs"
 # distribution that actually selected the action, so V-trace ratios
 # stay exact; this column only records the lag for accounting.
 POLICY_LAG = "policy_lag"
+# The denoising pass at which a row's token was unmasked, [num_rows]
+# int32, of a policy that generates a block of positions a step
+# (`JaxPolicy.block_step_state`): 0 .. passes - 1, or -1 for a row whose
+# token was GIVEN (an episode's first position), which no policy chose and
+# no loss weighs.
+UNMASK_STEPS = "unmask_steps"
 # The observations of OBS where a fused rollout wrote them, shape
 # [groups, T, fragments / groups, ...]: one group of fragments a device,
 # time-major inside a group, so entry [g, t, b] is OBS's row

@@ -530,6 +530,8 @@ def test_the_kernel_compiles_for_a_v5e_at_the_cell_s_widths(rows, one_chip):
     (16, 28, 4, 128, 8192),    # the third's full cache
     (16, 28, 4, 128, 4096),    # its rings
     (1, 28, 4, 128, 8192),     # its bootstrap step
+    (64, 4 * 32, 4, 128, 2048),  # the seventh's block step: a block's 4
+                                 # positions folded into the 32 heads' rows
 ])
 def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
         rows, heads, groups, d, S, one_chip):
@@ -547,6 +549,25 @@ def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in compiled
     assert not cache_copies(compiled, rows, S)
+
+
+def test_the_stream_mask_compiles_inside_the_fused_causal_form(one_chip):
+    """Mosaic takes `block_stream_allowed`'s integer divisions inside the
+    splash kernel and its backward kernel at the seventh cell's learner
+    shape (a clean and two noisy streams of 2,048 positions, 32 query heads
+    over 4 key/value heads of 128), and no score matrix is written."""
+    n = 3 * 2048
+
+    def loss(q, k, v, episode):
+        return jnp.sum(transformer.block_stream_attention(
+            q, k, v, episode, 1.0, block=4, streams=3).astype(jnp.float32))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+        shaped(one_chip, 1, 32, n, 128), shaped(one_chip, 1, 4, n, 128),
+        shaped(one_chip, 1, 4, n, 128),
+        shaped(one_chip, 1, n, dtype=jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert compiled.count("tpu_custom_call") >= 2
+    assert f"f32[1,32,{n},{n}]" not in compiled
 
 
 def cache_copies(compiled, rows, S):

@@ -1,0 +1,591 @@
+"""The `sdar_moe` token policy, which GENERATES BY DIFFUSION OVER BLOCKS, at a
+tiny size on the CPU: the rollout's block step through the cache (S
+denoising passes and a commit pass a block) against the plain reference
+(`benchmark/lib/reference_sdar_moe.py`) on the trace it sampled, for S in
+{1, 2, 4} at a block of 4: log-probabilities, values, and the order the
+positions were unmasked in; the learner's pass over the same trace against
+the reference, and its log-probabilities equal to the rollout's at unchanged
+parameters; the reference's 2T form against the block-by-block definition;
+a block of one position and one pass against a plain masked forward; the
+eight shares of an expert layer against the uncut layer; the block-level
+V-trace against a hand-rolled one and the loss and its gradient against the
+reference's; a given row weighs nothing; each named wrong mathematics
+refused by the cell's limits; what the builder and the optimizer refuse;
+the trainer on the fused Anakin path from the tuned example.
+"""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+import yaml
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from lib import reference_sdar_moe as reference  # noqa: E402
+
+from ray_tpu.models import catalog, transformer  # noqa: E402
+from ray_tpu.rllib import sample_batch as sb  # noqa: E402
+from ray_tpu.rllib.agents.impala import vtrace  # noqa: E402
+
+# Two layers; 4 query heads over 2 cached ones of 16; 4 of 8 experts of 32
+# held, 2 a token; an episode of 24 positions, the first given.
+T, N = 24, 3
+NET = dict(vocab_size=96, hidden_size=64, num_attention_heads=4,
+           num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
+           num_experts=8, experts_held=4, first_expert_held=0,
+           num_experts_per_tok=2, moe_intermediate_size=32,
+           norm_topk_prob=True, max_position_embeddings=T,
+           rope_theta=1000000, rms_norm_eps=1e-6, block_length=4,
+           denoise_steps=2)
+CFG = {"gamma": 0.99, "lambda": 1.0, "vf_loss_coeff": 0.5,
+       "entropy_coeff": 0.01, "vtrace_clip_rho_threshold": 1.0,
+       "vtrace_clip_pg_rho_threshold": 1.0}
+
+
+def build(dtype="f32", **changed):
+    """(model, seeded variables, net). The norms' weights are seeded too
+    (one at initialisation), so that a norm's place shows."""
+    net = dict(NET, **changed)
+    model = catalog.get_model(None, net["vocab_size"] - 1, {
+        "custom_model": "sdar_moe", "custom_model_config": net,
+        "compute_dtype": dtype})
+    variables = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((N, 1), jnp.int32),
+        model.initial_state(N), jnp.zeros((N, 1)))
+
+    def seeded(path, a):
+        if not path[-1].key.endswith("norm"):
+            return a
+        key = jax.random.fold_in(jax.random.PRNGKey(2), a.size + len(path))
+        return a * (1.0 + 0.5 * jax.random.normal(key, a.shape))
+    return model, dict(variables, params=jax.tree_util.tree_map_with_path(
+        seeded, variables["params"])), net
+
+
+def rollout(model, variables, net, seed=5, positions=T):
+    """One episode a row by the model's own block steps from an empty
+    cache: the trace (tokens, logp, steps [N, T], values [N, T / L]) and the
+    experts each pass chose, in the learner's layout [layers, N, (S + 1) T,
+    k] (the commit passes' first, whose last layer chooses none: -1)."""
+    L, S = net["block_length"], net["denoise_steps"]
+    first = jax.random.randint(jax.random.PRNGKey(seed), (N,), 0,
+                               net["vocab_size"] - 1)
+
+    def step(carry, key):
+        state, reset = carry
+        (tokens, logp, steps, value, state), kept = model.apply(
+            variables, first, state, reset, key, method="block_step",
+            mutable=["routing", "counters"])
+        commit = kept["routing"]["commit_experts"][-1]
+        commit = jnp.concatenate(
+            [commit, jnp.full((1,) + commit.shape[1:], -1)], axis=0)
+        return (state, jnp.zeros_like(reset)), (
+            tokens, logp, steps, value, commit,
+            kept["routing"]["experts"][-1])
+    _, (tokens, logp, steps, values, commit, noisy) = jax.jit(
+        lambda keys: jax.lax.scan(
+            step, (model.initial_state(N), jnp.ones(N)), keys))(
+                jax.random.split(jax.random.PRNGKey(seed + 1),
+                                 positions // L))
+
+    def rows(x):
+        """[blocks, N, L, ..] -> [N, T, ..]."""
+        return jnp.swapaxes(x, 0, 1).reshape((N, positions) + x.shape[3:])
+    # [blocks, layers, N, L, k] -> [layers, N, T, k], a stream at a time.
+    streams = [commit] + [noisy[:, s] for s in range(S)]
+    experts = jnp.concatenate([
+        jnp.moveaxis(x, 0, 2).reshape(x.shape[1], N, positions, -1)
+        for x in streams], axis=2)
+    return {"tokens": rows(tokens), "logp": rows(logp),
+            "steps": rows(steps), "values": values.T, "experts": experts}
+
+
+def plain(variables, trace, net, experts=None, **how):
+    return jax.jit(lambda v, t, s, e: reference.forward(
+        v, t, s, net, experts=e, **how))(
+            variables, trace["tokens"], trace["steps"], experts)
+
+
+def taken(logits, tokens):
+    """log-probabilities [N, T] of `tokens` under logits over the real ids."""
+    logp = jax.nn.log_softmax(logits[..., :NET["vocab_size"] - 1], axis=-1)
+    return jnp.take_along_axis(logp, jnp.minimum(
+        tokens, logp.shape[-1] - 1)[..., None], axis=-1)[..., 0]
+
+
+@pytest.mark.parametrize("passes", [1, 2, 4])
+def test_block_step_through_the_cache_matches_reference(passes):
+    model, variables, net = build(denoise_steps=passes)
+    trace = rollout(model, variables, net)
+    L = net["block_length"]
+    steps = np.asarray(trace["steps"])
+    # The first position of an episode is given; every other is unmasked at
+    # one of the passes, L / S of them a pass.
+    assert (steps[:, 0] == -1).all() and (steps[:, 1:] >= 0).all()
+    assert steps.max() == passes - 1
+    for block in steps[:, L:].reshape(N, -1, L).reshape(-1, L):
+        assert sorted(block) == sorted(
+            s for s in range(passes) for _ in range(L // passes))
+    held = plain(variables, trace, net, trace["experts"])
+    generated = steps >= 0
+    np.testing.assert_allclose(
+        np.asarray(taken(held["logits"], trace["tokens"]))[generated],
+        np.asarray(trace["logp"])[generated], atol=2e-4)
+    assert (np.asarray(trace["logp"])[~generated] == 0).all()
+    np.testing.assert_allclose(held["values"], trace["values"], atol=2e-4)
+    routing = reference.routing_verdict(
+        trace["experts"], held["experts"], held["select"])
+    assert routing["router_flips"] <= 0.01, routing
+
+
+@pytest.mark.parametrize("passes", [2, 4])
+def test_the_unmask_order_is_the_reference_s_top_probabilities(passes):
+    """At pass s the positions unmasked are those of the still masked whose
+    top probability, by the reference's own logits of that pass, is
+    highest."""
+    model, variables, net = build(denoise_steps=passes)
+    trace = rollout(model, variables, net)
+    L, per = net["block_length"], net["block_length"] // passes
+    steps = np.asarray(trace["steps"])
+    p = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32),
+                     variables["params"])
+    for s in range(passes - 1):  # the last pass takes what is left
+        seen = reference.pass_inputs(trace["tokens"], trace["steps"], s, net)
+        both = jnp.concatenate([trace["tokens"], seen], axis=1)
+        with jax.default_matmul_precision("highest"):
+            x, _, _ = reference._hidden(
+                p, both, jnp.tile(jnp.arange(T), 2),
+                lambda q, k: ((k < T) & ((k % T) // L < (q % T) // L)) | (
+                    ((q >= T) == (k >= T)) & ((k % T) // L == (q % T) // L)),
+                net, lambda a: a, None, None)
+            logits, _ = reference._heads(p, x[:, T:], net)
+        top = np.asarray(jnp.max(jax.nn.log_softmax(logits, -1), -1))
+        for row in range(N):
+            for b in range(T // L):
+                at = np.arange(b * L, (b + 1) * L)
+                masked = at[steps[row, at] >= s]
+                chosen = set(at[steps[row, at] == s])
+                want = sorted(masked, key=lambda i: (-top[row, i], i))[:per]
+                margin = np.diff(np.sort(top[row, masked]))
+                if margin.size and margin.min() < 1e-5:
+                    continue  # a tie within float32's rounding
+                assert chosen == set(want), (row, b, s)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_learner_pass_matches_reference_and_the_rollout(dtype):
+    model, variables, net = build(dtype)
+    trace = rollout(model, variables, net)
+    (logits, values), kept = jax.jit(lambda v, t, s: model.apply(
+        v, t, s, jnp.zeros(t.shape), method="block_causal",
+        mutable=["routing", "counters"]))(
+            variables, trace["tokens"], trace["steps"])
+    experts = kept["routing"]["experts"][-1]
+    held = plain(variables, trace, net, experts)
+    out = reference.compare((logits[..., :-1], values),
+                            (held["logits"], held["values"]))
+    routing = reference.routing_verdict(experts, held["experts"],
+                                        held["select"])
+    limit = 1e-4 if dtype == "f32" else reference.TOLERANCE
+    assert max(out["errors"].values()) <= limit, out
+    assert routing["router_flips"] <= (0.01 if dtype == "f32" else
+                                       reference.MAX_ROUTER_FLIPS), routing
+    # The MASK id has probability 0.
+    assert float(jnp.max(jax.nn.softmax(logits, -1)[..., -1])) == 0.0
+    # At unchanged parameters the learner's log-probabilities are the
+    # rollout's: the importance ratio of every block is 1.
+    generated = np.asarray(trace["steps"]) >= 0
+    L = net["block_length"]
+    ratio = np.exp(np.where(
+        generated, np.asarray(taken(logits, trace["tokens"]))
+        - np.asarray(trace["logp"]), 0.0).reshape(N, -1, L).sum(-1))
+    np.testing.assert_allclose(
+        ratio, 1.0, atol=5e-4 if dtype == "f32" else 0.15)
+    if dtype == "f32":
+        np.testing.assert_allclose(values, trace["values"], atol=2e-4)
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_the_2t_form_is_the_block_by_block_definition(passes):
+    model, variables, net = build(denoise_steps=passes)
+    trace = rollout(model, variables, net)
+    whole = plain(variables, trace, net)
+    by_blocks = reference.forward_by_blocks(
+        variables, trace["tokens"], trace["steps"], net)
+    np.testing.assert_allclose(whole["logits"], by_blocks["logits"],
+                               atol=2e-5)
+    np.testing.assert_allclose(whole["values"], by_blocks["values"],
+                               atol=2e-5)
+
+
+def test_a_block_of_one_and_one_pass_is_a_plain_masked_forward():
+    """L 1, S 1: a step yields one token a row; position i's distribution
+    comes from the MASK id at i reading the clean tokens before it."""
+    model, variables, net = build(block_length=1, denoise_steps=1)
+    trace = rollout(model, variables, net)
+    assert trace["tokens"].shape == (N, T)
+    steps = np.asarray(trace["steps"])
+    assert (steps[:, 0] == -1).all() and (steps[:, 1:] == 0).all()
+    logits, values = [], []
+    p = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32),
+                     variables["params"])
+    with jax.default_matmul_precision("highest"):
+        for i in range(T):
+            seen = trace["tokens"][:, :i + 1]
+            if i:
+                seen = seen.at[:, i].set(net["vocab_size"] - 1)
+            x, _, _ = reference._hidden(
+                p, seen, jnp.arange(i + 1), lambda q, k: k <= q, net,
+                lambda a: a, None, None)
+            out = reference._heads(p, x[:, -1], net)
+            logits.append(out[0])
+            values.append(out[1])
+    logp = taken(jnp.stack(logits, 1), trace["tokens"])
+    np.testing.assert_allclose(
+        np.asarray(logp)[:, 1:], np.asarray(trace["logp"])[:, 1:], atol=2e-4)
+    np.testing.assert_allclose(jnp.stack(values, 1), trace["values"],
+                               atol=2e-4)
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """A chip's expert part is the sum over the experts it holds; the eight
+    shares' parts sum to the layer with every expert, in the reference and
+    in the system's dispatch alike."""
+    E, k, H, W, M = 16, 4, 32, 24, 40
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    lp = {"w_gate": jax.random.normal(keys[0], (E, H, W)) / 6,
+          "w_up": jax.random.normal(keys[1], (E, H, W)) / 6,
+          "w_down": jax.random.normal(keys[2], (E, W, H)) / 5}
+    m = jax.random.normal(keys[3], (M, H))
+    probs = jax.nn.softmax(jax.random.normal(keys[4], (M, E)), -1)
+    top_p, top_i = jax.lax.top_k(probs, k)
+    top_p = top_p / top_p.sum(-1, keepdims=True)
+
+    def share(first, held, fn):
+        part = {name: w[first:first + held] for name, w in lp.items()}
+        return fn(part, first)
+    plain_part = lambda part, first: reference.moe(  # noqa: E731
+        part, m, {}, lambda a: a, top_i, top_p, first=first)
+    system_part = lambda part, first: transformer.dropless_experts(  # noqa
+        m, top_p, top_i, part["w_gate"], part["w_up"], part["w_down"],
+        first, E)[0]
+    whole = share(0, E, plain_part)
+    for fn in (plain_part, system_part):
+        parts = [share(first, 2, fn) for first in range(0, E, 2)]
+        np.testing.assert_allclose(sum(parts), whole, atol=1e-5)
+        assert float(jnp.max(jnp.abs(parts[0] - whole))) > 0.01
+
+
+def test_block_vtrace_is_the_library_s_over_blocks():
+    """The reference's hand-rolled V-trace over blocks against
+    `vtrace.from_importance_weights` at a bootstrap value of 0."""
+    rng = np.random.default_rng(0)
+    B, n = 3, 7
+    log_rhos = jnp.asarray(rng.normal(0, 0.5, (B, n)), jnp.float32)
+    rewards = jnp.asarray(rng.integers(0, 5, (B, n)), jnp.float32)
+    values = jnp.asarray(rng.normal(0, 1, (B, n)), jnp.float32)
+    discounts = jnp.full((B, n), 0.99).at[:, -1].set(0.0)
+    vs, pg = reference.block_vtrace(log_rhos, discounts, rewards, values, CFG)
+    want = vtrace.from_importance_weights(
+        log_rhos=log_rhos.T, discounts=discounts.T, rewards=rewards.T,
+        values=values.T, bootstrap_value=jnp.zeros(B),
+        clip_rho_threshold=1.0, clip_pg_rho_threshold=1.0, lambda_=1.0)
+    np.testing.assert_allclose(vs.T, want.vs, atol=1e-5)
+    np.testing.assert_allclose(pg.T, want.pg_advantages, atol=1e-5)
+
+
+@pytest.fixture(scope="module")
+def trained():
+    """The trainer from the tuned example at the cell's rehearsal sizes."""
+    from ray_tpu.rllib.agents.registry import get_trainer_class
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            here, "ray_tpu/rllib/tuned_examples/sdar-token-impala.yaml")) as f:
+        (example,) = yaml.safe_load(f).values()
+    with open(os.path.join(
+            BENCH, "workloads/sdar_block_token_anakin_2k.json")) as f:
+        workload = json.load(f)
+    from drivers.rllib_trainer import merge
+    config = merge(dict(example["config"], env=example["env"], seed=11),
+                   workload["rehearse_trainer_config"])
+    config.pop("num_tpus_for_learner")
+    trainer = get_trainer_class(example["run"])(config=config)
+    yield trainer, example, workload
+    trainer.stop()
+
+
+def test_the_trainer_runs_from_the_tuned_example(trained):
+    trainer, example, workload = trained
+    assert example["config"]["model"]["custom_model"] == "sdar_moe"
+    cfg = trainer.config
+    envs, T_, episode = (trainer.optimizer.num_envs,
+                         cfg["rollout_fragment_length"],
+                         cfg["env_config"]["episode_len"])
+    result = trainer.train()
+    stats = result["info"]["learner"]
+    # Steps are actions: an episode's positions less its given first.
+    assert result["timesteps_total"] == envs * T_ // (episode + 1) * episode
+    assert np.isfinite(stats["total_loss"])
+    assert stats["block_len"] == 4 and stats["denoise_steps"] == 2
+    assert stats["decode_passes_per_token"] == 0.75
+    assert stats["learner_rows_per_token"] == 3
+    minibatches = envs * T_ // cfg["sgd_minibatch_size"]
+    assert stats["given_rows"] * minibatches == envs * T_ // (episode + 1)
+    assert 0 < stats["unmask_top_prob_mean"] <= 1
+    assert result["episodes_total"] == envs * T_ // (episode + 1)
+    assert result["episode_len_mean"] == episode
+
+
+def test_the_tuned_example_is_the_benchmark_s_cell(trained):
+    _, example, workload = trained
+    with open(os.path.join(
+            BENCH, "configs", workload["config"] + ".json")) as f:
+        config = json.load(f)
+    published = {k: v for k, v in config["network"].items()
+                 if k != "param_count"}
+    model = example["config"]["model"]
+    assert model["custom_model_config"] == published
+    for key, value in workload["trainer_config"].items():
+        if key != "env":
+            assert example["config"][key] == value, key
+    assert example["env"] == workload["trainer_config"]["env"]
+    for key in ("lr", "grad_clip", "min_iter_time_s"):
+        assert example["config"][key] == config["trainer_config"][key]
+
+
+def minibatch(policy, seed=0):
+    """A seeded minibatch of the trainer's shape, as the rollout packs it,
+    and the same for the reference."""
+    cfg = policy.config
+    T_, L = cfg["rollout_fragment_length"], policy.block_len
+    frags = cfg["sgd_minibatch_size"] // T_
+    net = dict(NET, **cfg["model"]["custom_model_config"])
+    rng = np.random.default_rng(seed)
+    tokens = rng.integers(0, net["vocab_size"] - 1, (frags, T_))
+    steps = np.stack([np.concatenate([
+        rng.permutation(np.repeat(np.arange(net["denoise_steps"]),
+                                  L // net["denoise_steps"]))
+        for _ in range(T_ // L)]) for _ in range(frags)])
+    episode = cfg["env_config"]["episode_len"] + 1
+    steps[:, ::episode] = -1
+    dones = np.zeros((frags, T_), np.float32)
+    dones[:, episode - 1::episode] = 1.0
+    ref = {"tokens": tokens, "steps": steps,
+           "rewards": rng.integers(0, 2, (frags, T_)).astype(np.float32),
+           "behaviour_logp": (-np.log(net["vocab_size"]) + rng.uniform(
+               -0.5, 0.5, (frags, T_))).astype(np.float32)}
+    batch = {
+        sb.OBS: jnp.asarray(tokens.reshape(-1), jnp.int32),
+        sb.ACTIONS: jnp.asarray(tokens.reshape(-1), jnp.int32),
+        sb.UNMASK_STEPS: jnp.asarray(steps.reshape(-1), jnp.int32),
+        sb.REWARDS: jnp.asarray(ref["rewards"].reshape(-1)),
+        sb.DONES: jnp.asarray(dones.reshape(-1)),
+        sb.ACTION_LOGP: jnp.asarray(ref["behaviour_logp"].reshape(-1)),
+        sb.VF_PREDS: jnp.zeros(frags * T_, jnp.float32),
+        sb.BOOTSTRAP_OBS: jnp.zeros(frags, jnp.int32)}
+    return batch, ref, net
+
+
+def loss_and_grad(policy, batch):
+    return jax.jit(jax.value_and_grad(
+        lambda p: policy._loss_fn(policy, p, batch, jax.random.PRNGKey(0),
+                                  policy.loss_state)[0]))(policy.params)
+
+
+def test_the_loss_and_its_gradient_are_the_reference_s(trained):
+    policy = trained[0].get_policy()
+    batch, ref, net = minibatch(policy)
+    assert ref["tokens"].shape[1] == net["max_position_embeddings"]
+    loss, grads = loss_and_grad(policy, batch)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: reference.vtrace_loss(
+            {"params": p}, ref, net, policy.config)[0]))(
+                policy.params["params"])
+    assert abs(float(loss) - float(want)) <= 1e-3 * abs(float(want))
+    flat = lambda tree: {  # noqa: E731
+        jax.tree_util.keystr(path): leaf for path, leaf in
+        jax.tree_util.tree_flatten_with_path(tree)[0]}
+    got, want_grads = flat(grads["params"]), flat(want_grads)
+    for name, g in want_grads.items():
+        scale = float(jnp.max(jnp.abs(g))) or 1.0
+        assert float(jnp.max(jnp.abs(got[name] - g))) <= 2e-3 * scale, name
+    # The MASK id's column of the head takes no gradient.
+    assert float(jnp.max(jnp.abs(grads["params"]["head"][:, -1]))) == 0.0
+
+
+def test_a_given_row_weighs_nothing(trained):
+    """Whatever stands in a given row's reward, behaviour log-probability
+    or action, the loss and its gradient are what they were."""
+    policy = trained[0].get_policy()
+    batch, _, _ = minibatch(policy, seed=1)
+    given = batch[sb.UNMASK_STEPS] < 0
+    assert int(jnp.sum(given)) > 0
+    loss, grads = loss_and_grad(policy, batch)
+    other = dict(
+        batch,
+        **{sb.REWARDS: jnp.where(given, 100.0, batch[sb.REWARDS]),
+           sb.ACTION_LOGP: jnp.where(given, -7.0, batch[sb.ACTION_LOGP]),
+           sb.ACTIONS: jnp.where(given, 3, batch[sb.ACTIONS])})
+    loss2, grads2 = loss_and_grad(policy, other)
+    assert float(loss) == float(loss2)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads2)):
+        np.testing.assert_array_equal(a, b)
+    # A generated row's does move it.
+    moved = dict(batch, **{sb.REWARDS: batch[sb.REWARDS] + 1.0})
+    assert float(loss_and_grad(policy, moved)[0]) != float(loss)
+
+
+def test_the_first_minibatch_of_a_rollout_is_on_policy(trained):
+    """The optimizer's own rollout, learned from at the parameters that
+    sampled it: every block's importance ratio is 1."""
+    trainer = trained[0]
+    opt, policy = trainer.optimizer, trainer.get_policy()
+    cfg = policy.config
+    frags = N
+    model = policy.model
+    net = dict(NET, **cfg["model"]["custom_model_config"])
+    trace = rollout(model, policy.params, net, seed=9,
+                    positions=cfg["rollout_fragment_length"])
+    rows = lambda x: x[:frags].reshape(-1)  # noqa: E731
+    dones = np.zeros(trace["tokens"].shape, np.float32)
+    dones[:, -1] = 1.0
+    batch = {
+        sb.OBS: rows(trace["tokens"]), sb.ACTIONS: rows(trace["tokens"]),
+        sb.UNMASK_STEPS: rows(trace["steps"]),
+        sb.REWARDS: jnp.ones(frags * dones.shape[1]),
+        sb.DONES: jnp.asarray(rows(dones)),
+        sb.ACTION_LOGP: rows(trace["logp"]),
+        sb.BOOTSTRAP_OBS: jnp.zeros(frags, jnp.int32)}
+    _, stats = jax.jit(lambda p: policy._loss_fn(
+        policy, p, batch, jax.random.PRNGKey(0), policy.loss_state))(
+            policy.params)
+    assert abs(float(stats["is_ratio_mean"]) - 1.0) <= 1e-3
+    assert abs(float(stats["is_ratio_max"]) - 1.0) <= 5e-3
+    assert float(stats["given_rows"]) == frags
+    assert opt.num_envs >= frags
+
+
+@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
+def test_limits_refuse_wrong_mathematics(wrong):
+    """The reference with a named error (or a precision lower) in the
+    system's place, held to its own experts: the cell's limits refuse it."""
+    model, variables, net = build()
+    trace = rollout(model, variables, net)
+    how = ({"round_to": wrong} if wrong == "float8_e4m3"
+           else {"mutate": wrong})
+    bad = plain(variables, trace, net, **how)
+    held = plain(variables, trace, net, bad["experts"])
+    out = reference.compare((bad["logits"], bad["values"]),
+                            (held["logits"], held["values"]))
+    routing = reference.routing_verdict(
+        bad["experts"], held["experts"], held["select"])
+    assert not (out["ok"] and routing["ok"]), (out, routing)
+
+
+def test_the_cell_s_program_is_known_from_its_static_shapes():
+    with open(os.path.join(
+            BENCH, "configs/impala_sdar_30b_a3b.json")) as f:
+        network = {k: v for k, v in json.load(f)["network"].items()
+                   if k != "param_count"}
+    model = catalog.get_model(None, network["vocab_size"] - 1, {
+        "custom_model": "sdar_moe", "custom_model_config": network})
+    got = model.static_counters(64, 2048, "tpu", 8192)
+    assert got["block_len"] == 4 and got["denoise_steps"] == 2
+    assert got["decode_passes_per_token"] == 0.75
+    assert got["learner_rows_per_token"] == 3
+    # 64 blocks of 4 rows, 8 of 128 experts each: 16 rows a held expert.
+    assert got["decode_rows_per_expert"] == 16.0
+    assert got["decode_experts_batched"] == 1.0
+    assert got["decode_attention_kernel"] == 1.0
+    assert got["causal_attention_fused"] == 1.0
+    assert got["experts_grouped_kernel"] == 1.0
+    assert got["kv_groups"] == 8
+    # 5 layers x (K and V) x 4 heads x 128 x 2 bytes.
+    assert got["kv_cache_bytes_per_token"] == 5 * 2048
+    on_cpu = model.static_counters(64, 2048, "cpu", 8192)
+    assert on_cpu["decode_attention_kernel"] == 0.0
+    assert on_cpu["causal_attention_fused"] == 0.0
+    state = jax.eval_shape(lambda: model.initial_state(64))
+    assert set(state) == {"kv", "pos"}
+    assert sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(state["kv"])) == 64 * 2048 * 5 * 2048
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 1), jnp.int32),
+                           model.initial_state(1), jnp.zeros((1, 1))))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 550987009
+
+
+def test_the_stream_mask_is_the_reference_s():
+    """`block_stream_allowed` over S + 1 streams against the reference's
+    own 2T mask, a pass at a time."""
+    T_, L, S = 12, 4, 2
+    ids = np.arange((S + 1) * T_)
+    got = transformer.block_stream_allowed(T_, L)(ids[:, None], ids[None, :])
+    for s in range(S):
+        keep = np.concatenate([ids[:T_], ids[(1 + s) * T_:(2 + s) * T_]])
+        q, k = np.arange(2 * T_)[:, None], np.arange(2 * T_)[None, :]
+        want = ((k < T_) & ((k % T_) // L < (q % T_) // L)) | (
+            ((q >= T_) == (k >= T_)) & ((k % T_) // L == (q % T_) // L))
+        np.testing.assert_array_equal(got[np.ix_(keep, keep)], want)
+    # A noisy stream never reads another noisy stream.
+    assert not got[T_:2 * T_, 2 * T_:].any()
+    assert not got[2 * T_:, T_:2 * T_].any()
+
+
+@pytest.mark.parametrize("cfg,outputs,match", [
+    ({"num_shared_experts": 1}, 95, "not sdar_moe's"),
+    ({"use_sliding_window": True}, 95, "use_sliding_window"),
+    ({"tie_word_embeddings": True}, 95, "tie_word_embeddings"),
+    ({}, 96, "MASK id"),
+    ({"block_length": 4, "denoise_steps": 3}, 95, "block"),
+    ({"block_length": 5}, 95, "block"),
+])
+def test_custom_model_config_without_a_part_is_refused(cfg, outputs, match):
+    net = dict(NET, **cfg)
+    with pytest.raises(ValueError, match=match):
+        model = transformer.sdar_moe_from_config(outputs, net, jnp.float32)
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+                   jax.eval_shape(lambda: None), jnp.zeros((1, 1)))
+
+
+def test_keys_left_out_have_the_published_model_s_values():
+    model = transformer.sdar_moe_from_config(151935, {})
+    assert (model.hidden_size, model.num_heads, model.num_kv_heads,
+            model.head_dim, model.num_layers) == (2048, 32, 4, 128, 48)
+    assert (model.num_experts, model.experts_per_token, model.expert_width,
+            model.norm_topk_prob) == (128, 8, 768, True)
+    assert (model.vocab_size, model.num_outputs, model.mask_id,
+            model.context_len) == (151936, 151936, 151935, 32768)
+    assert (model.block_len, model.denoise_steps, model.qk_norm,
+            model.rope_theta, model.rms_eps) == (4, 2, "head", 1000000, 1e-6)
+
+
+@pytest.mark.parametrize("episode_len,fragment", [(30, 32), (31, 48),
+                                                  (15, 32)])
+def test_the_optimizer_refuses_fragments_that_are_not_whole_episodes(
+        episode_len, fragment):
+    """An episode is the env's steps and its given first position, in whole
+    blocks, and a fragment whole episodes."""
+    from ray_tpu.rllib.agents.registry import get_trainer_class
+    config = dict(
+        env="TokenBigram-v0",
+        env_config={"vocab_size": 95, "episode_len": episode_len},
+        anakin=True, num_workers=0, num_envs_per_worker=4,
+        rollout_fragment_length=fragment, train_batch_size=4 * fragment,
+        min_iter_time_s=0,
+        model={"custom_model": "sdar_moe", "compute_dtype": "f32",
+               "custom_model_config": dict(NET, max_position_embeddings=64)})
+    if (episode_len + 1) % 4 == 0 and fragment % (episode_len + 1) == 0:
+        get_trainer_class("IMPALA")(config=config).stop()
+        return
+    with pytest.raises(ValueError, match="whole episodes"):
+        get_trainer_class("IMPALA")(config=config)

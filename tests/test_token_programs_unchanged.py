@@ -1,5 +1,7 @@
-"""The five accepted token configurations' fused programs are what they were
-before the sixth joined the decoder: `_anakin_fn` of each cell at its
+"""The accepted token configurations' fused programs are what they were
+before the next one joined the decoder (first the five before the sixth; PR
+48, which taught the optimizer, the policy and the loss a step that yields a
+block of positions, left the six recorded hashes as they stood): `_anakin_fn` of each cell at its
 rehearsal size lowers to the text it lowered to at the parent of PR 45 (the
 texts' hashes were recorded there, from a `git archive` of that commit), so
 that what `dropless_experts`, `causal.block`, `decode` and the policy state's
@@ -39,6 +41,10 @@ LOWERED = {
     # kernel), whose rule takes no rehearsal's shape.
     "nemotron_h_token_anakin_2k":
         "525b2b0cc7d801a41fad7a5b12eda8929be35f728f6a6ade6e66b763e3547eb0",
+    # The seventh, recorded by PR 48, which brought it: the one program whose
+    # rollout scan is over blocks.
+    "sdar_block_token_anakin_2k":
+        "c498606a43e9189daaa749d56e2f3e59e44ac5ffed8b6995e639a3863b92fb05",
 }
 
 
