@@ -41,6 +41,20 @@ where the head-major form above, grid (rows, G, blocks) with few query
 rows a cached head, paid 2.25 ns a cached position and head whatever the
 bytes (PERF.md section 7, PR 35). `attend_grouped` is its whole-cache form
 and `grouped_decode_attention` the kernel with that form's derivative.
+
+A step that generates a BLOCK of L positions a row (`transformer
+.block_step`) reads the positions before the block from the caches and the
+block itself, in both directions, from the pass's own keys and values,
+which are operands and lie in no cache (`attend_block`, `block_kernel`,
+`block_decode_attention`: plain form, kernel, kernel with the plain form's
+derivative): s < lengths[b] of the caches, 0 <= lengths[b], and all L fresh
+positions, one softmax. The kernel is `prefix_kernel`'s grid over flat
+caches with the fresh block folded into the online softmax first (a row
+that begins holds nothing cached, and its maximum is finite from there),
+and it scores cached head g's queries, heads // groups x L rows of them,
+against lanes [g d, (g + 1) d) of a fetched row alone: with L positions a
+row the block-diagonal form's zeros are no longer hidden behind the bytes.
+Only a commit pass writes (`write_block`).
 """
 
 from __future__ import annotations
@@ -67,6 +81,14 @@ from jax.experimental.pallas import tpu as pltpu
 # positions; XLA's two products 1.471 / 1.471): 128 x 8 0.436 / 0.756, 128 x
 # 16 0.427 / 0.757 (709 GB/s), 128 x 32 0.423 / 0.759, 256 x 16 0.438 /
 # 0.758, 512 x 16 0.465 / 0.760, 1,024 x 8 0.525 / 0.760: the same choice.
+# The block entry (64 rows, 4 positions x 32 query heads over 4 cached ones
+# of 128, 2,048 positions filling from empty; ms a layer-pass with the fold
+# of the queries and of the output, my chip run, PR 49): a pass that
+# scatters its block into the caches and reads it back through the
+# block-diagonal form 0.401; the fresh block as operands, block-diagonal
+# 0.325, a cached head against its own lanes 0.228 at 128 x 16 (0.237 at
+# 128 x 8, 0.225 at 128 x 32, 0.243 at 256 x 16, 0.277 at 512 x 16), where
+# the cache's bytes alone are 0.201 at the 709 GB/s above.
 BLOCK = 128
 ROWS = 16
 # Two buffers of ROWS x BLOCK x 640 lanes x 2 bytes are 5.2 MB (grouped
@@ -308,3 +330,209 @@ def _grouped_backward(scale, kept, g):
 
 
 grouped_decode_attention.defvjp(_grouped_forward, _grouped_backward)
+
+
+# -- a block of fresh positions a row beside the caches ----------------------
+def attend_block(q, k, v, k_new, v_new, lengths, scale):
+    """A block step's sum, the plain form: q [B, G, R, d], the R rows of
+    cached head g the queries of every position of the row's block
+    (`heads // groups` heads x L positions, in any order); over the cached
+    positions s < lengths[b] of k, v [B, S, G * d] (stored flat: a
+    position's cached heads one row) and over the block's own L positions
+    k_new, v_new [B, L, G * d], all of them (both directions), which no
+    cache holds yet. 0 <= lengths[b] <= S: a row that begins holds nothing,
+    and the block alone is its sum. One softmax over S + L scores; two
+    products a side, every position of the cache read by each."""
+    f32 = jnp.float32
+    B, G, R, d = q.shape
+
+    def by_head(a):
+        return a.reshape(B, -1, G, d)
+    held = jnp.arange(k.shape[1])[None, :] < lengths[:, None]
+    cached = jnp.einsum("bgrd,bsgd->bgrs", q, by_head(k),
+                        preferred_element_type=f32) * scale
+    cached = jnp.where(held[:, None, None, :], cached, -jnp.inf)
+    fresh = jnp.einsum("bgrd,bsgd->bgrs", q, by_head(k_new),
+                       preferred_element_type=f32) * scale
+    # The block's own scores are finite: so is the maximum.
+    m = jax.lax.stop_gradient(jnp.maximum(
+        jnp.max(cached, axis=-1, keepdims=True),
+        jnp.max(fresh, axis=-1, keepdims=True)))
+    cached, fresh = jnp.exp(cached - m), jnp.exp(fresh - m)
+    total = (jnp.sum(cached, axis=-1, keepdims=True)
+             + jnp.sum(fresh, axis=-1, keepdims=True))
+    o = jnp.einsum("bgrs,bsgd->bgrd", (cached / total).astype(q.dtype),
+                   by_head(v), preferred_element_type=f32)
+    return (o + jnp.einsum(
+        "bgrs,bsgd->bgrd", (fresh / total).astype(q.dtype), by_head(v_new),
+        preferred_element_type=f32)).astype(q.dtype)
+
+
+def _fold(s, v, m_ref, l_ref, acc_ref, g, dtype):
+    """One block of scores s [rows, R, n] and values v [rows, n, d] into
+    cached head g's running maximum, sum and weighted values (`_kernel`'s
+    online softmax)."""
+    m_prev = m_ref[:, g]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next)
+    l_ref[:, g] = alpha * l_ref[:, g] + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[:, g] = m_next
+    acc_ref[:, g] = alpha * acc_ref[:, g] + jnp.einsum(
+        "trs,tsd->trd", p.astype(dtype), v,
+        preferred_element_type=jnp.float32)
+
+
+def _block_kernel(last_ref, lengths_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
+                  o_ref, m_ref, l_ref, acc_ref, *, scale, block):
+    """One grid step (i, j): block j of the caches of the rows of step i
+    against those rows' queries, a cached head at a time against its own
+    lanes; before block 0, the rows' own fresh block, so that a row's
+    maximum is finite whatever it holds of the cache."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
+    G, d = q_ref.shape[1], q_ref.shape[3]
+
+    def head(a, g):
+        return a[:, :, g * d:(g + 1) * d]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        kn, vn = kn_ref[...], vn_ref[...]
+        for g in range(G):
+            q = q_ref[:, g]
+            s = jnp.einsum("trd,tsd->trs", q, head(kn, g),
+                           preferred_element_type=f32) * scale
+            _fold(s, head(vn, g), m_ref, l_ref, acc_ref, g, q.dtype)
+
+    # The steps beyond the last block held run nothing; their block index
+    # is the last one's, which the pipeline has, so it copies nothing.
+    @pl.when(j <= last_ref[i])
+    def _():
+        # [rows, 1, 1]
+        lengths = lengths_ref[...]
+        k, v = k_ref[...], v_ref[...]
+        rows = k.shape[0]
+        at = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1, block), 2)
+        held = at < lengths
+        # What lies beyond a row's length is not the row's: 0 x NaN would
+        # be NaN.
+        at = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block, 1), 1)
+        v = jnp.where(at < lengths, v, jnp.zeros_like(v))
+        for g in range(G):
+            q = q_ref[:, g]
+            s = jnp.einsum("trd,tsd->trs", q, head(k, g),
+                           preferred_element_type=f32) * scale
+            _fold(jnp.where(held, s, -jnp.inf), head(v, g), m_ref, l_ref,
+                  acc_ref, g, q.dtype)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+# Under `jit`: a block step calls the entry 14 times at the same shapes, and
+# `pallas_call` traces and lowers its kernel anew at every call (a block
+# step's trace + lowering for a v5e took 4.7 + 2.2 s on the sandbox where
+# the parent's took 2.6 + 1.4; under `jit`, traced and lowered once, ~1.8 +
+# 0.9: set-up has a bound of its own). Every call keeps its caller's scopes
+# in the compiled program's op names.
+@functools.partial(jax.jit, static_argnames=("scale", "block", "rows",
+                                              "interpret"))
+def block_kernel(q, k, v, k_new, v_new, lengths, scale, *, block=None,
+                 rows=None, interpret=False):
+    """`attend_block`'s sum as a kernel: `prefix_kernel`'s grid over the
+    blocks held of the caches where they lie ([B, S, G * d], fetched once
+    for both products and all the cached heads), the rows' fresh block
+    folded into the same online softmax first, and cached head g's R query
+    rows scored against lanes [g d, (g + 1) d) of a fetched row alone: the
+    owed products, no zeros. `d` is whole lane tiles (`block_fused`). The
+    call states no `cost_estimate`, on purpose: with an honest one (the
+    owed FLOPs, the mean blocks' bytes) XLA's scheduler held the next
+    layer's weight prefetches back behind the call and the cell ran 2.0 %
+    slower (9,220 against 9,415 steps/s at one seed; PERF.md section 6, PR
+    49), the opposite of what `models/state_step.py`'s kernel found."""
+    B, G, R, d = q.shape
+    S, L = k.shape[1], k_new.shape[1]
+    block = block or BLOCK
+    rows = rows or rows_a_step(B)
+    if S % block or B % rows:
+        raise ValueError(
+            f"{S} positions are not whole blocks of {block}, or {B} rows "
+            f"not whole steps of {rows}")
+    lengths = lengths.astype(jnp.int32)
+
+    def held(i, j, last):
+        return i, jnp.minimum(j, last[i]), 0
+
+    def own(i, j, last):
+        return i, 0, 0
+
+    def whole(i, j, last):
+        return i, 0, 0, 0
+    return pl.pallas_call(
+        functools.partial(_block_kernel, scale=scale, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // rows, S // block),
+            in_specs=[
+                pl.BlockSpec((rows, 1, 1), own),
+                pl.BlockSpec((rows, G, R, d), whole),
+                pl.BlockSpec((rows, L, G * d), own),
+                pl.BlockSpec((rows, L, G * d), own),
+                pl.BlockSpec((rows, block, G * d), held),
+                pl.BlockSpec((rows, block, G * d), held)],
+            out_specs=pl.BlockSpec((rows, G, R, d), whole),
+            scratch_shapes=[pltpu.VMEM((rows, G, R, 1), jnp.float32),
+                            pltpu.VMEM((rows, G, R, 1), jnp.float32),
+                            pltpu.VMEM((rows, G, R, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="block_attention",
+        interpret=interpret,
+    )(last_blocks(lengths, block, rows), lengths.reshape(B, 1, 1), q, k_new,
+      v_new, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def block_decode_attention(q, k, v, k_new, v_new, lengths, scale):
+    """`block_kernel`, differentiable: the pullback is `attend_block`'s."""
+    return block_kernel(q, k, v, k_new, v_new, lengths, scale)
+
+
+def _block_forward(q, k, v, k_new, v_new, lengths, scale):
+    return block_decode_attention(q, k, v, k_new, v_new, lengths, scale), (
+        q, k, v, k_new, v_new, lengths)
+
+
+def _block_backward(scale, kept, g):
+    *operands, lengths = kept
+    _, pullback = jax.vjp(
+        lambda *a: attend_block(*a, lengths, scale), *operands)
+    return (*pullback(g), None)
+
+
+block_decode_attention.defvjp(_block_forward, _block_backward)
+
+
+# -- a block's keys and values into the caches --------------------------------
+def write_block(k, v, k_new, v_new, pos):
+    """The caches k, v [B, S, W] with k_new, v_new [B, L, W] at positions
+    [pos[b], pos[b] + L) of row b: XLA's scatter of B x L rows, in place in
+    a scan's carry. The two forms that write a row's L positions as ONE
+    slice were measured and lost (my chip runs, PR 49; PERF.md section 5):
+    `dynamic_update_slice` under `vmap` becomes a loop over the rows on a
+    TPU (0.469 ms for both caches of 64 rows where the scatter takes 0.065);
+    a kernel that reads, changes and writes the 16-row tile around a block
+    in place took 0.034 alone and cost the cell 0.25 % against the scatter
+    (a fusion XLA schedules among the step's other ops)."""
+    at = (jnp.arange(k.shape[0])[:, None],
+          pos[:, None] + jnp.arange(k_new.shape[1]))
+    return k.at[at].set(k_new), v.at[at].set(v_new)

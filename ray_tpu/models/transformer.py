@@ -305,11 +305,15 @@ The vocabulary's last id is the MASK id (its logit `MASK_LOGIT`: probability
   S still-masked positions with the highest top probability are unmasked,
   each token drawn from its own distribution; then a commit pass of the
   clean tokens, whose keys and values stay in the caches. S + 1 passes of L
-  rows a block. A pass writes its keys and values into the block's own slots
-  and its L x heads queries then read the cache up to the block's end, folded
-  into the cached heads' rows of queries, through the grouped decode kernel
-  as it is (`_attend_block`): no kernel of the block step's own, and no
-  merge of two softmaxes.
+  rows a block. A pass's L x heads queries, folded into the cached heads'
+  rows of queries, read the blocks before theirs from the caches and their
+  own block's keys and values as operands, in one softmax
+  (`block_attention`: where the program is lowered for a TPU and a cached
+  head is whole lane tiles, an entry of the decode kernel's file that folds
+  the fresh block into its online softmax and scores a cached head against
+  its own lanes). Only the commit pass writes
+  (`decode_attention.write_block`): of a denoising pass nothing outlives
+  the block.
 * `block_causal`: whole episodes [B, T] on the sampler's trace (the pass
   each position was unmasked at), one pass over S + 1 streams of T positions:
   the clean one, block-causal, and one a denoising pass, whose queries read
@@ -870,6 +874,51 @@ def _grouped_attention(q, k_cache, v_cache, pos, scale):
         return jax.lax.platform_dependent(
             q, k_cache, v_cache, lengths, tpu=kernel, default=whole)
     return whole(q, k_cache, v_cache, lengths)
+
+
+def block_fused(S: int, d: int) -> bool:
+    """Whether a block step over cached heads `d` wide, `S` positions of
+    them, can take the kernel form of `block_attention`: a function of the
+    static shape alone. Whole blocks and at least two of them, and a cached
+    head of whole lane tiles: the kernel cuts a fetched row into its cached
+    heads where the tiles' edges are."""
+    block = decode_attention.BLOCK
+    return S % block == 0 and S >= 2 * block and d % 128 == 0
+
+
+def block_attention(q, k_cache, v_cache, k, v, pos, scale):
+    """softmax(q k^T * scale) v of a block of positions a row, over the
+    positions [0, pos[b]) that row holds of the caches (the blocks before
+    this one) and over the block's own keys and values k, v [B, L, groups *
+    d], which are operands and need be in no cache: every position of the
+    block reads all of it, in both directions. q [B, groups, R, d]: the R
+    rows of a cached head are the queries of its `heads // groups` query
+    heads at the block's L positions, in any order. The caches lie flat,
+    [B, S, groups * d]. (o like q, the positions read of the caches.)
+
+    Two forms of the one sum (`models/decode_attention.py`), chosen as
+    `cached_attention` chooses: `block_fused` of the static shape, and the
+    platform the program is lowered for. A kernel (a TPU): the decode
+    kernel's grid over the blocks held up to the furthest row of a grid
+    step, the fresh block folded into the same online softmax, each cached
+    head's queries against that head's own lanes of a fetched row.
+    Everywhere else one softmax over the scores of both and two products a
+    side over the whole cache (`attend_block`), whose derivative the kernel
+    form carries."""
+    S = k_cache.shape[1]
+
+    def whole(*operands):
+        return decode_attention.attend_block(
+            *operands, scale), jnp.asarray(S, jnp.float32)
+
+    def kernel(*operands):
+        return decode_attention.block_decode_attention(
+            *operands, scale), decode_attention.positions_fetched(operands[-1])
+    operands = (q, k_cache, v_cache, k, v, pos)
+    if block_fused(S, q.shape[3]):
+        return jax.lax.platform_dependent(
+            *operands, tpu=kernel, default=whole)
+    return whole(*operands)
 
 
 def decode_fused(S: int, R: int, d_qk: int, value_dim: int) -> bool:
@@ -2463,13 +2512,16 @@ class TokenDecoder(nn.Module):
         a step: the block, the denoising passes, the passes a generated
         token costs the rollout ((denoise_steps + 1) / block_len) and the
         rows a token costs the learner's layers (its streams: denoise_steps
-        + 1); its step's rows are `batch_size` blocks, and a cached head's
-        query rows a block's."""
+        + 1), whether its step's attention is the block entry's kernel
+        (1.0: the block's own keys and values operands beside the caches,
+        a cached head against its own lanes) or the plain form (0.0), and
+        the writes a layer's caches take for one block (1: the commit
+        pass's; a pass that wrote where it read would make it denoise_steps
+        + 1); its step's rows are `batch_size` blocks."""
         k, E = self.experts_per_token, self.num_experts
         kernel = False
         attention = self.attention_layers
         step_rows = batch_size * (self.block_len or 1)
-        query_heads = self.num_heads * (self.block_len or 1)
         # The learner's rows a position: a block model's streams.
         streams = self.denoise_steps + 1 if self.block_len else 1
         fragment_len, learner_rows = (
@@ -2481,14 +2533,19 @@ class TokenDecoder(nn.Module):
                 self.kv_lora_rank)
         else:
             widths = (self.head_width,) * 2
-            kernel = (platform == "tpu"
-                      and self.kv_heads != self.num_heads and all(
-                          grouped_fused(self.cache_len(i), self.kv_heads,
-                                        query_heads, self.head_width)
-                          for i in attention))
+            if self.block_len:
+                kernel = platform == "tpu" and block_fused(
+                    self.context_len, self.head_width)
+            else:
+                kernel = (platform == "tpu"
+                          and self.kv_heads != self.num_heads and all(
+                              grouped_fused(self.cache_len(i), self.kv_heads,
+                                            self.num_heads, self.head_width)
+                              for i in attention))
         if kernel:
             block = decode_attention.BLOCK
-        elif self.kv_lora_rank or self.kv_heads != self.num_heads:
+        elif (self.kv_lora_rank or self.kv_heads != self.num_heads
+              or self.block_len):
             block = self.context_len
         else:
             block = min(DECODE_CACHE_BLOCK, self.context_len)
@@ -2505,7 +2562,9 @@ class TokenDecoder(nn.Module):
             out.update(
                 block_len=self.block_len, denoise_steps=self.denoise_steps,
                 decode_passes_per_token=streams / self.block_len,
-                learner_rows_per_token=streams)
+                learner_rows_per_token=streams,
+                block_attention_kernel=float(kernel),
+                block_cache_writes_per_block=1)
         if learner_rows:
             out["experts_grouped_kernel"] = float(
                 platform == "tpu"
@@ -3235,44 +3294,51 @@ class TokenDecoder(nn.Module):
 
 
     # -- a block of positions a step: the two forms ----------------------
-    def _attend_block(self, lp, x, positions, caches, write_only=False):
+    def _attend_block(self, lp, x, pos, caches, commit=False, last=False):
         """x + Attention(RMSNorm(x)) of a block of positions a row, x
-        [N, L, H] at `positions` [N, L], against the layer's caches: the
-        block's keys and values are written to their own slots first and
-        the L positions' queries then read every slot up to the block's
-        last, so that each reads the blocks before it and the whole of its
-        own, in both directions. One read of the cache serves the L
+        [N, L, H] at positions `pos` .. `pos + L - 1`, against the layer's
+        caches: the L positions' queries read the blocks before theirs from
+        the caches (positions [0, pos)) and the whole of their own block, in
+        both directions, from the pass's own keys and values, which reach
+        `block_attention` as operands. One read of the cache serves the L
         positions: they are folded into a cached head's rows of queries
-        (heads / groups x L of them a cached head) and go through
-        `cached_attention`'s grouped form as a decode step's do, kernel and
-        all. (h, the caches, the positions read); `write_only`: the keys
-        and values alone (a commit pass's last layer, whose output nothing
-        reads)."""
+        (heads / groups x L of them a cached head). A pass that is no
+        `commit` writes nothing: of a denoising pass nothing outlives the
+        block. A commit pass puts the block's keys and values into their
+        slots (`decode_attention.write_block`), and `last` (its last layer,
+        whose output nothing reads) stops there. (h, the caches, the
+        positions read.)"""
         cd, eps = self.compute_dtype, self.rms_eps
         N, L, _ = x.shape
+        groups, d = self.kv_heads, self.head_width
         k_cache, v_cache = caches
+        stored = k_cache.shape
+        # A position's cached heads as one row, as grouped heads' lie.
+        k_cache, v_cache = (c.reshape(N, stored[1], groups * d)
+                            for c in caches)
         with jax.named_scope("policy/block_attention"):
             n = rms_norm(x, lp["attn_norm"], eps, cd)
             q, k, v = self._qkv(lp, n)
+            positions = pos[:, None] + jnp.arange(L)
             q = rope(q, positions, self.rope_theta)
-            k = rope(k, positions, self.rope_theta)
-            rows = jnp.arange(N)[:, None]
-            k_cache = k_cache.at[rows, positions].set(
-                k.reshape((N, L) + k_cache.shape[2:]))
-            v_cache = v_cache.at[rows, positions].set(
-                v.reshape((N, L) + v_cache.shape[2:]))
-            if write_only:
-                return None, (k_cache, v_cache), None
-            groups, d = self.kv_heads, self.head_width
-            by_head = k_cache.shape[:2] + (groups, d)
+            k = rope(k, positions, self.rope_theta).reshape(N, L, -1)
+            v = v.reshape(N, L, -1)
+            if commit:
+                k_cache, v_cache = decode_attention.write_block(
+                    k_cache, v_cache, k, v, pos)
+            held = (k_cache.reshape(stored), v_cache.reshape(stored))
+            if last:
+                return None, held, None
             # [N, L, groups, heads a group, d] -> a cached head's rows.
             q = jnp.swapaxes(q.reshape(N, L, groups, -1, d), 1, 2)
-            o, read = _grouped_attention(
-                q.reshape(N, -1, d), k_cache.reshape(by_head),
-                v_cache.reshape(by_head), positions[:, -1], d ** -0.5)
+            # A commit pass reads the caches it has written: one buffer
+            # lives at a time, and the block's slots lie beyond `pos`.
+            o, read = block_attention(
+                q.reshape(N, groups, -1, d), k_cache, v_cache, k, v, pos,
+                d ** -0.5)
             o = jnp.swapaxes(o.reshape(N, groups, L, -1, d), 1, 2)
             h = x + jnp.dot(o.reshape(N, L, -1), lp["wo"].astype(cd))
-        return h, (k_cache, v_cache), read
+        return h, held, read
 
     def _block_pass(self, tokens, pos, caches, commit=False):
         """One pass of a block step: `tokens` [N, L] at the positions
@@ -3281,14 +3347,13 @@ class TokenDecoder(nn.Module):
         that chose, {layer: the positions its attention read}). A `commit` pass is run
         for the keys and values it leaves: its last layer stops at them."""
         N, L = tokens.shape
-        positions = pos[:, None] + jnp.arange(L)
         x = self.embed[tokens].astype(self.compute_dtype)
         held, experts, reads = [], [], {}
         for i, (layer, layer_caches) in enumerate(zip(self.layers, caches)):
             lp = layer()
             last = commit and i == self.num_layers - 1
             h, layer_caches, read = self._attend_block(
-                lp, x, positions, layer_caches, write_only=last)
+                lp, x, pos, layer_caches, commit, last)
             held.append(layer_caches)
             if last:
                 break
@@ -3328,10 +3393,10 @@ class TokenDecoder(nn.Module):
         clean tokens through the layers for the keys and values that later
         blocks read. A block costs S + 1 passes of L rows.
 
-        Every pass writes its keys and values into the block's own slots of
-        the caches, where the pass's queries read them (`_attend_block`);
-        the commit pass's are the ones that stay: of a denoising pass
-        nothing outlives the block."""
+        A pass's queries read the block's own keys and values as operands
+        beside the caches (`_attend_block`); the commit pass alone writes
+        them into the block's slots: of a denoising pass nothing outlives
+        the block."""
         L, S = self.block_len, self.denoise_steps
         pos = jnp.where(reset > 0, 0, state["pos"])
         given = (pos == 0)[:, None] & (jnp.arange(L) == 0)

@@ -212,9 +212,10 @@ KERNEL_BLOCK = 8
 @pytest.fixture
 def kernel_here(monkeypatch):
     """A program lowered for this CPU takes the branch a TPU's would, the
-    decode attention's kernel (`models/decode_attention.py`) run by the
-    Pallas interpreter over blocks of `KERNEL_BLOCK` positions, two rows a
-    grid step; both rules take a test's widths."""
+    decode attention's kernels (`models/decode_attention.py`: a step's and
+    a block step's) run by the Pallas interpreter over blocks of
+    `KERNEL_BLOCK` positions, two rows a grid step; the rules take a test's
+    widths."""
     import functools
 
     from ray_tpu.models import decode_attention, transformer
@@ -225,8 +226,11 @@ def kernel_here(monkeypatch):
     monkeypatch.setattr(decode_attention, "ROWS", 2)
     monkeypatch.setattr(decode_attention, "prefix_kernel", functools.partial(
         decode_attention.prefix_kernel, interpret=True))
+    monkeypatch.setattr(decode_attention, "block_kernel", functools.partial(
+        decode_attention.block_kernel, interpret=True))
     monkeypatch.setattr(transformer, "decode_fused", whole_blocks)
     monkeypatch.setattr(transformer, "grouped_fused", whole_blocks)
+    monkeypatch.setattr(transformer, "block_fused", whole_blocks)
     monkeypatch.setattr(
         jax.lax, "platform_dependent",
         lambda *args, tpu, default: tpu(*args))

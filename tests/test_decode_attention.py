@@ -210,6 +210,153 @@ def test_lanes_of_other_groups_never_leak(layout):
     np.testing.assert_array_equal(f32(got).reshape(clean.shape), clean)
 
 
+# -- a block step: a block's fresh keys and values beside the caches ----------
+# Rows two a grid step: a row that begins (nothing cached: the block alone),
+# one block in; a block that ends at a kernel block's edge beside the one
+# that begins there; a fresh block that straddles a kernel block's edge (the
+# entry takes any position; only the write wants multiples of L); the
+# cache's last block beside a row in the middle.
+BLOCK_POS = [0, 4, BLOCK - 4, BLOCK, BLOCK - 2, 2 * BLOCK + 2, WINDOW - 4,
+             WINDOW // 2]
+
+
+def block_operands(L, groups, dtype, key=7, d=128, per_group=2):
+    dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    B = len(BLOCK_POS)
+    keys = jax.random.split(jax.random.PRNGKey(key), 5)
+    q = jax.random.normal(keys[0], (B, groups, per_group * L, d), dtype)
+    k, v = (jax.random.normal(key, (B, WINDOW, groups * d), dtype)
+            for key in keys[1:3])
+    k_new, v_new = (jax.random.normal(key, (B, L, groups * d), dtype)
+                    for key in keys[3:])
+    return q, k, v, k_new, v_new, jnp.asarray(BLOCK_POS, jnp.int32), d ** -0.5
+
+
+def block_entry(*args, rows=2):
+    return decode_attention.block_kernel(
+        *args, block=BLOCK, rows=rows, interpret=True)
+
+
+def written_then_read(q, k, v, k_new, v_new, pos, scale):
+    """The sum as a step that writes where it reads has it: the block
+    scattered into the caches, `attend_grouped` up to the block's end."""
+    B, G, R, d = q.shape
+    L = k_new.shape[1]
+    at = (jnp.arange(B)[:, None], pos[:, None] + jnp.arange(L))
+    k, v = k.at[at].set(k_new), v.at[at].set(v_new)
+    return decode_attention.attend_grouped(
+        q.reshape(B, G * R, d), k.reshape(B, -1, G, d),
+        v.reshape(B, -1, G, d), pos + L, scale).reshape(q.shape)
+
+
+@pytest.mark.parametrize("dtype", LIMITS)
+@pytest.mark.parametrize("groups", [2, 4])
+@pytest.mark.parametrize("L", [2, 4])
+def test_block_entry_is_the_sum_over_a_cache_with_the_block_written_in(
+        L, groups, dtype):
+    args = block_operands(L, groups, dtype)
+    want = written_then_read(*args)
+    for got in (block_entry(*args), decode_attention.attend_block(*args)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert np.max(np.abs(f32(got) - f32(want))) <= LIMITS[dtype]
+    # A row that begins reads its own block alone: blind to the cache.
+    q, k, v, k_new, v_new, pos, scale = args
+    blind = block_entry(q, k * jnp.nan, v * jnp.nan, k_new, v_new,
+                        jnp.zeros_like(pos), scale)
+    alone = decode_attention.attend_block(
+        q, k, v, k_new, v_new, jnp.zeros_like(pos), scale)
+    assert np.max(np.abs(f32(blind) - f32(alone))) <= LIMITS[dtype]
+
+
+@pytest.mark.parametrize("rows", [1, 2, 8])
+def test_block_entry_at_any_rows_a_grid_step(rows):
+    args = block_operands(4, 4, "f32")
+    assert np.max(np.abs(f32(block_entry(*args, rows=rows))
+                         - f32(written_then_read(*args)))) <= LIMITS["f32"]
+
+
+@pytest.mark.parametrize("groups", [2, 4])
+def test_lanes_of_other_groups_never_leak_into_a_block_s_heads(groups):
+    """Every other cached head poisoned, keys and values, cached and
+    fresh: a group's heads read what they read of clean ones; and NaN at
+    and beyond a row's position in the caches changes nothing."""
+    q, k, v, k_new, v_new, pos, scale = block_operands(4, groups, "bf16")
+    d = q.shape[-1]
+    clean = f32(block_entry(q, k, v, k_new, v_new, pos, scale))
+    for g in range(groups):
+        others = (jnp.arange(groups * d) // d != g)[None, None, :]
+        got = f32(block_entry(q, *(jnp.where(others, jnp.nan, a) for a in (
+            k, v, k_new, v_new)), pos, scale))
+        np.testing.assert_array_equal(got[:, g], clean[:, g])
+    beyond = (jnp.arange(WINDOW)[None, :] >= pos[:, None])[:, :, None]
+    got = block_entry(q, jnp.where(beyond, jnp.nan, k),
+                      jnp.where(beyond, jnp.nan, v), k_new, v_new, pos, scale)
+    np.testing.assert_array_equal(f32(got), clean)
+
+
+@pytest.mark.parametrize("dtype", LIMITS)
+@pytest.mark.parametrize("L", [2, 4, 16])
+def test_a_block_s_write_changes_its_own_rows_alone(L, dtype):
+    """Rows at the cache's first and last block and across the batch:
+    [pos, pos + L) takes the block bit for bit, every other position is
+    what it was."""
+    dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    S, W = 64, 256
+    pos = jnp.asarray([0, S - L, 16 - L, 16, 32, 48 - L], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    k, v = (jax.random.normal(key, (6, S, W), dtype) for key in keys[:2])
+    k_new, v_new = (jax.random.normal(key, (6, L, W), dtype)
+                    for key in keys[2:])
+    own = ((jnp.arange(S)[None, :] >= pos[:, None])
+           & (jnp.arange(S)[None, :] < pos[:, None] + L))
+    for got, old, new in zip(
+            decode_attention.write_block(k, v, k_new, v_new, pos), (k, v),
+            (k_new, v_new)):
+        assert got.shape == old.shape and got.dtype == old.dtype
+        np.testing.assert_array_equal(
+            f32(got)[np.asarray(own)].reshape(6, L, W), f32(new))
+        np.testing.assert_array_equal(
+            f32(got)[~np.asarray(own)], f32(old)[~np.asarray(own)])
+
+
+@pytest.mark.parametrize("S,d,fused", [
+    (2048, 128, True),     # the seventh token cell's block step
+    (2048, 256, True),
+    (2048, 64, False),     # half a lane tile a cached head: no static cut
+    (128, 128, False),     # one block
+    (2000, 128, False),    # not whole blocks
+])
+def test_block_fused_is_a_rule_of_the_static_shape(S, d, fused):
+    assert transformer.block_fused(S, d) is fused
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+def test_gradients_through_the_block_entry_are_the_plain_form_s(
+        kernel_here, dtype):
+    """`block_attention` under a gradient, kernel form against plain form:
+    the same pullback, evaluated at the same operands."""
+    q, k, v, k_new, v_new, pos, scale = block_operands(4, 2, dtype)
+    weights = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+
+    def loss(form):
+        def f(q, k, v, k_new, v_new):
+            o = form(q, k, v, k_new, v_new, pos, scale)
+            o = o[0] if isinstance(o, tuple) else o
+            return jnp.sum(o.astype(jnp.float32) * weights)
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(q, k, v, k_new, v_new)
+    got = loss(transformer.block_attention)
+    want = loss(decode_attention.attend_block)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(f32(a), f32(b), atol=1e-6)
+    o, read = transformer.block_attention(q, k, v, k_new, v_new, pos, scale)
+    assert np.max(np.abs(f32(o) - f32(written_then_read(
+        q, k, v, k_new, v_new, pos, scale)))) <= LIMITS[dtype]
+    # Two rows a grid step, the blocks up to the further one's last cached
+    # position: a row that begins still has block 0 fetched.
+    assert float(read) == BLOCK * np.mean([1, 1, 3, 4])
+
+
 # -- the rule ----------------------------------------------------------------
 @pytest.mark.parametrize("S,R,d_qk,value_dim,fused", [
     (1024, 20, 576, 512, True),    # the second token cell's decode step
@@ -580,6 +727,69 @@ def cache_copies(compiled, rows, S):
                 "copy", "copy-start", "reshape", "transpose"))
             and any(shape in result(line) for shape in (
                 f"bf16[{rows},{S},", f"bf16[{rows},1,{S},"))]
+
+
+@pytest.mark.parametrize("rows", [64, 2])
+def test_the_block_entry_compiles_for_a_v5e_at_the_cell_s_widths(
+        rows, one_chip):
+    """Mosaic takes the seventh cell's block step as it stands: 32 query
+    rows a cached head (8 heads x 4 positions) against the head's own 128
+    lanes of a fetched row, four fresh positions (a quarter of a bfloat16
+    tile) as the products' short side, and the caches where they lie."""
+    S, G, R, d, L = 2048, 4, 32, 128, 4
+    cache, fresh = shaped(one_chip, rows, S, G * d), shaped(
+        one_chip, rows, L, G * d)
+    compiled = jax.jit(functools.partial(
+        transformer.block_attention, scale=d ** -0.5)).trace(
+            shaped(one_chip, rows, G, R, d), cache, cache, fresh, fresh,
+            shaped(one_chip, rows, dtype=jnp.int32)).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    assert "block_attention" in compiled and "tpu_custom_call" in compiled
+    assert not cache_copies(compiled, rows, S)
+
+
+def test_the_compiled_block_step_holds_no_copy_of_a_cache(one_chip):
+    """The seventh cell's whole block step at its real widths (shapes
+    alone), the state donated as the rollout's scan carries it: 14 calls of
+    the block entry (3 passes x 5 layers less the commit pass's last), ten
+    scatters (the commit pass's, a cache each: none of a denoising pass),
+    each cache aliased through; no cache laid out anew."""
+    import json
+    with open(os.path.join(BENCH, "configs/impala_sdar_30b_a3b.json")) as f:
+        network = {k: v for k, v in json.load(f)["network"].items()
+                   if k != "param_count"}
+    model = catalog.get_model(None, network["vocab_size"] - 1, {
+        "custom_model": "sdar_moe", "custom_model_config": network,
+        "compute_dtype": "bf16"})
+    rows, S = 64, network["max_position_embeddings"]
+
+    def there(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    variables = there(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+        model.initial_state(1), jnp.zeros((1, 1)))))
+
+    def step(variables, obs, state, reset, rng):
+        return model.apply(variables, obs, state, reset, rng,
+                           method="block_step", mutable=["routing"])[0]
+    compiled = jax.jit(step, donate_argnums=(2,)).trace(
+        variables, shaped(one_chip, rows, dtype=jnp.int32),
+        there(jax.eval_shape(lambda: model.initial_state(rows))),
+        shaped(one_chip, rows, dtype=jnp.float32),
+        there(jax.eval_shape(lambda: jax.random.PRNGKey(0)))).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert sum("block_attention" in line.split(" = ")[0]
+               for line in calls) == 14
+    assert not cache_copies(text, rows, S)
+    assert len([line for line in text.splitlines() if " scatter(" in line
+                and f"bf16[{rows},{S}," in line.split(" scatter(")[0]]) == 10
+    # Ten caches in, the same ten buffers out.
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 10 * rows * S * 512 * 2
+    assert memory.temp_size_in_bytes < rows * S * 512 * 2
 
 
 def test_a_cache_stored_by_head_would_be_copied_every_step(one_chip):

@@ -146,6 +146,80 @@ def test_block_step_through_the_cache_matches_reference(passes):
     assert routing["router_flips"] <= 0.01, routing
 
 
+# A context of whole kernel blocks (four of 8 under `kernel_here`, a row a
+# grid step).
+T_KERNEL = 32
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_block_step_through_the_kernel_forms_matches_reference(
+        kernel_here, passes):
+    """The block entry's kernel, by the interpreter: the rollout is the
+    reference's, at the limits of the plain form."""
+    model, variables, net = build(
+        denoise_steps=passes, max_position_embeddings=T_KERNEL)
+    trace = rollout(model, variables, net, positions=T_KERNEL)
+    held = plain(variables, trace, net, trace["experts"])
+    generated = np.asarray(trace["steps"]) >= 0
+    np.testing.assert_allclose(
+        np.asarray(taken(held["logits"], trace["tokens"]))[generated],
+        np.asarray(trace["logp"])[generated], atol=2e-4)
+    np.testing.assert_allclose(held["values"], trace["values"], atol=2e-4)
+    routing = reference.routing_verdict(
+        trace["experts"], held["experts"], held["select"])
+    assert routing["router_flips"] <= 0.01, routing
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+@pytest.mark.parametrize("commit", [False, True])
+def test_only_a_commit_pass_writes_and_only_its_block_s_rows(
+        commit, form, request):
+    """A denoising pass hands the caches back bit for bit; a commit pass
+    changes rows [pos, pos + L) of each row's caches, in every layer, and
+    nothing else. Both forms of the attention."""
+    if form == "kernel":
+        request.getfixturevalue("kernel_here")
+    model, variables, net = build(max_position_embeddings=T_KERNEL)
+    L = net["block_length"]
+    pos = jnp.asarray([0, 12, T_KERNEL - L], jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (N, L), 0,
+                                net["vocab_size"] - 1)
+    caches = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(a.size), a.shape,
+                                    a.dtype),
+        model.initial_state(N)["kv"])
+    _, after, _, reads = model.apply(
+        variables, tokens, pos, caches, commit, method="_block_pass")
+    own = np.asarray((jnp.arange(T_KERNEL)[None, :] >= pos[:, None])
+                     & (jnp.arange(T_KERNEL)[None, :] < pos[:, None] + L))
+    assert len(jax.tree.leaves(after)) == 2 * net["num_hidden_layers"]
+    for was, now in zip(jax.tree.leaves(caches), jax.tree.leaves(after)):
+        was, now = np.asarray(was), np.asarray(now)
+        assert was.shape == now.shape and was.dtype == now.dtype
+        np.testing.assert_array_equal(now[~own], was[~own])
+        assert commit == bool((now[own] != was[own]).any())
+        if commit:
+            assert (now[own] != was[own]).mean() > 0.99
+    # The fresh block is no read of the cache: the kernel fetches the blocks
+    # up to the furthest row's last cached position, the plain form all.
+    assert float(reads[0]) == pytest.approx(
+        32.0 if form == "plain" else 8 * np.mean([1, 2, 4]))
+
+
+@pytest.mark.parametrize("platform,kernel", [("tpu", 1.0), ("cpu", 0.0)])
+def test_the_counters_say_which_form_a_block_step_takes(platform, kernel):
+    model, _, _ = build(max_position_embeddings=256, head_dim=128)
+    got = model.static_counters(4, 256, platform)
+    assert got["block_attention_kernel"] == kernel
+    assert got["decode_attention_kernel"] == kernel
+    assert got["decode_cache_block"] == (128 if kernel else 256)
+    # The commit pass's alone, of denoise_steps + 1 passes.
+    assert got["block_cache_writes_per_block"] == 1
+    narrow, _, _ = build(max_position_embeddings=256)
+    assert narrow.static_counters(4, 256, "tpu")[
+        "block_attention_kernel"] == 0.0
+
+
 @pytest.mark.parametrize("passes", [2, 4])
 def test_the_unmask_order_is_the_reference_s_top_probabilities(passes):
     """At pass s the positions unmasked are those of the still masked whose
@@ -226,6 +300,10 @@ def test_the_2t_form_is_the_block_by_block_definition(passes):
                                atol=2e-5)
 
 
+# 24 eager forwards of the reference, each at a length of its own: 143 s
+# alone on the sandbox at PR 49 and at its parent, over the common limit
+# beside five busy workers.
+@pytest.mark.time_limit(420)
 def test_a_block_of_one_and_one_pass_is_a_plain_masked_forward():
     """L 1, S 1: a step yields one token a row; position i's distribution
     comes from the MASK id at i reading the clean tokens before it."""
@@ -505,6 +583,8 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
     assert got["decode_rows_per_expert"] == 16.0
     assert got["decode_experts_batched"] == 1.0
     assert got["decode_attention_kernel"] == 1.0
+    assert got["block_attention_kernel"] == 1.0
+    assert got["block_cache_writes_per_block"] == 1
     assert got["causal_attention_fused"] == 1.0
     assert got["experts_grouped_kernel"] == 1.0
     assert got["kv_groups"] == 8

@@ -41,10 +41,12 @@ LOWERED = {
     # kernel), whose rule takes no rehearsal's shape.
     "nemotron_h_token_anakin_2k":
         "525b2b0cc7d801a41fad7a5b12eda8929be35f728f6a6ade6e66b763e3547eb0",
-    # The seventh, recorded by PR 48, which brought it: the one program whose
-    # rollout scan is over blocks.
+    # The seventh, the one program whose rollout scan is over blocks: brought
+    # by PR 48, recorded anew by PR 49, which was meant to alter it (the
+    # block's keys and values reach the attention as operands, and only the
+    # commit pass writes) and measured the cell.
     "sdar_block_token_anakin_2k":
-        "c498606a43e9189daaa749d56e2f3e59e44ac5ffed8b6995e639a3863b92fb05",
+        "8f5ce9cf7d9f1d65198d64d8bf0c70e7a458231f6dce86038a01b5c1c2100345",
 }
 
 
