@@ -66,6 +66,12 @@ rllib/optimizers/async_samples_optimizer.py `InlineActorThread`):
 per-actor gauges `sebulba_action_fetch_pct.aK` (share of the actor's
 wall-clock blocked on the device action round-trip — the r5 wall this
 plane exists to watch), `sebulba_env_step_pct.aK` (host env stepping),
+`sebulba_gil_wait_pct.aK` (wall less CPU seconds of the actor's env-step
+and record phases, which block on nothing but the interpreter: the share
+of its wall-clock it wanted the GIL or a core and had neither; updated
+for fragments sampled inside a `profiling.phase_cpu_reads()` window, as
+`ray_tpu profile` opens one, and absent where the platform has no
+per-thread CPU clock),
 and `sebulba_policy_lag_steps.aK` (mean behavior-policy selection lag
 per transition under `sebulba_onchip_steps` windows). Updated at
 sample-fragment boundaries; declared with mean roll-up so the cluster

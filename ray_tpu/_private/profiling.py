@@ -37,7 +37,15 @@ The hot loops name their own time with `phase()` (below): one `with`
 that is a `jax.profiler.TraceAnnotation` ("ray_tpu.<name>", on the
 device trace's clock while a profiler session runs, a no-op outside
 one) and an entry in the calling thread's `PhaseClock` (always on;
-cumulative seconds and counts that partition the thread's wall time).
+cumulative seconds and counts that partition the thread's wall time,
+and beside each phase's wall seconds the CPU seconds the thread ran in
+it, read at the phase boundaries inside a `phase_cpu_reads()` window,
+which a capture opens). `clocks()`
+lists every bound clock, `process_cpu()` reads the whole
+process, and `host_account()` is the delta of two `host_snapshot()`s:
+each Python thread's wall and CPU by phase, and the process's CPU less
+theirs, which is its native threads'. `run_capture()` returns it as
+`host_account` for its window.
 
 `device_account.py`, beside this file, reads those annotations and the
 programs' `jax.named_scope`s back out of a trace: device seconds by
@@ -54,6 +62,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Set
 
 FLUSH_INTERVAL = 1.0
@@ -357,96 +366,399 @@ def sample_once() -> Dict[str, str]:
     return out
 
 
-class PhaseClock:
-    """One loop thread's wall time by phase: `{name: [seconds, count]}`
-    since the thread bound the clock. A single writer (the bound thread,
-    through `phase()`) and no lock; any thread may `snapshot()`. Phases
-    of one thread do not nest, so that they partition its wall time and
-    what no phase covers is a number of its own (`other_s`)."""
+# The calling thread's CPU seconds (`CLOCK_THREAD_CPUTIME_ID`); called only
+# for a clock that found the thread's CPU clock id at its bind.
+_thread_cpu = getattr(time, "thread_time", None)
 
-    __slots__ = ("_phases", "_open", "_t_start")
+# Reading that clock is a system call: 0.35 us on a plain Linux kernel and
+# 6-44 us on a sandboxed one (the benchmark's TPU hosts, whose clock also
+# ticks in steps of 10 ms), where two reads a phase cost an inline cell 8.6 %
+# of its rate. So the phases' CPU is read only while somebody asks for it
+# (`phase_cpu_reads()`: a capture's window), on every host alike. A thread's
+# own total (`cpu_s`) is read at snapshot time and is always there.
+_asked = 0  # `phase_cpu_reads()` windows that are open: the phases read
+# A plain Linux kernel keeps a thread's run-queue wait in its `schedstat`;
+# a sandboxed one (the benchmark's TPU hosts) keeps no such file at all.
+_HAS_SCHEDSTAT = os.path.exists("/proc/self/schedstat")
+_policy_lock = threading.Lock()  # guards _asked and _clocks
+
+
+class phase_cpu_reads:
+    """`with phase_cpu_reads(): ...` — every phase of every clocked thread
+    reads its CPU at both ends while a window is open, and no phase does
+    outside one. Windows may overlap."""
+
+    def __enter__(self):
+        global _asked
+        with _policy_lock:
+            _asked += 1
+        return self
+
+    def __exit__(self, *exc):
+        global _asked
+        with _policy_lock:
+            _asked -= 1
+        return False
+
+
+class PhaseClock:
+    """One loop thread's time by phase since the thread bound the clock:
+    `{name: [wall_s, count, cpu_s, read_wall_s]}`, wall seconds beside the
+    seconds the thread was RUNNING (its CPU clock), so that a phase's wall
+    less its CPU is what the thread spent off the CPU inside it: waiting
+    for the GIL, a lock, the device or a core. `cpu_s` and `read_wall_s`
+    are of the instances whose CPU was read: those that began inside a
+    `phase_cpu_reads()` window. A
+    single writer (the bound thread, through `phase()`) and no lock; any
+    thread may `snapshot()`. Phases of one thread do not nest, so that
+    they partition its wall time and what no phase covers is a number of
+    its own (`other_s`, `other_cpu_s`). Where the platform has no
+    per-thread CPU clock (`time.pthread_getcpuclockid`) every CPU reading
+    is None and the wall side is what it always was."""
+
+    __slots__ = ("_phases", "_open", "_t_start", "_thread", "_native_id",
+                 "_cpu_id", "_cpu_start", "_cpu_base", "_cpu_last",
+                 "__weakref__")
 
     def __init__(self):
         self._phases: Dict[str, list] = {}
-        self._open = None  # (name, t0) of the phase the thread is inside
+        # (name, t0, cpu0) of the phase the thread is inside; cpu0 is None
+        # for an instance whose CPU is not read
+        self._open = None
         self._t_start = time.perf_counter()
+        self._thread = None     # the bound threading.Thread
+        self._native_id = None  # its kernel id, for /proc/self/task/<id>
+        self._cpu_id = None     # its CPU clock, readable from any thread
+        self._cpu_start = 0.0   # that clock at the bind
+        self._cpu_base = 0.0    # CPU seconds on threads bound before it
+        self._cpu_last = 0.0    # the clock as the thread last wrote it
 
     def bind(self) -> "PhaseClock":
         """Make this the calling thread's clock (the thread's owner calls
-        it from that thread; a no-op when it already is). Wall time
-        counts from the first bind."""
-        if getattr(_thread, "clock", None) is not self:
+        it from that thread; a no-op when it already is). Wall and CPU
+        time count from the first bind. A clock handed on to another
+        thread keeps what the earlier one spent."""
+        me = threading.current_thread()
+        if getattr(_thread, "clock", None) is not self \
+                or self._thread is not me:
             if not self._phases:
                 self._t_start = time.perf_counter()
+            if self._thread is not me:
+                self._attach(me)
             _thread.clock = self
         return self
+
+    def _attach(self, me: threading.Thread) -> None:
+        self._cpu_base += self._cpu_last - self._cpu_start
+        getid = getattr(time, "pthread_getcpuclockid", None)
+        try:
+            cpu_id = getid(me.ident) if getid is not None else None
+        except OSError:
+            cpu_id = None
+        now = 0.0
+        if cpu_id is not None:
+            now = _thread_cpu()
+        self._cpu_start = self._cpu_last = now
+        self._native_id = getattr(me, "native_id", None)
+        self._thread = me
+        self._cpu_id = cpu_id
+        with _policy_lock:
+            _clocks.add(self)
+
+    @property
+    def thread_name(self) -> Optional[str]:
+        return None if self._thread is None else self._thread.name
 
     def seconds(self, name: str) -> float:
         cell = self._phases.get(name)
         return cell[0] if cell else 0.0
 
+    def count(self, name: str) -> int:
+        cell = self._phases.get(name)
+        return cell[1] if cell else 0
+
+    def _cpu_now(self) -> float:
+        """The bound thread's CPU clock, from any thread; the value the
+        thread itself last wrote once it is gone."""
+        if self._thread.is_alive():
+            try:
+                return time.clock_gettime(self._cpu_id)
+            except OSError:  # it ended between the two lines
+                pass
+        return self._cpu_last
+
+    def _run_delay(self) -> Optional[float]:
+        """Seconds the bound thread was runnable and had no core: field 2
+        of its `schedstat`. None where the kernel keeps no such file or
+        the thread is gone (its id may by then be another thread's)."""
+        if not _HAS_SCHEDSTAT or not self._thread.is_alive():
+            return None
+        try:
+            with open("/proc/self/task/%d/schedstat"
+                      % self._native_id) as f:
+                return int(f.read().split()[1]) / 1e9
+        except (OSError, TypeError, ValueError, IndexError):
+            return None
+
     def snapshot(self) -> dict:
         """Cumulative and monotone: `seconds` and `counts` per phase,
-        `wall_s` since the first bind, `other_s` = wall - sum(seconds).
-        A phase that is open now counts up to now, so a thread blocked
-        in one (a full learner queue) does not read as `other`."""
+        `wall_s` since the first bind, `other_s` = wall - sum(seconds); on
+        the CPU side `cpu_seconds` and `cpu_read_seconds` per phase (the
+        CPU and the wall seconds of the instances whose CPU was read, so
+        that over a `phase_cpu_reads()` window `cpu_read_seconds` moves as
+        `seconds` does), `cpu_s` (the thread's own clock since the first bind,
+        whatever was read), `other_cpu_s` = cpu_s - sum(cpu_seconds), and
+        `run_delay_s` (the thread's, since it started; read here and
+        nowhere else). A phase that is open now counts up to now on both
+        clocks, so a thread blocked in one (a full learner queue) does not
+        read as `other`."""
         now = time.perf_counter()
+        has_cpu = self._cpu_id is not None
+        cpu_now = self._cpu_now() if has_cpu else None
         open_ = self._open
-        cells = dict(self._phases)  # one C-level copy: safe against the writer
-        seconds = {k: v[0] for k, v in cells.items()}
-        counts = {k: v[1] for k, v in cells.items()}
+        # One C-level copy of the table, safe against the writer; a cell as
+        # (wall, count, cpu, read wall).
+        cells = {k: tuple(v) for k, v in dict(self._phases).items()}
         if open_ is not None:
-            seconds[open_[0]] = seconds.get(open_[0], 0.0) + now - open_[1]
-            counts.setdefault(open_[0], 0)
+            name, t0, c0 = open_
+            wall, count, cpu, read_wall = cells.get(name, (0.0, 0, 0.0, 0.0))
+            if c0 is not None:
+                cpu += max(0.0, cpu_now - c0)
+                read_wall += now - t0
+            cells[name] = (wall + now - t0, count, cpu, read_wall)
+        seconds = {k: v[0] for k, v in cells.items()}
         wall = now - self._t_start
-        return {"wall_s": wall, "other_s": wall - sum(seconds.values()),
-                "seconds": seconds, "counts": counts}
+        out = {"wall_s": wall, "other_s": wall - sum(seconds.values()),
+               "seconds": seconds,
+               "counts": {k: v[1] for k, v in cells.items()},
+               "cpu_seconds": None, "cpu_read_seconds": None, "cpu_s": None,
+               "other_cpu_s": None, "run_delay_s": None}
+        if has_cpu:
+            cpu = {k: v[2] for k, v in cells.items()}
+            cpu_s = self._cpu_base + cpu_now - self._cpu_start
+            out.update(cpu_seconds=cpu,
+                       cpu_read_seconds={k: v[3] for k, v in cells.items()},
+                       cpu_s=cpu_s, other_cpu_s=cpu_s - sum(cpu.values()),
+                       run_delay_s=self._run_delay())
+        return out
+
+
+def _add(a, b):
+    return None if a is None or b is None else a + b
+
+
+def _sub(a, b):
+    return None if a is None or b is None else a - b
 
 
 def sum_snapshots(snapshots: List[dict]) -> dict:
     """Snapshots of several threads added up key by key (shares of the
-    summed `wall_s` are then means over the threads)."""
-    out = {"wall_s": 0.0, "other_s": 0.0, "seconds": {}, "counts": {}}
+    summed `wall_s` are then means over the threads). A CPU key that one
+    of them lacks is None in the sum."""
+    tables = ("seconds", "counts", "cpu_seconds", "cpu_read_seconds")
+    scalars = ("wall_s", "other_s", "cpu_s", "other_cpu_s", "run_delay_s")
+    out = {key: {} for key in tables}
+    out.update({key: 0.0 for key in scalars})
     for snap in snapshots:
-        out["wall_s"] += snap["wall_s"]
-        out["other_s"] += snap["other_s"]
-        for key in ("seconds", "counts"):
-            for name, v in snap[key].items():
+        for key in scalars:
+            out[key] = _add(out[key], snap.get(key))
+        for key in tables:
+            table = snap.get(key)
+            if table is None or out[key] is None:
+                out[key] = None
+                continue
+            for name, v in table.items():
                 out[key][name] = out[key].get(name, 0) + v
     return out
 
 
+def off_cpu_s(now: dict, was: dict, names) -> Optional[float]:
+    """Seconds a thread spent OFF the CPU inside the phases `names` between
+    two snapshots of its clock: each phase's wall at the off-CPU share of
+    its instances whose CPU was read. None where the clock keeps no CPU
+    seconds or no instance of them was read in between."""
+    if now.get("cpu_seconds") is None or was.get("cpu_seconds") is None:
+        return None
+    total, read_any = 0.0, False
+    for name in names:
+        def delta(key):
+            return now[key].get(name, 0.0) - was[key].get(name, 0.0)
+        read = delta("cpu_read_seconds")
+        if read > 0:
+            read_any = True
+            total += delta("seconds") * (1.0 - delta("cpu_seconds") / read)
+    return total if read_any else None
+
+
 _thread = threading.local()  # .clock: the PhaseClock bound to this thread
+_clocks = weakref.WeakSet()  # every clock a thread has bound, while owned
+
+
+def clocks() -> List[tuple]:
+    """`(thread name, clock)` of every bound `PhaseClock` whose owner
+    still holds it, in thread-name order: the Python threads that account
+    for their time."""
+    with _policy_lock:
+        bound = list(_clocks)
+    return sorted(((c.thread_name, c) for c in bound),
+                  key=lambda pair: pair[0])
+
+
+def process_cpu() -> dict:
+    """CPU seconds of the whole process, native threads included
+    (`time.process_time()`), and the cores it may run on."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # no affinity on this platform
+        cores = os.cpu_count()
+    return {"cpu_s": time.process_time(), "cores": cores}
+
+
+def host_snapshot(named_clocks=None) -> dict:
+    """The cumulative host account: a snapshot a clocked thread, the
+    process's CPU and the time of the reading. `host_account` takes the
+    delta of two. `named_clocks` are `(name, clock)` pairs (default: every
+    registered clock of a live thread); a name that is there twice gets
+    `#2`, `#3`."""
+    if named_clocks is None:  # a thread that has ended accounts for nothing
+        named_clocks = [(name, clock) for name, clock in clocks()
+                        if clock._thread.is_alive()]
+    threads = {}
+    for name, clock in named_clocks:
+        key, n = name, 1
+        while key in threads:
+            n += 1
+            key = "%s#%d" % (name, n)
+        threads[key] = clock.snapshot()
+    return {"threads": threads, "process": process_cpu(),
+            "t": time.perf_counter()}
+
+
+def host_account(before: dict, after: dict) -> dict:
+    """What the host's threads did between two `host_snapshot`s: a
+    thread's wall and CPU seconds by phase ("other" is what no phase
+    covers, so a thread's phases add up to its wall; `cpu_s` is of the
+    instances whose CPU was read, `cpu_read_s` their wall: the phase's
+    wall where every instance was read, 0 where none was, and then the
+    thread's CPU in it stands under "other"), its `cpu_s` and
+    `run_delay_s`; the process's CPU seconds, its clocked (Python)
+    threads' part, the rest (native threads: PJRT's), and `python_cores`
+    = clocked CPU seconds over the window, which one GIL holds at 1.
+    CPU readings are None where the platform gives none."""
+    window = after["t"] - before["t"]
+    threads = {}
+    python_cpu = 0.0
+    for name, now in after["threads"].items():
+        was = before["threads"].get(name)
+        if was is None:
+            continue  # bound inside the window: no delta to take
+        has_cpu = now["cpu_seconds"] is not None \
+            and was["cpu_seconds"] is not None
+
+        def of_phase(key, p):
+            return now[key].get(p, 0) - was[key].get(p, 0)
+
+        phases = {
+            p: {"wall_s": of_phase("seconds", p),
+                "count": of_phase("counts", p),
+                "cpu_s": of_phase("cpu_seconds", p) if has_cpu else None,
+                "cpu_read_s": (of_phase("cpu_read_seconds", p)
+                               if has_cpu else None)}
+            for p in now["seconds"]}
+        other_wall = now["other_s"] - was["other_s"]
+        phases["other"] = {
+            "wall_s": other_wall, "count": 0,
+            "cpu_s": _sub(now["other_cpu_s"], was["other_cpu_s"]),
+            "cpu_read_s": other_wall if has_cpu else None}
+        threads[name] = {
+            "wall_s": now["wall_s"] - was["wall_s"],
+            "cpu_s": _sub(now["cpu_s"], was["cpu_s"]),
+            "run_delay_s": _sub(now["run_delay_s"], was["run_delay_s"]),
+            "phases": phases}
+        python_cpu = _add(python_cpu, threads[name]["cpu_s"])
+    cpu = after["process"]["cpu_s"] - before["process"]["cpu_s"]
+    return {"window_s": window, "threads": threads, "process": {
+        "cpu_s": cpu, "cores": after["process"]["cores"],
+        "python_cpu_s": python_cpu,
+        "native_cpu_s": _sub(cpu, python_cpu),
+        "python_cores": (python_cpu / window
+                         if python_cpu is not None and window > 0
+                         else None)}}
+
+
+def render_host_account(acct: dict, indent: str = "") -> List[str]:
+    """The host account as text lines: a table a thread, wall and CPU
+    seconds a phase and the share of the wall the thread was off the CPU;
+    wall seconds alone where the platform gave no CPU clock. "runnable
+    without a core" is `run_delay_s`: printed on a plain Linux box, whose
+    kernel keeps `schedstat`, and on no sandboxed host."""
+    proc = acct["process"]
+    has_cpu = proc["python_cpu_s"] is not None
+    out = ["%shost account over %.3f s: " % (indent, acct["window_s"])
+           + ("process cpu %.3f s = python threads %.3f s + native threads "
+              "%.3f s; python_cores %.2f of %d (1.0 = a full GIL)" % (
+                  proc["cpu_s"], proc["python_cpu_s"], proc["native_cpu_s"],
+                  proc["python_cores"], proc["cores"])
+              if has_cpu else
+              "process cpu %.3f s; no per-thread CPU clock here: wall "
+              "seconds only" % proc["cpu_s"])]
+    for name, t in acct["threads"].items():
+        head = "%s  %s: wall %.3f s" % (indent, name, t["wall_s"])
+        if t["cpu_s"] is not None:
+            head += ", cpu %.3f s" % t["cpu_s"]
+        if t["run_delay_s"] is not None:
+            head += ", runnable without a core %.3f s" % t["run_delay_s"]
+        out.append(head)
+        rows = sorted(t["phases"].items(), key=lambda kv: -kv[1]["wall_s"])
+        for phase_name, p in rows:
+            if p["wall_s"] <= 0 and not p["count"]:
+                continue
+            line = "%s    %10.6f s wall" % (indent, p["wall_s"])
+            if p["cpu_s"] is not None and p["cpu_read_s"] > 0:
+                line += " %10.6f s cpu %6.1f %% off-cpu" % (
+                    p["cpu_s"], 100.0 * (1.0 - p["cpu_s"] / p["cpu_read_s"]))
+                if p["cpu_read_s"] < 0.999 * p["wall_s"]:
+                    line += " (of the %.6f s read)" % p["cpu_read_s"]
+            elif p["cpu_s"] is not None:
+                line += "  cpu not read"
+            out.append("%s n=%-7d %s" % (line, p["count"], phase_name))
+    return out
 
 
 class phase:
     """`with phase("sebulba.upload"): ...` — a named step of a loop
     thread. Opens `jax.profiler.TraceAnnotation("ray_tpu.<name>")` (taken
     from `sys.modules`, as `_live_devices()` takes jax: this module never
-    imports it) and adds the `perf_counter` delta and a count to the
-    calling thread's `PhaseClock`. A thread with no clock gets the
-    annotation only. There is no switch: the accumulators are always on,
-    and "tracing on" means a `jax.profiler` session is running."""
+    imports it) and adds the `perf_counter` delta, the thread's CPU-clock
+    delta and a count to the calling thread's `PhaseClock`. A thread with
+    no clock gets the annotation only. There is no switch: the
+    accumulators are always on (the CPU side while a `phase_cpu_reads()`
+    window is open), and "tracing on" means
+    a `jax.profiler` session is running."""
 
-    __slots__ = ("_name", "_span", "_clock", "_t0")
+    __slots__ = ("_name", "_span", "_clock", "_t0", "_c0")
 
     def __init__(self, name: str):
         # The phase's time starts here, not in __enter__: `with phase(..)`
         # does both at once, and with several loop threads under one GIL
         # every call is a point where the thread may have to hand the GIL
-        # over. Reading the clock first keeps that wait inside the phase
+        # over. Reading the clocks first keeps that wait inside the phase
         # instead of in no phase at all.
         self._t0 = time.perf_counter()
+        clock = self._clock = getattr(_thread, "clock", None)
+        # None: an instance whose CPU is not read.
+        self._c0 = _thread_cpu() if _asked and clock is not None \
+            and clock._cpu_id is not None else None
         self._name = name
 
     def __enter__(self):
-        clock = self._clock = getattr(_thread, "clock", None)
+        clock = self._clock
         if clock is not None:
             if __debug__ and clock._open is not None:
                 raise RuntimeError(
                     f"phase {self._name!r} opened inside "
                     f"{clock._open[0]!r}: phases of a thread do not nest")
-            clock._open = (self._name, self._t0)
+            clock._open = (self._name, self._t0, self._c0)
         profiler = sys.modules.get("jax.profiler")
         if profiler is None:
             self._span = None
@@ -458,9 +770,15 @@ class phase:
     def then(self, name: str) -> None:
         """End this phase and begin `name`, inside the one `with` — for a
         step that changes its name half way, as when a lock is taken:
-        the wait for it, then the work under it."""
+        the wait for it, then the work under it. One reading of each
+        clock ends the one and begins the other."""
+        c0 = self._c0
         self.__exit__(None, None, None)
-        self.__init__(name)
+        self._name = name
+        if c0 is None:  # as a phase that begins here
+            clock = self._clock
+            self._c0 = _thread_cpu() if _asked \
+                and clock is not None and clock._cpu_id is not None else None
         self.__enter__()
 
     def __exit__(self, *exc):
@@ -469,13 +787,19 @@ class phase:
         clock = self._clock
         if clock is not None:
             clock._open = None
-            dt = time.perf_counter() - self._t0
+            t0, c0 = self._t0, self._c0
+            # Left on the phase for `then()`: where this one ended is
+            # where the next begins.
+            t1 = self._t0 = time.perf_counter()
             cell = clock._phases.get(self._name)
             if cell is None:
-                clock._phases[self._name] = [dt, 1]
-            else:
-                cell[0] += dt
-                cell[1] += 1
+                cell = clock._phases[self._name] = [0.0, 0, 0.0, 0.0]
+            cell[0] += t1 - t0
+            cell[1] += 1
+            if c0 is not None:
+                c1 = self._c0 = clock._cpu_last = _thread_cpu()
+                cell[2] += c1 - c0
+                cell[3] += t1 - t0
         return False
 
 
@@ -515,7 +839,10 @@ def run_capture(duration_s: float, hz: Optional[float] = None,
     payload a coordinated capture ships back to the head. Where a trace
     was written, `device_account` is its reduction by the program's own
     names (`device_account.account`; None where it holds no device op);
-    whatever goes wrong with the trace or its account is `xla_error`."""
+    whatever goes wrong with the trace or its account is `xla_error`.
+    `host_account` is what this process's clocked threads and the process
+    as a whole spent over the window (`host_account()` above; the window
+    is a `phase_cpu_reads()` one: the phases' CPU is read in it)."""
     from . import config
     duration_s = max(0.05, min(float(duration_s),
                                config.get("RAY_TPU_PROFILE_MAX_S")))
@@ -537,10 +864,13 @@ def run_capture(duration_s: float, hz: Optional[float] = None,
             xla_trace_dir = xla_dir
         except Exception as e:
             xla_error = "%s: %s" % (type(e).__name__, e)
-    if abort_event is not None:
-        abort_event.wait(duration_s)
-    else:
-        time.sleep(duration_s)
+    with phase_cpu_reads():  # the window's phases read their CPU
+        host_before = host_snapshot()
+        if abort_event is not None:
+            abort_event.wait(duration_s)
+        else:
+            time.sleep(duration_s)
+        host_after = host_snapshot()
     if tracing:
         try:
             import jax
@@ -553,6 +883,7 @@ def run_capture(duration_s: float, hz: Optional[float] = None,
     out["pid"] = os.getpid()
     out["duration_s"] = duration_s
     out["xla_trace_dir"] = xla_trace_dir
+    out["host_account"] = host_account(host_before, host_after)
     if xla_trace_dir:
         try:
             from . import device_account

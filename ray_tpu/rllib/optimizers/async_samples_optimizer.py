@@ -25,7 +25,8 @@ from typing import List
 
 import ray_tpu
 
-from ..._private.profiling import PhaseClock, phase, sum_snapshots
+from ..._private.profiling import (PhaseClock, host_snapshot, off_cpu_s,
+                                   phase, sum_snapshots)
 from ..sample_batch import SampleBatch
 from ..utils.actors import TaskPool
 from ..utils.compression import decompress_batch
@@ -60,12 +61,13 @@ class LearnerThread(threading.Thread):
         self.stats = {}
         self.error = None  # first exception that killed the thread
         self.learner_queue_size = WindowStat("learner_queue_size", 50)
-        self.queue_timer = _Timer()
         self.grad_timer = _Timer()
         # This thread's time by phase: learner.dequeue here, and
         # learner.h2d / lock_wait / train / readback inside the policy's
         # learn calls (the span of grad_timer).
         self.clock = PhaseClock()
+        # The wait for a batch, as a timer's readings: a view of the clock.
+        self.queue_timer = _PhaseTimer(self.clock, "learner.dequeue")
         self.daemon = True
         self._hbm_last = 0.0
 
@@ -82,7 +84,7 @@ class LearnerThread(threading.Thread):
     def step(self):
         from ..._private import metrics as metrics_mod
         t0 = time.perf_counter()
-        with self.queue_timer, phase("learner.dequeue"):
+        with phase("learner.dequeue"):
             try:
                 batch = self.inqueue.get(timeout=0.5)
             except queue.Empty:
@@ -122,6 +124,11 @@ class LearnerThread(threading.Thread):
 
     def stop(self):
         self.stopped = True
+
+
+# An actor's phases that block on nothing but the interpreter: numpy and
+# Python, no device call and no lock of the program's own.
+INTERPRETER_PHASES = ("sebulba.env_step", "sebulba.record")
 
 
 class InlineActorThread(threading.Thread):
@@ -190,8 +197,12 @@ class InlineActorThread(threading.Thread):
         `sebulba_action_fetch_pct.aK` (host blocked on the device
         round-trip), `sebulba_env_step_pct.aK` (both shares of the
         actor thread's clock: phases `sebulba.fetch`, `sebulba.env_step`
-        over `wall_s`), and `sebulba_policy_lag_steps.aK` (mean
-        selection lag)."""
+        over `wall_s`), `sebulba_gil_wait_pct.aK` (wall less CPU seconds
+        of the phases that block on nothing but the interpreter, over
+        `wall_s`: the thread wanted the GIL, or a core, and did not have
+        it; published for a fragment sampled inside a
+        `profiling.phase_cpu_reads()` window, as a capture opens one), and
+        `sebulba_policy_lag_steps.aK` (mean selection lag)."""
         if not hasattr(self.sampler, "transfer_stats"):
             return  # host-side VectorSampler: no device pipeline
         stats = self.sampler.transfer_stats()
@@ -210,6 +221,11 @@ class InlineActorThread(threading.Thread):
                          - last["phases"]["seconds"].get(name, 0.0))
                 metrics_mod.set_gauge(
                     f"{gauge}.{tag}", 100.0 * spent / dt, rollup="mean")
+            off_cpu = off_cpu_s(now, last["phases"], INTERPRETER_PHASES)
+            if off_cpu is not None:
+                metrics_mod.set_gauge(
+                    f"sebulba_gil_wait_pct.{tag}", 100.0 * off_cpu / dt,
+                    rollup="mean")
             dsteps = stats["steps"] - last["steps"]
             if dsteps > 0:
                 metrics_mod.set_gauge(
@@ -278,6 +294,10 @@ class AsyncSamplesOptimizer(PolicyOptimizer):
         self.learner_stats = {}
         self._inline_actors: List[InlineActorThread] = []
         self._inline_sampled_seen = 0
+        # The thread that calls step() in inline mode: one phase,
+        # `driver.collect`, so that every Python thread of the path has a
+        # clock and the process's CPU less theirs is its native threads'.
+        self._driver_clock = PhaseClock()
         self._compiled = False
         # Straggler detection (straggler.py): per-actor throughput /
         # fetch-latency windows judged against the fleet median each
@@ -584,12 +604,14 @@ class AsyncSamplesOptimizer(PolicyOptimizer):
         # state still allows for a slow host->device link.
         timeout = 600.0 if not self._compiled else 180.0
         deadline = time.monotonic() + timeout
-        while trained == 0 and time.monotonic() < deadline:
-            self._check_learner_alive()
-            try:
-                trained += self.learner.outqueue.get(timeout=1.0)
-            except queue.Empty:
-                continue
+        self._driver_clock.bind()
+        with phase("driver.collect"):
+            while trained == 0 and time.monotonic() < deadline:
+                self._check_learner_alive()
+                try:
+                    trained += self.learner.outqueue.get(timeout=1.0)
+                except queue.Empty:
+                    continue
         if trained == 0:
             raise RuntimeError(
                 "inline actors produced no trained batch within "
@@ -603,6 +625,21 @@ class AsyncSamplesOptimizer(PolicyOptimizer):
         self.num_steps_trained += trained
         self.learner_stats = self.learner.stats
         return self.learner_stats
+
+    def host_account(self) -> dict:
+        """Cumulative time of this optimizer's own threads, the one handle
+        a reader or an operator's script takes deltas of
+        (`profiling.host_account(before, after)`), as
+        `profiling.host_snapshot` makes it: `threads` = a
+        `PhaseClock.snapshot()` each (wall and CPU seconds by phase) for
+        the inline actors, the learner and the thread that calls
+        `step()`; `process` = `profiling.process_cpu()`, native threads
+        included; `t` the time of the reading."""
+        named = [(a.name, a.sampler.clock) for a in self._inline_actors
+                 if hasattr(a.sampler, "clock")]
+        named.append((self.learner.name, self.learner.clock))
+        named.append(("driver", self._driver_clock))
+        return host_snapshot(named)
 
     def inline_episodes(self):
         """Drain episode metrics from inline-actor samplers (merged into
@@ -785,6 +822,27 @@ class _Timer:
     def __exit__(self, *exc):
         self.total += time.perf_counter() - self._start
         self.count += 1
+
+    @property
+    def mean(self) -> float:
+        return self.total / self.count if self.count else 0.0
+
+
+class _PhaseTimer:
+    """A `_Timer`'s readings (`total`, `count`, `mean`) of one phase of a
+    `PhaseClock`: what timed the phase's span a second time is a view of
+    the clock."""
+
+    def __init__(self, clock: PhaseClock, name: str):
+        self._clock, self._name = clock, name
+
+    @property
+    def total(self) -> float:
+        return self._clock.seconds(self._name)
+
+    @property
+    def count(self) -> int:
+        return self._clock.count(self._name)
 
     @property
     def mean(self) -> float:

@@ -473,9 +473,10 @@ def _print_profile_summary(bundle: dict, top: int = 8):
     """Top-N hottest frames per process — the bundle usable without
     flamegraph tooling — and, under a process that took a device trace,
     its account: the ten heaviest scopes, collective seconds, idle by
-    family and phase."""
+    family and phase; under a process whose threads keep phase clocks,
+    their wall and CPU seconds by phase and the process's CPU."""
     from ray_tpu._private import device_account
-    from ray_tpu._private.profiling import top_frames
+    from ray_tpu._private.profiling import render_host_account, top_frames
     procs = bundle.get("processes") or []
     print(f"capture {bundle.get('capture_id')}: "
           f"{bundle.get('duration_s')}s @ {bundle.get('hz')}Hz, "
@@ -501,6 +502,10 @@ def _print_profile_summary(bundle: dict, top: int = 8):
         if p.get("device_account"):
             for line in device_account.render(p["device_account"], top=10,
                                               indent="   "):
+                print(line)
+        if (p.get("host_account") or {}).get("threads"):
+            for line in render_host_account(p["host_account"],
+                                            indent="   "):
                 print(line)
         for d in p.get("hbm") or []:
             print(f"   hbm {d['device']} ({d.get('kind') or d.get('platform')}): "

@@ -380,6 +380,46 @@ def test_the_summary_prints_the_account_under_its_process(tmp_path, capsys):
         assert needle in text, needle
 
 
+@pytest.mark.time_limit(60)
+@pytest.mark.parametrize("cpu_clock", [True, False],
+                         ids=["cpu", "wall_only"])
+def test_the_summary_prints_the_host_account_under_its_process(
+        capsys, monkeypatch, cpu_clock):
+    """What `run_capture` returns as `host_account`, printed by `profile
+    --summarize` under the process: a thread's wall and CPU seconds by
+    phase and the process's CPU; wall seconds alone where the platform has
+    no per-thread CPU clock."""
+    from ray_tpu.scripts.scripts import _print_profile_summary
+    if not cpu_clock:
+        monkeypatch.delattr(time, "pthread_getcpuclockid")
+    clock = profiling.PhaseClock()
+    out = {}
+
+    def loop():
+        clock.bind()
+        before = profiling.host_snapshot([("loop-thread", clock)])
+        with profiling.phase("test.work"):
+            time.sleep(0.01)
+        out["acct"] = profiling.host_account(
+            before, profiling.host_snapshot([("loop-thread", clock)]))
+
+    t = threading.Thread(target=loop, daemon=True)
+    t.start()
+    t.join(60)
+    assert not t.is_alive()
+    proc = {"role": "driver", "pid": 7, "node": "node0", "threads": ["a"],
+            "folded": {"a;f.py:g": 3}}
+    bundle = {"capture_id": "c", "duration_s": 1, "hz": 99,
+              "processes": [json.loads(json.dumps(dict(
+                  proc, host_account=out["acct"]))), dict(proc, pid=8)]}
+    _print_profile_summary(bundle)
+    text = capsys.readouterr().out
+    assert text.count("host account over") == 1  # the second has none
+    assert "loop-thread: wall" in text and "test.work" in text
+    assert ("python_cores" in text and "off-cpu" in text) == cpu_clock
+    assert ("wall seconds only" in text) == (not cpu_clock)
+
+
 def test_the_module_s_main_prints_the_whole_tables(tmp_path, capsys):
     s = Space()
     two_threads_of_one_name(s)

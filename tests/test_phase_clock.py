@@ -12,7 +12,12 @@ the names it puts on its time (ISSUE 25):
   and no eager op, nothing is lowered, and what `record` retains are the
   select program's own window handles;
 - every program carries its `jax.named_scope`s in its lowered op
-  metadata, so that no refactor drops one unnoticed.
+  metadata, so that no refactor drops one unnoticed;
+- the clock keeps CPU seconds beside wall seconds (ISSUE 50): a phase that
+  spins is on the CPU and one that sleeps is not, any thread may read
+  another's, an open phase counts up to now, `then` loses no CPU, a
+  platform without a per-thread CPU clock reads None, the registry
+  forgets what nobody owns, and `run_capture`'s `host_account` adds up.
 
 Every wait has its own timeout (there is no pytest-timeout here).
 """
@@ -160,6 +165,393 @@ def test_importing_the_primitive_does_not_import_jax():
             "assert c.snapshot()['counts'] == {'x': 1}\n"
             "assert 'jax' not in sys.modules, 'jax imported'")
     subprocess.run([sys.executable, "-c", code], check=True, timeout=WAIT_S)
+
+
+# ---------------------------------------------------------------------
+# CPU seconds beside wall seconds (ISSUE 50)
+# ---------------------------------------------------------------------
+CPU_KEYS = ("cpu_seconds", "cpu_read_seconds", "cpu_s", "other_cpu_s",
+            "run_delay_s")
+# A CPU clock and `perf_counter` are two clocks: a phase's CPU may pass
+# its wall by what reading them costs, a microsecond a phase.
+CLOCK_SLACK_S = 2e-3
+
+
+@pytest.fixture
+def cpu_reads():
+    """The phases read their CPU: a `phase_cpu_reads()` window, as a
+    capture opens one, around the whole test."""
+    with profiling.phase_cpu_reads():
+        yield
+
+
+def _spin(cpu_s: float) -> None:
+    """Pure Python until the calling thread has run for `cpu_s`."""
+    end = time.thread_time() + cpu_s
+    while time.thread_time() < end:
+        sum(i * i for i in range(200))
+
+
+def _spin_and_sleep():
+    clock = PhaseClock().bind()
+    with phase("test.spin"):
+        _spin(0.1)
+    with phase("test.sleep"):
+        time.sleep(0.1)
+    return clock.snapshot()
+
+
+@pytest.mark.time_limit(120)
+@pytest.mark.parametrize("name,on_cpu", [("test.spin", True),
+                                         ("test.sleep", False)])
+def test_a_phase_that_spins_is_on_the_cpu_and_one_that_sleeps_is_not(
+        cpu_reads, name, on_cpu):
+    # The best of a few tries: on a loaded box a spinning thread may be
+    # kept off the cores for a while, and that is not what is tested.
+    for _ in range(5):
+        snap = _in_thread(_spin_and_sleep)
+        wall, cpu = snap["seconds"][name], snap["cpu_seconds"][name]
+        if (cpu >= 0.5 * wall) if on_cpu else (cpu < 0.2 * wall):
+            break
+    else:
+        pytest.fail(f"{name}: cpu {cpu} of wall {wall}")
+    assert set(snap["cpu_seconds"]) == set(snap["seconds"])
+    assert snap["cpu_read_seconds"] == snap["seconds"]  # every one is read
+    assert snap["cpu_s"] >= 0.1 - CLOCK_SLACK_S
+
+
+@pytest.mark.time_limit(120)
+def test_wall_covers_cpu_and_what_no_phase_covers_is_not_negative(cpu_reads):
+    def loop():
+        clock = PhaseClock().bind()
+        for _ in range(30):
+            with phase("test.spin"):
+                _spin(0.002)
+            with phase("test.sleep"):
+                time.sleep(0.001)
+            _spin(0.001)  # in no phase
+        return clock.snapshot()
+
+    snap = _in_thread(loop)
+    for name, wall in snap["seconds"].items():
+        assert wall >= snap["cpu_seconds"][name] - CLOCK_SLACK_S, name
+    assert snap["wall_s"] >= snap["cpu_s"] - CLOCK_SLACK_S
+    assert snap["other_cpu_s"] >= 30 * 0.001 - CLOCK_SLACK_S
+    assert abs(sum(snap["cpu_seconds"].values()) + snap["other_cpu_s"]
+               - snap["cpu_s"]) < 1e-9
+    assert snap["run_delay_s"] is None or snap["run_delay_s"] >= 0
+
+
+@pytest.mark.time_limit(120)
+def test_any_thread_reads_the_bound_threads_cpu_and_it_outlives_the_thread(
+        cpu_reads):
+    spun, release = threading.Event(), threading.Event()
+    clock, own = PhaseClock(), {}
+
+    def body():
+        clock.bind()
+        t0 = time.thread_time()
+        with phase("test.spin"):
+            _spin(0.05)
+        own["cpu"] = time.thread_time() - t0
+        spun.set()
+        assert release.wait(WAIT_S)  # asleep: its CPU clock stands still
+
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    try:
+        assert spun.wait(WAIT_S)
+        during = clock.snapshot()
+    finally:
+        release.set()
+        t.join(WAIT_S)
+    assert not t.is_alive()
+    after = clock.snapshot()
+    # The reader's clock_gettime on the thread's clock id against the
+    # thread's own thread_time: the same kernel counter.
+    assert abs(during["cpu_s"] - own["cpu"]) < 0.02, (during, own)
+    assert abs(during["cpu_seconds"]["test.spin"] - own["cpu"]) < 0.02
+    # Gone: the value the thread last wrote, and no schedstat to read.
+    assert after["cpu_seconds"] == during["cpu_seconds"]
+    assert 0.05 - CLOCK_SLACK_S <= after["cpu_s"] <= during["cpu_s"] + 1e-9
+    assert after["run_delay_s"] is None
+    assert after["wall_s"] > during["wall_s"]
+
+
+@pytest.mark.time_limit(120)
+def test_an_open_phases_cpu_counts_up_to_now(cpu_reads):
+    entered, stop = threading.Event(), threading.Event()
+    clock = PhaseClock()
+
+    def body():
+        clock.bind()
+        with phase("test.open"):
+            entered.set()
+            while not stop.is_set():
+                _spin(0.005)
+
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    try:
+        assert entered.wait(WAIT_S)
+        deadline = time.monotonic() + WAIT_S
+        during = clock.snapshot()
+        while (during["cpu_seconds"]["test.open"] < 0.02
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+            during = clock.snapshot()
+    finally:
+        stop.set()
+        t.join(WAIT_S)
+    assert not t.is_alive()
+    assert during["counts"] == {"test.open": 0}
+    assert during["cpu_seconds"]["test.open"] >= 0.02
+    assert during["seconds"]["test.open"] >= \
+        during["cpu_seconds"]["test.open"] - CLOCK_SLACK_S
+    after = clock.snapshot()
+    assert after["counts"] == {"test.open": 1}
+    assert after["cpu_seconds"]["test.open"] >= \
+        during["cpu_seconds"]["test.open"] - CLOCK_SLACK_S
+
+
+@pytest.mark.time_limit(60)
+def test_then_loses_no_cpu_between_the_two_phases(cpu_reads, monkeypatch):
+    """One reading ends the first phase and begins the second: with a CPU
+    clock that ticks once a reading, two phases joined by `then` take the
+    readings 1, 2, 3 and account for all of 3 - 1."""
+    import itertools
+    ticks = itertools.count()
+    monkeypatch.setattr(profiling, "_thread_cpu", lambda: float(next(ticks)))
+
+    def body():
+        clock = PhaseClock().bind()
+        with phase("test.wait") as step:
+            step.then("test.work")
+        return clock
+
+    clock = _in_thread(body)
+    assert {k: v[1:3] for k, v in clock._phases.items()} == {
+        "test.wait": [1, 1.0], "test.work": [1, 1.0]}
+    assert clock._cpu_last - clock._cpu_start == 3.0
+
+
+@pytest.mark.time_limit(120)
+def test_the_phases_cpu_is_read_only_while_somebody_asks(monkeypatch):
+    real, reads = profiling._thread_cpu, []
+
+    def counted():
+        reads.append(1)
+        return real()
+
+    monkeypatch.setattr(profiling, "_thread_cpu", counted)
+
+    def rounds(n):
+        for _ in range(n):
+            with phase("test.spin"):
+                _spin(0.004)
+            with phase("test.lock_wait") as step:
+                time.sleep(0.002)
+                step.then("test.sleep")
+                time.sleep(0.002)
+
+    def loop():
+        clock = PhaseClock().bind()
+        del reads[:]
+        rounds(5)
+        quiet = clock.snapshot(), len(reads)
+        with profiling.phase_cpu_reads():
+            with profiling.phase_cpu_reads():  # windows may overlap
+                rounds(5)
+            rounds(5)
+        asked = clock.snapshot(), len(reads)
+        rounds(5)
+        return quiet, asked, (clock.snapshot(), len(reads))
+
+    for _ in range(5):  # the best of a few tries, as above
+        (quiet, n_quiet), (asked, n_asked), (after, n_after) = \
+            _in_thread(loop)
+        spin = asked["cpu_seconds"]["test.spin"] / asked[
+            "cpu_read_seconds"]["test.spin"]
+        if spin >= 0.5:
+            break
+    assert spin >= 0.5, asked
+    # Nobody asked: no read, no CPU by phase, and the thread's own total
+    # (read by the snapshot) is there all the same.
+    assert n_quiet == 0
+    assert set(quiet["cpu_seconds"].values()) == {0.0}
+    assert set(quiet["cpu_read_seconds"].values()) == {0.0}
+    assert quiet["cpu_s"] >= 5 * 0.004 - CLOCK_SLACK_S
+    assert quiet["other_cpu_s"] == quiet["cpu_s"]
+    # Asked: two reads a phase, and `then`'s one reading serves both the
+    # phase it ends and the one it begins.
+    assert n_asked == 10 * (2 + 3)
+    assert asked["counts"] == {"test.spin": 15, "test.lock_wait": 15,
+                               "test.sleep": 15}
+    for name in ("test.lock_wait", "test.sleep"):
+        assert asked["cpu_seconds"][name] < \
+            0.2 * asked["cpu_read_seconds"][name]
+    for name, wall in asked["seconds"].items():
+        assert 0.4 * wall < asked["cpu_read_seconds"][name] < 0.9 * wall
+    # The window closed: nothing more is read.
+    assert n_after == n_asked
+    assert after["cpu_read_seconds"] == asked["cpu_read_seconds"]
+    assert after["counts"]["test.spin"] == 20
+    assert profiling._asked == 0
+
+
+@pytest.mark.time_limit(60)
+def test_sum_snapshots_adds_the_cpu_keys(cpu_reads):
+    a, b = _in_thread(_spin_and_sleep), _in_thread(_spin_and_sleep)
+    both = sum_snapshots([a, b])
+    for key in ("wall_s", "other_s", "cpu_s", "other_cpu_s"):
+        assert both[key] == a[key] + b[key], key
+    assert both["cpu_seconds"] == {
+        name: a["cpu_seconds"][name] + b["cpu_seconds"][name]
+        for name in a["cpu_seconds"]}
+    if a["run_delay_s"] is not None and b["run_delay_s"] is not None:
+        assert both["run_delay_s"] == a["run_delay_s"] + b["run_delay_s"]
+    # One thread without a CPU clock: the sums that need it are None.
+    c = dict(b, **{key: None for key in CPU_KEYS})
+    mixed = sum_snapshots([a, c])
+    assert [mixed[key] for key in CPU_KEYS] == [None] * 5
+    assert mixed["seconds"] == both["seconds"]
+
+
+@pytest.mark.time_limit(60)
+def test_without_a_per_thread_cpu_clock_the_new_keys_are_none(monkeypatch):
+    monkeypatch.delattr(time, "pthread_getcpuclockid")
+
+    def loop():
+        clock = PhaseClock().bind()
+        with phase("test.a") as step:
+            time.sleep(0.002)
+            step.then("test.b")
+        with phase("test.a"):
+            pass
+        return clock.snapshot()
+
+    snap = _in_thread(loop)
+    assert [snap[key] for key in CPU_KEYS] == [None] * 5
+    assert snap["counts"] == {"test.a": 2, "test.b": 1}
+    assert snap["seconds"]["test.a"] >= 0.002
+    assert abs(sum(snap["seconds"].values()) + snap["other_s"]
+               - snap["wall_s"]) < 1e-9
+    acct = profiling.host_account(
+        {"threads": {"t": snap}, "process": profiling.process_cpu(), "t": 0.0},
+        {"threads": {"t": snap}, "process": profiling.process_cpu(), "t": 1.0})
+    assert acct["threads"]["t"]["cpu_s"] is None
+    assert acct["process"]["python_cores"] is None
+    assert "wall seconds only" in profiling.render_host_account(acct)[0]
+
+
+@pytest.mark.time_limit(60)
+def test_the_registry_forgets_a_clock_whose_owner_is_collected():
+    import gc
+
+    class Owner:
+        def __init__(self):
+            self.clock = PhaseClock()
+
+    owner = Owner()
+    thread = threading.Thread(target=owner.clock.bind, name="test-owned",
+                              daemon=True)
+    thread.start()
+    thread.join(WAIT_S)
+    assert ("test-owned", owner.clock) in profiling.clocks()
+    # Its thread has ended: still listed, but no live thread to account for.
+    assert "test-owned" not in profiling.host_snapshot()["threads"]
+    del owner
+    gc.collect()
+    assert "test-owned" not in [name for name, _ in profiling.clocks()]
+    assert set(profiling.process_cpu()) == {"cpu_s", "cores"}
+    assert profiling.process_cpu()["cores"] >= 1
+
+
+@pytest.mark.time_limit(120)
+def test_run_capture_returns_a_host_account_that_adds_up():
+    stop = threading.Event()
+    clock = PhaseClock()
+
+    def loop():
+        clock.bind()
+        while not stop.is_set():
+            with phase("test.spin"):
+                _spin(0.002)
+            with phase("test.sleep"):
+                time.sleep(0.002)
+
+    t = threading.Thread(target=loop, name="test-capture-loop", daemon=True)
+    t.start()
+    try:
+        deadline = time.monotonic() + WAIT_S
+        while not clock.snapshot()["counts"].get("test.sleep") \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
+        out = profiling.run_capture(0.3)
+    finally:
+        stop.set()
+        t.join(WAIT_S)
+    assert not t.is_alive()
+    acct = out["host_account"]
+    assert 0.3 <= acct["window_s"] < 5.0
+    mine = acct["threads"]["test-capture-loop"]
+    for thread in acct["threads"].values():
+        assert abs(sum(p["wall_s"] for p in thread["phases"].values())
+                   - thread["wall_s"]) < 1e-6
+        assert abs(sum(p["cpu_s"] for p in thread["phases"].values())
+                   - thread["cpu_s"]) < 1e-6
+    assert set(mine["phases"]) == {"test.spin", "test.sleep", "other"}
+    assert mine["phases"]["test.spin"]["count"] > 0
+    assert mine["phases"]["test.spin"]["cpu_s"] > \
+        mine["phases"]["test.sleep"]["cpu_s"]
+    proc = acct["process"]
+    assert abs(proc["python_cpu_s"] + proc["native_cpu_s"]
+               - proc["cpu_s"]) < 1e-9
+    assert proc["python_cpu_s"] >= mine["cpu_s"] > 0
+    assert proc["python_cores"] == proc["python_cpu_s"] / acct["window_s"]
+    text = "\n".join(profiling.render_host_account(acct))
+    assert "test-capture-loop" in text and "off-cpu" in text
+
+
+@pytest.mark.time_limit(120)
+def test_two_threads_that_spin_under_one_gil_read_one_core():
+    stop, clocks = threading.Event(), [PhaseClock(), PhaseClock()]
+
+    def loop(clock):
+        clock.bind()
+        with phase("test.spin"):
+            while not stop.is_set():
+                sum(i * i for i in range(200))
+
+    threads = [threading.Thread(target=loop, args=(c,), daemon=True)
+               for c in clocks]
+    for t in threads:
+        t.start()
+    named = [("spin-%d" % i, c) for i, c in enumerate(clocks)]
+    try:
+        deadline = time.monotonic() + WAIT_S
+        while not all(c._open for c in clocks) \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
+        # A reading takes the clocks one after the other; on a loaded box
+        # the reader may be kept waiting between two of them, and a window
+        # that the threads' walls do not match is taken again.
+        for _ in range(5):
+            before = profiling.host_snapshot(named)
+            time.sleep(0.5)
+            acct = profiling.host_account(before,
+                                          profiling.host_snapshot(named))
+            walls = sum(t["wall_s"] for t in acct["threads"].values())
+            if abs(walls - 2 * acct["window_s"]) < 0.1 * acct["window_s"]:
+                break
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(WAIT_S)
+    assert not any(t.is_alive() for t in threads)
+    assert abs(walls - 2 * acct["window_s"]) < 0.1 * acct["window_s"]
+    # Two threads wanted the CPU the whole time and one GIL let one run.
+    assert 0.3 < acct["process"]["python_cores"] <= 1.2, acct["process"]
 
 
 # ---------------------------------------------------------------------

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
+from ray_tpu._private import profiling
 from ray_tpu.rllib import sample_batch as sb
 from ray_tpu.rllib.env.batched_env import BatchedCartPole
 from ray_tpu.rllib.evaluation.device_sampler import DeviceSebulbaSampler
@@ -471,16 +472,35 @@ class TestPipelineSmoke:
         assert len(sampler.groups) == 2 and sampler.k == 5
         deadline = time.monotonic() + 60
         gauges = {}
-        while time.monotonic() < deadline:
-            t.train()
-            gauges = metrics_mod.snapshot()["gauges"]
-            if "sebulba_action_fetch_pct.a0" in gauges:
-                break
+        # The phases read their CPU while somebody asks, as a capture does.
+        with profiling.phase_cpu_reads():
+            while time.monotonic() < deadline:
+                t.train()
+                gauges = metrics_mod.snapshot()["gauges"]
+                if "sebulba_action_fetch_pct.a0" in gauges \
+                        and "sebulba_gil_wait_pct.a0" in gauges:
+                    break
         assert "sebulba_action_fetch_pct.a0" in gauges
         assert "sebulba_env_step_pct.a0" in gauges
         assert "sebulba_policy_lag_steps.a0" in gauges
         # Mean selection lag of k=5 windows is (k-1)/2 = 2.
         assert abs(gauges["sebulba_policy_lag_steps.a0"] - 2.0) < 1e-6
+        # Wall less CPU of the env-step and record phases, over the
+        # actor's wall: a share of it (a CPU clock may pass the wall's by
+        # what reading them costs).
+        assert -1.0 <= gauges["sebulba_gil_wait_pct.a0"] <= 100.0
+
+        # Every Python thread of the inline path has a clock, and the
+        # optimizer hands them out under one handle.
+        account = opt.host_account()
+        assert set(account["threads"]) == {"inline-actor-0", "learner",
+                                           "driver"}
+        assert account["threads"]["driver"]["counts"]["driver.collect"] > 0
+        for snap in account["threads"].values():
+            assert snap["cpu_s"] > 0 and set(snap["cpu_seconds"]) == set(
+                snap["seconds"])
+        assert account["process"]["cpu_s"] >= sum(
+            snap["cpu_s"] for snap in account["threads"].values())
 
         stats = opt.stats()
         transfer = stats["transfer"]
@@ -544,3 +564,11 @@ class TestPipelineSmoke:
             assert phases["sebulba.upload"] > 0 and phases["sebulba.select"] > 0
         finally:
             t.stop()
+        # The learner's queue timer (`learner_wait_pct`,
+        # `learner_queue_wait_ms`) is a view of the stopped thread's clock.
+        learner = opt.learner.clock.snapshot()
+        timer = opt.learner.queue_timer
+        assert timer.total == learner["seconds"]["learner.dequeue"]
+        assert timer.count == learner["counts"]["learner.dequeue"]
+        assert opt.stats()["timing"]["learner_queue_wait_ms"] == round(
+            1000 * timer.total / timer.count, 3)
