@@ -320,7 +320,9 @@ The vocabulary's last id is the MASK id (its logit `MASK_LOGIT`: probability
   the clean stream's keys of earlier blocks and their own block's
   (`block_stream_attention`: the plain mask, or the splash kernel with the
   mask computed inside it). Logits are taken at each position's own pass's
-  stream, values at each block's first position of pass 0's.
+  stream, values at each block's first position of pass 0's. The last
+  layer's clean stream stops at its keys and values, as the commit pass's
+  does: nothing reads its output.
 
 Both return the state, so a decode can follow a causal pass: {"kv": a
 layer's caches (none for a layer that is no attention), "pos"}, and, a key
@@ -341,6 +343,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import decode_attention, state_step
 
@@ -1015,7 +1018,8 @@ def _causal_fused(q, k, v, episode, scale, window=0, mask=None):
     t = CAUSAL_TILE
     # The kernel's static mask: which tiles it visits at all, and the
     # mask it computes inside those the boundary crosses (`mask`: another
-    # than the causal or the window one, `block_stream_attention`'s).
+    # than the causal or the window one, `block_stream_attention`'s, which
+    # may be rectangular: the queries are then the keys' last T positions).
     if mask is None:
         mask = (splash.LocalMask((T, T), (window - 1, 0), 0) if window
                 else splash.CausalMask((T, T)))
@@ -1034,7 +1038,8 @@ def _causal_fused(q, k, v, episode, scale, window=0, mask=None):
         [mask] * (heads // groups if grouped else heads)), **settings)
 
     def a_row(q, k, v, s):
-        return kernel(q, k, v, segment_ids=splash.SegmentIds(s, s))
+        return kernel(q, k, v, segment_ids=splash.SegmentIds(
+            s if T == s.shape[0] else s[-T:], s))
     # The kernel takes no scale.
     if not grouped:
         return jax.vmap(a_row)(q * scale, k, v, segments)
@@ -1123,57 +1128,99 @@ def block_stream_allowed(T: int, block: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _block_stream_mask(T: int, block: int, streams: int):
+def _block_stream_mask(T: int, block: int, streams: int, queries: int):
     """`block_stream_allowed` as a mask the splash kernel computes inside
-    itself, tile by tile, from the positions' numbers."""
+    itself, tile by tile, from the positions' numbers: the LAST `queries`
+    streams' queries (all of them: the square mask) against every stream's
+    keys, a query's number counted from the first stream's first position
+    as a key's is."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as masks)
+    allowed = block_stream_allowed(T, block)
+    first = (streams - queries) * T
 
     class BlockStreamMask(masks._ComputableMask):
         def __init__(self):
-            super().__init__((streams * T,) * 2,
-                             block_stream_allowed(T, block))
+            super().__init__(
+                (queries * T, streams * T),
+                (lambda q, k: allowed(q + first, k)) if first else allowed)
+
+        def __getitem__(self, idx):
+            """The library reads the mask tile by tile at every start, to
+            see which tiles it visits: a block's positions read alike, so
+            one of each block is computed (a sixteenth of the work at blocks
+            of 4: ~1 s of set-up for the two masks at T 2,048)."""
+            q0, q1, k0, k1 = (edge for s, n in zip(idx, self.shape)
+                              for edge in s.indices(n)[:2])
+            if any(edge % block for edge in (q0, q1, k0, k1)):
+                return super().__getitem__(idx)
+            one = self.mask_function(np.arange(q0, q1, block)[:, None],
+                                     np.arange(k0, k1, block)[None, :])
+            return one.repeat(block, axis=0).repeat(block, axis=1)
 
         def __eq__(self, other):
             return self is other
 
         def __hash__(self):
-            return hash((type(self).__name__, T, block, streams))
+            return hash((type(self).__name__, T, block, streams, queries))
     return BlockStreamMask()
 
 
+def block_stream_tiles(T: int, block: int, streams: int,
+                       queries: int) -> tuple:
+    """(tiles of `CAUSAL_TILE` x `CAUSAL_TILE` the fused form of
+    `block_stream_attention` visits with the last `queries` streams'
+    queries against `streams` streams' keys of `T` positions, all the tiles
+    of that rectangle): a tile is visited where any of its queries may read
+    any of its keys, as `causal_window_tiles` counts. T 2,048, a clean and
+    two noisy streams: 38 of 144 with every stream's queries, 28 of 96 with
+    the noisy streams' alone."""
+    t = CAUSAL_TILE
+    allowed = block_stream_allowed(T, block)
+    first, at = (streams - queries) * T, np.arange(t)
+    rows, columns = queries * T // t, streams * T // t
+    return sum(
+        bool(allowed(first + i * t + at[:, None], j * t + at[None, :]).any())
+        for i in range(rows) for j in range(columns)), rows * columns
+
+
 def _streams_plain(q, k, v, episode, scale, block, streams):
-    n = q.shape[2]
+    n, rows = k.shape[2], q.shape[2]
     ids = jnp.arange(n)
     mask = block_stream_allowed(n // streams, block)(
-        ids[:, None], ids[None, :])[None] & (
-            episode[:, :, None] == episode[:, None, :])
+        ids[n - rows:, None], ids[None, :])[None] & (
+            episode[:, n - rows:, None] == episode[:, None, :])
     return _masked_plain(q, k, v, mask, scale)
 
 
 def block_stream_attention(q, k, v, episode, scale, block, streams):
-    """softmax(q k^T * scale) v of a block-diffusion learner's pass: q
-    [B, heads, streams * T, d], k, v [B, groups, streams * T, d], the clean
-    stream's T positions first and each noisy stream's after it, `episode`
-    [B, streams * T] the episode a position belongs to (the clean stream's,
-    repeated). A query reads, within its episode, what
+    """softmax(q k^T * scale) v of a block-diffusion learner's pass: k, v
+    [B, groups, streams * T, d], the clean stream's T positions first and
+    each noisy stream's after it, `episode` [B, streams * T] the episode a
+    position belongs to (the clean stream's, repeated); q [B, heads, n, d]
+    the queries of the LAST n positions, whole streams of them: all
+    `streams * T` (every layer but the last), or the noisy streams' alone
+    (`block_causal`'s last layer, whose clean stream is read for its keys
+    and values and nothing else). A query reads, within its episode, what
     `block_stream_allowed` says: the clean stream's keys of earlier blocks
     of `block` positions and its own stream's keys of its own block. Two
     forms of that one sum, as `causal_attention`'s and chosen as its are
     (`causal_fused` of the static shape, and the platform): the plain one's
-    scores are [streams * T, streams * T] a head; the fused one is the same
-    splash kernel with the mask computed inside it from the positions'
-    numbers, so that the tiles it visits are those in which any query may
-    read any key (T 2,048, two noisy streams: 38 of 144, where a causal
-    pass over T visits 10 of 16)."""
-    n = q.shape[2]
-    if not causal_fused(n, q.shape[3], v.shape[3]):
+    scores are [n, streams * T] a head; the fused one is the same splash
+    kernel with the mask, square or rectangular, computed inside it from the
+    positions' numbers, so that the tiles it visits are those in which any
+    query may read any key (`block_stream_tiles`; T 2,048, two noisy
+    streams: 38 of 144, and 28 of 96 without the clean queries, whose 10
+    are a causal pass's over T)."""
+    if not all(causal_fused(a.shape[2], q.shape[3], v.shape[3])
+               for a in (q, k)):
         return _streams_plain(q, k, v, episode, scale, block, streams)
+    T = k.shape[2] // streams
     return jax.lax.platform_dependent(
         q, k, v, episode,
         tpu=functools.partial(
             _causal_fused, scale=scale,
-            mask=_block_stream_mask(n // streams, block, streams)),
+            mask=_block_stream_mask(T, block, streams, q.shape[2] // T)),
         default=functools.partial(_streams_plain, scale=scale, block=block,
                                   streams=streams))
 
@@ -2511,8 +2558,10 @@ class TokenDecoder(nn.Module):
         heads a key/value head. A model that generates a block of positions
         a step: the block, the denoising passes, the passes a generated
         token costs the rollout ((denoise_steps + 1) / block_len) and the
-        rows a token costs the learner's layers (its streams: denoise_steps
-        + 1), whether its step's attention is the block entry's kernel
+        rows a token costs a learner's layer in the mean (its streams,
+        denoise_steps + 1, less the last layer's clean stream, which stops
+        at its keys and values: - 1 / num_layers), whether its step's
+        attention is the block entry's kernel
         (1.0: the block's own keys and values operands beside the caches,
         a cached head against its own lanes) or the plain form (0.0), and
         the writes a layer's caches take for one block (1: the commit
@@ -2562,7 +2611,7 @@ class TokenDecoder(nn.Module):
             out.update(
                 block_len=self.block_len, denoise_steps=self.denoise_steps,
                 decode_passes_per_token=streams / self.block_len,
-                learner_rows_per_token=streams,
+                learner_rows_per_token=streams - 1 / self.num_layers,
                 block_attention_kernel=float(kernel),
                 block_cache_writes_per_block=1)
         if learner_rows:
@@ -2698,14 +2747,18 @@ class TokenDecoder(nn.Module):
         return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
 
     def _attend_causal(self, lp, x, positions, episode, cache_rows,
-                       window=0, rotary=True, streams=0):
+                       window=0, rotary=True, streams=0, unread=0):
         """x + Attention(RMSNorm(x)) over a fragment [B, T, H] from an
         empty window; (h, the layer's caches: the rows `cache_rows` of the
         fragment). A head's own keys: within `window` positions where
         given, rotated where `rotary`; with `streams`, the fragment is a
         block-diffusion learner's (`block_causal`: that many streams of T /
         streams positions, read by `block_stream_attention`) and hands over
-        no caches. Head-major throughout: the
+        no caches; of such a fragment's first `unread` positions (its last
+        layer's clean stream, whose output nothing reads) only the keys and
+        values are made, as a commit pass's last layer makes them
+        (`_attend_block`): no query, no score, no `W_o`, and h is of the
+        T - `unread` positions after them. Head-major throughout: the
         projections write queries, keys and values as [B, heads, T, d],
         which `causal_attention` reads, and `W_o` contracts its output
         over (head, d) as it lies, so that no transposed copy of any of
@@ -2718,7 +2771,7 @@ class TokenDecoder(nn.Module):
             return jnp.einsum("btr,rhd->bhtd", n,
                               w.astype(cd).reshape(w.shape[0], heads, -1))
 
-        def joined(o):
+        def joined(o, x=x):
             """x + [B, heads, T, d] W_o."""
             return x + jnp.einsum("bhtd,hdo->bto", o,
                                   lp["wo"].astype(cd).reshape(heads, -1, H))
@@ -2728,8 +2781,10 @@ class TokenDecoder(nn.Module):
             with jax.named_scope("policy/block_attention" if streams
                                  else self._attention_scope(window)):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
+                # The rows that ask: the `unread` before them only answer.
+                asking = (lambda a: a[:, unread:]) if unread else (lambda a: a)
 
-                def projected(w, norm, heads):
+                def projected(w, norm, heads, n=n):
                     a = by_head(n, lp[w], heads)
                     if self.qk_norm == "head":
                         # Over each head's own values, one weight for all.
@@ -2739,12 +2794,12 @@ class TokenDecoder(nn.Module):
                     # QK-norm over the whole projection: heads and d.
                     return rms_norm(a, lp[norm].reshape(heads, 1, -1), eps,
                                     cd, axes=(1, 3))
-                q = projected("wq", "q_norm", heads)
+                q = projected("wq", "q_norm", heads, asking(n))
                 k = projected("wk", "k_norm", groups)
                 scale = q.shape[-1] ** -0.5
                 if rotary:
                     # The softmax's scale goes onto q in RoPE's float32.
-                    q = rope(q, positions, self.rope_theta, scale,
+                    q = rope(q, asking(positions), self.rope_theta, scale,
                              head_major=True)
                     k = rope(k, positions, self.rope_theta, head_major=True)
                     scale = 1.0
@@ -2752,7 +2807,7 @@ class TokenDecoder(nn.Module):
                 if streams:
                     return joined(block_stream_attention(
                         q, k, v, episode, scale, self.block_len,
-                        streams)), ()
+                        streams), asking(x)), ()
                 h = joined(causal_attention(q, k, v, episode, scale, window))
                 caches = tuple(
                     jnp.take_along_axis(jnp.swapaxes(a, 1, 2),
@@ -3086,7 +3141,7 @@ class TokenDecoder(nn.Module):
             value = jnp.dot(y, self.value_w) + self.value_b
         return logits, value
 
-    def _count(self, experts, loads=None, reads=None):
+    def _count(self, experts, loads=None, reads=None, pairs=None):
         """What a pass counted, kept only where the caller asks for the
         collection (and never among the variables `init` returns): the
         experts chosen [expert layers, ..., k], for the reference check;
@@ -3097,7 +3152,8 @@ class TokenDecoder(nn.Module):
         here and the share that its product gathered (the rows that
         `dropless_experts` reports over `M k`, the mean over the expert
         layers; 1.0: the whole size, or the batched form, which sorts
-        none); in a decode step the share of the context's positions its
+        none; `pairs`: a layer's `M k` in the mean, where the layers differ
+        in it); in a decode step the share of the context's positions its
         attention read, the mean over the attention layers (`reads`:
         {layer: the positions it read}), and where the model has window
         layers the same of its full layers and of its window layers
@@ -3112,7 +3168,7 @@ class TokenDecoder(nn.Module):
             self.sow("counters", "expert_load_max", jnp.max(loads))
             self.sow("counters", "expert_load_mean", jnp.mean(loads))
             if self.held != self.num_experts:
-                pairs = experts[0].size
+                pairs = pairs or experts[0].size
                 self.sow("counters", "experts_held_row_share",
                          jnp.mean(jnp.sum(loads, axis=-1)) / pairs)
                 self.sow("counters", "dispatch_rows_share",
@@ -3460,7 +3516,24 @@ class TokenDecoder(nn.Module):
         given position's from pass 0: nothing reads them), so that the head
         runs over T rows; values [B, T / block_len], the value head on each
         block's first position in pass 0). Every layer is recomputed in the
-        backward pass, as `causal`'s are."""
+        backward pass, as `causal`'s are.
+
+        The LAST layer stops where the rollout's commit pass stops
+        (`_block_pass(commit=True)`): of its clean stream only the keys and
+        values are read (by the noisy streams' queries of that layer), so
+        it makes those for all (S + 1) T positions and everything else
+        (queries, scores, `W_o`, the router, the dispatch, the experts) for
+        the S T noisy positions alone; the fused attention's mask is then
+        the rectangle of the noisy queries against every key
+        (`block_stream_tiles`: 28 of 96 tiles at T 2,048, where the square
+        one visits 38 of 144). The rows left out fed an output that neither
+        the logits nor the values index: their cotangent was zero, so the
+        loss and every parameter's gradient are what they were. A position
+        costs the layers S + 1 - 1 / num_layers rows in the mean. The
+        "routing" collection keeps [layers, B, (S + 1) T, k]: the last
+        layer's clean positions state -1, no choice, as the commit pass's
+        last layer does in the rollout's trace. A model of one layer takes
+        the same path: its only layer is its last."""
         cd = self.compute_dtype
         L, S = self.block_len, self.denoise_steps
         B, T = tokens.shape
@@ -3479,27 +3552,34 @@ class TokenDecoder(nn.Module):
             positions = jnp.tile(positions, (1, S + 1))
             episode = jnp.tile(episode, (1, S + 1))
 
-        def block(lp, x):
+        def block(lp, x, last=False):
             h, _ = self._attend_causal(
-                lp, x, positions, episode, None, streams=S + 1)
+                lp, x, positions, episode, None, streams=S + 1,
+                unread=T if last else 0)
             out, load, top_i = self._feed_forward(
-                lp, h.reshape(B * (S + 1) * T, -1))
-            return out.reshape(x.shape), load, top_i
+                lp, h.reshape(-1, h.shape[-1]))
+            top_i = top_i.reshape(B, -1, top_i.shape[-1])
+            if last:
+                top_i = jnp.concatenate([jnp.full(
+                    (B, T) + top_i.shape[2:], -1, top_i.dtype), top_i], axis=1)
+            return out.reshape(h.shape), load, top_i
         if self.num_layers > 1:
             block = jax.checkpoint(
                 block, policy=jax.checkpoint_policies.save_only_these_names(
-                    CAUSAL_KEPT))
+                    CAUSAL_KEPT), static_argnums=(2,))
         x = self.embed[inputs].astype(cd)
         loads, experts = [], []
-        for layer in self.layers:
-            x, load, top_i = block(layer(), x)
+        for i, layer in enumerate(self.layers):
+            x, load, top_i = block(layer(), x, i == self.num_layers - 1)
             loads.append(load)
-            experts.append(top_i.reshape(B, (S + 1) * T, -1))
-        self._count(experts, loads)
+            experts.append(top_i)
+        self._count(experts, loads, pairs=B * T * self.experts_per_token * (
+            S + 1 - 1 / self.num_layers))
         with jax.named_scope("policy/block_streams"):
-            own = (1 + jnp.maximum(steps, 0)) * T + at
+            # x: the S noisy streams alone.
+            own = jnp.maximum(steps, 0) * T + at
             taken = jnp.take_along_axis(x, own[..., None], axis=1)
-            first = x[:, T:2 * T:L]
+            first = x[:, :T:L]
         logits, _ = self._token_logits(taken)
         _, values = self._heads(first)
         return logits, values

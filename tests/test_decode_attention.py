@@ -698,23 +698,27 @@ def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
     assert not cache_copies(compiled, rows, S)
 
 
-def test_the_stream_mask_compiles_inside_the_fused_causal_form(one_chip):
+@pytest.mark.parametrize("queries", [3, 2])
+def test_the_stream_mask_compiles_inside_the_fused_causal_form(
+        queries, one_chip):
     """Mosaic takes `block_stream_allowed`'s integer divisions inside the
     splash kernel and its backward kernel at the seventh cell's learner
     shape (a clean and two noisy streams of 2,048 positions, 32 query heads
-    over 4 key/value heads of 128), and no score matrix is written."""
-    n = 3 * 2048
+    over 4 key/value heads of 128), square (every stream's queries: the
+    first four layers) and rectangular (the noisy streams' queries against
+    every stream's keys: the last layer), and no score matrix is written."""
+    n, rows = 3 * 2048, queries * 2048
 
     def loss(q, k, v, episode):
         return jnp.sum(transformer.block_stream_attention(
             q, k, v, episode, 1.0, block=4, streams=3).astype(jnp.float32))
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
-        shaped(one_chip, 1, 32, n, 128), shaped(one_chip, 1, 4, n, 128),
+        shaped(one_chip, 1, 32, rows, 128), shaped(one_chip, 1, 4, n, 128),
         shaped(one_chip, 1, 4, n, 128),
         shaped(one_chip, 1, n, dtype=jnp.int32)).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert compiled.count("tpu_custom_call") >= 2
-    assert f"f32[1,32,{n},{n}]" not in compiled
+    assert f"f32[1,32,{rows},{n}]" not in compiled
 
 
 def cache_copies(compiled, rows, S):

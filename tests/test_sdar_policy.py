@@ -5,7 +5,10 @@ denoising passes and a commit pass a block) against the plain reference
 {1, 2, 4} at a block of 4: log-probabilities, values, and the order the
 positions were unmasked in; the learner's pass over the same trace against
 the reference, and its log-probabilities equal to the rollout's at unchanged
-parameters; the reference's 2T form against the block-by-block definition;
+parameters; the learner's last layer, whose clean stream stops at its keys
+and values, against the whole form written out here (logits, values, every
+parameter's gradient, the routing collection, the tiles the fused mask
+visits); the reference's 2T form against the block-by-block definition;
 a block of one position and one pass against a plain masked forward; the
 eight shares of an expert layer against the uncut layer; the block-level
 V-trace against a hand-rolled one and the loss and its gradient against the
@@ -14,9 +17,11 @@ refused by the cell's limits; what the builder and the optimizer refuse;
 the trainer on the fused Anakin path from the tuned example.
 """
 
+import functools
 import json
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +38,7 @@ from lib import reference_sdar_moe as reference  # noqa: E402
 
 from ray_tpu.models import catalog, transformer  # noqa: E402
 from ray_tpu.rllib import sample_batch as sb  # noqa: E402
-from ray_tpu.rllib.agents.impala import vtrace  # noqa: E402
+from ray_tpu.rllib.agents.impala import vtrace, vtrace_policy  # noqa: E402
 
 # Two layers; 4 query heads over 2 cached ones of 16; 4 of 8 experts of 32
 # held, 2 a token; an episode of 24 positions, the first given.
@@ -287,6 +292,189 @@ def test_learner_pass_matches_reference_and_the_rollout(dtype):
         np.testing.assert_allclose(values, trace["values"], atol=2e-4)
 
 
+def unmask_steps(rng, rows, positions, L, passes):
+    """A seeded trace's passes [rows, positions]: in every block each pass
+    unmasks L / passes positions, in an order of its own."""
+    return np.stack([np.concatenate([
+        rng.permutation(np.repeat(np.arange(passes), L // passes))
+        for _ in range(positions // L)]) for _ in range(rows)])
+
+
+def whole_block_causal(self, tokens, steps, reset):
+    """`TokenDecoder.block_causal` as it stood before its last layer stopped
+    at the clean stream's keys and values: every one of the (S + 1) T rows
+    through every layer's `_attend_causal` and `_feed_forward`, the logits
+    and values indexed in the S + 1 streams."""
+    L, S = self.block_len, self.denoise_steps
+    B, T_ = tokens.shape
+    at = jnp.arange(T_)
+    starts = (reset > 0).at[:, 0].set(True)
+    episode = jnp.tile(jnp.cumsum(starts, axis=1), (1, S + 1))
+    positions = jnp.tile(
+        at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1), (1, S + 1))
+    inputs = jnp.concatenate([tokens] + [
+        jnp.where(steps < s, tokens, self.mask_id) for s in range(S)], axis=1)
+    x = self.embed[inputs].astype(self.compute_dtype)
+    loads, experts = [], []
+    for layer in self.layers:
+        lp = layer()
+        h, _ = self._attend_causal(
+            lp, x, positions, episode, None, streams=S + 1)
+        out, load, top_i = self._feed_forward(
+            lp, h.reshape(B * (S + 1) * T_, -1))
+        x = out.reshape(x.shape)
+        loads.append(load)
+        experts.append(top_i.reshape(B, (S + 1) * T_, -1))
+    self._count(experts, loads)
+    own = (1 + jnp.maximum(steps, 0)) * T_ + at
+    logits, _ = self._token_logits(
+        jnp.take_along_axis(x, own[..., None], axis=1))
+    _, values = self._heads(x[:, T_:2 * T_:L])
+    return logits, values
+
+
+@functools.lru_cache(maxsize=None)
+def both_forms(layers, passes):
+    """{form: (logits, values, routing [layers, N, (S + 1) T, k], the
+    gradient of `vtrace_loss`)} of `block_causal` and of the whole form above
+    on one seeded trace, whose second row holds two episodes."""
+    model, variables, net = build(
+        num_hidden_layers=layers, denoise_steps=passes)
+    L = net["block_length"]
+    rng = np.random.default_rng(layers * 10 + passes)
+    tokens = rng.integers(0, net["vocab_size"] - 1, (N, T))
+    steps = unmask_steps(rng, N, T, L, passes)
+    dones = np.zeros((N, T), np.float32)
+    dones[:, -1] = dones[1, T // 2 - 1] = 1.0
+    steps[:, 0] = steps[1, T // 2] = -1
+    batch = {
+        sb.OBS: jnp.asarray(tokens.reshape(-1), jnp.int32),
+        sb.ACTIONS: jnp.asarray(tokens.reshape(-1), jnp.int32),
+        sb.UNMASK_STEPS: jnp.asarray(steps.reshape(-1), jnp.int32),
+        sb.REWARDS: jnp.asarray(rng.integers(0, 2, N * T), jnp.float32),
+        sb.DONES: jnp.asarray(dones.reshape(-1)),
+        sb.ACTION_LOGP: jnp.asarray(-np.log(net["vocab_size"]) + rng.uniform(
+            -0.5, 0.5, N * T), jnp.float32)}
+    reset = jnp.concatenate(
+        [jnp.zeros((N, 1)), jnp.asarray(dones)[:, :-1]], axis=1)
+    out = {}
+    for form in ("block_causal", whole_block_causal):
+        def apply(params, kept, form=form):
+            return model.apply(
+                dict(variables, params=params), jnp.asarray(tokens),
+                jnp.asarray(steps), reset, method=form, mutable=kept)
+
+        def apply_blocks(params, batch):
+            held, kept = apply(params, ["counters", "losses"])
+            return held, {k: v[-1] for k, v in kept["counters"].items()}, {}
+        policy = types.SimpleNamespace(
+            config=dict(CFG, rollout_fragment_length=T), block_len=L,
+            apply_blocks=apply_blocks)
+        (logits, values), kept = jax.jit(
+            lambda p: apply(p, ["routing", "counters"]))(variables["params"])
+        grads = jax.jit(jax.grad(lambda p: vtrace_policy.vtrace_loss(
+            policy, p, batch, jax.random.PRNGKey(0), None)[0]))(
+                variables["params"])
+        out[form if isinstance(form, str) else "whole"] = (
+            logits, values, kept["routing"]["experts"][-1], grads)
+    return out
+
+
+SHAPES = [(layers, passes) for layers in (1, 2, 3) for passes in (1, 2)]
+
+
+@pytest.mark.parametrize("layers,passes", SHAPES)
+def test_the_last_layer_s_clean_stream_was_owed_nothing(layers, passes):
+    """The logits, the values and the gradient of `vtrace_loss` with respect
+    to every parameter are the whole form's, to float32's rounding: the rows
+    the last layer leaves out had a zero cotangent. (Products of other
+    shapes sum in another order: the logits differ by 1.5e-7 at most, a
+    gradient by 1.4e-6 of its largest entry over the six shapes.)"""
+    got, want = (both_forms(layers, passes)[form]
+                 for form in ("block_causal", "whole"))
+    np.testing.assert_allclose(got[0], want[0], atol=1e-6)
+    np.testing.assert_allclose(got[1], want[1], atol=1e-6)
+    assert jax.tree.structure(got[3]) == jax.tree.structure(want[3])
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got[3])[0],
+                            jax.tree.leaves(want[3])):
+        scale = max(float(jnp.max(jnp.abs(w))), 1.0)
+        assert float(jnp.max(jnp.abs(g - w))) <= 5e-6 * scale, (
+            jax.tree_util.keystr(path))
+    # The last layer's queries, output projection, router and experts do
+    # learn: from the noisy rows.
+    last = got[3][f"layer_{layers - 1}"]
+    for name in ("wq", "wo", "router", "w_up", "wk", "wv"):
+        assert float(jnp.max(jnp.abs(last[name]))) > 0, name
+
+
+@pytest.mark.parametrize("layers,passes", SHAPES)
+def test_the_routing_collection_keeps_its_shape_and_says_no_choice(
+        layers, passes):
+    """[layers, N, (S + 1) T, k]: the last layer's clean rows state -1, as
+    the commit pass's last layer does in the rollout's trace; every other
+    entry is the whole form's."""
+    got, want = (np.asarray(both_forms(layers, passes)[form][2])
+                 for form in ("block_causal", "whole"))
+    assert got.shape == want.shape == (
+        layers, N, (passes + 1) * T, NET["num_experts_per_tok"])
+    assert (got[-1, :, :T] == -1).all() and (want >= 0).all()
+    np.testing.assert_array_equal(got[:-1], want[:-1])
+    np.testing.assert_array_equal(got[-1, :, T:], want[-1, :, T:])
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_the_noisy_queries_alone_are_the_square_form_s_rows(passes):
+    """`block_stream_attention` with the noisy streams' queries against
+    every stream's keys: rows [T:] of the square form, plain against plain,
+    with two episodes in a row."""
+    streams, heads, groups, d = passes + 1, 4, 2, 16
+    keys = jax.random.split(jax.random.PRNGKey(passes), 3)
+    q = jax.random.normal(keys[0], (N, heads, streams * T, d))
+    k, v = (jax.random.normal(key, (N, groups, streams * T, d))
+            for key in keys[1:])
+    episode = jnp.tile(
+        jnp.ones((N, T), jnp.int32).at[1, T // 2:].set(2), (1, streams))
+    square = transformer.block_stream_attention(
+        q, k, v, episode, d ** -0.5, 4, streams)
+    noisy = transformer.block_stream_attention(
+        q[:, :, T:], k, v, episode, d ** -0.5, 4, streams)
+    assert noisy.shape == (N, heads, passes * T, d)
+    np.testing.assert_allclose(noisy, square[:, :, T:], atol=1e-6)
+
+
+@pytest.mark.parametrize("queries,tiles", [(3, (38, 144)), (2, (28, 96))])
+def test_the_fused_mask_s_tiles_are_known_from_the_static_shape(
+        queries, tiles):
+    """At the cell's learner shape (T 2,048, blocks of 4, a clean and two
+    noisy streams, tiles of 512): the square mask visits 38 of 144 tiles,
+    the noisy queries' rectangle 28 of 96 (the clean queries' 10, a causal
+    pass's over T, go); `block_stream_tiles` says so, and the splash
+    kernel's own reading of the mask it is given visits the same, forward
+    and backward."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks, splash_attention_mask_info as info)
+    t = transformer.CAUSAL_TILE
+    assert transformer.block_stream_tiles(2048, 4, 3, queries) == tiles
+    assert transformer.causal_window_tiles(2048, 0)[0] == 38 - 28
+    mask = transformer._block_stream_mask(2048, 4, 3, queries)
+    assert mask.shape == (queries * 2048, 3 * 2048)
+    for process in (info.process_mask, info.process_mask_dkv):
+        held, computed = process(masks.MultiHeadMask([mask]), (t, t))
+        assert computed is not None  # computed in the kernel, not loaded
+        assert int((np.asarray(held.block_mask) > 0).sum()) == tiles[0]
+    # The rectangle is the square mask's rows [T:], read a block at a time
+    # (whole blocks: what the kernel's set-up asks for) or a position.
+    ids = np.arange(3 * 24)
+    small = transformer._block_stream_mask(24, 4, 3, 2)
+    want = transformer.block_stream_allowed(24, 4)(
+        ids[24:, None], ids[None, :])
+    for rows, columns in ((slice(None), slice(None)),
+                          (slice(4, 12), slice(8, 28)),
+                          (slice(3, 13), slice(5, 31))):
+        np.testing.assert_array_equal(
+            small[rows, columns], want[rows, columns])
+
+
 @pytest.mark.parametrize("passes", [1, 2])
 def test_the_2t_form_is_the_block_by_block_definition(passes):
     model, variables, net = build(denoise_steps=passes)
@@ -414,7 +602,8 @@ def test_the_trainer_runs_from_the_tuned_example(trained):
     assert np.isfinite(stats["total_loss"])
     assert stats["block_len"] == 4 and stats["denoise_steps"] == 2
     assert stats["decode_passes_per_token"] == 0.75
-    assert stats["learner_rows_per_token"] == 3
+    # Two layers: the last one's clean stream stops at its keys and values.
+    assert stats["learner_rows_per_token"] == 3 - 1 / 2
     minibatches = envs * T_ // cfg["sgd_minibatch_size"]
     assert stats["given_rows"] * minibatches == envs * T_ // (episode + 1)
     assert 0 < stats["unmask_top_prob_mean"] <= 1
@@ -448,10 +637,7 @@ def minibatch(policy, seed=0):
     net = dict(NET, **cfg["model"]["custom_model_config"])
     rng = np.random.default_rng(seed)
     tokens = rng.integers(0, net["vocab_size"] - 1, (frags, T_))
-    steps = np.stack([np.concatenate([
-        rng.permutation(np.repeat(np.arange(net["denoise_steps"]),
-                                  L // net["denoise_steps"]))
-        for _ in range(T_ // L)]) for _ in range(frags)])
+    steps = unmask_steps(rng, frags, T_, L, net["denoise_steps"])
     episode = cfg["env_config"]["episode_len"] + 1
     steps[:, ::episode] = -1
     dones = np.zeros((frags, T_), np.float32)
@@ -578,7 +764,8 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
     got = model.static_counters(64, 2048, "tpu", 8192)
     assert got["block_len"] == 4 and got["denoise_steps"] == 2
     assert got["decode_passes_per_token"] == 0.75
-    assert got["learner_rows_per_token"] == 3
+    # Five layers of three streams, the last one's clean stream left out.
+    assert got["learner_rows_per_token"] == 3 - 1 / 5 == 2.8
     # 64 blocks of 4 rows, 8 of 128 experts each: 16 rows a held expert.
     assert got["decode_rows_per_expert"] == 16.0
     assert got["decode_experts_batched"] == 1.0

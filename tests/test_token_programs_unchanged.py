@@ -44,9 +44,10 @@ LOWERED = {
     # The seventh, the one program whose rollout scan is over blocks: brought
     # by PR 48, recorded anew by PR 49, which was meant to alter it (the
     # block's keys and values reach the attention as operands, and only the
-    # commit pass writes) and measured the cell.
+    # commit pass writes) and measured the cell, and by PR 51, likewise (the
+    # learner's last layer makes its clean stream's keys and values alone).
     "sdar_block_token_anakin_2k":
-        "8f5ce9cf7d9f1d65198d64d8bf0c70e7a458231f6dce86038a01b5c1c2100345",
+        "c0916c3b76722bd443457859164d9128980b84409be2d9b3527f8b935c8c6133",
 }
 
 
