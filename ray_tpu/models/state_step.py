@@ -1,11 +1,13 @@
-"""A decode step of Kimi Delta Attention's matrix states, in place.
+"""A decode step of the gated delta rule's matrix states, in place.
 
     S' = Diag(exp g) S;  u = beta (v - S'^T k);  o = S'^T q + (k . q) u
     S = S' + k u^T
 
 for a row's state S [d_k, d_v] a head, float32 (`transformer.kda_step` is
 the plain form, and has the algebra), the states of the rows that `reset`
-zeroed first. Two forms:
+zeroed first. The decay in the shape the model publishes: a channel of the
+key's, g [.., heads, d_k] (Kimi Delta Attention), or ONE a head, g [..,
+heads] (Gated DeltaNet: Diag(exp g) is then exp(g) I). Two forms:
 
 * the plain form after a select, as XLA compiles it: a pass over every
   state for the two sums, then one that reads and writes them all.
@@ -23,6 +25,15 @@ Those that multiply the ROWS of a tile (exp g, k, q) are turned inside the
 kernel, all the heads of a grid step by one transpose of a [128, 128] tile
 whose column c is then one head's vector down the sublanes. No [.., d_k, 1]
 array exists in HBM (it would pad 128-fold, to the size of the states).
+A decay of one number a head is not turned and not broadcast to a head's
+channels anywhere: a grid step's decays arrive as one row [1, heads], as
+its betas do, the tile that is turned holds k and q alone, and a head's
+state is multiplied by its one number. On a v5e, three layers' states [32,
+32, 128, 128] carried by a scan, ms a step for the three (my chip run, PR
+52; PERF.md section 5): XLA's passes 0.873 under either decay; this kernel
+under a decay a channel 0.731, under one a head 0.697, and 0.730 where the
+one is first widened to a head's channels and handed over as a decay a
+channel.
 
 `in_place(kernel, plain)` is the kernel with the plain form's derivative: a
 `pallas_call` with aliased operands has no JVP, and a decode step is
@@ -83,13 +94,20 @@ def _body(reset_ref, beta_ref, q_ref, k_ref, g_ref, v_ref, s_ref, o_ref,
     """One grid step (b, j): block j of row b's heads."""
     heads, d_k, d_v = s_ref.shape
     q, k, beta = q_ref[...], k_ref[...], beta_ref[...]
-    turned = _turned(jnp.concatenate([jnp.exp(g_ref[...]), k, q], axis=0))
+    decays = jnp.exp(g_ref[...])
+    # A decay a channel goes down the sublanes with k and q; one a head
+    # stays the row [1, heads] it came as.
+    by_channel = decays.shape == k.shape
+    down = ([decays] if by_channel else []) + [k, q]
+    turned = _turned(jnp.concatenate(down, axis=0))
     # [heads, 1]
     kq = jnp.sum(k * q, axis=-1, keepdims=True)
     dropped = jnp.full((d_k, d_v), reset_ref[pl.program_id(0)]) > 0
     for h in range(heads):
-        decay, k_down, q_down = (
-            turned[:d_k, n * heads + h:n * heads + h + 1] for n in range(3))
+        *decay, k_down, q_down = (
+            turned[:d_k, n * heads + h:n * heads + h + 1]
+            for n in range(len(down)))
+        decay = decay[0] if by_channel else decays[:, h:h + 1]
         # Selected, not multiplied: what a row that begins held may be
         # anything. (Hidden behind the copies, as the rest is.)
         decayed = jnp.where(dropped, 0.0, decay * s_ref[h])
@@ -103,9 +121,9 @@ def _body(reset_ref, beta_ref, q_ref, k_ref, g_ref, v_ref, s_ref, o_ref,
 def kda_kernel(S, q, k, v, g, beta, reset, *, heads=None, interpret=False):
     """`transformer.kda_step` of the states S [B, H, d_k, d_v] zeroed where
     `reset` [B] > 0, as the kernel: (o [B, H, d_v] float32, the states after
-    the position, in S's buffer). `heads` heads a grid step
-    (`heads_a_step`); `interpret` runs it by the Pallas interpreter (a test
-    on a CPU)."""
+    the position, in S's buffer); g [B, H, d_k], or [B, H] for one decay a
+    head. `heads` heads a grid step (`heads_a_step`); `interpret` runs it
+    by the Pallas interpreter (a test on a CPU)."""
     B, H, d_k, d_v = S.shape
     heads = heads or heads_a_step(H)
     if H % heads or 3 * heads > LANES:
@@ -113,6 +131,11 @@ def kda_kernel(S, q, k, v, g, beta, reset, *, heads=None, interpret=False):
             f"{H} heads are not whole steps of {heads}, or three vectors a "
             f"head are more than one tile's {LANES} columns")
     f32 = jnp.float32
+    by_channel = g.ndim == 3
+
+    def a_row():
+        """A step's own numbers a head as one row, [1, heads]."""
+        return pl.BlockSpec((None, None, 1, heads), lambda b, j: (b, j, 0, 0))
 
     def vectors(d):
         return pl.BlockSpec((None, heads, d), lambda b, j: (b, j, 0))
@@ -126,9 +149,9 @@ def kda_kernel(S, q, k, v, g, beta, reset, *, heads=None, interpret=False):
         in_specs=[
             # A row's flag is read as a scalar.
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            # A step's own betas as one row, [1, heads].
-            pl.BlockSpec((None, None, 1, heads), lambda b, j: (b, j, 0, 0)),
-            vectors(d_k), vectors(d_k), vectors(d_k), vectors(d_v),
+            a_row(),
+            vectors(d_k), vectors(d_k),
+            vectors(d_k) if by_channel else a_row(), vectors(d_v),
             states()],
         out_specs=[vectors(d_v), states()],
         out_shape=[jax.ShapeDtypeStruct((B, H, d_v), f32),
@@ -140,15 +163,19 @@ def kda_kernel(S, q, k, v, g, beta, reset, *, heads=None, interpret=False):
         # copies, and of 1.6 s a call that the states' row lost, the cell
         # kept 0.5 (PERF.md section 5, PR 46).
         cost_estimate=pl.CostEstimate(
-            flops=8 * S.size, transcendentals=B * H * d_k,
-            bytes_accessed=4 * (2 * S.size + B * H * (3 * d_k + 2 * d_v))),
+            flops=8 * S.size, transcendentals=g.size,
+            bytes_accessed=4 * (2 * S.size + g.size
+                                + B * H * (2 * d_k + 2 * d_v))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         name="kda_state_step",
         interpret=interpret,
     )(reset.astype(jnp.int32),
       beta.astype(f32).reshape(B, H // heads, 1, heads),
-      q.astype(f32), k.astype(f32), g.astype(f32), v.astype(f32), S)
+      q.astype(f32), k.astype(f32),
+      g.astype(f32) if by_channel else g.astype(f32).reshape(
+          B, H // heads, 1, heads),
+      v.astype(f32), S)
 
 
 def in_place(kernel, plain):

@@ -1,4 +1,4 @@
-"""Transformer token policies in flax: one decoder, seven descriptions.
+"""Transformer token policies in flax: one decoder, eight descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
@@ -93,6 +93,31 @@ the key; `kda_heads` heads, d_k = d_v = `kda_head_dim`, P = heads x d:
   episode that begins inside a fragment cuts the scan: pairs of different
   episodes are 0 and the carried state is dropped at the boundary.
 
+Gated DeltaNet ("gdn"; qwen3_next, `model_type: qwen3_next`: 36 of its 48
+layers), the same delta rule under ONE decay a value head; `gdn_key_heads`
+query/key heads of `gdn_key_dim`, `gdn_value_heads` value heads of
+`gdn_value_dim`, value head j reading key head j // (value heads / key
+heads); K and V the two widths heads x dim:
+      [q~ | k~ | v~ | z] = n W_qkvz   [H, 2 K + 2 V], no bias;  [b | a] = n
+                                    W_ba [H, 2 x value heads]
+      [q' | k' | v'] = silu(conv(.))  ONE depthwise causal convolution over
+                                    q, k and v together, `gdn_taps` (4) taps
+      q, k normalised a head as KDA's, q times d_k^-1/2
+      g = -exp(A_log) * softplus(a + dt_bias)   ONE number a value head,
+                                    float32;  beta = sigmoid(b)
+      S_t = (I - beta_t k_t k_t^T) exp(g_t) S_{t-1} + beta_t k_t v_t^T
+      o_t = S_t^T q_t               S [d_k, d_v] a VALUE head
+      out = (RMSNorm_head(o) * w * silu(z)) W_out    one plain weight [d_v]
+  Its state is that matrix in float32, its key of the policy state "gdn",
+  and the convolution's last taps - 1 inputs [B, taps - 1, 2 K + V] under
+  "conv". It is KDA's recurrence with exp(g) constant over a head's
+  channels, and runs KDA's code in both forms, the decay in the shape the
+  model publishes: `kda_chunked` makes a chunk's pairs from a key head's
+  plain products times ONE [C, C] matrix of decays a value head
+  (`_head_decay_chunk`; no sub-blocks, no exponential a channel) and shares
+  the solve, the scan and the backward pass; `kda_decode_step` hands the
+  kernel its decays as a row a grid step, a number a head.
+
 Mamba-2 ("mamba2"; nemotron_h, `model_type: nemotron_h`: the state-space
 duality form, arXiv:2405.21060), a state-space layer with ONE decay a head;
 `ssm_heads` heads of `ssm_head_dim` channels (I = heads x channels), B and C
@@ -140,7 +165,11 @@ Attention, one of:
       (OLMoE): q_norm, k_norm, RMSNorm over the whole projection before
       the split into heads; `qk_norm: "head"` (LFM2): RMSNorm over EACH
       head's own `head_dim` values, one weight [head_dim] for all heads,
-      before RoPE;
+      before RoPE; `partial_rotary_factor` (qwen3_next: 0.25): RoPE over
+      the leading quarter of a head's values with that quarter's
+      frequencies, the rest as they are; `attention_gate` (qwen3_next):
+      W_q makes, a head, its query and as many values again, and the
+      head's output is multiplied by their sigmoid ahead of W_o;
       causal softmax(q k^T / sqrt(head_dim)) v; W_o. A KIND A LAYER
       (`window_layout`, `rope_layout`; OLMoE: every layer full and rotary;
       SmallThinker: a period of four, the first full and without
@@ -195,7 +224,9 @@ expert, absent experts' pairs last, the landed ones' rows (up to a static
 size chosen from the landed count, `dispatch_rows`) multiplied group by
 group (`grouped_product`: the library's Pallas grouped-matmul kernels on a
 TPU at shapes that have tiles, `jax.lax.ragged_dot` elsewhere) and added to
-their rows of the sum; the learner's minibatch and a prefill.
+their rows of the sum; the learner's minibatch and a prefill. The shared
+expert's output may stand behind a gate of its own (`shared_expert_gate`,
+qwen3_next: times sigmoid(n . w), w [hidden]).
 Batched: every row through every held expert in products batched over the
 experts, each term weighted w_e or exactly 0 before the sum; a decode step,
 whose groups of a few rows would each cost the grouped product an MXU tile
@@ -234,6 +265,12 @@ module follows the policy and does not move it. Its loss goes to the
 "losses" collection (the caller's objective adds what a model puts there)
 and is computed only where the caller keeps that collection.
 
+Norms: x / rms(x) * w, w 1 at initialisation; or zero-centred
+(`zero_centred_norms`, qwen3_next): x / rms(x) * (1 + w), w 0 at
+initialisation, for every norm of the hidden vector and of a head's queries
+and keys (an operator's own output norm keeps a plain weight). The
+parameter is w; a layer's tensors hand out 1 + w.
+
 Departures from the published models: the value head (none has one); no
 auxiliary router loss (the RL objective has no place for it; the
 `expert_load_*` counters show what follows); parameters, router, final norm
@@ -249,7 +286,10 @@ is left out), its attention position-free, its decay's and time step's
 draws and the convolution's bias assumed; sdar_moe's QK-norm a head
 (the Qwen3 body's; no key says it), its block of 4 and 2 passes, its
 sampler's rule and the MASK id are assumed (the configuration's file lists
-them), and its pre-training noise schedule enters nowhere. The OLMoE and
+them), and its pre-training noise schedule enters nowhere; qwen3_next's
+next-token module is not built (no key of the config names it), its
+projections' columns lie [q | k | v | z] and [b | a] where the source lays
+them a key head at a time, and its decay's draw is assumed. The OLMoE and
 glm4_moe_lite descriptions have as many key/value heads as query heads and
 refuse another count (their references have no grouped form; latent
 attention has no key/value heads to group).
@@ -330,8 +370,9 @@ a kind and only the kinds the model has, {"conv": a convolution layer's
 last gated inputs, a KDA layer's convolutions' last inputs (none for an
 attention layer)} and {"kda": a KDA layer's float32 matrices} beside them:
 {"ssm": a Mamba-2 layer's float32 matrices, its convolution's last inputs
-under "conv"}: every leaf of "kv" has a positions axis, no leaf of "conv",
-"kda" or "ssm" has (`STATE_KINDS`). `JaxPolicy` and the Anakin optimizer
+under "conv"}, {"gdn": a Gated DeltaNet layer's, likewise}: every leaf of
+"kv" has a positions axis, no leaf of "conv", "kda", "ssm" or "gdn" has
+(`STATE_KINDS`). `JaxPolicy` and the Anakin optimizer
 carry the whole as one pytree.
 """
 
@@ -625,17 +666,71 @@ SDAR_MOE_FIXED = {
 # width (`mlp_only_layers` is empty and every layer sparse) and the layers a
 # window would reach (`use_sliding_window` false).
 SDAR_MOE_UNREAD = ("intermediate_size", "max_window_layers")
+QWEN3_NEXT_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "num_hidden_layers": "num_layers",
+    "partial_rotary_factor": "partial_rotary_factor",
+    "linear_num_key_heads": "gdn_key_heads",
+    "linear_num_value_heads": "gdn_value_heads",
+    "linear_key_head_dim": "gdn_key_dim",
+    "linear_value_head_dim": "gdn_value_dim",
+    "linear_conv_kernel_dim": "gdn_taps",
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "shared_expert_intermediate_size": "shared_width",
+    "norm_topk_prob": "norm_topk_prob",
+    "max_position_embeddings": "context_len",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_eps",
+    # The deployment's: the share of the experts this chip holds, and the
+    # positions in a chunk of the learner's scan.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+    "gdn_chunk": "gdn_chunk",
+}
+# What Qwen3-Next-80B-A3B-Instruct's published `config.json` says, for the
+# keys a `custom_model_config` leaves out.
+QWEN3_NEXT_PUBLISHED = {
+    "vocab_size": 151936, "hidden_size": 2048, "num_attention_heads": 16,
+    "num_key_value_heads": 2, "head_dim": 256, "num_hidden_layers": 48,
+    "full_attention_interval": 4, "partial_rotary_factor": 0.25,
+    "linear_num_key_heads": 16, "linear_num_value_heads": 32,
+    "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+    "linear_conv_kernel_dim": 4, "num_experts": 512,
+    "num_experts_per_tok": 10, "moe_intermediate_size": 512,
+    "shared_expert_intermediate_size": 512, "norm_topk_prob": True,
+    "max_position_embeddings": 262144, "rope_theta": 10000000,
+    "rms_norm_eps": 1e-6,
+}
+QWEN3_NEXT_FIXED = {
+    "decoder_sparse_step": 1, "hidden_act": "silu", "mlp_only_layers": [],
+    "rope_scaling": None, "use_sliding_window": False,
+    "attention_bias": False, "tie_word_embeddings": False,
+    "model_type": "qwen3_next",
+}
+# Published keys that no part of the decoder reads: the dense feed-forward's
+# width (`mlp_only_layers` is empty and every layer sparse).
+QWEN3_NEXT_UNREAD = ("intermediate_size",)
 # The MASK id's logit: its probability is exactly 0 in float32, as minus
 # infinity's is, and 0 x it is 0 where the entropy multiplies the two.
 MASK_LOGIT = -1e30
 # The operators a layer of `layer_types` may name; "experts" (a model of
 # `one_function_layers` alone) names a layer that is its feed-forward and
 # no operator.
-LAYER_TYPES = ("conv", "full_attention", "kda", "mamba2", "experts")
+LAYER_TYPES = ("conv", "full_attention", "kda", "mamba2", "experts", "gdn")
 # The kinds of state a layer may keep between positions, each a key of the
 # policy state beside "pos": caches with a positions axis; a convolution's
-# last inputs; a KDA layer's matrices; a Mamba-2 layer's.
-STATE_KINDS = ("kv", "conv", "kda", "ssm")
+# last inputs; a KDA layer's matrices; a Mamba-2 layer's; a Gated DeltaNet
+# layer's.
+STATE_KINDS = ("kv", "conv", "kda", "ssm", "gdn")
+# The kinds that are a float32 matrix a head, by the layer type that keeps
+# one (the layer's convolution inputs stand under "conv" beside it).
+MATRIX_STATES = {"kda": "kda", "ssm": "mamba2", "gdn": "gdn"}
 # Published keys that must say what the decoder does (a value it has no
 # part for is refused, not ignored).
 GLM4_MOE_LITE_FIXED = {
@@ -848,10 +943,15 @@ def grouped_fused(S: int, groups: int, heads: int, d: int) -> bool:
     of `cached_attention`: a function of the static shape alone. Whole
     blocks and at least two of them; a position's cached heads whole lane
     tiles together, each at least half a tile (the widths the kernel was
-    compiled and measured at: 8 x 64 and 4 x 128); whole groups of query
-    heads. No cache's length is left out: a ring of 4,096 held whole, the
-    shape nearest to losing, read 0.190 ms a step against XLA's 0.205
-    (`cached_attention` has the sweep)."""
+    compiled and measured at: 8 x 64, 4 x 128 and 2 x 256); whole groups of
+    query heads. No cache's length is left out: a ring of 4,096 held whole,
+    the shape nearest to losing, read 0.190 ms a step against XLA's 0.205
+    (`cached_attention` has the sweep). 2 x 256 (qwen3_next: 16 query heads
+    over 2 cached heads of 256, 32 rows, 4,096 positions, one layer's
+    caches in a scan that writes a row a step; my chip run, PR 52): XLA's
+    two products 0.387 ms a step whatever the cache holds; the kernel 0.212
+    while it fills (the mean of positions 2,016-2,079) and 0.378 held
+    whole; 8 or 4 rows a grid step read the same."""
     block = decode_attention.BLOCK
     return (S % block == 0 and S >= 2 * block and heads % groups == 0
             and (groups * d) % 128 == 0 and d % 64 == 0)
@@ -977,7 +1077,11 @@ def causal_fused(T: int, d_qk: int, d_v: int) -> bool:
     and at least two of them (one tile is the plain form with a kernel's
     set-up on top), and widths the MXU takes whole, or half of one (64,
     lfm2_moe's heads: the one narrower width the kernel and its backward
-    were compiled for a v5e and compared with the reference at)."""
+    were compiled for a v5e and compared with the reference at). Heads of
+    256 for queries, keys AND values (qwen3_next's 16 over 2; the latent
+    layouts' 256 stand over values of 128 or 256 in as many heads): value
+    and gradient at [2, 16 / 2, 4096, 256] 8.86 ms fused against 32.2 plain
+    (my chip run, PR 52)."""
     return (T % CAUSAL_TILE == 0 and T >= 2 * CAUSAL_TILE and all(
         d % 128 == 0 or d == 64 for d in (d_qk, d_v)))
 
@@ -1337,6 +1441,52 @@ def _unit_lower_solve_bwd(block, kept, dX):
 unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
 
 
+def _head_decay_chunk(q, k, v, g, beta, episode, before, dtype):
+    """`_kda_chunk` where the decay is ONE a head (Gated DeltaNet): g [B,
+    heads, C], the float32 log decay of a VALUE head, v [B, heads, C, d_v],
+    beta [B, heads, C, 1], and q, k [B, key heads, C, d_k], a key head
+    serving `heads // key heads` consecutive value heads. The same seven
+    terms. exp(G_i - G_j) is then one number a pair and head, so the pairs'
+    products are a key head's plain q k^T and k k^T ([C, C], matrix
+    products, made once for the value heads that share the key) times a
+    [C, C] matrix of decays a value head: no [sub, sub, d] products, no
+    sub-blocks, no exp a channel. The pair's exponent is a masked cumulative
+    sum of g along i (`_ssd_chunk`'s rule: a sum of log decays, <= 0, never
+    a difference of two cumulative sums)."""
+    f32 = jnp.float32
+    C = g.shape[-1]
+    shared = v.shape[1] // k.shape[1]
+    at = jnp.arange(C)
+    earlier = at[:, None] > at[None, :]  # j < i
+    # seg[i, j]: the sum of g over the positions after j up to and with i.
+    seg = jnp.cumsum(jnp.where(earlier, g[..., :, None], 0.0), axis=-2)
+    cum = jnp.cumsum(g, axis=-1)
+    same = (episode[:, :, None] == episode[:, None, :])[:, None]
+    decay = jnp.exp(jnp.where(same & (at[:, None] >= at[None, :]), seg,
+                              -jnp.inf))
+    kd = k.astype(dtype)
+    kk = jnp.einsum("bhic,bhjc->bhij", kd, kd, preferred_element_type=f32)
+    qk = jnp.einsum("bhic,bhjc->bhij", q.astype(dtype), kd,
+                    preferred_element_type=f32)
+    if shared > 1:
+        q, k, kk, qk = (jnp.repeat(a, shared, axis=1) for a in (q, k, kk, qk))
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    kk = jnp.where(earlier, kk * decay, 0.0)
+    qk = qk * decay
+    # Against the states at the chunk's two ends.
+    since = jnp.exp(cum)[..., None]
+    until = jnp.exp(seg[..., -1, :])[..., None]
+    carried = (episode == before[:, None])[:, None, :, None]
+    lasting = (episode == episode[:, -1:])[:, None, :, None]
+    q_in = jnp.where(carried, q * since, 0.0).astype(dtype)
+    k_in = jnp.where(carried, k * since, 0.0)
+    k_out = jnp.where(lasting, k * until, 0.0).astype(dtype)
+    keep = jnp.where((episode[:, -1] == before)[:, None, None],
+                     jnp.exp(cum[..., -1:]), 0.0)
+    return (beta * kk, beta * v, beta * k_in, q_in, qk.astype(dtype), k_out,
+            keep)
+
+
 def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
     """One chunk of `kda_chunked` up to the state it begins with, for every
     row and head at once: q, k, g [B, heads, C, d_k] (g <= 0 the float32
@@ -1371,7 +1521,12 @@ def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
     pair inside one sub-block from the difference of the sub-block's own
     cumulative sums, channel by channel, elementwise ([sub, sub, d] a
     sub-block: why the sub-blocks are small). Matrix operands are cast to
-    `dtype`, sums are float32."""
+    `dtype`, sums are float32.
+
+    A decay of ONE number a head (g [B, heads, C]: Gated DeltaNet's) makes
+    the same terms by `_head_decay_chunk`, without the sub-blocks."""
+    if g.ndim == q.ndim - 1:
+        return _head_decay_chunk(q, k, v, g, beta, episode, before, dtype)
     f32 = jnp.float32
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
     lead, (C, d) = g.shape[:-2], g.shape[-2:]
@@ -1441,13 +1596,29 @@ def _kda_chunk(q, k, v, g, beta, episode, before, sub, dtype):
             keep)
 
 
-def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
-    """Kimi Delta Attention over a fragment from an empty state, in chunks:
+def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32,
+                scope="policy/kda_state"):
+    """The gated delta rule over a fragment from an empty state, in chunks:
     q, k, g [B, T, heads, d_k], v [B, T, heads, d_v], beta [B, T, heads]
     (q and k normalised, q scaled; g <= 0 the LOG decay a channel of the
     key, float32, as beta is), `episode` [B, T] the number of the episode
-    a step belongs to (it never falls along a row). A head's state S
-    [d_k, d_v] is 0 where an episode begins and
+    a step belongs to (it never falls along a row). The decay in the shape
+    the model publishes, and the keys likewise: a channel's (Kimi Delta
+    Attention, as above), or ONE a head, g [B, T, heads] (Gated DeltaNet),
+    and then q and k may be of fewer heads, [B, T, key heads, d_k], each
+    serving `heads // key heads` consecutive value heads; Diag(exp(g_t)) is
+    then exp(g_t) I. Both go through this one function: the chunk's terms
+    differ (`_kda_chunk`: the per-channel decays' sub-blocks, or
+    `_head_decay_chunk`'s one [C, C] matrix of decays a head), the solve,
+    the scan and the backward pass are the same code. What the scalar form
+    saves, on a v5e, value and gradient of one layer's scan at [2, 4096,
+    32, 128], bf16 operands, chunks of 64 (my chip run, PR 52; PERF.md
+    section 5): a decay a channel 58.9 ms; one decay a head widened to a
+    head's channels and fed to that path 58.6 (so it is never widened);
+    one decay a head as it is 30.7 with 32 key heads and 31.2 with 16
+    serving two value heads each (sharing a key's products saves nothing:
+    the pairs' decays are a value head's); chunks of 32 30.9, of 128 40.0.
+    A head's state S [d_k, d_v] is 0 where an episode begins and
 
         S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
         o_t = S_t^T q_t
@@ -1482,7 +1653,7 @@ def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
     nothing (g = 0, beta = 0). Matrix operands are cast to `dtype`, sums,
     decays and S are float32."""
     f32 = jnp.float32
-    B, T, heads, d_k = q.shape
+    (B, T, _, d_k), heads = q.shape, v.shape[2]
     sub = min(KDA_SUB_BLOCK, chunk)
     assert chunk % sub == 0, (chunk, sub)
     pad = -T % chunk
@@ -1502,7 +1673,7 @@ def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
     # The episode ahead of a chunk; ahead of the first the state is 0
     # whatever it is called.
     before = jnp.concatenate([episode[:1, :, 0], episode[:-1, :, -1]])
-    with jax.named_scope("policy/kda_state"):
+    with jax.named_scope(scope):
         L, *rhs, q_in, qk, k_out, keep = jax.lax.map(
             jax.checkpoint(lambda xs: _kda_chunk(*xs, sub=sub, dtype=dtype)),
             (q, k, v, g, beta, episode, before))
@@ -1534,8 +1705,10 @@ def kda_chunked(q, k, v, g, beta, episode, chunk, dtype=jnp.float32):
 def kda_step(S, q, k, v, g, beta):
     """The same recurrence, one position: S [B, heads, d_k, d_v] float32
     against q, k, g [B, heads, d_k], v [B, heads, d_v], beta [B, heads];
-    (o [B, heads, d_v], the state after the position). Decay by rows of
-    d_k, one outer product subtracted and added, q read:
+    (o [B, heads, d_v], the state after the position). g [B, heads] is ONE
+    decay a head (Gated DeltaNet), exp(g) times the whole of a head's
+    state. Decay by rows of d_k, one outer product subtracted and added, q
+    read:
 
         S' = Diag(exp(g)) S;  u = beta (v - S'^T k);  S = S' + k u^T
         o = S^T q = S'^T q + (k . q) u
@@ -1545,7 +1718,7 @@ def kda_step(S, q, k, v, g, beta):
     of the new state is what it reads of the decayed one, plus u times a
     scalar), then one read and one write make S."""
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-    decayed = jnp.exp(g)[..., None] * S
+    decayed = jnp.exp(g)[(...,) + (None,) * (S.ndim - g.ndim)] * S
     from_k = jnp.sum(decayed * k[..., None], axis=-2)
     from_q = jnp.sum(decayed * q[..., None], axis=-2)
     u = beta[..., None] * (v - from_k)
@@ -1568,7 +1741,13 @@ def kda_decode_step(S, q, k, v, g, beta, reset):
     first. Where the states are whole tiles (`state_step.whole_tiles`, of the
     static shape) a program lowered for a TPU takes the kernel, S read once
     and written once in place, with the plain form's derivative; everywhere
-    else the plain form, XLA's three passes."""
+    else the plain form, XLA's three passes. One decay a head, g [B, heads]
+    (Gated DeltaNet's), takes the same kernel and the same plain form. On a
+    v5e, three layers' states [32, 32, 128, 128] carried by a scan, ms a
+    step for the three (my chip run, PR 52): XLA's passes 0.873 under either
+    decay; the kernel under a decay a channel 0.731, under one a head 0.697
+    (578 GB/s of the states' 403 MB), and 0.730 where the one is first
+    widened to a head's channels: it is handed over as it is."""
     operands = (S, q, k, v, g, beta, reset)
     if state_step.whole_tiles(*S.shape[1:]):
         return jax.lax.platform_dependent(
@@ -1798,7 +1977,13 @@ def grouped_tiles(R: int, K: int, N: int, dtype=jnp.bfloat16):
     operands of two bytes, whole tiles of rows, and widths of at least 512
     in whole half lane tiles, which is what the kernels were measured at
     (no shape of the six cells lost to `ragged_dot`, so the rule leaves
-    none of them out)."""
+    none of them out). The narrowest experts so far, qwen3_next's 2,048 x
+    512 and 512 x 2,048 at 6,400 gathered rows in 32 groups, take (256,
+    2048, 512), (256, 512, 1024), (256, 1024, 512) by the same rule: one
+    layer's recomputed forward and backward through `dropless_experts` at
+    the cell's minibatch 11.73 ms against `ragged_dot`'s 12.67; rows of
+    128 11.53, columns up to 512 11.95 (256 rows) and 11.78 (128): within
+    2 % of the rule's, which stays one rule (my chip run, PR 52)."""
     if jnp.dtype(dtype).itemsize != 2 or any(
             d < 512 or d % 64 for d in (K, N)):
         return None
@@ -2114,6 +2299,13 @@ def _decay_inits() -> dict:
     return {"a_log": a_log, "dt_bias": dt_bias}
 
 
+def _gdn_a_log(key, shape, dtype=jnp.float32):
+    """A Gated DeltaNet layer's decay at initialisation, the family's draw:
+    A = exp(a_log) uniform in (0, 16] a value head (never 0: its log is
+    taken), beside a `dt_bias` of 1."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
+
+
 def _conv_bias_init(key, shape, dtype=jnp.float32):
     """A depthwise convolution's bias at initialisation: uniform within
     taps^-1/2 of 0 at the family's four taps (the source library's draw
@@ -2128,7 +2320,11 @@ class DecoderLayerParams(nn.Module):
     a channel's fan-in its taps) / bias (a constant of the model, small
     and seeded, in the "constants" collection: no gradient, no optimizer
     state) / a_log, dt_bias (a KDA or Mamba-2 layer's decay:
-    `_decay_inits`) / conv_bias (`_conv_bias_init`)."""
+    `_decay_inits`) / gdn_a_log (a Gated DeltaNet layer's: `_gdn_a_log`) /
+    conv_bias (`_conv_bias_init`) / centred (a zero-centred norm's weight:
+    the parameter w is 0 at initialisation and the tensor handed out is 1 +
+    w, which every `rms_norm` then multiplies by as it does a plain
+    one)."""
 
     shapes: tuple
 
@@ -2137,10 +2333,14 @@ class DecoderLayerParams(nn.Module):
                  "dense": nn.initializers.lecun_normal(),
                  "experts": nn.initializers.lecun_normal(batch_axis=(0,)),
                  "taps": nn.initializers.lecun_normal(in_axis=1, out_axis=0),
-                 "conv_bias": _conv_bias_init, **_decay_inits()}
+                 "conv_bias": _conv_bias_init, "gdn_a_log": _gdn_a_log,
+                 **_decay_inits()}
         tensors = {}
         for name, kind, shape in self.shapes:
-            if kind == "bias":
+            if kind == "centred":
+                tensors[name] = 1.0 + self.param(
+                    name, nn.initializers.zeros, shape)
+            elif kind == "bias":
                 tensors[name] = self.variable(
                     "constants", name, lambda s=shape: ROUTER_BIAS_SCALE
                     * jax.random.normal(self.make_rng("params"), s)).value
@@ -2174,6 +2374,17 @@ class TokenDecoder(nn.Module):
     # True: over the whole projection; "head": over each head, one weight
     # [head_dim]; False: none.
     qk_norm: Any = True
+    # The share of a head's leading values that RoPE rotates (the angles'
+    # frequencies over that many, not over the head); the rest pass as they
+    # are.
+    partial_rotary_factor: float = 1.0
+    # W_q yields, a head, its query and as many values again whose sigmoid
+    # multiplies the head's output ahead of W_o.
+    attention_gate: bool = False
+    # Every norm of the hidden vector and of a head's queries and keys is
+    # x / rms(x) * (1 + w), w 0 at initialisation (an operator's own output
+    # norm keeps a plain weight).
+    zero_centred_norms: bool = False
     sliding_window: int = 0
     window_layout: tuple = ()
     rope_layout: tuple = ()
@@ -2205,6 +2416,17 @@ class TokenDecoder(nn.Module):
     ssm_state: int = 128
     ssm_taps: int = 4
     ssm_chunk: int = 128
+    # "gdn", Gated DeltaNet: the delta rule under ONE decay a value head,
+    # `gdn_key_heads` query/key heads of `gdn_key_dim`, each serving
+    # `gdn_value_heads // gdn_key_heads` consecutive value heads of
+    # `gdn_value_dim`, one short convolution of `gdn_taps` taps over q, k
+    # and v together, the learner's scan in chunks of `gdn_chunk`.
+    gdn_key_heads: int = 16
+    gdn_value_heads: int = 32
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_taps: int = 4
+    gdn_chunk: int = 64
     # A layer is its operator OR its feed-forward ("experts" in
     # `layer_types`), x + f(RMSNorm(x)) with one f, not one after the other.
     one_function_layers: bool = False
@@ -2218,6 +2440,8 @@ class TokenDecoder(nn.Module):
     first_expert_held: int = 0
     shared_experts: int = 0
     shared_width: int = 0  # 0: `shared_experts` x `expert_width`
+    # The shared expert's output times sigmoid(n . w), w [hidden].
+    shared_expert_gate: bool = False
     hidden_act: str = "silu"  # the gate's, in every gated feed-forward
     # False: no gate matrix, W_down act(W_up n), experts and shared alike.
     gated_feed_forward: bool = True
@@ -2265,9 +2489,10 @@ class TokenDecoder(nn.Module):
 
     def layer_kind(self, i: int):
         """"conv" where layer `i`'s operator is the short convolution,
-        "kda" where it is Kimi Delta Attention, "mamba2" where it is the
-        state-space layer, "experts" where the layer is its feed-forward
-        alone; of an attention layer (the window it attends within, 0 for
+        "kda" where it is Kimi Delta Attention, "gdn" where it is Gated
+        DeltaNet, "mamba2" where it is the state-space layer, "experts"
+        where the layer is its feed-forward alone; of an attention layer
+        (the window it attends within, 0 for
         the whole episode; whether its queries and keys are rotated)."""
         if self.layer_types and self.layer_types[i] != "full_attention":
             if self.layer_types[i] not in LAYER_TYPES:
@@ -2294,6 +2519,13 @@ class TokenDecoder(nn.Module):
         return (self.kda_heads or self.num_heads) * self.kda_head_dim
 
     @property
+    def gdn_conv_width(self) -> int:
+        """The channels a Gated DeltaNet layer's one convolution runs over:
+        q, k (key heads x key dim each) and v (value heads x value dim)."""
+        return (2 * self.gdn_key_heads * self.gdn_key_dim
+                + self.gdn_value_heads * self.gdn_value_dim)
+
+    @property
     def ssm_width(self) -> int:
         """A Mamba-2 layer's inner channels: heads x head_dim."""
         return self.ssm_heads * self.ssm_head_dim
@@ -2316,10 +2548,24 @@ class TokenDecoder(nn.Module):
         two."""
         H, heads = self.hidden_size, self.num_heads
         feed_forward = not self.one_function_layers or kind == "experts"
-        shapes = [("attn_norm", "ones", (H,))] * (kind != "experts") + [
-            ("mlp_norm", "ones", (H,))] * feed_forward
+        norm = "centred" if self.zero_centred_norms else "ones"
+        shapes = [("attn_norm", norm, (H,))] * (kind != "experts") + [
+            ("mlp_norm", norm, (H,))] * feed_forward
         if kind == "experts":
             pass
+        elif kind == "gdn":
+            V = self.gdn_value_heads
+            P = V * self.gdn_value_dim
+            shapes += [
+                # [q | k | v | z], [b | a], and the one convolution's taps
+                # over q, k and v
+                ("gdn_qkvz", "dense", (H, self.gdn_conv_width + P)),
+                ("gdn_ba", "dense", (H, 2 * V)),
+                ("gdn_conv", "taps", (self.gdn_conv_width, self.gdn_taps)),
+                ("gdn_a_log", "gdn_a_log", (V,)),
+                ("gdn_dt_bias", "ones", (V,)),
+                ("gdn_o_norm", "ones", (self.gdn_value_dim,)),
+                ("gdn_out", "dense", (P, H))]
         elif kind == "mamba2":
             I, C = self.ssm_width, self.ssm_conv_width
             shapes += [
@@ -2366,11 +2612,13 @@ class TokenDecoder(nn.Module):
         else:
             q, kv = heads * self.head_width, self.kv_heads * self.head_width
             if self.qk_norm == "head":
-                shapes += [("q_norm", "ones", (self.head_width,)),
-                           ("k_norm", "ones", (self.head_width,))]
+                shapes += [("q_norm", norm, (self.head_width,)),
+                           ("k_norm", norm, (self.head_width,))]
             elif self.qk_norm:
-                shapes += [("q_norm", "ones", (q,)), ("k_norm", "ones", (kv,))]
-            shapes += [("wq", "dense", (H, q)), ("wk", "dense", (H, kv)),
+                shapes += [("q_norm", norm, (q,)), ("k_norm", norm, (kv,))]
+            # A head's query, and with a gate as many values again.
+            shapes += [("wq", "dense", (H, q * (1 + self.attention_gate))),
+                       ("wk", "dense", (H, kv)),
                        ("wv", "dense", (H, kv)), ("wo", "dense", (q, H))]
         if not feed_forward:
             return tuple(shapes)
@@ -2392,6 +2640,8 @@ class TokenDecoder(nn.Module):
             shapes += [("shared_gate", "dense", (H, SW))] * gate + [
                 ("shared_up", "dense", (H, SW)),
                 ("shared_down", "dense", (SW, H))]
+            if self.shared_expert_gate:
+                shapes.append(("shared_scale", "dense", (H, 1)))
         return tuple(shapes)
 
     def setup(self):
@@ -2417,6 +2667,10 @@ class TokenDecoder(nn.Module):
                 "TokenDecoder has no dense layer, next-next-token module "
                 "or router ahead of the attention in a model whose layers "
                 "are one function each or whose feed-forward has no gate")
+        if "gdn" in kinds and self.gdn_value_heads % self.gdn_key_heads:
+            raise ValueError(
+                f"{self.gdn_value_heads} value heads do not fall to "
+                f"{self.gdn_key_heads} key heads in whole groups")
         if "mamba2" in kinds and self.ssm_heads % self.ssm_groups:
             raise ValueError(
                 f"{self.ssm_heads} state-space heads do not fall into "
@@ -2447,7 +2701,12 @@ class TokenDecoder(nn.Module):
                 ("eh_proj", "dense", (2 * H, H)),
                 ("final_norm", "ones", (H,))), name=f"nextn_{i}")
             for i in range(self.nextn_layers)]
-        self.final_norm = self.param("final_norm", nn.initializers.ones, (H,))
+        if self.zero_centred_norms:
+            self.final_norm = 1.0 + self.param(
+                "final_norm", nn.initializers.zeros, (H,))
+        else:
+            self.final_norm = self.param(
+                "final_norm", nn.initializers.ones, (H,))
         if not self.tie_embeddings:
             self.head = self.param(
                 "head", nn.initializers.normal(0.01), (H, self.num_outputs))
@@ -2482,8 +2741,10 @@ class TokenDecoder(nn.Module):
         1 inputs of its three convolutions, [B, taps - 1, 3 x heads x d]
         in `compute_dtype`, its entry of "conv". A Mamba-2 layer likewise:
         [B, heads, P, N] float32 under "ssm", its one convolution's last
-        inputs [B, taps - 1, I + 2 G N] under "conv". A layer that is its
-        feed-forward alone keeps nothing."""
+        inputs [B, taps - 1, I + 2 G N] under "conv". A Gated DeltaNet
+        layer likewise: [B, value heads, d_k, d_v] float32 under "gdn", its
+        one convolution's last inputs [B, taps - 1, 2 K + V] under "conv".
+        A layer that is its feed-forward alone keeps nothing."""
         B = batch_size
         kinds = [self.layer_kind(i) for i in range(self.num_layers)]
 
@@ -2498,19 +2759,21 @@ class TokenDecoder(nn.Module):
             return ((B, S, self.kv_heads, self.head_width),) * 2
         tails = {"conv": (self.conv_taps - 1, self.hidden_size),
                  "kda": (self.kda_taps - 1, 3 * self.kda_width),
-                 "mamba2": (self.ssm_taps - 1, self.ssm_conv_width)}
+                 "mamba2": (self.ssm_taps - 1, self.ssm_conv_width),
+                 "gdn": (self.gdn_taps - 1, self.gdn_conv_width)}
         d = self.kda_head_dim
         matrices = {
-            "kda": ("kda", (self.kda_width // d, d, d)),
-            "ssm": ("mamba2", (self.ssm_heads, self.ssm_head_dim,
-                               self.ssm_state))}
+            "kda": (self.kda_width // d, d, d),
+            "ssm": (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+            "gdn": (self.gdn_value_heads, self.gdn_key_dim,
+                    self.gdn_value_dim)}
         held = {
             "kv": [tuple(jnp.zeros(s, self.compute_dtype) for s in shapes(i))
                    for i in range(self.num_layers)],
             "conv": [jnp.zeros((B,) + tails[kind], self.compute_dtype)
                      if kind in tails else () for kind in kinds]}
-        for key, (of, shape) in matrices.items():
-            held[key] = [jnp.zeros((B,) + shape, jnp.float32)
+        for key, of in MATRIX_STATES.items():
+            held[key] = [jnp.zeros((B,) + matrices[key], jnp.float32)
                          if kind == of else () for kind in kinds]
         return self._policy_state(held, jnp.zeros(batch_size, jnp.int32))
 
@@ -2521,7 +2784,7 @@ class TokenDecoder(nn.Module):
         kinds the model has."""
         kinds = self.layer_types[:self.num_layers]
         has = {"kv": True, "conv": bool(self.layer_types),
-               "kda": "kda" in kinds, "ssm": "mamba2" in kinds}
+               **{key: of in kinds for key, of in MATRIX_STATES.items()}}
         return {**{kind: tuple(held[kind]) for kind in STATE_KINDS
                    if has[kind]}, "pos": pos}
 
@@ -2553,8 +2816,8 @@ class TokenDecoder(nn.Module):
         with KDA layers, or with Mamba-2 layers: how many they are, the
         bytes of their matrix states a row, the positions in a chunk of
         the learner's scan, and whether a decode step passes over the
-        states in place, by the kernel (1.0: KDA's, of whole tiles), or by
-        XLA's fusions (0.0). A model whose heads are grouped: the query
+        states in place, by the kernel (1.0: the delta rule's, KDA's or
+        Gated DeltaNet's, of whole tiles), or by XLA's fusions (0.0). A model whose heads are grouped: the query
         heads a key/value head. A model that generates a block of positions
         a step: the block, the denoising passes, the passes a generated
         token costs the rollout ((denoise_steps + 1) / block_len) and the
@@ -2641,7 +2904,8 @@ class TokenDecoder(nn.Module):
         if not self.kv_lora_rank and self.kv_heads != self.num_heads:
             out["kv_groups"] = self.num_heads // self.kv_heads
         state = jax.eval_shape(lambda: self.initial_state(1))
-        chunks = {"conv": None, "kda": self.kda_chunk, "ssm": self.ssm_chunk}
+        chunks = {"conv": None, "kda": self.kda_chunk, "ssm": self.ssm_chunk,
+                  "gdn": self.gdn_chunk}
         for kind in STATE_KINDS[1:]:
             layers = sum(bool(entry) for entry in state.get(kind, ()))
             if not layers:
@@ -2652,11 +2916,13 @@ class TokenDecoder(nn.Module):
                             for a in jax.tree.leaves(state[kind]))})
             if chunks[kind]:
                 out[f"{kind}_chunk"] = chunks[kind]
-        if "kda_layers" in out or "ssm_layers" in out:
+        if any(f"{kind}_layers" in out for kind in ("kda", "ssm", "gdn")):
+            # The delta rule's states, a channel's decay or a head's.
+            stepped = jax.tree.leaves(
+                [state.get(kind, ()) for kind in ("kda", "gdn")])
             out["state_step_kernel"] = float(
-                platform == "tpu" and "kda_layers" in out and all(
-                    state_step.whole_tiles(*S.shape[1:])
-                    for S in jax.tree.leaves(state["kda"])))
+                platform == "tpu" and bool(stepped) and all(
+                    state_step.whole_tiles(*S.shape[1:]) for S in stepped))
         return out
 
     def __call__(self, obs, state, reset):
@@ -2671,6 +2937,9 @@ class TokenDecoder(nn.Module):
 
     # -- attention, both kinds, both forms --------------------------------
     def _qkv(self, lp, n):
+        """(q, k, v) by head of rows n; with `attention_gate` a fourth, the
+        values whose sigmoid multiplies a head's output: a head's columns
+        of W_q are its query's, then its gate's."""
         cd, eps = self.compute_dtype, self.rms_eps
         heads = n.shape[:-1] + (self.num_heads, -1)
         groups = n.shape[:-1] + (self.kv_heads, -1)
@@ -2682,10 +2951,33 @@ class TokenDecoder(nn.Module):
         q, k = projected("wq", "q_norm"), projected("wk", "k_norm")
         v = jnp.dot(n, lp["wv"].astype(cd))
         q, k = q.reshape(heads), k.reshape(groups)
+        gate = ()
+        if self.attention_gate:
+            q, *gate = jnp.split(q, 2, axis=-1)
         if self.qk_norm == "head":
             q = rms_norm(q, lp["q_norm"], eps, cd)
             k = rms_norm(k, lp["k_norm"], eps, cd)
-        return q, k, v.reshape(groups)
+        return (q, k, v.reshape(groups), *gate)
+
+    def _gated(self, o, gate):
+        """A head's output o times sigmoid(gate), in float32."""
+        with jax.named_scope("policy/attention_gate"):
+            return (o.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(self.compute_dtype)
+
+    def _rotate(self, x, positions, scale=1.0, head_major=False):
+        """`rope` of the leading `partial_rotary_factor` of a head's values
+        (all of them as a rule), the rest as they are; everything times
+        `scale` in float32."""
+        rotated = int(x.shape[-1] * self.partial_rotary_factor)
+        if rotated == x.shape[-1]:
+            return rope(x, positions, self.rope_theta, scale, head_major)
+        rest = x[..., rotated:]
+        if scale != 1.0:
+            rest = (rest.astype(jnp.float32) * scale).astype(x.dtype)
+        return jnp.concatenate([
+            rope(x[..., :rotated], positions, self.rope_theta, scale,
+                 head_major), rest], axis=-1)
 
     def _attention_scope(self, window: int) -> str:
         """The name a layer's own-heads attention has in a trace: by its
@@ -2785,7 +3077,9 @@ class TokenDecoder(nn.Module):
                 asking = (lambda a: a[:, unread:]) if unread else (lambda a: a)
 
                 def projected(w, norm, heads, n=n):
-                    a = by_head(n, lp[w], heads)
+                    return normed(by_head(n, lp[w], heads), norm, heads)
+
+                def normed(a, norm, heads):
                     if self.qk_norm == "head":
                         # Over each head's own values, one weight for all.
                         return rms_norm(a, lp[norm], eps, cd)
@@ -2794,21 +3088,28 @@ class TokenDecoder(nn.Module):
                     # QK-norm over the whole projection: heads and d.
                     return rms_norm(a, lp[norm].reshape(heads, 1, -1), eps,
                                     cd, axes=(1, 3))
-                q = projected("wq", "q_norm", heads, asking(n))
+                if self.attention_gate:
+                    q, gate = jnp.split(
+                        by_head(asking(n), lp["wq"], heads), 2, axis=-1)
+                    q = normed(q, "q_norm", heads)
+                else:
+                    q = projected("wq", "q_norm", heads, asking(n))
                 k = projected("wk", "k_norm", groups)
                 scale = q.shape[-1] ** -0.5
                 if rotary:
                     # The softmax's scale goes onto q in RoPE's float32.
-                    q = rope(q, asking(positions), self.rope_theta, scale,
-                             head_major=True)
-                    k = rope(k, positions, self.rope_theta, head_major=True)
+                    q = self._rotate(q, asking(positions), scale,
+                                     head_major=True)
+                    k = self._rotate(k, positions, head_major=True)
                     scale = 1.0
                 v = by_head(n, lp["wv"], groups)
                 if streams:
                     return joined(block_stream_attention(
                         q, k, v, episode, scale, self.block_len,
                         streams), asking(x)), ()
-                h = joined(causal_attention(q, k, v, episode, scale, window))
+                o = causal_attention(q, k, v, episode, scale, window)
+                h = joined(self._gated(o, gate) if self.attention_gate
+                           else o)
                 caches = tuple(
                     jnp.take_along_axis(jnp.swapaxes(a, 1, 2),
                                         cache_rows[:, :, None, None], axis=1)
@@ -2868,10 +3169,10 @@ class TokenDecoder(nn.Module):
             k_cache, v_cache = caches
             with jax.named_scope(self._attention_scope(window)):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
-                q, k, v = self._qkv(lp, n)
+                q, k, v, *gate = self._qkv(lp, n)
                 if rotary:
-                    q = rope(q, pos, self.rope_theta)
-                    k = rope(k, pos, self.rope_theta)
+                    q = self._rotate(q, pos)
+                    k = self._rotate(k, pos)
                 slot = pos % k_cache.shape[1] if window else pos
                 # Grouped heads' caches are stored flat: a position's row
                 # is written whole, and read through its view by head.
@@ -2883,6 +3184,8 @@ class TokenDecoder(nn.Module):
                 o, read = cached_attention(
                     q, k_cache.reshape(by_head), v_cache.reshape(by_head),
                     pos)
+                if gate:
+                    o = self._gated(o, gate[0])
                 h = x + jnp.dot(o.reshape(B, -1), lp["wo"].astype(cd))
             return h, (k_cache, v_cache), read
         # Latent attention, absorbed: W_UK goes into the query and W_UV
@@ -3018,6 +3321,86 @@ class TokenDecoder(nn.Module):
                 o, S = kda_decode_step(S, q, k, v, g, beta, reset)
             return self._kda_output(lp, x, n, o), taps[:, 1:], S
 
+    # -- Gated DeltaNet, both forms -----------------------------------------
+    def _gdn_inputs(self, lp, x):
+        """([q~ | k~ | v~] ahead of their convolution, z, [b | a]) of rows
+        x [.., H]: [q~ | k~ | v~ | z] = RMSNorm(x) W_qkvz, [b | a] =
+        RMSNorm(x) W_ba."""
+        cd = self.compute_dtype
+        n = rms_norm(x, lp["attn_norm"], self.rms_eps, cd)
+        mixed = jnp.dot(n, lp["gdn_qkvz"].astype(cd))
+        C = self.gdn_conv_width
+        return mixed[..., :C], mixed[..., C:], jnp.dot(
+            n, lp["gdn_ba"].astype(cd))
+
+    def _gdn_heads(self, lp, mixed, ba):
+        """(q, k [.., key heads, d_k], v [.., value heads, d_v], g, beta
+        [.., value heads]) of the convolved projections `mixed` [.., 2 K +
+        V] (float32, ahead of the activation) and the rows' [b | a]: q, k,
+        v = silu(.), q and k of unit length a head (eps 1e-6 under the
+        root) and q times d_k^-1/2, made in float32 and kept in
+        `compute_dtype`; float32, the LOG decay of a value head g =
+        -exp(A_log) softplus(a + dt_bias) <= 0 and beta = sigmoid(b)."""
+        cd, f32 = self.compute_dtype, jnp.float32
+        K = self.gdn_key_heads * self.gdn_key_dim
+        mixed = jax.nn.silu(mixed)
+        by = mixed.shape[:-1]
+        q, k = (mixed[..., i * K:(i + 1) * K].reshape(
+            by + (self.gdn_key_heads, -1)) for i in range(2))
+        v = mixed[..., 2 * K:].reshape(by + (self.gdn_value_heads, -1))
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        b, a = jnp.split(ba.astype(f32), 2, axis=-1)
+        g = -jnp.exp(lp["gdn_a_log"]) * jax.nn.softplus(
+            a + lp["gdn_dt_bias"])
+        return ((unit(q) * self.gdn_key_dim ** -0.5).astype(cd),
+                unit(k).astype(cd), v.astype(cd), g, jax.nn.sigmoid(b))
+
+    def _gdn_output(self, lp, x, z, o):
+        """x + (RMSNorm_head(o) * w * silu(z)) W_out for a value head's
+        outputs o [.., value heads, d_v]: the norm over each head's own
+        values, one plain weight [d_v], then the gate."""
+        cd, f32 = self.compute_dtype, jnp.float32
+        o = rms_norm(o, lp["gdn_o_norm"], self.rms_eps, f32)
+        o = (o.reshape(z.shape) * jax.nn.silu(z.astype(f32))).astype(cd)
+        return x + jnp.dot(o, lp["gdn_out"].astype(cd))
+
+    def _gdn_causal(self, lp, x, positions, episode):
+        """x + GatedDeltaNet(RMSNorm(x)) over a fragment [B, T, H] from
+        empty states (`kda_chunked` with one decay a head, a key head
+        serving its value heads); (h, (the convolution's last taps - 1
+        inputs [B, taps - 1, 2 K + V], the matrix state after the last
+        position [B, value heads, d_k, d_v] float32): what a decode
+        continues from)."""
+        with jax.named_scope("policy/gdn"):
+            mixed, z, ba = self._gdn_inputs(lp, x)
+            mixed, back = _taps_causal(mixed, lp["gdn_conv"], positions)
+            q, k, v, g, beta = self._gdn_heads(lp, mixed, ba)
+            o, S = kda_chunked(q, k, v, g, beta, episode, self.gdn_chunk,
+                               self.compute_dtype, scope="policy/gdn_state")
+            return self._gdn_output(lp, x, z, o), (
+                _taps_tail(back, positions), S)
+
+    def _gdn_step(self, lp, x, tails, S, reset):
+        """The same of one token a row, x [B, H], against the row's
+        states, zeroed first where `reset` (`kda_decode_step` with one
+        decay a head: in a program lowered for a TPU the kernel that reads
+        a state once and writes it once in place); (h, the convolution's
+        inputs with this one appended and the oldest dropped, the matrix
+        state)."""
+        shared = self.gdn_value_heads // self.gdn_key_heads
+        with jax.named_scope("policy/gdn"):
+            mixed, z, ba = self._gdn_inputs(lp, x)
+            mixed, taps = _taps_step(mixed, lp["gdn_conv"], tails, reset)
+            q, k, v, g, beta = self._gdn_heads(lp, mixed, ba)
+            # A key head's vectors, once a value head it serves.
+            q, k = (jnp.repeat(a, shared, axis=1) for a in (q, k))
+            with jax.named_scope("policy/gdn_state"):
+                o, S = kda_decode_step(S, q, k, v, g, beta, reset)
+            return self._gdn_output(lp, x, z, o), taps[:, 1:], S
+
     # -- Mamba-2, both forms ------------------------------------------------
     def _ssm_inputs(self, lp, x):
         """(z, x B C ahead of their convolution, dt ahead of its bias) of
@@ -3125,7 +3508,14 @@ class TokenDecoder(nn.Module):
         moe, *load = dropless_experts(
             n, top_p, top_i, *matrices("w_gate", "w_up", "w_down"),
             self.first_expert_held, self.num_experts, act)
-        if "shared_up" in lp:
+        if "shared_scale" in lp:
+            with jax.named_scope("policy/shared_expert"):
+                shared = swiglu(n, *matrices(
+                    "shared_gate", "shared_up", "shared_down"), act=act)
+                scale = jax.nn.sigmoid(jnp.dot(
+                    n, lp["shared_scale"].astype(cd)).astype(jnp.float32))
+                moe = moe + (scale * shared.astype(jnp.float32)).astype(cd)
+        elif "shared_up" in lp:
             with jax.named_scope("policy/shared_expert"):
                 moe = moe + swiglu(n, *matrices(
                     "shared_gate", "shared_up", "shared_down"), act=act)
@@ -3227,6 +3617,8 @@ class TokenDecoder(nn.Module):
                 h, caches = self._kda_causal(lp, x, positions, episode)
             elif kind == "mamba2":
                 h, caches = self._ssm_causal(lp, x, positions, episode)
+            elif kind == "gdn":
+                h, caches = self._gdn_causal(lp, x, positions, episode)
             else:
                 rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
                 h, caches = self._attend_causal(
@@ -3252,12 +3644,12 @@ class TokenDecoder(nn.Module):
             kind = self.layer_kind(i)
             x, caches, load, top_i = block(layer(), x, kind)
             matrix = ()
-            if kind in ("kda", "mamba2"):
+            if kind in MATRIX_STATES.values():
                 caches, matrix = caches
             held["kv"].append(() if isinstance(kind, str) else caches)
             held["conv"].append(caches if isinstance(kind, str) else ())
-            held["kda"].append(matrix if kind == "kda" else ())
-            held["ssm"].append(matrix if kind == "mamba2" else ())
+            for key, of in MATRIX_STATES.items():
+                held[key].append(matrix if kind == of else ())
             if top_i is not None:
                 loads.append(load)
                 experts.append(top_i.reshape(B, T, -1))
@@ -3329,13 +3721,16 @@ class TokenDecoder(nn.Module):
             elif kind == "mamba2":
                 h, tails, matrix = self._ssm_step(
                     lp, x, state["conv"][i], state["ssm"][i], reset)
+            elif kind == "gdn":
+                h, tails, matrix = self._gdn_step(
+                    lp, x, state["conv"][i], state["gdn"][i], reset)
             else:
                 h, caches, reads[i] = self._attend_step(
                     lp, x, pos, caches, *kind)
             held["kv"].append(caches)
             held["conv"].append(tails)
-            held["kda"].append(matrix if kind == "kda" else ())
-            held["ssm"].append(matrix if kind == "mamba2" else ())
+            for key, of in MATRIX_STATES.items():
+                held[key].append(matrix if kind == of else ())
             x, top_i = h, None
             if "mlp_norm" in lp:
                 x, _, top_i = self._feed_forward(lp, h, routing)
@@ -3825,3 +4220,46 @@ def sdar_moe_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=fields["vocab_size"], **fields)
+
+
+def qwen3_next_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks `qwen3_next`'s
+    published `config.json`'s own keys (a key left out has
+    Qwen3-Next-80B-A3B-Instruct's value), and the three that state the
+    deployment (the chip's share of the experts; the positions in a chunk
+    of the learner's scan); unknown keys are refused, and so is a published
+    key whose value the decoder has no part for (a dense layer, a window, a
+    bias, a scaled rotation). The family's parts: an operator a layer by
+    `full_attention_interval` (layer i is attention where (i + 1) is a
+    multiple of it, else Gated DeltaNet: the delta rule under one decay a
+    value head, a key head serving two value heads); grouped-head attention
+    of a `head_dim` of its own with QK-norm over each head, a rotation of
+    the leading `partial_rotary_factor` of a head, and a sigmoid gate on
+    its output that W_q makes beside the query; every norm zero-centred; a
+    softmax router over every expert renormalised over the chosen, SwiGLU
+    experts beside ONE shared expert of a width of its own behind a sigmoid
+    gate; no dense layer; an untied head. The family's next-token module
+    has no key in the config and is not built."""
+    known = (set(QWEN3_NEXT_CONFIG_KEYS) | set(QWEN3_NEXT_FIXED)
+             | set(QWEN3_NEXT_UNREAD) | {"full_attention_interval"})
+    _refuse_unknown(cfg, known, "qwen3_next")
+    if "mlp_only_layers" in cfg:  # a tuple says what a list says
+        cfg = dict(cfg, mlp_only_layers=list(cfg["mlp_only_layers"]))
+    _refuse_other_values(cfg, QWEN3_NEXT_FIXED)
+    merged = {**QWEN3_NEXT_PUBLISHED, **cfg}
+    fields = {QWEN3_NEXT_CONFIG_KEYS[k]: v for k, v in merged.items()
+              if k in QWEN3_NEXT_CONFIG_KEYS}
+    interval = merged["full_attention_interval"]
+    if not isinstance(interval, int) or interval < 1:
+        raise ValueError(
+            f"full_attention_interval {interval!r}: a whole number of "
+            "layers, the last of which is the attention")
+    fields.update(
+        layer_types=tuple(
+            "gdn" if (i + 1) % interval else "full_attention"
+            for i in range(fields["num_layers"])),
+        qk_norm="head", attention_gate=True, zero_centred_norms=True,
+        shared_experts=1, shared_expert_gate=True)
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)

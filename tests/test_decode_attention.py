@@ -390,6 +390,7 @@ def test_decode_fused_is_a_rule_of_the_static_shape(S, R, d_qk, value_dim,
     (4096, 8, 30, 64, False),    # no whole groups of query heads
     (4096, 2, 64, 64, True),     # one lane tile a position
     (32768, 8, 64, 128, True),
+    (4096, 2, 16, 256, True),    # the eighth token cell's: heads of 256
 ])
 def test_grouped_fused_is_a_rule_of_the_static_shape(S, groups, heads, d,
                                                      fused):
@@ -679,6 +680,8 @@ def test_the_kernel_compiles_for_a_v5e_at_the_cell_s_widths(rows, one_chip):
     (1, 28, 4, 128, 8192),     # its bootstrap step
     (64, 4 * 32, 4, 128, 2048),  # the seventh's block step: a block's 4
                                  # positions folded into the 32 heads' rows
+    (32, 16, 2, 256, 4096),    # the eighth's rollout: 8 query heads a
+    (2, 16, 2, 256, 4096),     # cached head of 256; its bootstrap step
 ])
 def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
         rows, heads, groups, d, S, one_chip):
@@ -809,14 +812,17 @@ def test_a_cache_stored_by_head_would_be_copied_every_step(one_chip):
     assert cache_copies(compiled, rows, S)
 
 
+@pytest.mark.parametrize("decay", ["a_channel", "a_head"])
 @pytest.mark.parametrize("rows", [32, 2])
 def test_the_state_step_compiles_for_a_v5e_at_the_cell_s_widths(
-        rows, one_chip):
+        rows, decay, one_chip):
     """The other kernel file's (`models/state_step.py`; here because this is
     the one file that loads the chip's compiler): Mosaic takes the fifth
     cell's 32 heads of [128, 128] (the rollout's 32 rows, the bootstrap
     step's 2), the turned vectors and the one-lane slices as they stand, and
-    a scan's carried states go through it where they lie: no copy of one."""
+    a scan's carried states go through it where they lie: no copy of one.
+    The eighth cell's states are the same 32 heads under ONE decay a head,
+    which arrives as a row a grid step and multiplies a head's whole tile."""
     def steps(S, q, k, v, g, beta, reset):
         def one(S, _):
             o, S = transformer.kda_decode_step(S, q, k, v, g, beta, reset)
@@ -826,7 +832,8 @@ def test_the_state_step_compiles_for_a_v5e_at_the_cell_s_widths(
     vector = shaped(one_chip, rows, 32, 128)
     compiled = jax.jit(steps, donate_argnums=(0,)).trace(
         shaped(one_chip, rows, 32, 128, 128, dtype=f32), vector, vector,
-        vector, shaped(one_chip, rows, 32, 128, dtype=f32),
+        vector, shaped(one_chip, rows, 32, 128, dtype=f32)
+        if decay == "a_channel" else shaped(one_chip, rows, 32, dtype=f32),
         shaped(one_chip, rows, 32, dtype=f32),
         shaped(one_chip, rows, dtype=jnp.int32)).lower(
             lowering_platforms=("tpu",)).compile().as_text()

@@ -721,9 +721,10 @@ def test_the_other_families_states_and_counters_are_what_they_were():
     assert set(jax.eval_shape(lambda: olmoe.initial_state(2))) == {
         "kv", "pos"}
     counted = olmoe.static_counters(2, 16, "cpu")
-    assert not any(key.startswith(("conv", "kda", "ssm", "kv_groups"))
+    assert not any(key.startswith(("conv", "kda", "ssm", "gdn", "kv_groups"))
                    for key in counted)
-    assert transformer.STATE_KINDS == ("kv", "conv", "kda", "ssm")
+    # The fifth kind came with the eighth description (PR 52).
+    assert transformer.STATE_KINDS == ("kv", "conv", "kda", "ssm", "gdn")
     assert transformer.ACTIVATIONS["relu2"] is transformer.relu2
     np.testing.assert_allclose(
         transformer.relu2(jnp.asarray([-2.0, 0.5, 3.0])), [0.0, 0.25, 9.0])

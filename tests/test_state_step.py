@@ -4,7 +4,10 @@ shape, is the plain step (`transformer.kda_step` after the select that
 zeroes the rows that reset) to float32 reassociation; its state comes back in
 the buffer it came in; its derivative is the plain form's; a shape of part
 tiles keeps XLA's fusions, and so does Mamba-2's step at any shape; and a
-program lowered off a TPU says it runs no kernel."""
+program lowered off a TPU says it runs no kernel. The decay a channel of the
+key (Kimi Delta Attention) and ONE decay a head (Gated DeltaNet) go through
+the same kernel and the same plain step: the second is the first fed the
+head's number on every channel, bit for bit."""
 
 import functools
 import json
@@ -41,6 +44,19 @@ def operands(B, heads, d_k, d_v, seed=0):
             jnp.arange(B) % 2)
 
 
+def one_decay(given):
+    """The same operands under ONE decay a head: g [B, heads], each head's
+    first channel's."""
+    S, q, k, v, g, beta, reset = given
+    return S, q, k, v, g[..., 0], beta, reset
+
+
+def widened(given):
+    """One decay a head as the decay a channel that says the same."""
+    S, q, k, v, g, beta, reset = given
+    return S, q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta, reset
+
+
 def close(got, want):
     for g, w in zip(got, want):
         assert g.shape == w.shape and g.dtype == w.dtype == jnp.float32
@@ -64,6 +80,34 @@ def test_the_kernel_is_the_plain_step(shape, heads):
     assert not np.isnan(got[0][1]).any()
 
 
+@pytest.mark.parametrize("shape, heads", [
+    ((2, 32, 128, 128), None), ((2, 32, 128, 128), 8),
+    ((3, 4, 64, 256), None)])
+def test_one_decay_a_head_is_the_same_kernel_and_the_same_step(shape, heads):
+    """The scalar form of both (g [B, heads]) against the per-channel form
+    fed the same number on every channel of a head: the plain steps agree
+    bit for bit (exp(g) times a row is the same product either way), the
+    kernel with the plain step to float32 reassociation; and no operand of
+    the scalar call is as wide as a decay a channel would be."""
+    given = one_decay(operands(*shape))
+    want = plain(*widened(given))
+    for got, w in zip(plain(*given), want):
+        np.testing.assert_array_equal(got, w)
+    close(interpreted(*given, heads=heads), want)
+    close(interpreted(*widened(given), heads=heads), want)
+    S = given[0].at[1].set(jnp.nan)
+    got = interpreted(S, *given[1:], heads=heads)
+    assert not np.isnan(got[0][1]).any()
+    jaxpr = jax.make_jaxpr(functools.partial(interpreted, heads=heads))(
+        *given)
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    B, H, d_k, d_v = shape
+    # reset, beta, q, k, g, v, S: g is as small as beta.
+    assert [v.aval.size for v in call.invars] == [
+        B, B * H, B * H * d_k, B * H * d_k, B * H, B * H * d_v,
+        B * H * d_k * d_v]
+
+
 def test_the_state_comes_back_in_the_buffer_it_came_in():
     """The call's one aliased pair is (S, the state after the step), S is
     its last operand, and no other operand is as large as a [.., d_k, 1]
@@ -80,8 +124,10 @@ def test_the_state_comes_back_in_the_buffer_it_came_in():
     close(jax.jit(interpreted, donate_argnums=(0,))(*given), want)
 
 
-def test_the_kernel_form_has_the_plain_form_s_derivative():
-    S, *vectors, reset = operands(2, 32, 128, 128)
+@pytest.mark.parametrize("decay", ["a_channel", "a_head"])
+def test_the_kernel_form_has_the_plain_form_s_derivative(decay):
+    given = operands(2, 32, 128, 128)
+    S, *vectors, reset = one_decay(given) if decay == "a_head" else given
     fused = state_step.in_place(interpreted, plain)
     weights = [jax.random.normal(jax.random.PRNGKey(n), a.shape)
                for n, a in enumerate(jax.eval_shape(plain, S, *vectors,
@@ -94,6 +140,7 @@ def test_the_kernel_form_has_the_plain_form_s_derivative():
     argnums = tuple(range(len(vectors) + 1))
     got = jax.grad(functools.partial(loss, fused), argnums)(S, *vectors)
     want = jax.grad(functools.partial(loss, plain), argnums)(S, *vectors)
+    assert got[4].shape == vectors[3].shape  # the decay's: its own shape
     for g, w in zip(got, want):
         assert g.dtype == w.dtype
         np.testing.assert_array_equal(np.asarray(g, np.float32),
@@ -133,6 +180,9 @@ def test_part_tiles_keep_the_plain_form_and_whole_ones_take_the_kernel(
     whole = operands(2, 8, 128, 128)
     assert kernels_in(transformer.kda_decode_step, *whole) == 1
     close(transformer.kda_decode_step(*whole), plain(*whole))
+    scalar = one_decay(whole)
+    assert kernels_in(transformer.kda_decode_step, *scalar) == 1
+    close(transformer.kda_decode_step(*scalar), plain(*scalar))
 
 
 def test_off_a_tpu_whole_tiles_take_the_plain_form_too():
@@ -145,7 +195,9 @@ def test_off_a_tpu_whole_tiles_take_the_plain_form_too():
 @pytest.mark.parametrize("config, custom_model, rows, fragment, on_a_tpu", [
     ("impala_kimi_linear_48b_a3b", "kimi_linear", 32, 4096, 1.0),
     # Mamba-2's states keep XLA's fusions on every platform.
-    ("impala_nemotron_twotower_30b_a3b", "nemotron_h", 128, 2048, 0.0)])
+    ("impala_nemotron_twotower_30b_a3b", "nemotron_h", 128, 2048, 0.0),
+    # Gated DeltaNet's take the kernel under one decay a head.
+    ("impala_qwen3_next_80b_a3b", "qwen3_next", 32, 4096, 1.0)])
 def test_a_cell_says_whether_its_states_take_the_kernel(
         config, custom_model, rows, fragment, on_a_tpu):
     with open(os.path.join(BENCH, "configs", config + ".json")) as f:

@@ -48,6 +48,10 @@ LOWERED = {
     # learner's last layer makes its clean stream's keys and values alone).
     "sdar_block_token_anakin_2k":
         "c0916c3b76722bd443457859164d9128980b84409be2d9b3527f8b935c8c6133",
+    # The eighth, brought by PR 52 (the delta rule under one decay a head;
+    # the seven above kept their hashes through it).
+    "qwen3_next_token_anakin_4k":
+        "783f59e85031328c545fbdb2bfab2d60403b8f66cf0d43647d80323c8f90328c",
 }
 
 
