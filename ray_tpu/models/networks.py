@@ -228,7 +228,9 @@ class LSTMNetwork(nn.Module):
     activation: str = "tanh"
 
     @nn.compact
-    def __call__(self, obs, state, reset_mask):
+    def __call__(self, obs, state, reset_mask, rollout=False):
+        # `rollout`: a stateful model's `JaxPolicy.step_state` says so; this
+        # one has one form.
         act = _activation(self.activation)
         B, T = obs.shape[0], obs.shape[1]
         x = obs.reshape(B, T, -1).astype(jnp.float32)

@@ -218,19 +218,24 @@ capacity, no token dropped or re-routed. The layer may hold a share of the
 experts (`experts_held`, from `first_expert_held`): it routes over all of
 them, computes the chosen ones it holds and leaves out what the absent ones
 would add; that partial sum goes on, as on one chip of an expert-parallel
-stage without its exchange. One sum, two blockings, chosen from the static
-shape (`experts_batched`). Grouped: the (row, expert) pairs sorted by
-expert, absent experts' pairs last, the landed ones' rows (up to a static
-size chosen from the landed count, `dispatch_rows`) multiplied group by
-group (`grouped_product`: the library's Pallas grouped-matmul kernels on a
-TPU at shapes that have tiles, `jax.lax.ragged_dot` elsewhere) and added to
-their rows of the sum; the learner's minibatch and a prefill. The shared
+stage without its exchange. One sum, three blockings, chosen from the static
+shape (`experts_batched`, `experts_sparse`). Grouped: the (row, expert)
+pairs sorted by expert, absent experts' pairs last, the landed ones' rows
+(up to a static size chosen from the landed count, `dispatch_rows`)
+multiplied group by group (`grouped_product`: the library's Pallas
+grouped-matmul kernels on a TPU at shapes that have tiles,
+`jax.lax.ragged_dot` elsewhere) and added to their rows of the sum; the
+learner's minibatch and a prefill. The shared
 expert's output may stand behind a gate of its own (`shared_expert_gate`,
 qwen3_next: times sigmoid(n . w), w [hidden]).
 Batched: every row through every held expert in products batched over the
 experts, each term weighted w_e or exactly 0 before the sum; a decode step,
 whose groups of a few rows would each cost the grouped product an MXU tile
 while the step is bound by reading every expert's weights once anyway.
+Chosen: the batched form's sum over the held experts that some row of the
+step chose, the others' matrices not read (`models/expert_step.py`, a
+kernel); a rollout's step that is expected to leave a tenth or more of them
+without a row (the three cells it takes: a fifth to a half).
 
 Router, float32, one of:
   softmax (OLMoE, SmallThinker): p = softmax(n W_r); the k largest p;
@@ -386,7 +391,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import decode_attention, state_step
+from ray_tpu.models import decode_attention, expert_step, state_step
 
 Dtype = Any
 
@@ -1906,6 +1911,50 @@ def experts_batched(M: int, k: int, E: int) -> bool:
     return M * E <= GROUP_COST_ROWS * E + GROUPED_ROW_COST * M * k
 
 
+# The share of the held experts that a step must be expected to leave
+# without a row before its products read the chosen ones alone
+# (`experts_sparse`): the edge measured on a v5e lies between 0.08 and 0.13.
+SPARSE_EMPTY_SHARE = 0.1
+
+
+def experts_sparse(M: int, k: int, E: int, H: int, W: int,
+                   dtype=jnp.bfloat16) -> bool:
+    """Whether a rollout's step of `M` rows of hidden `H`, each routed to
+    `k` of `E` experts `W` wide, reads the matrices of the held experts that
+    some row chose and of no other (`expert_step.chosen_kernel`) in a
+    program lowered for a TPU: a function of the static shape alone,
+    `experts_batched`'s neighbour, and the one place that decides it
+    (`dropless_experts` asks it for the products, `TokenDecoder.
+    decode_sparse` for the step's counter and `static_counters`). A step of
+    so few rows is bound by reading the experts' matrices in either form,
+    which `experts_batched`'s inequality does not describe; what decides is
+    the share of them a step leaves without a row, `(1 - k / E) ** M` under
+    a uniform router (a skewed one leaves more), whatever share of the
+    experts is held here, at `SPARSE_EMPTY_SHARE` or more, and the kernel's
+    tiles (`expert_step.whole_tiles`).
+
+    The edge, on a v5e (my chip run, PR 53, `chiprun_out/pr53/micro2.json`:
+    one layer's held experts alone under a uniform seeded router, the
+    kernel's ms a step over the batched form's, by the rows a step brings):
+    at the three cells' experts the kernel took 0.32-0.86 of the batched
+    form's time wherever this count is 0.15 or more (16 to 96 rows over 32
+    held of 512, 10 a row; 16 to 48 over 8 of 256, 8 a row; 16 over 16 of
+    64, 6 a row), 0.94 at 0.13 (64 rows over 8 of 256), and 0.98-1.05 at
+    0.047 and under (96 rows there; from 32 rows over 16 of 64), where
+    nearly every held expert has a row and a grid step's work grows with
+    the rows; past that XLA's batched product changes with the rows (at 128
+    rows a faster program, the kernel 1.17-2.0 of it; at 192 a slower one,
+    0.84-0.98; at 256 1.02-1.19). At the other token cells' own steps (count
+    under 0.003) it tied or lost: 0.99 (OLMoE's 128 rows over 64 of 64),
+    1.04 (LFM2's 64 over 8 of 32), 1.13 (Nemotron-H's 128 over 8 of 128),
+    1.96 (GLM's 128 over 8 of 64, 1,536 wide). The three cells the rule
+    takes leave 0.21, 0.36 and 0.53 empty by this count (their routers
+    0.24, 0.62 and 0.54)."""
+    return (experts_batched(M, k, E)
+            and (1 - k / E) ** M >= SPARSE_EMPTY_SHARE
+            and expert_step.whole_tiles(M, H, W, dtype))
+
+
 # What the grouped form of a layer that holds a share of the experts
 # compiles: row counts, as multiples of the expected number of (row, expert)
 # pairs that land here, `M * k * held / E`, rounded up to whole tiles of
@@ -2101,7 +2150,7 @@ def dispatch_index(count, sizes):
 
 
 def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
-                     num_experts=None, act=jax.nn.silu):
+                     num_experts=None, act=jax.nn.silu, rollout=False):
     """sum_e p_e W_down,e (act(W_gate,e n) * W_up,e n) for rows n [M, H]
     routed to `top_i` [M, k] of `num_experts` with weights `top_p`, over
     the experts held here: `first` .. `first + E - 1`, whose weights
@@ -2112,8 +2161,8 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     forms and in the pullbacks. Returns ([M, H], rows a held group [E], the
     sorted rows that were gathered).
 
-    Two forms of that sum, chosen by `experts_batched(M, k,
-    num_experts)`; both take operands in n's dtype, accumulate in float32,
+    Three forms of that sum, the first two chosen by `experts_batched(M, k,
+    num_experts)`; all take operands in n's dtype, accumulate in float32,
     weight in float32 and compute every chosen held expert of every row.
 
     Grouped: the M*k (row, expert) pairs sorted by expert, those of absent
@@ -2142,7 +2191,15 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
     a and e folded into the contraction: one product of [M, E*W] against
     W_down as [E*W, H]. The same sum: an expert a row did not choose has
     weight exactly 0. It does E/k times the matrix work and reads each
-    expert's weights once, where they lie."""
+    expert's weights once, where they lie.
+
+    Chosen (`rollout`: the caller is a rollout's step, which nothing
+    differentiates; `experts_sparse` of the static shape, and a program
+    lowered for a TPU; the batched
+    form everywhere else, the learner's bootstrap step included): the
+    batched form's c and its sum, over the held experts with a row alone;
+    the matrices of an expert no row chose, whose products the batched form
+    multiplies by 0, are not read (`expert_step.chosen_kernel`)."""
     M, k = top_i.shape
     E = w_up.shape[0]
     share = num_experts is not None and (first, E) != (0, num_experts)
@@ -2153,10 +2210,7 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             local = jnp.where(here, local, E)  # past every group
         # An index past the last group is dropped by the scatter.
         group_sizes = jnp.zeros(E, jnp.int32).at[local.reshape(-1)].add(1)
-    if experts_batched(M, k, num_experts or E):
-        with jax.named_scope("policy/dispatch"):
-            chosen = local[:, :, None] == jnp.arange(E)
-            c = jnp.sum(jnp.where(chosen, top_p[:, :, None], 0.0), axis=1)
+    def batched(n, c, group_sizes, w_gate, w_up, w_down):
         with jax.named_scope("policy/experts_batched"):
             if w_gate is not None:
                 gate = jnp.einsum("mh,ehw->emw", n, w_gate)
@@ -2164,8 +2218,23 @@ def dropless_experts(n, top_p, top_i, w_gate, w_up, w_down, first=0,
             weight = c.T[:, :, None]
             hidden = act(up) if w_gate is None else act(gate) * up
             a = (weight * hidden).astype(n.dtype)
-            mixed = jnp.einsum("emw,ewh->mh", a, w_down,
-                               preferred_element_type=jnp.float32)
+            return jnp.einsum("emw,ewh->mh", a, w_down,
+                              preferred_element_type=jnp.float32)
+
+    def chosen(*operands):
+        with jax.named_scope("policy/experts_chosen"):
+            return expert_step.chosen_kernel(*operands, act)
+    if experts_batched(M, k, num_experts or E):
+        with jax.named_scope("policy/dispatch"):
+            c = jnp.sum(jnp.where(local[:, :, None] == jnp.arange(E),
+                                  top_p[:, :, None], 0.0), axis=1)
+        operands = (n, c, group_sizes, w_gate, w_up, w_down)
+        if rollout and experts_sparse(
+                M, k, num_experts or E, *w_up.shape[1:], n.dtype):
+            mixed = jax.lax.platform_dependent(
+                *operands, tpu=chosen, default=batched)
+        else:
+            mixed = batched(*operands)
         return mixed.astype(n.dtype), group_sizes, jnp.int32(M * k)
 
     sizes = dispatch_rows(M, k, E, num_experts or E)
@@ -2487,6 +2556,14 @@ class TokenDecoder(nn.Module):
     def head_width(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def decode_sparse(self, rows: int) -> bool:
+        """Whether a rollout's step of `rows` rows reads the chosen held
+        experts' matrices alone, in a program lowered for a TPU
+        (`experts_sparse` of this model's shapes)."""
+        return experts_sparse(
+            rows, self.experts_per_token, self.num_experts,
+            self.hidden_size, self.expert_width, self.compute_dtype)
+
     def layer_kind(self, i: int):
         """"conv" where layer `i`'s operator is the short convolution,
         "kda" where it is Kimi Delta Attention, "gdn" where it is Gated
@@ -2793,7 +2870,12 @@ class TokenDecoder(nn.Module):
         """What the program is, from its static shapes and the platform
         it is compiled for. A decode step of `batch_size` rows: the mean
         rows a held expert group holds, whether the experts multiply in
-        the batched form (1.0) or the grouped one (0.0), the positions in
+        the batched form (1.0) or another (0.0), whether a rollout's step
+        reads the chosen held experts' matrices alone (1.0:
+        `decode_sparse`, in a program for a TPU; the batched form is then
+        the learner's bootstrap step's alone) and, where it does not, the
+        share of them its products read, 1.0 (where it does the step counts
+        it: `_count`), the positions in
         a block of the caches its attention reads, whether that attention
         is the kernel (1.0: over a latent cache, or over the grouped caches
         of every attention layer) or XLA's products (0.0), and with a
@@ -2861,10 +2943,14 @@ class TokenDecoder(nn.Module):
             block = self.context_len
         else:
             block = min(DECODE_CACHE_BLOCK, self.context_len)
+        sparse = (platform == "tpu" and not self.block_len
+                  and self.decode_sparse(step_rows))
         out = {
             "decode_rows_per_expert": step_rows * k / E,
             "decode_experts_batched": float(
-                experts_batched(step_rows, k, E)),
+                experts_batched(step_rows, k, E) and not sparse),
+            "decode_experts_sparse": float(sparse),
+            **({} if sparse else {"decode_experts_read_share": 1.0}),
             "decode_cache_block": block,
             "decode_attention_kernel": float(kernel),
             "causal_attention_fused": float(
@@ -2925,13 +3011,16 @@ class TokenDecoder(nn.Module):
                     state_step.whole_tiles(*S.shape[1:]) for S in stepped))
         return out
 
-    def __call__(self, obs, state, reset):
+    def __call__(self, obs, state, reset, rollout=False):
         """obs [B, T] token ids, reset [B, T] (1 where an episode starts
         at that step) -> (logits [B, T, V], value [B, T], state). T = 1 is
         a decode step against `state`; T > 1 is a causal pass from an
-        empty window (`state` is not read)."""
+        empty window (`state` is not read). `rollout`: the caller is a
+        rollout's step (`JaxPolicy.step_state`), which nothing
+        differentiates, and not a learner's bootstrap step."""
         if obs.shape[1] == 1:
-            logits, value, state = self.decode(obs[:, 0], state, reset[:, 0])
+            logits, value, state = self.decode(
+                obs[:, 0], state, reset[:, 0], rollout)
             return logits[:, None], value[:, None], state
         return self.causal(obs, reset)
 
@@ -3487,11 +3576,12 @@ class TokenDecoder(nn.Module):
         n = rms_norm(x, lp["attn_norm"], self.rms_eps, self.compute_dtype)
         return self._route(lp, n.reshape(-1, n.shape[-1]))
 
-    def _feed_forward(self, lp, h, routing=None):
+    def _feed_forward(self, lp, h, routing=None, rollout=False):
         """h + FeedForward(RMSNorm(h)) for rows h [M, H]; (out, (rows a
         held group, sorted rows gathered), experts [M, k]), the last two
         None of a dense layer.
-        `routing`: (weights, experts) chosen ahead of the attention."""
+        `routing`: (weights, experts) chosen ahead of the attention;
+        `rollout`: `dropless_experts`'."""
         cd = self.compute_dtype
         act = ACTIVATIONS[self.hidden_act]
         n = rms_norm(h, lp["mlp_norm"], self.rms_eps, cd)
@@ -3507,7 +3597,7 @@ class TokenDecoder(nn.Module):
             return (lp[w].astype(cd) if w in lp else None for w in names)
         moe, *load = dropless_experts(
             n, top_p, top_i, *matrices("w_gate", "w_up", "w_down"),
-            self.first_expert_held, self.num_experts, act)
+            self.first_expert_held, self.num_experts, act, rollout)
         if "shared_scale" in lp:
             with jax.named_scope("policy/shared_expert"):
                 shared = swiglu(n, *matrices(
@@ -3531,7 +3621,8 @@ class TokenDecoder(nn.Module):
             value = jnp.dot(y, self.value_w) + self.value_b
         return logits, value
 
-    def _count(self, experts, loads=None, reads=None, pairs=None):
+    def _count(self, experts, loads=None, reads=None, pairs=None,
+               chosen=None):
         """What a pass counted, kept only where the caller asks for the
         collection (and never among the variables `init` returns): the
         experts chosen [expert layers, ..., k], for the reference check;
@@ -3547,7 +3638,10 @@ class TokenDecoder(nn.Module):
         attention read, the mean over the attention layers (`reads`:
         {layer: the positions it read}), and where the model has window
         layers the same of its full layers and of its window layers
-        apart."""
+        apart; and where the step's expert products read the chosen held
+        experts' matrices alone (`chosen`: an expert layer's held experts
+        [held], whether some row chose each), the share of them they read,
+        the mean over the expert layers."""
         if self.is_initializing():
             return
         if experts:
@@ -3563,6 +3657,14 @@ class TokenDecoder(nn.Module):
                          jnp.mean(jnp.sum(loads, axis=-1)) / pairs)
                 self.sow("counters", "dispatch_rows_share",
                          jnp.mean(gathered) / pairs)
+        if chosen:
+            # Counted where the products are the kernel's, which is where
+            # the program is lowered for a TPU, as `dropless_experts`
+            # chooses; the batched form reads every held expert.
+            self.sow("counters", "decode_experts_read_share",
+                     jax.lax.platform_dependent(
+                         jnp.stack(chosen).astype(jnp.float32),
+                         tpu=jnp.mean, default=lambda held: jnp.ones(())))
         window = [bool(self.layer_kind(i)[0]) for i in reads or ()]
         reads = list((reads or {}).values())
         if reads and not (any(window) and not all(window)):
@@ -3701,11 +3803,12 @@ class TokenDecoder(nn.Module):
                      nll / jnp.maximum(jnp.sum(valid), 1))
         return load, top_i
 
-    def decode(self, token, state, reset):
+    def decode(self, token, state, reset, rollout=False):
         pos = jnp.where(reset > 0, 0, state["pos"])
+        sparse = rollout and self.decode_sparse(token.shape[0])
         x = self.embed[token].astype(self.compute_dtype)
         held = {kind: [] for kind in STATE_KINDS}
-        experts, reads = [], {}
+        experts, reads, chosen = [], {}, []
         for i, (layer, caches) in enumerate(zip(self.layers, state["kv"])):
             lp = layer()
             kind = self.layer_kind(i)
@@ -3733,13 +3836,15 @@ class TokenDecoder(nn.Module):
                 held[key].append(matrix if kind == of else ())
             x, top_i = h, None
             if "mlp_norm" in lp:
-                x, _, top_i = self._feed_forward(lp, h, routing)
+                x, load, top_i = self._feed_forward(lp, h, routing, rollout)
             if top_i is not None:
                 experts.append(top_i)
+                if sparse:
+                    chosen.append(load[0] > 0)
         if self.is_initializing():
             for module in self.nextn:
                 module()
-        self._count(experts, reads=reads)
+        self._count(experts, reads=reads, chosen=chosen)
         logits, value = self._heads(x)
         return logits, value, self._policy_state(held, pos + 1)
 

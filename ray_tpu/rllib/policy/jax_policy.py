@@ -242,12 +242,12 @@ class JaxPolicy(Policy):
         reset = jnp.concatenate([first, dones[:, :-1]], axis=1)
         return self._apply_counted(params, obs_bt, state, reset)
 
-    def _apply_counted(self, params, obs_bt, state, reset):
+    def _apply_counted(self, params, obs_bt, state, reset, **static):
         """`apply` of a stateful model, its "counters" collection and its
         "losses" collection (a model computes a loss of its own only where
         the caller keeps that collection, as this one does)."""
         out, kept = self.apply(params, obs_bt, state, reset,
-                               mutable=["counters", "losses"])
+                               mutable=["counters", "losses"], **static)
         return out, _newest(kept, "counters"), _newest(kept, "losses")
 
     def initial_state(self, batch_size: int):
@@ -260,9 +260,13 @@ class JaxPolicy(Policy):
         where the previous step ended an episode) -> (dist_inputs [B, O],
         value [B], state, what the model counted in the step). ONE action a
         row: the caller samples it from `dist_inputs`. A policy whose step
-        is a block of positions a row has `block_step_state` instead."""
+        is a block of positions a row has `block_step_state` instead. The
+        model is told that the step is a rollout's (`rollout=True`: nothing
+        differentiates it, so it may take a form that has no derivative;
+        the learner's passes, its one-step bootstrap among them, go through
+        `apply` and are not)."""
         (dist_bt, val_bt, state), counted, _ = self._apply_counted(
-            params, obs[:, None], state, reset[:, None])
+            params, obs[:, None], state, reset[:, None], rollout=True)
         return dist_bt[:, 0], val_bt[:, 0], state, counted
 
     @property

@@ -841,3 +841,34 @@ def test_the_state_step_compiles_for_a_v5e_at_the_cell_s_widths(
     assert not [line for line in compiled.splitlines()
                 if " copy(" in line and f"f32[{rows},32,128,128]" in
                 line.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("cell, rows, H, W, k, E, held, act", [
+    ("qwen3_next", 32, 2048, 512, 10, 512, 32, "silu"),
+    ("kimi_linear", 32, 2304, 1024, 8, 256, 8, "silu"),
+    ("smallthinker", 16, 2560, 768, 6, 64, 16, "relu"),
+])
+def test_the_chosen_experts_compile_for_a_v5e_at_the_cells_widths(
+        cell, rows, H, W, k, E, held, act, one_chip):
+    """The third kernel file's (`models/expert_step.py`; here because this is
+    the one file that loads the chip's compiler): a rollout's step of the
+    three cells whose steps leave held experts without a row is the kernel
+    (the matrices' blocks by a prefetched id, an expert a grid step), and
+    the same shapes without `rollout` (a learner's bootstrap step says
+    nothing) keep XLA's batched products and no kernel."""
+    def experts(rollout):
+        def run(n, top_p, top_i, w_gate, w_up, w_down):
+            return transformer.dropless_experts(
+                n, top_p, top_i, w_gate, w_up, w_down, 0, E,
+                transformer.ACTIVATIONS[act], rollout)[0]
+        return jax.jit(run).trace(
+            shaped(one_chip, rows, H),
+            shaped(one_chip, rows, k, dtype=jnp.float32),
+            shaped(one_chip, rows, k, dtype=jnp.int32),
+            shaped(one_chip, held, H, W), shaped(one_chip, held, H, W),
+            shaped(one_chip, held, W, H)).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    assert transformer.experts_sparse(rows, k, E, H, W)
+    compiled = experts(True)
+    assert "chosen_experts" in compiled and "tpu_custom_call" in compiled
+    assert "tpu_custom_call" not in experts(False)

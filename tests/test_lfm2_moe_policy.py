@@ -415,6 +415,7 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "custom_model": "lfm2_moe", "custom_model_config": net})
     assert model.static_counters(64, 4096, "tpu") == {
         "decode_rows_per_expert": 8.0, "decode_experts_batched": 1.0,
+        "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 2048.0,
         "kv_groups": 4, "conv_layers": 4, "conv_state_bytes_per_row": 32768}

@@ -682,6 +682,7 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "custom_model": "nemotron_h", "custom_model_config": net})
     assert model.static_counters(128, 2048, "tpu") == {
         "decode_rows_per_expert": 6.0, "decode_experts_batched": 1.0,
+        "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 1024.0,
         "kv_groups": 16, "conv_layers": 3,

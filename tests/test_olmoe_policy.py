@@ -422,6 +422,7 @@ def test_the_form_is_chosen_from_the_static_shape():
     assert experts_batched(4, 8, 64) and not experts_batched(2048, 8, 64)
     assert model.static_counters(128, 1024, "tpu") == {
         "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0,
+        "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": transformer.DECODE_CACHE_BLOCK,
         "decode_attention_kernel": 0.0, "causal_attention_fused": 1.0,
         # K and V of 16 heads of 128 in bfloat16, a layer (PR 38: every

@@ -769,6 +769,8 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
     # 64 blocks of 4 rows, 8 of 128 experts each: 16 rows a held expert.
     assert got["decode_rows_per_expert"] == 16.0
     assert got["decode_experts_batched"] == 1.0
+    assert got["decode_experts_sparse"] == 0.0
+    assert got["decode_experts_read_share"] == 1.0
     assert got["decode_attention_kernel"] == 1.0
     assert got["block_attention_kernel"] == 1.0
     assert got["block_cache_writes_per_block"] == 1

@@ -379,7 +379,10 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
     model = catalog.get_model(None, net["vocab_size"], {
         "custom_model": "smallthinker", "custom_model_config": net})
     assert model.static_counters(16, 8192, "tpu") == {
-        "decode_rows_per_expert": 1.5, "decode_experts_batched": 1.0,
+        # Under two rows a held expert: a rollout's step reads the chosen
+        # ones' matrices alone, and counts their share itself.
+        "decode_rows_per_expert": 1.5, "decode_experts_batched": 0.0,
+        "decode_experts_sparse": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "window_layers": 3, "kv_groups": 7,
         "kv_cache_bytes_per_token": 5120.0,
