@@ -7,6 +7,17 @@ The configuration's `network` block (the published `config.json` keys) is
 what the policy is built from: it goes into the trainer's
 `model.custom_model_config` unless the cell's rehearsal gives a tiny one.
 
+The weights are the configuration's, the traffic is the run's. The run's
+`--seed` reaches the trainer as its `seed`, which draws the policy's
+parameters as well as the env's state and the rollout's sampling keys. A
+configuration that names `weights_seed` has its parameters drawn again from
+that seed (`TokenSession._draw_weights`: the policy's own initialiser and
+the key the trainer would have used under `seed: weights_seed`, so the
+parameters are those of a run at `--seed <weights_seed>` bit for bit) before
+the first call of the trainer's program; everything else stays the run's. A
+cell whose rate follows the routers' draw then reads the same rate at every
+seed. A configuration that names none runs as it always has.
+
 `check_outputs`, on the stopped trainer, at the widths the trainer ran,
 outside the window: `check.sequences` seeded sequences of one episode's
 length, and
@@ -49,6 +60,28 @@ class TokenSession(rllib_trainer.Session):
         self.network = dict(
             model, sequence_length=self.trainer.config[
                 "rollout_fragment_length"])
+        if config.get("weights_seed") is not None:
+            self._draw_weights(int(config["weights_seed"]))
+
+    def _draw_weights(self, weights_seed: int) -> None:
+        """The policy's parameters drawn anew from `weights_seed`, as
+        `JaxPolicy` draws them from the trainer's seed: `model.init` under
+        `jax.jit`, the first key of `PRNGKey(seed)`'s counter, the same
+        dummies. The first draw is freed before the second is made (Adam's
+        zero moments stand beside them), so the chip never holds both."""
+        import jax
+
+        policy = self.policy
+        key = jax.random.fold_in(
+            jax.random.PRNGKey(weights_seed % rllib_trainer.SEED_SPACE),
+            np.uint32(1))
+        observation = np.zeros((1, 1) + tuple(policy.preprocessor.shape),
+                               policy.preprocessor.dtype)
+        for leaf in jax.tree.leaves(policy.params):
+            leaf.delete()
+        policy.set_weights(jax.jit(policy.model.init)(
+            key, observation, policy.model.initial_state(1),
+            np.zeros((1, 1), np.float32)))
 
     def check_outputs(self, seed: int) -> dict:
         import jax

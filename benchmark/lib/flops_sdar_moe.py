@@ -20,9 +20,9 @@ What a generated block owes, S = `denoise_steps`, L = `block_length`:
   still masked when a pass begins, L (S + 1) / 2 of them a block, and the
   value head once;
 * the learner, forward: S noisy streams through every layer and the clean
-  stream, whose last layer is owed its keys and values alone (the program
-  runs it whole: not owed); the head over the L positions once, the value
-  head once; backward twice that.
+  stream, whose last layer is owed its keys and values alone (since PR 51
+  the program runs no more of it than that); the head over the L positions
+  once, the value head once; backward twice that.
 
 `steps` are ACTIONS: an episode of T positions has T - 1 of them (its first
 position is given), so a step owes T / (T - 1) positions.

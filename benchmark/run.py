@@ -82,8 +82,10 @@ def device_memory_peak(device) -> int:
                + stats.get("peak_bytes_reserved", 0))
 
 
-def run_window(session, seconds: float, annotate, compiles) -> dict:
-    """Whole iterations from a boundary until `seconds` have passed."""
+def run_window(session, seconds: float, annotate, compiles,
+               min_iterations: int = 1) -> dict:
+    """Whole iterations from a boundary until `seconds` have passed, and
+    `min_iterations` of them at the least."""
     attempted = failed = 0
     reasons = []
     steps0, lowered0 = session.steps_trained(), compiles.count
@@ -96,7 +98,7 @@ def run_window(session, seconds: float, annotate, compiles) -> dict:
             failed += 1
             reasons.append(out["why"])
         now = time.perf_counter()
-        if now - t0 >= seconds:
+        if now - t0 >= seconds and attempted >= min_iterations:
             break
     return {"attempted": attempted, "failed": failed, "reasons": reasons[:3],
             "seconds": now - t0, "steps": session.steps_trained() - steps0,
@@ -191,8 +193,14 @@ def main() -> int:
         slice_s = float(workload.get("trace_slice_s", 3)) if args.trace else 0
         annotate = jax.profiler.TraceAnnotation
         setup_seconds = time.perf_counter() - T_START
+        # A cell whose iteration's time follows the episodes it happened to
+        # roll out may ask for a floor of whole iterations under its
+        # end-to-end window (`window.min_iterations`); a traced run's
+        # windows are as long as every cell's.
+        floor = 1 if args.trace else int(
+            (workload.get("window") or {}).get("min_iterations", 1))
         windows = [run_window(session, max(args.seconds - slice_s, 1e-3),
-                              annotate, compiles)]
+                              annotate, compiles, floor)]
         ctx.window_s = windows[0]["seconds"]
         values = {name: r.read(ctx, states[name])
                   for name, r in readers.items() if r.SOURCE != "device_trace"}
