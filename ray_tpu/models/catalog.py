@@ -76,11 +76,17 @@ def _qwen3_next(obs_space, num_outputs, cfg, dtype):
     return qwen3_next_from_config(num_outputs, cfg, dtype)
 
 
+def _laguna(obs_space, num_outputs, cfg, dtype):
+    from .transformer import laguna_from_config
+    return laguna_from_config(num_outputs, cfg, dtype)
+
+
 # name -> builder(obs_space, num_outputs, custom_model_config, dtype or None)
 CUSTOM_MODELS = {"olmoe": _olmoe, "glm4_moe_lite": _glm4_moe_lite,
                  "smallthinker": _smallthinker, "lfm2_moe": _lfm2_moe,
                  "kimi_linear": _kimi_linear, "nemotron_h": _nemotron_h,
-                 "sdar_moe": _sdar_moe, "qwen3_next": _qwen3_next}
+                 "sdar_moe": _sdar_moe, "qwen3_next": _qwen3_next,
+                 "laguna": _laguna}
 
 
 def _resolve_compute_dtype(cfg):

@@ -41,6 +41,12 @@ where the head-major form above, grid (rows, G, blocks) with few query
 rows a cached head, paid 2.25 ns a cached position and head whatever the
 bytes (PERF.md section 7, PR 35). `attend_grouped` is its whole-cache form
 and `grouped_decode_attention` the kernel with that form's derivative.
+Where a position's row is wider than 512 lanes and a cached head is whole
+lane tiles (8 x 128: `transformer.grouped_lanes`) the zeros of the
+block-diagonal queries stop hiding behind the bytes, and the step takes
+`lanes_kernel` (`lanes_decode_attention` with `attend_grouped`'s
+derivative): the block step's blocking below without a fresh block, each
+cached head's few query rows against that head's own lanes of a fetched row.
 
 A step that generates a BLOCK of L positions a row (`transformer
 .block_step`) reads the positions before the block from the caches and the
@@ -383,6 +389,32 @@ def _fold(s, v, m_ref, l_ref, acc_ref, g, dtype):
         preferred_element_type=jnp.float32)
 
 
+def _fold_cached(j, lengths_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                 scale, block):
+    """Block j of the flat caches of a grid step's rows into the running
+    softmax, a cached head at a time against its own lanes of a fetched
+    row: the positions at or beyond a row's length masked."""
+    G, d = q_ref.shape[1], q_ref.shape[3]
+    # [rows, 1, 1]
+    lengths = lengths_ref[...]
+    k, v = k_ref[...], v_ref[...]
+    rows = k.shape[0]
+    at = j * block + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1, block), 2)
+    held = at < lengths
+    # What lies beyond a row's length is not the row's: 0 x NaN would
+    # be NaN.
+    at = j * block + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, block, 1), 1)
+    v = jnp.where(at < lengths, v, jnp.zeros_like(v))
+    for g in range(G):
+        q = q_ref[:, g]
+        s = jnp.einsum("trd,tsd->trs", q, k[:, :, g * d:(g + 1) * d],
+                       preferred_element_type=jnp.float32) * scale
+        _fold(jnp.where(held, s, -jnp.inf), v[:, :, g * d:(g + 1) * d],
+              m_ref, l_ref, acc_ref, g, q.dtype)
+
+
 def _block_kernel(last_ref, lengths_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, acc_ref, *, scale, block):
     """One grid step (i, j): block j of the caches of the rows of step i
@@ -412,24 +444,8 @@ def _block_kernel(last_ref, lengths_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
     # is the last one's, which the pipeline has, so it copies nothing.
     @pl.when(j <= last_ref[i])
     def _():
-        # [rows, 1, 1]
-        lengths = lengths_ref[...]
-        k, v = k_ref[...], v_ref[...]
-        rows = k.shape[0]
-        at = j * block + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, 1, block), 2)
-        held = at < lengths
-        # What lies beyond a row's length is not the row's: 0 x NaN would
-        # be NaN.
-        at = j * block + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block, 1), 1)
-        v = jnp.where(at < lengths, v, jnp.zeros_like(v))
-        for g in range(G):
-            q = q_ref[:, g]
-            s = jnp.einsum("trd,tsd->trs", q, head(k, g),
-                           preferred_element_type=f32) * scale
-            _fold(jnp.where(held, s, -jnp.inf), head(v, g), m_ref, l_ref,
-                  acc_ref, g, q.dtype)
+        _fold_cached(j, lengths_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
+                     acc_ref, scale, block)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
@@ -520,6 +536,95 @@ def _block_backward(scale, kept, g):
 
 
 block_decode_attention.defvjp(_block_forward, _block_backward)
+
+
+# -- one position a row, a cached head against its own lanes ------------------
+def _lanes_kernel(last_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+                  l_ref, acc_ref, *, scale, block):
+    """One grid step (i, j) of `_block_kernel` without a fresh block: a row
+    holds position 0 (1 <= lengths), so its maximum is finite after block
+    0, as `_kernel`'s is."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j <= last_ref[i])
+    def _():
+        _fold_cached(j, lengths_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
+                     acc_ref, scale, block)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def lanes_kernel(q, k, v, lengths, scale, *, block=None, rows=None,
+                 interpret=False):
+    """`attend_grouped`'s sum, one query a row and head, by `block_kernel`'s
+    blocking: q [B, heads, d] over the caches where they lie, k, v [B, S,
+    groups, d] (a position's cached heads one row of groups * d contiguous
+    lanes, fetched once for both products and all the heads), cached head
+    g's `heads // groups` query rows scored against lanes [g d, (g + 1) d)
+    of a fetched row alone: the owed products, where `grouped_kernel`'s
+    block-diagonal queries multiply `groups` times as many. `d` is whole
+    lane tiles."""
+    B, heads, d = q.shape
+    S, G = k.shape[1:3]
+    R = heads // G
+    block = block or BLOCK
+    rows = rows or rows_a_step(B)
+    if S % block or B % rows:
+        raise ValueError(
+            f"{S} positions are not whole blocks of {block}, or {B} rows "
+            f"not whole steps of {rows}")
+    lengths = lengths.astype(jnp.int32)
+
+    def held(i, j, last):
+        return i, jnp.minimum(j, last[i]), 0
+
+    def whole(i, j, last):
+        return i, 0, 0, 0
+    return pl.pallas_call(
+        functools.partial(_lanes_kernel, scale=scale, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // rows, S // block),
+            in_specs=[
+                pl.BlockSpec((rows, 1, 1), lambda i, j, last: (i, 0, 0)),
+                pl.BlockSpec((rows, G, R, d), whole),
+                pl.BlockSpec((rows, block, G * d), held),
+                pl.BlockSpec((rows, block, G * d), held)],
+            out_specs=pl.BlockSpec((rows, G, R, d), whole),
+            scratch_shapes=[pltpu.VMEM((rows, G, R, 1), jnp.float32),
+                            pltpu.VMEM((rows, G, R, 1), jnp.float32),
+                            pltpu.VMEM((rows, G, R, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, G, R, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="lanes_attention",
+        interpret=interpret,
+    )(last_blocks(lengths, block, rows), lengths.reshape(B, 1, 1),
+      q.reshape(B, G, R, d), k.reshape(B, S, G * d),
+      v.reshape(B, S, G * d)).reshape(B, heads, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def lanes_decode_attention(q, k, v, lengths, scale):
+    """`lanes_kernel`, differentiable: the pullback is `attend_grouped`'s."""
+    return lanes_kernel(q, k, v, lengths, scale)
+
+
+def _lanes_forward(q, k, v, lengths, scale):
+    return lanes_decode_attention(q, k, v, lengths, scale), (
+        q, k, v, lengths)
+
+
+lanes_decode_attention.defvjp(_lanes_forward, _grouped_backward)
 
 
 # -- a block's keys and values into the caches --------------------------------

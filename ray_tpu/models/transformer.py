@@ -1,4 +1,4 @@
-"""Transformer token policies in flax: one decoder, eight descriptions.
+"""Transformer token policies in flax: one decoder, nine descriptions.
 
 `TokenDecoder` is a pre-norm decoder as a token policy: observations are
 token ids, the action logits are the language-model head's, and a value head
@@ -170,11 +170,23 @@ Attention, one of:
       frequencies, the rest as they are; `attention_gate` (qwen3_next):
       W_q makes, a head, its query and as many values again, and the
       head's output is multiplied by their sigmoid ahead of W_o;
+      `attention_gate: "head"` (laguna): ONE gate a head beside W_q, W_g
+      [hidden, heads], o_h times sigmoid(n . W_g[:, h]) ahead of W_o;
       causal softmax(q k^T / sqrt(head_dim)) v; W_o. A KIND A LAYER
-      (`window_layout`, `rope_layout`; OLMoE: every layer full and rotary;
-      SmallThinker: a period of four, the first full and without
-      positions, the next three windowed and rotary): rotate-half RoPE on
-      q and k, or nothing; the sum over every s <= t of the episode, or
+      (`layer_kind(i)`, an `AttentionKind`, from `window_layout`,
+      `rope_layout`, `heads_layout` and `rotations`; OLMoE: every layer
+      full and rotary; SmallThinker: a period of four, the first full and
+      without positions, the next three windowed and rotary; laguna,
+      `model_type: laguna`, whose GEOMETRY is the kind's: a period of four,
+      the first full with 48 query heads, rotated over the leading HALF of
+      a head by YaRN's frequencies (`rope_frequencies`: each frequency as
+      it was, or divided by the factor, or a blend of the two by how many
+      turns it makes over the original positions; cos and sin times the
+      attention factor), the next three within a window of 512 with 64
+      query heads under the default rotation over the whole head, all over
+      the same 8 key/value heads of 128; a layer's W_q, W_o and gate have
+      its own kind's heads): rotate-half RoPE on q and k by the kind's
+      rotation, or nothing; the sum over every s <= t of the episode, or
       over those with t - s < `sliding_window`. The cache holds K and V,
       [B, S, key/value heads, head_dim] each a layer, S the context's
       positions in a full layer and a RING of the window in a window
@@ -241,6 +253,9 @@ Router, float32, one of:
   softmax (OLMoE, SmallThinker): p = softmax(n W_r); the k largest p;
       weights are those p as they are unless `norm_topk_prob` (over
       their sum: the softmax over the chosen logits alone).
+  sigmoid without a bias (`sigmoid_router`; laguna): s = sigmoid(n W_r);
+      the k largest s choose and weigh, over their sum, times
+      `routed_scaling_factor`; nothing but parameters, so no "constants".
   sigmoid with a selection bias (`topk_method: noaux_tc`; one group;
   LFM2's `use_expert_bias`):
       s = sigmoid(n W_r); the k largest of s + b choose; weights are s at
@@ -294,7 +309,12 @@ sampler's rule and the MASK id are assumed (the configuration's file lists
 them), and its pre-training noise schedule enters nowhere; qwen3_next's
 next-token module is not built (no key of the config names it), its
 projections' columns lie [q | k | v | z] and [b | a] where the source lays
-them a key head at a time, and its decay's draw is assumed. The OLMoE and
+them a key head at a time, and its decay's draw is assumed; laguna's gate
+is assumed a head's (`gating: true` names no shape; one value a head makes
+the published sizes the published 33.44 B), its router's score the
+DeepSeek-V3 family's sigmoid without a selection bias (no key names either),
+no QK-norm and no bias (none is named), and YaRN's factor on the rotated
+half alone (the configuration's file lists them). The OLMoE and
 glm4_moe_lite descriptions have as many key/value heads as query heads and
 refuse another count (their references have no grouped form; latent
 attention has no key/value heads to group).
@@ -334,7 +354,10 @@ One set of parameters, two forms (the stateful-policy protocol of
   (`models/decode_attention.py`) where the program is lowered for a TPU
   and the window is whole blocks: each block of rows once for both
   products, the blocks up to the furthest position that the rows of a
-  grid step hold.
+  grid step hold. Grouped heads' kernel has two forms by the width of a
+  position's row (`grouped_lanes`): block-diagonal queries against the
+  whole row (up to 512 lanes), or each cached head's queries against its
+  own lanes (laguna's 8 x 128).
 
 Generation by diffusion over blocks (`block_len` L, `denoise_steps` S;
 sdar_moe, `model_type: sdar_moe`: SDAR, arXiv:2510.06303, the objective and
@@ -384,7 +407,7 @@ carry the whole as one pytree.
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -721,6 +744,65 @@ QWEN3_NEXT_FIXED = {
 # Published keys that no part of the decoder reads: the dense feed-forward's
 # width (`mlp_only_layers` is empty and every layer sparse).
 QWEN3_NEXT_UNREAD = ("intermediate_size",)
+LAGUNA_CONFIG_KEYS = {
+    "vocab_size": "vocab_size",
+    "hidden_size": "hidden_size",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "num_hidden_layers": "num_layers",
+    "sliding_window": "sliding_window",
+    "intermediate_size": "dense_width",
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "expert_width",
+    "shared_expert_intermediate_size": "shared_width",
+    "moe_routed_scaling_factor": "routed_scaling_factor",
+    "max_position_embeddings": "context_len",
+    "rms_norm_eps": "rms_eps",
+    # The deployment's: the share of the experts this chip holds.
+    "experts_held": "experts_held",
+    "first_expert_held": "first_expert_held",
+}
+# What Laguna-XS.2's published `config.json` says, for the keys a
+# `custom_model_config` leaves out.
+LAGUNA_PUBLISHED = {
+    "vocab_size": 100352, "hidden_size": 2048, "num_attention_heads": 48,
+    "num_key_value_heads": 8, "head_dim": 128, "num_hidden_layers": 40,
+    "sliding_window": 512, "intermediate_size": 8192, "num_experts": 256,
+    "num_experts_per_tok": 8, "moe_intermediate_size": 512,
+    "shared_expert_intermediate_size": 512,
+    "moe_routed_scaling_factor": 2.5, "max_position_embeddings": 262144,
+    "rms_norm_eps": 1e-6, "partial_rotary_factor": 0.5,
+    "layer_types": ["full_attention"] + ["sliding_attention"] * 3,
+    "mlp_layer_types": ["dense"] + ["sparse"] * 39,
+    "num_attention_heads_per_layer": [48, 64, 64, 64],
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "original_max_position_embeddings": 4096, "beta_slow": 1,
+            "beta_fast": 64, "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {
+            "rope_type": "default", "rope_theta": 10000,
+            "partial_rotary_factor": 1},
+        "original_max_position_embeddings": 4096},
+}
+# The two lists that are a period of four in the published file stand for
+# their repetition over the 40 layers.
+for _key in ("layer_types", "num_attention_heads_per_layer"):
+    LAGUNA_PUBLISHED[_key] = LAGUNA_PUBLISHED[_key] * 10
+LAGUNA_FIXED = {
+    "attention_bias": False, "tie_word_embeddings": False, "gating": True,
+    "moe_apply_router_weight_on_input": False, "model_type": "laguna",
+}
+# A kind's rotation in `rope_parameters`: the keys of each `rope_type`.
+LAGUNA_ROPE_KEYS = {
+    "default": {"rope_type", "rope_theta", "partial_rotary_factor"},
+    "yarn": {"rope_type", "rope_theta", "partial_rotary_factor", "factor",
+             "original_max_position_embeddings", "beta_fast", "beta_slow",
+             "attention_factor"},
+}
 # The MASK id's logit: its probability is exactly 0 in float32, as minus
 # infinity's is, and 0 x it is 0 where the entropy multiplies the two.
 MASK_LOGIT = -1e30
@@ -728,6 +810,27 @@ MASK_LOGIT = -1e30
 # `one_function_layers` alone) names a layer that is its feed-forward and
 # no operator.
 LAYER_TYPES = ("conv", "full_attention", "kda", "mamba2", "experts", "gdn")
+
+
+class Rotation(NamedTuple):
+    """How an attention layer rotates its queries and keys: `rope`'s theta,
+    the share of a head's leading values it rotates, and
+    `rope_frequencies`' scaling (() for none)."""
+    theta: float
+    share: float = 1.0
+    scaling: tuple = ()
+
+
+class AttentionKind(NamedTuple):
+    """An attention layer's geometry (`TokenDecoder.layer_kind`): the window
+    it attends within (0: the whole episode), whether it rotates its
+    queries and keys at all, its query heads, and its rotation."""
+    window: int
+    rotary: bool
+    heads: int
+    rotation: Rotation
+
+
 # The kinds of state a layer may keep between positions, each a key of the
 # policy state beside "pos": caches with a positions axis; a convolution's
 # last inputs; a KDA layer's matrices; a Mamba-2 layer's; a Gated DeltaNet
@@ -754,19 +857,52 @@ def rms_norm(x, weight, eps, dtype, axes=(-1,)):
     return (weight * (x32 * jax.lax.rsqrt(var + eps))).astype(dtype)
 
 
-def rope(x, positions, theta, scale=1.0, head_major=False):
-    """Rotate-half RoPE, times `scale` before the cast back to x's dtype.
+def rope_frequencies(dim: int, theta, scaling=()):
+    """(the `dim // 2` angles a position of a rotation over `dim` values,
+    the factor on its cos and sin). The default rotation: theta ** (-2 i /
+    dim), factor 1. `scaling` = (factor F, original positions P, beta_fast,
+    beta_slow, attention factor), YaRN (arXiv:2309.00071; `rope_type:
+    yarn`): frequency i stays as it was where it makes more than beta_fast
+    turns over the P original positions (extrapolated), is divided by F
+    where it makes fewer than beta_slow (interpolated), and is blended by a
+    linear ramp over the indices between, from low = floor(c(beta_fast)) to
+    high = ceil(c(beta_slow)), c(r) = dim ln(P / (2 pi r)) / (2 ln theta)
+    the index that makes r turns; cos and sin are multiplied by the
+    attention factor."""
+    inv_freq = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if not scaling:
+        return inv_freq, 1.0
+    factor, original, beta_fast, beta_slow, attention_factor = scaling
+
+    def turns(r):
+        return dim * np.log(original / (2 * np.pi * r)) / (2 * np.log(theta))
+    low = max(int(np.floor(turns(beta_fast))), 0)
+    high = min(int(np.ceil(turns(beta_slow))), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    # ramp 0: as it was; 1: every position `factor` times nearer.
+    return (inv_freq / factor * ramp + inv_freq * (1.0 - ramp),
+            float(attention_factor))
+
+
+def rope(x, positions, theta, scale=1.0, head_major=False, scaling=()):
+    """Rotate-half RoPE at `rope_frequencies(.., theta, scaling)`, times
+    `scale` before the cast back to x's dtype.
     x: [..., heads, head_dim] with positions x.shape[:-2]; or, head-major,
     [..., heads, T, head_dim] with positions [..., T]."""
     dim = x.shape[-1]
-    inv_freq = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    inv_freq, factor = rope_frequencies(dim, theta, scaling)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     angles = jnp.concatenate([angles, angles], axis=-1)
     angles = angles[..., None, :, :] if head_major else angles[..., None, :]
     x32 = x.astype(jnp.float32)
     half = dim // 2
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    out = x32 * jnp.cos(angles) + rotated * jnp.sin(angles)
+    if factor == 1.0:
+        out = x32 * jnp.cos(angles) + rotated * jnp.sin(angles)
+    else:
+        out = (x32 * (jnp.cos(angles) * factor)
+               + rotated * (jnp.sin(angles) * factor))
     return (out if scale == 1.0 else out * scale).astype(x.dtype)
 
 
@@ -789,23 +925,26 @@ def swiglu(n, w_gate, w_up, w_down, act=jax.nn.silu):
     return jnp.dot(act(jnp.dot(n, w_gate)) * jnp.dot(n, w_up), w_down)
 
 
-def route(n, router, k, renormalise, bias=None, scale=1.0, eps=0.0):
+def route(n, router, k, renormalise, bias=None, scale=1.0, eps=0.0,
+          sigmoid=False):
     """Float32 router: (weights [M, k], experts [M, k]) for rows n [M, H].
-    Without `bias`: softmax, the k largest. With `bias` [E]: sigmoid
-    scores, the k largest of score + bias choose, the weights are the
-    scores alone; the bias is a constant here. Weights are divided by
-    their sum (plus `eps`, where the description has one) where
-    `renormalise`, then times `scale`."""
+    Softmax, the k largest; or (`sigmoid`, or with `bias` [E]) sigmoid
+    scores, the k largest of the scores (plus `bias` where given, a
+    constant here) choose, and the weights are the scores alone. Weights
+    are divided by their sum (plus `eps`, where the description has one)
+    where `renormalise`, then times `scale`."""
     with jax.named_scope("policy/router"):
         logits = jnp.dot(n.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        if bias is None:
-            top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        else:
+        if bias is not None:
             scores = jax.nn.sigmoid(logits)
             _, top_i = jax.lax.top_k(
                 scores + jax.lax.stop_gradient(bias), k)
             top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        elif sigmoid:
+            top_p, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+        else:
+            top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
         if renormalise:
             total = jnp.sum(top_p, axis=-1, keepdims=True)
             top_p = top_p / (total + eps if eps else total)
@@ -913,7 +1052,17 @@ def cached_attention(q, k_cache, v_cache, pos, scale=None, value_dim=None):
     products over [B, S, 8, 64] 1.471 / 1.471 (each cached row half a lane
     tile), the kernel 0.427 / 0.757 (709 GB/s of 819); 16 rows, 28 over 4
     of 128, a cache of 8,192: 0.387 against 0.195 / 0.367; a ring of
-    4,096: 0.205 against 0.105 / 0.190. Everywhere else the two products
+    4,096: 0.205 against 0.105 / 0.190. A position's row of 8 x 128 =
+    1,024 lanes (laguna; 32 rows, 64 query heads; my chip run, PR 56): the
+    kernel in the form that scores a cached head against its own lanes
+    (`grouped_lanes`, `decode_attention.lanes_kernel`), ms a step, XLA /
+    block-diagonal / own lanes at blocks of 128 x 16 rows: a ring of 512
+    held whole 0.1154 / 0.1332 / 0.1118, a cache of 8,192 at position 4,064
+    1.7156 / 0.7654 / 0.7441, held whole 1.7159 / 1.4641 / 1.4423; own
+    lanes at 128 x 8 0.1101 / 0.7539 / 1.4406, 128 x 32 0.1161 / 0.7391 /
+    1.4466, 256 x 8 0.1115 / 0.7521 / 1.4419, 256 x 16 0.1146 / 0.7465 /
+    1.4454, 512 x 8 0.1140 / 0.7610 / 1.4447, 64 x 16 0.1107 / 0.7483 /
+    1.4412: the file's 128 x 16 stays. Everywhere else the two products
     over the whole cache (`decode_attention.attend_grouped`)."""
     S = k_cache.shape[1]
     f32 = jnp.float32
@@ -962,6 +1111,29 @@ def grouped_fused(S: int, groups: int, heads: int, d: int) -> bool:
             and (groups * d) % 128 == 0 and d % 64 == 0)
 
 
+def grouped_lanes(groups: int, d: int) -> bool:
+    """Whether the kernel form of grouped heads' caches scores a cached
+    head against its own lanes of a fetched row
+    (`decode_attention.lanes_kernel`) or all the heads of a row against the
+    whole row with block-diagonal queries (`grouped_kernel`): a function of
+    the static shape alone. Its own lanes where a cached head is whole lane
+    tiles (the kernel cuts a fetched row where the tiles' edges are) and a
+    position's row is wider than the 512 lanes the block-diagonal form was
+    measured at (8 x 64, 4 x 128, 2 x 256: those keep it). At 8 x 128 =
+    1,024 lanes the block-diagonal queries multiply 8 x the owed products
+    and stop hiding behind the bytes (laguna: 32 rows, 64 or 48 query heads
+    over 8 cached heads of 128, one layer's caches in a scan that writes a
+    row a step; ms a step at 64 heads, XLA's two products / block-diagonal
+    / own lanes; my chip run, PR 56): a ring of 512 held whole 0.1154 /
+    0.1332 / 0.1118; a cache of 8,192 at position 1,024 1.7155 / 0.2485 /
+    0.2278, at 4,064 1.7156 / 0.7654 / 0.7441, held whole 1.7159 / 1.4641 /
+    1.4423 (745 GB/s of 819); 48 heads read the same to 1 % (0.1152 /
+    0.1276 / 0.1112 and 1.6424 / 1.4583 / 1.4418). Blocks of 64-512 x 8-32
+    rows a grid step moved either kernel by under 1.5 % (`cached_attention`
+    has the table)."""
+    return d % 128 == 0 and groups * d > 512
+
+
 def _grouped_attention(q, k_cache, v_cache, pos, scale):
     """`cached_attention` of grouped heads' caches: (o, the positions
     read)."""
@@ -975,9 +1147,11 @@ def _grouped_attention(q, k_cache, v_cache, pos, scale):
             q, k_cache, v_cache, lengths, scale), jnp.asarray(S, jnp.float32)
 
     def kernel(q, k_cache, v_cache, lengths):
-        return decode_attention.grouped_decode_attention(
-            q, k_cache, v_cache, lengths,
-            scale), decode_attention.positions_fetched(lengths)
+        attend = (decode_attention.lanes_decode_attention
+                  if grouped_lanes(groups, q.shape[2])
+                  else decode_attention.grouped_decode_attention)
+        return attend(q, k_cache, v_cache, lengths,
+                      scale), decode_attention.positions_fetched(lengths)
     if grouped_fused(S, groups, q.shape[1], q.shape[2]):
         return jax.lax.platform_dependent(
             q, k_cache, v_cache, lengths, tpu=kernel, default=whole)
@@ -2447,9 +2621,18 @@ class TokenDecoder(nn.Module):
     # frequencies over that many, not over the head); the rest pass as they
     # are.
     partial_rotary_factor: float = 1.0
-    # W_q yields, a head, its query and as many values again whose sigmoid
-    # multiplies the head's output ahead of W_o.
-    attention_gate: bool = False
+    # Where the attention layers differ in their geometry (laguna), a
+    # layer's own, read as the two layouts below are: its query heads
+    # (`heads_layout`; empty: `num_heads` in every layer) and its rotation
+    # (`rotations`, an entry (theta, the share of a head rotated, scaling:
+    # `rope_frequencies`'s, () for none); empty: `rope_theta` over
+    # `partial_rotary_factor`, unscaled, in every layer).
+    heads_layout: tuple = ()
+    rotations: tuple = ()
+    # True: W_q yields, a head, its query and as many values again whose
+    # sigmoid multiplies the head's output ahead of W_o. "head": ONE gate a
+    # head, sigmoid(n . W_g[:, h]), W_g [hidden, heads] beside W_q.
+    attention_gate: Any = False
     # Every norm of the hidden vector and of a head's queries and keys is
     # x / rms(x) * (1 + w), w 0 at initialisation (an operator's own output
     # norm keeps a plain weight).
@@ -2514,9 +2697,11 @@ class TokenDecoder(nn.Module):
     hidden_act: str = "silu"  # the gate's, in every gated feed-forward
     # False: no gate matrix, W_down act(W_up n), experts and shared alike.
     gated_feed_forward: bool = True
-    # Router: softmax, or sigmoid with a selection bias; on the block's
-    # post-attention norm, or on the attention's own normalised input.
+    # Router: softmax, or sigmoid scores (`sigmoid_router`; with a
+    # selection bias they always are); on the block's post-attention norm,
+    # or on the attention's own normalised input.
     selection_bias: bool = False
+    sigmoid_router: bool = False
     router_before_attention: bool = False
     norm_topk_prob: bool = False
     topk_eps: float = 0.0  # beside the chosen weights' sum, where divided
@@ -2569,16 +2754,31 @@ class TokenDecoder(nn.Module):
         "kda" where it is Kimi Delta Attention, "gdn" where it is Gated
         DeltaNet, "mamba2" where it is the state-space layer, "experts"
         where the layer is its feed-forward alone; of an attention layer
-        (the window it attends within, 0 for
-        the whole episode; whether its queries and keys are rotated)."""
+        its geometry, an `AttentionKind`: the window it attends within (0
+        for the whole episode), whether its queries and keys are rotated,
+        its query heads and its rotation."""
         if self.layer_types and self.layer_types[i] != "full_attention":
             if self.layer_types[i] not in LAYER_TYPES:
                 raise ValueError(f"layer type {self.layer_types[i]!r}: "
                                  f"TokenDecoder has {LAYER_TYPES}")
             return self.layer_types[i]
         window = bool(self.window_layout) and bool(self.window_layout[i])
-        return (self.sliding_window if window else 0,
-                not self.rope_layout or bool(self.rope_layout[i]))
+        return AttentionKind(
+            self.sliding_window if window else 0,
+            not self.rope_layout or bool(self.rope_layout[i]),
+            self.heads_layout[i] if self.heads_layout else self.num_heads,
+            Rotation(*self.rotations[i]) if self.rotations
+            else self._rotation)
+
+    @property
+    def _rotation(self):
+        """The model's one rotation, where the layers do not differ."""
+        return Rotation(self.rope_theta, self.partial_rotary_factor)
+
+    @property
+    def _plain_kind(self):
+        """The kind of a full, rotated layer of `num_heads`."""
+        return AttentionKind(0, True, self.num_heads, self._rotation)
 
     @property
     def attention_layers(self) -> tuple:
@@ -2618,12 +2818,14 @@ class TokenDecoder(nn.Module):
         window = self.layer_kind(i)[0]
         return min(window or self.context_len, self.context_len)
 
-    def _layer_shapes(self, dense: bool, kind=(0, True)) -> tuple:
+    def _layer_shapes(self, dense: bool, kind=None) -> tuple:
         """`attn_norm` is the norm ahead of the layer's operator, whichever
-        that is (`kind`: `layer_kind`'s), `mlp_norm` the one ahead of its
-        feed-forward; a layer of `one_function_layers` has one of the
-        two."""
-        H, heads = self.hidden_size, self.num_heads
+        that is (`kind`: `layer_kind`'s; a full layer of `num_heads` where
+        not given), `mlp_norm` the one ahead of its feed-forward; a layer
+        of `one_function_layers` has one of the two."""
+        kind = kind or self._plain_kind
+        H = self.hidden_size
+        heads = self.num_heads if isinstance(kind, str) else kind.heads
         feed_forward = not self.one_function_layers or kind == "experts"
         norm = "centred" if self.zero_centred_norms else "ones"
         shapes = [("attn_norm", norm, (H,))] * (kind != "experts") + [
@@ -2693,10 +2895,14 @@ class TokenDecoder(nn.Module):
                            ("k_norm", norm, (self.head_width,))]
             elif self.qk_norm:
                 shapes += [("q_norm", norm, (q,)), ("k_norm", norm, (kv,))]
-            # A head's query, and with a gate as many values again.
-            shapes += [("wq", "dense", (H, q * (1 + self.attention_gate))),
+            # A head's query, and with a gate as many values again, or a
+            # gate a head beside it.
+            shapes += [("wq", "dense",
+                        (H, q * (1 + (self.attention_gate is True)))),
                        ("wk", "dense", (H, kv)),
                        ("wv", "dense", (H, kv)), ("wo", "dense", (q, H))]
+            if self.attention_gate == "head":
+                shapes.append(("wg", "dense", (H, heads)))
         if not feed_forward:
             return tuple(shapes)
         if dense:
@@ -2728,10 +2934,17 @@ class TokenDecoder(nn.Module):
                 f"experts {self.first_expert_held} .. "
                 f"{self.first_expert_held + self.held - 1} are not among "
                 f"the router's {self.num_experts}")
-        if self.num_heads % self.kv_heads:
+        for heads in {self.num_heads, *self.heads_layout[:self.num_layers]}:
+            if heads % self.kv_heads:
+                raise ValueError(
+                    f"{heads} query heads do not fall into "
+                    f"{self.kv_heads} key/value heads' groups")
+        if (self.heads_layout or self.rotations) and (
+                self.kv_lora_rank or self.block_len or self.nextn_layers):
             raise ValueError(
-                f"{self.num_heads} query heads do not fall into "
-                f"{self.kv_heads} key/value heads' groups")
+                "heads and rotations by layer are those of layers of a "
+                "head's own keys and values, one token a step, without a "
+                "next-next-token module")
         kinds = self.layer_types[:self.num_layers]
         if "experts" in kinds and not self.one_function_layers:
             raise ValueError(
@@ -2934,7 +3147,8 @@ class TokenDecoder(nn.Module):
                 kernel = (platform == "tpu"
                           and self.kv_heads != self.num_heads and all(
                               grouped_fused(self.cache_len(i), self.kv_heads,
-                                            self.num_heads, self.head_width)
+                                            self.layer_kind(i).heads,
+                                            self.head_width)
                               for i in attention))
         if kernel:
             block = decode_attention.BLOCK
@@ -3025,12 +3239,13 @@ class TokenDecoder(nn.Module):
         return self.causal(obs, reset)
 
     # -- attention, both kinds, both forms --------------------------------
-    def _qkv(self, lp, n):
-        """(q, k, v) by head of rows n; with `attention_gate` a fourth, the
-        values whose sigmoid multiplies a head's output: a head's columns
-        of W_q are its query's, then its gate's."""
+    def _qkv(self, lp, n, kind):
+        """(q, k, v) by head of rows n of a layer of `kind`; with
+        `attention_gate` a fourth, the values whose sigmoid multiplies a
+        head's output: a head's columns of W_q are its query's, then its
+        gate's; or ("head") one value a head, n W_g."""
         cd, eps = self.compute_dtype, self.rms_eps
-        heads = n.shape[:-1] + (self.num_heads, -1)
+        heads = n.shape[:-1] + (kind.heads, -1)
         groups = n.shape[:-1] + (self.kv_heads, -1)
 
         def projected(w, norm):
@@ -3041,7 +3256,9 @@ class TokenDecoder(nn.Module):
         v = jnp.dot(n, lp["wv"].astype(cd))
         q, k = q.reshape(heads), k.reshape(groups)
         gate = ()
-        if self.attention_gate:
+        if self.attention_gate == "head":
+            gate = (jnp.dot(n, lp["wg"].astype(cd))[..., None],)
+        elif self.attention_gate:
             q, *gate = jnp.split(q, 2, axis=-1)
         if self.qk_norm == "head":
             q = rms_norm(q, lp["q_norm"], eps, cd)
@@ -3054,19 +3271,21 @@ class TokenDecoder(nn.Module):
             return (o.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(self.compute_dtype)
 
-    def _rotate(self, x, positions, scale=1.0, head_major=False):
-        """`rope` of the leading `partial_rotary_factor` of a head's values
-        (all of them as a rule), the rest as they are; everything times
-        `scale` in float32."""
-        rotated = int(x.shape[-1] * self.partial_rotary_factor)
-        if rotated == x.shape[-1]:
-            return rope(x, positions, self.rope_theta, scale, head_major)
-        rest = x[..., rotated:]
-        if scale != 1.0:
-            rest = (rest.astype(jnp.float32) * scale).astype(x.dtype)
-        return jnp.concatenate([
-            rope(x[..., :rotated], positions, self.rope_theta, scale,
-                 head_major), rest], axis=-1)
+    def _rotate(self, x, positions, rotation, scale=1.0, head_major=False):
+        """`rope` by a layer's `rotation`: of the leading share of a head's
+        values (all of them as a rule), the rest as they are; everything
+        times `scale` in float32."""
+        theta, share, scaling = rotation
+        rotated = int(x.shape[-1] * share)
+        with jax.named_scope("policy/rope"):
+            if rotated == x.shape[-1]:
+                return rope(x, positions, theta, scale, head_major, scaling)
+            rest = x[..., rotated:]
+            if scale != 1.0:
+                rest = (rest.astype(jnp.float32) * scale).astype(x.dtype)
+            return jnp.concatenate([
+                rope(x[..., :rotated], positions, theta, scale, head_major,
+                     scaling), rest], axis=-1)
 
     def _attention_scope(self, window: int) -> str:
         """The name a layer's own-heads attention has in a trace: by its
@@ -3128,11 +3347,13 @@ class TokenDecoder(nn.Module):
         return w[..., :self.qk_nope_head_dim], w[..., self.qk_nope_head_dim:]
 
     def _attend_causal(self, lp, x, positions, episode, cache_rows,
-                       window=0, rotary=True, streams=0, unread=0):
+                       kind=None, streams=0, unread=0):
         """x + Attention(RMSNorm(x)) over a fragment [B, T, H] from an
         empty window; (h, the layer's caches: the rows `cache_rows` of the
-        fragment). A head's own keys: within `window` positions where
-        given, rotated where `rotary`; with `streams`, the fragment is a
+        fragment). A head's own keys: the geometry `kind`'s (a full,
+        rotated layer of `num_heads` where not given: within its window
+        where it has one, rotated where it rotates, by its rotation, as
+        many query heads as it has); with `streams`, the fragment is a
         block-diffusion learner's (`block_causal`: that many streams of T /
         streams positions, read by `block_stream_attention`) and hands over
         no caches; of such a fragment's first `unread` positions (its last
@@ -3145,7 +3366,8 @@ class TokenDecoder(nn.Module):
         over (head, d) as it lies, so that no transposed copy of any of
         them is made on the way."""
         cd, eps = self.compute_dtype, self.rms_eps
-        H, heads = x.shape[-1], self.num_heads
+        window, rotary, heads, rotation = kind or self._plain_kind
+        H = x.shape[-1]
 
         def by_head(n, w, heads=heads):
             """n [B, T, r] W [r, heads * d] -> [B, heads, T, d]."""
@@ -3177,19 +3399,22 @@ class TokenDecoder(nn.Module):
                     # QK-norm over the whole projection: heads and d.
                     return rms_norm(a, lp[norm].reshape(heads, 1, -1), eps,
                                     cd, axes=(1, 3))
-                if self.attention_gate:
+                if self.attention_gate is True:
                     q, gate = jnp.split(
                         by_head(asking(n), lp["wq"], heads), 2, axis=-1)
                     q = normed(q, "q_norm", heads)
                 else:
                     q = projected("wq", "q_norm", heads, asking(n))
+                if self.attention_gate == "head":
+                    gate = jnp.einsum("btr,rh->bht", asking(n),
+                                      lp["wg"].astype(cd))[..., None]
                 k = projected("wk", "k_norm", groups)
                 scale = q.shape[-1] ** -0.5
                 if rotary:
                     # The softmax's scale goes onto q in RoPE's float32.
-                    q = self._rotate(q, asking(positions), scale,
+                    q = self._rotate(q, asking(positions), rotation, scale,
                                      head_major=True)
-                    k = self._rotate(k, positions, head_major=True)
+                    k = self._rotate(k, positions, rotation, head_major=True)
                     scale = 1.0
                 v = by_head(n, lp["wv"], groups)
                 if streams:
@@ -3244,24 +3469,26 @@ class TokenDecoder(nn.Module):
             h = joined(o)
         return h, caches
 
-    def _attend_step(self, lp, x, pos, caches, window=0, rotary=True):
+    def _attend_step(self, lp, x, pos, caches, kind):
         """x + Attention(RMSNorm(x)) of one token a row, x [B, H], against
         the layer's caches, this position written first; (h, the caches,
-        the positions read). A window layer's caches are a ring: position
+        the positions read); `kind`: `_attend_causal`'s. A window layer's
+        caches are a ring: position
         p is written to slot p mod its length, over position p - length,
         which has just left the window; keys are rotated before they are
         cached, so the ring is read in whatever order it lies."""
         cd, eps = self.compute_dtype, self.rms_eps
         B = x.shape[0]
         rows = jnp.arange(B)
+        window, rotary, _, rotation = kind
         if not self.kv_lora_rank:
             k_cache, v_cache = caches
             with jax.named_scope(self._attention_scope(window)):
                 n = rms_norm(x, lp["attn_norm"], eps, cd)
-                q, k, v, *gate = self._qkv(lp, n)
+                q, k, v, *gate = self._qkv(lp, n, kind)
                 if rotary:
-                    q = self._rotate(q, pos)
-                    k = self._rotate(k, pos)
+                    q = self._rotate(q, pos, rotation)
+                    k = self._rotate(k, pos, rotation)
                 slot = pos % k_cache.shape[1] if window else pos
                 # Grouped heads' caches are stored flat: a position's row
                 # is written whole, and read through its view by head.
@@ -3565,7 +3792,8 @@ class TokenDecoder(nn.Module):
     def _route(self, lp, n):
         return route(
             n, lp["router"], self.experts_per_token, self.norm_topk_prob,
-            lp.get("router_bias"), self.routed_scaling_factor, self.topk_eps)
+            lp.get("router_bias"), self.routed_scaling_factor, self.topk_eps,
+            self.sigmoid_router)
 
     def _route_ahead(self, lp, x):
         """The routing of rows x [.., H] where the router reads the
@@ -3706,7 +3934,7 @@ class TokenDecoder(nn.Module):
             held = last - jnp.mod(last - jnp.arange(R), R)
             return jnp.clip(start[:, -1:] + held, 0, T - 1)
 
-        def block(lp, x, kind=(0, True)):
+        def block(lp, x, kind):
             """One layer; `caches` are its caches, or the convolution's
             state, or KDA's or Mamba-2's two (nothing where the layer is
             its feed-forward alone)."""
@@ -3724,7 +3952,7 @@ class TokenDecoder(nn.Module):
             else:
                 rows = ring_rows(min(kind[0], S)) if kind[0] else cache_rows
                 h, caches = self._attend_causal(
-                    lp, x, positions, episode, rows, *kind)
+                    lp, x, positions, episode, rows, kind)
             if "mlp_norm" not in lp:  # the operator alone
                 return h, caches, None, None
             out, load, top_i = self._feed_forward(
@@ -3787,7 +4015,7 @@ class TokenDecoder(nn.Module):
                 rms_norm(jax.lax.stop_gradient(x), lp["hnorm"], eps, cd),
                 rms_norm(embed[following], lp["enorm"], eps, cd)], axis=-1),
                 lp["eh_proj"].astype(cd))
-        z, _, load, top_i = block(lp, z, (0, True))
+        z, _, load, top_i = block(lp, z, self._plain_kind)
         with jax.named_scope("policy/mtp"):
             y = rms_norm(z, lp["final_norm"], eps, jnp.float32)
             logp = jax.nn.log_softmax(
@@ -3829,7 +4057,7 @@ class TokenDecoder(nn.Module):
                     lp, x, state["conv"][i], state["gdn"][i], reset)
             else:
                 h, caches, reads[i] = self._attend_step(
-                    lp, x, pos, caches, *kind)
+                    lp, x, pos, caches, kind)
             held["kv"].append(caches)
             held["conv"].append(tails)
             for key, of in MATRIX_STATES.items():
@@ -3874,7 +4102,7 @@ class TokenDecoder(nn.Module):
                             for c in caches)
         with jax.named_scope("policy/block_attention"):
             n = rms_norm(x, lp["attn_norm"], eps, cd)
-            q, k, v = self._qkv(lp, n)
+            q, k, v = self._qkv(lp, n, self._plain_kind)
             positions = pos[:, None] + jnp.arange(L)
             q = rope(q, positions, self.rope_theta)
             k = rope(k, positions, self.rope_theta).reshape(N, L, -1)
@@ -4365,6 +4593,94 @@ def qwen3_next_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
             for i in range(fields["num_layers"])),
         qk_norm="head", attention_gate=True, zero_centred_norms=True,
         shared_experts=1, shared_expert_gate=True)
+    if compute_dtype is not None:
+        fields["compute_dtype"] = compute_dtype
+    return TokenDecoder(num_outputs=num_outputs, **fields)
+
+
+def _laguna_rotation(kind: str, rope: dict) -> tuple:
+    """A `rotations` entry from `rope_parameters[kind]`."""
+    known = LAGUNA_ROPE_KEYS.get(rope.get("rope_type", "default"))
+    if known is None:
+        raise ValueError(
+            f"rope_parameters[{kind!r}] rope_type {rope['rope_type']!r}: "
+            f"TokenDecoder has {sorted(LAGUNA_ROPE_KEYS)}")
+    _refuse_unknown(rope, known, f"laguna's rope_parameters[{kind!r}]")
+    scaling = ()
+    if rope.get("rope_type") == "yarn":
+        factor = rope["factor"]
+        # The family's default where the file gives none: 0.1 ln F + 1.
+        scaling = (factor, rope["original_max_position_embeddings"],
+                   rope.get("beta_fast", 32), rope.get("beta_slow", 1),
+                   rope.get("attention_factor")
+                   or 0.1 * float(np.log(factor)) + 1.0)
+    return (rope["rope_theta"], rope.get("partial_rotary_factor", 1.0),
+            scaling)
+
+
+def laguna_from_config(num_outputs: int, cfg: dict, compute_dtype=None):
+    """`TokenDecoder` from a `custom_model_config` that speaks `laguna`'s
+    published `config.json`'s own keys (a key left out has Laguna-XS.2's
+    value), and the two that state the chip's share of the experts; unknown
+    keys are refused, and so is a published key whose value the decoder has
+    no part for (a bias, a tied head, no gate, the router's weight on an
+    expert's input, a rotation of another type, a dense layer after a
+    sparse one). The family's parts: an attention layer's GEOMETRY by its
+    kind in `layer_types` (the leading `num_hidden_layers` entries are
+    read): "full_attention" over the whole episode or "sliding_attention"
+    within `sliding_window`, `num_attention_heads_per_layer[i]` query heads
+    over the same key/value heads, rotated as `rope_parameters` says of its
+    kind (the default rotation, or YaRN, over that kind's share of a head);
+    one sigmoid gate a head on the attention's output (`gating`; W_g
+    [hidden, heads]: the shape that makes the published sizes the published
+    count); no QK-norm; the leading "dense" layers of `mlp_layer_types` a
+    dense SwiGLU, the others sigmoid-routed experts WITHOUT a selection
+    bias, the chosen scores over their sum times
+    `moe_routed_scaling_factor`, beside one shared expert of a width of its
+    own without a gate; an untied head."""
+    known = (set(LAGUNA_CONFIG_KEYS) | set(LAGUNA_FIXED) | {
+        "layer_types", "mlp_layer_types", "num_attention_heads_per_layer",
+        "rope_parameters", "partial_rotary_factor"})
+    _refuse_unknown(cfg, known, "laguna")
+    _refuse_other_values(cfg, LAGUNA_FIXED)
+    merged = {**LAGUNA_PUBLISHED, **cfg}
+    fields = {LAGUNA_CONFIG_KEYS[k]: v for k, v in merged.items()
+              if k in LAGUNA_CONFIG_KEYS}
+    layers = fields["num_layers"]
+    kinds = list(merged["layer_types"])
+    heads = list(merged["num_attention_heads_per_layer"])
+    feeds = list(merged["mlp_layer_types"])
+    for name, listed, allowed in (
+            ("layer_types", kinds, ("full_attention", "sliding_attention")),
+            ("num_attention_heads_per_layer", heads, None),
+            ("mlp_layer_types", feeds, ("dense", "sparse"))):
+        if len(listed) < layers or (allowed and set(listed) - set(allowed)):
+            raise ValueError(
+                f"{name} names each of the {layers} layers"
+                + (f" by {allowed}" if allowed else "") + f": {listed}")
+    dense = feeds[:layers].index("sparse") if "sparse" in feeds[:layers] \
+        else layers
+    if "dense" in feeds[dense:layers]:
+        raise ValueError(
+            "TokenDecoder's dense layers are the leading ones; "
+            f"mlp_layer_types {feeds[:layers]}")
+    rope = dict(merged["rope_parameters"])
+    rope.pop("original_max_position_embeddings", None)  # yarn's, said twice
+    _refuse_unknown(rope, ("full_attention", "sliding_attention"),
+                    "laguna's rope_parameters")
+    rotation = {kind: _laguna_rotation(kind, rope[kind])
+                for kind in set(kinds[:layers])}
+    if merged["partial_rotary_factor"] != rope["full_attention"].get(
+            "partial_rotary_factor", 1.0):
+        raise ValueError(
+            "partial_rotary_factor is the full layers' share of a head, "
+            "which rope_parameters states too; they differ")
+    fields.update(
+        window_layout=tuple(k == "sliding_attention" for k in kinds),
+        heads_layout=tuple(heads),
+        rotations=tuple(rotation[k] for k in kinds[:layers]),
+        dense_layers=dense, qk_norm=False, attention_gate="head",
+        shared_experts=1, sigmoid_router=True, norm_topk_prob=True)
     if compute_dtype is not None:
         fields["compute_dtype"] = compute_dtype
     return TokenDecoder(num_outputs=num_outputs, **fields)

@@ -212,10 +212,10 @@ KERNEL_BLOCK = 8
 @pytest.fixture
 def kernel_here(monkeypatch):
     """A program lowered for this CPU takes the branch a TPU's would, the
-    decode attention's kernels (`models/decode_attention.py`: a step's and
-    a block step's) run by the Pallas interpreter over blocks of
-    `KERNEL_BLOCK` positions, two rows a grid step; the rules take a test's
-    widths."""
+    decode attention's kernels (`models/decode_attention.py`: a step's, in
+    both of its grouped forms, and a block step's) run by the Pallas
+    interpreter over blocks of `KERNEL_BLOCK` positions, two rows a grid
+    step; the rules take a test's widths."""
     import functools
 
     from ray_tpu.models import decode_attention, transformer
@@ -228,6 +228,8 @@ def kernel_here(monkeypatch):
         decode_attention.prefix_kernel, interpret=True))
     monkeypatch.setattr(decode_attention, "block_kernel", functools.partial(
         decode_attention.block_kernel, interpret=True))
+    monkeypatch.setattr(decode_attention, "lanes_kernel", functools.partial(
+        decode_attention.lanes_kernel, interpret=True))
     monkeypatch.setattr(transformer, "decode_fused", whole_blocks)
     monkeypatch.setattr(transformer, "grouped_fused", whole_blocks)
     monkeypatch.setattr(transformer, "block_fused", whole_blocks)

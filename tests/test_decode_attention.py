@@ -391,11 +391,29 @@ def test_decode_fused_is_a_rule_of_the_static_shape(S, R, d_qk, value_dim,
     (4096, 2, 64, 64, True),     # one lane tile a position
     (32768, 8, 64, 128, True),
     (4096, 2, 16, 256, True),    # the eighth token cell's: heads of 256
+    (8192, 8, 48, 128, True),    # the ninth's full caches, 1,024 lanes
+    (512, 8, 64, 128, True),     # and its rings, four blocks
 ])
 def test_grouped_fused_is_a_rule_of_the_static_shape(S, groups, heads, d,
                                                      fused):
     assert decode_attention.BLOCK == 128
     assert grouped_fused(S, groups, heads, d) == fused
+
+
+@pytest.mark.parametrize("groups,d,lanes", [
+    (8, 128, True),     # the ninth token cell's row of 1,024 lanes
+    (4, 128, False),    # the third's and the seventh's 512: block-diagonal
+    (8, 64, False),     # the fourth's: heads of half a tile cannot be cut
+    (2, 256, False),    # the eighth's 512
+    (16, 64, False),    # 1,024 lanes of half tiles
+    (4, 256, True),
+])
+def test_grouped_lanes_is_a_rule_of_the_static_shape(groups, d, lanes):
+    """Which of the kernel's two forms grouped caches take: a cached head
+    against its own lanes where a position's row is wider than the 512
+    lanes the block-diagonal form was measured at and a head is whole
+    tiles; the four older cells keep the form they have."""
+    assert transformer.grouped_lanes(groups, d) == lanes
 
 
 @pytest.mark.parametrize("platform,S,kernel_there", [
@@ -682,6 +700,10 @@ def test_the_kernel_compiles_for_a_v5e_at_the_cell_s_widths(rows, one_chip):
                                  # positions folded into the 32 heads' rows
     (32, 16, 2, 256, 4096),    # the eighth's rollout: 8 query heads a
     (2, 16, 2, 256, 4096),     # cached head of 256; its bootstrap step
+    (32, 48, 8, 128, 8192),    # the ninth's full layers: 6 query heads a
+    (32, 64, 8, 128, 512),     # cached head of 128; its rings: 8 a head
+    (1, 64, 8, 128, 512),      # its bootstrap step (a cached head against
+                               # its own lanes: `lanes_attention`)
 ])
 def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
         rows, heads, groups, d, S, one_chip):
@@ -698,6 +720,8 @@ def test_the_grouped_form_compiles_for_a_v5e_at_the_cells_widths(
         shaped(one_chip, rows, dtype=jnp.int32)).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert "tpu_custom_call" in compiled
+    assert ("lanes_attention" in compiled) == transformer.grouped_lanes(
+        groups, d)
     assert not cache_copies(compiled, rows, S)
 
 

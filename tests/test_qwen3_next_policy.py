@@ -773,7 +773,7 @@ def test_keys_left_out_have_the_published_model_s_values():
     kinds = [model.layer_kind(i) for i in range(48)]
     assert kinds.count("gdn") == 36 and model.attention_layers == tuple(
         range(3, 48, 4))
-    assert all(kind == (0, True) for kind in kinds if kind != "gdn")
+    assert all(kind[:2] == (0, True) for kind in kinds if kind != "gdn")
     assert (model.num_heads, model.kv_heads, model.head_width,
             model.partial_rotary_factor, model.gdn_key_heads,
             model.gdn_value_heads, model.gdn_key_dim, model.gdn_value_dim,

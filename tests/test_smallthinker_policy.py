@@ -631,7 +631,7 @@ def test_a_layout_longer_than_the_depth_is_read_from_its_head():
         "custom_model": "smallthinker", "custom_model_config": dict(
             NET, sliding_window_layout=[0, 1, 1, 1] * 13,
             rope_layout=[0, 1, 1, 1] * 13)})
-    assert [model.layer_kind(i) for i in range(4)] == [
+    assert [model.layer_kind(i)[:2] for i in range(4)] == [
         (0, False), (WINDOW, True), (WINDOW, True), (WINDOW, True)]
     assert [model.cache_len(i) for i in range(4)] == [S, 8, 8, 8]
 
