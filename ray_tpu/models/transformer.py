@@ -186,7 +186,11 @@ Attention, one of:
       query heads under the default rotation over the whole head, all over
       the same 8 key/value heads of 128; a layer's W_q, W_o and gate have
       its own kind's heads): rotate-half RoPE on q and k by the kind's
-      rotation, or nothing; the sum over every s <= t of the episode, or
+      rotation, or nothing (a learner's pass, head-major rows of whole
+      tiles in a program for a TPU: the rotation with the softmax's scale,
+      and the gate, each ONE pass over a head's rows, `models/rowwise.py`,
+      kernels with their own pullbacks; every other form `rope` and XLA's
+      fusions); the sum over every s <= t of the episode, or
       over those with t - s < `sliding_window`. The cache holds K and V,
       [B, S, key/value heads, head_dim] each a layer, S the context's
       positions in a full layer and a RING of the window in a window
@@ -414,7 +418,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import decode_attention, expert_step, state_step
+from ray_tpu.models import (
+    decode_attention, expert_step, rowwise, state_step)
 
 Dtype = Any
 
@@ -3094,7 +3099,11 @@ class TokenDecoder(nn.Module):
         of every attention layer) or XLA's products (0.0), and with a
         latent cache its bytes a position. A causal pass over
         fragments of `fragment_len` tokens: whether its attention takes
-        the fused form (1.0) or the plain one (0.0); and over a minibatch
+        the fused form (1.0) or the plain one (0.0), and how many
+        layers' passes rotate a head's rows in one pass over them
+        (`rowwise.rotation`: the layers that rotate, where the fragment
+        and a head are whole tiles, in a program for a TPU; a gated
+        layer's gate follows it); and over a minibatch
         of `learner_rows` tokens (0: not said), whether the experts'
         grouped products are the grouped-matmul kernel (1.0: at the first
         of the sizes the dispatch compiles, the one the expected load
@@ -3159,6 +3168,9 @@ class TokenDecoder(nn.Module):
             block = min(DECODE_CACHE_BLOCK, self.context_len)
         sparse = (platform == "tpu" and not self.block_len
                   and self.decode_sparse(step_rows))
+        # The layers whose learner pass may rotate a head's own rows.
+        rotating = attention if (
+            platform == "tpu" and not self.kv_lora_rank) else ()
         out = {
             "decode_rows_per_expert": step_rows * k / E,
             "decode_experts_batched": float(
@@ -3169,6 +3181,11 @@ class TokenDecoder(nn.Module):
             "decode_attention_kernel": float(kernel),
             "causal_attention_fused": float(
                 platform == "tpu" and causal_fused(fragment_len, *widths)),
+            "rotation_fused_layers": float(sum(
+                kind.rotary and rowwise.whole_tiles(
+                    fragment_len, self.head_width,
+                    int(self.head_width * kind.rotation.share))
+                for kind in map(self.layer_kind, rotating))),
         }
         if self.block_len:
             out.update(
@@ -3265,19 +3282,34 @@ class TokenDecoder(nn.Module):
             k = rms_norm(k, lp["k_norm"], eps, cd)
         return (q, k, v.reshape(groups), *gate)
 
-    def _gated(self, o, gate):
-        """A head's output o times sigmoid(gate), in float32."""
-        with jax.named_scope("policy/attention_gate"):
+    def _gated(self, o, gate, rows=False):
+        """A head's output o times sigmoid(gate), in float32. `rows`: o is
+        a causal pass's, [B, heads, T, d] of `rowwise.whole_tiles`, and a
+        head's gate lies [B, T, heads]: one pass over o in a program
+        lowered for a TPU (`rowwise.gating`)."""
+        def plain(o, gate):
             return (o.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(self.compute_dtype)
+
+        def by_head(o, gate):
+            return plain(o, gate if gate.ndim == o.ndim
+                         else jnp.swapaxes(gate, 1, 2)[..., None])
+        with jax.named_scope("policy/attention_gate"):
+            if not rows:
+                return plain(o, gate)
+            return jax.lax.platform_dependent(
+                o, gate, tpu=rowwise.gating, default=by_head)
 
     def _rotate(self, x, positions, rotation, scale=1.0, head_major=False):
         """`rope` by a layer's `rotation`: of the leading share of a head's
         values (all of them as a rule), the rest as they are; everything
-        times `scale` in float32."""
+        times `scale` in float32. Head-major rows of `rowwise.whole_tiles`
+        (a learner's pass) in a program lowered for a TPU: the same
+        arithmetic as one pass over them (`rowwise.rotation`)."""
         theta, share, scaling = rotation
         rotated = int(x.shape[-1] * share)
-        with jax.named_scope("policy/rope"):
+
+        def plain(x, positions):
             if rotated == x.shape[-1]:
                 return rope(x, positions, theta, scale, head_major, scaling)
             rest = x[..., rotated:]
@@ -3286,6 +3318,17 @@ class TokenDecoder(nn.Module):
             return jnp.concatenate([
                 rope(x[..., :rotated], positions, theta, scale, head_major,
                      scaling), rest], axis=-1)
+
+        def rows(x, positions):
+            cos, sin = rowwise.tables(
+                positions, *rope_frequencies(rotated, theta, scaling),
+                x.shape[-1])
+            return rowwise.rotation(x, cos, sin, rotated, scale)
+        with jax.named_scope("policy/rope"):
+            if head_major and rowwise.whole_tiles(*x.shape[2:], rotated):
+                return jax.lax.platform_dependent(
+                    x, positions, tpu=rows, default=plain)
+            return plain(x, positions)
 
     def _attention_scope(self, window: int) -> str:
         """The name a layer's own-heads attention has in a trace: by its
@@ -3405,9 +3448,15 @@ class TokenDecoder(nn.Module):
                     q = normed(q, "q_norm", heads)
                 else:
                     q = projected("wq", "q_norm", heads, asking(n))
+                # Whether the gate is one pass over a head's rows.
+                rows = bool(self.attention_gate) and rowwise.whole_tiles(
+                    *q.shape[2:])
                 if self.attention_gate == "head":
-                    gate = jnp.einsum("btr,rh->bht", asking(n),
-                                      lp["wg"].astype(cd))[..., None]
+                    gate = jnp.einsum(
+                        "btr,rh->bth", asking(n), lp["wg"].astype(cd)
+                    ) if rows else jnp.einsum(
+                        "btr,rh->bht", asking(n),
+                        lp["wg"].astype(cd))[..., None]
                 k = projected("wk", "k_norm", groups)
                 scale = q.shape[-1] ** -0.5
                 if rotary:
@@ -3422,7 +3471,7 @@ class TokenDecoder(nn.Module):
                         q, k, v, episode, scale, self.block_len,
                         streams), asking(x)), ()
                 o = causal_attention(q, k, v, episode, scale, window)
-                h = joined(self._gated(o, gate) if self.attention_gate
+                h = joined(self._gated(o, gate, rows) if self.attention_gate
                            else o)
                 caches = tuple(
                     jnp.take_along_axis(jnp.swapaxes(a, 1, 2),

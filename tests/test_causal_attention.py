@@ -22,11 +22,18 @@ from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss
 
 @pytest.fixture
 def interpreted(monkeypatch):
-    """The fused form's kernels run by the Pallas interpreter."""
+    """The fused form's kernels run by the Pallas interpreter, and the
+    row-wise passes' in front of them and behind (`models/rowwise.py`: a
+    fragment and a head that are whole tiles for the one are for the
+    other)."""
     from jax.experimental.pallas.ops.tpu import splash_attention as splash
+    from ray_tpu.models import rowwise
     for make in ("make_splash_mha", "make_splash_mqa"):
         monkeypatch.setattr(splash, make, functools.partial(
             getattr(splash, make), interpret=True))
+    for kernel in ("rotate_kernel", "gate_kernel"):
+        monkeypatch.setattr(rowwise, kernel, functools.partial(
+            getattr(rowwise, kernel), interpret=True))
 
 
 @pytest.fixture

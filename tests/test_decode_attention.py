@@ -26,7 +26,8 @@ if BENCH not in sys.path:
 
 from lib import reference_glm4_moe_lite as reference  # noqa: E402
 
-from ray_tpu.models import catalog, decode_attention, transformer  # noqa: E402
+from ray_tpu.models import (  # noqa: E402
+    catalog, decode_attention, rowwise, transformer)
 from ray_tpu.models.transformer import decode_fused, grouped_fused  # noqa: E402
 from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
 
@@ -896,3 +897,66 @@ def test_the_chosen_experts_compile_for_a_v5e_at_the_cells_widths(
     compiled = experts(True)
     assert "chosen_experts" in compiled and "tpu_custom_call" in compiled
     assert "tpu_custom_call" not in experts(False)
+
+
+YARN = (32.0, 4096, 64.0, 1.0, 1.3)
+
+
+@pytest.mark.parametrize("cell, B, heads, T, d, share, scaling", [
+    ("laguna, window queries", 1, 64, 8192, 128, 1.0, ()),
+    ("laguna, full queries", 1, 48, 8192, 128, 0.5, YARN),
+    ("laguna, keys", 1, 8, 8192, 128, 1.0, ()),
+    ("sdar, three streams' queries", 4, 32, 6144, 128, 1.0, ()),
+    ("sdar, the last layer's queries", 4, 32, 4096, 128, 1.0, ()),
+    ("sdar, keys", 4, 4, 6144, 128, 1.0, ()),
+    ("smallthinker, queries", 1, 28, 8192, 128, 1.0, ()),
+    ("qwen3_next, queries", 2, 16, 4096, 256, 0.25, ()),
+    ("qwen3_next, keys", 2, 2, 4096, 256, 0.25, ()),
+])
+def test_the_rotation_compiles_for_a_v5e_at_the_cells_widths(
+        cell, B, heads, T, d, share, scaling, one_chip):
+    """The fourth kernel file's (`models/rowwise.py`; here because this is
+    the one file that loads the chip's compiler): a learner's head-major
+    rotation and its pullback are two calls of the one kernel; the
+    rollout's form (rows by head) keeps `rope`."""
+    rotation = transformer.Rotation(10000.0, share, scaling)
+
+    def rotated(head_major):
+        def loss(x, positions, w):
+            return jnp.sum(transformer.TokenDecoder._rotate(
+                None, x, positions, rotation, d ** -0.5, head_major) * w)
+        shape = (B, heads, T, d) if head_major else (B, T, heads, d)
+        return jax.jit(jax.value_and_grad(loss)).trace(
+            shaped(one_chip, *shape),
+            shaped(one_chip, B, T, dtype=jnp.int32),
+            shaped(one_chip, *shape)).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    assert rowwise.whole_tiles(T, d, int(d * share))
+    compiled = rotated(True)
+    assert compiled.count("tpu_custom_call") == 2
+    assert "rotate_rows" in compiled
+    assert "tpu_custom_call" not in rotated(False)
+
+
+@pytest.mark.parametrize("cell, B, heads, T, d, a_head", [
+    ("laguna, window layers", 1, 64, 8192, 128, True),
+    ("laguna, full layers", 1, 48, 8192, 128, True),
+    ("qwen3_next", 2, 16, 4096, 256, False),
+])
+def test_the_gate_compiles_for_a_v5e_at_the_cells_widths(
+        cell, B, heads, T, d, a_head, one_chip):
+    """The gate a head (its column out of a [positions, heads] tile) and
+    the gate a value, forward and pullback: two kernels, and the gate a
+    head never lies [B, heads, T, 1]."""
+    import types
+    me = types.SimpleNamespace(compute_dtype=jnp.bfloat16)
+
+    def loss(o, gate, w):
+        return jnp.sum(transformer.TokenDecoder._gated(me, o, gate, True) * w)
+    whole = shaped(one_chip, B, heads, T, d)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
+        whole, shaped(one_chip, B, T, heads) if a_head else whole,
+        whole).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert compiled.count("tpu_custom_call") == 2
+    assert "gate_rows_back" in compiled
+    assert f"[{B},{heads},{T},1]" not in compiled

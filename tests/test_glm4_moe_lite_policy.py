@@ -548,7 +548,8 @@ def test_the_cell_s_forms_are_chosen_from_the_static_shape():
         "decode_rows_per_expert": 8.0, "decode_experts_batched": 1.0,
         "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
-        "latent_cache_bytes_per_token": 5760, "causal_attention_fused": 1.0}
+        "latent_cache_bytes_per_token": 5760, "causal_attention_fused": 1.0,
+        "rotation_fused_layers": 0.0}
     assert model.static_counters(128, 1024, "cpu")[
         "decode_cache_block"] == 1024
 

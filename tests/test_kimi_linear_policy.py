@@ -793,7 +793,7 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "decode_experts_sparse": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "latent_cache_bytes_per_token": 1152,
-        "conv_layers": 4, "conv_state_bytes_per_row": 294912,
+        "rotation_fused_layers": 0.0, "conv_layers": 4, "conv_state_bytes_per_row": 294912,
         "kda_layers": 4, "kda_state_bytes_per_row": 8388608,
         "kda_chunk": 64, "state_step_kernel": 1.0}
     assert not transformer.causal_fused(4096, 192, 128)

@@ -418,6 +418,9 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 2048.0,
+        # Heads of 64 are half a lane tile: the learner's rotation keeps
+        # `rope` (`rowwise.whole_tiles`).
+        "rotation_fused_layers": 0.0,
         "kv_groups": 4, "conv_layers": 4, "conv_state_bytes_per_row": 32768}
     # Off a TPU the cache is read whole, by XLA's products.
     off = model.static_counters(64, 4096, "cpu")

@@ -685,7 +685,7 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 1024.0,
-        "kv_groups": 16, "conv_layers": 3,
+        "rotation_fused_layers": 0.0, "kv_groups": 16, "conv_layers": 3,
         "conv_state_bytes_per_row": 110592, "ssm_layers": 3,
         "ssm_state_bytes_per_row": 6291456, "ssm_chunk": 128,
         "state_step_kernel": 0.0}

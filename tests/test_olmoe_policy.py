@@ -425,6 +425,7 @@ def test_the_form_is_chosen_from_the_static_shape():
         "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
         "decode_cache_block": transformer.DECODE_CACHE_BLOCK,
         "decode_attention_kernel": 0.0, "causal_attention_fused": 1.0,
+        "rotation_fused_layers": float(net["num_hidden_layers"]),
         # K and V of 16 heads of 128 in bfloat16, a layer (PR 38: every
         # model with caches of a head's own says so).
         "kv_cache_bytes_per_token": 2 * 16 * 128 * 2.0 * net[

@@ -673,6 +673,8 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "decode_experts_sparse": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "experts_grouped_kernel": 1.0,
+        # The one gated layer: a quarter of a head of 256 rotated.
+        "rotation_fused_layers": 1.0,
         "kv_cache_bytes_per_token": 2048.0, "kv_groups": 8,
         "conv_layers": 3, "conv_state_bytes_per_row": 147456,
         "gdn_layers": 3, "gdn_state_bytes_per_row": 6291456,

@@ -385,6 +385,8 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         "decode_experts_sparse": 1.0,
         "decode_cache_block": 128, "decode_attention_kernel": 1.0,
         "causal_attention_fused": 1.0, "window_layers": 3, "kv_groups": 7,
+        # The three window layers rotate, the full one is position-free.
+        "rotation_fused_layers": 3.0,
         "kv_cache_bytes_per_token": 5120.0,
         "causal_window_tiles_kept": 108 / 136}
     # Off a TPU the caches are read whole, by XLA's products.

@@ -9,6 +9,18 @@ bookkeeping gained for a layer that is one function, an expert without a
 gate matrix and a fourth kind of state is shown not to reach them: no
 operation added, dropped or moved. A change that is MEANT to alter one of
 these programs records the new hash here, in the PR that measures the cell.
+
+PR 57 (a learner's rotation and gate as one pass over a head's rows,
+`models/rowwise.py`) is MEANT to change none of the eight: the pass is taken
+by the static shape (`rowwise.whole_tiles`: a head of whole lane tiles, a
+fragment of whole tiles of 512 positions) and, like every kernel, by the
+platform, and no rehearsal's shape has such tiles (heads of 16 to 32,
+fragments of 32), so every rehearsal keeps `rope` and the eight hashes
+stand, GLM's, Kimi's and Nemotron-H's (whose real cells never reach the
+pass) with them. Of the real cells' programs, lowered for a TPU: those
+three's and LFM2's are the parent's text (`_scratch`-style, PERF.md section
+6, PR 57); OLMoE's, SmallThinker's, SDAR's, Qwen3-Next's and Laguna's
+changed, as meant, and were measured there.
 """
 
 import hashlib
