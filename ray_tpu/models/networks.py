@@ -94,10 +94,7 @@ class VisionNetwork(nn.Module):
         h32 = h.astype(jnp.float32)
         logits = nn.Dense(self.num_outputs, name="logits",
                           kernel_init=nn.initializers.normal(0.01))(h32)
-        if self.vf_share_layers:
-            value = nn.Dense(1, name="value")(h32)
-        else:
-            value = nn.Dense(1, name="value")(h32)  # vision nets share trunk
+        value = nn.Dense(1, name="value")(h32)  # vision nets share trunk
         return logits, value[..., 0]
 
 

@@ -44,8 +44,12 @@ except Exception:
 
 import pytest
 
-# Seconds one test may take, set-up and teardown included: more than twice
-# the slowest tier-1 test of a whole six-worker run (CHANGES.md, PR 30).
+# Seconds one test may take, set-up and teardown included: twice the slowest
+# tier-1 test of a whole six-worker run and more (CHANGES.md, PR 58: on the
+# builder's eight cores from an empty compile cache the run took 1,180 s of
+# the driver's 1,470 and its slowest test 66.8 s, one that lowers a whole
+# cell; at PR 57 the slowest was 140 s). A test that needs more than half
+# the limit, 90 s, carries `@pytest.mark.time_limit(seconds)` and says why.
 TIME_LIMIT_S = 180
 # After the limit a raise has this long to unwind the test and its
 # fixtures; then the worker process dumps its stacks and exits (xdist
@@ -187,6 +191,35 @@ def pytest_configure(config):
         "the tier-1 run (-m 'not slow')")
 
 
+def pytest_generate_tests(metafunc):
+    """A check that the token families share (`tests/token_families.py`)
+    takes its cases from the family of the module that binds it."""
+    for names, of in getattr(metafunc.function, "family_cases", ()):
+        metafunc.parametrize(names, of(metafunc.module.FAMILY))
+
+
+@pytest.fixture(scope="module")
+def family(request):
+    """The token family a file's checks are of: its row, `FAMILY`."""
+    return request.module.FAMILY
+
+
+@pytest.fixture(scope="module")
+def token_trainer(family):
+    """The family's tiny model on the fused Anakin path, one a file,
+    after one iteration: the fused program is compiled and Adam's moments
+    are not zero. Its cost is the first test's that asks for it, which a
+    family's update file makes the loss's check (the first name it
+    imports), so that no one test carries every compile."""
+    from token_families import token_trainer_config
+
+    from ray_tpu.rllib.agents.impala import IMPALATrainer
+    trainer = IMPALATrainer(config=token_trainer_config(family))
+    trainer.train()
+    yield trainer
+    trainer.stop()
+
+
 @pytest.fixture
 def ray_start():
     """Boot a real multi-process runtime for a test, like the reference's
@@ -257,8 +290,10 @@ def grouped_pass_is_the_batched_pass(monkeypatch):
                     jnp.zeros(tokens.shape), mutable=["counters"])
                 return jnp.sum(jnp.sin(logits)) + jnp.sum(values), (
                     logits, values, kept["counters"])
-            (_, (logits, values, counted)), grads = jax.value_and_grad(
-                loss, has_aux=True)(variables["params"])
+            # One program a form: the constants below are read when it
+            # is traced, and `run` traces anew.
+            (_, (logits, values, counted)), grads = jax.jit(
+                jax.value_and_grad(loss, has_aux=True))(variables["params"])
             return (logits, values, grads), {
                 k: float(v[-1]) for k, v in counted.items()}
         want, counted = run()

@@ -212,7 +212,7 @@ class TestDemandShape:
             ray_tpu.shutdown()
 
     def test_packed_want_count_not_one_node_per_vector(self):
-        """ADVICE r5 over-provisioning fix: 6 x {CPU:1} against a CPU:4
+        """Advisor round 5, over-provisioning: 6 x {CPU:1} against a CPU:4
         type needs ceil(6/4)=2 nodes, not 6."""
         p, lm, a = self._make()
         lm.pending_demand = [{"CPU": 1.0}] * 6

@@ -1,32 +1,32 @@
-"""The `glm4_moe_lite` token policy at a tiny size on the CPU: the model
-against the plain reference (`benchmark/lib/reference_glm4_moe_lite.py`),
-latent attention absorbed through its cache against the decompressed causal
-pass, the expert layer that holds a share against the uncut layer, the
-next-next-token module's loss and where its gradient goes, V-trace with the
-model's own term, and the trainer on the fused Anakin path.
+"""The `glm4_moe_lite` token policy at a tiny size on the CPU: the family's
+row, the checks it shares with the other families (`tests/token_families.py`:
+each named wrong mathematics refused by the cell's limits, by the outputs, the
+routing or the module's loss; the grouped form of the expert product; the
+builder's refusals; the tuned example) and what is its own: the model and its
+next-next-token module's loss against the plain reference
+(`benchmark/lib/reference_glm4_moe_lite.py`), latent attention absorbed
+through its cache against the decompressed causal pass, the expert layer that
+holds a share against the uncut layer and its dispatch over the landed rows,
+the module's loss and where its gradient goes. The loss and the loop:
+`tests/test_glm4_moe_lite_update.py`.
 """
-
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from token_families import (  # noqa: F401: pytest collects what is named
+    Family, build, causal_routed, configuration, decode_routed, judged,
+    test_a_causal_pass_over_the_landed_rows_is_the_batched_pass,
+    test_custom_model_config_without_a_part_is_refused
+    as test_unknown_custom_model_config_keys_are_refused,
+    test_limits_refuse_wrong_mathematics,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_glm4_moe_lite as reference
 
-from lib import reference_glm4_moe_lite as reference  # noqa: E402
-
-from ray_tpu.models import catalog, transformer  # noqa: E402
-from ray_tpu.models.transformer import (  # noqa: E402
-    dropless_experts, experts_batched)
-from ray_tpu.rllib import sample_batch as sb  # noqa: E402
-from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
-from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss  # noqa: E402
+from ray_tpu.models import catalog, transformer
+from ray_tpu.models.transformer import dropless_experts, experts_batched
 
 # One dense layer, two expert layers holding 2 of 8 routed experts beside a
 # shared one, the module, a vocabulary of 96: the published keys.
@@ -42,59 +42,46 @@ NET = dict(vocab_size=96, hidden_size=64, num_attention_heads=4,
            rope_theta=1e6, rms_norm_eps=1e-5)
 B, S = 3, 16
 LATENT = NET["kv_lora_rank"] + NET["qk_rope_head_dim"]
-KEPT = ["routing", "counters", "losses"]
 
-
-def build(dtype, net=NET, bias_scale=None):
-    window = net["max_position_embeddings"]
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "glm4_moe_lite", "custom_model_config": net,
-        "compute_dtype": dtype})
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, window), 0, net["vocab_size"])
-    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
-                           model.initial_state(B), jnp.zeros((B, 1)))
-    if bias_scale is not None:
-        # A selection bias as large as the scores' own spread, so that
-        # what it is let into shows.
-        variables = dict(variables, constants=jax.tree.map(
-            lambda b: b * (bias_scale / transformer.ROUTER_BIAS_SCALE),
-            variables["constants"]))
-    return model, variables, tokens
-
-
-def token_trainer_config(**over):
-    cfg = dict(
-        env="TokenBigram-v0",
-        env_config={"vocab_size": NET["vocab_size"], "episode_len": S},
-        anakin=True, num_workers=0, num_envs_per_worker=8,
-        rollout_fragment_length=S, train_batch_size=8 * S,
-        sgd_minibatch_size=2 * S, num_sgd_iter=1,
-        anakin_updates_per_call=1, min_iter_time_s=0, lr=6e-4, seed=3,
-        model={"custom_model": "glm4_moe_lite", "custom_model_config": NET,
-               "compute_dtype": "f32"})
-    cfg.update(over)
-    return cfg
-
-
-def judged(system, variables, tokens, net=NET):
-    """The system's (logits, values, experts, the module's cross-entropy
-    by position) against the reference held to those experts: (outputs,
-    routing, loss)."""
-    logits, values, experts, loss = system
-    held = reference.forward(variables, tokens, net, experts=experts)
-    return (reference.compare((logits, values),
-                              (held["logits"], held["values"])),
-            reference.routing_verdict(experts, held["experts"],
-                                      held["select"]),
-            reference.compare_loss(loss, held["nextn_nll_by_position"]))
-
-
-def causal_kept(model, variables, tokens):
-    (logits, values, state), kept = model.apply(
-        variables, tokens, None, jnp.zeros(tokens.shape), mutable=KEPT)
-    return (logits, values, kept["routing"]["experts"][-1],
-            kept["routing"]["nextn_nll"][-1]), state, kept
+FAMILY = Family(
+    name="glm4_moe_lite", net=NET, reference=reference, B=B, S=S,
+    collections=frozenset({"params", "constants"}),
+    # The module's cross-entropy by position is judged beside the outputs.
+    kept=("losses",), module_loss=("nextn_nll", "nextn_nll_by_position"),
+    # A selection bias as large as the scores' own spread, so that what it
+    # is let into shows.
+    limits_build=dict(bias_scale=0.2),
+    refused_by={
+        # The trunk is as it was; the module's loss and its router differ.
+        "module_without_norms": lambda verdicts: (
+            not verdicts["loss"]["ok"] and verdicts["outputs"]["ok"]),
+        "bias_left_out_of_choice": lambda verdicts:
+            not verdicts["routing"]["ok"]},
+    envs=8,
+    wrong_updates={
+        "module_s_weight_dropped": dict(
+            patch=("NEXTN_LOSS_WEIGHT", 0.0), by="loss_error"),
+        "no_shared_expert_in_the_gradient": dict(
+            mutate="no_shared_expert", by="update_error"),
+        "sum_over_part_of_the_batch": dict(
+            part_of_the_batch=True, by=("loss_error", "update_error")),
+        "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0},
+                                 by="loss_error"),
+        "ten_times_the_entropy_coeff": dict(cfg={"entropy_coeff": 0.1},
+                                            by="loss_error"),
+        "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
+        "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error"),
+        "adam_s_state_not_read": dict(fresh_moments=True,
+                                      by="update_error")},
+    refused=(
+        ({"num_experts": 8}, "not glm4_moe_lite's"),
+        ({"compute_path": "absorbed"}, "not glm4_moe_lite's"),
+        ({"topk_method": "greedy"}, "noaux_tc"),
+        ({"n_group": 2}, "n_group"),
+        ({"num_key_value_heads": 2}, "key/value heads"),
+        ({"experts_held": 6, "first_expert_held": 4}, "not among")),
+    example="glm47-flash-token-impala.yaml",
+    cell="glm47_flash_token_anakin", config="impala_glm_4_7_flash")
 
 
 # -- the model against the reference ------------------------------------
@@ -102,11 +89,14 @@ def causal_kept(model, variables, tokens):
 def test_causal_pass_and_module_loss_match_reference(dtype):
     """float32 blocks: to float32 accuracy, the same experts in every
     layer. bfloat16 blocks: the limits written beside the reference."""
-    model, variables, tokens = build(dtype)
-    system, _, kept = causal_kept(model, variables, tokens)
+    built = build(FAMILY, dtype)
+    _, variables, tokens = built
+    system, _, kept = causal_routed(built, variables, tokens)
     # Two expert layers and the module's: the dense layer routes nothing.
     assert system[2].shape == (3, B, S, NET["num_experts_per_tok"])
-    outputs, routing, loss = judged(system, variables, tokens)
+    verdicts, _ = judged(FAMILY, system, variables, tokens)
+    outputs, routing, loss = (
+        verdicts[name] for name in ("outputs", "routing", "loss"))
     if dtype == "f32":
         assert routing["router_flips"] == 0.0
         assert max(outputs["errors"].values()) < 1e-5, outputs
@@ -123,58 +113,30 @@ def test_causal_pass_and_module_loss_match_reference(dtype):
                                kept["counters"]["mtp_loss"][-1], rtol=1e-6)
 
 
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The comparison fails each named error and blocks computed a
-    precision lower: the reference, so altered, in the system's place
-    against itself, by its outputs, its routing or the module's loss."""
-    _, variables, tokens = build("f32", bias_scale=0.2)
-    if wrong == "float8_e4m3":
-        got = reference.forward(variables, tokens, NET, round_to=wrong)
-    else:
-        got = reference.forward(variables, tokens, NET, mutate=wrong)
-    outputs, routing, loss = judged(
-        (got["logits"], got["values"], got["experts"],
-         got["nextn_nll_by_position"]), variables, tokens)
-    refused_by = [name for name, v in (
-        ("outputs", outputs), ("routing", routing), ("loss", loss))
-        if not v["ok"]]
-    assert refused_by, (wrong, outputs, routing, loss)
-    if wrong == "module_without_norms":
-        # The trunk is as it was; the module's loss and its router differ.
-        assert "loss" in refused_by and outputs["ok"]
-    if wrong == "bias_left_out_of_choice":
-        assert "routing" in refused_by
-
-
 # -- latent attention: absorbed through the cache == decompressed --------
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
 def test_absorbed_decode_through_latent_cache_matches_causal_pass(dtype):
     """Every position decoded one token at a time, W_UK absorbed into the
     query and W_UV applied to the weighted latents, against the causal
     pass's decompressed keys and values; the latent window read whole."""
-    model, variables, tokens = build(dtype)
-    logits, values, _ = model.apply(
-        variables, tokens, None, jnp.zeros((B, S)))
-    state, got_l, got_v, read = model.initial_state(B), [], [], []
+    built = build(FAMILY, dtype)
+    model, variables, tokens = built
+    # One program a form in float32, for the time it saves. Not in
+    # bfloat16: XLA:CPU then rounds elsewhere than the other form's ops
+    # do, and one near-tie of a router moves a token's logits.
+    jit = dtype == "f32"
+    (logits, values, _, _), _, _ = causal_routed(
+        built, variables, tokens, jit=jit)
     # The state: one latent row a position a layer, and nothing wider.
-    assert [[c.shape for c in layer] for layer in state["kv"]] == [
+    assert [[c.shape for c in layer]
+            for layer in model.initial_state(B)["kv"]] == [
         [(B, S, LATENT)]] * NET["num_hidden_layers"]
-
-    def decode(token, state):
-        return model.apply(variables, token, state, jnp.zeros(B),
-                           method="decode", mutable=["counters"])
-    if dtype == "f32":
-        decode = jax.jit(decode)
-    for t in range(S):
-        (step_l, step_v, state), kept = decode(tokens[:, t], state)
-        got_l.append(step_l)
-        got_v.append(step_v)
-        read.append(float(kept["counters"]["decode_cache_read_share"][-1]))
-    assert read == [1.0] * S
+    (got_l, got_v, _), state, counted = decode_routed(
+        built, variables, tokens, jit=jit)
+    assert [step["decode_cache_read_share"] for step in counted] == [1.0] * S
     tol = 1e-5 if dtype == "f32" else reference.TOLERANCE
-    assert reference.relative_error(jnp.stack(got_l, 1), logits) <= tol
-    assert reference.relative_error(jnp.stack(got_v, 1), values) <= tol
+    assert reference.relative_error(got_l, logits) <= tol
+    assert reference.relative_error(got_v, values) <= tol
     assert np.all(np.asarray(state["pos"]) == S)
     assert [[c.shape for c in layer] for layer in state["kv"]] == [
         [(B, S, LATENT)]] * NET["num_hidden_layers"]
@@ -185,26 +147,30 @@ def test_absorbed_decode_through_latent_cache_matches_causal_pass(dtype):
 def test_prefill_then_absorbed_decode_and_a_reset_inside_a_fragment():
     """The causal pass returns the latents a decode continues from, and
     a reset inside a fragment starts a fresh episode in both forms."""
-    model, variables, tokens = build("f32")
-    decode = jax.jit(lambda token, state, reset: model.apply(
-        variables, token, state, reset))
-    full, _, _ = model.apply(variables, tokens, None, jnp.zeros((B, S)))
-    _, _, state = model.apply(variables, tokens[:, :10], None,
-                              jnp.zeros((B, 10)))
+    built = build(FAMILY, "f32")
+    model, variables, tokens = built
+
+    def causal(tokens, reset=None):
+        (logits, _, _, _), state, _ = causal_routed(
+            built, variables, tokens, reset)
+        return logits, state
+    full, _ = causal(tokens)
+    _, state = causal(tokens[:, :10])
     assert state["kv"][0][0].shape == (B, S, LATENT)
     for t in range(10, S):
-        step, _, state = decode(tokens[:, t:t + 1], state, jnp.zeros((B, 1)))
+        step, _, state = built.decode(
+            variables, tokens[:, t:t + 1], state, jnp.zeros((B, 1)))
         assert reference.relative_error(step[:, 0], full[:, t]) < 1e-5
     reset = jnp.zeros((B, S)).at[:, 8].set(1.0)
-    both, _, state = model.apply(variables, tokens, None, reset)
-    second, _, _ = model.apply(variables, tokens[:, 8:], None,
-                               jnp.zeros((B, 8)))
+    both, state = causal(tokens, reset)
+    second, _ = causal(tokens[:, 8:])
     assert reference.relative_error(both[:, 8:], second) < 1e-5
     assert reference.relative_error(both[:, :8], full[:, :8]) < 1e-5
     assert np.all(np.asarray(state["pos"]) == 8)
     state = model.initial_state(B)
     for t in range(S):
-        step, _, state = decode(tokens[:, t:t + 1], state, reset[:, t:t + 1])
+        step, _, state = built.decode(
+            variables, tokens[:, t:t + 1], state, reset[:, t:t + 1])
         assert reference.relative_error(step[:, 0], both[:, t]) < 1e-5
 
 
@@ -504,11 +470,6 @@ def test_a_share_s_dispatch_over_the_landed_rows_equals_the_masked_loop(
         assert not any(np.any(np.asarray(g)) for g in (got, *got_g))
 
 
-def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
-        grouped_pass_is_the_batched_pass):
-    grouped_pass_is_the_batched_pass(*build("f32"))
-
-
 # (M, k, E) -> the form: the shapes the OLMoE tests list and the sweep's
 # crossing (PERF.md section 5), as before the layer could hold a share; and
 # the new cell's decode step and update, whose held count (8 of 64) scales
@@ -582,7 +543,7 @@ def test_module_loss_gradients_match_reference_and_stop_at_the_trunk():
     the module's own parameters alone: none into the trunk, the embedding
     or the head (it follows the policy, it does not move it), and none
     into any router's selection bias."""
-    model, variables, tokens = build("f32")
+    model, variables, tokens = build(FAMILY, "f32")
 
     def system(v):
         _, kept = model.apply(v, tokens, None, jnp.zeros((B, S)),
@@ -593,8 +554,8 @@ def test_module_loss_gradients_match_reference_and_stop_at_the_trunk():
         return reference.NEXTN_LOSS_WEIGHT * reference.forward(
             v, tokens, NET)["nextn_nll"]
 
-    got, grads = jax.value_and_grad(system)(variables)
-    want, want_grads = jax.value_and_grad(plain)(variables)
+    got, grads = jax.jit(jax.value_and_grad(system))(variables)
+    want, want_grads = jax.jit(jax.value_and_grad(plain))(variables)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for name, g in grads["params"].items():
         moved = any(bool(jnp.any(leaf != 0)) for leaf in jax.tree.leaves(g))
@@ -608,310 +569,10 @@ def test_module_loss_gradients_match_reference_and_stop_at_the_trunk():
         assert float(jnp.max(jnp.abs(g - w))) <= 2e-3 * scale, path
 
 
-# -- the loss and the loop ------------------------------------------------
-@pytest.fixture(scope="module")
-def token_trainer():
-    trainer = IMPALATrainer(config=token_trainer_config())
-    yield trainer
-    trainer.stop()
-
-
-def test_vtrace_minibatch_loss_with_the_model_s_term_matches_reference(
-        token_trainer):
-    """One minibatch of whole episodes through the system's loss (packed
-    rows, ACTION_LOGP, the bootstrap step through the latent cache, the
-    model's "losses" added) and through `jax.grad` of the plain reference;
-    the router bias has no gradient and no optimizer state."""
-    policy = token_trainer.get_policy()
-    cfg = policy.config
-    rng = np.random.default_rng(5)
-    tokens = rng.integers(0, NET["vocab_size"], size=(B, S))
-    actions = rng.integers(0, NET["vocab_size"], size=(B, S))
-    rewards = rng.integers(0, 2, size=(B, S)).astype(np.float32)
-    behaviour_logp = rng.uniform(-5.5, -4.0, size=(B, S)).astype(np.float32)
-    dones = np.zeros((B, S), np.float32)
-    dones[:, -1] = 1.0
-    batch = {
-        sb.OBS: jnp.asarray(tokens.reshape(-1), jnp.int32),
-        sb.ACTIONS: jnp.asarray(actions.reshape(-1), jnp.int32),
-        sb.REWARDS: jnp.asarray(rewards.reshape(-1)),
-        sb.DONES: jnp.asarray(dones.reshape(-1)),
-        sb.ACTION_LOGP: jnp.asarray(behaviour_logp.reshape(-1)),
-        sb.BOOTSTRAP_OBS: jnp.asarray(tokens[:, 0], jnp.int32),
-    }
-    variables = jax.tree.map(jnp.asarray, policy.get_weights())
-    assert set(variables) == {"params", "constants"}
-
-    (total, stats), grads = jax.value_and_grad(
-        lambda v: vtrace_loss(policy, v, batch, None, {}),
-        has_aux=True)(variables)
-    ref_batch = {"tokens": tokens, "actions": actions, "rewards": rewards,
-                 "behaviour_logp": behaviour_logp}
-    (want_total, parts), want_grads = jax.value_and_grad(
-        lambda v: reference.vtrace_loss(v, ref_batch, NET, cfg),
-        has_aux=True)(variables)
-    np.testing.assert_allclose(total, want_total, rtol=1e-4)
-    np.testing.assert_allclose(
-        stats["mtp_loss"] * B * (S - 2), parts["nextn_nll"], rtol=1e-4)
-    # The model's term is in the total: without it the two differ.
-    assert abs(float(total) - float(
-        want_total - reference.NEXTN_LOSS_WEIGHT * parts["nextn_nll"])) \
-        > 1e-2 * abs(float(total))
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads["params"])
-    want_flat = jax.tree.leaves(want_grads["params"])
-    assert len(flat) == len(want_flat)
-    for (path, got), want in zip(flat, want_flat):
-        scale = float(jnp.max(jnp.abs(want))) + 1e-8
-        assert float(jnp.max(jnp.abs(got - want))) <= 2e-3 * scale, path
-    assert not any(bool(jnp.any(g != 0))
-                   for g in jax.tree.leaves(grads["constants"]))
-    # Adam's moments exist for the parameters alone.
-    moments = [leaf for leaf in jax.tree.leaves(policy.opt_state)
-               if leaf.dtype == jnp.float32]
-    assert len(moments) == 2 * len(jax.tree.leaves(variables["params"]))
-    assert stats["expert_load_mean"] > 0
-    assert 0.0 < stats["experts_held_row_share"] < 1.0
-
-
-def one_update(trainer, seed=7, **wrong):
-    """One update of `frags` seeded whole episodes by the optimizer's own
-    step (`AnakinOptimizer.learn`, the body of the fused program's
-    learner) from the trainer's parameters and optimizer state, against
-    the reference's loss, gradients and `adam_update`: what the
-    benchmark's driver does at the cell's minibatch. `wrong` plants a
-    fault in the reference's side."""
-    policy, opt = trainer.get_policy(), trainer.optimizer
-    frags, cfg = opt.minibatch // opt.T, dict(policy.config)
-    rng = np.random.default_rng(seed)
-    walk = rng.integers(0, NET["vocab_size"], size=(frags, S + 1))
-    ref_batch = {
-        "tokens": walk[:, :S], "actions": walk[:, 1:],
-        "rewards": rng.integers(0, 2, size=(frags, S)).astype(np.float32),
-        "behaviour_logp": rng.uniform(-5.0, -4.0, size=(frags, S)).astype(
-            np.float32)}
-    dones = np.zeros((frags, S), np.float32)
-    dones[:, -1] = 1.0
-    batch = {
-        sb.OBS: jnp.asarray(ref_batch["tokens"].reshape(-1), jnp.int32),
-        sb.ACTIONS: jnp.asarray(ref_batch["actions"].reshape(-1), jnp.int32),
-        sb.REWARDS: jnp.asarray(ref_batch["rewards"].reshape(-1)),
-        sb.DONES: jnp.asarray(dones.reshape(-1)),
-        sb.ACTION_LOGP: jnp.asarray(ref_batch["behaviour_logp"].reshape(-1)),
-        sb.VF_PREDS: jnp.zeros(frags * S, jnp.float32),
-        sb.BOOTSTRAP_OBS: jnp.asarray(walk[:, S], jnp.int32)}
-
-    def flat(tree):
-        return {jax.tree_util.keystr(path): np.asarray(leaf)
-                for path, leaf in
-                jax.tree_util.tree_flatten_with_path(tree)[0]}
-    before = policy.params
-    (adam,) = [s for s in jax.tree.leaves(
-        policy.opt_state, is_leaf=lambda s: hasattr(s, "mu"))
-        if hasattr(s, "mu")]
-    after, _, stats = jax.jit(opt.learn)(
-        before, policy.opt_state, batch, jax.random.PRNGKey(0))
-    assert jax.tree.all(jax.tree.map(
-        jnp.array_equal, after["constants"], before["constants"]))
-
-    cfg.update(wrong.get("cfg", {}))
-    if wrong.get("part_of_the_batch"):
-        ref_batch = {k: v[:-1] for k, v in ref_batch.items()}
-    (want_loss, _), grads = jax.value_and_grad(
-        lambda p: reference.vtrace_loss(
-            {"params": p, "constants": before["constants"]}, ref_batch,
-            NET, cfg, mutate=wrong.get("mutate")), has_aux=True)(
-                before["params"])
-    count, mu, nu = int(adam.count), flat(adam.mu["params"]), \
-        flat(adam.nu["params"])
-    assert count > 0
-    if wrong.get("fresh_moments"):
-        count, mu, nu = 0, *(
-            {k: np.zeros_like(v) for k, v in m.items()} for m in (mu, nu))
-    want_change, norm = reference.adam_update(
-        flat(grads), mu, nu, count, cfg)
-    assert norm > 0
-    old, new = flat(before["params"]), flat(after["params"])
-    return reference.compare_update(stats["total_loss"], want_loss, {
-        name: float(reference.change_error(old[name], new[name], want))
-        for name, want in want_change.items()})
-
-
-def test_one_update_by_the_optimizer_s_own_step_matches_reference(
-        token_trainer):
-    token_trainer.train()  # Adam's moments are not zero
-    found = one_update(token_trainer)
-    assert found["ok"], found
-    assert found["loss_error"] < 1e-5 and found["update_error"] < 1e-2, found
-
-
-WRONG_UPDATES = {
-    "module_s_weight_dropped": dict(weight=0.0, by="loss_error"),
-    "no_shared_expert_in_the_gradient": dict(mutate="no_shared_expert",
-                                             by="update_error"),
-    "sum_over_part_of_the_batch": dict(part_of_the_batch=True),
-    "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0}, by="loss_error"),
-    "ten_times_the_entropy_coeff": dict(cfg={"entropy_coeff": 0.1},
-                                        by="loss_error"),
-    "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
-    "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error"),
-    "adam_s_state_not_read": dict(fresh_moments=True, by="update_error"),
-}
-
-
-@pytest.mark.parametrize("wrong", WRONG_UPDATES)
-def test_update_limits_refuse_a_wrong_update(wrong, token_trainer,
-                                             monkeypatch):
-    """The comparison of one update fails each named error, planted in the
-    reference's side: by the loss, by the worst parameter's change, or by
-    both."""
-    token_trainer.train()
-    fault = dict(WRONG_UPDATES[wrong])
-    by = fault.pop("by", None)
-    if "weight" in fault:
-        monkeypatch.setattr(reference, "NEXTN_LOSS_WEIGHT",
-                            fault.pop("weight"))
-    found = one_update(token_trainer, **fault)
-    assert not found["ok"], found
-    limits = {"loss_error": reference.UPDATE_LOSS_TOLERANCE,
-              "update_error": reference.UPDATE_TOLERANCE}
-    for name in [by] if by else limits:
-        assert found[name] > limits[name], found
-
-
-@pytest.mark.parametrize("steps_before", [0, 3])
-@pytest.mark.parametrize("clip", [None, 0.5])
-def test_reference_adam_update_is_the_optimizer_s(clip, steps_before):
-    """`reference.adam_update` (plain numpy) against the chain the policy
-    builds (`default_optimizer`: clip by global norm, Adam), from fresh
-    moments and from moments three updates old."""
-    from ray_tpu.rllib.policy.jax_policy import default_optimizer
-    cfg = {"lr": 3e-3, "grad_clip": clip}
-    tx = default_optimizer(cfg)
-    keys = jax.random.split(jax.random.PRNGKey(4), 2 * (steps_before + 1))
-    params = {"a": jax.random.normal(keys[0], (5, 7)),
-              "b": jax.random.normal(keys[1], (3,))}
-
-    def grads_of(i):
-        return {"a": jax.random.normal(keys[2 * i], (5, 7)),
-                "b": 3.0 * jax.random.normal(keys[2 * i + 1], (3,))}
-    state = tx.init(params)
-    for i in range(steps_before):
-        _, state = tx.update(grads_of(i), state, params)
-    (adam,) = [s for s in jax.tree.leaves(
-        state, is_leaf=lambda s: hasattr(s, "mu")) if hasattr(s, "mu")]
-    grads = grads_of(steps_before)
-    want, _ = tx.update(grads, state, params)
-    got, norm = reference.adam_update(
-        grads, adam.mu, adam.nu, int(adam.count), cfg)
-    assert int(adam.count) == steps_before and norm > (clip or 0)
-    for name in params:
-        np.testing.assert_allclose(got[name], want[name], rtol=2e-5)
-
-
-@pytest.mark.parametrize("moved_by", [0.4, 3.0])
-def test_update_comparison_allows_float32_storage_and_nothing_more(
-        moved_by):
-    """A scale of 1.0 that an update moves by less than half its float32
-    spacing (6e-8) stays where it is, and that is not held against the
-    update; a change that is wrong by more than the spacing is."""
-    spacing = float(np.spacing(np.float32(1.0)))
-    before = np.ones(64, np.float32)
-    want = np.full(64, moved_by * spacing, np.float32)
-    after = (before.astype(np.float64) + want).astype(np.float32)
-    assert float(reference.change_error(before, after, want)) == 0.0
-    if moved_by > 0.5:  # the same change with its sign wrong
-        wrong = (before.astype(np.float64) - want).astype(np.float32)
-        error = float(reference.change_error(before, wrong, want))
-        assert error > 1.0
-        assert not reference.compare_update(1.0, 1.0, {"scale": error})["ok"]
-    else:  # the parameter has not moved at all
-        assert np.array_equal(after, before)
-
-
-def test_glm_token_trainer_trains_on_the_fused_path(token_trainer):
-    """`IMPALATrainer(anakin, TokenBigram-v0, glm4_moe_lite)` by config
-    alone: two iterations, a finite loss, a rising count, the new counters
-    in `learner_stats`, and a selection bias that no update has moved."""
-    policy = token_trainer.get_policy()
-    bias_before = jax.tree.map(np.asarray, policy.get_weights()["constants"])
-    head_before = np.asarray(policy.get_weights()["params"]["head"])
-    counts = []
-    for _ in range(2):
-        result = token_trainer.train()
-        stats = result["info"]["learner"]
-        assert np.isfinite(stats["total_loss"])
-        counts.append(result["timesteps_total"])
-    assert counts[1] - counts[0] == 8 * S and counts[0] > 0
-    kept = token_trainer.optimizer.learner_stats
-    assert kept["expert_load_max"] >= kept["expert_load_mean"] > 0
-    # 2 of 8 experts held: about a quarter of the (row, expert) pairs.
-    assert 0.05 < kept["experts_held_row_share"] < 0.6
-    # What the learner's product gathered: all, in the batched form these
-    # sizes take.
-    assert kept["dispatch_rows_share"] == 1.0
-    assert kept["experts_grouped_kernel"] == 0.0  # this is no TPU
-    assert 3.0 < kept["mtp_loss"] < 6.0  # ln 96 = 4.56 at random weights
-    assert kept["decode_rows_per_expert"] == 8 * 2 / 8
-    assert kept["decode_experts_batched"] == 1.0
-    assert kept["decode_cache_block"] == S
-    assert kept["decode_cache_read_share"] == 1.0
-    assert kept["causal_attention_fused"] == 0.0
-    assert kept["latent_cache_bytes_per_token"] == 3 * LATENT * 4
-    # The rollout's state: the latents, [rows, window, 24] a layer.
-    state, _ = token_trainer.optimizer._pstate
-    assert [c.shape for c in jax.tree.leaves(state["kv"])] == [
-        (8, S, LATENT)] * 3
-    after = policy.get_weights()
-    for a, b in zip(jax.tree.leaves(bias_before),
-                    jax.tree.leaves(after["constants"])):
-        assert np.array_equal(a, np.asarray(b))
-    assert not np.array_equal(head_before, np.asarray(after["params"]["head"]))
-
-
-@pytest.mark.parametrize("cfg,match", [
-    ({"num_experts": 8}, "not glm4_moe_lite's"),
-    ({"compute_path": "absorbed"}, "not glm4_moe_lite's"),
-    ({"topk_method": "greedy"}, "noaux_tc"),
-    ({"n_group": 2}, "n_group"),
-    ({"num_key_value_heads": 2}, "key/value heads"),
-    ({"experts_held": 6, "first_expert_held": 4}, "not among"),
-])
-def test_unknown_custom_model_config_keys_are_refused(cfg, match):
-    with pytest.raises(ValueError, match=match):
-        model = catalog.get_model(None, 96, {
-            "custom_model": "glm4_moe_lite",
-            "custom_model_config": dict(NET, **cfg)})
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                   model.initial_state(1), jnp.zeros((1, 1)))
-
-
-def test_the_tuned_example_is_the_benchmark_s_cell():
-    """`rllib train -f glm47-flash-token-impala.yaml` and the cell
-    `glm47_flash_token_anakin` are one trainer config, and the
-    configuration's file holds every published number of its source but
-    the ones it lists as reduced."""
-    import json
-
-    import yaml
-    root = os.path.dirname(BENCH)
-    with open(os.path.join(root, "ray_tpu", "rllib", "tuned_examples",
-                           "glm47-flash-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(
-            BENCH, "workloads", "glm47_flash_token_anakin.json")) as f:
-        cell = json.load(f)
-    with open(os.path.join(
-            BENCH, "configs", "impala_glm_4_7_flash.json")) as f:
-        config = json.load(f)
-    network = {k: v for k, v in config["network"].items()
-               if k != "param_count"}
-    want = dict(cell["trainer_config"], **config["trainer_config"])
-    want["model"] = dict(want["model"], custom_model_config=network)
-    want["num_tpus_for_learner"] = cell["chips"]
-    assert example["run"] == config["trainer"]
-    assert example["env"] == want.pop("env")
-    assert example["config"] == want
-    # The source's config (catalog row 81), the reduced keys apart.
+def test_the_configuration_s_file_holds_its_source_s_published_numbers():
+    """Every published number of the source (catalog row 81) but the ones
+    the file lists as reduced."""
+    _, _, config, network = configuration(FAMILY)
     published = {
         "hidden_size": 2048, "intermediate_size": 10240,
         "moe_intermediate_size": 1536, "num_attention_heads": 20,
@@ -930,4 +591,3 @@ def test_the_tuned_example_is_the_benchmark_s_cell():
         "vocab_size": 154880, "max_position_embeddings": 202752}
     assert (config["n_routed_experts"], network["n_routed_experts"],
             network["experts_held"]) == (8, 64, 8)
-    assert set(config["reduced"]) == set(config["reduced_why"])

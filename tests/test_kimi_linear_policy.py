@@ -1,40 +1,52 @@
-"""The `kimi_linear` token policy at a tiny size on the CPU: the model against
-the plain reference (`benchmark/lib/reference_kimi_linear.py`, whose KDA is the
-recurrence itself, one position at a time) in its causal form (a scan over
-chunks, the delta rule's triangular systems solved for every chunk at once
-ahead of it, outside every loop, against the library's solve) and in its
-decode through three kinds of state (a KDA layer's matrix a head and its
-convolutions' last inputs, the latent layer's cache); the scan's backward pass
-against `jax.grad` through the recurrence, for every KDA parameter; a decode
-that continues a causal pass from the state it handed over; resets inside a
-chunk, at a chunk's edge, and an episode one token long against separate
-passes; gates that lose more than e^100 inside one chunk; the expert layer
-that holds a share against the uncut layer; each named wrong mathematics
-refused by the cell's limits. V-trace's loss, its gradients, one update of the
-optimizer's own against the reference's and the trainer on the fused Anakin
-path stand in `tests/test_kimi_linear_update.py`, a file of its own so that
-the two run on two workers.
+"""The `kimi_linear` token policy at a tiny size on the CPU: the family's row,
+the checks it shares with the other families (`tests/token_families.py`: the
+model against the plain reference `benchmark/lib/reference_kimi_linear.py`,
+whose KDA is the recurrence itself, one position at a time, in its causal form
+and decoded through three kinds of state, a KDA layer's matrix a head and its
+convolutions' last inputs and the latent layer's cache; a decode that
+continues a causal pass from the state it handed over; resets inside a chunk,
+at a chunk's edge, and an episode one token long against separate passes; the
+model's gradient against the recurrence's; each named wrong mathematics
+refused by the cell's limits; the grouped form of the expert product; the
+cell's program from its shapes; the builder's refusals; the tuned example) and
+what is its own: the causal form as a scan over chunks, the delta rule's
+triangular systems solved for every chunk at once ahead of it, outside every
+loop, against the library's solve; the scan's backward pass against `jax.grad`
+through the recurrence, for every KDA parameter; gates that lose more than
+e^100 inside one chunk; a matrix state kept in bfloat16 refused; the expert
+layer that holds a share against the uncut layer. The loss and the loop:
+`tests/test_kimi_linear_update.py`.
 """
 
+import functools
 import json
 import os
-import sys
-import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from token_families import (  # noqa: F401: pytest collects what is named
+    BENCH, Family, build, causal_routed, configuration, count, decode_routed,
+    judged, model_gradients, read_by, seeded_norms, shapes_of,
+    share_of,
+    test_a_bfloat16_matrix_state_is_refused_by_the_decode_s_limit,
+    test_a_causal_pass_over_the_landed_rows_is_the_batched_pass,
+    test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over,
+    test_causal_pass_matches_reference,
+    test_custom_model_config_without_a_part_is_refused,
+    test_decode_through_every_kind_of_state_matches_reference
+    as test_decode_through_three_kinds_of_state_matches_reference,
+    test_limits_refuse_wrong_mathematics,
+    test_resets_inside_a_chunk_at_its_edge_and_an_episode_one_token_long,
+    test_the_cell_s_program_is_known_from_its_static_shapes,
+    test_the_model_s_gradient_is_the_reference_s,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_kimi_linear as reference
 
-from lib import reference_kimi_linear as reference  # noqa: E402
-
-from ray_tpu.models import catalog, transformer  # noqa: E402
-from ray_tpu.models.transformer import dropless_experts  # noqa: E402
+from ray_tpu.models import catalog, transformer
+from ray_tpu.models.transformer import dropless_experts
 
 # The cell's five layers: a dense KDA layer, then one period of expert
 # layers, K K M K; 4 heads; KDA heads of 16, chunks of 8 positions in
@@ -61,6 +73,124 @@ MATRIX, TAILS, CACHE = (4, 16, 16), (3, 3 * 64), (S, 16 + 8)
 RESET = jnp.zeros((B, S)).at[:, 11].set(1.0).at[:, 12].set(1.0).at[
     :, 16].set(1.0)
 EPISODES = ((0, 11), (11, 12), (12, 16), (16, S))
+KDA_PARAMETERS = {"kda_qkv", "kda_conv", "kda_fa", "kda_fb", "kda_a_log",
+                  "kda_dt_bias", "kda_b", "kda_ga", "kda_gb", "kda_o_norm",
+                  "kda_out"}
+# The cell's parameters at the published widths, by hand.
+KDA = (3 * 2304 * 4096 + 3 * 4096 * 4 + 2 * (2304 * 128 + 128 * 4096)
+       + 32 + 4096 + 2304 * 32 + 128 + 4096 * 2304)
+ATTENTION = (2304 * 32 * 192 + 2304 * 576 + 512 + 512 * 32 * 256
+             + 4096 * 2304)
+EXPERTS = 2304 * 256 + 9 * 3 * 2304 * 1024
+PARAMETERS = (2 * 20480 * 2304 + KDA + 3 * 2304 * 9216 + 3 * (KDA + EXPERTS)
+              + ATTENTION + EXPERTS + 5 * 2 * 2304 + 2304 + 2304 + 1)
+
+
+def latent_layer_shown(variables):
+    """The one latent layer's softmax far enough from uniform, and its
+    output large enough beside the other four layers', that its scale
+    shows in the logits."""
+    params = dict(variables["params"])
+    params["layer_3"] = dict(params["layer_3"],
+                             wq=2.0 * params["layer_3"]["wq"],
+                             wo=3.0 * params["layer_3"]["wo"])
+    return dict(variables, params=params)
+
+
+FAMILY = Family(
+    name="kimi_linear", net=NET, reference=reference, B=B, S=S,
+    # What a pass hands a decode: the one latent cache, four layers'
+    # convolution inputs, four layers' matrices, a key a kind.
+    state_kinds=("kv", "conv", "kda"), matrix_kind="kda",
+    state_shapes=lambda positions: (
+        [(positions, CACHE[1])], [TAILS] * 4, [MATRIX] * 4),
+    state_layers={"kv": [0, 0, 0, 1, 0], "conv": [1, 1, 1, 0, 1],
+                  "kda": [1, 1, 1, 0, 1]},
+    collections=frozenset({"params", "constants"}),
+    expert_layers=4, experts_per_token=2,  # the expert layers
+    # The norms' weights are seeded too, so that a norm's place shows.
+    seeded=seeded_norms(), limits_build=dict(bias_scale=0.2),
+    shown=latent_layer_shown, reset=RESET, episodes=EPISODES,
+    # Blocks in bfloat16, at these widths (heads of 16 values under a norm
+    # of their own): the reference itself, its blocks rounded to bfloat16,
+    # stands 6-12 % from its float32 self. No further off than twice that,
+    # and the routing within a tenth.
+    bfloat16=(2, 0.3, 0.1),
+    # A fragment of whole chunks and one that ends inside a chunk.
+    other_lengths=(S - 3,), length_key="model_max_length",
+    handed_atol=2e-5,
+    # Prefixes shorter than the taps, at a chunk's edge, inside a chunk.
+    prefixes=(2, 8, 13),
+    # A matrix state in bfloat16, the decays as drawn: by far more than
+    # the cell's limit (6 %: 3 % by step 50 and past 25 % by step 384).
+    carried_error=lambda wrong, kept: (
+        not wrong["ok"]
+        and wrong["errors"]["logits"] > 4 * reference.TOLERANCE),
+    # The latent layer alone reads a cache: off a TPU, all of it.
+    decode_counters={"decode_cache_read_share": 1.0},
+    wrong_updates={
+        "taps_reversed_in_the_gradient": dict(mutate="taps_reversed"),
+        "one_decay_a_head": dict(mutate="one_decay_a_head"),
+        "beta_out_of_the_subtraction": dict(
+            mutate="beta_out_of_subtraction"),
+        "a_rotated_latent_key": dict(mutate="k_r_rotated"),
+        "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0},
+                                 by="loss_error"),
+        "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
+        "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error")},
+    refused=(
+        ({"n_routed_experts": 8}, "not kimi_linear's"),
+        ({"layer_types": ["kda"]}, "not kimi_linear's"),
+        ({"q_lora_rank": 16}, "q_lora_rank"),
+        ({"mla_use_nope": False}, "mla_use_nope"),
+        ({"num_expert_group": 2}, "num_expert_group"),
+        ({"topk_group": 2}, "topk_group"),
+        ({"num_nextn_predict_layers": 1}, "num_nextn_predict_layers"),
+        ({"moe_router_activation_func": "softmax"}, "moe_router_activation"),
+        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+        ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
+        ({"num_key_value_heads": 2}, "key/value heads"),
+        ({"linear_attn_config": dict(LINEAR, kda_layers=[1, 2, 3])},
+         "names each of the 5 layers once"),
+        ({"linear_attn_config": dict(LINEAR, full_attn_layers=[4, 5])},
+         "names each of the 5 layers once"),
+        ({"linear_attn_config": dict(LINEAR, chunk_size=64)},
+         "linear_attn_config"),
+        ({"experts_held": 6, "first_expert_held": 4}, "not among")),
+    example="kimi-linear-token-impala.yaml",
+    cell="kimi_linear_token_anakin_4k",
+    config="impala_kimi_linear_48b_a3b",
+    # At the published widths: 602.4 M parameters; ONE latent cache of
+    # 4,096 positions, 1,152 bytes a position; four layers' convolution
+    # inputs, 294,912 bytes a sequence, and four layers' matrices,
+    # 8,388,608, whatever its length; a head's 192 padded to 256 takes the
+    # fused causal form.
+    program=dict(
+        rows=32, fragment=4096,
+        on_tpu={
+            # Under two rows a held expert: a rollout's step reads the
+            # chosen ones' matrices alone, and counts their share itself.
+            "decode_rows_per_expert": 1.0, "decode_experts_batched": 0.0,
+            "decode_experts_sparse": 1.0,
+            "decode_cache_block": 128, "decode_attention_kernel": 1.0,
+            "causal_attention_fused": 1.0,
+            "latent_cache_bytes_per_token": 1152,
+            "rotation_fused_layers": 0.0, "conv_layers": 4,
+            "conv_state_bytes_per_row": 294912,
+            "kda_layers": 4, "kda_state_bytes_per_row": 8388608,
+            "kda_chunk": 64, "state_step_kernel": 1.0},
+        # Off a TPU the cache is read whole, by XLA's products, and every
+        # held expert's matrices.
+        off_tpu={
+            "decode_experts_batched": 1.0, "decode_experts_sparse": 0.0,
+            "decode_experts_read_share": 1.0,
+            "causal_attention_fused": 0.0, "decode_cache_block": 4096,
+            "decode_attention_kernel": 0.0, "state_step_kernel": 0.0},
+        state={"kv": [((32, 4096, 576), "bfloat16")],
+               "conv": [((32, 3, 3 * 4096), "bfloat16")] * 4,
+               "kda": [((32, 32, 128, 128), "float32")] * 4},
+        # 602,435,713 trained parameters and four routers' 256 biases.
+        parameters=PARAMETERS + 4 * 256))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -72,208 +202,7 @@ def sub_blocks_of_four():
         yield
 
 
-def build(dtype, net=NET, bias_scale=None, tokens=S):
-    """(model, seeded variables, tokens). The norms' weights are seeded
-    too (one at initialisation), so that a norm's place shows."""
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "kimi_linear", "custom_model_config": net,
-        "compute_dtype": dtype})
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, tokens), 0, net["vocab_size"])
-    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
-                           model.initial_state(B), jnp.zeros((B, 1)))
-
-    def seeded(path, a):
-        if not path[-1].key.endswith("norm"):
-            return a
-        key = jax.random.fold_in(jax.random.PRNGKey(2), zlib.crc32(
-            jax.tree_util.keystr(path).encode()) % 2 ** 31)
-        return a * (1.0 + 0.5 * jax.random.normal(key, a.shape))
-    variables = dict(variables, params=jax.tree_util.tree_map_with_path(
-        seeded, variables["params"]))
-    if bias_scale is not None:
-        # A selection bias as large as the scores' own spread, so that
-        # choosing by score + bias and weighing by score differ.
-        variables = dict(variables, constants=jax.tree.map(
-            lambda b: b * (bias_scale / transformer.ROUTER_BIAS_SCALE),
-            variables["constants"]))
-    return model, variables, tokens
-
-
-def plain(variables, tokens, net=NET, experts=None, starts=None, **how):
-    """The reference's forward, compiled (its scans run op by op
-    otherwise)."""
-    return jax.jit(lambda v, t, e, s: reference.forward(
-        v, t, net, experts=e, starts=s, **how))(
-            variables, tokens, experts, starts)
-
-
-def judged(system, variables, tokens, net=NET, starts=None):
-    """The system's (logits, values, experts) against the reference held
-    to those experts: (outputs, routing)."""
-    logits, values, experts = system
-    held = plain(variables, tokens, net, experts, starts)
-    return (reference.compare((logits, values),
-                              (held["logits"], held["values"])),
-            reference.routing_verdict(experts, held["experts"],
-                                      held["select"]))
-
-
-def within_bfloat16(system, variables, tokens, net=NET, starts=None):
-    """Blocks in bfloat16, at these widths (heads of 16 values under a
-    norm of their own): the limits at the published widths are no measure
-    here, where the reference itself, its blocks rounded to bfloat16,
-    stands 6-12 % from its float32 self. The system is held to that: no
-    further off than twice the rounded reference, and its routing within a
-    tenth."""
-    outputs, routing = judged(system, variables, tokens, net, starts)
-    low = plain(variables, tokens, net, starts=starts,
-                round_to=jnp.bfloat16)
-    rounded, _ = judged((low["logits"], low["values"], low["experts"]),
-                        variables, tokens, net, starts)
-    assert routing["router_flips"] <= 0.1, routing
-    for name, error in outputs["errors"].items():
-        assert error <= 2 * rounded["errors"][name] < 0.3, (
-            outputs, rounded)
-
-
-def causal_routed(model, variables, tokens, reset=None):
-    (logits, values, state), kept = jax.jit(
-        lambda v, t, r: model.apply(v, t, None, r,
-                                    mutable=["routing", "counters"]))(
-            variables, tokens,
-            jnp.zeros(tokens.shape) if reset is None else reset)
-    return (logits, values, kept["routing"]["experts"][-1]), state, kept
-
-
-def decode_routed(model, variables, tokens, reset=None, jit=True,
-                  between=None):
-    """Every position one token at a time from empty state:
-    ((logits, values, experts), the last state, the counters a step).
-    `between` alters the state after every step."""
-    def step(token, state, reset):
-        return model.apply(variables, token, state, reset, method="decode",
-                           mutable=["routing", "counters"])
-    if jit:
-        step = jax.jit(step)
-    if reset is None:
-        reset = jnp.zeros(tokens.shape)
-    state = model.initial_state(tokens.shape[0])
-    logits, values, experts, counted = [], [], [], []
-    for t in range(tokens.shape[1]):
-        (step_l, step_v, state), kept = step(
-            tokens[:, t], state, reset[:, t])
-        if between is not None:
-            state = between(state)
-        logits.append(step_l)
-        values.append(step_v)
-        experts.append(kept["routing"]["experts"][-1])
-        counted.append({k: float(v[-1])
-                        for k, v in kept["counters"].items()})
-    return (jnp.stack(logits, 1), jnp.stack(values, 1),
-            jnp.stack(experts, 2)), state, counted
-
-
-def state_shapes(state):
-    return tuple([c.shape[1:] for c in jax.tree.leaves(state[key])]
-                 for key in ("kv", "conv", "kda"))
-
-
-STATE_SHAPES = ([CACHE], [TAILS] * 4, [MATRIX] * 4)
-
-
-# -- the model against the reference -----------------------------------
-@pytest.mark.parametrize("tokens", [S, S - 3])
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_causal_pass_matches_reference(dtype, tokens):
-    """A fragment of whole chunks and one that ends inside a chunk.
-    float32 blocks: to float32 accuracy, the same experts in every layer.
-    bfloat16 blocks: as near as the reference rounded where they round."""
-    net = dict(NET, model_max_length=tokens)
-    model, variables, tokens = build(dtype, net, tokens=tokens)
-    system, state, _ = causal_routed(model, variables, tokens)
-    assert system[2].shape == (4, B, tokens.shape[1], 2)  # expert layers
-    if dtype == "f32":
-        held = plain(variables, tokens, net, system[2])
-        assert np.array_equal(np.sort(system[2], -1),
-                              np.sort(held["experts"], -1))
-        for got, want in zip(system[:2], (held["logits"], held["values"])):
-            assert reference.relative_error(got, want) < 1e-5
-        # The matrix states the scan hands over are the recurrence's.
-        for got, want in zip(jax.tree.leaves(state["kda"]),
-                             held["kda_states"]):
-            assert reference.relative_error(got, want) < 1e-5
-    else:
-        within_bfloat16(system, variables, tokens, net)
-    # What the pass hands a decode: the one latent cache, four layers'
-    # convolution inputs, four layers' matrices, a key a kind.
-    cache = (tokens.shape[1], CACHE[1])
-    assert state_shapes(state) == ([cache], [TAILS] * 4, [MATRIX] * 4)
-    assert [len(kv) for kv in state["kv"]] == [0, 0, 0, 1, 0]
-    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(state["kda"]))
-    assert np.all(np.asarray(state["pos"]) == tokens.shape[1])
-
-
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_decode_through_three_kinds_of_state_matches_reference(dtype):
-    """Against the reference, which has neither cache nor state; and,
-    float32, against the causal pass and the state it returns."""
-    model, variables, tokens = build(dtype)
-    system, state, counted = decode_routed(model, variables, tokens,
-                                           jit=dtype == "f32")
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        causal, handed, _ = causal_routed(model, variables, tokens)
-        assert reference.relative_error(system[0], causal[0]) < 1e-5
-        assert np.array_equal(system[2], causal[2])
-        for got, want in zip(jax.tree.leaves(state),
-                             jax.tree.leaves(handed)):
-            np.testing.assert_allclose(got, want, atol=2e-5)
-    else:
-        within_bfloat16(system, variables, tokens)
-    assert state_shapes(state) == STATE_SHAPES
-    # The matrix state is float32 whatever the blocks compute in; the
-    # convolutions' inputs and the cache are the blocks'.
-    blocks = jnp.float32 if dtype == "f32" else jnp.bfloat16
-    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(state["kda"]))
-    assert all(a.dtype == blocks for a in jax.tree.leaves(
-        (state["conv"], state["kv"])))
-    # The latent layer alone reads a cache: off a TPU, all of it.
-    assert counted[-1] == {"decode_cache_read_share": 1.0}
-
-
-def scalar_of(logits, values):
-    weight = jax.random.normal(jax.random.PRNGKey(7), logits.shape)
-    return jnp.sum(logits * weight) + jnp.sum(jnp.sin(values))
-
-
-def kda_gradients(variables, tokens, reset=None):
-    """The gradient of one scalar of the outputs with respect to every
-    parameter, through the system's scan over chunks and through the
-    reference's recurrence."""
-    model, _, _ = build("f32")
-
-    def system(params):
-        logits, values, _ = model.apply(
-            dict(variables, params=params), tokens, None,
-            jnp.zeros(tokens.shape) if reset is None else reset)
-        return scalar_of(logits, values)
-
-    def recurrence(params):
-        out = reference.forward(dict(variables, params=params), tokens, NET,
-                                starts=reset)
-        return scalar_of(out["logits"], out["values"])
-    return (jax.jit(jax.grad(system))(variables["params"]),
-            jax.jit(jax.grad(recurrence))(variables["params"]))
-
-
-KDA_PARAMETERS = {"kda_qkv", "kda_conv", "kda_fa", "kda_fb", "kda_a_log",
-                  "kda_dt_bias", "kda_b", "kda_ga", "kda_gb", "kda_o_norm",
-                  "kda_out"}
-
-
+# -- the scan over chunks against the recurrence ----------------------------
 _OPERATOR = {}  # compiled once for the fragment whole, once cut by resets
 
 
@@ -283,7 +212,7 @@ def operator_gradients(variables, layer, reset=None):
     layer's parameters) through the system's scan over chunks and through
     the reference's recurrence."""
     if (reset is None) not in _OPERATOR:
-        model, _, _ = build("f32")
+        model, _, _ = build(FAMILY, "f32")
         x = jax.random.normal(jax.random.PRNGKey(5),
                               (B, S, NET["hidden_size"]))
         weight = jax.random.normal(jax.random.PRNGKey(6), x.shape)
@@ -316,7 +245,7 @@ def test_the_scan_s_backward_pass_is_the_recurrence_s_gradient(reset, gates):
     bodies) is `jax.grad`'s through the recurrence, to 1e-5; the fragment
     whole and cut by resets; the gates as drawn and so fast that a channel
     loses more than e^100 inside one chunk."""
-    _, variables, _ = build("f32")
+    _, variables, _ = build(FAMILY, "f32")
     if gates == "fast":
         variables = fast_gates(variables)
     for layer in ("layer_0", "layer_2"):
@@ -329,78 +258,6 @@ def test_the_scan_s_backward_pass_is_the_recurrence_s_gradient(reset, gates):
             assert np.isfinite(got[name]).all()
             assert reference.relative_error(
                 got[name], want[name]) < 1e-5, (layer, name)
-
-
-@pytest.mark.parametrize("reset", [None, RESET], ids=["whole", "resets"])
-def test_the_model_s_gradient_is_the_reference_s(reset):
-    """Every parameter of the five blocks, through four scans and the
-    latent layer: what float32 leaves after five blocks' worth of sums in
-    two orders (the operator alone agrees to 1e-5: the test above)."""
-    _, variables, tokens = build("f32")
-    got, want = kda_gradients(variables, tokens, reset)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert reference.relative_error(a, b) < 5e-5
-
-
-def test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over():
-    """Prefixes shorter than the taps, at a chunk's edge, inside a
-    chunk: the pass's state is the matrix after its last position
-    and the convolutions' last three inputs (zeros where the episode is
-    shorter), and the decode goes on from it."""
-    model, variables, tokens = build("f32")
-    decode = jax.jit(lambda token, state, reset: model.apply(
-        variables, token, state, reset))
-    (full, _, _), _, _ = causal_routed(model, variables, tokens)
-    for prefix in (2, 8, 13):
-        _, state, _ = causal_routed(model, variables, tokens[:, :prefix])
-        for t in range(prefix, S):
-            step, _, state = decode(tokens[:, t:t + 1], state,
-                                    jnp.zeros((B, 1)))
-            assert reference.relative_error(
-                step[:, 0], full[:, t]) < 1e-5, (prefix, t)
-
-
-def test_resets_inside_a_chunk_at_its_edge_and_an_episode_one_token_long():
-    """Four episodes in a fragment, the second one token long, the last
-    beginning with a chunk: what separate passes give, in both forms and
-    in the reference; the state handed over is the last episode's alone."""
-    model, variables, tokens = build("f32")
-    both, state, _ = causal_routed(model, variables, tokens, RESET)
-    parts = []
-    for a, b in EPISODES:
-        if b - a > 1:
-            parts.append(causal_routed(model, variables, tokens[:, a:b]))
-        else:
-            # A causal pass takes two tokens or more: the lone token as a
-            # decode step from empty state.
-            lone, _, _ = model.apply(variables, tokens[:, a:b],
-                                     model.initial_state(B), jnp.ones((B, 1)))
-            parts.append(((lone,), None, None))
-    separate = jnp.concatenate([p[0][0] for p in parts], axis=1)
-    assert reference.relative_error(both[0], separate) < 1e-5
-    last = parts[-1][1]
-    for key in ("conv", "kda"):
-        for got, want in zip(jax.tree.leaves(state[key]),
-                             jax.tree.leaves(last[key])):
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    assert np.all(np.asarray(state["pos"]) == S - 16)
-    outputs, routing = judged(both, variables, tokens, starts=RESET)
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    assert routing["router_flips"] == 0.0
-    stepped, stepped_state, _ = decode_routed(model, variables, tokens, RESET)
-    assert reference.relative_error(stepped[0], both[0]) < 1e-5
-    for got, want in zip(jax.tree.leaves(stepped_state["kda"]),
-                         jax.tree.leaves(state["kda"])):
-        np.testing.assert_allclose(got, want, atol=1e-5)
-    # A fragment that ends one token into an episode hands over one
-    # input of the convolutions, two zero rows, and a matrix of rank one.
-    _, _, short = model.apply(
-        variables, tokens[:, :13], None, RESET[:, :13])
-    for held in jax.tree.leaves(short["conv"]):
-        assert not np.any(np.asarray(held[:, :2]))
-        assert np.any(np.asarray(held[:, 2]))
-    for held in jax.tree.leaves(short["kda"]):
-        assert np.all(np.linalg.matrix_rank(np.asarray(held)) == 1)
 
 
 def fast_gates(variables, by=16.0):
@@ -419,22 +276,23 @@ def test_gates_that_lose_e100_inside_a_chunk_stay_finite_and_agree():
     from exp(-G): the whole model's outputs and gradients are finite and
     the recurrence's, which multiplies by exp(g) one position at a time
     (the operator's own gradients at these gates: the test above)."""
-    model, variables, tokens = build("f32")
+    built = build(FAMILY, "f32")
+    _, variables, tokens = built
     variables = fast_gates(variables)
     lp = variables["params"]["layer_0"]
     assert CHUNK * float(jnp.min(jnp.exp(lp["kda_a_log"]))) * 15.0 > 100.0
     for reset in (None, RESET):
-        system, state, _ = causal_routed(model, variables, tokens, reset)
+        system, state, _ = causal_routed(built, variables, tokens, reset)
         assert all(np.isfinite(a).all() for a in system[:2])
-        outputs, routing = judged(system, variables, tokens, starts=reset)
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        assert routing["router_flips"] == 0.0
-    got, want = kda_gradients(variables, tokens, RESET)
+        verdicts, _ = judged(FAMILY, system, variables, tokens, starts=reset)
+        assert max(verdicts["outputs"]["errors"].values()) < 1e-5, verdicts
+        assert verdicts["routing"]["router_flips"] == 0.0
+    got, want = model_gradients(FAMILY, variables, tokens, RESET)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         # Sums of products of decays e^-100 apart, in two orders.
         assert np.isfinite(a).all()
         assert reference.relative_error(a, b) < 2e-4
-    stepped, _, _ = decode_routed(model, variables, tokens, RESET)
+    stepped, _, _ = decode_routed(built, variables, tokens, RESET)
     assert reference.relative_error(stepped[0], system[0]) < 1e-5
     # The factorised product the scan avoids overflows at these gates.
     n = transformer.rms_norm(
@@ -484,21 +342,10 @@ def test_the_chunked_scan_is_the_recurrence_at_any_chunk_and_sub_block():
         return jnp.stack(out, axis=1), S
     want, want_state = recurrence()
     for chunk in (4, 8, 16):
-        got, state = transformer.kda_chunked(q, k, v, g, beta, episode, chunk)
+        got, state = jax.jit(transformer.kda_chunked, static_argnums=6)(
+            q, k, v, g, beta, episode, chunk)
         assert reference.relative_error(got, want) < 1e-5, chunk
         assert reference.relative_error(state, want_state) < 1e-5, chunk
-
-
-def read_by(run, operands):
-    """(outputs, final state, gradients by q, k, v, g, beta) of a scalar
-    that reads every output and every entry of the final state."""
-    def scalar(*operands):
-        o, S = run(*operands)
-        return (jnp.sum(jnp.sin(o) * jnp.arange(1, o.shape[1] + 1)[
-            None, :, None, None]) + jnp.sum(jnp.cos(S))), (o, S)
-    grads, (o, S) = jax.jit(jax.grad(
-        scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
-    return (o, S) + grads
 
 
 @pytest.fixture(scope="module")
@@ -636,56 +483,6 @@ def test_no_loop_of_the_lowered_scan_holds_a_triangular_solve():
     assert not any("triangular" in body for body in bodies)
 
 
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The comparison fails each named error and blocks computed a
-    precision lower: the reference, so altered, in the system's place
-    against itself, by its outputs or by its routing. The fragment holds
-    resets, so that a convolution that reaches across one shows."""
-    _, variables, tokens = build("f32", bias_scale=0.2)
-    # The one latent layer's softmax far enough from uniform, and its
-    # output large enough beside the other four layers', that its scale
-    # shows in the logits.
-    params = dict(variables["params"])
-    params["layer_3"] = dict(params["layer_3"],
-                             wq=2.0 * params["layer_3"]["wq"],
-                             wo=3.0 * params["layer_3"]["wo"])
-    variables = dict(variables, params=params)
-    if wrong == "float8_e4m3":
-        got = plain(variables, tokens, starts=RESET, round_to=wrong)
-    else:
-        got = plain(variables, tokens, starts=RESET, mutate=wrong)
-    outputs, routing = judged(
-        (got["logits"], got["values"], got["experts"]), variables, tokens,
-        starts=RESET)
-    assert not (outputs["ok"] and routing["ok"]), (wrong, outputs, routing)
-
-
-def test_a_bfloat16_matrix_state_is_refused_by_the_decode_s_limit():
-    """The state is summed into at every step, so keeping it in bfloat16
-    (rounded after every step; everything else float32) is no rounding of
-    a block's output: its error is carried on and added to. Over a few
-    hundred steps the logits leave the reference by far more than the
-    cell's limit (6 %: here 3 % by step 50 and past 25 % by step 384),
-    where the float32 state's stay at 1e-5."""
-    steps = 384
-    net = dict(NET, model_max_length=steps)
-    model, variables, tokens = build("f32", net, tokens=steps)
-
-    def rounded(state):
-        return dict(state, kda=jax.tree.map(
-            lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
-            state["kda"]))
-    kept, _, _ = decode_routed(model, variables, tokens)
-    lost, _, _ = decode_routed(model, variables, tokens, between=rounded)
-    outputs, _ = judged(kept, variables, tokens, net)
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    held = plain(variables, tokens, net, kept[2])
-    wrong = reference.compare(lost[:2], (held["logits"], held["values"]))
-    assert not wrong["ok"] and wrong["errors"]["logits"] > 4 * \
-        reference.TOLERANCE, wrong
-
-
 # -- the expert layer that holds a share ---------------------------------
 def test_the_32_shares_add_up_to_the_uncut_layer():
     """32 shares of 2 of 64 experts: their parts, with the shared expert
@@ -707,15 +504,12 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
     m = transformer.rms_norm(h, jnp.ones(H), 1e-5, jnp.float32)
     net = dict(NET, num_experts=E, num_experts_per_token=k)
 
-    def share_of(first, size):
-        return dict(lp, **{w: lp[w][first:first + size]
-                           for w in ("w_gate", "w_up", "w_down")})
-
+    @functools.partial(jax.jit, static_argnums=(1, 2))
     def layer(first, size, shared=1):
         share = dict(net, experts_held=size, first_expert_held=first,
                      num_shared_experts=shared)
         with jax.default_matmul_precision("highest"):
-            return reference._moe(share_of(first, size), bias, h, m, share,
+            return reference._moe(share_of(lp, first, size), bias, h, m, share,
                                   lambda a: a, None, None)
     whole, chosen, _ = layer(0, E)
     with jax.default_matmul_precision("highest"):
@@ -741,7 +535,7 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
         n, p, i = (jnp.tile(a, (reps, 1)) for a in (rows, top_p, top_i))
         routed, landed = jnp.zeros_like(n), 0
         for first in range(0, E, held):
-            s = share_of(first, held)
+            s = share_of(lp, first, held)
             part, sizes, _ = dropless_experts(
                 n, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E)
             routed, landed = routed + part, landed + int(jnp.sum(sizes))
@@ -753,79 +547,21 @@ def test_the_32_shares_add_up_to_the_uncut_layer():
     assert not transformer.experts_batched(64 * rows.shape[0], k, E)
 
 
-def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
-        grouped_pass_is_the_batched_pass):
-    grouped_pass_is_the_batched_pass(*build("f32"))
-
-
-def published_cut():
-    with open(os.path.join(
-            BENCH, "configs", "impala_kimi_linear_48b_a3b.json")) as f:
-        network = json.load(f)["network"]
-    return {k: v for k, v in network.items() if k != "param_count"}
-
-
-def shapes_of(model):
-    return jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.eval_shape(lambda: model.initial_state(1)),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32))
-
-
-def count(tree):
-    return sum(int(np.prod(v.shape)) for v in jax.tree.leaves(tree))
-
-
-def test_the_cell_s_program_is_known_from_its_static_shapes():
-    """At the published widths: 602.4 M parameters; ONE latent cache of
-    4,096 positions, 1,152 bytes a position; four layers' convolution
-    inputs, 294,912 bytes a sequence, and four layers' matrices, 8,388,608,
-    whatever its length; a head's 192 padded to 256 takes the fused causal
-    form; nothing but shapes is built."""
-    net = published_cut()
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "kimi_linear", "custom_model_config": net})
-    assert model.static_counters(32, 4096, "tpu") == {
-        # Under two rows a held expert: a rollout's step reads the chosen
-        # ones' matrices alone, and counts their share itself.
-        "decode_rows_per_expert": 1.0, "decode_experts_batched": 0.0,
-        "decode_experts_sparse": 1.0,
-        "decode_cache_block": 128, "decode_attention_kernel": 1.0,
-        "causal_attention_fused": 1.0, "latent_cache_bytes_per_token": 1152,
-        "rotation_fused_layers": 0.0, "conv_layers": 4, "conv_state_bytes_per_row": 294912,
-        "kda_layers": 4, "kda_state_bytes_per_row": 8388608,
-        "kda_chunk": 64, "state_step_kernel": 1.0}
+def test_the_published_cut_s_parameters_are_the_hand_count_s():
+    """The latent layer has no query compression; a layer is its KDA and
+    its experts; `causal_fused` takes a head's 192 once padded to 256."""
+    network = configuration(FAMILY)[3]
+    model = transformer.kimi_linear_from_config(
+        network["vocab_size"], network)
     assert not transformer.causal_fused(4096, 192, 128)
     assert model._latent_key_width(4096) == 256
     assert model._latent_key_width(S) == 192
-    # Off a TPU the cache is read whole, by XLA's products.
-    off = model.static_counters(32, 4096, "cpu")
-    assert (off["causal_attention_fused"], off["decode_cache_block"],
-            off["decode_attention_kernel"], off["state_step_kernel"]) == (
-                0.0, 4096, 0.0, 0.0)
-    state = jax.eval_shape(lambda: model.initial_state(32))
-    assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["kv"])] == [
-        ((32, 4096, 576), jnp.bfloat16)]
-    assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["conv"])] == [
-        ((32, 3, 3 * 4096), jnp.bfloat16)] * 4
-    assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["kda"])] == [
-        ((32, 32, 128, 128), jnp.float32)] * 4
     variables = shapes_of(model)
-    assert set(variables) == {"params", "constants"}
     latent = variables["params"]["layer_3"]
     assert latent["wq"].shape == (2304, 32 * 192)
     assert not {"wq_a", "q_a_norm", "wq_b"} & set(latent)
-    kda = (3 * 2304 * 4096 + 3 * 4096 * 4 + 2 * (2304 * 128 + 128 * 4096)
-           + 32 + 4096 + 2304 * 32 + 128 + 4096 * 2304)
-    attention = (2304 * 32 * 192 + 2304 * 576 + 512 + 512 * 32 * 256
-                 + 4096 * 2304)
-    experts = 2304 * 256 + 9 * 3 * 2304 * 1024
-    assert count(variables["params"]["layer_1"]) == kda + experts + 2 * 2304
-    assert count(variables["params"]) == (
-        2 * 20480 * 2304 + kda + 3 * 2304 * 9216 + 3 * (kda + experts)
-        + attention + experts + 5 * 2 * 2304 + 2304 + 2304 + 1)
-    assert count(variables["params"]) == 602_435_713
+    assert count(variables["params"]["layer_1"]) == KDA + EXPERTS + 2 * 2304
+    assert count(variables["params"]) == PARAMETERS == 602_435_713
     assert count(variables["constants"]) == 4 * 256
 
 
@@ -834,7 +570,7 @@ def test_the_counters_count_each_kind_of_state_from_its_own_layers():
     the convolution state is read off the state's own leaves: a KDA
     layer's is 3 x 12,288 values, not (taps - 1) x hidden. The second and
     the fourth configuration read what they read: 5,760 and 32,768."""
-    model, _, _ = build("bf16")
+    model, _, _ = build(FAMILY, "bf16")
     counted = model.static_counters(4, S, "cpu")
     assert counted["latent_cache_bytes_per_token"] == 1 * (16 + 8) * 2
     assert (counted["conv_layers"], counted["conv_state_bytes_per_row"]) == (
@@ -860,34 +596,6 @@ def test_the_counters_count_each_kind_of_state_from_its_own_layers():
     assert accepted["impala_lfm2_8b_a1b"]["conv_layers"] == 4
 
 
-@pytest.mark.parametrize("cfg,match", [
-    ({"n_routed_experts": 8}, "not kimi_linear's"),
-    ({"layer_types": ["kda"]}, "not kimi_linear's"),
-    ({"q_lora_rank": 16}, "q_lora_rank"),
-    ({"mla_use_nope": False}, "mla_use_nope"),
-    ({"num_expert_group": 2}, "num_expert_group"),
-    ({"topk_group": 2}, "topk_group"),
-    ({"num_nextn_predict_layers": 1}, "num_nextn_predict_layers"),
-    ({"moe_router_activation_func": "softmax"}, "moe_router_activation"),
-    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
-    ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
-    ({"num_key_value_heads": 2}, "key/value heads"),
-    ({"linear_attn_config": dict(LINEAR, kda_layers=[1, 2, 3])},
-     "names each of the 5 layers once"),
-    ({"linear_attn_config": dict(LINEAR, full_attn_layers=[4, 5])},
-     "names each of the 5 layers once"),
-    ({"linear_attn_config": dict(LINEAR, chunk_size=64)}, "linear_attn_config"),
-    ({"experts_held": 6, "first_expert_held": 4}, "not among"),
-])
-def test_custom_model_config_without_a_part_is_refused(cfg, match):
-    with pytest.raises(ValueError, match=match):
-        model = catalog.get_model(None, 96, {
-            "custom_model": "kimi_linear",
-            "custom_model_config": dict(NET, **cfg)})
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                   model.initial_state(1), jnp.zeros((1, 1)))
-
-
 def test_keys_left_out_have_the_published_model_s_values():
     """An empty description is Kimi-Linear-48B-A3B itself: 27 layers, 20
     of them KDA in the period K K K M, the first dense, 48 B parameters
@@ -909,30 +617,10 @@ def test_keys_left_out_have_the_published_model_s_values():
     assert 2.9e9 < per_token < 3.6e9
 
 
-def test_the_tuned_example_is_the_benchmark_s_cell():
-    """`rllib train -f kimi-linear-token-impala.yaml` and the cell
-    `kimi_linear_token_anakin_4k` are one trainer config, and the
-    configuration's file holds every published number of its source but
-    the ones it lists as reduced."""
-    import yaml
-    root = os.path.dirname(BENCH)
-    with open(os.path.join(root, "ray_tpu", "rllib", "tuned_examples",
-                           "kimi-linear-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(
-            BENCH, "workloads", "kimi_linear_token_anakin_4k.json")) as f:
-        cell = json.load(f)
-    with open(os.path.join(
-            BENCH, "configs", "impala_kimi_linear_48b_a3b.json")) as f:
-        config = json.load(f)
-    network = {k: v for k, v in config["network"].items()
-               if k != "param_count"}
-    want = dict(cell["trainer_config"], **config["trainer_config"])
-    want["model"] = dict(want["model"], custom_model_config=network)
-    want["num_tpus_for_learner"] = cell["chips"]
-    assert example["run"] == config["trainer"]
-    assert example["env"] == want.pop("env")
-    assert example["config"] == want
+def test_the_configuration_s_file_holds_its_source_s_published_numbers():
+    """Every published number of the source (the catalog's row) but the
+    ones the file lists as reduced."""
+    _, _, config, network = configuration(FAMILY)
     # The source's config (the catalog's row), the reduced keys apart.
     published = dict(
         transformer.KIMI_LINEAR_PUBLISHED, **transformer.KIMI_LINEAR_FIXED,
@@ -961,8 +649,7 @@ def test_the_tuned_example_is_the_benchmark_s_cell():
     assert (network["num_experts"], network["experts_held"]) == (256, 8)
     assert config["reduced"] == list(reduced) + ["env"]
     assert set(config["reduced"]) == set(config["reduced_why"])
-    # 602,435,713 trained parameters and four routers' 256 biases.
-    assert config["network"]["param_count"] == 602_436_737
+    assert config["network"]["param_count"] == PARAMETERS + 4 * 256
     model = transformer.kimi_linear_from_config(20480, network)
     assert model.layer_types == (
         "kda", "kda", "kda", "full_attention", "kda")

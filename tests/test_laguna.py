@@ -1,41 +1,42 @@
-"""The `laguna` token policy at a tiny size on the CPU: the model against
-the plain reference (`benchmark/lib/reference_laguna.py`) with an attention
-whose GEOMETRY is a layer kind's (a full layer of 4 query heads under YaRN
-over half a head beside window layers of 6 under the default rotation over
-the whole head, all over 2 cached heads, a gate a head), fragments longer
-than three windows and than the preset's original positions, so that the
-rings turn and the scaled rotation leaves its trained range; the decode
-through two full caches and three rings against the causal pass; the decode
-kernel's two forms at the cell's cached row; YaRN's frequencies at the
-PUBLISHED parameters against hand-computed values; the expert layer that
-holds a share against the uncut layer; the published parameter count; each
-named wrong mathematics refused by the cell's limits; V-trace's loss, its
-gradients and one update of the optimizer's own against the reference's; the
-builder's refusals; and the trainer on the fused Anakin path.
+"""The `laguna` token policy at a tiny size on the CPU: the family's row, the
+checks it shares with the other families (`tests/token_families.py`: the model
+against the plain reference `benchmark/lib/reference_laguna.py` in its causal
+form and decoded through two full caches and three rings, each named wrong
+mathematics refused by the cell's limits, the grouped form of the expert
+product, the cell's program from its shapes, the builder's refusals, the tuned
+example) and what is its own: an attention whose GEOMETRY is a layer kind's (a
+full layer of 4 query heads under YaRN over half a head beside window layers
+of 6 under the default rotation over the whole head, all over 2 cached heads,
+a gate a head), fragments longer than three windows and than the preset's
+original positions, so that the rings turn and the scaled rotation leaves its
+trained range; the decode kernel's two forms at the cell's cached row; YaRN's
+frequencies at the PUBLISHED parameters against hand-computed values; the
+expert layer that holds a share against the uncut layer; the published
+parameter count. The loss and the loop: `tests/test_laguna_update.py`.
 """
 
-import json
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from token_families import (  # noqa: F401: pytest collects what is named
+    Family, build, causal_routed, configuration, decode_routed, shapes_of,
+    share_of,
+    test_a_causal_pass_over_the_landed_rows_is_the_batched_pass,
+    test_causal_pass_matches_reference,
+    test_custom_model_config_without_a_part_is_refused,
+    test_decode_through_every_kind_of_state_matches_reference
+    as test_decode_through_two_full_caches_and_three_rings_matches_reference,
+    test_limits_refuse_wrong_mathematics,
+    test_the_cell_s_program_is_known_from_its_static_shapes,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_laguna as reference
 
-from lib import reference_glm4_moe_lite  # noqa: E402
-from lib import reference_laguna as reference  # noqa: E402
-
-from ray_tpu.models import catalog, decode_attention, transformer  # noqa: E402
-from ray_tpu.models.transformer import dropless_experts  # noqa: E402
-from ray_tpu.rllib import sample_batch as sb  # noqa: E402
-from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
-from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss  # noqa: E402
+from ray_tpu.models import decode_attention, transformer
+from ray_tpu.models.transformer import dropless_experts
 
 # The published layers 0-4 in small: full + dense, three window layers and a
 # full one with experts; 4 / 6 query heads over 2 cached heads of 16; 4 of 16
@@ -70,120 +71,87 @@ CACHES = [(S, 32)] + [(WINDOW, 32)] * 3 + [(S, 32)]
 PUBLISHED_YARN = transformer.LAGUNA_PUBLISHED[
     "rope_parameters"]["full_attention"]
 
-
-def build(dtype, net=NET, sharp=1.0):
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "laguna", "custom_model_config": net,
-        "compute_dtype": dtype})
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, S), 0, net["vocab_size"])
-    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
-                           model.initial_state(B), jnp.zeros((B, 1)))
-    if sharp != 1.0:
-        # Queries and keys large enough that a softmax has a few heavy
-        # terms, so that one position more or less in it, or another
-        # rotation, shows.
-        variables = jax.tree_util.tree_map_with_path(
-            lambda path, a: a * sharp if path[-1].key in ("wq", "wk", "wg")
-            else a, variables)
-    return model, variables, tokens
-
-
-def judged(system, variables, tokens, net=NET):
-    """The system's (logits, values, experts) against the reference held
-    to those experts: (outputs, routing)."""
-    logits, values, experts = system
-    held = reference.forward(variables, tokens, net, experts=experts)
-    return (reference.compare((logits, values),
-                              (held["logits"], held["values"])),
-            reference.routing_verdict(experts, held["experts"],
-                                      held["select"]))
-
-
-def causal_routed(model, variables, tokens):
-    (logits, values, state), kept = model.apply(
-        variables, tokens, None, jnp.zeros(tokens.shape),
-        mutable=["routing", "counters"])
-    return (logits, values, kept["routing"]["experts"][-1]), state, kept
-
-
-def decode_routed(model, variables, tokens, jit=True):
-    """Every position one token at a time from an empty window:
-    ((logits, values, experts), the last state, the counters a step)."""
-    def step(token, state):
-        return model.apply(variables, token, state, jnp.zeros(B),
-                           method="decode", mutable=["routing", "counters"])
-    if jit:
-        step = jax.jit(step)
-    state = model.initial_state(B)
-    logits, values, experts, counted = [], [], [], []
-    for t in range(tokens.shape[1]):
-        (step_l, step_v, state), kept = step(tokens[:, t], state)
-        logits.append(step_l)
-        values.append(step_v)
-        experts.append(kept["routing"]["experts"][-1])
-        counted.append({k: float(v[-1])
-                        for k, v in kept["counters"].items()})
-    return (jnp.stack(logits, 1), jnp.stack(values, 1),
-            jnp.stack(experts, 2)), state, counted
-
-
-# -- the model against the reference -----------------------------------
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_causal_pass_matches_reference(dtype):
-    """float32 blocks: to float32 accuracy, the same experts in every
-    layer. bfloat16 blocks: the limits written beside the reference."""
-    model, variables, tokens = build(dtype)
-    system, state, _ = causal_routed(model, variables, tokens)
+FAMILY = Family(
+    name="laguna", net=NET, reference=reference, B=B, S=S,
+    # What a pass hands a decode: the context's positions of a full layer,
+    # a ring of the window of each window layer.
+    state_shapes=lambda positions: (
+        [(positions, 32)] * 2 + [(WINDOW, 32)] * 6 + [(positions, 32)] * 2,),
+    state_layers={"kv": [2, 2, 2, 2, 2]},
     # Four of the five layers route.
-    assert system[2].shape == (4, B, S, 3)
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-    else:
-        # 384 (token, layer) pairs: a flip is 0.26 %, and a near-tie.
-        assert routing["router_flips"] <= 0.1
-        assert routing["max_flip_gap"] <= reference.MAX_FLIP_GAP
-        assert outputs["ok"], outputs
-    # What the pass hands a decode: the context's positions of a full
-    # layer, a ring of the window of each window layer.
-    assert [[c.shape[1:] for c in layer] for layer in state["kv"]] == [
-        [shape] * 2 for shape in CACHES]
-    assert np.all(np.asarray(state["pos"]) == S)
-
-
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_decode_through_two_full_caches_and_three_rings_matches_reference(
-        dtype):
-    """32 positions through rings of 8: every slot is overwritten three
-    times, and the second half lies beyond YaRN's 16 original positions.
-    Against the reference, which has no cache; and, float32, against the
-    causal pass, which keeps every position and masks the window."""
-    model, variables, tokens = build(dtype)
-    system, state, counted = decode_routed(model, variables, tokens,
-                                           jit=dtype == "f32")
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        causal, _, _ = causal_routed(model, variables, tokens)
-        assert reference.relative_error(system[0], causal[0]) < 1e-5
-        assert reference.relative_error(system[1], causal[1]) < 1e-5
-        assert np.array_equal(system[2], causal[2])
-    else:
-        assert routing["router_flips"] <= 0.1
-        assert outputs["ok"], outputs
-    assert [[c.shape[1:] for c in layer] for layer in state["kv"]] == [
-        [shape] * 2 for shape in CACHES]
+    expert_layers=4, experts_per_token=3,
+    sharp_keys=("wq", "wk", "wg"), limits_build=dict(sharp=4.0),
     # One block a cache at this size: a full layer reads its 32 positions,
     # a ring its 8 of the context's 32.
-    assert counted[-1] == {
+    decode_counters={
         "decode_cache_read_share": pytest.approx((2 + 3 / 4) / 5),
         "decode_cache_read_share_full": 1.0,
-        "decode_cache_read_share_window": pytest.approx(1 / 4)}
+        "decode_cache_read_share_window": pytest.approx(1 / 4)},
+    wrong_updates={
+        "no_gate_in_the_gradient": dict(mutate="no_attention_gate"),
+        "full_layers_rotated_as_window_layers": dict(
+            mutate="default_rope_on_the_full_layer"),
+        "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0},
+                                 by="loss_error"),
+        "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
+        "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error")},
+    refused=(
+        ({"n_routed_experts": 8}, "not laguna's"),
+        ({"rope_theta": 10000}, "not laguna's"),
+        ({"gating": False}, "gating"),
+        ({"attention_bias": True}, "attention_bias"),
+        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+        ({"moe_apply_router_weight_on_input": True},
+         "router_weight_on_input"),
+        ({"model_type": "qwen3_moe"}, "model_type"),
+        ({"num_key_value_heads": 4}, "groups"),
+        ({"num_attention_heads_per_layer": [4, 6]}, "names each"),
+        ({"layer_types": ["full_attention", "linear_attention"] * 3},
+         "names each"),
+        ({"mlp_layer_types": ["dense", "sparse", "dense", "sparse",
+                              "sparse"]}, "leading"),
+        ({"rope_parameters": dict(ROPE, full_attention=dict(
+            ROPE["full_attention"], rope_type="llama3"))}, "rope_type"),
+        ({"rope_parameters": dict(ROPE, sliding_attention=dict(
+            ROPE["sliding_attention"], factor=4))}, "rope_parameters"),
+        ({"partial_rotary_factor": 0.25}, "partial_rotary_factor"),
+        ({"experts_held": 12, "first_expert_held": 8}, "not among")),
+    example="laguna-token-impala.yaml", cell="laguna_token_anakin_8k",
+    config="impala_laguna_xs2_33b_a3b",
+    # What the benchmark's cell is, from shapes alone: 32 rows, five layers
+    # of the published widths, 32 of 256 experts held; a TPU's program
+    # takes the decode kernel in every layer (a ring of 512 is four
+    # blocks), the chosen experts' kernel in the rollout, the fused causal
+    # form whose window layers visit two tiles a row of tiles; the caches
+    # count a ring as 512 positions and a full cache as the episode.
+    program=dict(
+        rows=32, fragment=8192, minibatch=(8192,),
+        on_tpu={
+            "decode_rows_per_expert": 1.0, "decode_experts_batched": 0.0,
+            "decode_experts_sparse": 1.0,
+            "decode_cache_block": decode_attention.BLOCK,
+            "decode_attention_kernel": 1.0, "causal_attention_fused": 1.0,
+            "rotation_fused_layers": 5.0, "experts_grouped_kernel": 1.0,
+            "kv_cache_bytes_per_token": (
+                2 * 8 * 128 * 2 * (2 * 8192 + 3 * 512) / 8192),
+            "window_layers": 3, "kv_groups": 6,
+            # 16 tiles a side: 136 causal, 16 + 15 within a window of one
+            # tile.
+            "causal_window_tiles_kept": 31 / 136},
+        off_tpu={
+            "decode_experts_batched": 1.0, "decode_experts_sparse": 0.0,
+            "decode_experts_read_share": 1.0, "decode_cache_block": 8192,
+            "decode_attention_kernel": 0.0, "causal_attention_fused": 0.0,
+            "rotation_fused_layers": 0.0, "experts_grouped_kernel": 0.0,
+            "causal_window_tiles_kept": 1.0},
+        state={"kv": [((32, 8192, 1024), "bfloat16")] * 2
+               + [((32, 512, 1024), "bfloat16")] * 6
+               + [((32, 8192, 1024), "bfloat16")] * 2},
+        # The trainer's own count is the configuration's.
+        parameters=691_625_985))
 
 
+# -- the decode: the kernel's forms, a prefill --------------------------------
 @pytest.fixture
 def lanes_here(kernel_here, monkeypatch):
     """`conftest.kernel_here`, the grouped caches through the form that
@@ -203,15 +171,16 @@ def test_a_decode_through_either_kernel_form_is_the_causal_pass(
     if form == "lanes":
         request.getfixturevalue("lanes_here")
     net = dict(NET, sliding_window=2 * WINDOW)
-    model, variables, tokens = build("f32", net)
-    system, state, counted = decode_routed(model, variables, tokens)
+    built = build(FAMILY, "f32", net, fresh=True)
+    _, variables, tokens = built
+    system, state, counted = decode_routed(built, variables, tokens)
     for t, step in enumerate(counted):
         held = 8 * (t // 8 + 1)
         assert step["decode_cache_read_share_full"] == pytest.approx(
             held / S)
         assert step["decode_cache_read_share_window"] == pytest.approx(
             min(held, 2 * WINDOW) / S)
-    causal, _, _ = causal_routed(model, variables, tokens)
+    causal, _, _ = causal_routed(built, variables, tokens)
     assert reference.relative_error(system[0], causal[0]) < 1e-5
     assert reference.relative_error(system[1], causal[1]) < 1e-5
     assert np.array_equal(system[2], causal[2])
@@ -265,40 +234,21 @@ def test_prefill_then_ring_decode_and_a_reset_inside_a_fragment():
     """A fragment whose rows start a new episode at different steps, then
     a decode that goes on from the state it hands over: the logits of the
     episode's own positions, whatever came before it in the fragment."""
-    model, variables, tokens = build("f32")
+    built = build(FAMILY, "f32")
+    _, variables, tokens = built
     half = S // 2
     reset = jnp.zeros((B, S)).at[:, half].set(1.0)
-    (both, _, state), _ = model.apply(
-        variables, tokens, None, reset, mutable=["routing", "counters"])
-    (alone, _, _), _ = model.apply(
-        variables, tokens[:, half:], None, jnp.zeros((B, half)),
-        mutable=["routing", "counters"])
+    (both, _, _), state, _ = causal_routed(built, variables, tokens, reset)
+    (alone, _, _), _, _ = causal_routed(built, variables, tokens[:, half:])
     assert reference.relative_error(both[:, half:], alone) < 1e-5
     # The state is the second episode's: a decode goes on from position 16.
     more = jax.random.randint(jax.random.PRNGKey(2), (B, 4), 0, 96)
-    (want, _, _), _ = model.apply(
-        variables, jnp.concatenate([tokens[:, half:], more], axis=1), None,
-        jnp.zeros((B, half + 4)), mutable=["routing", "counters"])
+    (want, _, _), _, _ = causal_routed(
+        built, variables, jnp.concatenate([tokens[:, half:], more], axis=1))
     for t in range(4):
-        (step, _, state), _ = model.apply(
-            variables, more[:, t:t + 1], state, jnp.zeros((B, 1)),
-            mutable=["routing", "counters"])
+        step, _, state = built.decode(
+            variables, more[:, t:t + 1], state, jnp.zeros((B, 1)))
         assert reference.relative_error(step[:, 0], want[:, half + t]) < 1e-5
-
-
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The comparison fails each named error and blocks computed a
-    precision lower: the reference, so altered, in the system's place
-    against itself, by its outputs or by its routing."""
-    _, variables, tokens = build("f32", sharp=4.0)
-    if wrong == "float8_e4m3":
-        got = reference.forward(variables, tokens, NET, round_to=wrong)
-    else:
-        got = reference.forward(variables, tokens, NET, mutate=wrong)
-    outputs, routing = judged(
-        (got["logits"], got["values"], got["experts"]), variables, tokens)
-    assert not (outputs["ok"] and routing["ok"]), (wrong, outputs, routing)
 
 
 # -- the geometry by layer kind: the parts, one at a time ----------------
@@ -306,7 +256,7 @@ def test_two_head_counts_and_two_rotations_stand_in_one_stack():
     """A layer's W_q, W_o and gate have its own kind's heads; its rotation
     is its kind's: the full layers YaRN over half a head, the window layers
     the default over all of it."""
-    model, variables, _ = build("f32")
+    model, variables, _ = build(FAMILY, "f32")
     shapes = jax.tree.map(lambda a: a.shape, variables["params"])
     for i, heads in enumerate([4, 6, 6, 6, 4]):
         layer = shapes[f"layer_{i}"]
@@ -396,14 +346,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         "shared_down": normal(W, H, over=6)})
     x = jnp.asarray(rng.normal(size=(2, 12, H)), jnp.float32)
 
-    def share_of(first, size):
-        return dict(lp, **{w: lp[w][first:first + size]
-                           for w in ("w_gate", "w_up", "w_down")})
-
+    @functools.partial(jax.jit, static_argnums=(1,))
     def layer(first, size, **other):
         with jax.default_matmul_precision("highest"):
             return reference._layer(
-                dict(share_of(first, size), **other), x,
+                dict(share_of(lp, first, size), **other), x,
                 dict(net, experts_held=size, first_expert_held=first), 1,
                 lambda a: a, None, None)
     whole, chosen, _ = layer(0, E)
@@ -435,7 +382,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         rows, p, i = (jnp.tile(a, (reps, 1)) for a in (m, top_p, top_i))
         routed, landed = jnp.zeros_like(rows), 0
         for first in range(0, E, held):
-            s = share_of(first, held)
+            s = share_of(lp, first, held)
             part, sizes, _ = dropless_experts(
                 rows, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E,
                 jax.nn.silu)
@@ -446,10 +393,6 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     assert transformer.experts_batched(m.shape[0], k, E)
     assert not transformer.experts_batched(64 * m.shape[0], k, E)
 
-
-def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
-        grouped_pass_is_the_batched_pass):
-    grouped_pass_is_the_batched_pass(*build("f32"))
 
 
 def test_the_published_config_counts_the_published_parameters():
@@ -463,11 +406,7 @@ def test_the_published_config_counts_the_published_parameters():
         48, 64, 64, 64, 48]
     assert model.layer_kind(0).rotation.scaling[0] == 64
     assert (model.cache_len(0), model.cache_len(1)) == (262144, 512)
-    variables = jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.eval_shape(lambda: model.initial_state(1)),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32))
+    variables = shapes_of(model)
     assert set(variables) == {"params"}
     count = sum(int(np.prod(a.shape))
                 for a in jax.tree.leaves(variables["params"]))
@@ -482,277 +421,11 @@ def test_the_published_config_counts_the_published_parameters():
     assert round((count + elementwise) / 1e9, 2) == 34.07
 
 
-def test_the_cell_s_program_is_known_from_its_static_shapes():
-    """What the benchmark's cell is, from shapes alone: 32 rows, five
-    layers of the published widths, 32 of 256 experts held; a TPU's
-    program takes the decode kernel in every layer (a ring of 512 is four
-    blocks), the chosen experts' kernel in the rollout, the fused causal
-    form whose window layers visit two tiles a row of tiles; the caches
-    count a ring as 512 positions and a full cache as the episode."""
-    with open(os.path.join(
-            BENCH, "configs", "impala_laguna_xs2_33b_a3b.json")) as f:
-        config = json.load(f)
-    network = {k: v for k, v in config["network"].items()
-               if k != "param_count"}
-    model = transformer.laguna_from_config(network["vocab_size"], network)
-    counted = model.static_counters(32, 8192, "tpu", 8192)
-    assert counted["decode_attention_kernel"] == 1.0
-    assert counted["decode_cache_block"] == decode_attention.BLOCK
-    assert counted["causal_attention_fused"] == 1.0
-    assert counted["decode_experts_sparse"] == 1.0
-    assert counted["experts_grouped_kernel"] == 1.0
-    assert (counted["window_layers"], counted["kv_groups"]) == (3, 6)
-    # 16 tiles a side: 136 causal, 16 + 15 within a window of one tile.
-    assert counted["causal_window_tiles_kept"] == 31 / 136
-    assert counted["kv_cache_bytes_per_token"] == (
-        2 * 8 * 128 * 2 * (2 * 8192 + 3 * 512) / 8192)
-    on_cpu = model.static_counters(32, 8192, "cpu", 8192)
-    assert on_cpu["decode_attention_kernel"] == 0.0
-    assert on_cpu["causal_attention_fused"] == 0.0
-    # The trainer's own count is the configuration's.
-    variables = jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.eval_shape(lambda: model.initial_state(1)),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32))
-    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(
-        variables)) == config["network"]["param_count"] == 691_625_985
-
-
-# -- the loss and the loop ------------------------------------------------
-def token_trainer_config(**over):
-    cfg = dict(
-        env="TokenBigram-v0",
-        env_config={"vocab_size": NET["vocab_size"], "episode_len": S},
-        anakin=True, num_workers=0, num_envs_per_worker=4,
-        rollout_fragment_length=S, train_batch_size=4 * S,
-        sgd_minibatch_size=2 * S, num_sgd_iter=1,
-        anakin_updates_per_call=1, min_iter_time_s=0, lr=6e-4, seed=3,
-        model={"custom_model": "laguna", "custom_model_config": NET,
-               "compute_dtype": "f32"})
-    cfg.update(over)
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def token_trainer():
-    trainer = IMPALATrainer(config=token_trainer_config())
-    yield trainer
-    trainer.stop()
-
-
-def seeded_batch(frags, seed):
-    """`frags` whole episodes of a walk (`TokenBigram-v0`: the action
-    taken is the next observation), as the learner's packed batch and as
-    the reference's."""
-    rng = np.random.default_rng(seed)
-    walk = rng.integers(0, NET["vocab_size"], size=(frags, S + 1))
-    ref_batch = {
-        "tokens": walk[:, :S], "actions": walk[:, 1:],
-        "rewards": rng.integers(0, 2, size=(frags, S)).astype(np.float32),
-        "behaviour_logp": rng.uniform(-5.0, -4.0, size=(frags, S)).astype(
-            np.float32)}
-    dones = np.zeros((frags, S), np.float32)
-    dones[:, -1] = 1.0
-    batch = {
-        sb.OBS: jnp.asarray(ref_batch["tokens"].reshape(-1), jnp.int32),
-        sb.ACTIONS: jnp.asarray(ref_batch["actions"].reshape(-1), jnp.int32),
-        sb.REWARDS: jnp.asarray(ref_batch["rewards"].reshape(-1)),
-        sb.DONES: jnp.asarray(dones.reshape(-1)),
-        sb.ACTION_LOGP: jnp.asarray(ref_batch["behaviour_logp"].reshape(-1)),
-        sb.VF_PREDS: jnp.zeros(frags * S, jnp.float32),
-        sb.BOOTSTRAP_OBS: jnp.asarray(walk[:, S], jnp.int32)}
-    return batch, ref_batch
-
-
-def test_vtrace_minibatch_loss_and_gradients_match_reference(token_trainer):
-    """One minibatch of whole episodes through the system's loss (packed
-    rows, ACTION_LOGP, the bootstrap step differentiated through the full
-    caches and the rings) and through `jax.grad` of the plain reference:
-    every parameter's gradient, the gates', both kinds' W_q and the
-    routers' among them."""
-    policy = token_trainer.get_policy()
-    batch, ref_batch = seeded_batch(B, 5)
-    variables = jax.tree.map(jnp.asarray, policy.get_weights())
-    assert set(variables) == {"params"}
-    (total, stats), grads = jax.value_and_grad(
-        lambda v: vtrace_loss(policy, v, batch, None, {}),
-        has_aux=True)(variables)
-    (want_total, _), want_grads = jax.value_and_grad(
-        lambda v: reference.vtrace_loss(v, ref_batch, NET, policy.config),
-        has_aux=True)(variables)
-    np.testing.assert_allclose(total, want_total, rtol=1e-4)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads["params"])
-    want_flat = jax.tree.leaves(want_grads["params"])
-    assert len(flat) == len(want_flat)
-    for (path, got), want in zip(flat, want_flat):
-        scale = float(jnp.max(jnp.abs(want))) + 1e-8
-        assert float(jnp.max(jnp.abs(got - want))) <= 2e-3 * scale, path
-    assert stats["expert_load_mean"] > 0
-    assert 0.0 < stats["experts_held_row_share"] < 1.0
-
-
-def one_update(trainer, seed=7, **wrong):
-    """One update of seeded whole episodes by the optimizer's own step
-    (`AnakinOptimizer.learn`) from the trainer's parameters and optimizer
-    state, against the reference's loss, gradients and Adam: what the
-    benchmark's driver does at the cell's minibatch. `wrong` plants a
-    fault in the reference's side."""
-    policy, opt = trainer.get_policy(), trainer.optimizer
-    cfg = dict(policy.config, **wrong.get("cfg", {}))
-    batch, ref_batch = seeded_batch(opt.minibatch // opt.T, seed)
-
-    def flat(tree):
-        return {jax.tree_util.keystr(path): np.asarray(leaf)
-                for path, leaf in
-                jax.tree_util.tree_flatten_with_path(tree)[0]}
-    before = policy.params
-    (adam,) = [s for s in jax.tree.leaves(
-        policy.opt_state, is_leaf=lambda s: hasattr(s, "mu"))
-        if hasattr(s, "mu")]
-    after, _, stats = jax.jit(opt.learn)(
-        before, policy.opt_state, batch, jax.random.PRNGKey(0))
-    (want_loss, _), grads = jax.value_and_grad(
-        lambda p: reference.vtrace_loss(
-            {"params": p}, ref_batch, NET, cfg,
-            mutate=wrong.get("mutate")), has_aux=True)(before["params"])
-    count = int(adam.count)
-    assert count > 0
-    want_change, norm = reference_glm4_moe_lite.adam_update(
-        flat(grads), flat(adam.mu["params"]), flat(adam.nu["params"]),
-        count, cfg)
-    assert norm > 0
-    old, new = flat(before["params"]), flat(after["params"])
-    return reference.compare_update(stats["total_loss"], want_loss, {
-        name: float(reference.change_error(old[name], new[name], want))
-        for name, want in want_change.items()})
-
-
-def test_one_update_by_the_optimizer_s_own_step_matches_reference(
-        token_trainer):
-    token_trainer.train()  # Adam's moments are not zero
-    found = one_update(token_trainer)
-    assert found["ok"], found
-    assert found["loss_error"] < 1e-5 and found["update_error"] < 1e-2, found
-
-
-WRONG_UPDATES = {
-    "no_gate_in_the_gradient": dict(mutate="no_attention_gate"),
-    "full_layers_rotated_as_window_layers": dict(
-        mutate="default_rope_on_the_full_layer"),
-    "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0}, by="loss_error"),
-    "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
-    "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error"),
-}
-
-
-@pytest.mark.parametrize("wrong", WRONG_UPDATES)
-def test_update_limits_refuse_a_wrong_update(wrong, token_trainer):
-    """The comparison of one update fails each named error, planted in
-    the reference's side: by the loss, by the worst parameter's change, or
-    by either."""
-    token_trainer.train()
-    fault = dict(WRONG_UPDATES[wrong])
-    by = fault.pop("by", None)
-    found = one_update(token_trainer, **fault)
-    assert not found["ok"], found
-    if by:
-        limits = {"loss_error": reference.UPDATE_LOSS_TOLERANCE,
-                  "update_error": reference.UPDATE_TOLERANCE}
-        assert found[by] > limits[by], found
-
-
-def test_laguna_token_trainer_trains_on_the_fused_path(token_trainer):
-    """`IMPALATrainer(anakin, TokenBigram-v0, laguna)` by config alone:
-    two iterations, a finite loss, a rising count, a policy state whose
-    caches differ in length by layer, the counters in `learner_stats`."""
-    counts = []
-    for _ in range(2):
-        result = token_trainer.train()
-        stats = result["info"]["learner"]
-        assert np.isfinite(stats["total_loss"])
-        counts.append(result["timesteps_total"])
-    assert counts[1] - counts[0] == 4 * S and counts[0] > 0
-    kept = token_trainer.optimizer.learner_stats
-    assert kept["expert_load_max"] >= kept["expert_load_mean"] > 0
-    # 4 of 16 experts held: about a quarter of the (row, expert) pairs.
-    assert 0.05 < kept["experts_held_row_share"] < 0.6
-    assert kept["dispatch_rows_share"] == 1.0
-    assert kept["decode_rows_per_expert"] == 4 * 3 / 16
-    assert kept["decode_cache_block"] == S
-    # One block a cache: a full layer's 32 positions, a ring's 8 of 32.
-    assert kept["decode_cache_read_share_full"] == 1.0
-    assert kept["decode_cache_read_share_window"] == pytest.approx(1 / 4)
-    assert kept["decode_cache_read_share"] == pytest.approx(
-        (2 + 3 / 4) / 5)
-    assert kept["causal_attention_fused"] == 0.0
-    assert kept["decode_attention_kernel"] == 0.0  # this is no TPU
-    assert (kept["window_layers"], kept["kv_groups"]) == (3, 2)
-    # float32 here: 2 x 2 heads x 16 x 4 B a position a layer.
-    assert kept["kv_cache_bytes_per_token"] == 256 * (
-        2 * S + 3 * WINDOW) / S
-    state, _ = token_trainer.optimizer._pstate
-    assert [c.shape for c in jax.tree.leaves(state["kv"])] == [
-        (4,) + shape for shape in CACHES for _ in range(2)]
-
-
-# -- the builder -----------------------------------------------------------
-@pytest.mark.parametrize("cfg,match", [
-    ({"n_routed_experts": 8}, "not laguna's"),
-    ({"rope_theta": 10000}, "not laguna's"),
-    ({"gating": False}, "gating"),
-    ({"attention_bias": True}, "attention_bias"),
-    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
-    ({"moe_apply_router_weight_on_input": True}, "router_weight_on_input"),
-    ({"model_type": "qwen3_moe"}, "model_type"),
-    ({"num_key_value_heads": 4}, "groups"),
-    ({"num_attention_heads_per_layer": [4, 6]}, "names each"),
-    ({"layer_types": ["full_attention", "linear_attention"] * 3},
-     "names each"),
-    ({"mlp_layer_types": ["dense", "sparse", "dense", "sparse", "sparse"]},
-     "leading"),
-    ({"rope_parameters": dict(ROPE, full_attention=dict(
-        ROPE["full_attention"], rope_type="llama3"))}, "rope_type"),
-    ({"rope_parameters": dict(ROPE, sliding_attention=dict(
-        ROPE["sliding_attention"], factor=4))}, "rope_parameters"),
-    ({"partial_rotary_factor": 0.25}, "partial_rotary_factor"),
-    ({"experts_held": 12, "first_expert_held": 8}, "not among"),
-])
-def test_custom_model_config_without_a_part_is_refused(cfg, match):
-    with pytest.raises(ValueError, match=match):
-        model = catalog.get_model(None, 96, {
-            "custom_model": "laguna",
-            "custom_model_config": dict(NET, **cfg)})
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                   model.initial_state(1), jnp.zeros((1, 1)))
-
-
-def test_the_tuned_example_is_the_benchmark_s_cell():
-    """`rllib train -f laguna-token-impala.yaml` and the cell
-    `laguna_token_anakin_8k` are one trainer config, and the
-    configuration's file holds every published number of its source but
-    the ones it lists as reduced."""
-    import yaml
-    root = os.path.dirname(BENCH)
-    with open(os.path.join(root, "ray_tpu", "rllib", "tuned_examples",
-                           "laguna-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(
-            BENCH, "workloads", "laguna_token_anakin_8k.json")) as f:
-        cell = json.load(f)
-    with open(os.path.join(
-            BENCH, "configs", "impala_laguna_xs2_33b_a3b.json")) as f:
-        config = json.load(f)
-    network = {k: v for k, v in config["network"].items()
-               if k != "param_count"}
-    want = dict(cell["trainer_config"], **config["trainer_config"])
-    want["model"] = dict(want["model"], custom_model_config=network)
-    want["num_tpus_for_learner"] = cell["chips"]
-    assert example["run"] == config["trainer"]
-    assert example["env"] == want.pop("env")
-    assert example["config"] == want
-    # The source's config (the builder's copy of the catalog's row), the
-    # reduced keys apart; the lists stay whole and are read by their head.
+def test_the_configuration_s_file_holds_its_source_s_published_numbers():
+    """Every published number of the source (the builder's copy of the
+    catalog's row) but the ones the file lists as reduced; the lists stay
+    whole and are read by their head."""
+    _, _, config, network = configuration(FAMILY)
     published = dict(transformer.LAGUNA_PUBLISHED, **transformer.LAGUNA_FIXED)
     assert set(config["reduced"]) == {
         "num_hidden_layers", "num_experts", "vocab_size",

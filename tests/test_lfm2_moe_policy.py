@@ -1,36 +1,40 @@
-"""The `lfm2_moe` token policy at a tiny size on the CPU: the model against
-the plain reference (`benchmark/lib/reference_lfm2_moe.py`) in its causal form
-and in its decode through two kinds of state (a convolution layer's last two
-gated inputs, an attention layer's cache); a decode that continues a causal
-pass from the state it handed over; a reset inside a fragment and an episode
-one token long against separate passes; the expert layer that holds a share
-against the uncut layer; the renormalisation's epsilon; each named wrong
-mathematics refused by the cell's limits; V-trace's loss, its gradients and
-one update of the optimizer's own against the reference's; and the trainer
-on the fused Anakin path.
+"""The `lfm2_moe` token policy at a tiny size on the CPU: the family's row,
+the checks it shares with the other families (`tests/token_families.py`: the
+model against the plain reference `benchmark/lib/reference_lfm2_moe.py` in its
+causal form and decoded through two kinds of state, a convolution layer's last
+two gated inputs and an attention layer's cache; a decode that continues a
+causal pass from the state it handed over; each named wrong mathematics
+refused by the cell's limits; the grouped form of the expert product; the
+cell's program from its shapes; the builder's refusals; the tuned example) and
+what is its own: a reset inside a fragment and an episode one token long
+against separate passes; the convolution against the sum written out; the
+renormalisation's epsilon; the expert layer that holds a share against the
+uncut layer. The loss and the loop: `tests/test_lfm2_moe_update.py`.
 """
 
-import os
-import sys
-import zlib
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from token_families import (  # noqa: F401: pytest collects what is named
+    Family, build, causal_routed, configuration, count, decode_routed,
+    held_to_reference, seeded_norms, shapes_of, share_of, state_shapes,
+    test_a_causal_pass_over_the_landed_rows_is_the_batched_pass,
+    test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over,
+    test_causal_pass_matches_reference,
+    test_custom_model_config_without_a_part_is_refused,
+    test_decode_through_every_kind_of_state_matches_reference
+    as test_decode_through_both_kinds_of_state_matches_reference,
+    test_limits_refuse_wrong_mathematics,
+    test_the_cell_s_program_is_known_from_its_static_shapes,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_lfm2_moe as reference
 
-from lib import reference_lfm2_moe as reference  # noqa: E402
-
-from ray_tpu.models import catalog, transformer  # noqa: E402
-from ray_tpu.models.transformer import dropless_experts  # noqa: E402
-from ray_tpu.rllib import sample_batch as sb  # noqa: E402
-from ray_tpu.rllib.agents.impala import IMPALATrainer  # noqa: E402
-from ray_tpu.rllib.agents.impala.vtrace_policy import vtrace_loss  # noqa: E402
+from ray_tpu.models import catalog, transformer
+from ray_tpu.models.transformer import dropless_experts
 
 # The cell's five layers: a dense convolution layer, then one period of
 # expert layers, an attention and three convolutions; 8 query heads in 2
@@ -49,136 +53,82 @@ NET = dict(vocab_size=96, hidden_size=64, num_attention_heads=8,
 CONV_STATE, CACHE = (2, 64), (S, 2 * 8)
 # A reset inside the fragment, and an episode one token long after it.
 RESET = jnp.zeros((B, S)).at[:, 11].set(1.0).at[:, 12].set(1.0)
+# The cell's parameters at the published widths, by hand: the embedding
+# counted once (the head is tied to it).
+CONV = 4 * 2048 * 2048 + 2048 * 3
+ATTENTION = 2 * 2048 * 2048 + 2 * 2048 * 512 + 2 * 64
+EXPERTS = 2048 * 32 + 8 * 3 * 2048 * 1792
+PARAMETERS = (16384 * 2048 + CONV + 3 * 2048 * 7168 + ATTENTION + EXPERTS
+              + 3 * (CONV + EXPERTS) + 5 * 2 * 2048 + 2048 + 2048 + 1)
 
-
-def build(dtype, net=NET, bias_scale=None):
-    """(model, seeded variables, tokens). The norms' weights are seeded
-    too (one at initialisation): a norm with unit weights commutes with
-    RoPE, and a per-head norm's place would not show."""
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "lfm2_moe", "custom_model_config": net,
-        "compute_dtype": dtype})
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, S), 0, net["vocab_size"])
-    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
-                           model.initial_state(B), jnp.zeros((B, 1)))
-
-    def seeded(path, a):
-        if not path[-1].key.endswith("norm"):
-            return a
-        key = jax.random.fold_in(jax.random.PRNGKey(2), zlib.crc32(
-            jax.tree_util.keystr(path).encode()) % 2 ** 31)
-        return a * (1.0 + 0.5 * jax.random.normal(key, a.shape))
-    variables = dict(variables, params=jax.tree_util.tree_map_with_path(
-        seeded, variables["params"]))
-    if bias_scale is not None:
-        # A selection bias as large as the scores' own spread, so that
-        # choosing by score + bias and weighing by score differ.
-        variables = dict(variables, constants=jax.tree.map(
-            lambda b: b * (bias_scale / transformer.ROUTER_BIAS_SCALE),
-            variables["constants"]))
-    return model, variables, tokens
-
-
-def judged(system, variables, tokens, net=NET, starts=None):
-    """The system's (logits, values, experts) against the reference held
-    to those experts: (outputs, routing)."""
-    logits, values, experts = system
-    held = reference.forward(variables, tokens, net, experts=experts,
-                             starts=starts)
-    return (reference.compare((logits, values),
-                              (held["logits"], held["values"])),
-            reference.routing_verdict(experts, held["experts"],
-                                      held["select"]))
-
-
-def causal_routed(model, variables, tokens, reset=None):
-    (logits, values, state), kept = model.apply(
-        variables, tokens, None,
-        jnp.zeros(tokens.shape) if reset is None else reset,
-        mutable=["routing", "counters"])
-    return (logits, values, kept["routing"]["experts"][-1]), state, kept
-
-
-def decode_routed(model, variables, tokens, reset=None, jit=True):
-    """Every position one token at a time from empty state:
-    ((logits, values, experts), the last state, the counters a step)."""
-    def step(token, state, reset):
-        return model.apply(variables, token, state, reset, method="decode",
-                           mutable=["routing", "counters"])
-    if jit:
-        step = jax.jit(step)
-    if reset is None:
-        reset = jnp.zeros(tokens.shape)
-    state = model.initial_state(B)
-    logits, values, experts, counted = [], [], [], []
-    for t in range(tokens.shape[1]):
-        (step_l, step_v, state), kept = step(
-            tokens[:, t], state, reset[:, t])
-        logits.append(step_l)
-        values.append(step_v)
-        experts.append(kept["routing"]["experts"][-1])
-        counted.append({k: float(v[-1])
-                        for k, v in kept["counters"].items()})
-    return (jnp.stack(logits, 1), jnp.stack(values, 1),
-            jnp.stack(experts, 2)), state, counted
-
-
-def state_shapes(state):
-    return ([c.shape[1:] for c in jax.tree.leaves(state["kv"])],
-            [c.shape[1:] for c in jax.tree.leaves(state["conv"])])
-
-
-# -- the model against the reference -----------------------------------
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_causal_pass_matches_reference(dtype):
-    """float32 blocks: to float32 accuracy, the same experts in every
-    layer. bfloat16 blocks: the limits written beside the reference."""
-    model, variables, tokens = build(dtype)
-    system, state, _ = causal_routed(model, variables, tokens)
-    assert system[2].shape == (4, B, S, 2)  # the expert layers
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-    else:
-        assert routing["router_flips"] <= 0.1
-        assert routing["max_flip_gap"] <= reference.MAX_FLIP_GAP
-        assert outputs["ok"], outputs
-    # What the pass hands a decode: the one attention layer's K and V,
-    # and two rows of every convolution layer, under a key of their own.
-    assert state_shapes(state) == ([CACHE] * 2, [CONV_STATE] * 4)
-    assert [len(kv) for kv in state["kv"]] == [0, 2, 0, 0, 0]
-    assert np.all(np.asarray(state["pos"]) == S)
-
-
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_decode_through_both_kinds_of_state_matches_reference(dtype):
-    """Against the reference, which has neither cache nor state; and,
-    float32, against the causal pass and the state it returns."""
-    model, variables, tokens = build(dtype)
-    system, state, counted = decode_routed(model, variables, tokens,
-                                           jit=dtype == "f32")
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        causal, handed, _ = causal_routed(model, variables, tokens)
-        assert reference.relative_error(system[0], causal[0]) < 1e-5
-        assert np.array_equal(system[2], causal[2])
-        for got, want in zip(jax.tree.leaves(state),
-                             jax.tree.leaves(handed)):
-            np.testing.assert_allclose(got, want, atol=1e-5)
-    else:
-        assert routing["router_flips"] <= 0.1
-        assert outputs["ok"], outputs
-    assert state_shapes(state) == ([CACHE] * 2, [CONV_STATE] * 4)
-    assert state["conv"][0].dtype == (
-        jnp.float32 if dtype == "f32" else jnp.bfloat16)
+FAMILY = Family(
+    name="lfm2_moe", net=NET, reference=reference, B=B, S=S,
+    # What a pass hands a decode: the one attention layer's K and V, and
+    # two rows of every convolution layer, under a key of their own.
+    state_kinds=("kv", "conv"),
+    state_shapes=lambda positions: (
+        [(positions, 2 * 8)] * 2, [CONV_STATE] * 4),
+    state_layers={"kv": [0, 2, 0, 0, 0], "conv": [1, 0, 1, 1, 1]},
+    collections=frozenset({"params", "constants"}),
+    expert_layers=4, experts_per_token=2,  # the expert layers
+    seeded=seeded_norms(), limits_build=dict(bias_scale=0.2),
+    # The head's alone.
+    refused_by={"untied_head": lambda verdicts:
+                verdicts["outputs"]["errors"]["value"] == 0.0},
+    reset=RESET, handed_atol=1e-5,
+    # Prefixes shorter than the taps, as long, and longer.
+    prefixes=(2, 3, 5, 13),
     # The attention layer alone reads a cache: grouped, so all of it.
-    assert counted[-1] == {"decode_cache_read_share": 1.0}
+    decode_counters={"decode_cache_read_share": 1.0},
+    wrong_updates={
+        "taps_reversed_in_the_gradient": dict(mutate="taps_reversed"),
+        "qk_norm_after_rope": dict(mutate="qk_norm_after_rope"),
+        "an_untied_head": dict(mutate="untied_head"),
+        "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0},
+                                 by="loss_error"),
+        "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
+        "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error")},
+    refused=(
+        ({"n_routed_experts": 8}, "not lfm2_moe's"),
+        ({"head_dim": 16}, "not lfm2_moe's"),
+        ({"conv_bias": True}, "conv_bias"),
+        ({"use_expert_bias": False}, "use_expert_bias"),
+        ({"tie_embedding": False}, "tie_embedding"),
+        ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
+        ({"num_key_value_heads": 3}, "groups"),
+        ({"layer_types": ["conv", "full_attention"]}, "layer_types has 2"),
+        ({"layer_types": ["conv", "sliding_attention"] + TYPES[2:]},
+         "sliding_attention"),
+        ({"experts_held": 6, "first_expert_held": 4}, "not among")),
+    example="lfm2-token-impala.yaml", cell="lfm2_token_anakin_4k",
+    config="impala_lfm2_8b_a1b",
+    # At the published widths: 507.8 M parameters, the embedding counted
+    # once; one cache of 4,096 positions, 2,048 bytes a position, and four
+    # states of two rows, 32,768 bytes a sequence whatever its length;
+    # heads of 64 take the fused causal form.
+    program=dict(
+        rows=64, fragment=4096,
+        on_tpu={
+            "decode_rows_per_expert": 8.0, "decode_experts_batched": 1.0,
+            "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
+            "decode_cache_block": 128, "decode_attention_kernel": 1.0,
+            "causal_attention_fused": 1.0,
+            "kv_cache_bytes_per_token": 2048.0,
+            # Heads of 64 are half a lane tile: the learner's rotation
+            # keeps `rope` (`rowwise.whole_tiles`).
+            "rotation_fused_layers": 0.0,
+            "kv_groups": 4, "conv_layers": 4,
+            "conv_state_bytes_per_row": 32768},
+        # Off a TPU the cache is read whole, by XLA's products.
+        off_tpu={"causal_attention_fused": 0.0, "decode_cache_block": 4096,
+                 "decode_attention_kernel": 0.0},
+        state={"kv": [((64, 4096, 8 * 64), "bfloat16")] * 2,
+               "conv": [((64, 2, 2048), "bfloat16")] * 4},
+        # 507,822,209 trained parameters and four routers' 32 biases.
+        parameters=PARAMETERS + 4 * 32))
 
 
+# -- the decode and the resets ----------------------------------------------
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
 def test_a_decode_through_the_kernel_form_is_the_causal_pass(
         dtype, kernel_here):
@@ -187,55 +137,37 @@ def test_a_decode_through_the_kernel_form_is_the_causal_pass(
     token long inside the fragment: a step reads the blocks up to the
     furthest position a row holds of its own episode, and the logits are
     the causal pass's over the same resets."""
-    model, variables, tokens = build(dtype)
-    system, state, counted = decode_routed(model, variables, tokens, RESET,
-                                           jit=dtype == "f32")
+    built = build(FAMILY, dtype, fresh=True)
+    _, variables, tokens = built
+    system, state, counted = decode_routed(built, variables, tokens, RESET)
     # Episodes start at 0, 11 and 12: a row's position within its own.
     place = [t if t < 11 else 0 if t == 11 else t - 12 for t in range(S)]
     assert [step["decode_cache_read_share"] for step in counted] == [
         pytest.approx(8 * (at // 8 + 1) / S) for at in place]
-    assert state_shapes(state) == ([CACHE] * 2, [CONV_STATE] * 4)
+    assert state_shapes(FAMILY, state) == ([CACHE] * 2, [CONV_STATE] * 4)
     if dtype == "f32":
-        causal, _, _ = causal_routed(model, variables, tokens, RESET)
+        causal, _, _ = causal_routed(built, variables, tokens, RESET)
         assert reference.relative_error(system[0], causal[0]) < 1e-5
         assert reference.relative_error(system[1], causal[1]) < 1e-5
         assert np.array_equal(system[2], causal[2])
     else:
-        outputs, routing = judged(system, variables, tokens, starts=RESET)
-        assert routing["router_flips"] <= 0.1
-        assert outputs["ok"], outputs
-
-
-def test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over():
-    """Prefixes shorter than the taps, as long, and longer: the pass's
-    state is the last two gated inputs (zeros where the episode is
-    shorter), and the decode goes on from it."""
-    model, variables, tokens = build("f32")
-    decode = jax.jit(lambda token, state, reset: model.apply(
-        variables, token, state, reset))
-    full, _, _ = model.apply(variables, tokens, None, jnp.zeros((B, S)))
-    for prefix in (2, 3, 5, 13):
-        _, _, state = model.apply(variables, tokens[:, :prefix], None,
-                                  jnp.zeros((B, prefix)))
-        for t in range(prefix, S):
-            step, _, state = decode(tokens[:, t:t + 1], state,
-                                    jnp.zeros((B, 1)))
-            assert reference.relative_error(
-                step[:, 0], full[:, t]) < 1e-5, (prefix, t)
+        held_to_reference(FAMILY, dtype, system, variables, tokens,
+                          starts=RESET)
 
 
 def test_a_reset_inside_a_fragment_and_an_episode_one_token_long():
     """Three episodes in a fragment, the second one token long: what
     separate passes give, in both forms and in the reference; the state
     handed over is the last episode's alone."""
-    model, variables, tokens = build("f32")
-    both, state, _ = causal_routed(model, variables, tokens, RESET)
-    parts = [causal_routed(model, variables, tokens[:, a:b])
+    built = build(FAMILY, "f32")
+    model, variables, tokens = built
+    both, state, _ = causal_routed(built, variables, tokens, RESET)
+    parts = [causal_routed(built, variables, tokens[:, a:b])
              for a, b in ((0, 11), (12, S))]
     # A causal pass takes two tokens or more: the lone token as a decode
     # step from empty state.
-    lone, _, _ = model.apply(variables, tokens[:, 11:12],
-                             model.initial_state(B), jnp.ones((B, 1)))
+    lone, _, _ = built.decode(variables, tokens[:, 11:12],
+                              model.initial_state(B), jnp.ones((B, 1)))
     separate = jnp.concatenate(
         [parts[0][0][0], lone, parts[1][0][0]], axis=1)
     assert reference.relative_error(both[0], separate) < 1e-5
@@ -243,15 +175,13 @@ def test_a_reset_inside_a_fragment_and_an_episode_one_token_long():
                          jax.tree.leaves(parts[1][1]["conv"])):
         np.testing.assert_allclose(got, want, atol=1e-5)
     assert np.all(np.asarray(state["pos"]) == S - 12)
-    outputs, routing = judged(both, variables, tokens, starts=RESET)
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    assert routing["router_flips"] == 0.0
-    stepped, _, _ = decode_routed(model, variables, tokens, RESET)
+    held_to_reference(FAMILY, "f32", both, variables, tokens, starts=RESET)
+    stepped, _, _ = decode_routed(built, variables, tokens, RESET)
     assert reference.relative_error(stepped[0], both[0]) < 1e-5
     # A fragment that ends one token into an episode hands over one
     # gated input and a zero row.
-    _, _, short = model.apply(
-        variables, tokens[:, :13], None, RESET[:, :13])
+    _, short, _ = causal_routed(built, variables, tokens[:, :13],
+                                RESET[:, :13])
     for held in jax.tree.leaves(short["conv"]):
         assert not np.any(np.asarray(held[:, 0]))
         assert np.any(np.asarray(held[:, 1]))
@@ -260,7 +190,7 @@ def test_a_reset_inside_a_fragment_and_an_episode_one_token_long():
 def test_the_convolution_is_the_sum_written_out():
     """`_conv_causal` against v_t = sum_j w[:, j] g_{t - 2 + j} written
     as a loop over positions and taps, an episode boundary in the middle."""
-    model, variables, tokens = build("f32")
+    model, variables, tokens = build(FAMILY, "f32")
     lp = variables["params"]["layer_2"]
     x = jax.random.normal(jax.random.PRNGKey(3), (B, S, 64))
     positions = jnp.broadcast_to(
@@ -281,27 +211,6 @@ def test_the_convolution_is_the_sum_written_out():
     want = np.asarray(x) + (c * v) @ np.asarray(lp["conv_out"])
     np.testing.assert_allclose(h, want, atol=2e-5)
     np.testing.assert_allclose(state, g[:, -2:], atol=1e-6)
-
-
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The comparison fails each named error and blocks computed a
-    precision lower: the reference, so altered, in the system's place
-    against itself, by its outputs or by its routing. The fragment holds
-    a reset, so that a convolution that reaches across it shows."""
-    _, variables, tokens = build("f32", bias_scale=0.2)
-    if wrong == "float8_e4m3":
-        got = reference.forward(variables, tokens, NET, round_to=wrong,
-                                starts=RESET)
-    else:
-        got = reference.forward(variables, tokens, NET, mutate=wrong,
-                                starts=RESET)
-    outputs, routing = judged(
-        (got["logits"], got["values"], got["experts"]), variables, tokens,
-        starts=RESET)
-    assert not (outputs["ok"] and routing["ok"]), (wrong, outputs, routing)
-    if wrong == "untied_head":
-        assert outputs["errors"]["value"] == 0.0  # the head's alone
 
 
 # -- the router's division ------------------------------------------------
@@ -330,7 +239,7 @@ def test_the_renormalisation_s_epsilon_is_the_description_s():
             plain, chosen / jnp.sum(chosen, axis=-1, keepdims=True))
     # Faint scores: the weights no longer add up to one.
     assert float(jnp.max(jnp.sum(p, axis=-1))) < 0.5
-    model, _, _ = build("f32")
+    model, _, _ = build(FAMILY, "f32")
     assert model.topk_eps == reference.TOPK_EPS == 1e-6
     for name, cfg in (("glm4_moe_lite", dict(
             q_lora_rank=8, kv_lora_rank=8, qk_nope_head_dim=8,
@@ -357,14 +266,11 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     h = jnp.asarray(rng.normal(size=(2, 12, H)), jnp.float32)
     m = transformer.rms_norm(h, jnp.ones(H), 1e-5, jnp.float32)
 
-    def share_of(first, size):
-        return dict(lp, **{w: lp[w][first:first + size]
-                           for w in ("w_gate", "w_up", "w_down")})
-
+    @functools.partial(jax.jit, static_argnums=(1,))
     def layer(first, size):
         net = dict(NET, experts_held=size, first_expert_held=first)
         with jax.default_matmul_precision("highest"):
-            return reference._moe(share_of(first, size), bias, h, m, net,
+            return reference._moe(share_of(lp, first, size), bias, h, m, net,
                                   lambda a: a, None, None)
     whole, chosen, _ = layer(0, E)
     parts = sum(layer(first, held)[0] - h for first in range(0, E, held))
@@ -381,7 +287,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         n, p, i = (jnp.tile(a, (reps, 1)) for a in (rows, top_p, top_i))
         routed, landed = jnp.zeros_like(n), 0
         for first in range(0, E, held):
-            s = share_of(first, held)
+            s = share_of(lp, first, held)
             part, sizes, _ = dropless_experts(
                 n, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E)
             routed, landed = routed + part, landed + int(jnp.sum(sizes))
@@ -390,66 +296,6 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
             routed[:rows.shape[0]], (whole - h).reshape(-1, H)) < 1e-4
     assert transformer.experts_batched(rows.shape[0], k, E)
     assert not transformer.experts_batched(64 * rows.shape[0], k, E)
-
-
-def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
-        grouped_pass_is_the_batched_pass):
-    grouped_pass_is_the_batched_pass(*build("f32"))
-
-
-def published_cut():
-    return dict(NET, vocab_size=16384, hidden_size=2048,
-                num_attention_heads=32, num_key_value_heads=8,
-                intermediate_size=7168, num_experts=32, experts_held=8,
-                num_experts_per_tok=4, moe_intermediate_size=1792,
-                max_position_embeddings=4096)
-
-
-def test_the_cell_s_program_is_known_from_its_static_shapes():
-    """At the published widths: 507.8 M parameters, the embedding counted
-    once; one cache of 4,096 positions, 2,048 bytes a position, and four
-    states of two rows, 32,768 bytes a sequence whatever its length;
-    heads of 64 take the fused causal form; nothing but shapes is built."""
-    net = published_cut()
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "lfm2_moe", "custom_model_config": net})
-    assert model.static_counters(64, 4096, "tpu") == {
-        "decode_rows_per_expert": 8.0, "decode_experts_batched": 1.0,
-        "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
-        "decode_cache_block": 128, "decode_attention_kernel": 1.0,
-        "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 2048.0,
-        # Heads of 64 are half a lane tile: the learner's rotation keeps
-        # `rope` (`rowwise.whole_tiles`).
-        "rotation_fused_layers": 0.0,
-        "kv_groups": 4, "conv_layers": 4, "conv_state_bytes_per_row": 32768}
-    # Off a TPU the cache is read whole, by XLA's products.
-    off = model.static_counters(64, 4096, "cpu")
-    assert (off["causal_attention_fused"], off["decode_cache_block"],
-            off["decode_attention_kernel"]) == (0.0, 4096, 0.0)
-    state = jax.eval_shape(lambda: model.initial_state(64))
-    assert [c.shape for c in jax.tree.leaves(state["kv"])] == [
-        (64, 4096, 8 * 64)] * 2
-    assert [c.shape for c in jax.tree.leaves(state["conv"])] == [
-        (64, 2, 2048)] * 4
-    variables = jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.eval_shape(lambda: model.initial_state(1)),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32))
-    assert set(variables) == {"params", "constants"}
-    assert "head" not in variables["params"]
-    assert variables["params"]["layer_1"]["q_norm"].shape == (64,)
-    count = sum(int(np.prod(v.shape))
-                for v in jax.tree.leaves(variables["params"]))
-    conv = 4 * 2048 * 2048 + 2048 * 3
-    attention = 2 * 2048 * 2048 + 2 * 2048 * 512 + 2 * 64
-    experts = 2048 * 32 + 8 * 3 * 2048 * 1792
-    assert count == (16384 * 2048 + conv + 3 * 2048 * 7168
-                     + attention + experts + 3 * (conv + experts)
-                     + 5 * 2 * 2048 + 2048 + 2048 + 1)
-    assert count == 507_822_209
-    assert sum(int(np.prod(v.shape)) for v in jax.tree.leaves(
-        variables["constants"])) == 4 * 32
 
 
 def test_the_accepted_descriptions_keep_their_state_and_counters():
@@ -468,234 +314,15 @@ def test_the_accepted_descriptions_keep_their_state_and_counters():
     assert "conv_layers" not in counted and "window_layers" not in counted
 
 
-# -- the loss and the loop ------------------------------------------------
-def token_trainer_config(**over):
-    cfg = dict(
-        env="TokenBigram-v0",
-        env_config={"vocab_size": NET["vocab_size"], "episode_len": S},
-        anakin=True, num_workers=0, num_envs_per_worker=4,
-        rollout_fragment_length=S, train_batch_size=4 * S,
-        sgd_minibatch_size=2 * S, num_sgd_iter=1,
-        anakin_updates_per_call=1, min_iter_time_s=0, lr=6e-4, seed=3,
-        model={"custom_model": "lfm2_moe", "custom_model_config": NET,
-               "compute_dtype": "f32"})
-    cfg.update(over)
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def token_trainer():
-    trainer = IMPALATrainer(config=token_trainer_config())
-    yield trainer
-    trainer.stop()
-
-
-def seeded_batch(frags, seed):
-    """`frags` whole episodes of a walk (`TokenBigram-v0`: the action
-    taken is the next observation), as the learner's packed batch and as
-    the reference's."""
-    rng = np.random.default_rng(seed)
-    walk = rng.integers(0, NET["vocab_size"], size=(frags, S + 1))
-    ref_batch = {
-        "tokens": walk[:, :S], "actions": walk[:, 1:],
-        "rewards": rng.integers(0, 2, size=(frags, S)).astype(np.float32),
-        "behaviour_logp": rng.uniform(-5.0, -4.0, size=(frags, S)).astype(
-            np.float32)}
-    dones = np.zeros((frags, S), np.float32)
-    dones[:, -1] = 1.0
-    batch = {
-        sb.OBS: jnp.asarray(ref_batch["tokens"].reshape(-1), jnp.int32),
-        sb.ACTIONS: jnp.asarray(ref_batch["actions"].reshape(-1), jnp.int32),
-        sb.REWARDS: jnp.asarray(ref_batch["rewards"].reshape(-1)),
-        sb.DONES: jnp.asarray(dones.reshape(-1)),
-        sb.ACTION_LOGP: jnp.asarray(ref_batch["behaviour_logp"].reshape(-1)),
-        sb.VF_PREDS: jnp.zeros(frags * S, jnp.float32),
-        sb.BOOTSTRAP_OBS: jnp.asarray(walk[:, S], jnp.int32)}
-    return batch, ref_batch
-
-
-def test_vtrace_minibatch_loss_and_gradients_match_reference(token_trainer):
-    """One minibatch of whole episodes through the system's loss (packed
-    rows, ACTION_LOGP, the bootstrap step differentiated through both
-    kinds of state) and through `jax.grad` of the plain reference; the
-    tied embedding's gradient is the lookup's and the head's together; the
-    router bias has no gradient and no optimizer state."""
-    policy = token_trainer.get_policy()
-    batch, ref_batch = seeded_batch(B, 5)
-    variables = jax.tree.map(jnp.asarray, policy.get_weights())
-    assert set(variables) == {"params", "constants"}
-    (total, stats), grads = jax.value_and_grad(
-        lambda v: vtrace_loss(policy, v, batch, None, {}),
-        has_aux=True)(variables)
-    (want_total, _), want_grads = jax.value_and_grad(
-        lambda v: reference.vtrace_loss(v, ref_batch, NET, policy.config),
-        has_aux=True)(variables)
-    np.testing.assert_allclose(total, want_total, rtol=1e-4)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads["params"])
-    want_flat = jax.tree.leaves(want_grads["params"])
-    assert len(flat) == len(want_flat)
-    for (path, got), want in zip(flat, want_flat):
-        scale = float(jnp.max(jnp.abs(want))) + 1e-8
-        assert float(jnp.max(jnp.abs(got - want))) <= 2e-3 * scale, path
-    assert not any(bool(jnp.any(g != 0))
-                   for g in jax.tree.leaves(grads["constants"]))
-    moments = [leaf for leaf in jax.tree.leaves(policy.opt_state)
-               if leaf.dtype == jnp.float32]
-    assert len(moments) == 2 * len(jax.tree.leaves(variables["params"]))
-    assert stats["expert_load_mean"] > 0
-    assert 0.0 < stats["experts_held_row_share"] < 1.0
-
-
-def one_update(trainer, seed=7, **wrong):
-    """One update of seeded whole episodes by the optimizer's own step
-    (`AnakinOptimizer.learn`) from the trainer's parameters and optimizer
-    state, against the reference's loss, gradients and Adam: what the
-    benchmark's driver does at the cell's minibatch. `wrong` plants a
-    fault in the reference's side."""
-    policy, opt = trainer.get_policy(), trainer.optimizer
-    cfg = dict(policy.config, **wrong.get("cfg", {}))
-    batch, ref_batch = seeded_batch(opt.minibatch // opt.T, seed)
-
-    def flat(tree):
-        return {jax.tree_util.keystr(path): np.asarray(leaf)
-                for path, leaf in
-                jax.tree_util.tree_flatten_with_path(tree)[0]}
-    before = policy.params
-    (adam,) = [s for s in jax.tree.leaves(
-        policy.opt_state, is_leaf=lambda s: hasattr(s, "mu"))
-        if hasattr(s, "mu")]
-    after, _, stats = jax.jit(opt.learn)(
-        before, policy.opt_state, batch, jax.random.PRNGKey(0))
-    assert all(np.array_equal(a, b) for a, b in zip(
-        jax.tree.leaves(after["constants"]),
-        jax.tree.leaves(before["constants"])))
-    (want_loss, _), grads = jax.value_and_grad(
-        lambda p: reference.vtrace_loss(
-            {"params": p, "constants": before["constants"]}, ref_batch,
-            NET, cfg, mutate=wrong.get("mutate")),
-        has_aux=True)(before["params"])
-    count = int(adam.count)
-    assert count > 0
-    want_change, norm = reference.adam_update(
-        flat(grads), flat(adam.mu["params"]), flat(adam.nu["params"]),
-        count, cfg)
-    assert norm > 0
-    old, new = flat(before["params"]), flat(after["params"])
-    return reference.compare_update(stats["total_loss"], want_loss, {
-        name: float(reference.change_error(old[name], new[name], want))
-        for name, want in want_change.items()})
-
-
-def test_one_update_by_the_optimizer_s_own_step_matches_reference(
-        token_trainer):
-    token_trainer.train()  # Adam's moments are not zero
-    found = one_update(token_trainer)
-    assert found["ok"], found
-    assert found["loss_error"] < 1e-5 and found["update_error"] < 1e-2, found
-
-
-WRONG_UPDATES = {
-    "taps_reversed_in_the_gradient": dict(mutate="taps_reversed"),
-    "qk_norm_after_rope": dict(mutate="qk_norm_after_rope"),
-    "an_untied_head": dict(mutate="untied_head"),
-    "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0}, by="loss_error"),
-    "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
-    "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error"),
-}
-
-
-@pytest.mark.parametrize("wrong", WRONG_UPDATES)
-def test_update_limits_refuse_a_wrong_update(wrong, token_trainer):
-    """The comparison of one update fails each named error, planted in
-    the reference's side: by the loss, by the worst parameter's change, or
-    by either."""
-    token_trainer.train()
-    fault = dict(WRONG_UPDATES[wrong])
-    by = fault.pop("by", None)
-    found = one_update(token_trainer, **fault)
-    assert not found["ok"], found
-    if by:
-        limits = {"loss_error": reference.UPDATE_LOSS_TOLERANCE,
-                  "update_error": reference.UPDATE_TOLERANCE}
-        assert found[by] > limits[by], found
-
-
-def test_lfm2_token_trainer_trains_on_the_fused_path(token_trainer):
-    """`IMPALATrainer(anakin, TokenBigram-v0, lfm2_moe)` by config alone:
-    two iterations, a finite loss, a rising count, a policy state of two
-    kinds of leaf carried by the optimizer as one pytree, the new counters
-    in `learner_stats`."""
-    counts = []
-    for _ in range(2):
-        result = token_trainer.train()
-        stats = result["info"]["learner"]
-        assert np.isfinite(stats["total_loss"])
-        counts.append(result["timesteps_total"])
-    assert counts[1] - counts[0] == 4 * S and counts[0] > 0
-    kept = token_trainer.optimizer.learner_stats
-    assert kept["expert_load_max"] >= kept["expert_load_mean"] > 0
-    # 2 of 8 experts held: about a quarter of the (row, expert) pairs.
-    assert 0.05 < kept["experts_held_row_share"] < 0.6
-    # What the learner's product gathered: all, in the batched form these
-    # sizes take.
-    assert kept["dispatch_rows_share"] == 1.0
-    assert kept["experts_grouped_kernel"] == 0.0  # this is no TPU
-    assert kept["decode_rows_per_expert"] == 4 * 2 / 8
-    assert kept["decode_cache_read_share"] == 1.0
-    assert kept["causal_attention_fused"] == 0.0
-    # float32 here: one layer's 2 x 2 heads x 8 x 4 B a position; four
-    # layers' two rows of 64 x 4 B a sequence.
-    assert kept["kv_cache_bytes_per_token"] == 128
-    assert (kept["conv_layers"], kept["conv_state_bytes_per_row"]) == (
-        4, 4 * 2 * 64 * 4)
-    state, _ = token_trainer.optimizer._pstate
-    assert set(state) == {"kv", "conv", "pos"}
-    assert state_shapes(state) == ([CACHE] * 2, [CONV_STATE] * 4)
-    # What the benchmark's two readers of the state make of it.
-    caches = jax.tree.leaves(state["kv"])
-    assert sum(c.nbytes for c in caches) / (4 * S) == 128
-    assert sum(c.nbytes for c in jax.tree.leaves(state["conv"])) / 4 == 2048
-
-
-def test_learner_stats_report_what_the_grouped_kernel_read(kernel_here):
-    """The trainer on the fused Anakin path with the kernel form in its
-    rollout and under its learner's bootstrap step: the one cache, three
-    blocks of 8, fills from empty every rollout and is read 1/2 + block /
-    2S of."""
-    trainer = IMPALATrainer(config=token_trainer_config())
-    try:
-        result = trainer.train()
-        assert np.isfinite(result["info"]["learner"]["total_loss"])
-        kept = trainer.optimizer.learner_stats
-        assert kept["decode_cache_read_share"] == pytest.approx(
-            0.5 + 8 / (2 * S))
-        # The host's counters are of the platform the trainer runs on.
-        assert kept["decode_attention_kernel"] == 0.0
-        assert kept["decode_cache_block"] == S
-    finally:
-        trainer.stop()
-
-
-@pytest.mark.parametrize("cfg,match", [
-    ({"n_routed_experts": 8}, "not lfm2_moe's"),
-    ({"head_dim": 16}, "not lfm2_moe's"),
-    ({"conv_bias": True}, "conv_bias"),
-    ({"use_expert_bias": False}, "use_expert_bias"),
-    ({"tie_embedding": False}, "tie_embedding"),
-    ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
-    ({"num_key_value_heads": 3}, "groups"),
-    ({"layer_types": ["conv", "full_attention"]}, "layer_types has 2"),
-    ({"layer_types": ["conv", "sliding_attention"] + TYPES[2:]},
-     "sliding_attention"),
-    ({"experts_held": 6, "first_expert_held": 4}, "not among"),
-])
-def test_custom_model_config_without_a_part_is_refused(cfg, match):
-    with pytest.raises(ValueError, match=match):
-        model = catalog.get_model(None, 96, {
-            "custom_model": "lfm2_moe",
-            "custom_model_config": dict(NET, **cfg)})
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                   model.initial_state(1), jnp.zeros((1, 1)))
+def test_the_published_cut_s_parameters_are_the_hand_count_s():
+    """The embedding counted once: no head beside it; a norm a head."""
+    _, _, _, network = configuration(FAMILY)
+    model = transformer.lfm2_moe_from_config(16384, network)
+    variables = shapes_of(model)
+    assert "head" not in variables["params"]
+    assert variables["params"]["layer_1"]["q_norm"].shape == (64,)
+    assert count(variables["params"]) == PARAMETERS == 507_822_209
+    assert count(variables["constants"]) == 4 * 32
 
 
 def test_a_tied_head_gives_as_many_logits_as_the_vocabulary_has_ids():
@@ -714,42 +341,16 @@ def test_keys_left_out_have_the_published_model_s_values():
         2, 6, 10, 14, 18, 21)
     assert (model.dense_layers, model.num_experts, model.held,
             model.experts_per_token, model.head_width) == (2, 32, 32, 4, 64)
-    variables = jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.eval_shape(lambda: model.initial_state(1)),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32))
+    variables = shapes_of(model)
     count = sum(int(np.prod(v.shape))
                 for v in jax.tree.leaves(variables["params"]))
     assert 8.2e9 < count < 8.5e9
 
 
-def test_the_tuned_example_is_the_benchmark_s_cell():
-    """`rllib train -f lfm2-token-impala.yaml` and the cell
-    `lfm2_token_anakin_4k` are one trainer config, and the configuration's
-    file holds every published number of its source but the ones it lists
-    as reduced."""
-    import json
-
-    import yaml
-    root = os.path.dirname(BENCH)
-    with open(os.path.join(root, "ray_tpu", "rllib", "tuned_examples",
-                           "lfm2-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(
-            BENCH, "workloads", "lfm2_token_anakin_4k.json")) as f:
-        cell = json.load(f)
-    with open(os.path.join(
-            BENCH, "configs", "impala_lfm2_8b_a1b.json")) as f:
-        config = json.load(f)
-    network = {k: v for k, v in config["network"].items()
-               if k != "param_count"}
-    want = dict(cell["trainer_config"], **config["trainer_config"])
-    want["model"] = dict(want["model"], custom_model_config=network)
-    want["num_tpus_for_learner"] = cell["chips"]
-    assert example["run"] == config["trainer"]
-    assert example["env"] == want.pop("env")
-    assert example["config"] == want
+def test_the_configuration_s_file_holds_its_source_s_published_numbers():
+    """Every published number of the source (the catalog's row) but the
+    ones the file lists as reduced."""
+    _, _, config, network = configuration(FAMILY)
     # The source's config (the catalog's row), the reduced keys apart.
     published = dict(transformer.LFM2_MOE_PUBLISHED, conv_bias=False,
                      use_expert_bias=True, model_type="lfm2_moe")
@@ -768,8 +369,7 @@ def test_the_tuned_example_is_the_benchmark_s_cell():
     assert (network["num_experts"], network["experts_held"]) == (32, 8)
     assert config["reduced"] == list(reduced) + ["env"]
     assert set(config["reduced"]) == set(config["reduced_why"])
-    # 507,822,209 trained parameters and four routers' 32 biases.
-    assert config["network"]["param_count"] == 507_822_337
+    assert config["network"]["param_count"] == PARAMETERS + 4 * 32
     model = transformer.lfm2_moe_from_config(16384, network)
     assert (model.hidden_size, model.num_heads, model.kv_heads,
             model.head_width, model.conv_taps, model.dense_width,

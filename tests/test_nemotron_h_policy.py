@@ -1,40 +1,49 @@
-"""The `nemotron_h` token policy at a tiny size on the CPU: the model against
-the plain reference (`benchmark/lib/reference_nemotron_h.py`, whose Mamba-2 is
-the recurrence itself, one position at a time) in its causal form (chunk
-terms by a masked cumulative sum, a scan over chunks that carries the
-matrix) and in its decode through three kinds of state (a Mamba-2 layer's
-matrix a head and its one convolution's last inputs, the attention layer's
-grouped cache); layers that are ONE function each; experts without a gate
-matrix; `ssd_chunked` alone against a loop of `ssd_step`, gradients too; a
-decode that continues a causal pass; resets inside a chunk, at a chunk's
-edge, and an episode one token long against separate passes; decays that
-lose more than e^100 inside one chunk; the sixteen shares of an expert layer
-against the uncut layer; each named wrong mathematics refused by the cell's
-limits. V-trace's loss, its gradients, one update of the optimizer's own
-against the reference's and the trainer on the fused Anakin path stand in
-`tests/test_nemotron_h_update.py`, a file of its own so that the two run on
-two workers.
+"""The `nemotron_h` token policy at a tiny size on the CPU: the family's row,
+the checks it shares with the other families (`tests/token_families.py`: the
+model against the plain reference `benchmark/lib/reference_nemotron_h.py`,
+whose Mamba-2 is the recurrence itself, one position at a time, in its causal
+form and decoded through three kinds of state, a Mamba-2 layer's matrix a head
+and its one convolution's last inputs and the attention layer's grouped cache;
+a decode that continues a causal pass; resets inside a chunk, at a chunk's
+edge, and an episode one token long against separate passes; the model's
+gradient against the recurrence's; each named wrong mathematics refused by
+the cell's limits; the grouped form of the expert product, without a gate
+matrix; the cell's program from its shapes; the builder's refusals; the tuned
+example) and what is its own: layers that are ONE function each; experts
+without a gate matrix; the causal form's chunk terms by a masked cumulative
+sum and its scan over chunks, `ssd_chunked` alone against a loop of
+`ssd_step`, gradients too; decays that lose more than e^100 inside one chunk;
+a matrix state kept in bfloat16 refused; the sixteen shares of an expert layer
+against the uncut layer. The loss and the loop:
+`tests/test_nemotron_h_update.py`.
 """
 
-import json
-import os
-import sys
-import zlib
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from token_families import (  # noqa: F401: pytest collects what is named
+    Family, build, causal_routed, decode_routed, judged, model_gradients,
+    configuration, read_by, seeded_norms, share_of,
+    test_a_bfloat16_matrix_state_is_refused_by_the_decode_s_limit,
+    test_a_causal_pass_over_the_landed_rows_is_the_batched_pass,
+    test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over,
+    test_causal_pass_matches_reference,
+    test_custom_model_config_without_a_part_is_refused,
+    test_decode_through_every_kind_of_state_matches_reference
+    as test_decode_through_three_kinds_of_state_matches_reference,
+    test_limits_refuse_wrong_mathematics,
+    test_resets_inside_a_chunk_at_its_edge_and_an_episode_one_token_long,
+    test_the_cell_s_program_is_known_from_its_static_shapes,
+    test_the_model_s_gradient_is_the_reference_s,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_nemotron_h as reference
 
-from lib import reference_nemotron_h as reference  # noqa: E402
-
-from ray_tpu.models import catalog, transformer  # noqa: E402
-from ray_tpu.models.transformer import dropless_experts  # noqa: E402
+from ray_tpu.models import transformer
+from ray_tpu.models.transformer import dropless_experts
 
 # The cell's seven layers, M E M E M * E; 8 state-space heads of 8 channels
 # in 2 groups, a state of 16, chunks of 8; 4 query heads over 2 cached ones
@@ -63,184 +72,113 @@ SSM_PARAMETERS = {"ssm_in", "ssm_conv", "ssm_conv_bias", "ssm_a_log",
                   "ssm_dt_bias", "ssm_d", "ssm_norm", "ssm_out"}
 
 
-def build(dtype, net=NET, bias_scale=None, tokens=S):
-    """(model, seeded variables, tokens). The norms' weights and D are
-    seeded too (one at initialisation), so that a norm's place shows."""
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "nemotron_h", "custom_model_config": net,
-        "compute_dtype": dtype})
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, tokens), 0, net["vocab_size"])
-    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
-                           model.initial_state(B), jnp.zeros((B, 1)))
-
-    def seeded(path, a):
-        if not path[-1].key.endswith(("norm", "ssm_d")):
-            return a
-        key = jax.random.fold_in(jax.random.PRNGKey(2), zlib.crc32(
-            jax.tree_util.keystr(path).encode()) % 2 ** 31)
-        return a * (1.0 + 0.5 * jax.random.normal(key, a.shape))
-    variables = dict(variables, params=jax.tree_util.tree_map_with_path(
-        seeded, variables["params"]))
-    if bias_scale is not None:
-        # A selection bias as large as the scores' own spread, so that
-        # choosing by score + bias and weighing by score differ.
-        variables = dict(variables, constants=jax.tree.map(
-            lambda b: b * (bias_scale / transformer.ROUTER_BIAS_SCALE),
-            variables["constants"]))
-    return model, variables, tokens
+def attention_layer_shown(variables):
+    """The one attention layer's softmax far enough from uniform, and its
+    output large enough beside the other layers', that a rotation shows in
+    the logits."""
+    params = dict(variables["params"])
+    params["layer_5"] = dict(params["layer_5"],
+                             wq=4.0 * params["layer_5"]["wq"],
+                             wk=4.0 * params["layer_5"]["wk"],
+                             wo=3.0 * params["layer_5"]["wo"])
+    return dict(variables, params=params)
 
 
-def plain(variables, tokens, net=NET, experts=None, starts=None, **how):
-    """The reference's forward, compiled (its scans run op by op
-    otherwise)."""
-    return jax.jit(lambda v, t, e, s: reference.forward(
-        v, t, net, experts=e, starts=s, **how))(
-            variables, tokens, experts, starts)
+def slow_decays(variables):
+    """Decays slow enough that a state holds hundreds of positions."""
+    params = dict(variables["params"])
+    for layer in SSM_LAYERS:
+        params[layer] = dict(
+            params[layer],
+            ssm_a_log=params[layer]["ssm_a_log"] - jnp.log(16.0))
+    return dict(variables, params=params)
 
 
-def judged(system, variables, tokens, net=NET, starts=None):
-    """The system's (logits, values, experts) against the reference held
-    to those experts: (outputs, routing)."""
-    logits, values, experts = system
-    held = plain(variables, tokens, net, experts, starts)
-    return (reference.compare((logits, values),
-                              (held["logits"], held["values"])),
-            reference.routing_verdict(experts, held["experts"],
-                                      held["select"]))
-
-
-def within_bfloat16(system, variables, tokens, net=NET, starts=None):
-    """Blocks in bfloat16, at these widths: the limits at the published
-    widths are no measure here, where the reference itself, its blocks
-    rounded to bfloat16, stands several per cent from its float32 self. The
-    system is held to that: no further off than twice the rounded
-    reference, and its routing within a tenth."""
-    outputs, routing = judged(system, variables, tokens, net, starts)
-    low = plain(variables, tokens, net, starts=starts,
-                round_to=jnp.bfloat16)
-    rounded, _ = judged((low["logits"], low["values"], low["experts"]),
-                        variables, tokens, net, starts)
-    assert routing["router_flips"] <= 0.1, routing
-    for name, error in outputs["errors"].items():
-        assert error <= 2 * rounded["errors"][name] < 0.3, (
-            outputs, rounded)
-
-
-def causal_routed(model, variables, tokens, reset=None):
-    (logits, values, state), kept = jax.jit(
-        lambda v, t, r: model.apply(v, t, None, r,
-                                    mutable=["routing", "counters"]))(
-            variables, tokens,
-            jnp.zeros(tokens.shape) if reset is None else reset)
-    return (logits, values, kept["routing"]["experts"][-1]), state, kept
-
-
-def decode_routed(model, variables, tokens, reset=None, jit=True,
-                  between=None):
-    """Every position one token at a time from empty state:
-    ((logits, values, experts), the last state, the counters a step).
-    `between` alters the state after every step."""
-    def step(token, state, reset):
-        return model.apply(variables, token, state, reset, method="decode",
-                           mutable=["routing", "counters"])
-    if jit:
-        step = jax.jit(step)
-    if reset is None:
-        reset = jnp.zeros(tokens.shape)
-    state = model.initial_state(tokens.shape[0])
-    logits, values, experts, counted = [], [], [], []
-    for t in range(tokens.shape[1]):
-        (step_l, step_v, state), kept = step(
-            tokens[:, t], state, reset[:, t])
-        if between is not None:
-            state = between(state)
-        logits.append(step_l)
-        values.append(step_v)
-        experts.append(kept["routing"]["experts"][-1])
-        counted.append({k: float(v[-1])
-                        for k, v in kept["counters"].items()})
-    return (jnp.stack(logits, 1), jnp.stack(values, 1),
-            jnp.stack(experts, 2)), state, counted
-
-
-def state_shapes(state):
-    return tuple([c.shape[1:] for c in jax.tree.leaves(state[key])]
-                 for key in ("kv", "conv", "ssm"))
-
-
-STATE_SHAPES = ([CACHE] * 2, [TAILS] * 3, [MATRIX] * 3)
-
-
-# -- the model against the reference -----------------------------------
-@pytest.mark.parametrize("tokens", [S, S - 3])
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_causal_pass_matches_reference(dtype, tokens):
-    """A fragment of whole chunks and one that ends inside a chunk.
-    float32 blocks: to float32 accuracy, the same experts in every layer.
-    bfloat16 blocks: as near as the reference rounded where they round."""
-    net = dict(NET, max_position_embeddings=tokens)
-    model, variables, tokens = build(dtype, net, tokens=tokens)
-    system, state, _ = causal_routed(model, variables, tokens)
-    assert system[2].shape == (3, B, tokens.shape[1], 2)  # expert layers
-    if dtype == "f32":
-        held = plain(variables, tokens, net, system[2])
-        assert np.array_equal(np.sort(system[2], -1),
-                              np.sort(held["experts"], -1))
-        for got, want in zip(system[:2], (held["logits"], held["values"])):
-            assert reference.relative_error(got, want) < 1e-5
-        # The matrix states the scan hands over are the recurrence's.
-        for got, want in zip(jax.tree.leaves(state["ssm"]),
-                             held["ssm_states"]):
-            assert reference.relative_error(got, want) < 1e-5
-    else:
-        within_bfloat16(system, variables, tokens, net)
-    # What the pass hands a decode: the one grouped cache, three layers'
+FAMILY = Family(
+    name="nemotron_h", net=NET, reference=reference, B=B, S=S,
+    # What a pass hands a decode: the one grouped cache, three layers'
     # convolution inputs, three layers' matrices, a key a kind; a layer
     # that is its feed-forward alone keeps nothing.
-    cache = (tokens.shape[1], CACHE[1])
-    assert state_shapes(state) == ([cache] * 2, [TAILS] * 3, [MATRIX] * 3)
-    assert [len(kv) for kv in state["kv"]] == [0, 0, 0, 0, 0, 2, 0]
-    assert [np.ndim(c) for c in state["conv"]] == [3, 1, 3, 1, 3, 1, 1]
-    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(state["ssm"]))
-    assert np.all(np.asarray(state["pos"]) == tokens.shape[1])
-
-
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_decode_through_three_kinds_of_state_matches_reference(dtype):
-    """Against the reference, which has neither cache nor state; and,
-    float32, against the causal pass and the state it returns."""
-    model, variables, tokens = build(dtype)
-    system, state, counted = decode_routed(model, variables, tokens,
-                                           jit=dtype == "f32")
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        causal, handed, _ = causal_routed(model, variables, tokens)
-        assert reference.relative_error(system[0], causal[0]) < 1e-5
-        assert np.array_equal(system[2], causal[2])
-        for got, want in zip(jax.tree.leaves(state),
-                             jax.tree.leaves(handed)):
-            np.testing.assert_allclose(got, want, atol=2e-5)
-    else:
-        within_bfloat16(system, variables, tokens)
-    assert state_shapes(state) == STATE_SHAPES
-    # The matrix state is float32 whatever the blocks compute in; the
-    # convolution's inputs and the cache are the blocks'.
-    blocks = jnp.float32 if dtype == "f32" else jnp.bfloat16
-    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(state["ssm"]))
-    assert all(a.dtype == blocks for a in jax.tree.leaves(
-        (state["conv"], state["kv"])))
+    state_kinds=("kv", "conv", "ssm"), matrix_kind="ssm",
+    state_shapes=lambda positions: (
+        [(positions, CACHE[1])] * 2, [TAILS] * 3, [MATRIX] * 3),
+    state_layers={"kv": [0, 0, 0, 0, 0, 2, 0],
+                  "conv": [1, 0, 1, 0, 1, 0, 0],
+                  "ssm": [1, 0, 1, 0, 1, 0, 0]},
+    collections=frozenset({"params", "constants"}),
+    expert_layers=3, experts_per_token=2,  # the expert layers
+    # The norms' weights and D are seeded too (one at initialisation), so
+    # that a norm's place shows.
+    seeded=seeded_norms("ssm_d"), limits_build=dict(bias_scale=0.2),
+    shown=attention_layer_shown, reset=RESET, episodes=EPISODES,
+    # Blocks in bfloat16, at these widths: the reference itself, its
+    # blocks rounded to bfloat16, stands several per cent from its float32
+    # self. No further off than twice that, and the routing within a tenth.
+    bfloat16=(2, 0.3, 0.1),
+    # A fragment of whole chunks and one that ends inside a chunk.
+    other_lengths=(S - 3,), handed_atol=2e-5,
+    # Prefixes shorter than the taps, at a chunk's edge, inside a chunk.
+    prefixes=(2, 3, 5, 8, 13),
+    long_lived=slow_decays,
+    carried_error=lambda wrong, kept: not wrong["ok"],
     # The attention layer alone reads a cache: off a TPU, all of it.
-    assert counted[-1] == {"decode_cache_read_share": 1.0}
+    decode_counters={"decode_cache_read_share": 1.0},
+    wrong_updates={
+        "taps_reversed_in_the_gradient": dict(mutate="taps_reversed"),
+        "a_decay_a_channel": dict(mutate="decay_a_channel"),
+        "relu_for_relu2": dict(mutate="relu_not_squared"),
+        "the_gate_after_the_norm": dict(mutate="gate_after_norm"),
+        "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0},
+                                 by="loss_error"),
+        "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
+        "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error")},
+    refused=(
+        (dict(n_group=2), "n_group"), (dict(topk_group=2), "topk_group"),
+        (dict(mamba_proj_bias=True), "mamba_proj_bias"),
+        (dict(use_bias=True), "use_bias"),
+        (dict(attention_bias=True), "attention_bias"),
+        (dict(mlp_bias=True), "mlp_bias"),
+        (dict(use_conv_bias=False), "use_conv_bias"),
+        (dict(sliding_window=128), "sliding_window"),
+        (dict(mlp_hidden_act="silu"), "mlp_hidden_act"),
+        (dict(time_step_limit=[0, 1.0]), "time_step_limit"),
+        (dict(hybrid_override_pattern="ME-M*EM"), "no layer"),
+        (dict(hybrid_override_pattern="MEM"), "names 7 layers"),
+        (dict(kda_chunk=64), "not nemotron_h's"),
+        (dict(first_expert_held=7), "not among"),
+        (dict(mamba_num_heads=9), "groups")),
+    example="nemotron-h-token-impala.yaml",
+    cell="nemotron_h_token_anakin_2k",
+    config="impala_nemotron_twotower_30b_a3b",
+    # At the published widths a decode step of 128 rows sends 6 rows to a
+    # held expert, in the batched form; the one grouped cache takes the
+    # kernel (32 query heads over 2 cached ones: 256 lanes a position) and
+    # the causal pass the fused form; three matrix states of 2 MB a row.
+    program=dict(
+        rows=128, fragment=2048,
+        on_tpu={
+            "decode_rows_per_expert": 6.0, "decode_experts_batched": 1.0,
+            "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
+            "decode_cache_block": 128, "decode_attention_kernel": 1.0,
+            "causal_attention_fused": 1.0,
+            "kv_cache_bytes_per_token": 1024.0,
+            "rotation_fused_layers": 0.0, "kv_groups": 16, "conv_layers": 3,
+            "conv_state_bytes_per_row": 110592, "ssm_layers": 3,
+            "ssm_state_bytes_per_row": 6291456, "ssm_chunk": 128,
+            "state_step_kernel": 0.0},
+        off_tpu={"causal_attention_fused": 0.0, "decode_cache_block": 2048,
+                 "decode_attention_kernel": 0.0},
+        state={"kv": [((128, 2048, 256), "bfloat16")] * 2,
+               "conv": [((128, 3, 6144), "bfloat16")] * 3,
+               "ssm": [((128, 64, 64, 128), "float32")] * 3},
+        parameters=528_095_809))
 
 
 def test_a_layer_is_one_function_and_an_expert_has_no_gate():
     """The parameters say it: a mixer layer has the operator's norm and no
     feed-forward, an expert layer the feed-forward's norm, two matrices an
     expert and two for the shared one, and no operator."""
-    _, variables, _ = build("f32")
+    _, variables, _ = build(FAMILY, "f32")
     params = variables["params"]
     for i, letter in enumerate(NET["hybrid_override_pattern"]):
         names = set(params[f"layer_{i}"])
@@ -256,31 +194,7 @@ def test_a_layer_is_one_function_and_an_expert_has_no_gate():
     assert set(variables["constants"]) == {"layer_1", "layer_3", "layer_6"}
 
 
-def scalar_of(logits, values):
-    weight = jax.random.normal(jax.random.PRNGKey(7), logits.shape)
-    return jnp.sum(logits * weight) + jnp.sum(jnp.sin(values))
-
-
-def model_gradients(variables, tokens, reset=None):
-    """The gradient of one scalar of the outputs with respect to every
-    parameter, through the system's scan over chunks and through the
-    reference's recurrence."""
-    model, _, _ = build("f32")
-
-    def system(params):
-        logits, values, _ = model.apply(
-            dict(variables, params=params), tokens, None,
-            jnp.zeros(tokens.shape) if reset is None else reset)
-        return scalar_of(logits, values)
-
-    def recurrence(params):
-        out = reference.forward(dict(variables, params=params), tokens, NET,
-                                starts=reset)
-        return scalar_of(out["logits"], out["values"])
-    return (jax.jit(jax.grad(system))(variables["params"]),
-            jax.jit(jax.grad(recurrence))(variables["params"]))
-
-
+# -- the scan over chunks against the recurrence ----------------------------
 _OPERATOR = {}  # compiled once for the fragment whole, once cut by resets
 
 
@@ -290,7 +204,7 @@ def operator_gradients(variables, layer, reset=None):
     parameters) through the system's scan over chunks and through the
     reference's recurrence."""
     if (reset is None) not in _OPERATOR:
-        model, _, _ = build("f32")
+        model, _, _ = build(FAMILY, "f32")
         x = jax.random.normal(jax.random.PRNGKey(5),
                               (B, S, NET["hidden_size"]))
         weight = jax.random.normal(jax.random.PRNGKey(6), x.shape)
@@ -334,7 +248,7 @@ def test_the_scan_s_backward_pass_is_the_recurrence_s_gradient(reset, decays):
     bodies) is `jax.grad`'s through the recurrence, to 1e-5; the fragment
     whole and cut by resets; the decays as drawn and so fast that a head
     loses more than e^100 inside one chunk."""
-    _, variables, _ = build("f32")
+    _, variables, _ = build(FAMILY, "f32")
     if decays == "fast":
         variables = fast_decays(variables)
     for layer in ("layer_0", "layer_4"):
@@ -349,96 +263,26 @@ def test_the_scan_s_backward_pass_is_the_recurrence_s_gradient(reset, decays):
                 got[name], want[name]) < 1e-5, (layer, name)
 
 
-@pytest.mark.parametrize("reset", [None, RESET], ids=["whole", "resets"])
-def test_the_model_s_gradient_is_the_reference_s(reset):
-    """Every parameter of the seven layers, through three scans, the
-    attention layer and three expert layers without a gate matrix."""
-    _, variables, tokens = build("f32")
-    got, want = model_gradients(variables, tokens, reset)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert reference.relative_error(a, b) < 5e-5
-
-
-def test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over():
-    """Prefixes shorter than the taps, at a chunk's edge, inside a chunk:
-    the pass's state is the matrix after its last position and the
-    convolution's last three inputs (zeros where the episode is shorter),
-    and the decode goes on from it."""
-    model, variables, tokens = build("f32")
-    decode = jax.jit(lambda token, state, reset: model.apply(
-        variables, token, state, reset))
-    (full, _, _), _, _ = causal_routed(model, variables, tokens)
-    for prefix in (2, 3, 5, 8, 13):
-        _, state, _ = causal_routed(model, variables, tokens[:, :prefix])
-        for t in range(prefix, S):
-            step, _, state = decode(tokens[:, t:t + 1], state,
-                                    jnp.zeros((B, 1)))
-            assert reference.relative_error(
-                step[:, 0], full[:, t]) < 1e-5, (prefix, t)
-
-
-def test_resets_inside_a_chunk_at_its_edge_and_an_episode_one_token_long():
-    """Four episodes in a fragment, the second one token long, the last
-    beginning with a chunk: what separate passes give, in both forms and
-    in the reference; the state handed over is the last episode's alone."""
-    model, variables, tokens = build("f32")
-    both, state, _ = causal_routed(model, variables, tokens, RESET)
-    parts = []
-    for a, b in EPISODES:
-        if b - a > 1:
-            parts.append(causal_routed(model, variables, tokens[:, a:b]))
-        else:
-            # A causal pass takes two tokens or more: the lone token as a
-            # decode step from empty state.
-            lone, _, _ = model.apply(variables, tokens[:, a:b],
-                                     model.initial_state(B), jnp.ones((B, 1)))
-            parts.append(((lone,), None, None))
-    separate = jnp.concatenate([p[0][0] for p in parts], axis=1)
-    assert reference.relative_error(both[0], separate) < 1e-5
-    last = parts[-1][1]
-    for key in ("conv", "ssm"):
-        for got, want in zip(jax.tree.leaves(state[key]),
-                             jax.tree.leaves(last[key])):
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    assert np.all(np.asarray(state["pos"]) == S - 16)
-    outputs, routing = judged(both, variables, tokens, starts=RESET)
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    assert routing["router_flips"] == 0.0
-    stepped, stepped_state, _ = decode_routed(model, variables, tokens, RESET)
-    assert reference.relative_error(stepped[0], both[0]) < 1e-5
-    for got, want in zip(jax.tree.leaves(stepped_state["ssm"]),
-                         jax.tree.leaves(state["ssm"])):
-        np.testing.assert_allclose(got, want, atol=1e-5)
-    # A fragment that ends one token into an episode hands over one input
-    # of the convolution, two zero rows, and a matrix of rank one a head.
-    _, _, short = model.apply(
-        variables, tokens[:, :13], None, RESET[:, :13])
-    for held in jax.tree.leaves(short["conv"]):
-        assert not np.any(np.asarray(held[:, :2]))
-        assert np.any(np.asarray(held[:, 2]))
-    for held in jax.tree.leaves(short["ssm"]):
-        assert np.all(np.linalg.matrix_rank(np.asarray(held)) == 1)
-
-
 def test_decays_that_lose_e100_inside_a_chunk_stay_finite_and_agree():
     """Every exponent of the scan is a sum of log decays: the whole model's
     outputs and gradients are finite and the recurrence's, which multiplies
     by exp(la) one position at a time."""
-    model, variables, tokens = build("f32")
+    built = build(FAMILY, "f32")
+    _, variables, tokens = built
     variables = fast_decays(variables)
     lp = variables["params"]["layer_0"]
     assert CHUNK * float(jnp.min(jnp.exp(lp["ssm_a_log"]))) * 15.0 > 100.0
     for reset in (None, RESET):
-        system, state, _ = causal_routed(model, variables, tokens, reset)
+        system, state, _ = causal_routed(built, variables, tokens, reset)
         assert all(np.isfinite(a).all() for a in system[:2])
-        outputs, routing = judged(system, variables, tokens, starts=reset)
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        assert routing["router_flips"] == 0.0
-    got, want = model_gradients(variables, tokens, RESET)
+        verdicts, _ = judged(FAMILY, system, variables, tokens, starts=reset)
+        assert max(verdicts["outputs"]["errors"].values()) < 1e-5, verdicts
+        assert verdicts["routing"]["router_flips"] == 0.0
+    got, want = model_gradients(FAMILY, variables, tokens, RESET)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.isfinite(a).all()
         assert reference.relative_error(a, b) < 2e-4
-    stepped, _, _ = decode_routed(model, variables, tokens, RESET)
+    stepped, _, _ = decode_routed(built, variables, tokens, RESET)
     assert reference.relative_error(stepped[0], system[0]) < 1e-5
 
 
@@ -477,18 +321,6 @@ def by_steps(starts):
                   for a in (x, Bm, Cm, la, jnp.asarray(starts))))
         return jnp.moveaxis(y, 0, 1), S
     return run
-
-
-def read_by(run, operands):
-    """(outputs, final state, gradients by x, B, C, la) of a scalar that
-    reads every output and every entry of the final state."""
-    def scalar(*operands):
-        y, S = run(*operands)
-        return (jnp.sum(jnp.sin(y) * jnp.arange(1, y.shape[1] + 1)[
-            None, :, None, None]) + jnp.sum(jnp.cos(S))), (y, S)
-    grads, (y, S) = jax.jit(jax.grad(
-        scalar, argnums=(0, 1, 2, 3), has_aux=True))(*operands)
-    return (y, S) + grads
 
 
 @pytest.mark.parametrize("rate", [3.0, 40.0], ids=["slow", "fast"])
@@ -532,63 +364,6 @@ def test_the_pair_weights_are_sums_of_log_decays_not_differences():
     assert abs(float(lost)) > 1e-4
 
 
-# -- what the limits refuse ------------------------------------------------
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The comparison fails each named error and blocks computed a
-    precision lower: the reference, so altered, in the system's place
-    against itself, by its outputs or by its routing. The fragment holds
-    resets, so that a convolution that reaches across one shows."""
-    _, variables, tokens = build("f32", bias_scale=0.2)
-    # The one attention layer's softmax far enough from uniform, and its
-    # output large enough beside the other layers', that a rotation shows
-    # in the logits.
-    params = dict(variables["params"])
-    params["layer_5"] = dict(params["layer_5"],
-                             wq=4.0 * params["layer_5"]["wq"],
-                             wk=4.0 * params["layer_5"]["wk"],
-                             wo=3.0 * params["layer_5"]["wo"])
-    variables = dict(variables, params=params)
-    if wrong == "float8_e4m3":
-        got = plain(variables, tokens, starts=RESET, round_to=wrong)
-    else:
-        got = plain(variables, tokens, starts=RESET, mutate=wrong)
-    outputs, routing = judged(
-        (got["logits"], got["values"], got["experts"]), variables, tokens,
-        starts=RESET)
-    assert not (outputs["ok"] and routing["ok"]), (wrong, outputs, routing)
-
-
-def test_a_bfloat16_matrix_state_is_refused_by_the_decode_s_limit():
-    """The state is summed into at every step, so keeping it in bfloat16
-    (rounded after every step; everything else float32) is no rounding of
-    a block's output: its error is carried on and added to. Over a few
-    hundred steps the logits leave the reference by more than the cell's
-    limit, where the float32 state's stay at 1e-5."""
-    steps = 384
-    net = dict(NET, max_position_embeddings=steps)
-    model, variables, tokens = build("f32", net, tokens=steps)
-    # Decays slow enough that a state holds hundreds of positions.
-    params = dict(variables["params"])
-    for layer in SSM_LAYERS:
-        params[layer] = dict(
-            params[layer],
-            ssm_a_log=params[layer]["ssm_a_log"] - jnp.log(16.0))
-    variables = dict(variables, params=params)
-
-    def rounded(state):
-        return dict(state, ssm=jax.tree.map(
-            lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
-            state["ssm"]))
-    kept, _, _ = decode_routed(model, variables, tokens)
-    lost, _, _ = decode_routed(model, variables, tokens, between=rounded)
-    outputs, _ = judged(kept, variables, tokens, net)
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    held = plain(variables, tokens, net, kept[2])
-    wrong = reference.compare(lost[:2], (held["logits"], held["values"]))
-    assert not wrong["ok"], wrong
-
-
 # -- the expert layer that holds a share ---------------------------------
 def test_the_16_shares_add_up_to_the_uncut_layer():
     """16 shares of 2 of 32 experts without a gate matrix: their parts,
@@ -608,16 +383,14 @@ def test_the_16_shares_add_up_to_the_uncut_layer():
     m = transformer.rms_norm(h, jnp.ones(H), 1e-5, jnp.float32)
     net = dict(NET, n_routed_experts=E, num_experts_per_tok=k)
 
-    def share_of(first, size):
-        return dict(lp, **{w: lp[w][first:first + size]
-                           for w in ("w_up", "w_down")})
-
+    @functools.partial(jax.jit, static_argnums=(1, 2))
     def layer(first, size, shared=1):
         share = dict(net, experts_held=size, first_expert_held=first,
                      n_shared_experts=shared)
         with jax.default_matmul_precision("highest"):
-            return reference._moe(share_of(first, size), bias, h, m, share,
-                                  lambda a: a, None, None)
+            return reference._moe(
+                share_of(lp, first, size, ("w_up", "w_down")), bias, h, m,
+                share, lambda a: a, None, None)
     whole, chosen, _ = layer(0, E)
     with jax.default_matmul_precision("highest"):
         shared = reference._relu2_mlp(m, lp["shared_up"], lp["shared_down"],
@@ -642,7 +415,7 @@ def test_the_16_shares_add_up_to_the_uncut_layer():
         n, p, i = (jnp.tile(a, (reps, 1)) for a in (rows, top_p, top_i))
         routed, landed = jnp.zeros_like(n), 0
         for first in range(0, E, held):
-            s = share_of(first, held)
+            s = share_of(lp, first, held, ("w_up", "w_down"))
             part, sizes, _ = dropless_experts(
                 n, p, i, None, s["w_up"], s["w_down"], first, E,
                 transformer.relu2)
@@ -655,60 +428,14 @@ def test_the_16_shares_add_up_to_the_uncut_layer():
     assert not transformer.experts_batched(64 * rows.shape[0], k, E)
 
 
-def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
-        grouped_pass_is_the_batched_pass):
-    """The grouped form without a gate matrix, its `switch` over the row
-    counts and their pullbacks of two products."""
-    grouped_pass_is_the_batched_pass(*build("f32"))
-
-
 # -- the cell, from its static shapes ---------------------------------------
-def published_cut():
-    with open(os.path.join(
-            BENCH, "configs", "impala_nemotron_twotower_30b_a3b.json")) as f:
-        net = json.load(f)["network"]
-    return {k: v for k, v in net.items() if k != "param_count"}
-
-
-def test_the_cell_s_program_is_known_from_its_static_shapes():
-    """At the published widths a decode step of 128 rows sends 6 rows to a
-    held expert, in the batched form; the learner's 8,192 rows go grouped,
-    through a ladder of row counts; the one grouped cache takes the kernel
-    (32 query heads over 2 cached ones: 256 lanes a position) and the
-    causal pass the fused form; three matrix states of 2 MB a row; nothing
-    but shapes is built."""
-    net = published_cut()
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "nemotron_h", "custom_model_config": net})
-    assert model.static_counters(128, 2048, "tpu") == {
-        "decode_rows_per_expert": 6.0, "decode_experts_batched": 1.0,
-        "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
-        "decode_cache_block": 128, "decode_attention_kernel": 1.0,
-        "causal_attention_fused": 1.0, "kv_cache_bytes_per_token": 1024.0,
-        "rotation_fused_layers": 0.0, "kv_groups": 16, "conv_layers": 3,
-        "conv_state_bytes_per_row": 110592, "ssm_layers": 3,
-        "ssm_state_bytes_per_row": 6291456, "ssm_chunk": 128,
-        "state_step_kernel": 0.0}
-    off = model.static_counters(128, 2048, "cpu")
-    assert (off["causal_attention_fused"], off["decode_cache_block"],
-            off["decode_attention_kernel"], off["state_step_kernel"]) == (
-                0.0, 2048, 0.0, 0.0)
+def test_the_cell_s_learner_goes_grouped_through_a_ladder_of_row_counts():
+    """The learner's 8,192 rows go grouped, through a ladder of row
+    counts; 32 query heads over 2 cached ones take the grouped kernel."""
     assert transformer.grouped_fused(2048, 2, 32, 128)
     assert not transformer.experts_batched(8192, 6, 128)
     assert transformer.dispatch_rows(8192, 6, 8, 128) == (
         3840, 6144, 12288, 49152)
-    state = jax.eval_shape(lambda: model.initial_state(128))
-    assert set(state) == {"kv", "conv", "ssm", "pos"}
-    assert [a.shape for a in jax.tree.leaves(state["ssm"])] == [
-        (128, 64, 64, 128)] * 3
-    assert [a.shape for a in jax.tree.leaves(state["conv"])] == [
-        (128, 3, 6144)] * 3
-    assert [a.shape for a in jax.tree.leaves(state["kv"])] == [
-        (128, 2048, 256)] * 2
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-        model.initial_state(1), jnp.zeros((1, 1))))
-    assert sum(a.size for a in jax.tree.leaves(shapes)) == 528_095_809
 
 
 def test_the_other_families_states_and_counters_are_what_they_were():
@@ -731,32 +458,6 @@ def test_the_other_families_states_and_counters_are_what_they_were():
         transformer.relu2(jnp.asarray([-2.0, 0.5, 3.0])), [0.0, 0.25, 9.0])
 
 
-@pytest.mark.parametrize("cfg,match", [
-    (dict(n_group=2), "n_group"), (dict(topk_group=2), "topk_group"),
-    (dict(mamba_proj_bias=True), "mamba_proj_bias"),
-    (dict(use_bias=True), "use_bias"),
-    (dict(attention_bias=True), "attention_bias"),
-    (dict(mlp_bias=True), "mlp_bias"),
-    (dict(use_conv_bias=False), "use_conv_bias"),
-    (dict(sliding_window=128), "sliding_window"),
-    (dict(mlp_hidden_act="silu"), "mlp_hidden_act"),
-    (dict(time_step_limit=[0, 1.0]), "time_step_limit"),
-    (dict(hybrid_override_pattern="ME-M*EM"), "no layer"),
-    (dict(hybrid_override_pattern="MEM"), "names 7 layers"),
-    (dict(kda_chunk=64), "not nemotron_h's"),
-    (dict(first_expert_held=7), "not among"),
-    (dict(mamba_num_heads=9), "groups"),
-])
-def test_custom_model_config_without_a_part_is_refused(cfg, match):
-    net = dict(NET, **cfg)
-    with pytest.raises(ValueError, match=match):
-        model = catalog.get_model(None, 96, {
-            "custom_model": "nemotron_h", "custom_model_config": net})
-        jax.eval_shape(lambda: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-            model.initial_state(1), jnp.zeros((1, 1))))
-
-
 def test_keys_left_out_have_the_published_model_s_values():
     model = transformer.nemotron_h_from_config(131072, {})
     assert (model.hidden_size, model.num_layers, model.num_heads,
@@ -774,23 +475,5 @@ def test_keys_left_out_have_the_published_model_s_values():
     assert model.hidden_act == "relu2" and model.selection_bias
     assert not model.qk_norm and not model.tie_embeddings
     # Every key of the published config is taken, the unread ones too.
-    published = published_cut()
+    published = configuration(FAMILY)[3]
     assert transformer.nemotron_h_from_config(16384, published).held == 8
-
-
-def test_the_tuned_example_is_the_benchmark_s_cell():
-    import yaml
-    root = os.path.dirname(BENCH)
-    with open(os.path.join(root, "ray_tpu", "rllib", "tuned_examples",
-                           "nemotron-h-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(BENCH, "workloads",
-                           "nemotron_h_token_anakin_2k.json")) as f:
-        cell = json.load(f)["trainer_config"]
-    config = example["config"]
-    assert config["model"]["custom_model"] == "nemotron_h"
-    assert config["model"]["custom_model_config"] == published_cut()
-    for key, value in cell.items():
-        if key != "env":
-            assert config[key] == value, key
-    assert example["env"] == cell["env"]

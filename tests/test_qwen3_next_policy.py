@@ -1,40 +1,53 @@
-"""The `qwen3_next` token policy at a tiny size on the CPU: the model against
-the plain reference (`benchmark/lib/reference_qwen3_next.py`, whose Gated
-DeltaNet is the recurrence itself, one position at a time) in its causal form
-(the delta rule's chunked scan under ONE decay a head, a key head serving two
-value heads) and in its decode through three kinds of state (a Gated DeltaNet
-layer's matrix a value head and its convolution's last inputs, the attention
-layer's grouped cache); the scan against the literal loop, forward and
-gradients, at several chunk lengths with episodes that begin inside a chunk;
-the scalar-decay path against the per-channel one (`kda_chunked`, `kda_step`)
-fed the same decay on every channel, which ties the two models' shared code;
-the partial rotation, the two gates and the zero-centred norms against the
-reference, each named wrong mathematics refused by the cell's limits; the
-sixteen shares of the experts against the uncut layer. V-trace's loss, its
-gradients, one update of the optimizer's own against the reference's and the
-trainer on the fused Anakin path stand in `tests/test_qwen3_next_update.py`,
-a file of its own so that the two run on two workers.
+"""The `qwen3_next` token policy at a tiny size on the CPU: the family's row,
+the checks it shares with the other families (`tests/token_families.py`: the
+model against the plain reference `benchmark/lib/reference_qwen3_next.py`,
+whose Gated DeltaNet is the recurrence itself, one position at a time, in its
+causal form and decoded through three kinds of state, a Gated DeltaNet layer's
+matrix a value head and its convolution's last inputs and the attention
+layer's grouped cache; a decode that continues a causal pass; resets inside a
+chunk, at a chunk's edge, and an episode one token long against separate
+passes; the model's gradient against the recurrence's; the partial rotation,
+the two gates and the zero-centred norms, each named wrong mathematics refused
+by the cell's limits; the grouped form of the expert product; the cell's
+program from its shapes; the builder's refusals; the tuned example) and what
+is its own: the delta rule's chunked scan under ONE decay a head, a key head
+serving two value heads, against the literal loop, forward and gradients, at
+several chunk lengths with episodes that begin inside a chunk; the
+scalar-decay path against the per-channel one (`kda_chunked`, `kda_step`) fed
+the same decay on every channel, which ties the two models' shared code; a
+matrix state kept in bfloat16 refused; the sixteen shares of the experts
+against the uncut layer. The loss and the loop:
+`tests/test_qwen3_next_update.py`.
 """
 
+import functools
 import json
 import os
-import sys
-import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from token_families import (  # noqa: F401: pytest collects what is named
+    BENCH, Family, build, configuration, count, noise, read_by, shapes_of,
+    share_of,
+    test_a_bfloat16_matrix_state_is_refused_by_the_decode_s_limit,
+    test_a_causal_pass_over_the_landed_rows_is_the_batched_pass,
+    test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over,
+    test_causal_pass_matches_reference,
+    test_custom_model_config_without_a_part_is_refused,
+    test_decode_through_every_kind_of_state_matches_reference
+    as test_decode_through_three_kinds_of_state_matches_reference,
+    test_limits_refuse_wrong_mathematics,
+    test_resets_inside_a_chunk_at_its_edge_and_an_episode_one_token_long,
+    test_the_cell_s_program_is_known_from_its_static_shapes,
+    test_the_model_s_gradient_is_the_reference_s,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_qwen3_next as reference
 
-from lib import reference_qwen3_next as reference  # noqa: E402
-
-from ray_tpu.models import catalog, transformer  # noqa: E402
-from ray_tpu.models.transformer import dropless_experts  # noqa: E402
+from ray_tpu.models import catalog, transformer
+from ray_tpu.models.transformer import dropless_experts
 
 # The cell's four layers, one period G G G A: 2 key heads serving 4 value
 # heads of 16, chunks of 8 positions solved in blocks of 4; 4 query heads
@@ -60,6 +73,151 @@ MATRIX, TAILS, CACHE = (4, 16, 16), (3, 2 * 32 + 64), (S, 2 * 16)
 RESET = jnp.zeros((B, S)).at[:, 11].set(1.0).at[:, 12].set(1.0).at[
     :, 16].set(1.0)
 EPISODES = ((0, 11), (11, 12), (12, 16), (16, S))
+# The cell's parameters at the published widths, by hand.
+GDN = 2048 * 12288 + 2048 * 64 + 8192 * 4 + 32 + 32 + 128 + 4096 * 2048
+GATED = 2048 * 8192 + 2 * 2048 * 512 + 4096 * 2048 + 2 * 256
+EXPERTS = 2048 * 512 + 32 * 3 * 2048 * 512 + 3 * 2048 * 512 + 2048
+PARAMETERS = (2 * 18992 * 2048 + 3 * GDN + GATED + 4 * (EXPERTS + 4096)
+              + 2048 + 2048 + 1)
+
+
+def seeded(path, a):
+    """The norms' weights are seeded too (zero-centred ones stand at 0 at
+    initialisation, the output norm at 1), so that a norm's place and its
+    centring show, and the decays are slowed."""
+    name = path[-1].key
+    if name == "gdn_a_log":
+        # A = exp(A_log) in (0, 0.8) where the family draws (0, 16): at
+        # its draw nearly every head forgets its state within a
+        # position, and what these tests are for is a state that lasts.
+        return a - 3.0
+    if not name.endswith("norm") and name != "shared_scale":
+        return a
+    return a + 0.5 * noise(path, a)
+
+
+def attention_layer_shown(variables):
+    """The attention layer's softmax far enough from uniform (its queries
+    and keys are normalised a head, so it is their norms' weights that
+    sharpen it: 1 + w about 4), and its output large enough beside the
+    three layers before it, that its rotation and its gate show in the
+    logits; the decays slow enough that a state outlives a few
+    positions."""
+    params = dict(variables["params"])
+    params["layer_3"] = dict(params["layer_3"],
+                             q_norm=params["layer_3"]["q_norm"] + 3.0,
+                             k_norm=params["layer_3"]["k_norm"] + 3.0,
+                             wo=6.0 * params["layer_3"]["wo"])
+    for name in GDN_LAYERS:
+        params[name] = dict(
+            params[name], gdn_a_log=params[name]["gdn_a_log"] - 3.0,
+            gdn_out=4.0 * params[name]["gdn_out"])
+    return dict(variables, params=params)
+
+
+def long_sums(variables):
+    """Decays slow enough that the sums into a state are long."""
+    params = dict(variables["params"])
+    for name in GDN_LAYERS:
+        params[name] = dict(
+            params[name], gdn_a_log=params[name]["gdn_a_log"] - 5.0,
+            gdn_out=3.0 * params[name]["gdn_out"])
+    return dict(variables, params=params)
+
+
+FAMILY = Family(
+    name="qwen3_next", net=NET, reference=reference, B=B, S=S,
+    # What a pass hands a decode: the one layer's two caches, three
+    # layers' convolution inputs, three layers' matrices, a key a kind.
+    state_kinds=("kv", "conv", "gdn"), matrix_kind="gdn",
+    state_shapes=lambda positions: (
+        [(positions, CACHE[1])] * 2, [TAILS] * 3, [MATRIX] * 3),
+    state_layers={"kv": [0, 0, 0, 2], "conv": [1, 1, 1, 0],
+                  "gdn": [1, 1, 1, 0]},
+    expert_layers=4, experts_per_token=3,  # every layer
+    seeded=seeded, shown=attention_layer_shown, reset=RESET,
+    episodes=EPISODES,
+    # Blocks in bfloat16, at these widths (heads of 16 values under a norm
+    # of their own): the reference itself, its blocks rounded to bfloat16,
+    # stands 5-17 % from its float32 self. No further off than three times
+    # that (two roundings of the same sums differ that much between seeds
+    # here: a head's normalised output turns on the sign of q . k, which a
+    # rounding may take either way), and the routing within a fifth (3 of
+    # 16 experts after four bfloat16 blocks).
+    bfloat16=(3, 0.6, 0.2),
+    # A fragment of whole chunks and one that ends inside a chunk.
+    other_lengths=(S - 3,), handed_atol=2e-5,
+    # Prefixes shorter than the taps, at a chunk's edge, inside a chunk.
+    prefixes=(2, 8, 13),
+    long_lived=long_sums,
+    carried_error=lambda wrong, kept: (
+        max(wrong["errors"].values()) > max(
+            2e-3, 100 * max(kept["errors"].values()))),
+    # The attention layer alone reads a cache: off a TPU, all of it.
+    decode_counters={"decode_cache_read_share": 1.0},
+    wrong_updates={
+        "taps_reversed_in_the_gradient": dict(mutate="taps_reversed"),
+        "a_decay_a_key_head": dict(mutate="decay_a_key_head"),
+        "beta_out_of_the_subtraction": dict(
+            mutate="beta_out_of_subtraction"),
+        "the_whole_head_rotated": dict(mutate="rope_whole_head"),
+        "no_attention_gate": dict(mutate="no_attention_gate"),
+        "norms_not_zero_centred": dict(mutate="norms_not_zero_centred"),
+        "vf_coeff_doubled": dict(cfg={"vf_loss_coeff": 1.0},
+                                 by="loss_error"),
+        "no_clip": dict(cfg={"grad_clip": None}, by="update_error"),
+        "ten_times_the_lr": dict(cfg={"lr": 6e-3}, by="update_error")},
+    refused=(
+        ({"n_routed_experts": 8}, "not qwen3_next's"),
+        ({"layer_types": ["linear_attention"]}, "not qwen3_next's"),
+        ({"num_nextn_predict_layers": 1}, "not qwen3_next's"),
+        ({"mlp_only_layers": [0]}, "mlp_only_layers"),
+        ({"decoder_sparse_step": 2}, "decoder_sparse_step"),
+        ({"use_sliding_window": True}, "use_sliding_window"),
+        ({"attention_bias": True}, "attention_bias"),
+        ({"hidden_act": "gelu"}, "hidden_act"),
+        ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+        ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
+        ({"full_attention_interval": 0}, "full_attention_interval"),
+        ({"linear_num_value_heads": 3}, "value heads"),
+        ({"num_key_value_heads": 3}, "key/value heads"),
+        ({"experts_held": 6, "first_expert_held": 12}, "not among")),
+    example="qwen3-next-token-impala.yaml",
+    cell="qwen3_next_token_anakin_4k",
+    config="impala_qwen3_next_80b_a3b",
+    # At the published widths: 625.7 M parameters; ONE layer's caches of
+    # 4,096 positions, 2,048 bytes a position; three layers' convolution
+    # inputs, 147,456 bytes a sequence, and three layers' matrices,
+    # 6,291,456, whatever its length; heads of 256 in groups of 8 take the
+    # decode kernel and the fused causal form; the 32 held experts of 512
+    # take the grouped-matmul kernels.
+    program=dict(
+        rows=32, fragment=4096, minibatch=(8192,),
+        on_tpu={
+            # Under two rows a held expert: a rollout's step reads the
+            # chosen ones' matrices alone, and counts their share itself.
+            "decode_rows_per_expert": 0.625, "decode_experts_batched": 0.0,
+            "decode_experts_sparse": 1.0,
+            "decode_cache_block": 128, "decode_attention_kernel": 1.0,
+            "causal_attention_fused": 1.0, "experts_grouped_kernel": 1.0,
+            # The one gated layer: a quarter of a head of 256 rotated.
+            "rotation_fused_layers": 1.0,
+            "kv_cache_bytes_per_token": 2048.0, "kv_groups": 8,
+            "conv_layers": 3, "conv_state_bytes_per_row": 147456,
+            "gdn_layers": 3, "gdn_state_bytes_per_row": 6291456,
+            "gdn_chunk": 64, "state_step_kernel": 1.0},
+        # Off a TPU the cache is read whole, by XLA's products, and every
+        # held expert's matrices.
+        off_tpu={
+            "decode_experts_batched": 1.0, "decode_experts_sparse": 0.0,
+            "decode_experts_read_share": 1.0,
+            "causal_attention_fused": 0.0, "decode_cache_block": 4096,
+            "decode_attention_kernel": 0.0, "state_step_kernel": 0.0,
+            "rotation_fused_layers": 0.0, "experts_grouped_kernel": 0.0},
+        state={"kv": [((32, 4096, 512), "bfloat16")] * 2,
+               "conv": [((32, 3, 8192), "bfloat16")] * 3,
+               "gdn": [((32, 32, 128, 128), "float32")] * 3},
+        parameters=PARAMETERS))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -71,272 +229,14 @@ def solve_in_blocks_of_four():
         yield
 
 
-def build(dtype, net=NET, tokens=S):
-    """(model, seeded variables, tokens). The norms' weights are seeded
-    too (zero-centred ones stand at 0 at initialisation, the output norm at
-    1), so that a norm's place and its centring show, and the decays are
-    slowed."""
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "qwen3_next", "custom_model_config": net,
-        "compute_dtype": dtype})
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (B, tokens), 0, net["vocab_size"])
-    variables = model.init(jax.random.PRNGKey(0), tokens[:, :1],
-                           model.initial_state(B), jnp.zeros((B, 1)))
-
-    def seeded(path, a):
-        name = path[-1].key
-        if name == "gdn_a_log":
-            # A = exp(A_log) in (0, 0.8) where the family draws (0, 16): at
-            # its draw nearly every head forgets its state within a
-            # position, and what these tests are for is a state that lasts.
-            return a - 3.0
-        if not name.endswith("norm") and name != "shared_scale":
-            return a
-        key = jax.random.fold_in(jax.random.PRNGKey(2), zlib.crc32(
-            jax.tree_util.keystr(path).encode()) % 2 ** 31)
-        return a + 0.5 * jax.random.normal(key, a.shape)
-    return model, dict(variables, params=jax.tree_util.tree_map_with_path(
-        seeded, variables["params"])), tokens
-
-
-def plain(variables, tokens, net=NET, experts=None, starts=None, **how):
-    """The reference's forward, compiled (its scans run op by op
-    otherwise)."""
-    return jax.jit(lambda v, t, e, s: reference.forward(
-        v, t, net, experts=e, starts=s, **how))(
-            variables, tokens, experts, starts)
-
-
-def judged(system, variables, tokens, net=NET, starts=None):
-    """The system's (logits, values, experts) against the reference held
-    to those experts: (outputs, routing)."""
-    logits, values, experts = system
-    held = plain(variables, tokens, net, experts, starts)
-    return (reference.compare((logits, values),
-                              (held["logits"], held["values"])),
-            reference.routing_verdict(experts, held["experts"],
-                                      held["select"]))
-
-
-def within_bfloat16(system, variables, tokens, net=NET, starts=None):
-    """Blocks in bfloat16, at these widths (heads of 16 values under a norm
-    of their own): the limits at the published widths are no measure here,
-    where the reference itself, its blocks rounded to bfloat16, stands
-    5-17 % from its float32 self. The system is held to that: no further
-    off than three times the rounded reference (two roundings of the same
-    sums differ that much between seeds here: a head's normalised output
-    turns on the sign of q . k, which a rounding may take either way), and
-    its routing within a fifth (3 of 16 experts after four bfloat16
-    blocks)."""
-    outputs, routing = judged(system, variables, tokens, net, starts)
-    low = plain(variables, tokens, net, starts=starts,
-                round_to=jnp.bfloat16)
-    rounded, _ = judged((low["logits"], low["values"], low["experts"]),
-                        variables, tokens, net, starts)
-    assert routing["router_flips"] <= 0.2, routing
-    for name, error in outputs["errors"].items():
-        assert error <= 3 * rounded["errors"][name] < 0.6, (
-            outputs, rounded)
-
-
-def causal_routed(model, variables, tokens, reset=None):
-    (logits, values, state), kept = jax.jit(
-        lambda v, t, r: model.apply(v, t, None, r,
-                                    mutable=["routing", "counters"]))(
-            variables, tokens,
-            jnp.zeros(tokens.shape) if reset is None else reset)
-    return (logits, values, kept["routing"]["experts"][-1]), state, kept
-
-
-def decode_routed(model, variables, tokens, reset=None, jit=True,
-                  between=None):
-    """Every position one token at a time from empty state:
-    ((logits, values, experts), the last state, the counters a step).
-    `between` alters the state after every step."""
-    def step(token, state, reset):
-        return model.apply(variables, token, state, reset, method="decode",
-                           mutable=["routing", "counters"])
-    if jit:
-        step = jax.jit(step)
-    if reset is None:
-        reset = jnp.zeros(tokens.shape)
-    state = model.initial_state(tokens.shape[0])
-    logits, values, experts, counted = [], [], [], []
-    for t in range(tokens.shape[1]):
-        (step_l, step_v, state), kept = step(
-            tokens[:, t], state, reset[:, t])
-        if between is not None:
-            state = between(state)
-        logits.append(step_l)
-        values.append(step_v)
-        experts.append(kept["routing"]["experts"][-1])
-        counted.append({k: float(v[-1])
-                        for k, v in kept["counters"].items()})
-    return (jnp.stack(logits, 1), jnp.stack(values, 1),
-            jnp.stack(experts, 2)), state, counted
-
-
-def state_shapes(state):
-    return tuple([c.shape[1:] for c in jax.tree.leaves(state[key])]
-                 for key in ("kv", "conv", "gdn"))
-
-
-STATE_SHAPES = ([CACHE] * 2, [TAILS] * 3, [MATRIX] * 3)
-
-
-# -- the model against the reference -----------------------------------
-@pytest.mark.parametrize("tokens", [S, S - 3])
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_causal_pass_matches_reference(dtype, tokens):
-    """A fragment of whole chunks and one that ends inside a chunk.
-    float32 blocks: to float32 accuracy (1e-5: what the sums' two orders
-    leave; bfloat16 anywhere float32 is stated reads 1e-2), the same
-    experts in every layer. bfloat16 blocks: as near as the reference
-    rounded where they round."""
-    net = dict(NET, max_position_embeddings=tokens)
-    model, variables, tokens = build(dtype, net, tokens=tokens)
-    system, state, _ = causal_routed(model, variables, tokens)
-    assert system[2].shape == (4, B, tokens.shape[1], 3)  # every layer
-    if dtype == "f32":
-        held = plain(variables, tokens, net, system[2])
-        assert np.array_equal(np.sort(system[2], -1),
-                              np.sort(held["experts"], -1))
-        for got, want in zip(system[:2], (held["logits"], held["values"])):
-            assert reference.relative_error(got, want) < 1e-5
-        # The matrix states the scan hands over are the recurrence's.
-        for got, want in zip(jax.tree.leaves(state["gdn"]),
-                             held["gdn_states"]):
-            assert reference.relative_error(got, want) < 1e-5
-    else:
-        within_bfloat16(system, variables, tokens, net)
-    # What the pass hands a decode: the one layer's two caches, three
-    # layers' convolution inputs, three layers' matrices, a key a kind.
-    cache = (tokens.shape[1], CACHE[1])
-    assert state_shapes(state) == ([cache] * 2, [TAILS] * 3, [MATRIX] * 3)
-    assert [len(kv) for kv in state["kv"]] == [0, 0, 0, 2]
-    assert set(state) == {"kv", "conv", "gdn", "pos"}
-    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(state["gdn"]))
-    assert np.all(np.asarray(state["pos"]) == tokens.shape[1])
-
-
-@pytest.mark.parametrize("dtype", ["f32", "bf16"])
-def test_decode_through_three_kinds_of_state_matches_reference(dtype):
-    """Against the reference, which has neither cache nor state, on logits
-    and value; and, float32, against the causal pass and the state it
-    returns."""
-    model, variables, tokens = build(dtype)
-    system, state, counted = decode_routed(model, variables, tokens,
-                                           jit=dtype == "f32")
-    outputs, routing = judged(system, variables, tokens)
-    if dtype == "f32":
-        assert routing["router_flips"] == 0.0
-        assert max(outputs["errors"].values()) < 1e-5, outputs
-        causal, handed, _ = causal_routed(model, variables, tokens)
-        assert reference.relative_error(system[0], causal[0]) < 1e-5
-        assert reference.relative_error(system[1], causal[1]) < 1e-5
-        assert np.array_equal(system[2], causal[2])
-        for got, want in zip(jax.tree.leaves(state),
-                             jax.tree.leaves(handed)):
-            np.testing.assert_allclose(got, want, atol=2e-5)
-    else:
-        within_bfloat16(system, variables, tokens)
-    assert state_shapes(state) == STATE_SHAPES
-    # The matrix state is float32 whatever the blocks compute in; the
-    # convolution's inputs and the caches are the blocks'.
-    blocks = jnp.float32 if dtype == "f32" else jnp.bfloat16
-    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(state["gdn"]))
-    assert all(a.dtype == blocks for a in jax.tree.leaves(
-        (state["conv"], state["kv"])))
-    # The attention layer alone reads a cache: off a TPU, all of it.
-    assert counted[-1] == {"decode_cache_read_share": 1.0}
-
-
-def test_resets_inside_a_chunk_at_its_edge_and_an_episode_one_token_long():
-    """Four episodes in a fragment, the second one token long, the last
-    beginning with a chunk: what separate passes give, in both forms and
-    in the reference; nothing crosses a boundary (the matrix, the
-    convolution's inputs, the attention)."""
-    model, variables, tokens = build("f32")
-    together, state, _ = causal_routed(model, variables, tokens, RESET)
-    stepped, stepped_state, _ = decode_routed(
-        model, variables, tokens, RESET)
-    outputs, routing = judged(together, variables, tokens, starts=RESET)
-    assert routing["router_flips"] == 0.0
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    for first, last in EPISODES:
-        if last - first > 1:
-            alone, _, _ = causal_routed(
-                model, variables, tokens[:, first:last])
-        else:
-            # A causal pass takes two tokens or more: the lone token as a
-            # decode step from empty state.
-            alone = model.apply(variables, tokens[:, first:last],
-                                model.initial_state(B), jnp.ones((B, 1)))
-        for got in (together, stepped):
-            assert reference.relative_error(
-                got[0][:, first:last], alone[0]) < 1e-5, (first, last)
-            assert reference.relative_error(
-                got[1][:, first:last], alone[1]) < 1e-5, (first, last)
-    # Both forms end in the last episode's state.
-    for kind in ("gdn", "conv"):
-        for got, want in zip(jax.tree.leaves(state[kind]),
-                             jax.tree.leaves(stepped_state[kind])):
-            np.testing.assert_allclose(got, want, atol=2e-5)
-    assert np.all(np.asarray(state["pos"]) == S - 16)
-
-
-def test_a_decode_continues_a_causal_pass_from_the_state_it_hands_over():
-    """Prefixes shorter than the taps, at a chunk's edge, inside a chunk:
-    the pass's state is the matrix after its last position, the
-    convolution's last three inputs (zeros where the episode is shorter)
-    and the cache's rows, and the decode goes on from it."""
-    model, variables, tokens = build("f32")
-    decode = jax.jit(lambda token, state, reset: model.apply(
-        variables, token, state, reset))
-    (full, _, _), _, _ = causal_routed(model, variables, tokens)
-    for prefix in (2, 8, 13):
-        _, state, _ = causal_routed(model, variables, tokens[:, :prefix])
-        for t in range(prefix, S):
-            step, _, state = decode(tokens[:, t:t + 1], state,
-                                    jnp.zeros((B, 1)))
-            assert reference.relative_error(
-                step[:, 0], full[:, t]) < 1e-5, (prefix, t)
-
-
-def scalar_of(logits, values):
-    weight = jax.random.normal(jax.random.PRNGKey(7), logits.shape)
-    return jnp.sum(logits * weight) + jnp.sum(jnp.sin(values))
-
-
-@pytest.mark.parametrize("reset", [None, RESET], ids=["whole", "resets"])
-def test_the_model_s_gradient_is_the_reference_s(reset):
-    """Every parameter of the four blocks, through three scans over chunks
-    (their solves, their `lax.map`s and `lax.scan`s, their recomputed
-    bodies) and the gated attention, against `jax.grad` through the
-    reference's literal recurrence: what float32 leaves after four blocks'
-    worth of sums in two orders."""
-    model, variables, tokens = build("f32")
-
-    def system(params):
-        logits, values, _ = model.apply(
-            dict(variables, params=params), tokens, None,
-            jnp.zeros(tokens.shape) if reset is None else reset)
-        return scalar_of(logits, values)
-
-    def recurrence(params):
-        out = reference.forward(dict(variables, params=params), tokens, NET,
-                                starts=reset)
-        return scalar_of(out["logits"], out["values"])
-    got = jax.jit(jax.grad(system))(variables["params"])
-    want = jax.jit(jax.grad(recurrence))(variables["params"])
+def test_a_layer_s_parameters_are_the_delta_rule_s_or_the_gated_attention_s():
+    """What the model's gradient is taken with respect to."""
+    _, variables, _ = build(FAMILY, "f32")
     assert {"gdn_qkvz", "gdn_ba", "gdn_conv", "gdn_a_log", "gdn_dt_bias",
-            "gdn_o_norm", "gdn_out"} < set(want["layer_0"])
-    assert {"wq", "q_norm", "shared_scale"} < set(want["layer_3"])
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree.leaves(want)):
-        assert np.isfinite(a).all()
-        assert reference.relative_error(a, b) < 5e-5, path
+            "gdn_o_norm", "gdn_out"} < set(variables["params"]["layer_0"])
+    assert {"wq", "q_norm", "shared_scale"} < set(
+        variables["params"]["layer_3"])
+    assert set(variables) == {"params"}
 
 
 # -- the delta rule under one decay a head -----------------------------
@@ -393,18 +293,6 @@ def test_the_chunked_scan_is_the_literal_recurrence(chunk):
     assert got.shape == want.shape and state.shape == want_state.shape
     assert reference.relative_error(got, want) < 1e-5
     assert reference.relative_error(state, want_state) < 1e-5
-
-
-def read_by(run, operands):
-    """(outputs, final state, gradients by q, k, v, g, beta) of a scalar
-    that reads every output and every entry of the final state."""
-    def scalar(*operands):
-        o, S = run(*operands)
-        return (jnp.sum(jnp.sin(o) * jnp.arange(1, o.shape[1] + 1)[
-            None, :, None, None]) + jnp.sum(jnp.cos(S))), (o, S)
-    grads, (o, S) = jax.jit(jax.grad(
-        scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
-    return (o, S) + grads
 
 
 @pytest.fixture(scope="module")
@@ -503,71 +391,6 @@ def test_the_scalar_chunk_forms_no_decay_a_channel():
     assert max(int(np.prod(shape)) for shape in wide) > rows * 4 * 8 * 8
 
 
-# -- the limits refuse what is wrong -------------------------------------
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The comparison fails each named error (the partial rotation's two,
-    the two gates', the norms' centring, the delta rule's) and blocks
-    computed a precision lower: the reference, so altered, in the system's
-    place against itself, by its outputs or by its routing. The fragment
-    holds resets, so that a convolution that reaches across one shows."""
-    _, variables, tokens = build("f32")
-    # The attention layer's softmax far enough from uniform (its queries
-    # and keys are normalised a head, so it is their norms' weights that
-    # sharpen it: 1 + w about 4), and its output large enough beside the
-    # three layers before it, that its rotation and its gate show in the
-    # logits; the decays slow enough that a state outlives a few positions.
-    params = dict(variables["params"])
-    params["layer_3"] = dict(params["layer_3"],
-                             q_norm=params["layer_3"]["q_norm"] + 3.0,
-                             k_norm=params["layer_3"]["k_norm"] + 3.0,
-                             wo=6.0 * params["layer_3"]["wo"])
-    for name in GDN_LAYERS:
-        params[name] = dict(
-            params[name], gdn_a_log=params[name]["gdn_a_log"] - 3.0,
-            gdn_out=4.0 * params[name]["gdn_out"])
-    variables = dict(variables, params=params)
-    if wrong == "float8_e4m3":
-        got = plain(variables, tokens, starts=RESET, round_to=wrong)
-    else:
-        got = plain(variables, tokens, starts=RESET, mutate=wrong)
-    outputs, routing = judged(
-        (got["logits"], got["values"], got["experts"]), variables, tokens,
-        starts=RESET)
-    assert not (outputs["ok"] and routing["ok"]), (wrong, outputs, routing)
-
-
-def test_a_bfloat16_matrix_state_is_refused_by_the_decode_s_limit():
-    """The state is summed into at every step, so keeping it in bfloat16
-    (rounded after every step; everything else float32) is no rounding of
-    a block's output: its error is carried on and added to. Over a few
-    hundred steps the logits leave the reference by more than the cell's
-    limit, where the float32 state's stay at 1e-5."""
-    steps = 384
-    net = dict(NET, max_position_embeddings=steps)
-    model, variables, tokens = build("f32", net, tokens=steps)
-    params = dict(variables["params"])
-    for name in GDN_LAYERS:  # decays slow enough that the sums are long
-        params[name] = dict(
-            params[name], gdn_a_log=params[name]["gdn_a_log"] - 5.0,
-            gdn_out=3.0 * params[name]["gdn_out"])
-    variables = dict(variables, params=params)
-
-    def rounded(state):
-        return dict(state, gdn=jax.tree.map(
-            lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
-            state["gdn"]))
-    kept, _, _ = decode_routed(model, variables, tokens)
-    lost, _, _ = decode_routed(model, variables, tokens, between=rounded)
-    outputs, _ = judged(kept, variables, tokens, net)
-    assert max(outputs["errors"].values()) < 1e-5, outputs
-    held = plain(variables, tokens, net, kept[2])
-    wrong = reference.compare(lost[:2], (held["logits"], held["values"]))
-    assert max(wrong["errors"].values()) > 100 * max(
-        outputs["errors"].values()), wrong
-    assert max(wrong["errors"].values()) > 2e-3, wrong
-
-
 # -- the expert layer that holds a share ---------------------------------
 def test_the_16_shares_add_up_to_the_uncut_layer():
     """Sixteen shares of 4 of 64 experts: their parts, with the gated
@@ -589,14 +412,11 @@ def test_the_16_shares_add_up_to_the_uncut_layer():
     m = transformer.rms_norm(h, jnp.ones(H), 1e-6, jnp.float32)
     net = dict(NET, num_experts=E, num_experts_per_tok=k)
 
-    def share_of(first, size):
-        return dict(lp, **{w: lp[w][first:first + size]
-                           for w in ("w_gate", "w_up", "w_down")})
-
+    @functools.partial(jax.jit, static_argnums=(1,))
     def layer(first, size):
         share = dict(net, experts_held=size, first_expert_held=first)
         with jax.default_matmul_precision("highest"):
-            return reference._moe(share_of(first, size), h, m, share,
+            return reference._moe(share_of(lp, first, size), h, m, share,
                                   lambda a: a, None, None)
     whole, chosen, _ = layer(0, E)
     with jax.default_matmul_precision("highest"):
@@ -619,7 +439,7 @@ def test_the_16_shares_add_up_to_the_uncut_layer():
         n, p, i = (jnp.tile(a, (reps, 1)) for a in (rows, top_p, top_i))
         routed, landed = jnp.zeros_like(n), 0
         for first in range(0, E, held):
-            s = share_of(first, held)
+            s = share_of(lp, first, held)
             part, sizes, _ = dropless_experts(
                 n, p, i, s["w_gate"], s["w_up"], s["w_down"], first, E)
             routed, landed = routed + part, landed + int(jnp.sum(sizes))
@@ -630,55 +450,13 @@ def test_the_16_shares_add_up_to_the_uncut_layer():
     assert transformer.experts_batched(rows.shape[0], k, E)
     assert not transformer.experts_batched(64 * rows.shape[0], k, E)
 
-
-def test_a_causal_pass_over_the_landed_rows_is_the_batched_pass(
-        grouped_pass_is_the_batched_pass):
-    grouped_pass_is_the_batched_pass(*build("f32"))
-
-
 # -- what the program is, from its static shapes --------------------------
-def published_cut():
-    with open(os.path.join(
-            BENCH, "configs", "impala_qwen3_next_80b_a3b.json")) as f:
-        network = json.load(f)["network"]
-    return {k: v for k, v in network.items() if k != "param_count"}
-
-
-def shapes_of(model):
-    return jax.eval_shape(
-        model.init, jax.random.PRNGKey(0),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.eval_shape(lambda: model.initial_state(1)),
-        jax.ShapeDtypeStruct((1, 1), jnp.float32))
-
-
-def count(tree):
-    return sum(int(np.prod(v.shape)) for v in jax.tree.leaves(tree))
-
-
-def test_the_cell_s_program_is_known_from_its_static_shapes():
-    """At the published widths: 625.7 M parameters; ONE layer's caches of
-    4,096 positions, 2,048 bytes a position; three layers' convolution
-    inputs, 147,456 bytes a sequence, and three layers' matrices,
-    6,291,456, whatever its length; heads of 256 in groups of 8 take the
-    decode kernel and the fused causal form; the 32 held experts of 512
-    take the grouped-matmul kernels; nothing but shapes is built."""
-    net = published_cut()
-    model = catalog.get_model(None, net["vocab_size"], {
-        "custom_model": "qwen3_next", "custom_model_config": net})
-    assert model.static_counters(32, 4096, "tpu", 8192) == {
-        # Under two rows a held expert: a rollout's step reads the chosen
-        # ones' matrices alone, and counts their share itself.
-        "decode_rows_per_expert": 0.625, "decode_experts_batched": 0.0,
-        "decode_experts_sparse": 1.0,
-        "decode_cache_block": 128, "decode_attention_kernel": 1.0,
-        "causal_attention_fused": 1.0, "experts_grouped_kernel": 1.0,
-        # The one gated layer: a quarter of a head of 256 rotated.
-        "rotation_fused_layers": 1.0,
-        "kv_cache_bytes_per_token": 2048.0, "kv_groups": 8,
-        "conv_layers": 3, "conv_state_bytes_per_row": 147456,
-        "gdn_layers": 3, "gdn_state_bytes_per_row": 6291456,
-        "gdn_chunk": 64, "state_step_kernel": 1.0}
+def test_the_published_cut_s_parameters_are_the_hand_count_s():
+    """A layer is its Gated DeltaNet or its gated attention, and its
+    experts; the learner's ladder and the grouped-matmul's tiles."""
+    network = configuration(FAMILY)[3]
+    model = transformer.qwen3_next_from_config(
+        network["vocab_size"], network)
     assert transformer.causal_fused(4096, 256, 256)
     assert transformer.grouped_fused(4096, 2, 16, 256)
     # The learner's ladder: 81,920 pairs, 5,120 expected here.
@@ -686,42 +464,21 @@ def test_the_cell_s_program_is_known_from_its_static_shapes():
         6400, 10240, 20480, 81920)
     assert transformer.grouped_tiles(6400, 2048, 512) == (
         (256, 2048, 512), (256, 512, 1024), (256, 1024, 512))
-    # Off a TPU the cache is read whole, by XLA's products.
-    off = model.static_counters(32, 4096, "cpu", 8192)
-    assert (off["causal_attention_fused"], off["decode_cache_block"],
-            off["decode_attention_kernel"], off["state_step_kernel"],
-            off["experts_grouped_kernel"]) == (0.0, 4096, 0.0, 0.0, 0.0)
-    state = jax.eval_shape(lambda: model.initial_state(32))
-    assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["kv"])] == [
-        ((32, 4096, 512), jnp.bfloat16)] * 2
-    assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["conv"])] == [
-        ((32, 3, 8192), jnp.bfloat16)] * 3
-    assert [(c.shape, c.dtype) for c in jax.tree.leaves(state["gdn"])] == [
-        ((32, 32, 128, 128), jnp.float32)] * 3
     variables = shapes_of(model)
-    assert set(variables) == {"params"}  # no constants: a softmax router
     attention = variables["params"]["layer_3"]
     assert attention["wq"].shape == (2048, 16 * 512)
     assert attention["q_norm"].shape == attention["k_norm"].shape == (256,)
-    gdn = (2048 * 12288 + 2048 * 64 + 8192 * 4 + 32 + 32 + 128
-           + 4096 * 2048)
-    assert gdn == 33_718_464
-    gated = 2048 * 8192 + 2 * 2048 * 512 + 4096 * 2048 + 2 * 256
-    assert gated == 27_263_488
-    experts = 2048 * 512 + 32 * 3 * 2048 * 512 + 3 * 2048 * 512 + 2048
-    assert experts == 104_859_648
-    assert count(variables["params"]["layer_0"]) == gdn + experts + 4096
-    assert count(attention) == gated + experts + 4096
-    assert count(variables["params"]) == (
-        2 * 18992 * 2048 + 3 * gdn + gated + 4 * (experts + 4096) + 2048
-        + 2048 + 1) == 625_669_185
+    assert (GDN, GATED, EXPERTS) == (33_718_464, 27_263_488, 104_859_648)
+    assert count(variables["params"]["layer_0"]) == GDN + EXPERTS + 4096
+    assert count(attention) == GATED + EXPERTS + 4096
+    assert count(variables["params"]) == PARAMETERS == 625_669_185
 
 
 def test_the_counters_count_each_kind_of_state_from_its_own_layers():
     """The matrix states stand under their own key and are counted from
     their own leaves; the accepted delta-rule configuration reads what it
     read, and has no such key."""
-    model, _, _ = build("bf16")
+    model, _, _ = build(FAMILY, "bf16")
     counted = model.static_counters(4, S, "cpu")
     assert counted["kv_cache_bytes_per_token"] == 2 * 2 * 16 * 2
     assert (counted["conv_layers"], counted["conv_state_bytes_per_row"]) == (
@@ -740,31 +497,6 @@ def test_the_counters_count_each_kind_of_state_from_its_own_layers():
     assert accepted["kda_state_bytes_per_row"] == 8388608
     assert accepted["state_step_kernel"] == 1.0
     assert "gdn" not in jax.eval_shape(lambda: other.initial_state(1))
-
-
-@pytest.mark.parametrize("cfg,match", [
-    ({"n_routed_experts": 8}, "not qwen3_next's"),
-    ({"layer_types": ["linear_attention"]}, "not qwen3_next's"),
-    ({"num_nextn_predict_layers": 1}, "not qwen3_next's"),
-    ({"mlp_only_layers": [0]}, "mlp_only_layers"),
-    ({"decoder_sparse_step": 2}, "decoder_sparse_step"),
-    ({"use_sliding_window": True}, "use_sliding_window"),
-    ({"attention_bias": True}, "attention_bias"),
-    ({"hidden_act": "gelu"}, "hidden_act"),
-    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
-    ({"rope_scaling": {"type": "yarn"}}, "rope_scaling"),
-    ({"full_attention_interval": 0}, "full_attention_interval"),
-    ({"linear_num_value_heads": 3}, "value heads"),
-    ({"num_key_value_heads": 3}, "key/value heads"),
-    ({"experts_held": 6, "first_expert_held": 12}, "not among"),
-])
-def test_custom_model_config_without_a_part_is_refused(cfg, match):
-    with pytest.raises(ValueError, match=match):
-        model = catalog.get_model(None, 96, {
-            "custom_model": "qwen3_next",
-            "custom_model_config": dict(NET, **cfg)})
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                   model.initial_state(1), jnp.zeros((1, 1)))
 
 
 def test_keys_left_out_have_the_published_model_s_values():
@@ -793,34 +525,15 @@ def test_keys_left_out_have_the_published_model_s_values():
     assert 2.9e9 < per_token < 4.0e9
 
 
-def test_the_tuned_example_is_the_benchmark_s_cell():
-    """`rllib train -f qwen3-next-token-impala.yaml` and the cell
-    `qwen3_next_token_anakin_4k` are one trainer config, the cell's traffic
-    is the fifth cell's letter for letter (the two delta-rule models stand
-    under one load), and the configuration's file holds every published
-    number of its source but the ones it lists as reduced."""
-    import yaml
-    root = os.path.dirname(BENCH)
-    with open(os.path.join(root, "ray_tpu", "rllib", "tuned_examples",
-                           "qwen3-next-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(
-            BENCH, "workloads", "qwen3_next_token_anakin_4k.json")) as f:
-        cell = json.load(f)
+def test_the_cell_s_traffic_is_its_sibling_s_and_its_file_its_source_s():
+    """The cell's traffic is the fifth cell's letter for letter (the two
+    delta-rule models stand under one load), and the configuration's file
+    holds every published number of its source (the catalog's row) but the
+    ones it lists as reduced."""
+    _, cell, config, network = configuration(FAMILY)
     with open(os.path.join(
             BENCH, "workloads", "kimi_linear_token_anakin_4k.json")) as f:
         sibling = json.load(f)
-    with open(os.path.join(
-            BENCH, "configs", "impala_qwen3_next_80b_a3b.json")) as f:
-        config = json.load(f)
-    network = {k: v for k, v in config["network"].items()
-               if k != "param_count"}
-    want = dict(cell["trainer_config"], **config["trainer_config"])
-    want["model"] = dict(want["model"], custom_model_config=network)
-    want["num_tpus_for_learner"] = cell["chips"]
-    assert example["run"] == config["trainer"]
-    assert example["env"] == want.pop("env")
-    assert example["config"] == want
     traffic = dict(sibling["trainer_config"], env_config=dict(
         sibling["trainer_config"]["env_config"], vocab_size=18992))
     assert cell["trainer_config"] == traffic
@@ -845,6 +558,6 @@ def test_the_tuned_example_is_the_benchmark_s_cell():
     assert config["reduced"] == list(reduced) + ["env"]
     assert set(config["reduced"]) == set(config["reduced_why"])
     assert "next_token_module" in config["assumed"]
-    assert config["network"]["param_count"] == 625_669_185
+    assert config["network"]["param_count"] == PARAMETERS
     model = transformer.qwen3_next_from_config(18992, network)
     assert model.layer_types == ("gdn", "gdn", "gdn", "full_attention")

@@ -117,7 +117,7 @@ class TestHorizonPlumbing:
 
 
 class TestAdvisoryFixes:
-    """Round-2 advisor findings (ADVICE.md r2)."""
+    """Round-2 advisor findings."""
 
     def test_mapping_fn_registry_resolves_and_rejects(self):
         from ray_tpu.rllib.utils.registry import (
@@ -201,7 +201,7 @@ class TestAdvisoryFixes:
 
 class TestBenchMedianWindows:
     def test_even_window_count_uses_median_low(self):
-        """ADVICE r5: statistics.median of an even count averages the
+        """Advisor round 5: statistics.median of an even count averages the
         middle two — a rate belonging to NO window, so the extra lookup
         crashed. median_low always names a real window."""
         import bench

@@ -65,13 +65,15 @@ def engage(patch, rows=ROWS):
     return calls
 
 
-def both(monkeypatch, fn, *args, rows=ROWS):
+def both(monkeypatch, fn, *args, rows=ROWS, compiled=False):
     """(value, aux, gradients in every argument) of `fn` as the program
     lowers off a TPU, the same through the kernels, and the kernels' calls
-    the second took."""
+    the second took. `compiled`: each as one program (a whole model's ops
+    one by one take minutes), traced anew under what `engage` patches."""
     def run():
-        (value, aux), grads = jax.value_and_grad(
-            fn, argnums=tuple(range(len(args))), has_aux=True)(*args)
+        grad = jax.value_and_grad(
+            fn, argnums=tuple(range(len(args))), has_aux=True)
+        (value, aux), grads = (jax.jit(grad) if compiled else grad)(*args)
         return value, aux, grads
     plain = run()
     with monkeypatch.context() as patch:
@@ -255,13 +257,15 @@ def test_the_gate_is_gated_s_values_and_gradients(heads, d, a_head, dtype,
 
 # -- whole models -------------------------------------------------------------
 def laguna():
-    from test_laguna import build
-    return build("f32")
+    from test_laguna import FAMILY
+    from token_families import build
+    return build(FAMILY, "f32")
 
 
 def qwen3_next():
-    from test_qwen3_next_policy import build
-    return build("f32")
+    from test_qwen3_next_policy import FAMILY
+    from token_families import build
+    return build(FAMILY, "f32")
 
 
 @pytest.mark.parametrize("build, rotations, gates", [
@@ -284,7 +288,7 @@ def test_a_causal_pass_through_the_kernels_is_the_causal_pass(
             jnp.zeros(tokens.shape))
         return jnp.sum(jnp.sin(logits)) + jnp.sum(values), (logits, values)
     (_, want, (d_want,)), (_, got, (d_got,)), calls = both(
-        monkeypatch, loss, variables["params"], rows=8)
+        monkeypatch, loss, variables["params"], rows=8, compiled=True)
     # Traced for every layer's forward pass at the least (a pullback is
     # traced once a shape).
     assert calls["rotate"] > rotations and calls["gate"] > gates

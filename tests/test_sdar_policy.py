@@ -1,44 +1,41 @@
 """The `sdar_moe` token policy, which GENERATES BY DIFFUSION OVER BLOCKS, at a
-tiny size on the CPU: the rollout's block step through the cache (S
-denoising passes and a commit pass a block) against the plain reference
-(`benchmark/lib/reference_sdar_moe.py`) on the trace it sampled, for S in
-{1, 2, 4} at a block of 4: log-probabilities, values, and the order the
-positions were unmasked in; the learner's pass over the same trace against
-the reference, and its log-probabilities equal to the rollout's at unchanged
-parameters; the learner's last layer, whose clean stream stops at its keys
-and values, against the whole form written out here (logits, values, every
-parameter's gradient, the routing collection, the tiles the fused mask
-visits); the reference's 2T form against the block-by-block definition;
-a block of one position and one pass against a plain masked forward; the
-eight shares of an expert layer against the uncut layer; the block-level
-V-trace against a hand-rolled one and the loss and its gradient against the
-reference's; a given row weighs nothing; each named wrong mathematics
-refused by the cell's limits; what the builder and the optimizer refuse;
-the trainer on the fused Anakin path from the tuned example.
+tiny size on the CPU: the family's row, the checks it shares with the other
+families (`tests/token_families.py`: each named wrong mathematics refused by
+the cell's limits, the cell's program from its shapes, the builder's
+refusals, the tuned example) and what is its own: the rollout's block step
+through the cache (S denoising passes and a commit pass a block) against the
+plain reference (`benchmark/lib/reference_sdar_moe.py`) on the trace it
+sampled, for S in {1, 2, 4} at a block of 4: log-probabilities, values, and
+the order the positions were unmasked in; the learner's pass over the same
+trace against the reference, and its log-probabilities equal to the rollout's
+at unchanged parameters; the learner's last layer, whose clean stream stops
+at its keys and values, against the whole form written out here (logits,
+values, every parameter's gradient, the routing collection, the tiles the
+fused mask visits); the reference's 2T form against the block-by-block
+definition; a block of one position and one pass against a plain masked
+forward; the eight shares of an expert layer against the uncut layer; the
+block-level V-trace against a hand-rolled one. The loss and the loop:
+`tests/test_sdar_update.py`.
 """
 
 import functools
-import json
-import os
-import sys
 import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-import yaml
+from token_families import (  # noqa: F401: pytest collects what is named
+    Family, build, plain, test_custom_model_config_without_a_part_is_refused,
+    test_limits_refuse_wrong_mathematics,
+    test_the_cell_s_program_is_known_from_its_static_shapes,
+    test_the_tuned_example_is_the_benchmark_s_cell)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+from lib import reference_sdar_moe as reference
 
-from lib import reference_sdar_moe as reference  # noqa: E402
-
-from ray_tpu.models import catalog, transformer  # noqa: E402
-from ray_tpu.rllib import sample_batch as sb  # noqa: E402
-from ray_tpu.rllib.agents.impala import vtrace, vtrace_policy  # noqa: E402
+from ray_tpu.models import transformer
+from ray_tpu.rllib import sample_batch as sb
+from ray_tpu.rllib.agents.impala import vtrace, vtrace_policy
 
 # Two layers; 4 query heads over 2 cached ones of 16; 4 of 8 experts of 32
 # held, 2 a token; an episode of 24 positions, the first given.
@@ -55,36 +52,29 @@ CFG = {"gamma": 0.99, "lambda": 1.0, "vf_loss_coeff": 0.5,
        "vtrace_clip_pg_rho_threshold": 1.0}
 
 
-def build(dtype="f32", **changed):
-    """(model, seeded variables, net). The norms' weights are seeded too
-    (one at initialisation), so that a norm's place shows."""
-    net = dict(NET, **changed)
-    model = catalog.get_model(None, net["vocab_size"] - 1, {
-        "custom_model": "sdar_moe", "custom_model_config": net,
-        "compute_dtype": dtype})
-    variables = model.init(
-        jax.random.PRNGKey(0), jnp.zeros((N, 1), jnp.int32),
-        model.initial_state(N), jnp.zeros((N, 1)))
-
-    def seeded(path, a):
-        if not path[-1].key.endswith("norm"):
-            return a
-        key = jax.random.fold_in(jax.random.PRNGKey(2), a.size + len(path))
-        return a * (1.0 + 0.5 * jax.random.normal(key, a.shape))
-    return model, dict(variables, params=jax.tree_util.tree_map_with_path(
-        seeded, variables["params"])), net
+def seeded(path, a):
+    """The norms' weights are seeded too (one at initialisation), so that
+    a norm's place shows."""
+    if not path[-1].key.endswith("norm"):
+        return a
+    key = jax.random.fold_in(jax.random.PRNGKey(2), a.size + len(path))
+    return a * (1.0 + 0.5 * jax.random.normal(key, a.shape))
 
 
-def rollout(model, variables, net, seed=5, positions=T):
-    """One episode a row by the model's own block steps from an empty
-    cache: the trace (tokens, logp, steps [N, T], values [N, T / L]) and the
-    experts each pass chose, in the learner's layout [layers, N, (S + 1) T,
-    k] (the commit passes' first, whose last layer chooses none: -1)."""
-    L, S = net["block_length"], net["denoise_steps"]
+def sdar(dtype="f32", **changed):
+    """(model, seeded variables, net) of the tiny model, its description
+    `changed`."""
+    built = build(FAMILY, dtype, dict(NET, **changed))
+    return built.model, built.variables, built.net
+
+
+@functools.lru_cache(maxsize=None)
+def rollout_of(model, seed, positions):
+    """The rollout of one episode a row, compiled once a model."""
     first = jax.random.randint(jax.random.PRNGKey(seed), (N,), 0,
-                               net["vocab_size"] - 1)
+                               model.mask_id)
 
-    def step(carry, key):
+    def step(variables, carry, key):
         state, reset = carry
         (tokens, logp, steps, value, state), kept = model.apply(
             variables, first, state, reset, key, method="block_step",
@@ -95,11 +85,21 @@ def rollout(model, variables, net, seed=5, positions=T):
         return (state, jnp.zeros_like(reset)), (
             tokens, logp, steps, value, commit,
             kept["routing"]["experts"][-1])
-    _, (tokens, logp, steps, values, commit, noisy) = jax.jit(
-        lambda keys: jax.lax.scan(
-            step, (model.initial_state(N), jnp.ones(N)), keys))(
-                jax.random.split(jax.random.PRNGKey(seed + 1),
-                                 positions // L))
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1),
+                            positions // model.block_len)
+    return jax.jit(lambda variables: jax.lax.scan(
+        functools.partial(step, variables),
+        (model.initial_state(N), jnp.ones(N)), keys)[1])
+
+
+def rollout(model, variables, net, seed=5, positions=T):
+    """One episode a row by the model's own block steps from an empty
+    cache: the trace (tokens, logp, steps [N, T], values [N, T / L]) and the
+    experts each pass chose, in the learner's layout [layers, N, (S + 1) T,
+    k] (the commit passes' first, whose last layer chooses none: -1)."""
+    S = net["denoise_steps"]
+    tokens, logp, steps, values, commit, noisy = rollout_of(
+        model, seed, positions)(variables)
 
     def rows(x):
         """[blocks, N, L, ..] -> [N, T, ..]."""
@@ -113,10 +113,49 @@ def rollout(model, variables, net, seed=5, positions=T):
             "steps": rows(steps), "values": values.T, "experts": experts}
 
 
-def plain(variables, trace, net, experts=None, **how):
-    return jax.jit(lambda v, t, s, e: reference.forward(
-        v, t, s, net, experts=e, **how))(
-            variables, trace["tokens"], trace["steps"], experts)
+FAMILY = Family(
+    name="sdar_moe", net=NET, reference=reference, B=N, S=T,
+    # The MASK id is the vocabulary's last: the policy has one output less.
+    outputs=lambda net: net["vocab_size"] - 1, seeded=seeded,
+    # The reference reads the trace the model's own rollout sampled.
+    inputs=lambda built: rollout(built.model, built.variables, built.net),
+    forward=lambda variables, trace, net, starts=None, **how:
+        reference.forward(variables, trace["tokens"], trace["steps"], net,
+                          **how),
+    refused=(
+        ({"num_shared_experts": 1}, 95, "not sdar_moe's"),
+        ({"use_sliding_window": True}, 95, "use_sliding_window"),
+        ({"tie_word_embeddings": True}, 95, "tie_word_embeddings"),
+        ({}, 96, "MASK id"),
+        ({"block_length": 4, "denoise_steps": 3}, 95, "block"),
+        ({"block_length": 5}, 95, "block")),
+    example="sdar-token-impala.yaml", cell="sdar_block_token_anakin_2k",
+    config="impala_sdar_30b_a3b",
+    program=dict(
+        rows=64, fragment=2048, minibatch=(8192,),
+        on_tpu={
+            # 64 blocks of 4 rows, 8 of 128 experts each: 16 rows a held
+            # expert.
+            "decode_rows_per_expert": 16.0, "decode_experts_batched": 1.0,
+            "decode_experts_sparse": 0.0, "decode_experts_read_share": 1.0,
+            "decode_cache_block": 128, "decode_attention_kernel": 1.0,
+            "causal_attention_fused": 1.0, "rotation_fused_layers": 5.0,
+            "block_len": 4, "denoise_steps": 2,
+            "decode_passes_per_token": 0.75,
+            # Five layers of three streams, the last one's clean stream
+            # left out.
+            "learner_rows_per_token": 3 - 1 / 5,
+            "block_attention_kernel": 1.0,
+            "block_cache_writes_per_block": 1,
+            "experts_grouped_kernel": 1.0,
+            # 5 layers x (K and V) x 4 heads x 128 x 2 bytes.
+            "kv_cache_bytes_per_token": 5 * 2048, "kv_groups": 8},
+        off_tpu={
+            "decode_cache_block": 2048, "decode_attention_kernel": 0.0,
+            "causal_attention_fused": 0.0, "rotation_fused_layers": 0.0,
+            "block_attention_kernel": 0.0, "experts_grouped_kernel": 0.0},
+        state={"kv": [((64, 2048, 512), "bfloat16")] * 10},
+        parameters=550987009))
 
 
 def taken(logits, tokens):
@@ -128,7 +167,7 @@ def taken(logits, tokens):
 
 @pytest.mark.parametrize("passes", [1, 2, 4])
 def test_block_step_through_the_cache_matches_reference(passes):
-    model, variables, net = build(denoise_steps=passes)
+    model, variables, net = sdar(denoise_steps=passes)
     trace = rollout(model, variables, net)
     L = net["block_length"]
     steps = np.asarray(trace["steps"])
@@ -139,7 +178,7 @@ def test_block_step_through_the_cache_matches_reference(passes):
     for block in steps[:, L:].reshape(N, -1, L).reshape(-1, L):
         assert sorted(block) == sorted(
             s for s in range(passes) for _ in range(L // passes))
-    held = plain(variables, trace, net, trace["experts"])
+    held = plain(FAMILY, variables, trace, net, trace["experts"])
     generated = steps >= 0
     np.testing.assert_allclose(
         np.asarray(taken(held["logits"], trace["tokens"]))[generated],
@@ -161,10 +200,10 @@ def test_block_step_through_the_kernel_forms_matches_reference(
         kernel_here, passes):
     """The block entry's kernel, by the interpreter: the rollout is the
     reference's, at the limits of the plain form."""
-    model, variables, net = build(
+    model, variables, net = sdar(
         denoise_steps=passes, max_position_embeddings=T_KERNEL)
     trace = rollout(model, variables, net, positions=T_KERNEL)
-    held = plain(variables, trace, net, trace["experts"])
+    held = plain(FAMILY, variables, trace, net, trace["experts"])
     generated = np.asarray(trace["steps"]) >= 0
     np.testing.assert_allclose(
         np.asarray(taken(held["logits"], trace["tokens"]))[generated],
@@ -184,7 +223,7 @@ def test_only_a_commit_pass_writes_and_only_its_block_s_rows(
     nothing else. Both forms of the attention."""
     if form == "kernel":
         request.getfixturevalue("kernel_here")
-    model, variables, net = build(max_position_embeddings=T_KERNEL)
+    model, variables, net = sdar(max_position_embeddings=T_KERNEL)
     L = net["block_length"]
     pos = jnp.asarray([0, 12, T_KERNEL - L], jnp.int32)
     tokens = jax.random.randint(jax.random.PRNGKey(3), (N, L), 0,
@@ -213,14 +252,14 @@ def test_only_a_commit_pass_writes_and_only_its_block_s_rows(
 
 @pytest.mark.parametrize("platform,kernel", [("tpu", 1.0), ("cpu", 0.0)])
 def test_the_counters_say_which_form_a_block_step_takes(platform, kernel):
-    model, _, _ = build(max_position_embeddings=256, head_dim=128)
+    model, _, _ = sdar(max_position_embeddings=256, head_dim=128)
     got = model.static_counters(4, 256, platform)
     assert got["block_attention_kernel"] == kernel
     assert got["decode_attention_kernel"] == kernel
     assert got["decode_cache_block"] == (128 if kernel else 256)
     # The commit pass's alone, of denoise_steps + 1 passes.
     assert got["block_cache_writes_per_block"] == 1
-    narrow, _, _ = build(max_position_embeddings=256)
+    narrow, _, _ = sdar(max_position_embeddings=256)
     assert narrow.static_counters(4, 256, "tpu")[
         "block_attention_kernel"] == 0.0
 
@@ -230,7 +269,7 @@ def test_the_unmask_order_is_the_reference_s_top_probabilities(passes):
     """At pass s the positions unmasked are those of the still masked whose
     top probability, by the reference's own logits of that pass, is
     highest."""
-    model, variables, net = build(denoise_steps=passes)
+    model, variables, net = sdar(denoise_steps=passes)
     trace = rollout(model, variables, net)
     L, per = net["block_length"], net["block_length"] // passes
     steps = np.asarray(trace["steps"])
@@ -261,14 +300,14 @@ def test_the_unmask_order_is_the_reference_s_top_probabilities(passes):
 
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
 def test_learner_pass_matches_reference_and_the_rollout(dtype):
-    model, variables, net = build(dtype)
+    model, variables, net = sdar(dtype)
     trace = rollout(model, variables, net)
     (logits, values), kept = jax.jit(lambda v, t, s: model.apply(
         v, t, s, jnp.zeros(t.shape), method="block_causal",
         mutable=["routing", "counters"]))(
             variables, trace["tokens"], trace["steps"])
     experts = kept["routing"]["experts"][-1]
-    held = plain(variables, trace, net, experts)
+    held = plain(FAMILY, variables, trace, net, experts)
     out = reference.compare((logits[..., :-1], values),
                             (held["logits"], held["values"]))
     routing = reference.routing_verdict(experts, held["experts"],
@@ -338,7 +377,7 @@ def both_forms(layers, passes):
     """{form: (logits, values, routing [layers, N, (S + 1) T, k], the
     gradient of `vtrace_loss`)} of `block_causal` and of the whole form above
     on one seeded trace, whose second row holds two episodes."""
-    model, variables, net = build(
+    model, variables, net = sdar(
         num_hidden_layers=layers, denoise_steps=passes)
     L = net["block_length"]
     rng = np.random.default_rng(layers * 10 + passes)
@@ -477,43 +516,45 @@ def test_the_fused_mask_s_tiles_are_known_from_the_static_shape(
 
 @pytest.mark.parametrize("passes", [1, 2])
 def test_the_2t_form_is_the_block_by_block_definition(passes):
-    model, variables, net = build(denoise_steps=passes)
+    model, variables, net = sdar(denoise_steps=passes)
     trace = rollout(model, variables, net)
-    whole = plain(variables, trace, net)
-    by_blocks = reference.forward_by_blocks(
-        variables, trace["tokens"], trace["steps"], net)
+    whole = plain(FAMILY, variables, trace, net)
+    by_blocks = jax.jit(lambda v, t, s: reference.forward_by_blocks(
+        v, t, s, net))(variables, trace["tokens"], trace["steps"])
     np.testing.assert_allclose(whole["logits"], by_blocks["logits"],
                                atol=2e-5)
     np.testing.assert_allclose(whole["values"], by_blocks["values"],
                                atol=2e-5)
 
 
-# 24 eager forwards of the reference, each at a length of its own: 143 s
-# alone on the sandbox at PR 49 and at its parent, over the common limit
-# beside five busy workers.
-@pytest.mark.time_limit(420)
 def test_a_block_of_one_and_one_pass_is_a_plain_masked_forward():
     """L 1, S 1: a step yields one token a row; position i's distribution
-    comes from the MASK id at i reading the clean tokens before it."""
-    model, variables, net = build(block_length=1, denoise_steps=1)
+    comes from the MASK id at i reading the clean tokens before it (a
+    causal forward over the whole length, whose position i reads nothing
+    after it: one program for the 24 positions)."""
+    model, variables, net = sdar(block_length=1, denoise_steps=1)
     trace = rollout(model, variables, net)
     assert trace["tokens"].shape == (N, T)
     steps = np.asarray(trace["steps"])
     assert (steps[:, 0] == -1).all() and (steps[:, 1:] == 0).all()
-    logits, values = [], []
     p = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32),
                      variables["params"])
-    with jax.default_matmul_precision("highest"):
-        for i in range(T):
-            seen = trace["tokens"][:, :i + 1]
-            if i:
-                seen = seen.at[:, i].set(net["vocab_size"] - 1)
+
+    @jax.jit
+    def masked_forward(seen):
+        with jax.default_matmul_precision("highest"):
             x, _, _ = reference._hidden(
-                p, seen, jnp.arange(i + 1), lambda q, k: k <= q, net,
+                p, seen, jnp.arange(T), lambda q, k: k <= q, net,
                 lambda a: a, None, None)
-            out = reference._heads(p, x[:, -1], net)
-            logits.append(out[0])
-            values.append(out[1])
+            return reference._heads(p, x, net)
+    logits, values = [], []
+    for i in range(T):
+        seen = trace["tokens"]
+        if i:
+            seen = seen.at[:, i].set(net["vocab_size"] - 1)
+        out = masked_forward(seen)
+        logits.append(out[0][:, i])
+        values.append(out[1][:, i])
     logp = taken(jnp.stack(logits, 1), trace["tokens"])
     np.testing.assert_allclose(
         np.asarray(logp)[:, 1:], np.asarray(trace["logp"])[:, 1:], atol=2e-4)
@@ -568,231 +609,6 @@ def test_block_vtrace_is_the_library_s_over_blocks():
     np.testing.assert_allclose(pg.T, want.pg_advantages, atol=1e-5)
 
 
-@pytest.fixture(scope="module")
-def trained():
-    """The trainer from the tuned example at the cell's rehearsal sizes."""
-    from ray_tpu.rllib.agents.registry import get_trainer_class
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            here, "ray_tpu/rllib/tuned_examples/sdar-token-impala.yaml")) as f:
-        (example,) = yaml.safe_load(f).values()
-    with open(os.path.join(
-            BENCH, "workloads/sdar_block_token_anakin_2k.json")) as f:
-        workload = json.load(f)
-    from drivers.rllib_trainer import merge
-    config = merge(dict(example["config"], env=example["env"], seed=11),
-                   workload["rehearse_trainer_config"])
-    config.pop("num_tpus_for_learner")
-    trainer = get_trainer_class(example["run"])(config=config)
-    yield trainer, example, workload
-    trainer.stop()
-
-
-def test_the_trainer_runs_from_the_tuned_example(trained):
-    trainer, example, workload = trained
-    assert example["config"]["model"]["custom_model"] == "sdar_moe"
-    cfg = trainer.config
-    envs, T_, episode = (trainer.optimizer.num_envs,
-                         cfg["rollout_fragment_length"],
-                         cfg["env_config"]["episode_len"])
-    result = trainer.train()
-    stats = result["info"]["learner"]
-    # Steps are actions: an episode's positions less its given first.
-    assert result["timesteps_total"] == envs * T_ // (episode + 1) * episode
-    assert np.isfinite(stats["total_loss"])
-    assert stats["block_len"] == 4 and stats["denoise_steps"] == 2
-    assert stats["decode_passes_per_token"] == 0.75
-    # Two layers: the last one's clean stream stops at its keys and values.
-    assert stats["learner_rows_per_token"] == 3 - 1 / 2
-    minibatches = envs * T_ // cfg["sgd_minibatch_size"]
-    assert stats["given_rows"] * minibatches == envs * T_ // (episode + 1)
-    assert 0 < stats["unmask_top_prob_mean"] <= 1
-    assert result["episodes_total"] == envs * T_ // (episode + 1)
-    assert result["episode_len_mean"] == episode
-
-
-def test_the_tuned_example_is_the_benchmark_s_cell(trained):
-    _, example, workload = trained
-    with open(os.path.join(
-            BENCH, "configs", workload["config"] + ".json")) as f:
-        config = json.load(f)
-    published = {k: v for k, v in config["network"].items()
-                 if k != "param_count"}
-    model = example["config"]["model"]
-    assert model["custom_model_config"] == published
-    for key, value in workload["trainer_config"].items():
-        if key != "env":
-            assert example["config"][key] == value, key
-    assert example["env"] == workload["trainer_config"]["env"]
-    for key in ("lr", "grad_clip", "min_iter_time_s"):
-        assert example["config"][key] == config["trainer_config"][key]
-
-
-def minibatch(policy, seed=0):
-    """A seeded minibatch of the trainer's shape, as the rollout packs it,
-    and the same for the reference."""
-    cfg = policy.config
-    T_, L = cfg["rollout_fragment_length"], policy.block_len
-    frags = cfg["sgd_minibatch_size"] // T_
-    net = dict(NET, **cfg["model"]["custom_model_config"])
-    rng = np.random.default_rng(seed)
-    tokens = rng.integers(0, net["vocab_size"] - 1, (frags, T_))
-    steps = unmask_steps(rng, frags, T_, L, net["denoise_steps"])
-    episode = cfg["env_config"]["episode_len"] + 1
-    steps[:, ::episode] = -1
-    dones = np.zeros((frags, T_), np.float32)
-    dones[:, episode - 1::episode] = 1.0
-    ref = {"tokens": tokens, "steps": steps,
-           "rewards": rng.integers(0, 2, (frags, T_)).astype(np.float32),
-           "behaviour_logp": (-np.log(net["vocab_size"]) + rng.uniform(
-               -0.5, 0.5, (frags, T_))).astype(np.float32)}
-    batch = {
-        sb.OBS: jnp.asarray(tokens.reshape(-1), jnp.int32),
-        sb.ACTIONS: jnp.asarray(tokens.reshape(-1), jnp.int32),
-        sb.UNMASK_STEPS: jnp.asarray(steps.reshape(-1), jnp.int32),
-        sb.REWARDS: jnp.asarray(ref["rewards"].reshape(-1)),
-        sb.DONES: jnp.asarray(dones.reshape(-1)),
-        sb.ACTION_LOGP: jnp.asarray(ref["behaviour_logp"].reshape(-1)),
-        sb.VF_PREDS: jnp.zeros(frags * T_, jnp.float32),
-        sb.BOOTSTRAP_OBS: jnp.zeros(frags, jnp.int32)}
-    return batch, ref, net
-
-
-def loss_and_grad(policy, batch):
-    return jax.jit(jax.value_and_grad(
-        lambda p: policy._loss_fn(policy, p, batch, jax.random.PRNGKey(0),
-                                  policy.loss_state)[0]))(policy.params)
-
-
-def test_the_loss_and_its_gradient_are_the_reference_s(trained):
-    policy = trained[0].get_policy()
-    batch, ref, net = minibatch(policy)
-    assert ref["tokens"].shape[1] == net["max_position_embeddings"]
-    loss, grads = loss_and_grad(policy, batch)
-    want, want_grads = jax.jit(jax.value_and_grad(
-        lambda p: reference.vtrace_loss(
-            {"params": p}, ref, net, policy.config)[0]))(
-                policy.params["params"])
-    assert abs(float(loss) - float(want)) <= 1e-3 * abs(float(want))
-    flat = lambda tree: {  # noqa: E731
-        jax.tree_util.keystr(path): leaf for path, leaf in
-        jax.tree_util.tree_flatten_with_path(tree)[0]}
-    got, want_grads = flat(grads["params"]), flat(want_grads)
-    for name, g in want_grads.items():
-        scale = float(jnp.max(jnp.abs(g))) or 1.0
-        assert float(jnp.max(jnp.abs(got[name] - g))) <= 2e-3 * scale, name
-    # The MASK id's column of the head takes no gradient.
-    assert float(jnp.max(jnp.abs(grads["params"]["head"][:, -1]))) == 0.0
-
-
-def test_a_given_row_weighs_nothing(trained):
-    """Whatever stands in a given row's reward, behaviour log-probability
-    or action, the loss and its gradient are what they were."""
-    policy = trained[0].get_policy()
-    batch, _, _ = minibatch(policy, seed=1)
-    given = batch[sb.UNMASK_STEPS] < 0
-    assert int(jnp.sum(given)) > 0
-    loss, grads = loss_and_grad(policy, batch)
-    other = dict(
-        batch,
-        **{sb.REWARDS: jnp.where(given, 100.0, batch[sb.REWARDS]),
-           sb.ACTION_LOGP: jnp.where(given, -7.0, batch[sb.ACTION_LOGP]),
-           sb.ACTIONS: jnp.where(given, 3, batch[sb.ACTIONS])})
-    loss2, grads2 = loss_and_grad(policy, other)
-    assert float(loss) == float(loss2)
-    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads2)):
-        np.testing.assert_array_equal(a, b)
-    # A generated row's does move it.
-    moved = dict(batch, **{sb.REWARDS: batch[sb.REWARDS] + 1.0})
-    assert float(loss_and_grad(policy, moved)[0]) != float(loss)
-
-
-def test_the_first_minibatch_of_a_rollout_is_on_policy(trained):
-    """The optimizer's own rollout, learned from at the parameters that
-    sampled it: every block's importance ratio is 1."""
-    trainer = trained[0]
-    opt, policy = trainer.optimizer, trainer.get_policy()
-    cfg = policy.config
-    frags = N
-    model = policy.model
-    net = dict(NET, **cfg["model"]["custom_model_config"])
-    trace = rollout(model, policy.params, net, seed=9,
-                    positions=cfg["rollout_fragment_length"])
-    rows = lambda x: x[:frags].reshape(-1)  # noqa: E731
-    dones = np.zeros(trace["tokens"].shape, np.float32)
-    dones[:, -1] = 1.0
-    batch = {
-        sb.OBS: rows(trace["tokens"]), sb.ACTIONS: rows(trace["tokens"]),
-        sb.UNMASK_STEPS: rows(trace["steps"]),
-        sb.REWARDS: jnp.ones(frags * dones.shape[1]),
-        sb.DONES: jnp.asarray(rows(dones)),
-        sb.ACTION_LOGP: rows(trace["logp"]),
-        sb.BOOTSTRAP_OBS: jnp.zeros(frags, jnp.int32)}
-    _, stats = jax.jit(lambda p: policy._loss_fn(
-        policy, p, batch, jax.random.PRNGKey(0), policy.loss_state))(
-            policy.params)
-    assert abs(float(stats["is_ratio_mean"]) - 1.0) <= 1e-3
-    assert abs(float(stats["is_ratio_max"]) - 1.0) <= 5e-3
-    assert float(stats["given_rows"]) == frags
-    assert opt.num_envs >= frags
-
-
-@pytest.mark.parametrize("wrong", reference.MUTATIONS + ("float8_e4m3",))
-def test_limits_refuse_wrong_mathematics(wrong):
-    """The reference with a named error (or a precision lower) in the
-    system's place, held to its own experts: the cell's limits refuse it."""
-    model, variables, net = build()
-    trace = rollout(model, variables, net)
-    how = ({"round_to": wrong} if wrong == "float8_e4m3"
-           else {"mutate": wrong})
-    bad = plain(variables, trace, net, **how)
-    held = plain(variables, trace, net, bad["experts"])
-    out = reference.compare((bad["logits"], bad["values"]),
-                            (held["logits"], held["values"]))
-    routing = reference.routing_verdict(
-        bad["experts"], held["experts"], held["select"])
-    assert not (out["ok"] and routing["ok"]), (out, routing)
-
-
-def test_the_cell_s_program_is_known_from_its_static_shapes():
-    with open(os.path.join(
-            BENCH, "configs/impala_sdar_30b_a3b.json")) as f:
-        network = {k: v for k, v in json.load(f)["network"].items()
-                   if k != "param_count"}
-    model = catalog.get_model(None, network["vocab_size"] - 1, {
-        "custom_model": "sdar_moe", "custom_model_config": network})
-    got = model.static_counters(64, 2048, "tpu", 8192)
-    assert got["block_len"] == 4 and got["denoise_steps"] == 2
-    assert got["decode_passes_per_token"] == 0.75
-    # Five layers of three streams, the last one's clean stream left out.
-    assert got["learner_rows_per_token"] == 3 - 1 / 5 == 2.8
-    # 64 blocks of 4 rows, 8 of 128 experts each: 16 rows a held expert.
-    assert got["decode_rows_per_expert"] == 16.0
-    assert got["decode_experts_batched"] == 1.0
-    assert got["decode_experts_sparse"] == 0.0
-    assert got["decode_experts_read_share"] == 1.0
-    assert got["decode_attention_kernel"] == 1.0
-    assert got["block_attention_kernel"] == 1.0
-    assert got["block_cache_writes_per_block"] == 1
-    assert got["causal_attention_fused"] == 1.0
-    assert got["experts_grouped_kernel"] == 1.0
-    assert got["kv_groups"] == 8
-    # 5 layers x (K and V) x 4 heads x 128 x 2 bytes.
-    assert got["kv_cache_bytes_per_token"] == 5 * 2048
-    on_cpu = model.static_counters(64, 2048, "cpu", 8192)
-    assert on_cpu["decode_attention_kernel"] == 0.0
-    assert on_cpu["causal_attention_fused"] == 0.0
-    state = jax.eval_shape(lambda: model.initial_state(64))
-    assert set(state) == {"kv", "pos"}
-    assert sum(a.size * a.dtype.itemsize
-               for a in jax.tree.leaves(state["kv"])) == 64 * 2048 * 5 * 2048
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 1), jnp.int32),
-                           model.initial_state(1), jnp.zeros((1, 1))))
-    assert sum(a.size for a in jax.tree.leaves(params)) == 550987009
-
-
 def test_the_stream_mask_is_the_reference_s():
     """`block_stream_allowed` over S + 1 streams against the reference's
     own 2T mask, a pass at a time."""
@@ -810,22 +626,6 @@ def test_the_stream_mask_is_the_reference_s():
     assert not got[2 * T_:, T_:2 * T_].any()
 
 
-@pytest.mark.parametrize("cfg,outputs,match", [
-    ({"num_shared_experts": 1}, 95, "not sdar_moe's"),
-    ({"use_sliding_window": True}, 95, "use_sliding_window"),
-    ({"tie_word_embeddings": True}, 95, "tie_word_embeddings"),
-    ({}, 96, "MASK id"),
-    ({"block_length": 4, "denoise_steps": 3}, 95, "block"),
-    ({"block_length": 5}, 95, "block"),
-])
-def test_custom_model_config_without_a_part_is_refused(cfg, outputs, match):
-    net = dict(NET, **cfg)
-    with pytest.raises(ValueError, match=match):
-        model = transformer.sdar_moe_from_config(outputs, net, jnp.float32)
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                   jax.eval_shape(lambda: None), jnp.zeros((1, 1)))
-
-
 def test_keys_left_out_have_the_published_model_s_values():
     model = transformer.sdar_moe_from_config(151935, {})
     assert (model.hidden_size, model.num_heads, model.num_kv_heads,
@@ -836,25 +636,3 @@ def test_keys_left_out_have_the_published_model_s_values():
             model.context_len) == (151936, 151936, 151935, 32768)
     assert (model.block_len, model.denoise_steps, model.qk_norm,
             model.rope_theta, model.rms_eps) == (4, 2, "head", 1000000, 1e-6)
-
-
-@pytest.mark.parametrize("episode_len,fragment", [(30, 32), (31, 48),
-                                                  (15, 32)])
-def test_the_optimizer_refuses_fragments_that_are_not_whole_episodes(
-        episode_len, fragment):
-    """An episode is the env's steps and its given first position, in whole
-    blocks, and a fragment whole episodes."""
-    from ray_tpu.rllib.agents.registry import get_trainer_class
-    config = dict(
-        env="TokenBigram-v0",
-        env_config={"vocab_size": 95, "episode_len": episode_len},
-        anakin=True, num_workers=0, num_envs_per_worker=4,
-        rollout_fragment_length=fragment, train_batch_size=4 * fragment,
-        min_iter_time_s=0,
-        model={"custom_model": "sdar_moe", "compute_dtype": "f32",
-               "custom_model_config": dict(NET, max_position_embeddings=64)})
-    if (episode_len + 1) % 4 == 0 and fragment % (episode_len + 1) == 0:
-        get_trainer_class("IMPALA")(config=config).stop()
-        return
-    with pytest.raises(ValueError, match="whole episodes"):
-        get_trainer_class("IMPALA")(config=config)

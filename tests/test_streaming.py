@@ -364,7 +364,7 @@ class TestMidPipelineLoss:
 
 
 class TestSequenceGap:
-    """Effectively-once gap fix (ADVICE r5): a receiver restarting from
+    """Effectively-once gap fix (advisor round 5): a receiver restarting from
     a checkpoint must REFUSE items past the sequence hole left by
     acked-but-uncheckpointed applies, and the sender must replay its
     retention — silently applying past the hole loses the suffix."""
